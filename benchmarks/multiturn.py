@@ -492,18 +492,20 @@ def main() -> dict:
             args.out = "benchmarks/results/multiturn_tiering.json"
 
     if args.platform != "auto":
-        # Before any jax computation (env vars are read too early in
-        # some images; jax.config is the reliable override). Inside an
-        # already-initialized process (the in-pytest smoke) both calls
-        # are harmless no-ops and the session's devices win.
+        # Before any jax computation.
         import jax
 
         jax.config.update("jax_platforms", args.platform)
         if args.platform == "cpu":
-            from tpu_inference.compat import set_cpu_device_count
-
             need = max(args.dp, 2 if args.compare_routing else 1)
-            set_cpu_device_count(max(1, need * args.tp * args.sp))
+            try:
+                jax.config.update("jax_num_cpu_devices",
+                                  max(1, need * args.tp * args.sp))
+            except RuntimeError:
+                # Backends are already up (main() called inside a
+                # process that has run jax, e.g. pytest): jax refuses
+                # to change the count, and the host's devices stand.
+                pass
 
     if args.compare_routing:
         return _compare_routing(args)

@@ -862,9 +862,7 @@ def main() -> dict:
                 os.path.dirname(args.out) or ".", "replay_pd_trace.json")
 
     if args.platform != "auto":
-        # Before any jax computation (env vars are read too early in
-        # some images; jax.config is the reliable override — same
-        # pattern as the server CLI and tests/conftest.py).
+        # Before any jax computation.
         import jax
 
         jax.config.update("jax_platforms", args.platform)
@@ -874,11 +872,14 @@ def main() -> dict:
             # and shrinking a host that asked for more (the in-process
             # --smoke test runs inside pytest's 8-device session) would
             # pin the whole process to 1 device before backend init.
-            # (After backend init the call is a harmless no-op, so the
-            # pytest session's 8 devices always win.)
-            from tpu_inference.compat import set_cpu_device_count
-
-            set_cpu_device_count(args.dp * args.tp * args.sp)
+            try:
+                jax.config.update("jax_num_cpu_devices",
+                                  args.dp * args.tp * args.sp)
+            except RuntimeError:
+                # Backends are already up (main() called inside a
+                # process that has run jax, e.g. pytest): jax refuses
+                # to change the count, and the host's devices stand.
+                pass
 
     from tpu_inference.engine.autosize import (parse_decode_ladder,
                                                resolve_sizing_args)

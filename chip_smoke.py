@@ -1,0 +1,860 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof the system still starts on the chip.
+
+Run from the repo root on a machine with a TPU:
+
+    python chip_smoke.py            # one chip (what the driver runs)
+    python chip_smoke.py --chips 4  # the cross-chip paths only (4-chip host)
+
+It drives the main path once through the entry points a user calls — the
+server CLI over HTTP — serving Mistral-7B at its published widths (random
+weights from --seed, int8, the Pallas attention kernels, HBM-derived batch
+and pool, default ladder and warm-up), then checks the result by the repo's
+own means: logits through prefill-then-paged-decode against a plain float32
+dense forward. One chip, in order:
+
+  serve    the CLI; requests over HTTP (one prompt per prefill bucket, a
+           greedy repeat, a burst past the ladder's base rung, /api/chat,
+           one prompt past the 4096 window); /healthz and /metrics must
+           name the tpu backend, the chip's device_kind and pallas;
+           SIGTERM must exit 0
+  parity   same widths, depth cut to PARITY_LAYERS so the float32
+           reference fits beside the engine: engine logits vs reference
+  fleet    the CLI again with --fleet subprocess --dp 1; passes only if
+           the router process stays off the chip
+
+With --chips 4, only what exists across chips, and what it is compared
+with: tp4 (the CLI with --tp 4 in bf16 at full depth, which one chip
+cannot hold; then tp=4 vs tp=1 logits and shard placement) and dp4 (the
+subprocess fleet with one worker process per chip).
+
+This process never imports jax: a chip belongs to one process at a time,
+so every phase is a child that owns the chip for its lifetime and has
+exited before the next starts. Any phase that fails, times out or reports
+a backend other than the TPU ends the run at once with a non-zero exit and
+no result line. Earlier lines are per-phase JSON (wall seconds, warm-up
+seconds and graphs, the sizes 'auto' chose, peak device bytes); the last
+line of stdout is the device JSON the driver reads.
+"""
+
+from __future__ import annotations
+
+import argparse
+import concurrent.futures
+import json
+import os
+import signal
+import socket
+import subprocess
+import sys
+import time
+import urllib.error
+import urllib.request
+
+ROOT = os.path.dirname(os.path.abspath(__file__))
+
+# What the smoke serves and how hard it looks. A dict the phases take as
+# an argument (and ship to their children), so tests/test_chip_smoke.py
+# can drive the same phases at a tiny preset on the CPU without this
+# script growing options.
+SETTINGS = {
+    "model": "mistral-7b",
+    "platform": "tpu",
+    "quant": "int8",
+    "attn_backend": "pallas",
+    "sizing": ["--max-batch-size", "auto", "--num-pages", "auto"],
+    # 320 pages x 16 = 5120 tokens of context: past Mistral's 4096
+    # window, so the windowed kernels' offset path and behind-window
+    # page eviction both run (the default 64 = 1024 tokens never binds).
+    "max_pages_per_seq": 320,
+    "bucket_prompts": [40, 100, 200, 400, 900],   # one per prefill bucket
+    "long_prompt": 4300,
+    "max_tokens": 16,
+    # More requests at once than the ladder's base rung of 8, each long
+    # enough that they are all decoding together.
+    "burst": 12,
+    "burst_tokens": 64,
+    "boot_timeout_s": 900,
+    "drain_timeout_s": 30,
+    # parity: depth cut so the f32 reference fits beside the engine on
+    # one 16 GB chip (4 layers: ~1.4 GB int8 engine + f32 temporaries).
+    "parity_layers": 4,
+    "parity_prompts": [300, 1500],   # 1500 > the largest (1024) bucket
+    "parity_decode_steps": 8,
+    # Engine logits vs the float32 reference, as shares of a position's
+    # reference logit spread (std over the vocabulary). Both sides
+    # multiply the SAME int8 codes; they differ in activation dtype
+    # (bf16 with f32 accumulation vs f32 at "highest" matmul precision)
+    # and in attention (paged Pallas kernels vs dense). bf16 rounds the
+    # residual stream and every projection to 8 mantissa bits; over 4
+    # layers that is 1.7-1.9% rms of the spread — measured with NO
+    # Pallas kernel in the path (dense backend on the CPU, same widths:
+    # rms 0.0175 / 0.0187, max 0.070 / 0.087 over 32000 logits) and the
+    # same on the v5e with the kernels (max 0.075 at the same position).
+    # So these bounds sit ~1.6x above bf16's own noise; what they catch
+    # is a wrong page, mask, scale or weight (errors of order 1). The
+    # kernels' arithmetic is held much tighter, alone: kernel_tol.
+    "parity_tol": {"rms": 0.03, "max": 0.15},
+    # The two Pallas kernels alone, at the model's head shapes, window
+    # and page size, on random bf16 K/V: output vs dense float32
+    # attention over the same values, as a share of each query's output
+    # spread. Operands are identical on both sides and the result is
+    # taken in f32, so ALL that differs is the kernel's own arithmetic:
+    # 1e-5 in interpret mode on the CPU and 2e-5 on the v5e (Mosaic
+    # runs the kernels' f32 dots at full precision). 50x above that; a
+    # kernel that kept its scores or softmax sums in bf16 (2^-9 = 2e-3
+    # per term) lands above this.
+    "kernel_tol": 0.001,
+    # tp4_parity: bf16 at a depth one chip also holds (the tp=1 side;
+    # the same 4 layers the tolerance above was measured at), prompts
+    # inside one prefill bucket (the one-chip parity does the chunking).
+    "tp_layers": 4,
+    "tp_prompts": [300, 500],
+    # Warm-up on, as a user gets it. The four-chip phases turn it off:
+    # what they are for exists across chips, warm-up does not, and each
+    # of their seconds is charged four times.
+    "warmup": True,
+}
+
+OUT_DIR = os.path.join(ROOT, "chiprun_out", "chip_smoke")
+
+
+class SmokeFailure(Exception):
+    """A check failed; the run ends non-zero with no result line."""
+
+
+def check(cond: bool, what: str) -> None:
+    if not cond:
+        raise SmokeFailure(what)
+
+
+def emit(record: dict) -> None:
+    print(json.dumps(record), flush=True)
+
+
+# --------------------------------------------------------------- HTTP side
+
+
+def free_port() -> int:
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        return s.getsockname()[1]
+
+
+def http_text(url: str, timeout: float = 60.0) -> str:
+    with urllib.request.urlopen(url, timeout=timeout) as r:
+        return r.read().decode()
+
+
+def http_json(url: str, timeout: float = 60.0):
+    return json.loads(http_text(url, timeout))
+
+
+def prompt_of(n_tokens: int, salt: int) -> str:
+    """ASCII text that the byte tokenizer turns into exactly n_tokens
+    ids (BOS + one per byte); ``salt`` makes prompts differ."""
+    words = f"request {salt} asks the server about paged attention on a tpu; "
+    return (words * (n_tokens // len(words) + 1))[:n_tokens - 1]
+
+
+def stream_generate(base: str, prompt: str, max_tokens: int,
+                    chat: bool = False, timeout: float = 600.0) -> dict:
+    """One streaming request; checks the NDJSON framing and the terminal
+    record's counters, returns that record."""
+    body = {"model": "smoke", "temperature": 0, "max_tokens": max_tokens,
+            "stream": True}
+    if chat:
+        body["messages"] = [{"role": "user", "content": prompt}]
+    else:
+        body["prompt"] = prompt
+    req = urllib.request.Request(
+        base + ("/api/chat" if chat else "/api/generate"),
+        data=json.dumps(body).encode())
+    lines = []
+    with urllib.request.urlopen(req, timeout=timeout) as r:
+        check(r.status == 200, f"HTTP {r.status}")
+        ctype = r.headers.get("Content-Type", "")
+        check(ctype.startswith("application/x-ndjson"),
+              f"Content-Type {ctype!r} is not NDJSON")
+        for raw in r:
+            check(raw.endswith(b"\n"), "NDJSON line without a newline")
+            lines.append(json.loads(raw))
+    check(len(lines) >= 2, f"{len(lines)} NDJSON lines: no token streamed")
+    *tokens, final = lines
+    key = "message" if chat else "response"
+    for t in tokens:
+        check(t.get("done") is False and key in t,
+              f"malformed token line {t}")
+    check(final.get("done") is True, f"last line is not done: {final}")
+    for field in ("total_duration", "prompt_eval_count",
+                  "prompt_eval_duration", "eval_count", "eval_duration",
+                  "done_reason"):
+        check(field in final, f"done record lacks {field}")
+    n = final["eval_count"]
+    check(1 <= n <= max_tokens, f"eval_count {n} outside 1..{max_tokens}")
+    check(final["done_reason"] != "length" or n == max_tokens,
+          f"done_reason=length with eval_count {n} != {max_tokens}")
+    # One line per token, plus at most one flushing a split UTF-8 tail.
+    check(n <= len(tokens) <= n + 1,
+          f"{len(tokens)} token lines for eval_count {n}")
+    if not chat:
+        check(len(final["context"]) == final["prompt_eval_count"] + n,
+              "context length != prompt_eval_count + eval_count")
+    return final
+
+
+def metric_value(metrics: str, name: str) -> float:
+    """Sum of a family's samples in a Prometheus text page."""
+    total, seen = 0.0, False
+    for line in metrics.splitlines():
+        if line.startswith(name) and line[len(name)] in " {":
+            total += float(line.rsplit(" ", 1)[1])
+            seen = True
+    check(seen, f"/metrics has no {name}")
+    return total
+
+
+class Server:
+    """The real CLI as a child process; the child owns the chip."""
+
+    def __init__(self, cfg: dict, name: str, extra: list):
+        self.cfg, self.name = cfg, name
+        self.port = free_port()
+        self.base = f"http://127.0.0.1:{self.port}"
+        os.makedirs(OUT_DIR, exist_ok=True)
+        self.log_path = os.path.join(OUT_DIR, f"{name}.log")
+        cmd = [sys.executable, "-m", "tpu_inference.server",
+               "--model", cfg["model"], "--platform", cfg["platform"],
+               "--attn-backend", cfg["attn_backend"],
+               "--max-pages-per-seq", str(cfg["max_pages_per_seq"]),
+               "--port", str(self.port), *cfg["sizing"], *extra,
+               *([] if cfg["warmup"] else ["--no-warmup"])]
+        self.cmd = " ".join(cmd[1:])
+        self._log = open(self.log_path, "wb")
+        self.t0 = time.monotonic()
+        self.proc = subprocess.Popen(cmd, cwd=ROOT, stdout=self._log,
+                                     stderr=subprocess.STDOUT)
+
+    def log_tail(self, n: int = 2000) -> str:
+        with open(self.log_path, "rb") as f:
+            return f.read()[-n:].decode(errors="replace")
+
+    def wait_ready(self) -> float:
+        """Poll /healthz until 200; fails at once if the child exits
+        (no TPU: its first line of business is --platform)."""
+        deadline = self.t0 + self.cfg["boot_timeout_s"]
+        while time.monotonic() < deadline:
+            rc = self.proc.poll()
+            check(rc is None, f"{self.name}: server exited rc={rc} before "
+                              f"serving:\n{self.log_tail()}")
+            try:
+                http_json(self.base + "/healthz", timeout=5)
+                return time.monotonic() - self.t0
+            except (urllib.error.URLError, ConnectionError, TimeoutError):
+                time.sleep(1.0)
+        raise SmokeFailure(
+            f"{self.name}: not serving after "
+            f"{self.cfg['boot_timeout_s']}s:\n{self.log_tail()}")
+
+    def device_facts(self, replicas: int = 1) -> list:
+        """Per-replica device facts from /healthz, checked against what
+        was asked for; /metrics must say the same."""
+        hz = http_json(self.base + "/healthz")
+        check(hz["status"] == "ok", f"/healthz status {hz['status']}")
+        devs = [r["device"] for r in hz["replicas"]]
+        check(len(devs) == replicas, f"{len(devs)} replicas, not {replicas}")
+        for d in devs:
+            check(d["platform"] == self.cfg["platform"],
+                  f"replica runs on {d['platform']!r}, "
+                  f"not {self.cfg['platform']!r}")
+            check(d["attn_backend"] == self.cfg["attn_backend"],
+                  f"attention backend {d['attn_backend']!r}")
+            check(d["kind"] == devs[0]["kind"] and d["kind"],
+                  "replicas on different device kinds")
+        info = [l for l in http_text(self.base + "/metrics").splitlines()
+                if l.startswith("tpu_inf_build_info{")]
+        check(bool(info), "/metrics has no tpu_inf_build_info")
+        for l in info:
+            for label in (f'backend="{self.cfg["platform"]}"',
+                          f'device_kind="{devs[0]["kind"]}"',
+                          f'attn_backend="{self.cfg["attn_backend"]}"'):
+                check(label in l, f"/metrics build_info lacks {label}: {l}")
+        return devs
+
+    def stop(self) -> float:
+        """SIGTERM; the child must exit 0 inside its drain budget."""
+        t = time.monotonic()
+        self.proc.send_signal(signal.SIGTERM)
+        try:
+            rc = self.proc.wait(self.cfg["drain_timeout_s"])
+        except subprocess.TimeoutExpired:
+            raise SmokeFailure(f"{self.name}: still running "
+                               f"{self.cfg['drain_timeout_s']}s after SIGTERM")
+        check(rc == 0, f"{self.name}: exit code {rc} after SIGTERM:\n"
+                       f"{self.log_tail()}")
+        return time.monotonic() - t
+
+    def kill(self) -> None:
+        if self.proc.poll() is None:
+            self.proc.kill()
+            self.proc.wait()
+        self._log.close()
+
+
+def run_child(cfg: dict, name: str, timeout: float = 900.0) -> dict:
+    """One jax-owning child of this file (``_child <name> <settings>``);
+    its last stdout line is its JSON result."""
+    os.makedirs(OUT_DIR, exist_ok=True)
+    t0 = time.monotonic()
+    with open(os.path.join(OUT_DIR, f"{name}.log"), "wb") as log:
+        try:
+            p = subprocess.run(
+                [sys.executable, os.path.abspath(__file__), "_child", name,
+                 json.dumps(cfg)],
+                cwd=ROOT, stdout=subprocess.PIPE, stderr=log,
+                timeout=timeout)
+        except subprocess.TimeoutExpired:
+            raise SmokeFailure(f"{name}: no result after {timeout}s")
+    out = p.stdout.decode(errors="replace")
+    check(p.returncode == 0, f"{name}: child exit code {p.returncode}:\n"
+                             f"{out[-3000:]}")
+    rec = json.loads(out.strip().splitlines()[-1])
+    rec["wall_s"] = round(time.monotonic() - t0, 1)
+    return rec
+
+
+# ------------------------------------------------------------ the phases
+
+
+def phase_record(name: str, srv: Server, boot_s: float, devs: list,
+                 **more) -> dict:
+    d = devs[0]
+    return {"phase": name, "ok": True, "cmd": srv.cmd,
+            "wall_s": round(time.monotonic() - srv.t0, 1),
+            "boot_s": round(boot_s, 1), "warmup_s": d["warmup_s"],
+            "warmup_graphs": d["warmup_graphs"],
+            "max_batch_size": d["max_batch_size"],
+            "num_pages": d["num_pages"], "ladder": d["ladder"],
+            "peak_bytes_in_use": d["peak_bytes_in_use"],
+            "device": {"platform": d["platform"], "kind": d["kind"]},
+            **more}
+
+
+def phase_serve(cfg: dict) -> dict:
+    srv = Server(cfg, "serve", ["--quant", cfg["quant"]])
+    try:
+        boot_s = srv.wait_ready()
+        srv.device_facts()
+        n = cfg["max_tokens"]
+        # One prompt per prefill bucket, alone: the 1-step latency graph.
+        first = None
+        for i, length in enumerate(cfg["bucket_prompts"]):
+            rec = stream_generate(srv.base, prompt_of(length, i), n)
+            check(rec["prompt_eval_count"] == length,
+                  f"prompt of {length} tokens counted "
+                  f"{rec['prompt_eval_count']}")
+            first = first or rec
+        # Greedy repeat of the first prompt under the same conditions.
+        again = stream_generate(srv.base,
+                                prompt_of(cfg["bucket_prompts"][0], 0), n)
+        check(again["context"] == first["context"],
+              "greedy repeat of one prompt produced different tokens")
+        # A burst wider than the base rung: batched prefill + fused-K
+        # decode, and the ladder must climb.
+        lens = cfg["bucket_prompts"]
+        with concurrent.futures.ThreadPoolExecutor(cfg["burst"]) as pool:
+            futs = [pool.submit(stream_generate, srv.base,
+                                prompt_of(lens[i % len(lens)], 100 + i),
+                                cfg["burst_tokens"])
+                    for i in range(cfg["burst"])]
+            burst = [f.result() for f in futs]
+        switches = metric_value(http_text(srv.base + "/metrics"),
+                                "tpu_inf_rung_switches_total")
+        check(switches >= 1, "a burst past the base rung never moved the "
+                             "decode ladder")
+        chat = stream_generate(srv.base, "why page the kv cache?", n,
+                               chat=True)
+        long_tokens = None
+        if cfg["long_prompt"]:
+            # Past the sliding window: windowed kernels with a non-zero
+            # window start, chunked prefill over five chunks, eviction.
+            rec = stream_generate(srv.base,
+                                  prompt_of(cfg["long_prompt"], 7), n)
+            check(rec["prompt_eval_count"] == cfg["long_prompt"],
+                  f"long prompt counted {rec['prompt_eval_count']}")
+            long_tokens = rec["prompt_eval_count"] + rec["eval_count"]
+        devs = srv.device_facts()       # after load: peak memory is real
+        drain_s = srv.stop()
+        return phase_record(
+            "serve", srv, boot_s, devs,
+            requests=len(cfg["bucket_prompts"]) + 2 + len(burst)
+            + (1 if cfg["long_prompt"] else 0),
+            tokens_out=(sum(r["eval_count"] for r in burst)
+                        + chat["eval_count"]),
+            rung_switches=switches, long_request_tokens=long_tokens,
+            sigterm_exit_s=round(drain_s, 1))
+    finally:
+        srv.kill()
+
+
+def phase_fleet(cfg: dict, dp: int = 1) -> dict:
+    """--fleet subprocess: a router plus one worker process per chip. The
+    workers can only reach their chips if the router never initialised a
+    JAX backend, so serving at all is the proof."""
+    name = "fleet" if dp == 1 else f"dp{dp}"
+    srv = Server(cfg, name, ["--quant", cfg["quant"], "--fleet",
+                             "subprocess", "--dp", str(dp)])
+    try:
+        boot_s = srv.wait_ready()
+        srv.device_facts(replicas=dp)
+        n = cfg["max_tokens"]
+        n_req = 2 if dp == 1 else 2 * dp
+        with concurrent.futures.ThreadPoolExecutor(n_req) as pool:
+            futs = [pool.submit(stream_generate, srv.base,
+                                prompt_of(cfg["bucket_prompts"][1], 200 + i),
+                                n) for i in range(n_req)]
+            recs = [f.result() for f in futs]
+        devs = srv.device_facts(replicas=dp)
+        hz = http_json(srv.base + "/healthz")
+        check(hz.get("fleet") == "subprocess", "/healthz is not the fleet's")
+        pids = {r["pid"] for r in hz["replicas"]}
+        check(len(pids) == dp and os.getpid() not in pids
+              and srv.proc.pid not in pids,
+              f"workers are not {dp} processes of their own: {pids}")
+        # One chip group per worker: each was spawned with different
+        # chips visible, and since a chip belongs to one process at a
+        # time, dp live workers that all served sit on dp different ones.
+        chips = [d["visible_chips"] for d in devs]
+        check(len(set(chips)) == dp and None not in chips,
+              f"workers were not given chips of their own: {chips}")
+        served = [r["routing"]["hits"] + r["routing"]["cold"]
+                  for r in hz["replicas"]]
+        check(all(n >= 1 for n in served),
+              f"a worker served nothing: {served}")
+        drain_s = srv.stop()
+        return phase_record(name, srv, boot_s, devs, requests=len(recs),
+                            worker_pids=sorted(pids), served=served,
+                            worker_chips=chips,
+                            sigterm_exit_s=round(drain_s, 1))
+    finally:
+        srv.kill()
+
+
+def phase_tp4(cfg: dict) -> dict:
+    """--tp 4 in bf16 at full depth: 14.5 GB of weights, which one 16 GB
+    chip cannot hold beside a KV pool, sharded 3.6 GB a chip."""
+    srv = Server(cfg, "tp4", ["--tp", "4"])
+    try:
+        boot_s = srv.wait_ready()
+        devs = srv.device_facts()
+        check(len(devs[0]["ids"]) == 4,
+              f"KV pool sits on devices {devs[0]['ids']}, not on four")
+        n = cfg["max_tokens"]
+        length = cfg["bucket_prompts"][1]
+        first = stream_generate(srv.base, prompt_of(length, 0), n)
+        again = stream_generate(srv.base, prompt_of(length, 0), n)
+        check(again["context"] == first["context"],
+              "greedy repeat of one prompt produced different tokens")
+        with concurrent.futures.ThreadPoolExecutor(4) as pool:
+            futs = [pool.submit(stream_generate, srv.base,
+                                prompt_of(length, 300 + i), n)
+                    for i in range(4)]
+            recs = [f.result() for f in futs]
+        devs = srv.device_facts()
+        drain_s = srv.stop()
+        return phase_record("tp4", srv, boot_s, devs, requests=2 + len(recs),
+                            pool_devices=devs[0]["ids"],
+                            sigterm_exit_s=round(drain_s, 1))
+    finally:
+        srv.kill()
+
+
+def run(chips: int, seed: int, cfg: dict) -> list:
+    """The phases for ``chips`` in order, each printed as it passes."""
+    cfg = dict(cfg, seed=seed)
+    if chips == 1:
+        phases = [lambda: phase_serve(cfg),
+                  lambda: dict(run_child(cfg, "parity"), phase="parity"),
+                  lambda: phase_fleet(cfg)]
+    else:
+        cfg = dict(cfg, warmup=False, parity_prompts=cfg["tp_prompts"])
+        phases = [lambda: phase_tp4(cfg),
+                  lambda: dict(run_child(cfg, "tp4_parity"),
+                               phase="tp4_parity"),
+                  lambda: phase_fleet(cfg, dp=4)]
+    done = []
+    for phase in phases:
+        rec = phase()
+        emit(rec)
+        done.append(rec)
+    return done
+
+
+def result_device(phases: list) -> dict:
+    """The device of the last line, as JAX reported it inside the phases:
+    every phase on the same platform and kind; the count from the child
+    that listed jax.devices()."""
+    seen = {(p["device"]["platform"], p["device"]["kind"]) for p in phases}
+    check(len(seen) == 1, f"phases ran on different devices: {seen}")
+    platform, kind = seen.pop()
+    count = next(p["device"]["count"] for p in phases
+                 if "count" in p["device"])
+    return {"platform": platform, "kind": kind, "count": count}
+
+
+# --------------------------------------------- children (these import jax)
+
+
+def _child_setup(cfg: dict):
+    """Common start of a jax-owning child: platform, compile cache, and
+    the refusal to run anywhere but where it was sent."""
+    from tpu_inference.runtime import (enable_compile_cache,
+                                       require_backend, select_platform)
+
+    select_platform(cfg["platform"], cpu_devices=4)
+    enable_compile_cache()
+    require_backend(cfg["platform"])
+    import jax
+
+    d = jax.devices()[0]
+    return {"platform": d.platform, "kind": d.device_kind,
+            "count": len(jax.devices())}
+
+
+def _build_engine(cfg: dict, layers: int, quant: str, mesh=None,
+                  params=None):
+    import dataclasses
+
+    from tpu_inference.config import PRESETS, EngineConfig
+    from tpu_inference.engine.engine import InferenceEngine
+
+    mcfg = dataclasses.replace(PRESETS[cfg["model"]](), n_layers=layers)
+    ecfg = EngineConfig(quant=quant, attn_backend=cfg["attn_backend"],
+                        max_pages_per_seq=128, num_pages=512,
+                        max_batch_size=8, enable_prefix_cache=False)
+    return InferenceEngine(mcfg, ecfg, params=params, seed=cfg["seed"],
+                           mesh=mesh)
+
+
+def _engine_logits(eng, prompts: list, decode_steps: int):
+    """Drive the engine's real path — (chunked) prefill, then fused-K
+    paged decode — and read logits back off the pool it wrote.
+
+    The serving graphs return sampled tokens only, so logits come from a
+    probe built of the engine's own parts (its paged attention, model
+    forward and unembed): one query at position p over the pool's first
+    p+1 tokens. At p = prompt_len-1 every page it reads was written by
+    the prefill graphs; at p = the last position, by prefill AND the
+    decode graph's scatter writes. Returns, per prompt, the generated
+    tokens and {position: logits [V] f32}."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tpu_inference.engine.engine import Sequence
+
+    # A budget the run never reaches: a finished sequence would hand
+    # its pages back before the probe reads them.
+    seqs = [Sequence(request_id=i, prompt_tokens=list(p),
+                     max_new_tokens=4 * decode_steps)
+            for i, p in enumerate(prompts)]
+    for s in seqs:
+        eng.prefill(s)
+    while any(len(s.generated) < decode_steps + 1 for s in seqs):
+        eng.decode_steps()
+
+    def probe(params, kv, token, pos, block_table):
+        attn = eng._paged_attn(eng.model_cfg, block_table, pos[:, None],
+                               jnp.ones((1, 1), bool), q_offset=pos,
+                               kv_len=pos + 1)
+        hidden, kv = eng.mod.forward_hidden(
+            params, eng.model_cfg, token[:, None], pos[:, None], kv, attn)
+        return kv, eng.mod.unembed(params, eng.model_cfg, hidden[:, 0])
+
+    probe = jax.jit(probe, donate_argnums=(1,))
+    out = []
+    for s in seqs:
+        stream = s.prompt_tokens + s.generated
+        table = jnp.asarray(eng._block_table_array(s.pages))[None]
+        logits = {}
+        # The last token the checked stream has in KV is the one
+        # before the last sampled token.
+        for p in (len(s.prompt_tokens) - 1,
+                  len(s.prompt_tokens) + decode_steps - 1):
+            eng.kv, lg = probe(eng.params, eng.kv,
+                               jnp.asarray([stream[p]], jnp.int32),
+                               jnp.asarray([p], jnp.int32), table)
+            logits[p] = np.asarray(lg[0], np.float32)
+        out.append((s.generated[:decode_steps + 1], logits))
+    return out
+
+
+def _reference_logits(eng, streams: list):
+    """The plain reference: one dense float32 forward over each whole
+    token stream (models.common.make_dense_attn, no cache, no kernels,
+    matmuls at "highest" precision), on the engine's own weights — the
+    same int8 codes, or the same bf16 values, widened to f32."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tpu_inference.models.common import make_dense_attn
+
+    cfg32 = dataclasses.replace(eng.model_cfg, dtype=jnp.float32)
+    width = max(len(s) for s in streams)
+    toks = np.zeros((len(streams), width), np.int32)
+    for i, s in enumerate(streams):
+        toks[i, :len(s)] = s            # right pad: causal, so harmless
+    pos = np.broadcast_to(np.arange(width, dtype=np.int32), toks.shape)
+
+    def fwd(params, tokens, positions):
+        logits, _ = eng.mod.forward(params, cfg32, tokens, positions, None,
+                                    make_dense_attn(cfg32.sliding_window))
+        return logits
+
+    # Replicated inputs: under a mesh the params are sharded, and GSPMD
+    # partitions this program too; the arithmetic stays f32 either way.
+    with jax.default_matmul_precision("highest"):
+        return np.asarray(jax.jit(fwd)(eng.params, jnp.asarray(toks),
+                                       jnp.asarray(pos)), np.float32)
+
+
+def _compare(got: list, ref, prompts: list, tol: dict) -> dict:
+    """Engine logits and greedy tokens against the reference. Errors are
+    shares of the position's reference logit spread (std over the
+    vocabulary); every position is measured before any is judged, so a
+    failure still reports the whole table."""
+    import numpy as np
+
+    rms, peak, gaps, bad = [], [], [], []
+    for i, (generated, logits) in enumerate(got):
+        for p, lg in logits.items():
+            r = ref[i, p]
+            check(lg.shape == r.shape, f"logits shape {lg.shape}")
+            check(bool(np.isfinite(lg).all()), f"non-finite logits at {p}")
+            d = (lg - r) / np.std(r)
+            rms.append(float(np.sqrt(np.mean(d * d))))
+            peak.append(float(np.max(np.abs(d))))
+            if rms[-1] > tol["rms"] or peak[-1] > tol["max"]:
+                bad.append(f"prompt {i} position {p}: logit error rms "
+                           f"{rms[-1]:.4f} max {peak[-1]:.4f}")
+        # The serving graphs' own greedy tokens, judged on the
+        # reference's logits: each must be the reference's top token, or
+        # within the max-error tolerance of it (random weights leave
+        # near-ties that bf16 may flip).
+        for j, tok in enumerate(generated):
+            r = ref[i, len(prompts[i]) - 1 + j]
+            gaps.append(float((r.max() - r[tok]) / np.std(r)))
+            if gaps[-1] > tol["max"]:
+                bad.append(f"prompt {i} token {j}: sampled {gaps[-1]:.4f} "
+                           "below the reference's best")
+    res = {"logit_err_rms": round(max(rms), 5),
+           "logit_err_max": round(max(peak), 5),
+           "token_gap_max": round(max(gaps), 5),
+           "checks": len(rms) + len(gaps), "tol": tol}
+    check(not bad, f"{'; '.join(bad)} (of the logit spread; {res})")
+    return res
+
+
+def _kernel_errors(cfg: dict, mcfg, interpret: bool = False) -> dict:
+    """Both Pallas kernels against dense float32 attention on the same
+    random bf16 pool: the model's heads, window and 16-token pages;
+    contexts short, long, and past the window (so the windowed page
+    offset is exercised at a non-zero window start); one prefill chunk
+    that starts behind the window's edge."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tpu_inference.engine import kv_cache as kvc
+    from tpu_inference.kernels.paged_attention import paged_attention
+    from tpu_inference.kernels.prefill_attention import (
+        paged_prefill_attention)
+    from tpu_inference.models.common import dense_causal_attention
+
+    hq, hkv, d = mcfg.n_heads, mcfg.n_kv_heads, mcfg.head_dim
+    win, page = mcfg.sliding_window, 16
+    span = (win or 4096) + 1024
+    mp = span // page
+    kv_lens = np.array([100, span // 4 + 7, span - 700, span - 1], np.int32)
+    b = len(kv_lens)
+    key = jax.random.split(jax.random.PRNGKey(cfg["seed"]), 4)
+    pool_shape = (b * mp + 1, page, hkv, d)
+    k_pool = jax.random.normal(key[0], pool_shape, jnp.bfloat16)
+    v_pool = jax.random.normal(key[1], pool_shape, jnp.bfloat16)
+    tables = jnp.asarray(1 + np.arange(b * mp, dtype=np.int32).reshape(b, mp))
+    kv = kvc.KVPages(k=k_pool[None], v=v_pool[None])
+    k_all, v_all = kvc.gather_kv(kv, 0, tables)
+
+    def err(got, want):
+        """Worst |got - want| of any query, as a share of that query's
+        own output spread (over heads x dims): queries that attend to 10
+        tokens and to 4000 differ 20x in output scale."""
+        check(got.dtype == jnp.float32, f"kernel output is {got.dtype}")
+        got, want = np.asarray(got), np.asarray(want)
+        check(bool(np.isfinite(got).all()), "non-finite kernel output")
+        spread = np.std(want, axis=(-2, -1), keepdims=True)
+        return float(np.max(np.abs(got - want) / spread))
+
+    out = {}
+    with jax.default_matmul_precision("highest"):
+        # bf16 values held in f32: the kernels return q's dtype, so the
+        # operands are what serving feeds them (bf16-exact q, bf16 pool)
+        # but the result is not rounded to bf16 on the way out.
+        q = jax.random.normal(key[2], (b, hq, d),
+                              jnp.bfloat16).astype(jnp.float32)
+        got = paged_attention(q, k_pool, v_pool, tables,
+                              jnp.asarray(kv_lens), interpret=interpret,
+                              sliding_window=win)
+        want = dense_causal_attention(
+            q[:, None], k_all, v_all,
+            q_offset=jnp.asarray(kv_lens - 1), kv_len=jnp.asarray(kv_lens),
+            sliding_window=win)[:, 0]
+        out["decode"] = err(got, want)
+        # Prefill: a fresh 512-token chunk, and one whose first query
+        # sits 96 tokens before the context passes the window.
+        s_len = 512
+        q_off = np.array([0, (win or 4096) - 96], np.int32)
+        lens = q_off + s_len
+        qp = jax.random.normal(key[3], (2, s_len, hq, d),
+                               jnp.bfloat16).astype(jnp.float32)
+        got = paged_prefill_attention(
+            qp, k_pool, v_pool, tables[:2], jnp.asarray(lens),
+            jnp.asarray(q_off), interpret=interpret, sliding_window=win)
+        want = dense_causal_attention(
+            qp, k_all[:2], v_all[:2],
+            q_offset=jnp.asarray(q_off), kv_len=jnp.asarray(lens),
+            sliding_window=win)
+        out["prefill"] = err(got, want)
+    return {k: round(v, 5) for k, v in out.items()}
+
+
+def _seeded_prompts(cfg: dict, vocab: int) -> list:
+    import numpy as np
+
+    rng = np.random.default_rng(cfg["seed"])
+    return [rng.integers(1, vocab, size=n).tolist()
+            for n in cfg["parity_prompts"]]
+
+
+def child_parity(cfg: dict) -> dict:
+    device = _child_setup(cfg)
+    eng = _build_engine(cfg, cfg["parity_layers"], cfg["quant"])
+    prompts = _seeded_prompts(cfg, eng.model_cfg.vocab_size)
+    got = _engine_logits(eng, prompts, cfg["parity_decode_steps"])
+    ref = _reference_logits(
+        eng, [p + g[:-1] for p, (g, _) in zip(prompts, got)])
+    res = _compare(got, ref, prompts, cfg["parity_tol"])
+    info = eng.device_info()
+    check(info["attn_backend"] == cfg["attn_backend"], "attention backend")
+    if cfg["attn_backend"] == "pallas":
+        res["kernel_err"] = _kernel_errors(cfg, eng.model_cfg)
+        res["kernel_tol"] = cfg["kernel_tol"]
+        check(max(res["kernel_err"].values()) <= cfg["kernel_tol"],
+              f"Pallas kernel vs dense float32 attention: {res}")
+    return {"ok": True, "layers": cfg["parity_layers"],
+            "depth_cut": f"{cfg['parity_layers']} of the model's layers: "
+                         "what a float32 reference fits beside on one chip",
+            "prompt_tokens": cfg["parity_prompts"], **res,
+            "peak_bytes_in_use": info["peak_bytes_in_use"],
+            "device": device}
+
+
+def child_tp4_parity(cfg: dict) -> dict:
+    """tp=4 against tp=1 on the same seeded bf16 model (depth cut to what
+    one chip holds), both against the float32 reference; and the shards
+    really sit on four devices."""
+    import jax
+    import numpy as np
+
+    device = _child_setup(cfg)
+    check(len(jax.devices()) >= 4, f"{len(jax.devices())} devices, need 4")
+    from tpu_inference.config import ParallelConfig
+    from tpu_inference.models.registry import build_model
+    from tpu_inference.parallel.mesh import build_mesh
+    from tpu_inference.parallel.shardings import param_shardings
+
+    mesh = build_mesh(ParallelConfig(tp=4), devices=jax.devices()[:4])
+    prompts = None
+    results = {}
+    for name, m in (("tp4", mesh), ("tp1", None)):
+        params = None
+        if m is None:
+            # The SAME weights as the tp=4 engine drew, bit for bit: both
+            # through build_model's jitted init (jit and eager differ by
+            # a bf16 ulp here and there), this side onto one device.
+            one = build_mesh(ParallelConfig(tp=1), devices=jax.devices()[:1])
+            params, _ = build_model(
+                eng.model_cfg, cfg["seed"],
+                shardings=param_shardings(eng.model_cfg, one))
+            del eng
+        eng = _build_engine(cfg, cfg["tp_layers"], "none", mesh=m,
+                            params=params)
+        prompts = prompts or _seeded_prompts(cfg, eng.model_cfg.vocab_size)
+        if m is not None:
+            # Placement, read off the arrays: every weight matrix and the
+            # pool have one shard on each of the four devices, and a
+            # tp-sharded one holds a quarter of it.
+            for label, arr in (("wq", eng.params["blocks"]["wq"]),
+                               ("w_down", eng.params["blocks"]["w_down"]),
+                               ("kv pool", eng.kv.k)):
+                devs = {s.device.id for s in arr.addressable_shards}
+                check(len(devs) == 4, f"{label} sits on devices {devs}")
+                shard = arr.addressable_shards[0].data
+                check(shard.size * 4 == arr.size,
+                      f"{label} shard {shard.shape} is not a quarter of "
+                      f"{arr.shape}")
+        got = _engine_logits(eng, prompts, cfg["parity_decode_steps"])
+        ref = _reference_logits(
+            eng, [p + g[:-1] for p, (g, _) in zip(prompts, got)])
+        results[name] = (got, _compare(got, ref, prompts, cfg["parity_tol"]))
+    # tp=4 vs tp=1 directly, at the prompts' last positions (the same
+    # token stream on both sides). Two bf16 engines that differ in the
+    # order of their partial sums: bf16's own noise again, same bounds.
+    rms, peak = 0.0, 0.0
+    for (_, l4), (_, l1), p in zip(results["tp4"][0], results["tp1"][0],
+                                   prompts):
+        d = (l4[len(p) - 1] - l1[len(p) - 1]) / np.std(l1[len(p) - 1])
+        rms = max(rms, float(np.sqrt(np.mean(d * d))))
+        peak = max(peak, float(np.max(np.abs(d))))
+    tol = cfg["parity_tol"]
+    check(rms <= tol["rms"] and peak <= tol["max"],
+          f"tp=4 vs tp=1 logits: rms {rms:.4f} max {peak:.4f} of the spread")
+    return {"ok": True, "layers": cfg["tp_layers"],
+            "tp4_vs_ref": results["tp4"][1], "tp1_vs_ref": results["tp1"][1],
+            "tp4_vs_tp1": {"logit_err_rms": round(rms, 5),
+                           "logit_err_max": round(peak, 5)},
+            "device": device}
+
+
+CHILDREN = {"parity": child_parity, "tp4_parity": child_tp4_parity}
+
+
+def main() -> int:
+    if len(sys.argv) > 1 and sys.argv[1] == "_child":
+        emit(CHILDREN[sys.argv[2]](json.loads(sys.argv[3])))
+        return 0
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                    help="4 = the cross-chip phases only (tp4, dp4)")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the random weights and prompts")
+    args = ap.parse_args()
+    t0 = time.monotonic()
+    try:
+        phases = run(args.chips, args.seed, SETTINGS)
+        device = result_device(phases)
+    except SmokeFailure as e:
+        print(f"chip_smoke FAILED: {e}", file=sys.stderr)
+        return 1
+    emit({"summary": {p["phase"]: p["wall_s"] for p in phases},
+          "model": SETTINGS["model"], "seed": args.seed,
+          "wall_s": round(time.monotonic() - t0, 1), "claim": None})
+    emit({"ok": True, "device": device})
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

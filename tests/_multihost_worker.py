@@ -57,8 +57,7 @@ def main() -> None:
     sh = NamedSharding(mesh, P("dp", "tp"))
     x = jax.make_array_from_callback(
         (4, 4), sh, lambda idx: np.ones((2, 2), np.float32))
-    from tpu_inference.compat import shard_map
-    f = jax.jit(shard_map(
+    f = jax.jit(jax.shard_map(
         lambda a: jax.lax.psum(jnp.sum(a), ("dp", "tp")),
         mesh=mesh, in_specs=P("dp", "tp"), out_specs=P()))
     psum = float(f(x))

@@ -4,7 +4,10 @@ The suite is ~70% XLA:CPU compile time on a single-core box, and the
 graphs are identical run to run, so the compiled executables are cached
 on disk (keyed by HLO + compile options + jaxlib version). Shared by
 tests/conftest.py and the bare-subprocess tests/_multihost_worker.py so
-the knobs cannot drift.
+the knobs cannot drift. WHERE the cache lives is the program's own rule
+(tpu_inference/runtime.py enable_compile_cache: JAX_COMPILATION_CACHE_DIR
+when set, else <checkout>/.jax_cache); this file only adds the CPU-only
+extras the tests need on top.
 
 ``jax_persistent_cache_enable_xla_caches="all"`` is required for XLA:CPU
 executable reuse (the default scope caches nothing useful on CPU).
@@ -34,8 +37,8 @@ def enable(jax) -> None:
         return
     if not os.environ.get("TPU_INF_XLA_LOGS"):
         os.environ.setdefault("TF_CPP_MIN_LOG_LEVEL", "3")
-    jax.config.update("jax_compilation_cache_dir",
-                      os.environ.get("TPU_INF_XLA_CACHE",
-                                     "/tmp/tpu_inference_xla_cache"))
+    from tpu_inference.runtime import enable_compile_cache
+
+    enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.3)
     jax.config.update("jax_persistent_cache_enable_xla_caches", "all")

@@ -146,10 +146,79 @@ def test_ladder_from_auto_sizing_is_engine_valid():
     assert len(rungs) >= 2                # a 1B/v5e top is 16+ (above)
 
 
-def test_detect_peak_flops_has_default():
-    """CPU/unknown chips report the v5e peak so the MFU estimate always
-    renders (same stance as DEFAULT_HBM_BYTES)."""
-    assert autosize.detect_peak_flops() > 0
+class _Dev:
+    """Stand-in for a jax device (chip_spec reads three attributes)."""
+
+    def __init__(self, platform, kind, bytes_limit=None):
+        self.platform, self.device_kind = platform, kind
+        self._limit = bytes_limit
+
+    def memory_stats(self):
+        return {"bytes_limit": self._limit} if self._limit else None
+
+
+def test_chip_spec_v5e_published_peaks():
+    """ONE table, keyed by device_kind, holding the published figures:
+    the v5e's bf16 peak is 197 TFLOP/s (393 is its int8 figure)."""
+    v5e = autosize.chip_spec(_Dev("tpu", "TPU v5 lite"))
+    assert v5e.peak_bf16_flops == 197e12
+    assert v5e.peak_int8_ops == 393e12
+    assert v5e.hbm_bytes == 16e9 and v5e.hbm_bw == 819e9
+
+
+def test_chip_spec_unknown_tpu_raises():
+    """An unknown TPU kind is an error, never rated as a v5e."""
+    with pytest.raises(ValueError, match="unknown TPU device_kind"):
+        autosize.chip_spec(_Dev("tpu", "TPU v99"))
+
+
+def test_chip_spec_cpu_has_no_peak():
+    """On the CPU there is nothing to rate against: no spec (so the MFU
+    gauge and roofline shares are absent, "not measured"), and 'auto'
+    sizing refuses instead of sizing as some chip."""
+    assert autosize.chip_spec(_Dev("cpu", "cpu")) is None
+    assert autosize.chip_spec() is None          # the tests' own backend
+    with pytest.raises(ValueError, match="explicit sizes"):
+        autosize.detect_hbm_bytes(_Dev("cpu", "cpu"))
+
+
+def test_detect_hbm_prefers_the_devices_own_figure():
+    """memory_stats()["bytes_limit"] where the backend reports it, the
+    table otherwise."""
+    own = 16_909_336_576
+    assert autosize.detect_hbm_bytes(
+        _Dev("tpu", "TPU v5 lite", bytes_limit=own)) == own
+    assert autosize.detect_hbm_bytes(_Dev("tpu", "TPU v5 lite")) == 16e9
+
+
+def test_resolve_sizing_auto_and_explicit():
+    """The CLI's sizing ask resolves against an HBM figure (in the
+    process that owns the device): 'auto' fills batch, pool and the
+    ladder below the batch; explicit sizes pass through with their
+    ladder; a bad explicit ladder is a usage error up front."""
+    import types
+
+    from tpu_inference.config import PRESETS, EngineConfig
+
+    args = types.SimpleNamespace(max_batch_size="auto", num_pages="auto",
+                                 decode_ladder="auto", target_ctx=0,
+                                 batch_cap=32, draft_model=None)
+    req = autosize.sizing_request(args)
+    ecfg = autosize.resolve_sizing(
+        PRESETS["mistral-7b"](), EngineConfig(quant="int8"), req,
+        hbm_bytes=16e9)
+    assert ecfg.max_batch_size == 32 and ecfg.num_pages > 2000
+    assert ecfg.decode_ladder == (8, 16, 32)
+    args.max_batch_size, args.num_pages = 16, 300
+    ecfg = autosize.resolve_sizing(
+        PRESETS["mistral-7b"](), EngineConfig(),
+        autosize.sizing_request(args))
+    assert (ecfg.max_batch_size, ecfg.num_pages) == (16, 300)
+    assert ecfg.decode_ladder == (8, 16)
+    args.decode_ladder = "8,12"
+    with pytest.raises(ValueError, match="end at max_batch_size"):
+        autosize.sizing_request(args)
+    assert autosize.resolve_sizing(None, ecfg, None) is ecfg   # no ask
 
 
 def test_int_or_auto_argparse_type():

@@ -1,0 +1,176 @@
+"""chip_smoke.py's own control flow, at a tiny preset on the CPU.
+
+The smoke exists to run on the chip; what can break it between chip
+runs is its plumbing: the CLI flags it passes, the /healthz and /metrics
+fields it reads, the NDJSON checks, the child protocol, the exit codes.
+These tests drive the same phase functions with the settings dict
+steered to a tiny model on the CPU (``platform: "cpu"`` is the device
+assertion the phases then hold the servers to) — the script itself has
+no option for that. The children share the tests' compile cache.
+
+Also here: the guard that the subprocess fleet's router never
+initialises a JAX backend (on a TPU host that would take the chip from
+its own workers).
+"""
+
+import json
+import os
+import shutil
+import subprocess
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+import chip_smoke  # noqa: E402
+
+# tiny-mistral: 2 layers, window 64 — the long request (200 tokens)
+# crosses the window like the real one crosses 4096. Warm-up off keeps
+# the compile count to what the requests touch.
+TINY = dict(
+    chip_smoke.SETTINGS, model="tiny-mistral", platform="cpu",
+    attn_backend="dense", warmup=False,
+    sizing=["--max-batch-size", "16", "--num-pages", "256",
+            "--host-cache-pages", "0"],
+    max_pages_per_seq=16, bucket_prompts=[20, 100], long_prompt=200,
+    burst=12, burst_tokens=48, max_tokens=6, boot_timeout_s=300,
+    parity_layers=2, parity_prompts=[30, 150], seed=0)
+
+
+@pytest.fixture(autouse=True)
+def _logs_in_tmp(tmp_path, monkeypatch):
+    monkeypatch.setattr(chip_smoke, "OUT_DIR", str(tmp_path))
+
+
+def test_serve_phase_tiny():
+    rec = chip_smoke.phase_serve(TINY)
+    assert rec["ok"] and rec["device"] == {"platform": "cpu", "kind": "cpu"}
+    assert rec["rung_switches"] >= 1
+    assert rec["long_request_tokens"] == 206
+    assert (rec["max_batch_size"], rec["num_pages"]) == (16, 256)
+    assert rec["ladder"] == [8, 16]
+
+
+def test_parity_phase_tiny():
+    rec = chip_smoke.run_child(TINY, "parity")
+    assert rec["ok"] and rec["layers"] == 2
+    # prefill-written and decode-written positions of both prompts, and
+    # every greedy token, were held to the reference.
+    assert rec["checks"] == 2 * (2 + TINY["parity_decode_steps"] + 1)
+    assert rec["logit_err_rms"] <= TINY["parity_tol"]["rms"]
+    assert rec["token_gap_max"] == 0.0
+    assert rec["device"]["platform"] == "cpu"
+
+
+def test_parity_phase_catches_a_wrong_model():
+    """The comparison is not vacuous: a reference on other weights (a
+    different seed) is far outside the tolerance."""
+    import numpy as np
+
+    got = [([1, 2], {3: np.zeros(8, np.float32)})]
+    ref = np.ones((1, 5, 8), np.float32) * np.arange(8)
+    with pytest.raises(chip_smoke.SmokeFailure, match="logit error rms"):
+        chip_smoke._compare(got, ref, [[0, 0, 0, 0]],
+                            tol=chip_smoke.SETTINGS["parity_tol"])
+
+
+def test_fleet_phase_tiny():
+    rec = chip_smoke.phase_fleet(TINY)
+    assert rec["ok"] and rec["worker_chips"] == ["0"]
+    assert rec["served"] == [2]
+    assert len(rec["worker_pids"]) == 1
+
+
+def test_pallas_off_the_tpu_fails_the_phase():
+    """No quiet slow path: asked for the Pallas kernels on a machine
+    without a TPU, the server refuses to start (it does not fall to
+    interpret mode), and the phase fails with it."""
+    with pytest.raises(chip_smoke.SmokeFailure, match="needs a TPU"):
+        chip_smoke.phase_serve(dict(TINY, attn_backend="pallas"))
+
+
+def test_result_device_needs_one_device():
+    phases = [{"phase": "serve", "device": {"platform": "tpu", "kind": "a"}},
+              {"phase": "parity",
+               "device": {"platform": "tpu", "kind": "a", "count": 1}}]
+    assert chip_smoke.result_device(phases) == {
+        "platform": "tpu", "kind": "a", "count": 1}
+    phases[0]["device"]["kind"] = "b"
+    with pytest.raises(chip_smoke.SmokeFailure, match="different devices"):
+        chip_smoke.result_device(phases)
+
+
+def _run_smoke(cwd, timeout=300):
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    return subprocess.run([sys.executable, "chip_smoke.py"], cwd=cwd,
+                          env=env, capture_output=True, text=True,
+                          timeout=timeout)
+
+
+def test_without_a_tpu_the_first_child_fails_and_nothing_else_starts(
+        tmp_path):
+    """The contract's refusal: no accelerator -> non-zero exit, no result
+    line, and no phase after the first (--platform tpu pins the TPU even
+    where the environment says cpu)."""
+    work = tmp_path / "checkout"
+    work.mkdir()
+    shutil.copy(os.path.join(ROOT, "chip_smoke.py"), work)
+    os.symlink(os.path.join(ROOT, "tpu_inference"), work / "tpu_inference")
+    p = _run_smoke(work)
+    assert p.returncode != 0
+    assert p.stdout.strip() == ""           # no phase line, no result line
+    assert "serve: server exited" in p.stderr
+    logs = os.listdir(work / "chiprun_out" / "chip_smoke")
+    assert logs == ["serve.log"]            # parity and fleet never began
+
+
+def test_alone_in_a_directory_it_fails(tmp_path):
+    shutil.copy(os.path.join(ROOT, "chip_smoke.py"), tmp_path)
+    p = _run_smoke(tmp_path, timeout=60)
+    assert p.returncode != 0 and p.stdout.strip() == ""
+
+
+ROUTER_GUARD = r"""
+import json, sys, threading
+from jax._src import xla_bridge
+from tpu_inference.engine.engine import Sequence
+from tpu_inference.server.http import build_server
+
+srv = build_server(model="tiny-llama", warmup=False, dp=1, platform="cpu",
+                   server_overrides=dict(fleet="subprocess"),
+                   sizing=dict(max_batch_size=2, num_pages=32,
+                               decode_ladder="off", target_ctx=0,
+                               batch_cap=32, speculative=False),
+                   page_size=8, max_pages_per_seq=4, prefill_buckets=(16,))
+srv.group.start()
+done, toks = threading.Event(), []
+srv.group.submit(Sequence(request_id=1, prompt_tokens=[1, 2, 3],
+                          max_new_tokens=4),
+                 lambda s, t: toks.append(t), lambda s: done.set())
+assert done.wait(120), "request never finished"
+hz = srv.group.health_snapshot()
+assert "tpu_inf_build_info" in srv.group.prometheus_text()
+out = {"initialized": xla_bridge.backends_are_initialized(),
+       "tokens": len(toks), "worker": hz["replicas"][0]["device"]}
+srv.group.stop(drain=False)
+print(json.dumps(out))
+"""
+
+
+def test_fleet_router_never_initialises_a_backend():
+    """One process per chip: after building the fleet, booting a worker,
+    serving a request through it and reading its health, the ROUTER's
+    process still has no JAX backend — the worker (which does, and says
+    so in its hello) was started after the router built its envelope."""
+    env = dict(os.environ, PYTHONPATH=ROOT, JAX_PLATFORMS="cpu")
+    p = subprocess.run([sys.executable, "-c", ROUTER_GUARD], env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert p.returncode == 0, p.stderr[-3000:]
+    out = json.loads(p.stdout.strip().splitlines()[-1])
+    assert out["initialized"] is False
+    assert out["tokens"] == 4
+    assert out["worker"]["platform"] == "cpu"
+    assert out["worker"]["max_batch_size"] == 2     # the sizing ask arrived
+    assert out["worker"]["visible_chips"] == "0"    # its chip, by spawn env

@@ -46,7 +46,7 @@ def _dense_reference(q, k_pool, v_pool, bt, kv_len):
 def test_paged_attention_matches_dense(dtype):
     rng = np.random.default_rng(0)
     q, k_pool, v_pool, bt, kv_len = _random_paged_setup(rng, dtype=dtype)
-    got = paged_attention(q, k_pool, v_pool, bt, kv_len)
+    got = paged_attention(q, k_pool, v_pool, bt, kv_len, interpret=True)
     want = _dense_reference(q, k_pool, v_pool, bt, kv_len)
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(got, np.float32),
@@ -59,7 +59,7 @@ def test_paged_attention_single_token_context():
     rng = np.random.default_rng(1)
     q, k_pool, v_pool, bt, _ = _random_paged_setup(rng, b=2)
     kv_len = jnp.asarray([1, 1], jnp.int32)
-    got = paged_attention(q, k_pool, v_pool, bt, kv_len)
+    got = paged_attention(q, k_pool, v_pool, bt, kv_len, interpret=True)
     want = _dense_reference(q, k_pool, v_pool, bt, kv_len)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
@@ -69,7 +69,7 @@ def test_paged_attention_mha():
     """n_rep == 1 (no GQA grouping)."""
     rng = np.random.default_rng(2)
     q, k_pool, v_pool, bt, kv_len = _random_paged_setup(rng, hq=4, hkv=4)
-    got = paged_attention(q, k_pool, v_pool, bt, kv_len)
+    got = paged_attention(q, k_pool, v_pool, bt, kv_len, interpret=True)
     want = _dense_reference(q, k_pool, v_pool, bt, kv_len)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
@@ -88,7 +88,7 @@ def test_engine_pallas_backend_matches_dense():
     dense = InferenceEngine(model_cfg, ecfg, params=params,
                             attn_backend="dense")
     pallas = InferenceEngine(model_cfg, ecfg, params=params,
-                             attn_backend="pallas")
+                             attn_backend="pallas", pallas_interpret=True)
     got_d = dense.generate(prompts, max_new_tokens=10)
     got_p = pallas.generate(prompts, max_new_tokens=10)
     assert got_d == got_p
@@ -112,7 +112,8 @@ def test_engine_pallas_backend_mixtral_sharded_matches_dense():
     got_d = dense.generate(prompts, max_new_tokens=8)
     mesh = build_mesh(cfgs.ParallelConfig(tp=2))
     pallas = InferenceEngine(model_cfg, ecfg, params=params,
-                             attn_backend="pallas", mesh=mesh)
+                             attn_backend="pallas", mesh=mesh,
+                             pallas_interpret=True)
     got_p = pallas.generate(prompts, max_new_tokens=8)
     assert got_d == got_p
 
@@ -137,7 +138,8 @@ def test_engine_pallas_backend_sharded_matches_dense():
     got_d = dense.generate(prompts, max_new_tokens=10)
     mesh = build_mesh(cfgs.ParallelConfig(dp=2, tp=2))
     pallas = InferenceEngine(model_cfg, ecfg, params=params,
-                             attn_backend="pallas", mesh=mesh)
+                             attn_backend="pallas", mesh=mesh,
+                             pallas_interpret=True)
     got_p = pallas.generate(prompts, max_new_tokens=10)
     assert got_d == got_p
 
@@ -160,7 +162,7 @@ def test_paged_prefill_attention_matches_dense(block_q, q_offsets):
     kv_len = q_off + prompt
 
     got = paged_prefill_attention(q, k_pool, v_pool, bt, kv_len, q_off,
-                                  block_q=block_q)
+                                  block_q=block_q, interpret=True)
     kv = kvc.KVPages(k=k_pool[None], v=v_pool[None])
     k_all, v_all = kvc.gather_kv(kv, 0, bt)
     want = common.dense_causal_attention(q, k_all, v_all, q_offset=q_off,
@@ -184,7 +186,8 @@ def test_paged_prefill_non_power_of_two_bucket():
     bt = jnp.asarray(np.arange(1, 1 + mp)[None].astype(np.int32))
     kv_len = jnp.asarray([s], jnp.int32)
     got = paged_prefill_attention(q, k_pool, v_pool, bt, kv_len,
-                                  jnp.zeros((b,), jnp.int32), block_q=16)
+                                  jnp.zeros((b,), jnp.int32), block_q=16,
+                                  interpret=True)
     kv = kvc.KVPages(k=k_pool[None], v=v_pool[None])
     k_all, v_all = kvc.gather_kv(kv, 0, bt)
     want = common.dense_causal_attention(q, k_all, v_all, q_offset=0,

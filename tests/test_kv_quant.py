@@ -79,7 +79,7 @@ def test_dense_and_pallas_token_equal_kv_int8():
                             seed=0).generate(PROMPTS, max_new_tokens=10)
     pallas = InferenceEngine(
         cfg, EngineConfig(**BASE, kv_quant="int8", attn_backend="pallas"),
-        seed=0).generate(PROMPTS, max_new_tokens=10)
+        seed=0, pallas_interpret=True).generate(PROMPTS, max_new_tokens=10)
     assert dense == pallas
 
 
@@ -97,10 +97,11 @@ def test_tp_sharded_kv_int8_matches_unsharded():
     from tpu_inference.parallel.mesh import build_mesh
     cfg = tiny_llama()
     ecfg = EngineConfig(**BASE, kv_quant="int8", attn_backend="pallas")
-    base = InferenceEngine(cfg, ecfg, seed=0).generate(PROMPTS,
-                                                       max_new_tokens=10)
+    base = InferenceEngine(cfg, ecfg, seed=0, pallas_interpret=True
+                           ).generate(PROMPTS, max_new_tokens=10)
     mesh = build_mesh(ParallelConfig(tp=2))
-    tp_eng = InferenceEngine(cfg, ecfg, seed=0, mesh=mesh)
+    tp_eng = InferenceEngine(cfg, ecfg, seed=0, mesh=mesh,
+                             pallas_interpret=True)
     assert tp_eng.kv.k_scale.sharding.spec == \
         jax.sharding.PartitionSpec(None, None, None, "tp")
     assert base == tp_eng.generate(PROMPTS, max_new_tokens=10)
@@ -131,8 +132,8 @@ def test_both_quant_tiers_together():
     cfg = tiny_llama()
     ecfg = EngineConfig(**BASE, quant="int8", kv_quant="int8",
                         attn_backend="pallas")
-    out = InferenceEngine(cfg, ecfg, seed=0).generate(PROMPTS,
-                                                      max_new_tokens=8)
+    out = InferenceEngine(cfg, ecfg, seed=0, pallas_interpret=True
+                          ).generate(PROMPTS, max_new_tokens=8)
     assert all(len(t) == 8 for t in out)
     assert all(0 <= tok < cfg.vocab_size for t in out for tok in t)
 
@@ -171,7 +172,7 @@ def test_dense_and_pallas_token_equal_kv_int4():
                             seed=0).generate(PROMPTS, max_new_tokens=10)
     pallas = InferenceEngine(
         cfg, EngineConfig(**BASE, kv_quant="int4", attn_backend="pallas"),
-        seed=0).generate(PROMPTS, max_new_tokens=10)
+        seed=0, pallas_interpret=True).generate(PROMPTS, max_new_tokens=10)
     assert dense == pallas
 
 
@@ -207,10 +208,11 @@ def test_tp_sharded_kv_int4_matches_unsharded():
     from tpu_inference.parallel.mesh import build_mesh
     cfg = tiny_llama()
     ecfg = EngineConfig(**BASE, kv_quant="int4", attn_backend="pallas")
-    base = InferenceEngine(cfg, ecfg, seed=0).generate(PROMPTS,
-                                                       max_new_tokens=10)
+    base = InferenceEngine(cfg, ecfg, seed=0, pallas_interpret=True
+                           ).generate(PROMPTS, max_new_tokens=10)
     mesh = build_mesh(ParallelConfig(tp=2))
-    tp_eng = InferenceEngine(cfg, ecfg, seed=0, mesh=mesh)
+    tp_eng = InferenceEngine(cfg, ecfg, seed=0, mesh=mesh,
+                             pallas_interpret=True)
     assert tp_eng.kv.k.dtype == jnp.uint8
     assert base == tp_eng.generate(PROMPTS, max_new_tokens=10)
 

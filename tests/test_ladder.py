@@ -375,20 +375,28 @@ def test_parse_decode_ladder_validates_before_boot():
             autosize.parse_decode_ladder(bad, top)
 
 
-def test_metrics_expose_rung_occupancy_mfu(model_setup):
+@pytest.mark.parametrize("chip", [None, "TPU v5 lite"])
+def test_metrics_expose_rung_occupancy_mfu(model_setup, monkeypatch, chip):
     """/metrics surfaces the ladder telemetry the acceptance names:
-    active rung, top rung, graph-switch counter, lane occupancy, and
-    the derived MFU estimate."""
+    active rung, top rung, graph-switch counter, lane occupancy — and
+    the derived MFU estimate only where there is a chip to rate against
+    (steered here: on the tests' CPU the gauge must be ABSENT, not
+    computed against a v5e)."""
     from tpu_inference import telemetry as tm
+    from tpu_inference.engine import autosize
 
+    if chip:
+        monkeypatch.setattr(autosize, "chip_spec",
+                            lambda device=None: autosize.CHIP_SPECS[chip])
     model_cfg, params = model_setup
     engine = InferenceEngine(model_cfg, _ecfg(), params=params)
     EngineScheduler(engine)             # binds the MFU gauge
     text = tm.render_prometheus([({}, engine.telemetry.registry)])
     for name in ("tpu_inf_decode_rung", "tpu_inf_decode_ladder_top",
-                 "tpu_inf_rung_switches_total", "tpu_inf_decode_occupancy",
-                 "tpu_inf_mfu_estimate"):
+                 "tpu_inf_rung_switches_total", "tpu_inf_decode_occupancy"):
         assert f"\n{name}" in text or text.startswith(name), name
+    assert ("\ntpu_inf_mfu_estimate" in text) == bool(chip)
+    assert (engine.telemetry.mfu_estimate() is not None) == bool(chip)
     assert "tpu_inf_decode_ladder_top 16" in text
 
 

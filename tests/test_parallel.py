@@ -310,3 +310,109 @@ def test_sp_ulysses_rejects_indivisible_heads():
     mesh = build_mesh(ParallelConfig(tp=2, sp=4))
     with pytest.raises(ValueError, match="ulysses"):
         InferenceEngine(cfg, ecfg, seed=0, mesh=mesh)
+
+
+# Sharded serving paths no other test reaches (these were the old driver
+# hook's multi-chip dry run): each case serves under a mesh on the
+# production engine graphs and must produce the unsharded engine's tokens.
+_MESH_ECFG = dict(page_size=16, num_pages=32, max_pages_per_seq=8,
+                  max_batch_size=4, prefill_buckets=(32,), max_new_tokens=8)
+
+
+def _generate(cfg, ecfg, mesh=None, **kw):
+    return InferenceEngine(cfg, ecfg, seed=0, mesh=mesh, **kw).generate(
+        [[1, 2, 3, 4, 5], [7, 8, 9]], max_new_tokens=4)
+
+
+def _gemma_under_tp():
+    """Gemma dialect: a head_dim (32) decoupled from d_model/n_heads
+    (128/8 = 16) through the sharded KV pool; norm offset, GeGLU, scaled
+    embeddings and the tied unembedding ride along."""
+    import dataclasses
+    cfg = dataclasses.replace(
+        tp_llama_cfg(), name="tp-gemma", n_kv_heads=8, tie_embeddings=True,
+        norm_offset=1.0, hidden_act="gelu_tanh", embed_scale=True,
+        head_dim_override=32)
+    ecfg = EngineConfig(**_MESH_ECFG)
+    assert (_generate(cfg, ecfg, build_mesh(ParallelConfig(tp=4)))
+            == _generate(cfg, ecfg))
+
+
+def _dp_tp_mesh():
+    """A (dp=2, tp=4) mesh handed to ONE engine: weights shard over tp
+    and replicate over dp."""
+    cfg, ecfg = tp_llama_cfg(), EngineConfig(**_MESH_ECFG)
+    assert (_generate(cfg, ecfg, build_mesh(ParallelConfig(dp=2, tp=4)))
+            == _generate(cfg, ecfg))
+
+
+def _draft_speculation_under_tp():
+    """Draft-model speculation under TP: the draft's weights and its KV
+    pool shard like the target's; greedy tokens equal plain decode."""
+    import dataclasses
+    cfg = tp_llama_cfg()
+    draft = dataclasses.replace(cfg, n_layers=1, name="tp-draft")
+    spec = EngineConfig(**_MESH_ECFG, num_speculative_tokens=2)
+    assert (_generate(cfg, spec, build_mesh(ParallelConfig(tp=4)),
+                      draft_cfg=draft)
+            == _generate(cfg, EngineConfig(**_MESH_ECFG)))
+
+
+def _scheduler_over_tp_engine():
+    """The threaded continuous-batching scheduler (admission, batched
+    prefill, fused decode, streaming callbacks) — the loop the HTTP
+    server drives — over a TP-sharded engine."""
+    import threading
+
+    from tpu_inference.engine.engine import Sequence
+    from tpu_inference.engine.scheduler import EngineScheduler
+
+    cfg, ecfg = tp_llama_cfg(), EngineConfig(**_MESH_ECFG)
+    prompts = [[1 + i, 2, 3] for i in range(3)]
+    want = InferenceEngine(cfg, ecfg, seed=0).generate(prompts,
+                                                       max_new_tokens=3)
+    sched = EngineScheduler(InferenceEngine(
+        cfg, ecfg, seed=0, mesh=build_mesh(ParallelConfig(tp=4)))).start()
+    try:
+        seqs = [Sequence(request_id=i, prompt_tokens=p, max_new_tokens=3)
+                for i, p in enumerate(prompts)]
+        done = {s.request_id: threading.Event() for s in seqs}
+        toks = {s.request_id: [] for s in seqs}
+        for s in seqs:
+            sched.submit(
+                s, on_token=lambda sq, t: toks[sq.request_id].append(t),
+                on_finish=lambda sq: done[sq.request_id].set())
+        for s in seqs:
+            assert done[s.request_id].wait(300), "serving loop hung"
+    finally:
+        sched.stop(drain=False)
+    assert [toks[i] for i in range(3)] == want
+
+
+@pytest.mark.parametrize("case", [_gemma_under_tp, _dp_tp_mesh,
+                                  _draft_speculation_under_tp,
+                                  _scheduler_over_tp_engine],
+                         ids=lambda f: f.__name__.strip("_"))
+def test_sharded_serving_path_matches_unsharded(case):
+    case()
+
+
+def test_build_model_initialises_straight_into_shards():
+    """Random init under a mesh draws every leaf into its sharded layout
+    (no chip ever holds the whole model — Mistral-7B bf16 over tp=4 does
+    not fit the first chip unsharded), and the values do not depend on
+    the layout: tp=4 and a one-device mesh give the same bits."""
+    import dataclasses
+
+    cfg = dataclasses.replace(tp_llama_cfg(), dtype=jnp.bfloat16)
+    mesh4 = build_mesh(ParallelConfig(tp=4))
+    mesh1 = build_mesh(ParallelConfig(tp=1), devices=jax.devices()[:1])
+    p4, _ = build_model(cfg, seed=3, shardings=param_shardings(cfg, mesh4))
+    p1, _ = build_model(cfg, seed=3, shardings=param_shardings(cfg, mesh1))
+    wq = p4["blocks"]["wq"]
+    assert len({s.device.id for s in wq.addressable_shards}) == 4
+    assert wq.addressable_shards[0].data.size * 4 == wq.size
+    same = jax.tree.map(lambda a, b: bool((np.asarray(a, np.float32)
+                                           == np.asarray(b, np.float32)
+                                           ).all()), p4, p1)
+    assert all(jax.tree.leaves(same)), same

@@ -65,7 +65,10 @@ def test_replay_smoke_commits_phase_breakdown(tmp_path, monkeypatch):
     # the committed artifact's copy is graded in test_step_ledger.py).
     att = art["summary"]["step_attribution"]
     assert att["enabled"] and att["records"] > 0
-    assert att["verdicts"] and att["mfu"]["ledger"] is not None
+    # ...attributed but not rated: this run is on the CPU, so every
+    # verdict reads "not measured" and there is no MFU figure.
+    assert set(att["verdicts"].values()) == {"not measured"}
+    assert att["mfu"]["ledger"] is None and att["mfu"]["gauge"] is None
 
 
 def test_replay_smoke_compare_admission(tmp_path, monkeypatch):

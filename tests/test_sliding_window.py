@@ -196,7 +196,7 @@ def test_swa_pallas_engine_matches_dense_engine(swa8, swa8_dense_engine):
     want = swa8_dense_engine.generate(prompts, max_new_tokens=14)
     pallas = InferenceEngine(cfg, cfgs.EngineConfig(**SWA_KW,
                                                     attn_backend="pallas"),
-                             params=params)
+                             params=params, pallas_interpret=True)
     got = pallas.generate(prompts, max_new_tokens=14)
     assert got == want
 

@@ -128,6 +128,27 @@ def test_roofline_pinned_verdicts():
     assert empty["records_window"] == 0 and empty["kinds"] == {}
 
 
+def test_without_peaks_nothing_is_rated():
+    """No chip, no peaks: the same records still sum (counts, seconds,
+    FLOPs, bytes) but carry no roofline share and no bottleneck verdict,
+    and the ledger-replayed MFU is None — "not measured", never a
+    number against a chip that is not there."""
+    led = StepLedger(depth=8)
+    led.push("decode", rung=4, slots=4, tokens=500, chunk_tokens=0,
+             steps=1, device_s=1.0, staging_s=0.0, bubble_s=0.0,
+             kv_read_tokens=0, kv_swap_bytes=0.0, spec_accepted=0,
+             compile_event=False)
+    rep = roofline_report(led, _model(peak_flops=None, peak_hbm_bw=None))
+    agg = rep["kinds"]["decode"]
+    assert agg["verdict"] == telemetry.NOT_MEASURED
+    assert "compute_frac" not in agg and "hbm_frac" not in agg
+    assert agg["achieved_flops_per_s"] == pytest.approx(1e6)
+    assert rep["peaks"] == {"flops_per_s": None, "hbm_bytes_per_s": None}
+    assert rep["mfu"]["ledger"] is None
+    merged = telemetry.merge_steps_reports([rep, rep])
+    assert merged["kinds"]["decode"]["verdict"] == telemetry.NOT_MEASURED
+
+
 def test_kv_read_attention_flops_counted():
     """Attention FLOPs scale with (query, context) pairs attended —
     the term that makes long-context decode drift toward hbm-bound."""
@@ -388,16 +409,22 @@ def test_fleet_steps_and_blackbox_over_http(tmp_path):
             assert rep["kinds"], "no step kinds attributed"
             for kind, agg in rep["kinds"].items():
                 assert kind in telemetry.STEP_KINDS
-                assert agg["verdict"] in ("compute-bound", "hbm-bound",
-                                          "host-bound")
-            # Cross-check: ledger-replayed MFU vs the live gauge.
-            mfu = rep["mfu"]
-            assert mfu["gauge"] and mfu["ledger"] is not None
-            assert 0.8 <= mfu["agreement"] <= 1.2, mfu
+                # The workers run on the CPU: times and counts are
+                # attributed, but there is no chip to rate them against
+                # — no shares, no verdict, no MFU (never a v5e's).
+                assert agg["verdict"] == telemetry.NOT_MEASURED
+                assert "compute_frac" not in agg and "hbm_frac" not in agg
+                assert agg["device_s"] > 0 and agg["flops"] > 0
+            assert rep["peaks"] == {"flops_per_s": None,
+                                    "hbm_bytes_per_s": None}
+            assert rep["mfu"] == {"gauge": None, "ledger": None,
+                                  "agreement": None}
         fleet = snap["fleet"]
         assert fleet["enabled"] and fleet["replicas_merged"] == 2
         assert fleet["records_window"] > 0 and fleet["rung_occupancy"]
-        assert 0.8 <= fleet["mfu"]["agreement"] <= 1.2, fleet["mfu"]
+        assert {k["verdict"] for k in fleet["kinds"].values()} == {
+            telemetry.NOT_MEASURED}
+        assert fleet["mfu"]["agreement"] is None
 
         # kill -9 one worker: its blackbox directory survives the kill
         # (periodic heartbeat at minimum) and the index lists it.

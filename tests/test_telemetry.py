@@ -151,40 +151,9 @@ def test_log_event_level_gating(capsys, monkeypatch):
 def test_disabled_telemetry_is_noop(monkeypatch):
     tel = EngineTelemetry(enabled=False)
     tel.decode_dispatch_s.observe(0.1)     # all no-ops, no registry
-    tel.degraded_mode.set(1)
+    tel.prefill_dispatches.inc()
     tel.request_finished("stop")
     assert tel.phase_snapshot() == {}
     assert tel.registry.collect() == []
     monkeypatch.setenv("TPU_INF_TELEMETRY", "0")
     assert not telemetry.telemetry_enabled()
-
-
-def test_int4_pallas_degraded_gate(monkeypatch, capsys):
-    """kv_quant=int4 + pallas on (simulated) real TPU without an int4
-    Mosaic validation artifact: boot warns through the structured logger
-    and pins tpu_inf_degraded_mode=1; the operator override clears it."""
-    import jax
-
-    import tpu_inference.engine.engine as eng_mod
-    from tpu_inference.config import EngineConfig, tiny_llama
-
-    monkeypatch.delenv("TPU_INF_INT4_VALIDATED", raising=False)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    kw = dict(page_size=8, num_pages=32, max_pages_per_seq=4,
-              max_batch_size=2, prefill_buckets=(16,), kv_quant="int4",
-              attn_backend="pallas")
-    eng = eng_mod.InferenceEngine(tiny_llama(512), EngineConfig(**kw))
-    assert eng.telemetry.degraded_mode.value == 1
-    err = capsys.readouterr().err
-    rec = json.loads([l for l in err.splitlines()
-                      if "degraded_mode" in l][0])
-    assert rec["level"] == "warning" and rec["kv_quant"] == "int4"
-    # The same config on CPU (no real chip) must NOT flag.
-    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
-    eng = eng_mod.InferenceEngine(tiny_llama(512), EngineConfig(**kw))
-    assert eng.telemetry.degraded_mode.value == 0
-    # Operator override: validated out-of-repo.
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setenv("TPU_INF_INT4_VALIDATED", "1")
-    eng = eng_mod.InferenceEngine(tiny_llama(512), EngineConfig(**kw))
-    assert eng.telemetry.degraded_mode.value == 0

@@ -182,11 +182,12 @@ def test_slo_tracker_breaches_and_pooling():
 
 def test_emit_build_info_stable_series():
     r = telemetry.Registry()
-    telemetry.emit_build_info(r, backend="cpu", fleet="subprocess",
+    device = {"platform": "cpu", "kind": "cpu", "attn_backend": "dense"}
+    telemetry.emit_build_info(r, device=device, fleet="subprocess",
                               kv_quant="int8", spec_mode="ngram",
                               routing="prefix_affinity")
     # Re-emitting (a worker restart) replaces in place: one series.
-    telemetry.emit_build_info(r, backend="cpu", fleet="subprocess",
+    telemetry.emit_build_info(r, device=device, fleet="subprocess",
                               kv_quant="int8", spec_mode="ngram",
                               routing="prefix_affinity")
     text = telemetry.render_prometheus([({"replica": "0"}, r)])
@@ -199,6 +200,8 @@ def test_emit_build_info_stable_series():
     from tpu_inference import __version__
     assert labels["version"] == __version__
     assert labels["kv_quant"] == "int8" and labels["fleet"] == "subprocess"
+    assert (labels["backend"], labels["device_kind"],
+            labels["attn_backend"]) == ("cpu", "cpu", "dense")
     assert meta["tpu_inf_build_info"]["type"] == "gauge"
 
 

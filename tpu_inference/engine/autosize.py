@@ -35,39 +35,51 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional
 
-# Per-chip HBM capacities (bytes). The canonical table — bench.py's
-# fits-on-chip gate imports it via detect_hbm_bytes().
-HBM_BY_DEVICE_KIND = {
-    "TPU v5 lite": 16e9,
-    "TPU v4": 32e9,
-    "TPU v5p": 95e9,
-    "TPU v6 lite": 32e9,
-}
-DEFAULT_HBM_BYTES = 16e9  # unknown chip / CPU smoke runs: size as a v5e
 
-# Per-chip bf16 peak FLOP/s (the MFU denominator; bench.py keeps its own
-# copy paired with HBM bandwidth for the roofline extras). Unknown chips
-# / CPU report against a v5e so the /metrics MFU estimate always renders
-# — on CPU it is a sizing exercise, like DEFAULT_HBM_BYTES.
-PEAK_FLOPS_BY_DEVICE_KIND = {
-    "TPU v5 lite": 394e12,
-    "TPU v4": 275e12,
-    "TPU v5p": 459e12,
-    "TPU v6 lite": 918e12,
-}
-DEFAULT_PEAK_FLOPS = 394e12
+@dataclasses.dataclass(frozen=True)
+class ChipSpec:
+    """Published per-chip figures: HBM bytes, dense peak rates, HBM
+    bandwidth (bytes/s)."""
+    hbm_bytes: float
+    peak_bf16_flops: float
+    peak_int8_ops: float
+    hbm_bw: float
 
-# Per-chip HBM bandwidth (bytes/s) — the bytes-roofline denominator the
-# step-ledger bottleneck verdicts (telemetry.roofline_report) divide by.
-# Same unknown-chip stance as the peak-FLOPs table: CPU reports against
-# a v5e so the attribution math always renders.
-PEAK_HBM_BW_BY_DEVICE_KIND = {
-    "TPU v5 lite": 819e9,
-    "TPU v4": 1228e9,
-    "TPU v5p": 2765e9,
-    "TPU v6 lite": 1640e9,
+
+# THE device table, keyed by jax's ``device_kind``. Source: Google Cloud
+# TPU documentation, "System architecture" page of each generation
+# ("TPU v5e": 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM, 819 GB/s;
+# "TPU v4": 275 TFLOP/s, 32 GiB, 1228 GB/s; "TPU v5p": 459 TFLOP/s bf16,
+# 918 TOP/s int8, 95 GB, 2765 GB/s; "TPU v6e": 918 TFLOP/s bf16, 1836
+# TOP/s int8, 32 GB, 1640 GB/s). A TPU that is not in it is an error
+# (chip_spec), never a default; the CPU has no entry on purpose.
+CHIP_SPECS = {
+    "TPU v5 lite": ChipSpec(16e9, 197e12, 393e12, 819e9),
+    "TPU v4": ChipSpec(32e9, 275e12, 275e12, 1228e9),
+    "TPU v5p": ChipSpec(95e9, 459e12, 918e12, 2765e9),
+    "TPU v6 lite": ChipSpec(32e9, 918e12, 1836e12, 1640e9),
 }
-DEFAULT_PEAK_HBM_BW = 819e9
+
+
+def chip_spec(device=None) -> Optional[ChipSpec]:
+    """Published figures of ``device`` (default: the first visible one).
+    None on the CPU — there is no chip to rate against, so MFU and
+    roofline shares are "not measured" there, not computed against
+    somebody else's peak. An unknown TPU kind raises."""
+    if device is None:
+        import jax
+
+        device = jax.devices()[0]
+    if device.platform != "tpu":
+        return None
+    try:
+        return CHIP_SPECS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"unknown TPU device_kind {device.device_kind!r}: add its "
+            "published HBM size, peak rates and bandwidth to "
+            "engine/autosize.py CHIP_SPECS (known: "
+            f"{', '.join(sorted(CHIP_SPECS))})") from None
 
 
 def estimate_param_count(model_cfg) -> int:
@@ -136,8 +148,8 @@ class AutoSizing:
     target_ctx: int
 
 
-def auto_size(model_cfg, *, hbm_bytes: Optional[float] = None,
-              quant: str = "none", kv_quant: str = "none", tp: int = 1,
+def auto_size(model_cfg, *, hbm_bytes: float, quant: str = "none",
+              kv_quant: str = "none", tp: int = 1,
               page_size: int = 16, max_pages_per_seq: int = 64,
               target_ctx: Optional[int] = None, batch_cap: int = 32,
               reserve_frac: float = 0.15,
@@ -149,7 +161,7 @@ def auto_size(model_cfg, *, hbm_bytes: Optional[float] = None,
     (the caller should quantize, raise tp, or pick a bigger chip) or
     when the KV budget can't hold even one full-length sequence.
     """
-    hbm = float(hbm_bytes if hbm_bytes is not None else DEFAULT_HBM_BYTES)
+    hbm = float(hbm_bytes)
     wb = weight_bytes(model_cfg, quant)
     per_chip_w = wb // tp
     usable = (1.0 - reserve_frac) * hbm
@@ -238,32 +250,23 @@ def auto_host_cache_pages(model_cfg, *, kv_quant: str = "none",
     return budget // max(per_page, 1)
 
 
-def detect_hbm_bytes() -> float:
-    """Per-chip HBM of the visible device (table lookup; CPU and unknown
-    chips size as a 16 GB v5e so smoke runs exercise the same math)."""
-    import jax
+def detect_hbm_bytes(device=None) -> float:
+    """Per-chip HBM that ``auto`` sizing budgets against: what the
+    device itself reports (``memory_stats()["bytes_limit"]``) where the
+    backend does, else the table. Without a known chip (the CPU) there
+    is nothing to size against and this raises — pass explicit sizes."""
+    if device is None:
+        import jax
 
-    return HBM_BY_DEVICE_KIND.get(jax.devices()[0].device_kind,
-                                  DEFAULT_HBM_BYTES)
-
-
-def detect_peak_flops() -> float:
-    """Per-chip bf16 peak FLOP/s of the visible device — the denominator
-    of the /metrics MFU estimate (CPU and unknown chips report against a
-    v5e, same stance as detect_hbm_bytes)."""
-    import jax
-
-    return PEAK_FLOPS_BY_DEVICE_KIND.get(jax.devices()[0].device_kind,
-                                         DEFAULT_PEAK_FLOPS)
-
-
-def detect_peak_hbm_bw() -> float:
-    """Per-chip HBM bandwidth (bytes/s) of the visible device — the
-    bytes-roofline denominator for step-ledger bottleneck verdicts."""
-    import jax
-
-    return PEAK_HBM_BW_BY_DEVICE_KIND.get(jax.devices()[0].device_kind,
-                                          DEFAULT_PEAK_HBM_BW)
+        device = jax.devices()[0]
+    spec = chip_spec(device)
+    if spec is None:
+        raise ValueError(
+            f"--max-batch-size/--num-pages auto size from the chip's HBM "
+            f"and this process runs on {device.platform!r}: pass "
+            "explicit sizes")
+    limit = (device.memory_stats() or {}).get("bytes_limit")
+    return float(limit or spec.hbm_bytes)
 
 
 def decode_ladder_rungs(top: int, base: int = 8) -> tuple:
@@ -418,32 +421,69 @@ def int_or_auto(v: str):
             f"expected an integer or 'auto', got {v!r}")
 
 
+def sizing_request(args) -> dict:
+    """The CLI's sizing ask (batch, pool, ladder and the two knobs of
+    'auto'), before any device is known. Explicit sizes get their ladder
+    validated on the spot — a usage error before any model loads; with
+    'auto' in either size that waits for ``resolve_sizing`` in the
+    process that owns the device. JSON-able: the subprocess fleet ships
+    it to its workers."""
+    req = {"max_batch_size": args.max_batch_size,
+           "num_pages": args.num_pages,
+           "decode_ladder": getattr(args, "decode_ladder", "off"),
+           "target_ctx": getattr(args, "target_ctx", 0),
+           "batch_cap": getattr(args, "batch_cap", 32),
+           "speculative": bool(getattr(args, "draft_model", None))}
+    if "auto" not in (req["max_batch_size"], req["num_pages"]):
+        parse_decode_ladder(req["decode_ladder"], req["max_batch_size"])
+    return req
+
+
+def resolve_sizing(model_cfg, engine_cfg, req: Optional[dict], *,
+                   tp: int = 1, hbm_bytes: Optional[float] = None):
+    """EngineConfig with ``req``'s batch, pool and ladder filled in.
+    'auto' sizes come from ``hbm_bytes`` (default: the visible device's
+    own figure — so this runs in the process that owns the chip)."""
+    if req is None:
+        return engine_cfg
+    mbs, pages = req["max_batch_size"], req["num_pages"]
+    if "auto" in (mbs, pages):
+        sz = auto_size(
+            model_cfg,
+            hbm_bytes=detect_hbm_bytes() if hbm_bytes is None else hbm_bytes,
+            quant=engine_cfg.quant, kv_quant=engine_cfg.kv_quant, tp=tp,
+            page_size=engine_cfg.page_size,
+            max_pages_per_seq=engine_cfg.max_pages_per_seq,
+            target_ctx=req["target_ctx"] or None,
+            batch_cap=req["batch_cap"], speculative=req["speculative"])
+        mbs = sz.max_batch_size if mbs == "auto" else mbs
+        pages = sz.num_pages if pages == "auto" else pages
+        import sys
+
+        print(f"[autosize] {model_cfg.name}: batch={mbs} num_pages={pages} "
+              f"(hbm {sz.hbm_bytes / 1e9:.2f} GB, weights/chip "
+              f"{sz.weight_bytes_per_chip / 1e9:.2f} GB, kv pool/chip "
+              f"{sz.kv_pool_bytes_per_chip / 1e9:.2f} GB, target ctx "
+              f"{sz.target_ctx})", file=sys.stderr)
+    return dataclasses.replace(
+        engine_cfg, max_batch_size=mbs, num_pages=pages,
+        decode_ladder=parse_decode_ladder(req["decode_ladder"], mbs))
+
+
 def resolve_sizing_args(args) -> tuple:
-    """Shared CLI hook: turn 'auto' in ``args.max_batch_size`` /
-    ``args.num_pages`` into chip-derived values (no-op when both are
-    ints). Reads model/checkpoint/quant/kv_quant/tp/page_size/
-    max_pages_per_seq and the optional target_ctx/batch_cap attrs.
-    Returns (max_batch_size, num_pages)."""
+    """In-process CLI hook (benchmarks): (max_batch_size, num_pages)
+    with 'auto' resolved against this process's device; a no-op on
+    ints. Reads model/checkpoint/quant/kv_quant/tp/page_size/
+    max_pages_per_seq and the optional target_ctx/batch_cap attrs."""
     mbs, pages = args.max_batch_size, args.num_pages
     if "auto" not in (mbs, pages):
         return mbs, pages
-    mcfg = resolve_model_config(args.model, args.checkpoint)
-    sz = auto_size(
-        mcfg, hbm_bytes=detect_hbm_bytes(), quant=args.quant,
-        kv_quant=args.kv_quant, tp=args.tp, page_size=args.page_size,
-        max_pages_per_seq=args.max_pages_per_seq,
-        target_ctx=getattr(args, "target_ctx", 0) or None,
-        batch_cap=getattr(args, "batch_cap", 32),
-        speculative=bool(getattr(args, "draft_model", None)))
-    if mbs == "auto":
-        mbs = sz.max_batch_size
-    if pages == "auto":
-        pages = sz.num_pages
-    import sys
+    from tpu_inference.config import EngineConfig
 
-    print(f"[autosize] {mcfg.name}: batch={mbs} num_pages={pages} "
-          f"(hbm {sz.hbm_bytes / 1e9:.0f} GB, weights/chip "
-          f"{sz.weight_bytes_per_chip / 1e9:.2f} GB, kv pool/chip "
-          f"{sz.kv_pool_bytes_per_chip / 1e9:.2f} GB, target ctx "
-          f"{sz.target_ctx})", file=sys.stderr)
-    return mbs, pages
+    ecfg = resolve_sizing(
+        resolve_model_config(args.model, args.checkpoint),
+        EngineConfig(quant=args.quant, kv_quant=args.kv_quant,
+                     page_size=args.page_size,
+                     max_pages_per_seq=args.max_pages_per_seq),
+        dict(sizing_request(args), decode_ladder="off"), tp=args.tp)
+    return ecfg.max_batch_size, ecfg.num_pages

@@ -577,7 +577,11 @@ class EngineScheduler:
                 try:
                     self.engine.prefetch_host_hits(head.seq)
                 except Exception as exc:  # noqa: BLE001 — keep loop alive
+                    # A failed host->device restore is a step failure
+                    # like any other: logged AND fed to the replica's
+                    # health machine, not skipped over.
                     self._log_step_error("host_prefetch", exc, [head.seq])
+                    self._note_error(exc)
         if start_adopt is not None:
             seq = start_adopt.seq
             t_adopt = time.perf_counter()

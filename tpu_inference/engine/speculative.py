@@ -255,7 +255,6 @@ def verify_round(engine, params, kv, tokens, ctx_lens, block_tables, cap,
     Returns VerifyRoundOut; with n_prop==0 a round degenerates to one
     plain decode step (one forward, one emitted token).
     """
-    from tpu_inference.engine.engine import make_paged_attn
     from tpu_inference.engine.sampling import (apply_repeat_penalty,
                                                roll_window)
 
@@ -275,11 +274,9 @@ def verify_round(engine, params, kv, tokens, ctx_lens, block_tables, cap,
     ar = jnp.arange(s_len, dtype=jnp.int32)[None, :]
     positions = jnp.minimum(ctx_lens[:, None] + ar, ecfg.max_context - 1)
     valid = active[:, None] & (positions < cap[:, None])
-    attn = make_paged_attn(engine.model_cfg, ecfg.page_size, block_tables,
-                           positions, valid, q_offset=ctx_lens,
-                           kv_len=ctx_lens + s_len,
-                           attn_backend=engine.attn_backend,
-                           mesh=engine.mesh)
+    attn = engine._paged_attn(engine.model_cfg, block_tables, positions,
+                              valid, q_offset=ctx_lens,
+                              kv_len=ctx_lens + s_len)
     hidden, kv = engine.mod.forward_hidden(params, engine.model_cfg,
                                            tokens_in, positions, kv, attn)
     logits_all = engine.mod.unembed(params, engine.model_cfg, hidden)

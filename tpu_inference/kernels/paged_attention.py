@@ -126,7 +126,7 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                     block_tables: jax.Array, kv_len: jax.Array,
                     k_scale: jax.Array | None = None,
                     v_scale: jax.Array | None = None,
-                    interpret: bool | None = None,
+                    interpret: bool = False,
                     sliding_window: int = 0) -> jax.Array:
     """Decode attention over the paged KV pool.
 
@@ -144,10 +144,11 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     axis shrinks to the window's page span and the index maps offset
     into the block table from the window's first page, so decode cost
     is O(window), not O(context).
+    interpret: run in Pallas interpret mode (tests on the CPU pass True).
+    The default compiles through Mosaic and so needs a TPU — the backend
+    is never consulted to pick a slower mode quietly.
     Returns [B, Hq, D] in q.dtype.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     quantized = k_scale is not None
     # uint8 pool = nibble-packed int4 codes (engine/kv_cache.py); the
     # pool's trailing dim is D/2 bytes and the kernel unpacks in VMEM.

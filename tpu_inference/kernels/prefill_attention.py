@@ -131,7 +131,7 @@ def paged_prefill_attention(q: jax.Array, k_pages: jax.Array,
                             k_scale: jax.Array | None = None,
                             v_scale: jax.Array | None = None,
                             block_q: int = 128,
-                            interpret: bool | None = None,
+                            interpret: bool = False,
                             sliding_window: int = 0) -> jax.Array:
     """Prefill attention over the paged KV pool.
 
@@ -144,10 +144,10 @@ def paged_prefill_attention(q: jax.Array, k_pages: jax.Array,
     k/v_scale:    [P, page_size, Hkv] f32 when the pool is quantized —
                   int8 codes or uint8 nibble-packed int4 (trailing dim
                   D/2); dequant happens in VMEM per page.
+    interpret:    Pallas interpret mode (tests on the CPU pass True); the
+                  default compiles through Mosaic and needs a TPU.
     Returns [B, S, Hq, D] in q.dtype.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     quantized = k_scale is not None
     # uint8 pool = nibble-packed int4 codes (engine/kv_cache.py); the
     # pool's trailing dim is D/2 bytes and the kernel unpacks in VMEM.
@@ -223,6 +223,13 @@ def paged_prefill_attention(q: jax.Array, k_pages: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, n_qb, hkv, bq * n_rep, d),
                                        q.dtype),
+        # The query block, its f32 accumulators and the score tile of
+        # all hq*bq = 4096 rows live in VMEM at once: ~17 MiB at 32
+        # heads x 128, just over the 16 MiB a kernel gets by default
+        # (the v5e compiler refused the 4x512 prefill graph for 1.4
+        # MiB). The chip has 128 MiB; say what the kernel may take.
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=48 * 1024 * 1024),
         interpret=interpret,
     )(block_tables, kv_len, q_offset, *operands)
     return (out.reshape(b, n_qb, hkv, bq, n_rep, d)

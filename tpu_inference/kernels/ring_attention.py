@@ -31,8 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from tpu_inference import compat
-from tpu_inference.compat import shard_map
 
 NEG_INF = -1e30
 
@@ -73,7 +71,7 @@ def ring_attention_local(q: jax.Array, k: jax.Array, v: jax.Array,
     mask (each query sees itself + the window-1 tokens before it); fully
     behind-window chunks skip their einsums just like fully-future ones.
     Returns [B, S_loc, Hq, D] in q.dtype."""
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, s_loc, hq, d = q.shape
     hkv = k.shape[2]
@@ -101,12 +99,11 @@ def ring_attention_local(q: jax.Array, k: jax.Array, v: jax.Array,
 
         def skip(ops):
             # Mark the constants as device-varying so both cond branches
-            # agree under shard_map's varying-axis typing (compat.pvary:
-            # pcast on current jax, pvary on older, no-op on 0.4.x).
+            # agree under shard_map's varying-axis typing.
             vals = (jnp.full((b, hq, s_loc), NEG_INF, jnp.float32),
                     jnp.zeros((b, hq, s_loc), jnp.float32),
                     jnp.zeros((b, s_loc, hq, d), jnp.float32))
-            return compat.pvary(vals, (axis_name,))
+            return jax.lax.pcast(vals, (axis_name,), to="varying")
 
         # Chunks entirely in the causal future contribute nothing; skip
         # their einsums (the ring still rotates them — wall-clock per step
@@ -143,10 +140,10 @@ def seq_sharded_call(body, q, k, v, mesh: Mesh, axis_name: str,
     replicated), run the per-shard ``body`` under shard_map, return with
     the same sequence sharding. Used by ring and ulysses."""
     spec = P(None, axis_name, None, None)
-    fn = shard_map(functools.partial(body, axis_name=axis_name,
-                                     sliding_window=sliding_window),
-                   mesh=mesh, in_specs=(spec, spec, spec),
-                   out_specs=spec)
+    fn = jax.shard_map(functools.partial(body, axis_name=axis_name,
+                                         sliding_window=sliding_window),
+                       mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec)
     sh = NamedSharding(mesh, spec)
     return fn(jax.device_put(q, sh), jax.device_put(k, sh),
               jax.device_put(v, sh))

@@ -43,7 +43,6 @@ import functools
 import jax
 from jax.sharding import Mesh
 
-from tpu_inference import compat
 
 def ulysses_attention_local(q: jax.Array, k: jax.Array, v: jax.Array,
                             axis_name: str = "sp",
@@ -64,7 +63,7 @@ def ulysses_attention_local(q: jax.Array, k: jax.Array, v: jax.Array,
     collective)."""
     from tpu_inference.models.common import dense_causal_attention
 
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     hq, hkv = q.shape[2], k.shape[2]
     if n == 1:
         return dense_causal_attention(q, k, v, sliding_window=sliding_window)

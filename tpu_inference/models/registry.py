@@ -16,8 +16,20 @@ def get_model_fns(cfg: ModelConfig) -> types.ModuleType:
     return {"llama": llama, "mixtral": mixtral, "gpt2": gpt2}[cfg.family]
 
 
-def build_model(cfg: ModelConfig, seed: int = 0) -> Tuple[dict, types.ModuleType]:
-    """Random-init params + family module."""
+def build_model(cfg: ModelConfig, seed: int = 0,
+                shardings=None) -> Tuple[dict, types.ModuleType]:
+    """Random-init params + family module.
+
+    With ``shardings`` (a NamedSharding tree, parallel/shardings.py
+    param_shardings) every leaf is generated straight into its sharded
+    layout: each chip draws and keeps only its own shard, f32
+    intermediates included — a model that one chip cannot hold (Mistral-7B
+    bf16 over tp=4: the unsharded init needs 7 GB for one f32 leaf) never
+    exists in one place. Same values either way: the threefry PRNG is
+    partitionable, so a leaf's bits do not depend on how it is sharded."""
     mod = get_model_fns(cfg)
-    params = mod.init_params(cfg, jax.random.PRNGKey(seed))
-    return params, mod
+    key = jax.random.PRNGKey(seed)
+    if shardings is None:
+        return mod.init_params(cfg, key), mod
+    init = jax.jit(lambda k: mod.init_params(cfg, k), out_shardings=shardings)
+    return init(key), mod

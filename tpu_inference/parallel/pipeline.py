@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from tpu_inference.compat import shard_map
 from tpu_inference.config import ModelConfig
 from tpu_inference.models import llama
 from tpu_inference.models.common import make_dense_attn, rms_norm
@@ -120,8 +119,8 @@ def pp_forward(params: dict, cfg: ModelConfig, tokens: jax.Array,
         # activation-sized as the module docstring promises).
         return jax.lax.psum(out, "pp").reshape(b, seq, cfg.d_model)
 
-    fn = shard_map(stage_fn, mesh=mesh,
-                   in_specs=(stage_specs(params), P(), P()),
-                   out_specs=P(), check_vma=False)
+    fn = jax.shard_map(stage_fn, mesh=mesh,
+                       in_specs=(stage_specs(params), P(), P()),
+                       out_specs=P(), check_vma=False)
     hidden = fn(params, tokens, positions)
     return llama.unembed(params, cfg, hidden)

@@ -56,6 +56,7 @@ from tpu_inference.config import (FrameworkConfig, class_rank,
 from tpu_inference.engine import kv_cache as kvc
 from tpu_inference.engine.engine import Sequence
 from tpu_inference.engine.prefix_cache import _chain_hashes
+from tpu_inference.runtime import chip_env
 from tpu_inference.server import kv_fabric, shm_arena
 from tpu_inference.server.replicas import (FleetSaturated, FleetUnavailable,
                                            _RETRYABLE, _clone_request,
@@ -372,15 +373,29 @@ class _EngineInfo:
         self.prefix_cache = True if hello.get("prefix_cache") else None
         self.spec_draft = hello.get("spec_draft", False)
         self.host_pool = None
+        self._device = hello.get("device") or {}
+
+    def device_info(self) -> dict:
+        """Worker 0's InferenceEngine.device_info() as of its hello."""
+        return dict(self._device)
 
 
 class ProcessEngineGroup:
     """Router + N engine-worker processes behind the EngineGroup facade
     (``ServerConfig.fleet = "subprocess"``)."""
 
-    def __init__(self, cfg: FrameworkConfig):
+    def __init__(self, cfg: FrameworkConfig,
+                 platform: Optional[str] = None,
+                 sizing: Optional[dict] = None):
+        """``platform`` (the CLI's --platform) and ``sizing`` (an
+        autosize.sizing_request) ride each worker's envelope untouched:
+        they are settled where the device is, and this process — the
+        router — never initialises a JAX backend, so the chips stay
+        free for the workers."""
         pcfg = cfg.parallel
         self.cfg = cfg
+        self._worker_platform = platform
+        self._worker_sizing = sizing
         self.server_cfg = cfg.server
         self.engine_cfg = cfg.engine
         self.dp = max(1, pcfg.dp)
@@ -699,14 +714,6 @@ class ProcessEngineGroup:
             class_preempted=lambda c: self.class_preemptions.get(c, 0),
             class_deferred=lambda c: len(self._deferred.get(c) or ()),
             class_shed=lambda c: self.class_shed.get(c, 0))
-        import jax
-        telemetry.emit_build_info(
-            r, backend=jax.default_backend(), fleet="subprocess",
-            kv_quant=self.engine_cfg.kv_quant,
-            spec_mode=(self.engine_cfg.spec_mode
-                       if self.engine_cfg.num_speculative_tokens > 0
-                       else "off"),
-            routing=self.server_cfg.routing)
         for h in self.workers:
             self._register_worker_gauges(h)
 
@@ -809,13 +816,10 @@ class ProcessEngineGroup:
     # ----------------------------------------------------------- spawn
 
     def _envelope(self, replica: int) -> dict:
-        import jax
-
-        pcfg = self.cfg.parallel
         env = {
             "config": framework_config_to_dict(self.cfg),
-            "platform": jax.default_backend(),
-            "cpu_devices": max(1, pcfg.tp * pcfg.sp),
+            "platform": self._worker_platform,
+            "sizing": self._worker_sizing,
             "warmup": self.cfg.server.warmup,
             # Per-worker phase role: the one envelope field that differs
             # between replicas (README "P/D disaggregation").
@@ -859,6 +863,9 @@ class ProcessEngineGroup:
         root = os.path.dirname(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))))
         env["PYTHONPATH"] = root + os.pathsep + env.get("PYTHONPATH", "")
+        # One worker per chip group: replica i sees chips i*n .. only.
+        n_chips = self.cfg.parallel.tp * self.cfg.parallel.sp
+        env.update(chip_env(h.replica * n_chips, n_chips))
         proc = subprocess.Popen(
             [sys.executable, "-m", "tpu_inference.server.worker",
              "--socket", h.socket_path, "--replica", str(h.replica)],
@@ -900,6 +907,15 @@ class ProcessEngineGroup:
         self.warmup_total_s += hello.get("warmup_s", 0.0)
         if self.engine is None:
             self.engine = _EngineInfo(hello)
+            # The fleet-level join gauge waits for the first hello: its
+            # device labels are facts only the workers have.
+            telemetry.emit_build_info(
+                self._fleet_registry, device=self.engine.device_info(),
+                fleet="subprocess", kv_quant=self.engine_cfg.kv_quant,
+                spec_mode=(self.engine_cfg.spec_mode
+                           if self.engine_cfg.num_speculative_tokens > 0
+                           else "off"),
+                routing=self.server_cfg.routing)
         telemetry.log_event(
             "worker_up", level="info", replica=h.replica,
             pid=h.pid, incarnation=h.incarnation)
@@ -2946,8 +2962,8 @@ class ProcessEngineGroup:
                 "incarnation": h.incarnation,
                 "routing": dict(self._route_stats[h.replica]),
             }
-            for k in ("pool_pressure", "under_pressure", "preemptions",
-                      "load", "draining", "host_cache",
+            for k in ("device", "pool_pressure", "under_pressure",
+                      "preemptions", "load", "draining", "host_cache",
                       "swap_in_resumes", "prefill_backlog",
                       "ladder_occupancy", "pd_handoffs", "pd_adoptions",
                       "pd_adopt_fallbacks", "slo",

@@ -58,7 +58,9 @@ def _now_iso() -> str:
 
 
 def build_engine_group(cfg: FrameworkConfig, load_params=None,
-                       draft_cfg=None, load_draft=None) -> "EngineGroup":
+                       draft_cfg=None, load_draft=None,
+                       platform: Optional[str] = None,
+                       sizing: Optional[dict] = None) -> "EngineGroup":
     """Construct the dp replica fleet for a FrameworkConfig.
 
     ``cfg.server.fleet`` picks the backend (README "Process fleet"):
@@ -70,11 +72,13 @@ def build_engine_group(cfg: FrameworkConfig, load_params=None,
     ``load_draft`` are callables (mesh | None) -> params so checkpoints
     stream into each replica's own device layout (in-process only —
     workers load their own checkpoints from cfg.checkpoint_path).
-    """
-    import jax
 
-    from tpu_inference.config import ParallelConfig
-    from tpu_inference.parallel.mesh import build_mesh
+    ``platform`` (the CLI's --platform) and ``sizing``
+    (autosize.sizing_request) are settled by whichever process owns the
+    devices: here for the in-process fleet, in each worker for the
+    subprocess fleet — whose router (this process, then) must not touch
+    a backend at all.
+    """
     from tpu_inference.server.replicas import EngineGroup
 
     if cfg.server.fleet == "subprocess":
@@ -84,7 +88,7 @@ def build_engine_group(cfg: FrameworkConfig, load_params=None,
                 "speculative decoding yet (the worker boots its own "
                 "params; use spec_mode='ngram' or the in-process fleet)")
         from tpu_inference.server.fleet import ProcessEngineGroup
-        return ProcessEngineGroup(cfg)
+        return ProcessEngineGroup(cfg, platform=platform, sizing=sizing)
     if cfg.server.fleet != "in-process":
         raise ValueError(f"unknown fleet backend {cfg.server.fleet!r}; "
                          "one of ('in-process', 'subprocess')")
@@ -95,7 +99,17 @@ def build_engine_group(cfg: FrameworkConfig, load_params=None,
             "--fleet subprocess: the live KV handoff moves pages "
             "between worker PROCESSES (README 'P/D disaggregation'); "
             "the in-process fleet serves every replica mixed")
+    import jax
+
+    from tpu_inference.config import ParallelConfig
+    from tpu_inference.engine.autosize import resolve_sizing
+    from tpu_inference.parallel.mesh import build_mesh
+    from tpu_inference.runtime import require_backend
+
+    if platform is not None:
+        require_backend(platform)
     pcfg = cfg.parallel
+    cfg.engine = resolve_sizing(cfg.model, cfg.engine, sizing, tp=pcfg.tp)
     if pcfg.dp <= 1:
         meshes = [build_mesh(pcfg) if pcfg.n_devices > 1 else None]
     else:
@@ -207,6 +221,13 @@ class InferenceServer:
               else "off")
         cap = scfg.admission_queue_depth or "off"
         host_pages = self.cfg.engine.host_cache_pages
+        if self.engine is not None:
+            dev = self.engine.device_info()
+            print(f"device: platform={dev['platform']} "
+                  f"kind={dev['kind']!r} ids={dev['ids']} "
+                  f"attn_backend={dev['attn_backend']} "
+                  f"max_batch_size={dev['max_batch_size']} "
+                  f"num_pages={dev['num_pages']}")
         ladder = self.engine.ladder if self.engine is not None else (1,)
         if len(ladder) > 1:
             print(f"batch ladder: rungs={list(ladder)} "
@@ -1059,6 +1080,8 @@ def build_server(model: str = "tiny-llama", tokenizer: str = "byte",
                  draft_checkpoint: Optional[str] = None,
                  enable_debug: bool = False,
                  server_overrides: Optional[dict] = None,
+                 platform: Optional[str] = None,
+                 sizing: Optional[dict] = None,
                  **engine_overrides) -> InferenceServer:
     """Convenience constructor used by CLI, tests, and benchmarks.
 
@@ -1067,7 +1090,8 @@ def build_server(model: str = "tiny-llama", tokenizer: str = "byte",
     "auto" with ``checkpoint`` set. ``tokenizer="auto"`` uses the
     checkpoint directory's tokenizer files when present, else bytes.
     ``server_overrides`` are extra ServerConfig fields (supervision
-    knobs: step_watchdog_s, admission_queue_depth, ...).
+    knobs: step_watchdog_s, admission_queue_depth, ...). ``platform``
+    and ``sizing``: see build_engine_group.
     """
     import os
 
@@ -1129,6 +1153,7 @@ def build_server(model: str = "tiny-llama", tokenizer: str = "byte",
         load_params=_loader(model_cfg, checkpoint) if checkpoint else None,
         draft_cfg=draft_cfg,
         load_draft=(_loader(draft_cfg, draft_checkpoint)
-                    if draft_checkpoint else None))
+                    if draft_checkpoint else None),
+        platform=platform, sizing=sizing)
     load_ns = int((time.perf_counter() - t0) * 1e9)
     return InferenceServer(cfg, group=group, load_duration_ns=load_ns)

@@ -337,9 +337,8 @@ class EngineGroup:
         # Dashboard-join info gauge, on the fleet registry AND every
         # replica registry (label values are pure config: identical
         # across replicas and restarts).
-        import jax
         ecfg = self.engines[0].engine_cfg
-        kw = dict(backend=jax.default_backend(),
+        kw = dict(device=self.engines[0].device_info(),
                   fleet=self.server_cfg.fleet,
                   kv_quant=ecfg.kv_quant,
                   spec_mode=(self.engines[0].spec_mode
@@ -877,6 +876,7 @@ class EngineGroup:
             d = h.snapshot()
             # KV-pool pressure view: operators (and load balancers) see
             # which replicas are burning headroom before they quarantine.
+            d["device"] = e.device_info()
             d["pool_pressure"] = round(e.pool_pressure, 4)
             d["under_pressure"] = e.under_pressure
             d["preemptions"] = e.preemptions_total

@@ -1097,21 +1097,26 @@ def capture_jax_profile(profile_dir: str, replica: int,
             "replica": int(replica)}
 
 
-def emit_build_info(registry: Registry, *, backend: str = "",
+def emit_build_info(registry: Registry, *, device: Dict[str, Any],
                     fleet: str = "", kv_quant: str = "",
                     spec_mode: str = "", routing: str = "") -> None:
     """The ``tpu_inf_build_info`` info-gauge (constant 1; the labels
     are the payload) every registry emits so dashboards can join series
-    across replicas and restarts. Label VALUES are pure config — a
-    worker restart re-mints the identical series, so the restart carry
-    never sees a label change."""
+    across replicas and restarts. ``device`` is an engine's
+    ``device_info()``: the backend, device kind and attention backend
+    the engine REALLY runs on, so a scrape says which chip its numbers
+    came from. Label VALUES are config plus hardware — a worker restart
+    re-mints the identical series, so the restart carry never sees a
+    label change."""
     from tpu_inference import __version__
     registry.gauge(
         "tpu_inf_build_info",
         "Build/config info gauge (constant 1; the labels carry the "
         "version and serving configuration for dashboard joins)",
         fn=lambda: 1.0,
-        version=__version__, backend=backend or "unknown",
+        version=__version__, backend=device.get("platform") or "unknown",
+        device_kind=device.get("kind") or "unknown",
+        attn_backend=device.get("attn_backend") or "unknown",
         fleet=fleet or "none", kv_quant=kv_quant or "none",
         spec_mode=spec_mode or "off", routing=routing or "none")
 
@@ -1227,8 +1232,11 @@ NULL_LEDGER = _NullLedger()
 
 class StepCostModel:
     """Analytic per-record FLOPs + HBM bytes from the architecture
-    config — no device counters needed, so the same model grades CPU
-    smoke runs and real-TPU campaigns.
+    config — no device counters needed. The peaks they are rated
+    against are the chip's published ones (engine/autosize.py
+    CHIP_SPECS); on the CPU both are None and every share, verdict and
+    MFU figure below reads "not measured" instead of being computed
+    against a chip that is not there.
 
     - matmul FLOPs: 2 x params per token position processed (generated
       tokens + prompt chunk tokens).
@@ -1247,27 +1255,29 @@ class StepCostModel:
 
     def __init__(self, *, n_params: int, n_layers: int, n_heads: int,
                  head_dim: int, weight_bytes: int, kv_token_bytes: int,
-                 peak_flops: float, peak_hbm_bw: float):
+                 peak_flops: Optional[float],
+                 peak_hbm_bw: Optional[float]):
         self.n_params = int(n_params)
         self.n_layers = int(n_layers)
         self.n_heads = int(n_heads)
         self.head_dim = int(head_dim)
         self.weight_bytes = int(weight_bytes)
         self.kv_token_bytes = int(kv_token_bytes)
-        self.peak_flops = float(peak_flops)
-        self.peak_hbm_bw = float(peak_hbm_bw)
+        self.peak_flops = peak_flops
+        self.peak_hbm_bw = peak_hbm_bw
 
     @classmethod
     def from_engine(cls, engine) -> "StepCostModel":
         from tpu_inference.engine import autosize
         mcfg, ecfg = engine.model_cfg, engine.engine_cfg
+        chip = autosize.chip_spec()
         return cls(n_params=engine.n_params, n_layers=mcfg.n_layers,
                    n_heads=mcfg.n_heads, head_dim=mcfg.head_dim,
                    weight_bytes=autosize.weight_bytes(mcfg, ecfg.quant),
                    kv_token_bytes=autosize.kv_bytes_per_token(
                        mcfg, ecfg.kv_quant),
-                   peak_flops=autosize.detect_peak_flops(),
-                   peak_hbm_bw=autosize.detect_peak_hbm_bw())
+                   peak_flops=chip and chip.peak_bf16_flops,
+                   peak_hbm_bw=chip and chip.hbm_bw)
 
     def flops(self, rec: tuple) -> float:
         positions = rec[4] + rec[5]          # tokens + chunk_tokens
@@ -1285,11 +1295,16 @@ class StepCostModel:
         return {k: getattr(self, k) for k in self.__slots__}
 
 
-def _finalize_kind(agg: Dict[str, Any], peak_flops: float,
-                   peak_hbm_bw: float) -> Dict[str, Any]:
+NOT_MEASURED = "not measured"
+
+
+def _finalize_kind(agg: Dict[str, Any], peak_flops: Optional[float],
+                   peak_hbm_bw: Optional[float]) -> Dict[str, Any]:
     """Derive achieved rates, roofline fractions, and the bottleneck
     verdict from one kind's raw sums — shared by the per-replica report
-    and the fleet merge so the two can never disagree on semantics."""
+    and the fleet merge so the two can never disagree on semantics.
+    Without peaks (no chip) the fractions are absent and the verdict is
+    NOT_MEASURED."""
     device_s = agg["device_s"]
     host_s = agg["staging_s"] + agg["bubble_s"]
     out = dict(agg)
@@ -1300,18 +1315,21 @@ def _finalize_kind(agg: Dict[str, Any], peak_flops: float,
     else:
         out["achieved_flops_per_s"] = 0.0
         out["achieved_bytes_per_s"] = 0.0
-    compute_frac = out["achieved_flops_per_s"] / max(peak_flops, 1.0)
-    hbm_frac = out["achieved_bytes_per_s"] / max(peak_hbm_bw, 1.0)
     host_frac = host_s / max(host_s + device_s, 1e-12)
-    out["compute_frac"] = round(compute_frac, 6)
-    out["hbm_frac"] = round(hbm_frac, 6)
     out["host_frac"] = round(host_frac, 6)
-    if host_frac > 0.5:
-        out["verdict"] = "host-bound"
-    elif compute_frac >= hbm_frac:
-        out["verdict"] = "compute-bound"
+    if not (peak_flops and peak_hbm_bw):
+        out["verdict"] = NOT_MEASURED
     else:
-        out["verdict"] = "hbm-bound"
+        compute_frac = out["achieved_flops_per_s"] / peak_flops
+        hbm_frac = out["achieved_bytes_per_s"] / peak_hbm_bw
+        out["compute_frac"] = round(compute_frac, 6)
+        out["hbm_frac"] = round(hbm_frac, 6)
+        if host_frac > 0.5:
+            out["verdict"] = "host-bound"
+        elif compute_frac >= hbm_frac:
+            out["verdict"] = "compute-bound"
+        else:
+            out["verdict"] = "hbm-bound"
     for k in ("device_s", "staging_s", "bubble_s", "flops", "hbm_bytes",
               "kv_swap_bytes"):
         out[k] = round(out[k], 6)
@@ -1319,7 +1337,8 @@ def _finalize_kind(agg: Dict[str, Any], peak_flops: float,
 
 
 def _ledger_mfu_ewma(recs: Sequence[tuple], n_params: int,
-                     peak_flops: float, bind_unix: Optional[float],
+                     peak_flops: Optional[float],
+                     bind_unix: Optional[float],
                      now: float, tau_s: float = 30.0) -> Optional[float]:
     """Replay the MFU gauge's dt-weighted EWMA (telemetry bind_scheduler:
     alpha = 1 - exp(-dt/tau), tau ≈ 30 s) over the ledger's (ts, tokens)
@@ -1330,7 +1349,7 @@ def _ledger_mfu_ewma(recs: Sequence[tuple], n_params: int,
     caller via ``truncated``)."""
     import math
 
-    if not recs:
+    if not recs or not peak_flops:
         return None
     rate = 0.0
     t = bind_unix if bind_unix is not None else recs[0][0]
@@ -1343,7 +1362,7 @@ def _ledger_mfu_ewma(recs: Sequence[tuple], n_params: int,
     dt = now - t
     if dt > 1e-3:
         rate *= math.exp(-dt / tau_s)   # zero-rate tail, gauge-identical
-    return rate * 2.0 * n_params / max(peak_flops, 1.0)
+    return rate * 2.0 * n_params / peak_flops
 
 
 def roofline_report(ledger, model: StepCostModel, *,
@@ -1443,8 +1462,8 @@ def merge_steps_reports(reports: Sequence[Optional[Dict[str, Any]]]
     if not reports:
         return {"enabled": False}
     peaks = reports[0].get("peaks") or {}
-    peak_flops = peaks.get("flops_per_s") or 1.0
-    peak_bw = peaks.get("hbm_bytes_per_s") or 1.0
+    peak_flops = peaks.get("flops_per_s")
+    peak_bw = peaks.get("hbm_bytes_per_s")
     kinds: Dict[str, Dict[str, Any]] = {}
     rungs: Dict[str, Dict[str, float]] = {}
     for rep in reports:
@@ -1812,7 +1831,6 @@ class EngineTelemetry:
             self.decode_dispatches = NULL_METRIC
             self.prefill_dispatches = NULL_METRIC
             self.hybrid_steps = NULL_METRIC
-            self.degraded_mode = NULL_METRIC
             self.spec_gamma_g = NULL_METRIC
             self.kv_offload_pages = NULL_METRIC
             self.kv_restore_pages = NULL_METRIC
@@ -1899,10 +1917,6 @@ class EngineTelemetry:
         self.hybrid_steps = r.counter(
             "tpu_inf_hybrid_steps_total",
             "Hybrid prefill+decode fused dispatches issued")
-        self.degraded_mode = r.gauge(
-            "tpu_inf_degraded_mode",
-            "1 when serving in a known-degraded configuration (e.g. "
-            "unvalidated int4 Pallas path on real TPU)")
         if engine is not None:
             self.bind_engine(engine)
 
@@ -2082,8 +2096,9 @@ class EngineTelemetry:
         r.gauge("tpu_inf_queue_depth", "Requests waiting for admission",
                 fn=lambda: len(sched._waiting))
         # Derived MFU estimate: decoded-token rate x ~2 FLOPs/param/
-        # token over the chip's bf16 peak (engine/autosize.py table;
-        # CPU reports against a v5e, like the rest of the sizing math).
+        # token over the chip's bf16 peak (engine/autosize.py
+        # CHIP_SPECS). Without a chip there is no peak and the gauge is
+        # not registered at all — absent, not computed against a v5e.
         # The rate is a dt-weighted EWMA (~30 s time constant) updated by
         # WHOEVER collects — /metrics scrapes, stats snapshots, and
         # fleet merges all read the same smoothed value, so a fast
@@ -2095,7 +2110,10 @@ class EngineTelemetry:
         from tpu_inference.engine import autosize as _autosize
 
         engine = sched.engine
-        peak = _autosize.detect_peak_flops()
+        chip = _autosize.chip_spec()
+        if chip is None:
+            return
+        peak = chip.peak_bf16_flops
         tau_s = 30.0
         state = {"tokens": stats.tokens_generated,
                  "t": time.perf_counter(), "rate": 0.0}
@@ -2123,11 +2141,12 @@ class EngineTelemetry:
 
     def mfu_estimate(self) -> Optional[float]:
         """Latest scrape-window MFU estimate (None when telemetry is
-        off or no scheduler is bound)."""
+        off, no scheduler is bound, or there is no chip to rate
+        against)."""
         g = getattr(self, "_mfu_gauge", None)
-        # 12 decimals, not 6: a toy CPU model against a real chip's
-        # peak sits at MFU ~1e-9, and the /debug/steps agreement
-        # cross-check needs the ratio, not a rounded-to-zero pair.
+        # 12 decimals, not 6: a small model on a big chip sits at MFU
+        # ~1e-9, and the /debug/steps agreement cross-check needs the
+        # ratio, not a rounded-to-zero pair.
         return round(g.collect_value(), 12) if g is not None else None
 
     def steps_report(self, window_s: float = 60.0) -> Dict[str, Any]:
