@@ -187,7 +187,7 @@ def main() -> int:
             data = jax.ShapeDtypeStruct(
                 (kv.k.shape[0], kvc.SWAP_CHUNK) + kv.k.shape[2:],
                 kv.k.dtype, sharding=kv.k.sharding)
-            return kvc._scatter_pool.lower(kv.k, idx, data)
+            return kvc._restore_jit.lower(kv.k, idx, data)
         raise SystemExit(f"unknown graph {graph!r}")
 
     failed = 0

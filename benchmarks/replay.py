@@ -56,9 +56,9 @@ def scrape_metrics(port: int, fmt: str = None) -> tuple:
 def step_attribution(port: int) -> dict:
     """GET /debug/steps compressed into the artifact's attribution
     block (README "Performance attribution"): the fleet-merged
-    bottleneck verdict per step kind, the per-rung occupancy histogram,
-    the top-3 time sinks, and the MFU cross-check — so every committed
-    row explains WHY it ran at the throughput it did."""
+    bottleneck verdict per step kind, the per-rung occupancy histogram
+    and the MFU gauge — so every committed row explains WHY it ran at
+    the throughput it did."""
     url = f"http://127.0.0.1:{port}/debug/steps"
     with urllib.request.urlopen(url, timeout=60) as r:
         snap = json.loads(r.read().decode())
@@ -71,7 +71,6 @@ def step_attribution(port: int) -> dict:
         "verdicts": {k: v.get("verdict")
                      for k, v in (fleet.get("kinds") or {}).items()},
         "rung_occupancy": fleet.get("rung_occupancy") or {},
-        "top_sinks": fleet.get("top_sinks") or [],
         "compile_events": fleet.get("compile_events"),
         "mfu": fleet.get("mfu") or {},
         "replica_verdicts": {

@@ -1,0 +1,220 @@
+"""The engine loop's phase clock (telemetry.LoopClock): phases are an
+exclusive, complete partition of the loop's wall; starved time accrues
+only with nothing in flight and work pending; a long visit is one stall
+and one ``loop_stall`` event; with telemetry off it is the null object."""
+
+import json
+import threading
+import time
+
+import pytest
+
+from tpu_inference import telemetry
+from tpu_inference.telemetry import (LOOP_FAMILIES, LOOP_PHASES, LoopClock,
+                                     NULL_CLOCK, Registry)
+
+
+class FakeTime:
+    def __init__(self):
+        self.t = 1000.0
+
+    def __call__(self):
+        return self.t
+
+    def tick(self, dt):
+        self.t += dt
+
+
+def _clock():
+    ft = FakeTime()
+    return LoopClock(now=ft), ft
+
+
+def _stall_events(capsys):
+    return [json.loads(line) for line in capsys.readouterr().err.splitlines()
+            if '"loop_stall"' in line]
+
+
+def test_phases_partition_the_wall_exactly():
+    clock, ft = _clock()
+    t_start = clock.start()
+    visits = [("admit", 0.004), ("stage", 0.011), ("enqueue", 0.002),
+              ("device_wait", 0.310), ("deliver", 0.003), ("reap", 0.0005),
+              ("idle", 0.1), ("stage", 0.009), ("heartbeat", 0.0002),
+              ("swap", 0.02), ("prefix_lookup", 0.001), ("other", 0.0)]
+    for phase, dt in visits:
+        assert clock.enter(phase) == ft.t      # enter returns the instant
+        ft.tick(dt)
+    clock.stop()
+    wall = ft.t - t_start
+    assert sum(clock.seconds.values()) == pytest.approx(wall, rel=1e-9)
+    assert clock.total_s() == pytest.approx(wall, rel=1e-9)
+    assert clock.seconds["stage"] == pytest.approx(0.020)
+    assert clock.seconds["device_wait"] == pytest.approx(0.310)
+    # Exported: one family per phase, and their sum, equal to 1e-6.
+    reg = Registry()
+    clock.register(reg)
+    values = {m.name: m.collect_value() for m in reg.collect()}
+    assert set(LOOP_FAMILIES.values()) <= set(values)
+    assert len(LOOP_FAMILIES) == len(LOOP_PHASES) == 11
+    total = values["tpu_inf_loop_seconds_total"]
+    assert sum(values[f] for f in LOOP_FAMILIES.values()) == pytest.approx(
+        total, rel=1e-6)
+    assert total == pytest.approx(wall, rel=1e-6)
+
+
+def test_nothing_accrues_outside_start_and_stop():
+    """An engine driven directly (no scheduler loop) still gets instants
+    from enter(), but no visit is left open to grow into a false stall."""
+    clock, ft = _clock()
+    assert clock.enter("stage") == ft.t
+    ft.tick(50.0)
+    assert clock.enter("other") == ft.t
+    assert clock.total_s() == 0.0 and clock.stalls == 0
+    clock.start()
+    ft.tick(0.5)
+    clock.stop()
+    ft.tick(100.0)                       # between two runs of the loop
+    clock.start()
+    ft.tick(0.25)
+    clock.stop()
+    assert clock.total_s() == pytest.approx(0.75)
+    assert clock.stalls == 0
+
+
+def test_starved_needs_empty_queue_and_pending_work():
+    clock, ft = _clock()
+    clock.start()
+
+    def visit(phase, dt):
+        clock.enter(phase)
+        ft.tick(dt)
+
+    # No work known to the scheduler: host time is not starvation.
+    clock.has_work = False
+    visit("stage", 0.010)
+    clock.enter("other")
+    assert clock.starved_s == 0.0
+    # Work pending, nothing in flight: stage and enqueue starve the
+    # device ...
+    clock.has_work = True
+    visit("stage", 0.010)
+    visit("enqueue", 0.002)
+    clock.dispatched(1)                       # ... until this returns
+    assert clock.starved_s == pytest.approx(0.012)
+    assert clock.in_flight
+    # In flight: host phases overlap the device, waiting on it is not
+    # the host's doing either.
+    ft.tick(0.001)
+    visit("stage", 0.010)
+    visit("device_wait", 0.300)
+    clock.enter("other")
+    assert clock.starved_s == pytest.approx(0.012)
+    # Result observed, nothing else enqueued: deliver/reap starve again.
+    clock.observed(1)
+    assert not clock.in_flight
+    visit("deliver", 0.004)
+    visit("idle", 0.100)                      # idle never starves
+    visit("device_wait", 0.050)               # nor waiting on the device
+    clock.enter("other")
+    assert clock.starved_s == pytest.approx(0.016)
+    # An older program observed later does not un-observe a newer one.
+    clock.dispatched(2)
+    clock.observed(2)
+    clock.observed(1)
+    assert clock.observed_seq == 2 and not clock.in_flight
+    clock.stop()
+    assert clock.starved_s <= clock.total_s()
+
+
+def test_long_visit_is_one_stall_and_one_event(capsys):
+    clock, ft = _clock()
+    clock.start()
+    clock.active, clock.waiting = 3, 2
+    clock.dispatched(41)
+    clock.enter("idle")
+    ft.tick(30.0)                             # a long idle is no stall
+    clock.enter("reap")
+    ft.tick(0.9)                              # under the limit
+    clock.enter("heartbeat")
+    ft.tick(6.5)                              # the pause names itself
+    clock.enter("other")
+    clock.stop()
+    assert clock.stalls == 1
+    assert clock.stall_s == pytest.approx(6.5)
+    events = _stall_events(capsys)
+    assert len(events) == 1
+    ev = events[0]
+    assert ev["phase"] == "heartbeat" and ev["seconds"] == pytest.approx(6.5)
+    assert ev["dispatch"] == 41 and ev["active"] == 3 and ev["waiting"] == 2
+    reg = Registry()
+    clock.register(reg)
+    values = {m.name: m.collect_value() for m in reg.collect()}
+    assert values["tpu_inf_loop_stalls_total"] == 1
+    assert values["tpu_inf_loop_stall_seconds_total"] == pytest.approx(6.5)
+
+
+def test_null_clock_when_telemetry_is_off(monkeypatch):
+    monkeypatch.setenv("TPU_INF_TELEMETRY", "0")
+    tel = telemetry.EngineTelemetry()
+    assert tel.clock is NULL_CLOCK
+    assert not [m for m in tel.registry.collect()
+                if m.name.startswith("tpu_inf_loop_")]
+    clock = tel.clock
+    t0 = clock.start()
+    clock.has_work = True                      # settable, kept nowhere
+    assert clock.has_work is False
+    t1 = clock.enter("stage")
+    t2 = clock.dispatched(7)
+    clock.observed(7)
+    clock.stop()
+    # It still tells the time: callers use it in place of a clock read.
+    now = time.perf_counter()
+    assert t0 <= t1 <= t2 <= now and now - t0 < 5.0
+    assert clock.phase is None and not clock.in_flight
+
+
+def test_scheduler_loop_is_fully_partitioned(monkeypatch):
+    """A real scheduler over a tiny engine: the exported phase families
+    sum to the exported total, the loop's wall is covered, the engine's
+    sites were visited, and dispatches were numbered and observed."""
+    from tpu_inference.config import EngineConfig, tiny_llama
+    from tpu_inference.engine.engine import InferenceEngine, Sequence
+    from tpu_inference.engine.scheduler import EngineScheduler
+
+    engine = InferenceEngine(tiny_llama(), EngineConfig(
+        max_batch_size=4, num_pages=64, page_size=8, max_pages_per_seq=8,
+        prefill_buckets=(16, 32), decode_steps_per_call=4))
+    clock = engine.telemetry.clock
+    sched = EngineScheduler(engine)
+    done = threading.Event()
+    left = [3]
+
+    def on_finish(seq):
+        left[0] -= 1
+        if left[0] == 0:
+            done.set()
+
+    t0 = time.perf_counter()
+    sched.start()
+    for i in range(3):
+        sched.submit(Sequence(request_id=i, prompt_tokens=[5, 6, 7, 8 + i],
+                              max_new_tokens=12),
+                     lambda s, t: None, on_finish)
+    assert done.wait(120)
+    sched.stop()
+    wall = time.perf_counter() - t0
+    assert clock.phase is None                 # stopped with the loop
+    values = {m.name: m.collect_value()
+              for m in engine.telemetry.registry.collect()
+              if m.kind == "counter"}
+    total = values["tpu_inf_loop_seconds_total"]
+    assert sum(values[f] for f in LOOP_FAMILIES.values()) == pytest.approx(
+        total, rel=1e-6)
+    assert 0.5 * wall < total <= wall + 1e-3
+    for phase in ("admit", "stage", "enqueue", "device_wait", "deliver",
+                  "reap", "idle"):
+        assert clock.seconds[phase] > 0, phase
+    assert clock.dispatched_seq >= 4           # 1+ prefill, 3+ decode calls
+    assert clock.observed_seq == clock.dispatched_seq
+    assert 0 <= clock.starved_s <= total

@@ -572,10 +572,6 @@ def test_request_id_propagation_and_span_accounting(server):
         phase_sum = t["queue_wait_s"] + t["prefill_s"] + t["decode_s"]
         assert abs(phase_sum - t["e2e_s"]) < 1e-3
         assert t["ttft_s"] >= t["queue_wait_s"]
-        assert t["dispatch_wall_s"] > 0
-        assert t["bubble_s"] >= 0
-        # Dispatch exposure can't exceed the request's wall clock.
-        assert t["dispatch_wall_s"] <= t["e2e_s"] + 1e-3
 
         # Streaming + no client id: the server mints one and echoes it.
         resp = await client.post("/api/generate", json={

@@ -5,6 +5,9 @@ the boot-time int4 degraded-mode gate."""
 
 import json
 import math
+import os
+import threading
+import time
 
 import pytest
 
@@ -157,3 +160,118 @@ def test_disabled_telemetry_is_noop(monkeypatch):
     assert tel.registry.collect() == []
     monkeypatch.setenv("TPU_INF_TELEMETRY", "0")
     assert not telemetry.telemetry_enabled()
+
+
+# --------------------------------- heartbeat off the engine thread
+
+
+def _slow_recorder(tmp_path, gate, monkeypatch):
+    """A FlightRecorder whose file write blocks on ``gate`` and records
+    the thread it ran on."""
+    from tpu_inference.telemetry import STEP_FIELDS, FlightRecorder
+
+    rec = tuple([123.0, "decode"] + [0] * (len(STEP_FIELDS) - 2))
+    fr = FlightRecorder(str(tmp_path), replica=0, steps_fn=lambda: [rec],
+                        stats_fn=lambda: {"thread":
+                                          threading.current_thread().name},
+                        periodic_interval_s=0.5)
+    writers = []
+    real_write = fr._write
+
+    def slow_write(path, payload):
+        writers.append(threading.current_thread().name)
+        assert gate.wait(10)
+        real_write(path, payload)
+
+    monkeypatch.setattr(fr, "_write", slow_write)
+    return fr, writers
+
+
+def test_heartbeat_writes_off_thread_and_skips_when_pending(tmp_path,
+                                                            monkeypatch):
+    gate = threading.Event()
+    fr, writers = _slow_recorder(tmp_path, gate, monkeypatch)
+    me = threading.current_thread().name
+    assert fr.periodic_due()
+    t0 = time.perf_counter()
+    assert fr.maybe_periodic()
+    assert time.perf_counter() - t0 < 1.0, "the caller never waits the write"
+    assert not fr.periodic_due() and not fr.maybe_periodic()
+    time.sleep(0.6)                      # next beat due, the first still out
+    assert fr.maybe_periodic()
+    assert (fr.beats, fr.beats_skipped) == (2, 1)
+    periodic = os.path.join(fr.dir, "periodic.json")
+    assert not os.path.exists(periodic)
+    gate.set()
+    assert fr.join_beat(10.0)
+    assert writers == ["blackbox-heartbeat"] and me not in writers
+    payload = json.loads(open(periodic).read())
+    assert payload["trigger"] == "periodic"
+    # The ring copy crossed as raw tuples; dicts were built by the writer,
+    # which also took the stats.
+    assert payload["steps"][0]["kind"] == "decode"
+    assert payload["steps"][0]["ts"] == 123.0
+    assert payload["stats"] == {"thread": "blackbox-heartbeat"}
+    time.sleep(0.6)
+    assert fr.maybe_periodic() and fr.join_beat(10.0)
+    assert (fr.beats, fr.beats_skipped) == (3, 1)
+
+
+def test_capture_atexit_still_writes_synchronously(tmp_path, monkeypatch):
+    gate = threading.Event()
+    gate.set()
+    fr, writers = _slow_recorder(tmp_path, gate, monkeypatch)
+    path = fr.capture("atexit", min_interval_s=0.0)
+    assert path and os.path.exists(path), "written before capture returned"
+    assert writers == [threading.current_thread().name]
+    payload = json.loads(open(path).read())
+    assert payload["trigger"] == "atexit"
+    assert payload["steps"][0]["kind"] == "decode"
+    assert payload["stats"] == {"thread": threading.current_thread().name}
+
+
+def test_heartbeat_counters_and_boot_gauges_on_the_registry(tmp_path):
+    tel = EngineTelemetry(enabled=True)
+
+    def value(name):
+        (m,) = [m for m in tel.registry.collect() if m.name == name]
+        return m.collect_value()
+
+    assert value("tpu_inf_loop_heartbeats_total") == 0   # no recorder yet
+    fr = telemetry.attach_flight_recorder(tel, str(tmp_path), 0)
+    fr.periodic_interval_s = 0.0
+    assert fr.maybe_periodic() and fr.join_beat(10.0)
+    assert value("tpu_inf_loop_heartbeats_total") == 1
+    assert value("tpu_inf_heartbeats_skipped_total") == 0
+    tel.boot_weights_s.set(9.5)
+    tel.boot_ready_s.set(31.0)
+    assert value("tpu_inf_boot_weights_seconds") == 9.5
+    assert value("tpu_inf_boot_ready_seconds") == 31.0
+    assert 0.0 < telemetry.process_age_s() < 24 * 3600
+
+
+def test_compile_monitor_counts_requests_and_cache_hits():
+    import jax
+    import jax.numpy as jnp
+
+    mon = telemetry.install_compile_monitor()
+    assert telemetry.install_compile_monitor() is mon     # once a process
+    x3, x5 = jnp.ones((3,)), jnp.ones((5,))   # their own programs: first
+    before = mon.snapshot()
+    fn = jax.jit(telemetry.named_program(
+        "tpu_inf_test_program", lambda x: x * 3 + before[0]))
+    fn(x3)
+    compiles, seconds, hits = mon.snapshot()
+    assert compiles == before[0] + 1 and seconds > before[1]
+    fn(x3)                               # same shape: no compile request
+    assert mon.snapshot()[0] == compiles
+    fn(x5)                               # a new shape is a new request
+    assert mon.snapshot()[0] == compiles + 1
+    assert hits <= compiles              # a hit is one kind of request
+    text = telemetry.render_prometheus([])
+    assert f"tpu_inf_xla_compiles_total {compiles + 1}" in text
+    assert "tpu_inf_xla_cache_hits_total" in text
+    names = {r["name"] for r in telemetry.process_counters_dump()}
+    assert names == {"tpu_inf_xla_compiles_total",
+                     "tpu_inf_xla_compile_seconds_total",
+                     "tpu_inf_xla_cache_hits_total"}

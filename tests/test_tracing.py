@@ -368,3 +368,162 @@ def test_group_health_and_stats_carry_slo(group):
     assert len(with_replica) == 4 and len(fleet_rows) == 2   # 2q x 2rep
     binfo = [l for n, l, v in samples if n == "tpu_inf_build_info"]
     assert len(binfo) == 3                                   # 2rep+fleet
+
+
+# ------------------------------- the program's spans in the profiler
+
+
+def _profile_events(trace_dir):
+    """{event name: [its stats dicts]} of the host planes of the one
+    .xplane.pb under ``trace_dir``."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(str(trace_dir) + "/**/*.xplane.pb", recursive=True)
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("tpu_inf/"):
+                    events.setdefault(ev.name, []).append(
+                        {k: v for k, v in ev.stats})
+    return events
+
+
+def test_profile_holds_phase_and_dispatch_annotations(tmp_path):
+    """A CPU profile taken through capture_jax_profile carries the loop
+    clock's phases and one tpu_inf/dispatch per device dispatch (with its
+    number, the ledger's ``seq``); outside a capture nothing is emitted;
+    and no step program is anonymous."""
+    from tpu_inference.engine.engine import InferenceEngine, Sequence
+    from tpu_inference.engine.scheduler import EngineScheduler
+
+    engine = InferenceEngine(tiny_llama(512), EngineConfig(**ENGINE_KW),
+                             seed=0)
+    sched = EngineScheduler(engine)
+    sched.start()
+
+    def one(rid):
+        done = threading.Event()
+        sched.submit(Sequence(request_id=rid, prompt_tokens=[1, 2, 3, rid],
+                              max_new_tokens=24),
+                     lambda s, t: None, lambda s: done.set())
+        assert done.wait(120)
+
+    try:
+        one(1)                             # compiles, before any capture
+        assert not telemetry.profile_capturing()
+        assert engine.telemetry.clock._ann is None
+        seq_before = engine._dispatch_seq
+        feeder = threading.Thread(target=lambda: [one(r) for r in (2, 3, 4)])
+        out = {}
+        prof = threading.Thread(target=lambda: out.update(
+            telemetry.capture_jax_profile(str(tmp_path), 0, 1.0)))
+        prof.start()
+        time.sleep(0.2)
+        feeder.start()
+        feeder.join(120)
+        prof.join(120)
+        assert not telemetry.profile_capturing()
+        one(5)                             # after: again no annotation
+        assert engine.telemetry.clock._ann is None
+    finally:
+        sched.stop(drain=False)
+    events = _profile_events(out["dir"])
+    for phase in ("stage", "enqueue", "device_wait", "deliver"):
+        assert events.get("tpu_inf/" + phase), (phase, sorted(events))
+    assert set(events) <= {"tpu_inf/dispatch"} | {
+        "tpu_inf/" + p for p in telemetry.LOOP_PHASES}
+    dispatches = events["tpu_inf/dispatch"]
+    seqs = sorted(int(d["seq"]) for d in dispatches)
+    assert seqs and seqs == sorted(set(seqs))
+    assert seq_before < seqs[0] and seqs[-1] <= engine._dispatch_seq
+    kinds = {d["kind"] for d in dispatches}
+    assert "decode" in kinds and kinds <= set(telemetry.STEP_KINDS)
+    assert all({"rung", "slots", "tokens", "chunk_tokens"} <= set(d)
+               for d in dispatches)
+    # The ledger joins on the same numbers.
+    ledger_seqs = {r["seq"] for r in engine.telemetry.step_ledger.snapshot()}
+    assert set(seqs) <= ledger_seqs
+
+
+def test_step_programs_have_stable_names():
+    """No step program compiles as jit__unknown: one name per role."""
+    from tpu_inference.engine import kv_cache as kvc
+    from tpu_inference.engine.engine import InferenceEngine
+
+    plain = InferenceEngine(
+        tiny_llama(512), EngineConfig(**ENGINE_KW, decode_steps_per_call=8,
+                                      hybrid_prefill=True), seed=0)
+    spec = InferenceEngine(
+        tiny_llama(512), EngineConfig(**ENGINE_KW, spec_mode="ngram",
+                                      num_speculative_tokens=3), seed=0)
+    names = {
+        "prefill": plain._prefill_jit.__name__,
+        "decode_k": plain._decode_multi_jit.__name__,
+        "decode_1": plain._decode_one_jit.__name__,
+        "hybrid": plain._hybrid_jit.__name__,
+        "verify": spec._verify_jit.__name__,
+        "offload": kvc._offload_jit.__name__,
+        "restore": kvc._restore_jit.__name__,
+    }
+    assert names == {
+        "prefill": "tpu_inf_prefill", "decode_k": "tpu_inf_decode_k8",
+        "decode_1": "tpu_inf_decode_1", "hybrid": "tpu_inf_hybrid",
+        "verify": "tpu_inf_spec_verify", "offload": "tpu_inf_kv_offload",
+        "restore": "tpu_inf_kv_restore"}
+    # And the name is what XLA is given.
+    import jax.numpy as jnp
+    hlo = kvc._offload_jit.lower(jnp.zeros((2, 4, 3)),
+                                 jnp.zeros((2,), jnp.int32)).as_text()
+    assert "jit_tpu_inf_kv_offload" in hlo and "unknown" not in hlo
+
+
+def test_queue_wait_split_sums_to_queue_wait():
+    """Per request: boundary + capacity = queue_wait, on the span and in
+    the three histograms, from the same timestamps."""
+    from tpu_inference.engine.engine import InferenceEngine, Sequence
+    from tpu_inference.engine.scheduler import EngineScheduler
+
+    engine = InferenceEngine(tiny_llama(512), EngineConfig(**ENGINE_KW),
+                             seed=0)
+    sched = EngineScheduler(engine)
+    sched.start()
+    done = threading.Event()
+    left = [5]
+
+    def on_finish(seq):
+        left[0] -= 1
+        if left[0] == 0:
+            done.set()
+
+    try:
+        # 5 requests into 2 slots: three wait for capacity.
+        for rid in range(5):
+            sched.submit(Sequence(request_id=rid,
+                                  prompt_tokens=[1, 2, 3, 4 + rid],
+                                  max_new_tokens=8, trace_id=f"qw-{rid}"),
+                         lambda s, t: None, on_finish)
+        assert done.wait(120)
+    finally:
+        sched.stop(drain=False)
+    tel = engine.telemetry
+    capacity_total = 0.0
+    for rid in range(5):
+        (span,) = [s for s in tel.recorder.export_recent(f"qw-{rid}")
+                   if s["name"] == "queue_wait"]
+        a = span["attrs"]
+        assert a["boundary_wait_s"] >= 0 and a["capacity_wait_s"] >= 0
+        assert a["boundary_wait_s"] + a["capacity_wait_s"] == pytest.approx(
+            span["dur"], abs=3e-6)
+        capacity_total += a["capacity_wait_s"]
+    assert tel.queue_wait_s.count == 5
+    assert tel.queue_boundary_wait_s.count == 5
+    assert tel.queue_capacity_wait_s.count == 5
+    assert (tel.queue_boundary_wait_s.sum + tel.queue_capacity_wait_s.sum
+            == pytest.approx(tel.queue_wait_s.sum, rel=1e-9))
+    # The three that found no free slot waited for capacity, visibly.
+    assert tel.queue_capacity_wait_s.sum == pytest.approx(capacity_total,
+                                                          abs=2e-5)
+    assert capacity_total > 0

@@ -290,13 +290,10 @@ class Sequence:
     # replica's host tier before dispatch (README "KV fabric") — the
     # fourth temperature: warmth another replica prefilled.
     route_fabric_hit_pages: int = 0
-    # Phase accounting accrued by the engine: wall time of device
-    # dispatches this request participated in, and its share of the
-    # host-side bubble between decode calls. Shared dispatches accrue
-    # fully to every participant (they wait on the same call), so these
-    # are per-request *exposure*, not an additive fleet total.
-    dispatch_wall_s: float = 0.0
-    bubble_s: float = 0.0
+    # The first admission pass that saw this request waiting (perf
+    # counter; 0 = none yet): splits queue wait into waiting for the
+    # running dispatch to come back and waiting for capacity.
+    admit_seen_time: float = 0.0
     # Adaptive-γ state for draft-free n-gram speculation (README
     # "Speculative decoding"): current per-sequence γ (-1 = engine
     # default, 0 = throttled), EWMA acceptance rate, and the countdown
@@ -353,6 +350,7 @@ class InferenceEngine:
                  draft_params: Optional[dict] = None,
                  pallas_interpret: bool = False):
         model_cfg.validate()
+        t_boot = time.perf_counter()
         self.model_cfg = model_cfg
         self.engine_cfg = engine_cfg
         self.mod = get_model_fns(model_cfg)
@@ -425,6 +423,11 @@ class InferenceEngine:
             kv_sh = shd.kv_sharding(mesh)
             kv_scale_sh = shd.kv_scale_sharding(mesh)
         self.params = params
+        # Boot phases are host walls: no sync is added to time them, so
+        # whatever the device still owes on the weights when their last
+        # program is enqueued lands in the phases after (warm-up ends
+        # in the boot's one block_until_ready).
+        t_weights = time.perf_counter()
         self.n_params = int(sum(x.size for x in jax.tree.leaves(params)))
         # Resident bytes of the (possibly quantized) weights — global
         # logical size, independent of sharding. Reported by /api/ps and
@@ -438,11 +441,24 @@ class InferenceEngine:
         # dispatch donates self.kv, so other threads (health, hello)
         # must never touch the array to ask.
         self._devices = sorted(self.kv.k.devices(), key=lambda d: d.id)
+        t_pool = time.perf_counter()
         self.allocator = PageAllocator(engine_cfg.num_pages)
         # Step-phase telemetry (telemetry.py): dispatch/bubble histograms
         # + read-through page/param gauges. TPU_INF_TELEMETRY=0 swaps in
         # no-op metrics (the overhead-comparison arm).
         self.telemetry = telemetry.EngineTelemetry(self)
+        # Boot phases (gauges set once; a caller that loaded a
+        # checkpoint itself adds its load time to the first).
+        self.boot_s = {"weights": t_weights - t_boot,
+                       "pool": t_pool - t_weights}
+        self.telemetry.boot_weights_s.set(self.boot_s["weights"])
+        self.telemetry.boot_pool_s.set(self.boot_s["pool"])
+        # Monotone dispatch number of the step programs (the ledger's
+        # ``seq``, the tpu_inf/dispatch annotation's ``seq``), and the
+        # prefill chunks enqueued whose result no readback has covered
+        # yet: (seq, enqueue instant, stalled decode lanes?).
+        self._dispatch_seq = 0
+        self._unsettled: List[Tuple[int, float, bool]] = []
         # Host-side bubble tracking: perf_counter at the end of the last
         # decode dispatch, None when the decode streak broke (idle batch
         # or an interleaved prefill) so cross-idle gaps never count.
@@ -658,17 +674,22 @@ class InferenceEngine:
         self._embed_jit = None
         self._embed_lock = threading.Lock()
 
+        k_fused = max(1, engine_cfg.decode_steps_per_call)
         self._prefill_jit = jax.jit(
-            partial(self._prefill_fn), donate_argnums=(1,))
+            telemetry.named_program("tpu_inf_prefill", self._prefill_fn),
+            donate_argnums=(1,))
         self._decode_multi_jit = jax.jit(
-            partial(self._decode_multi_fn), donate_argnums=(1,))
+            telemetry.named_program("tpu_inf_decode_k" + str(k_fused),
+                                    self._decode_multi_fn),
+            donate_argnums=(1,))
         # Hybrid prefill-decode steps (EngineConfig.hybrid_prefill): one
         # fused dispatch advances a [1, S] prefill chunk AND the [B]
         # K-step decode scan on the shared (page-disjoint) pool. One
         # graph per prefill bucket; the decode half keeps the fused-K
         # shape, so compile count matches the serial path's.
         self._hybrid_jit = jax.jit(
-            partial(self._hybrid_step_fn), donate_argnums=(1,))
+            telemetry.named_program("tpu_inf_hybrid", self._hybrid_step_fn),
+            donate_argnums=(1,))
         # Single-step decode graph: a 1-iteration scan, so a token leaves
         # the device every step instead of every K — the scheduler's
         # latency mode uses it when the batch is nearly empty (streaming
@@ -680,7 +701,9 @@ class InferenceEngine:
             self._decode_one_jit = self._decode_multi_jit
         else:
             self._decode_one_jit = jax.jit(
-                partial(self._decode_multi_fn, k_steps=1),
+                telemetry.named_program(
+                    "tpu_inf_decode_1",
+                    partial(self._decode_multi_fn, k_steps=1)),
                 donate_argnums=(1,))
         # Sequence-parallel prefill (ring attention over the sp axis) for
         # fresh full-prompt chunks on an sp>1 mesh.
@@ -703,7 +726,9 @@ class InferenceEngine:
                         f"({model_cfg.n_kv_heads}) divisible by tp*sp "
                         f"({tp}*{self.sp}); use sp_attn='ring'")
             self._prefill_sp_jit = jax.jit(
-                partial(self._prefill_fn, sp_mode=engine_cfg.sp_attn),
+                telemetry.named_program(
+                    "tpu_inf_prefill_sp",
+                    partial(self._prefill_fn, sp_mode=engine_cfg.sp_attn)),
                 donate_argnums=(1,))
 
         # Speculative decoding (BASELINE.json config 4): a draft model with
@@ -738,8 +763,10 @@ class InferenceEngine:
                   " KV pages, not O(window)")
         if self.spec_ngram:
             from tpu_inference.engine.speculative import verify_round
-            self._verify_jit = jax.jit(partial(verify_round, self),
-                                       donate_argnums=(1,))
+            self._verify_jit = jax.jit(
+                telemetry.named_program("tpu_inf_spec_verify",
+                                        partial(verify_round, self)),
+                donate_argnums=(1,))
             # Compiled verify widths (tokens per round = width): the
             # full γ+1 round plus a narrow 2-wide probe round, so a
             # γ=0-throttled lane re-checks its echo at near-plain cost.
@@ -767,10 +794,14 @@ class InferenceEngine:
                                                sharding=kv_sh,
                                                scale_sharding=kv_scale_sh)
             from tpu_inference.engine.speculative import spec_round
-            self._spec_jit = jax.jit(partial(spec_round, self),
-                                     donate_argnums=(2, 3))
+            self._spec_jit = jax.jit(
+                telemetry.named_program("tpu_inf_spec_round",
+                                        partial(spec_round, self)),
+                donate_argnums=(2, 3))
             self._draft_prefill_jit = jax.jit(
-                partial(self._draft_prefill_fn), donate_argnums=(1,))
+                telemetry.named_program("tpu_inf_draft_prefill",
+                                        self._draft_prefill_fn),
+                donate_argnums=(1,))
 
     # ------------------------------------------------------------------
     # Device graphs (pure functions of arrays; jitted once per bucket/batch)
@@ -930,6 +961,13 @@ class InferenceEngine:
     # Host-side orchestration
     # ------------------------------------------------------------------
 
+    def note_checkpoint_load(self, seconds: float) -> None:
+        """The caller loaded this engine's weights from a checkpoint
+        itself, before construction: that time belongs to the boot's
+        weights phase."""
+        self.boot_s["weights"] += seconds
+        self.telemetry.boot_weights_s.set(self.boot_s["weights"])
+
     def device_info(self) -> dict:
         """Where this engine really runs, read off the KV pool's own
         placement (not off the process default): its devices, their
@@ -966,12 +1004,27 @@ class InferenceEngine:
         ecfg = self.engine_cfg
         graphs = 0
 
-        def run(jitted, *args):
+        timeline: List[dict] = []
+        mon = telemetry.compile_monitor()
+
+        def run(label, jitted, *args):
             """One warm-up dispatch = one compiled graph (every call
-            below has a shape no earlier call had)."""
+            below has a shape no earlier call had). Each is timed and
+            named in the boot timeline, with where XLA got it from."""
             nonlocal graphs
             graphs += 1
-            return jitted(*args)
+            before = mon.snapshot() if mon is not None else None
+            t = time.perf_counter()
+            out = jitted(*args)
+            entry = {"graph": label, "program": jitted.__name__,
+                     "seconds": round(time.perf_counter() - t, 4)}
+            if before is not None:
+                compiles, _, hits = mon.snapshot()
+                entry["from"] = ("cache" if hits > before[2] else
+                                 "compiled" if compiles > before[0]
+                                 else "memory")
+            timeline.append(entry)
+            return out
 
         # Role-specialized warmup (README "P/D disaggregation"): a
         # prefill worker never dispatches the decode ladder and a decode
@@ -999,16 +1052,20 @@ class InferenceEngine:
                 if bucket > ecfg.max_context:
                     continue
                 toks = jnp.zeros((p, bucket), jnp.int32)
+                shape = f"{p}x{bucket}"
                 self.kv, _, _ = run(
+                    f"prefill {shape}",
                     self._prefill_jit, self.params, self.kv, toks, one,
                     zero, bt, self._next_key(), tz, tp, tk, sd, rp, rl, win)
                 if self.sp > 1 and bucket % self.sp == 0:
                     self.kv, _, _ = run(
+                        f"prefill_sp {shape}",
                         self._prefill_sp_jit, self.params, self.kv, toks,
                         one, zero, bt, self._next_key(), tz, tp, tk, sd, rp,
                         rl, win)
                 if self.spec_draft:
                     self.draft_kv = run(
+                        f"draft_prefill {shape}",
                         self._draft_prefill_jit, self.draft_params,
                         self.draft_kv, toks, one, zero, bt)
         def decode_half_args(b):
@@ -1028,12 +1085,12 @@ class InferenceEngine:
                     jnp.full((b, PENALTY_WINDOW), -1, jnp.int32))
 
         if not warm_decode:
-            return self._warmup_done(t0, graphs)
+            return self._warmup_done(t0, graphs, timeline)
         if self.spec_draft:
             b = ecfg.max_batch_size
             out = run(
-                self._spec_jit, self.params, self.draft_params, self.kv,
-                self.draft_kv,
+                f"spec_round b={b}", self._spec_jit, self.params,
+                self.draft_params, self.kv, self.draft_kv,
                 jnp.zeros((b,), jnp.int32), jnp.zeros((b,), jnp.int32),
                 jnp.zeros((b, self.max_pages), jnp.int32),
                 jnp.zeros((b,), jnp.int32), jnp.zeros((b,), bool),
@@ -1055,7 +1112,8 @@ class InferenceEngine:
             # mid-serving-compile failure mode ADVICE r3 flagged).
             for b in self.ladder:
                 for decode in decodes:
-                    self.kv, _, _, _ = run(decode, self.params, self.kv,
+                    self.kv, _, _, _ = run(f"decode b={b}", decode,
+                                           self.params, self.kv,
                                            *decode_half_args(b))
                 if ecfg.decode_pipeline_depth > 1:
                     # Dispatch-ahead carry folds run jnp.where at [b] /
@@ -1078,6 +1136,7 @@ class InferenceEngine:
             for b in self.ladder:
                 for width in self._spec_widths:
                     out = run(
+                        f"spec_verify b={b} w={width}",
                         self._verify_jit, self.params, self.kv,
                         jnp.zeros((b,), jnp.int32),
                         jnp.zeros((b,), jnp.int32),
@@ -1110,6 +1169,7 @@ class InferenceEngine:
                     continue
                 for b in self.ladder:
                     self.kv, _, _, _, _ = run(
+                        f"hybrid 1x{bucket} b={b}",
                         self._hybrid_jit, self.params, self.kv,
                         jnp.zeros((1, bucket), jnp.int32), one1, zero1, bt1,
                         self._next_key(), jnp.zeros((1,), jnp.float32),
@@ -1120,12 +1180,23 @@ class InferenceEngine:
                         jnp.zeros((1,), jnp.int32),
                         jnp.full((1, PENALTY_WINDOW), -1, jnp.int32),
                         *decode_half_args(b))
-        return self._warmup_done(t0, graphs)
+        return self._warmup_done(t0, graphs, timeline)
 
-    def _warmup_done(self, t0: float, graphs: int) -> float:
+    def _warmup_done(self, t0: float, graphs: int,
+                     timeline: List[dict]) -> float:
         jax.block_until_ready(self.kv)
         self.warmup_s = time.perf_counter() - t0
         self.warmup_graphs = graphs
+        self.telemetry.boot_warmup_s.set(self.warmup_s)
+        # One structured event for the whole boot: where its seconds
+        # went, graph by graph (a graph's seconds are its call's wall:
+        # compile or cache fetch + enqueue; the runs themselves overlap
+        # the next call and end inside ``warmup_s``).
+        telemetry.log_event(
+            "boot_timeline", level="warning",
+            weights_s=round(self.boot_s["weights"], 3),
+            pool_s=round(self.boot_s["pool"], 3),
+            warmup_s=round(self.warmup_s, 3), graphs=timeline)
         return self.warmup_s
 
     def embed(self, token_ids: List[int]) -> np.ndarray:
@@ -1226,39 +1297,93 @@ class InferenceEngine:
 
     # -- Decode dispatch/bubble accounting (telemetry.py phase model).
 
-    def _note_decode_entry(self, active_seqs: List["Sequence"]) -> float:
+    def _run(self, kind: str, jitted, args: tuple, *, rung: int = 0,
+             slots: int = 0, tokens: int = 0,
+             chunk_tokens: int = 0) -> Tuple[Any, int, float, float]:
+        """Enqueue one step program: the ``enqueue`` phase of the loop
+        clock around the jitted call, a fresh dispatch number, and —
+        only while a profile is being captured — a ``tpu_inf/dispatch``
+        annotation carrying that number and the dispatch's shape, so
+        ledger records and trace events join on ``seq``. Returns
+        (outputs, seq, instant the call began, instant it returned).
+        ``tokens`` is what the dispatch may generate (its grants)."""
+        clock = self.telemetry.clock
+        seq = self._dispatch_seq = self._dispatch_seq + 1
+        t0 = clock.enter("enqueue")
+        if telemetry.profile_capturing():
+            with jax.profiler.TraceAnnotation(
+                    "tpu_inf/dispatch", kind=kind, seq=seq, rung=rung,
+                    slots=slots, tokens=tokens, chunk_tokens=chunk_tokens):
+                out = jitted(*args)
+        else:
+            out = jitted(*args)
+        return out, seq, t0, clock.dispatched(seq)
+
+    def _observed(self, seq: int, now: float) -> None:
+        """The host has read back a result of dispatch ``seq`` at
+        ``now``: it and every program enqueued before it are done.
+        Prefill chunks pushed to the ledger at enqueue (nobody reads a
+        non-final chunk's token) get their ``t_done`` and true
+        ``device_s`` here, from a readback the engine performs anyway —
+        for a non-final chunk that is the next readback behind it, so
+        its ``t_done`` is an upper bound."""
+        tel = self.telemetry
+        tel.clock.observed(seq)
+        while self._unsettled and self._unsettled[0][0] <= seq:
+            chunk_seq, t_enq, stalled = self._unsettled.pop(0)
+            tel.step_ledger.settle(chunk_seq, tel.recorder.to_unix(now))
+            if stalled:
+                tel.decode_stall_during_prefill_s.observe(now - t_enq)
+
+    def _wait(self, seq: int, read: Callable[[], Any]
+              ) -> Tuple[Any, float, float]:
+        """A blocking readback of dispatch ``seq``'s result: ``read()``
+        under the ``device_wait`` phase, then the dispatch counts as
+        observed. Returns (what ``read`` returned, the instant the wait
+        began, the instant it ended)."""
+        clock = self.telemetry.clock
+        t_wait = clock.enter("device_wait")
+        out = read()
+        t_done = clock.enter("other")
+        self._observed(seq, t_done)
+        return out, t_wait, t_done
+
+    def _note_decode_entry(self, now: float) -> None:
         """Record the host-side bubble since the last decode dispatch
-        ended (if the decode streak is unbroken) and return the dispatch
-        start timestamp."""
-        now = time.perf_counter()
+        ended (if the decode streak is unbroken); ``now`` is the instant
+        the dispatch's jitted call began."""
         last = self._last_decode_end
         self._pending_bubble = 0.0
         if last is not None and self.telemetry.enabled:
             gap = now - last
             self.telemetry.dispatch_bubble_s.observe(gap)
             self._pending_bubble = gap     # step-ledger host-bound input
-            for seq in active_seqs:
-                seq.bubble_s += gap
-        return now
 
-    def _note_decode_exit(self, t0: float,
-                          active_seqs: List["Sequence"]) -> float:
-        """Record one decode dispatch's host wall and refresh the bubble
-        reference point. The streak survives only while some sequence is
-        still live — cross-idle gaps are not bubbles. Returns the
-        dispatch wall (the step ledger's device_s input)."""
-        now = time.perf_counter()
+    def _note_decode_exit(self, t0: float, now: float) -> float:
+        """Record one decode dispatch's host wall (``t0`` .. ``now``,
+        the clock's own stamps) and refresh the bubble reference point.
+        The streak survives only while some sequence is still live —
+        cross-idle gaps are not bubbles. Returns the dispatch wall (the
+        step ledger's device_s input)."""
         dt = now - t0
         tel = self.telemetry
-        if tel.enabled:
-            tel.decode_dispatch_s.observe(dt)
-            tel.decode_dispatches.inc()
-            for seq in active_seqs:
-                seq.dispatch_wall_s += dt
+        tel.decode_dispatch_s.observe(dt)
+        tel.decode_dispatches.inc()
         self._last_decode_end = (
             now if any(s is not None and not s.done for s in self.slots)
             else None)
         return dt
+
+    def _run_decode(self, kind: str, jitted, args: tuple, *, rung: int,
+                    slots: int, tokens: int, chunk_tokens: int = 0
+                    ) -> Tuple[Any, int, float, float]:
+        """``_run`` for a dispatch with decode lanes: bubble before it,
+        dispatch wall after. Returns (outputs, seq, t0, dispatch wall)."""
+        out, seq, t0, t1 = self._run(kind, jitted, args, rung=rung,
+                                     slots=slots, tokens=tokens,
+                                     chunk_tokens=chunk_tokens)
+        self._note_decode_entry(t0)
+        return out, seq, t0, self._note_decode_exit(t0, t1)
 
     def _next_key(self) -> jax.Array:
         self._step_count += 1
@@ -1428,9 +1553,15 @@ class InferenceEngine:
         """Demote-time device->host copy (the prefix cache's offload_fn):
         one bundled transfer for the whole victim batch, with swap
         telemetry. Engine thread only (reads the live pool)."""
-        t0 = time.perf_counter()
+        clock = self.telemetry.clock
+        prev = clock.phase
+        t0 = clock.enter("swap")
         out = kvc.offload_pages(self.kv, pages)
-        t1 = time.perf_counter()
+        t1 = clock.enter(prev or "other")
+        if out:
+            # The device_get read the live pool: every dispatch so far
+            # has settled.
+            self._observed(self._dispatch_seq, t1)
         if out and self.host_pool is not None:
             # Pool accounting is part of the tier's stats surface (like
             # offloaded/restored totals) — NOT gated on telemetry.
@@ -1456,9 +1587,11 @@ class InferenceEngine:
         device) and record swap telemetry. ``trace_id`` attributes the
         swap-in span to the request that triggered it (empty = a
         maintenance-lane span)."""
-        t0 = time.perf_counter()
+        clock = self.telemetry.clock
+        prev = clock.phase
+        t0 = clock.enter("swap")
         self.kv = kvc.restore_pages(self.kv, fresh, entries)
-        t1 = time.perf_counter()
+        t1 = clock.enter(prev or "other")
         if self.host_pool is not None:
             # Pool accounting is part of the tier's stats surface —
             # NOT gated on telemetry (offloaded/restored totals aren't).
@@ -1826,9 +1959,12 @@ class InferenceEngine:
         shared: List[int] = []
         n_restored = 0
         if self.prefix_cache is not None:
+            clock = self.telemetry.clock
+            clock.enter("prefix_lookup")
             pages, host_entries, seq.cached_tokens = self.prefix_cache.lookup(
                 prompt, max_tokens=len(prompt) - 1,
                 digests=self._seq_digests(seq, prompt))
+            clock.enter("admit")
             shared = self._restore_host_entries(
                 pages, host_entries,
                 trace_id=seq.trace_id or str(seq.request_id))
@@ -1925,6 +2061,7 @@ class InferenceEngine:
         sampled-token device array for the chunk)."""
         ecfg = self.engine_cfg
         chunk_cap = ecfg.chunk_tokens_cap
+        self.telemetry.clock.enter("stage")
         st = self._stage_chunk_arrays(seq, prompt, offset, chunk_cap)
         use_sp = self._use_sp(offset, st["chunk_tokens"], len(prompt),
                               st["bucket"])
@@ -1936,10 +2073,11 @@ class InferenceEngine:
         # already visible in prefill_dispatch_s). Mid-prefill sequences
         # are excluded by active_sequences, so this counts only victims.
         stalled = bool(self.active_sequences())
-        t0 = time.perf_counter()
         self._last_decode_end = None     # prefill breaks the decode streak
-        self.kv, tok, _ = prefill(self.params, self.kv,
-                                  *self._chunk_device_args(st))
+        c = st["chunk_tokens"]
+        args = (self.params, self.kv, *self._chunk_device_args(st))
+        (self.kv, tok, _), dseq, t0, t1 = self._run(
+            "prefill_chunk", prefill, args, slots=1, chunk_tokens=c)
         if self.spec_draft:
             # Mirror the chunk into the draft model's KV (same pages).
             self.draft_kv = self._draft_prefill_jit(
@@ -1948,45 +2086,44 @@ class InferenceEngine:
                 jnp.asarray(st["prefix_len"]),
                 jnp.asarray(st["block_table"]))
         if self.telemetry.enabled:
-            dt = time.perf_counter() - t0
+            dt = t1 - t0
             self.telemetry.prefill_dispatch_s.observe(dt)
             self.telemetry.prefill_dispatches.inc()
-            if stalled:
-                # The stall histogram must record the chunk's DEVICE
-                # wall, not the (async on TPU) enqueue overhead dt —
-                # blocking here costs nothing extra: the stalled lanes
-                # can't advance until this chunk completes anyway.
-                jax.block_until_ready(tok)
-                self.telemetry.decode_stall_during_prefill_s.observe(
-                    time.perf_counter() - t0)
-            seq.dispatch_wall_s += dt
             # Per-chunk trace span (README "Observability" span schema):
             # children of the request's prefill span, so a long prompt's
             # chunk cadence is visible on the trace timeline.
             self.telemetry.recorder.add(
                 "prefill_chunk", seq.trace_id or str(seq.request_id),
-                t0, t0 + dt, parent="prefill",
-                offset=int(offset), tokens=int(st["chunk_tokens"]))
-            c = st["chunk_tokens"]
+                t0, t1, parent="prefill",
+                offset=int(offset), tokens=int(c))
             final = offset + c >= len(prompt)
+            # Pushed at enqueue with the enqueue wall; the true device_s
+            # and t_done land when a readback covers this chunk
+            # (_observed: the final chunk's own token, else the next
+            # sync behind it) — no sync exists only to time it. The
+            # stall histogram (lanes stalled behind this serial chunk)
+            # is fed from the same instant.
             self._ledger_push(
                 "prefill_chunk", rung=0, slots=1,
                 tokens=1 if final else 0, chunk_tokens=c,
                 device_s=dt, kv_read=c * offset + c * (c + 1) // 2,
                 compile_event=st["bucket"]
-                not in self._prefill_buckets_seen)
+                not in self._prefill_buckets_seen,
+                seq=dseq, t_enqueue=t0)
+            self._unsettled.append((dseq, t0, stalled))
             self._prefill_buckets_seen.add(st["bucket"])
-        return offset + st["chunk_tokens"], tok
+        return offset + c, tok, dseq
 
     def _prefill_chunked(self, seq: Sequence, prompt: List[int]) -> None:
         """Serial (one-lane) prefill; chunks prompts that exceed the
         largest bucket. Each chunk attends to itself + all cached tokens
         (prefix_len); only the final chunk's sampled token is kept."""
         offset = seq.cached_tokens
-        tok = None
+        tok = dseq = None
         while offset < len(prompt):
-            offset, tok = self._prefill_one_chunk(seq, prompt, offset)
-        self._prefill_finish(seq, prompt, int(tok[0]))
+            offset, tok, dseq = self._prefill_one_chunk(seq, prompt, offset)
+        first, _, _ = self._wait(dseq, lambda: int(tok[0]))
+        self._prefill_finish(seq, prompt, first)
 
     # -- Incremental (interleavable) prefill: one chunk per call, so the
     # -- scheduler can run decode steps between a long prompt's chunks
@@ -2017,11 +2154,12 @@ class InferenceEngine:
         prompt = seq.prefill_prompt
         assert prompt is not None, "prefill_step without prefill_begin"
         self._chaos_step_gate()
-        seq.prefill_offset, tok = self._prefill_one_chunk(
+        seq.prefill_offset, tok, dseq = self._prefill_one_chunk(
             seq, prompt, seq.prefill_offset)
         if seq.prefill_offset < len(prompt):
             return False
-        self._prefill_finish(seq, prompt, int(tok[0]))
+        first, _, _ = self._wait(dseq, lambda: int(tok[0]))
+        self._prefill_finish(seq, prompt, first)
         seq.prefill_prompt = None
         return True
 
@@ -2043,7 +2181,7 @@ class InferenceEngine:
         prompt_len=1 with an all-zero block table, so their single write
         lands on the trash page and their sampled token is discarded.
         """
-        ecfg = self.engine_cfg
+        self.telemetry.clock.enter("stage")
         p = next(s for s in self._prefill_batch_sizes if s >= len(group))
         toks = np.zeros((p, bucket), np.int32)
         plen = np.ones((p,), np.int32)
@@ -2069,35 +2207,34 @@ class InferenceEngine:
             if rpens[i] != 1.0:
                 wins[i] = self._penalty_window_row(seq)
         prefill = self._prefill_sp_jit if use_sp else self._prefill_jit
-        t0 = time.perf_counter()
         self._last_decode_end = None     # prefill breaks the decode streak
-        self.kv, tok, _ = prefill(
-            self.params, self.kv, jnp.asarray(toks), jnp.asarray(plen),
-            jnp.asarray(pref), jnp.asarray(bts), self._next_key(),
-            jnp.asarray(temps), jnp.asarray(top_ps), jnp.asarray(top_ks),
-            jnp.asarray(seeds), jnp.asarray(rpens), jnp.asarray(rlasts),
-            jnp.asarray(wins))
+        n = len(group)
+        chunk_tokens = int(plen[:n].sum())
+        args = (self.params, self.kv, jnp.asarray(toks), jnp.asarray(plen),
+                jnp.asarray(pref), jnp.asarray(bts), self._next_key(),
+                jnp.asarray(temps), jnp.asarray(top_ps),
+                jnp.asarray(top_ks), jnp.asarray(seeds),
+                jnp.asarray(rpens), jnp.asarray(rlasts), jnp.asarray(wins))
+        (self.kv, tok, _), dseq, t0, _ = self._run(
+            "prefill_chunk", prefill, args, slots=n, tokens=n,
+            chunk_tokens=chunk_tokens)
         if self.spec_draft:
             self.draft_kv = self._draft_prefill_jit(
                 self.draft_params, self.draft_kv, jnp.asarray(toks),
                 jnp.asarray(plen), jnp.asarray(pref), jnp.asarray(bts))
-        toks_out = np.asarray(tok)
+        toks_out, _, t_done = self._wait(dseq, lambda: np.asarray(tok))
         if self.telemetry.enabled:
-            dt = time.perf_counter() - t0    # includes the token readback
+            dt = t_done - t0                 # includes the token readback
             self.telemetry.prefill_dispatch_s.observe(dt)
             self.telemetry.prefill_dispatches.inc()
-            for seq, _ in group:
-                seq.dispatch_wall_s += dt
             graph_key = (bucket, p, use_sp)
             self._ledger_push(
-                "prefill_chunk", rung=0, slots=len(group),
-                tokens=len(group),
-                chunk_tokens=int(plen[:len(group)].sum()),
-                device_s=dt,
-                kv_read=int((plen[:len(group)] * pref[:len(group)]
-                             + plen[:len(group)]
-                             * (plen[:len(group)] + 1) // 2).sum()),
-                compile_event=graph_key not in self._prefill_buckets_seen)
+                "prefill_chunk", rung=0, slots=n, tokens=n,
+                chunk_tokens=chunk_tokens, device_s=dt,
+                kv_read=int((plen[:n] * pref[:n]
+                             + plen[:n] * (plen[:n] + 1) // 2).sum()),
+                compile_event=graph_key not in self._prefill_buckets_seen,
+                seq=dseq, t_enqueue=t0, t_done=t_done)
             self._prefill_buckets_seen.add(graph_key)
         for i, (seq, prompt) in enumerate(group):
             self._prefill_finish(seq, prompt, int(toks_out[i]))
@@ -2425,12 +2562,16 @@ class InferenceEngine:
                      spec_accepted: int = 0,
                      staging_s: Optional[float] = None,
                      bubble_s: Optional[float] = None,
-                     compile_event: Optional[bool] = None) -> None:
+                     compile_event: Optional[bool] = None,
+                     seq: int = 0, t_enqueue: float = 0.0,
+                     t_done: float = 0.0) -> None:
         """Push one per-dispatch record into the step ledger, folding in
         the staged bubble/staging micros (unless the caller captured
         them at stage time — pipelined calls push at SYNC, by which
         point the scratch belongs to a newer dispatch) and the KV-swap
-        byte delta since the previous record. Callers gate on
+        byte delta since the previous record. ``t_enqueue`` / ``t_done``
+        are the loop clock's instants (perf counter; recorded as unix on
+        the span recorder's anchor; 0 = not known yet). Callers gate on
         telemetry.enabled (the swap counters are NULL_METRIC otherwise).
         """
         tel = self.telemetry
@@ -2447,10 +2588,13 @@ class InferenceEngine:
         if compile_event is None:
             compile_event = self._last_compile_event
             self._last_compile_event = False
+        to_unix = tel.recorder.to_unix
         tel.step_ledger.push(
             kind, rung, slots, tokens, chunk_tokens, steps, device_s,
             staging_s, bubble_s, kv_read, swap, spec_accepted,
-            compile_event)
+            compile_event, seq=seq,
+            t_enqueue=to_unix(t_enqueue) if t_enqueue else 0.0,
+            t_done=to_unix(t_done) if t_done else 0.0)
 
     def _compact_slots(self) -> None:
         """Step-down helper: relocate bound sequences out of high slots
@@ -2527,8 +2671,8 @@ class InferenceEngine:
         benign: their ``allowed`` is 0, so the graph masks every read
         and write (writes land on the trash page) and their token is
         discarded (-1)."""
-        tel_on = self.telemetry.enabled
-        t_stage = time.perf_counter() if tel_on else 0.0
+        clock = self.telemetry.clock
+        t_stage = clock.enter("stage")
         if not self._stage_reuse:
             # Legacy rebuild-per-dispatch (the bubble comparison arm).
             tokens = np.zeros((rung,), np.int32)
@@ -2552,8 +2696,10 @@ class InferenceEngine:
                 rpens[i], rlasts[i] = self._penalty_arrays(seq)
                 if rpens[i] != 1.0:
                     windows[i] = self._penalty_window_row(seq)
-            if tel_on:
-                self._last_staging_s = time.perf_counter() - t_stage
+            # Re-entering the phase reads the clock once: the ledger's
+            # staging_s stays this function's wall, while the stage
+            # phase runs on through the caller's device_puts.
+            self._last_staging_s = clock.enter("stage") - t_stage
             return (tokens, ctx_lens, bts, temps, top_ps, top_ks, seeds,
                     rpens, rlasts, windows)
         buf = self._stage_buffers(rung)
@@ -2584,8 +2730,7 @@ class InferenceEngine:
                 row[n:] = 0
             if buf["rpens"][i] != 1.0:
                 buf["windows"][i] = self._penalty_window_row(seq)
-        if tel_on:
-            self._last_staging_s = time.perf_counter() - t_stage
+        self._last_staging_s = clock.enter("stage") - t_stage
         return (buf["tokens"].copy(), buf["ctx"].copy(), buf["bts"].copy(),
                 buf["temps"].copy(), buf["top_ps"].copy(),
                 buf["top_ks"].copy(), buf["seeds"].copy(),
@@ -2631,6 +2776,7 @@ class InferenceEngine:
         proposal this round — plain fused decode is strictly better than
         a verify round that could only emit one token per lane."""
         ecfg = self.engine_cfg
+        self.telemetry.clock.enter("stage")
         k_steps = max(1, ecfg.decode_steps_per_call)
         if max_steps is not None:
             k_steps = min(k_steps, max_steps)
@@ -2673,15 +2819,19 @@ class InferenceEngine:
         # token) instead of masking K-1 steps of the fused graph.
         decode = self._decode_one_jit if k_steps == 1 else \
             self._decode_multi_jit
-        t0 = self._note_decode_entry(active_seqs)
-        self.kv, outs, _, _ = decode(
+        args = (
             self.params, self.kv, jnp.asarray(tokens), jnp.asarray(ctx_lens),
             jnp.asarray(bts), jnp.asarray(allowed), jnp.asarray(eos_ids),
             self._next_key(), jnp.asarray(temps), jnp.asarray(top_ps),
             jnp.asarray(top_ks), jnp.asarray(seeds), jnp.asarray(rpens),
             jnp.asarray(rlasts), jnp.asarray(windows))
-        outs = np.asarray(outs)                                 # [K, B]
-        dt = self._note_decode_exit(t0, active_seqs)
+        (self.kv, outs, _, _), dseq, t0, _ = self._run(
+            "decode", decode, args, rung=b, slots=len(active_seqs),
+            tokens=int(allowed.sum()))
+        self._note_decode_entry(t0)
+        # Synchronous round: the call's wall is enqueue + readback.
+        outs, _, t_done = self._wait(dseq, lambda: np.asarray(outs))  # [K, B]
+        dt = self._note_decode_exit(t0, t_done)
         kv_read = sum(s.ctx_len for s in active_seqs) * k_steps
 
         result: Dict[int, List[int]] = {}
@@ -2695,7 +2845,8 @@ class InferenceEngine:
             self.telemetry.tokens_per_dispatch.observe(n_tokens)
             self._ledger_push("decode", rung=b, slots=len(active_seqs),
                               tokens=n_tokens, steps=k_steps,
-                              device_s=dt, kv_read=kv_read)
+                              device_s=dt, kv_read=kv_read,
+                              seq=dseq, t_enqueue=t0, t_done=t_done)
         return result
 
     # ------------------------------------------------------------------
@@ -2745,20 +2896,21 @@ class InferenceEngine:
         like hybrid calls. Counts as a prefill dispatch, not a hybrid
         step, and observes no decode stall — the lanes it would have
         stalled are covered by in-flight work."""
-        t0 = time.perf_counter()
         self._last_decode_end = None   # prefill breaks the decode streak
-        self.kv, p_tok, _ = self._prefill_jit(
-            self.params, self.kv, *self._chunk_device_args(chunk))
+        c = chunk["chunk_tokens"]
+        args = (self.params, self.kv, *self._chunk_device_args(chunk))
+        (self.kv, p_tok, _), dseq, t0, t1 = self._run(
+            "prefill_chunk", self._prefill_jit, args, slots=1,
+            chunk_tokens=c)
         call = {"outs": None, "final": None, "final_window": None,
                 "allowed": {}, "seqs": {}, "rung": 0,
+                "seq": dseq, "t_enqueue": t0,
                 "prefill": {"seq": chunk["seq"], "prompt": chunk["prompt"],
                             "final": chunk["final"], "tok": p_tok}}
         if self.telemetry.enabled:
-            dt = time.perf_counter() - t0
+            dt = t1 - t0
             self.telemetry.prefill_dispatch_s.observe(dt)
             self.telemetry.prefill_dispatches.inc()
-            chunk["seq"].dispatch_wall_s += dt
-            c = chunk["chunk_tokens"]
             off = int(chunk["prefix_len"][0])
             call["ledger"] = {
                 "kind": "prefill_chunk", "rung": 0, "slots": 1,
@@ -2788,6 +2940,7 @@ class InferenceEngine:
         is always rewritten by a later owner before being attended).
         """
         ecfg = self.engine_cfg
+        self.telemetry.clock.enter("stage")
         k_steps = max(1, ecfg.decode_steps_per_call)
         if not self._inflight:
             self._compact_slots()     # rung can step down between bursts
@@ -2875,36 +3028,38 @@ class InferenceEngine:
             tokens_d = jnp.where(carried_d, call["final"], tokens_d)
             window_d = jnp.where(carried_d[:, None], call["final_window"],
                                  window_d)
-        t0 = self._note_decode_entry(staged)
-        if chunk is None:
-            self.kv, outs, final, final_window = self._decode_multi_jit(
-                self.params, self.kv, tokens_d, jnp.asarray(ctx_lens),
-                jnp.asarray(bts), jnp.asarray(allowed), jnp.asarray(eos_ids),
-                self._next_key(), jnp.asarray(temps), jnp.asarray(top_ps),
-                jnp.asarray(top_ks), jnp.asarray(seeds), jnp.asarray(rpens),
-                jnp.asarray(rlasts), window_d)
-            p_tok = None
-        else:
-            self.kv, p_tok, outs, final, final_window = self._hybrid_jit(
-                self.params, self.kv, *self._chunk_device_args(chunk),
-                tokens_d, jnp.asarray(ctx_lens),
-                jnp.asarray(bts), jnp.asarray(allowed), jnp.asarray(eos_ids),
-                self._next_key(), jnp.asarray(temps), jnp.asarray(top_ps),
-                jnp.asarray(top_ks), jnp.asarray(seeds), jnp.asarray(rpens),
-                jnp.asarray(rlasts), window_d)
-            self.hybrid_steps_total += 1
-            self.telemetry.hybrid_steps.inc()
+        decode_args = (
+            tokens_d, jnp.asarray(ctx_lens),
+            jnp.asarray(bts), jnp.asarray(allowed), jnp.asarray(eos_ids),
+            self._next_key(), jnp.asarray(temps), jnp.asarray(top_ps),
+            jnp.asarray(top_ks), jnp.asarray(seeds), jnp.asarray(rpens),
+            jnp.asarray(rlasts), window_d)
+        granted = int(allowed.sum())
         # Non-blocking dispatch: the wall recorded here is host dispatch
         # overhead; the device wait surfaces in decode_sync_s at
         # _sync_oldest.
-        dispatch_dt = self._note_decode_exit(t0, staged)
-        if chunk is not None and self.telemetry.enabled:
-            dt = time.perf_counter() - t0
-            self.telemetry.hybrid_dispatch_s.observe(dt)
-            chunk["seq"].dispatch_wall_s += dt
+        if chunk is None:
+            ((self.kv, outs, final, final_window), dseq, t0,
+             dispatch_dt) = self._run_decode(
+                "decode", self._decode_multi_jit,
+                (self.params, self.kv, *decode_args),
+                rung=b, slots=len(staged), tokens=granted)
+            p_tok = None
+        else:
+            ((self.kv, p_tok, outs, final, final_window), dseq, t0,
+             dispatch_dt) = self._run_decode(
+                "hybrid", self._hybrid_jit,
+                (self.params, self.kv, *self._chunk_device_args(chunk),
+                 *decode_args),
+                rung=b, slots=len(staged), tokens=granted,
+                chunk_tokens=chunk["chunk_tokens"])
+            self.hybrid_steps_total += 1
+            self.telemetry.hybrid_steps.inc()
+            self.telemetry.hybrid_dispatch_s.observe(dispatch_dt)
         call = {"outs": outs, "final": final,
                 "final_window": final_window,
                 "allowed": allowed_by_slot, "rung": b,
+                "seq": dseq, "t_enqueue": t0,
                 "seqs": {s.slot: s for s in staged}}
         if chunk is not None:
             call["prefill"] = {"seq": chunk["seq"], "prompt": chunk["prompt"],
@@ -2951,40 +3106,32 @@ class InferenceEngine:
             # ngram spec round staged into the pipeline: its fold is
             # emission-shaped (accept-prefix + caps), not K-step-shaped.
             return self._sync_spec_call(call)
-        t0 = time.perf_counter()
         pf = call.get("prefill")
-        if call["outs"] is not None:
-            outs = np.asarray(call["outs"])           # [K, B]
-        else:
+
+        def read():
+            if call["outs"] is not None:
+                return np.asarray(call["outs"])       # [K, B]
             # Chunk-only call (no decode half): the blocking sync is on
-            # the chunk's sampled token instead.
-            outs = None
+            # the chunk's sampled token instead (the pipeline's ordering
+            # needs it: a later call may release pages it writes).
             if pf is not None:
                 jax.block_until_ready(pf["tok"])
-        sync_dt = time.perf_counter() - t0
-        if self.telemetry.enabled:
-            dt = sync_dt
-            if outs is not None:
-                self.telemetry.decode_sync_s.observe(dt)
-            if pf is not None:
-                # The chunk shared this call, so its request waited on
-                # the same sync (the chunk's prefill compute usually
-                # dominates it) — without this the long prompt's
-                # timeline would show near-zero dispatch wall. Chunk-
-                # only waits stay out of decode_sync_s (pure prefill
-                # device time, not a decode sync).
-                pf["seq"].dispatch_wall_s += dt
-            for seq in call["seqs"].values():
-                if not seq.done and self.slots[seq.slot] is seq:
-                    seq.dispatch_wall_s += dt
-        # The blocking sync is DEVICE time (already in decode_sync_s /
-        # dispatch_wall_s): refresh the bubble reference point so the
-        # next decode entry measures only host work after it — without
-        # this, dispatch-ahead mode would re-count every device step as
+            return None
+
+        outs, t0, t_done = self._wait(call["seq"], read)
+        sync_dt = t_done - t0
+        if outs is not None:
+            # Chunk-only waits stay out of decode_sync_s (pure prefill
+            # device time, not a decode sync).
+            self.telemetry.decode_sync_s.observe(sync_dt)
+        # The blocking sync is DEVICE time (already in decode_sync_s):
+        # refresh the bubble reference point so the next decode entry
+        # measures only host work after it — without this,
+        # dispatch-ahead mode would re-count every device step as
         # "host-side bubble" and the phase_breakdown would blame the
         # host for a busy device.
         self._last_decode_end = (
-            time.perf_counter()
+            t_done
             if any(s is not None and not s.done for s in self.slots)
             else None)
         result: Dict[int, List[int]] = {}
@@ -3022,9 +3169,15 @@ class InferenceEngine:
                 led["kind"], rung=led["rung"], slots=led["slots"],
                 tokens=tokens, chunk_tokens=led["chunk_tokens"],
                 steps=led["steps"],
-                device_s=led["dispatch_s"] + sync_dt,
+                # a chunk's device_s is enqueue -> observed; a decode
+                # call's stays its dispatch + sync walls
+                device_s=(t_done - call["t_enqueue"]
+                          if led["kind"] == "prefill_chunk"
+                          else led["dispatch_s"] + sync_dt),
                 kv_read=led["kv_read"], staging_s=led["staging_s"],
-                bubble_s=led["bubble_s"], compile_event=led["compile"])
+                bubble_s=led["bubble_s"], compile_event=led["compile"],
+                seq=call["seq"], t_enqueue=call["t_enqueue"],
+                t_done=t_done)
         return result
 
     def _pressure_settle_round(self) -> Dict[int, List[int]]:
@@ -3217,22 +3370,25 @@ class InferenceEngine:
         outs_all = []
         kv_read = sum(s.ctx_len for s in active_seqs) * total
         dispatch_wall = 0.0
+        t_first = 0.0
         for c in range(n_calls):
-            t0 = self._note_decode_entry(active_seqs)
-            self.kv, outs, tokens_dev, window_dev = self._decode_multi_jit(
-                self.params, self.kv, tokens_dev,
-                jnp.asarray(ctx_lens + c * allowed, np.int32), bts_d,
-                allowed_d, no_eos, self._next_key(), temps_d, top_ps_d,
-                top_ks_d, seeds_d, rpens_d, rlasts_d, window_dev)
+            ((self.kv, outs, tokens_dev, window_dev), dseq, t0,
+             dt) = self._run_decode(
+                "decode", self._decode_multi_jit,
+                (self.params, self.kv, tokens_dev,
+                 jnp.asarray(ctx_lens + c * allowed, np.int32), bts_d,
+                 allowed_d, no_eos, self._next_key(), temps_d, top_ps_d,
+                 top_ks_d, seeds_d, rpens_d, rlasts_d, window_dev),
+                rung=b, slots=len(active_seqs), tokens=int(allowed.sum()))
             outs_all.append(outs)
-            dispatch_wall += self._note_decode_exit(t0, active_seqs)
-        t_sync = time.perf_counter()
-        jax.block_until_ready(tokens_dev)
-        sync_dt = time.perf_counter() - t_sync
-        if self.telemetry.enabled:
-            self.telemetry.decode_sync_s.observe(sync_dt)
+            dispatch_wall += dt
+            t_first = t_first or t0
+        _, t_sync, t_done = self._wait(       # the run's one readback
+            dseq, lambda: jax.block_until_ready(tokens_dev))
+        sync_dt = t_done - t_sync
+        self.telemetry.decode_sync_s.observe(sync_dt)
         # Device wait, not host bubble (same rationale as _sync_oldest).
-        self._last_decode_end = time.perf_counter()
+        self._last_decode_end = t_done
 
         result: Dict[int, List[int]] = {rid.request_id: []
                                         for rid in active_seqs}
@@ -3254,7 +3410,8 @@ class InferenceEngine:
                 "decode", rung=b, slots=len(active_seqs),
                 tokens=sum(len(t) for t in result.values()),
                 steps=total, device_s=dispatch_wall + sync_dt,
-                kv_read=kv_read)
+                kv_read=kv_read, seq=dseq, t_enqueue=t_first,
+                t_done=t_done)
         return result
 
     def _spec_grant(self, active_seqs: List[Sequence], s_len: int,
@@ -3338,16 +3495,20 @@ class InferenceEngine:
         # sampler consumes randomness at a data-dependent rate, so a
         # position-keyed stream would not reproduce anyway); spec uses the
         # engine-global key.
-        t0 = self._note_decode_entry(active_seqs)
-        out = self._spec_jit(
-            self.params, self.draft_params, self.kv, self.draft_kv,
-            jnp.asarray(tokens), jnp.asarray(ctx_lens), jnp.asarray(bts),
-            jnp.asarray(cap), jnp.asarray(active), self._next_key(),
-            jnp.asarray(temps), jnp.asarray(top_ps), jnp.asarray(top_ks))
+        out, dseq, t0, _ = self._run(
+            "spec_verify", self._spec_jit,
+            (self.params, self.draft_params, self.kv, self.draft_kv,
+             jnp.asarray(tokens), jnp.asarray(ctx_lens), jnp.asarray(bts),
+             jnp.asarray(cap), jnp.asarray(active), self._next_key(),
+             jnp.asarray(temps), jnp.asarray(top_ps), jnp.asarray(top_ks)),
+            rung=b, slots=len(active_seqs),
+            tokens=s_len * len(active_seqs))
+        self._note_decode_entry(t0)
         self.kv, self.draft_kv = out.kv, out.draft_kv
-        emitted = np.asarray(out.emitted)                   # [B, gamma+1]
-        n_acc = np.asarray(out.n_accepted)
-        dt = self._note_decode_exit(t0, active_seqs)
+        (emitted, n_acc), _, t_done = self._wait(dseq, lambda: (
+            np.asarray(out.emitted),                        # [B, gamma+1]
+            np.asarray(out.n_accepted)))
+        dt = self._note_decode_exit(t0, t_done)
         # Pre-fold context: the verify forward read the cache at the ctx
         # the lanes ENTERED the round with.
         kv_read = sum(s.ctx_len for s in active_seqs) * s_len
@@ -3389,7 +3550,8 @@ class InferenceEngine:
             self._ledger_push(
                 "spec_verify", rung=b, slots=len(active_seqs),
                 tokens=n_toks, device_s=dt, kv_read=kv_read,
-                spec_accepted=self.spec_accepted - acc0)
+                spec_accepted=self.spec_accepted - acc0,
+                seq=dseq, t_enqueue=t0, t_done=t_done)
         return result
 
     # ------------------------------------------------------------------
@@ -3558,19 +3720,23 @@ class InferenceEngine:
         # consumes randomness at a data-dependent rate, so a position-
         # keyed stream would not reproduce anyway); greedy — where the
         # byte-identity guarantee lives — is unaffected.
-        t0 = self._note_decode_entry(active_seqs)
-        out = self._verify_jit(
-            self.params, self.kv, jnp.asarray(tokens),
-            jnp.asarray(ctx_lens), jnp.asarray(bts), jnp.asarray(cap),
-            jnp.asarray(act), jnp.asarray(drafts), jnp.asarray(n_prop),
-            self._next_key(), jnp.asarray(temps), jnp.asarray(top_ps),
-            jnp.asarray(top_ks), jnp.asarray(rpens), jnp.asarray(rlasts),
-            jnp.asarray(windows))
+        out, dseq, t0, dt = self._run_decode(
+            "spec_verify", self._verify_jit,
+            (self.params, self.kv, jnp.asarray(tokens),
+             jnp.asarray(ctx_lens), jnp.asarray(bts), jnp.asarray(cap),
+             jnp.asarray(act), jnp.asarray(drafts), jnp.asarray(n_prop),
+             self._next_key(), jnp.asarray(temps), jnp.asarray(top_ps),
+             jnp.asarray(top_ks), jnp.asarray(rpens), jnp.asarray(rlasts),
+             jnp.asarray(windows)),
+            rung=b, slots=len(active_seqs),
+            tokens=s_len * len(active_seqs))
         self.kv = out.kv
-        # Stash the (non-blocking) dispatch wall and the cache-read
-        # estimate for whichever caller pushes this round's ledger
-        # record (sync path: after the fold; pipelined: at sync).
-        self._last_verify_dt = self._note_decode_exit(t0, active_seqs)
+        # Stash the (non-blocking) dispatch wall, the dispatch's number
+        # and instant, and the cache-read estimate for whichever caller
+        # pushes this round's ledger record (sync path: after the fold;
+        # pipelined: at sync).
+        self._last_verify_dt = dt
+        self._last_verify = (dseq, t0)
         self._last_verify_kv_read = (
             sum(s.ctx_len for s in active_seqs) * s_len)
         self.spec_rounds_total += 1
@@ -3657,16 +3823,20 @@ class InferenceEngine:
         out, prop_by_slot, rung = self._dispatch_verify(active_seqs,
                                                         proposals, s_len)
         acc0 = self.spec_accepted
+        dseq, t0 = self._last_verify
+        (emitted, n_acc), _, t_done = self._wait(dseq, lambda: (
+            np.asarray(out.emitted), np.asarray(out.n_accepted)))
         result = self._fold_spec_emissions(
             {s.slot: s for s in active_seqs}, emit_by_slot, prop_by_slot,
-            np.asarray(out.emitted), np.asarray(out.n_accepted))
+            emitted, n_acc)
         if self.telemetry.enabled:
             self._ledger_push(
                 "spec_verify", rung=rung, slots=len(active_seqs),
                 tokens=sum(len(t) for t in result.values()),
                 device_s=self._last_verify_dt,
                 kv_read=self._last_verify_kv_read,
-                spec_accepted=self.spec_accepted - acc0)
+                spec_accepted=self.spec_accepted - acc0,
+                seq=dseq, t_enqueue=t0, t_done=t_done)
         return result
 
     def _stage_ngram_call(self) -> Optional[dict]:
@@ -3702,7 +3872,9 @@ class InferenceEngine:
             return None
         out, prop_by_slot, rung = self._dispatch_verify(active_seqs,
                                                         proposals, s_len)
+        dseq, t0 = self._last_verify
         call = {"spec": True, "emitted": out.emitted,
+                "seq": dseq, "t_enqueue": t0,
                 "n_accepted": out.n_accepted,
                 "allowed": dict(emit_by_slot), "n_prop": prop_by_slot,
                 "seqs": {s.slot: s for s in active_seqs},
@@ -3728,20 +3900,14 @@ class InferenceEngine:
     def _sync_spec_call(self, call: dict) -> Dict[int, List[int]]:
         """Block on an in-flight spec round and fold its emissions
         (the _sync_oldest arm for ``spec`` calls)."""
-        t0 = time.perf_counter()
-        emitted = np.asarray(call["emitted"])           # [B, γ+1] blocks
-        n_acc = np.asarray(call["n_accepted"])
-        sync_dt = time.perf_counter() - t0
-        if self.telemetry.enabled:
-            dt = sync_dt
-            self.telemetry.decode_sync_s.observe(dt)
-            for seq in call["seqs"].values():
-                if not seq.done and seq.slot >= 0 \
-                        and self.slots[seq.slot] is seq:
-                    seq.dispatch_wall_s += dt
+        (emitted, n_acc), t0, t_done = self._wait(call["seq"], lambda: (
+            np.asarray(call["emitted"]),                # [B, γ+1] blocks
+            np.asarray(call["n_accepted"])))
+        sync_dt = t_done - t0
+        self.telemetry.decode_sync_s.observe(sync_dt)
         # Device wait, not host bubble (same rationale as _sync_oldest).
         self._last_decode_end = (
-            time.perf_counter()
+            t_done
             if any(s is not None and not s.done for s in self.slots)
             else None)
         acc0 = self.spec_accepted
@@ -3756,7 +3922,9 @@ class InferenceEngine:
                 device_s=led["dispatch_s"] + sync_dt,
                 kv_read=led["kv_read"], staging_s=led["staging_s"],
                 bubble_s=led["bubble_s"], compile_event=led["compile"],
-                spec_accepted=self.spec_accepted - acc0)
+                spec_accepted=self.spec_accepted - acc0,
+                seq=call["seq"], t_enqueue=call["t_enqueue"],
+                t_done=t_done)
         return result
 
     def _ngram_steps_pipelined(self) -> Dict[int, List[int]]:

@@ -22,7 +22,6 @@ inference: pages are append-only within a sequence).
 
 from __future__ import annotations
 
-from functools import partial
 from typing import List, NamedTuple, Optional
 
 import jax
@@ -30,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from tpu_inference import integrity
+from tpu_inference.telemetry import named_program
 from tpu_inference.config import EngineConfig, ModelConfig
 
 
@@ -359,6 +359,15 @@ def _chunk_indices(pages: List[int]):
         yield len(group), idx
 
 
+def _gather_pool(pool: jax.Array, idx: jax.Array) -> jax.Array:
+    """Page gather for the device->host copy: pool[:, idx] (one named
+    program per pool shape, not an anonymous gather per slice)."""
+    return pool[:, idx]
+
+
+_offload_jit = jax.jit(named_program("tpu_inf_kv_offload", _gather_pool))
+
+
 def offload_pages(kv: KVPages, pages: List[int]) -> List[HostKVPage]:
     """Copy ``pages`` out of the device pool into host memory.
 
@@ -374,9 +383,10 @@ def offload_pages(kv: KVPages, pages: List[int]) -> List[HostKVPage]:
     chunks = []
     for count, idx_np in _chunk_indices(pages):
         idx = jnp.asarray(idx_np)
-        arrs = [kv.k[:, idx], kv.v[:, idx]]
+        arrs = [_offload_jit(kv.k, idx), _offload_jit(kv.v, idx)]
         if kv.quantized:
-            arrs += [kv.k_scale[:, idx], kv.v_scale[:, idx]]
+            arrs += [_offload_jit(kv.k_scale, idx),
+                     _offload_jit(kv.v_scale, idx)]
         chunks.append((count, arrs))
     host = jax.device_get([arrs for _, arrs in chunks])
     out: List[HostKVPage] = []
@@ -392,12 +402,15 @@ def offload_pages(kv: KVPages, pages: List[int]) -> List[HostKVPage]:
     return out
 
 
-@partial(jax.jit, donate_argnums=(0,))
 def _scatter_pool(pool: jax.Array, idx: jax.Array,
                   data: jax.Array) -> jax.Array:
     """In-place (donated) page scatter: pool[:, idx] = data. Padding rows
     target page 0 (trash), so duplicate trash indices are harmless."""
     return pool.at[:, idx].set(data)
+
+
+_restore_jit = jax.jit(named_program("tpu_inf_kv_restore", _scatter_pool),
+                       donate_argnums=(0,))
 
 
 def restore_pages(kv: KVPages, pages: List[int],
@@ -425,7 +438,7 @@ def restore_pages(kv: KVPages, pages: List[int],
                             first.dtype)
             for i, hp in enumerate(group):
                 data[:, i] = getattr(hp, host_attr)
-            return _scatter_pool(pool, idx, jnp.asarray(data))
+            return _restore_jit(pool, idx, jnp.asarray(data))
 
         k = _bulk("k", k)
         v = _bulk("v", v)

@@ -411,6 +411,7 @@ class EngineScheduler:
         """Post-prefill bookkeeping shared by the batched and incremental
         paths: counters, first-token delivery, immediate finish."""
         seq = pending.seq
+        self.engine.telemetry.clock.enter("admit")
         self.stats.prefills += 1
         self.stats.tokens_generated += 1
         if not seq.resume_base:
@@ -420,11 +421,15 @@ class EngineScheduler:
             self.stats.tokens_prefix_cached += seq.cached_tokens
         tel = self.engine.telemetry
         if tel.enabled and seq.enqueue_time and not seq.resume_base:
-            # Resume prefills skip the queue-wait histogram: their
+            # Resume prefills skip the queue-wait histograms: their
             # enqueue->prefill gap spans the whole first attempt.
-            tel.queue_wait_s.observe(
-                max(0.0, seq.prefill_start - seq.enqueue_time))
+            boundary, capacity = self._queue_wait_split(seq)
+            tel.queue_wait_s.observe(boundary + capacity)
+            tel.queue_boundary_wait_s.observe(boundary)
+            tel.queue_capacity_wait_s.observe(capacity)
+        tel.clock.enter("deliver")
         pending.on_token(seq, seq.generated[-1])
+        tel.clock.enter("admit")
         if (not seq.done and seq.handoff_after_prefill
                 and self.on_prefill_handoff is not None):
             # P/D disaggregation: the prefill settled — emit the live
@@ -438,6 +443,18 @@ class EngineScheduler:
                 seq.finish_time = time.perf_counter()
         if seq.done:
             self._finish(seq)
+
+    @staticmethod
+    def _queue_wait_split(seq: Sequence) -> tuple:
+        """Queue wait (enqueue -> prefill start) split at the first
+        admission pass that saw the request: (waiting for the running
+        dispatch to come back, then admission work and passes it was
+        turned away for slots or pages). Same timestamps, so the two
+        sum to the queue wait exactly."""
+        enq = seq.enqueue_time
+        start = max(enq, seq.prefill_start or enq)
+        seen = min(max(seq.admit_seen_time or start, enq), start)
+        return seen - enq, start - seen
 
     def _step_incremental_prefill(self) -> None:
         """Advance the in-progress multi-chunk prefill by ONE chunk."""
@@ -499,8 +516,15 @@ class EngineScheduler:
         start_chunked: Optional[_Pending] = None
         start_adopt: Optional[_Pending] = None
         reserved = 0
+        t_pass = self.engine.telemetry.clock.enter("admit")
         with self._lock:
             engine = self.engine
+            # This pass is the first to see whoever queued since the
+            # last one (the queue is bounded and short; a class jump may
+            # have landed one mid-queue, so all are looked at).
+            for pending in self._waiting:
+                if not pending.seq.admit_seen_time:
+                    pending.seq.admit_seen_time = t_pass
             free_slots = len(engine.free_slots())
             bound = sum(s is not None for s in engine.slots)
             base_rung = engine.ladder[0]
@@ -797,8 +821,11 @@ class EngineScheduler:
         rec = tel.recorder
         tid = seq.trace_id or str(seq.request_id)
         if rec.enabled and seq.enqueue_time:
+            boundary, capacity = self._queue_wait_split(seq)
             rec.add("queue_wait", tid, enq, max(enq, start),
-                    admission=self.engine.admission)
+                    admission=self.engine.admission,
+                    boundary_wait_s=round(boundary, 6),
+                    capacity_wait_s=round(capacity, 6))
             if not seq.adopted:
                 rec.add("prefill", tid, start, max(start, first),
                         cached_tokens=seq.cached_tokens,
@@ -892,21 +919,29 @@ class EngineScheduler:
             "e2e_s": round(max(0.0, fin - (seq.enqueue_time
                                            or seq.prefill_start or fin)), 6),
             "ttft_s": round(max(0.0, first - (seq.enqueue_time or first)), 6),
-            # Engine-accrued phase exposure: wall time of device
-            # dispatches this request participated in, and its share of
-            # host-side bubbles between decode calls.
-            "dispatch_wall_s": round(seq.dispatch_wall_s, 6),
-            "bubble_s": round(seq.bubble_s, 6),
             "tpot_s": round((fin - first) / (n_out - 1), 6)
             if n_out > 1 else None,
         }
 
     def _deliver(self, new_tokens: Dict[int, List[int]]) -> None:
+        clock = self.engine.telemetry.clock
+        clock.enter("deliver")
         for rid, toks in new_tokens.items():
             pending = self._callbacks.get(rid)
             if pending is not None:
                 for tok in toks:
                     pending.on_token(pending.seq, tok)
+        clock.enter("other")
+
+    def _reap(self) -> None:
+        """Finish every sequence the loop may finish now."""
+        done = self._reapable()
+        if done:
+            clock = self.engine.telemetry.clock
+            clock.enter("reap")
+            for s in done:
+                self._finish(s)
+            clock.enter("other")
 
     def _reapable(self) -> List[Sequence]:
         """Finished sequences the run loop may finish NOW. A sequence
@@ -920,23 +955,45 @@ class EngineScheduler:
                 if s is not None and s.done and s is not own]
 
     def run(self) -> None:
+        """The engine loop. Every stretch of it runs under a phase of
+        the loop clock (telemetry.LoopClock): the engine's dispatch and
+        sync sites enter stage / enqueue / device_wait / swap /
+        prefix_lookup themselves, this loop names the rest."""
+        clock = self.engine.telemetry.clock
+        clock.start()
+        try:
+            self._run_loop(clock)
+        finally:
+            clock.stop()
+
+    def _run_loop(self, clock) -> None:
         engine = self.engine
         while not self._stop.is_set():
+            # Work for the device exists: what the clock needs to call
+            # host time with nothing in flight "starved" (and what a
+            # loop_stall event reports).
+            clock.waiting = len(self._waiting)
+            clock.active = len(self._callbacks)
+            clock.has_work = bool(clock.waiting or clock.active)
             # Re-read each tick: the recorder may be attached after the
             # engine thread starts (worker boot binds it post-start).
             flight = engine.telemetry.flight
-            if flight is not None:
+            if flight is not None and flight.periodic_due():
                 # Rolling periodic.json refresh — the capture a kill -9
                 # leaves behind (no signal handler runs for SIGKILL).
+                # Only the ledger's ring copy happens on this thread.
+                clock.enter("heartbeat")
                 flight.maybe_periodic()
             # Cross-thread chaos page-pressure requests (/debug/chaos)
             # and migration imports (the worker's import-kv RPC) apply
             # HERE — the allocator and host tier are engine-thread only,
             # and imports must land before admission so a migrated
             # request's prefill sees them.
+            clock.enter("swap")
             engine.apply_pending_page_pressure()
             engine.apply_pending_imports()
             self._admit()
+            clock.enter("other")
             active = engine.active_sequences()
             if not active:
                 # Flush any dispatch-ahead calls, then reap
@@ -946,10 +1003,12 @@ class EngineScheduler:
                     # The drain may have synced a hybrid prefill's final
                     # chunk (e.g. every decode lane finished mid-chunks).
                     self._poll_hybrid_prefill()
-                for s in self._reapable():
-                    self._finish(s)
+                self._reap()
                 if self._prefilling is not None:
                     continue          # next iteration runs the next chunk
+                # Idle also when requests wait that admission turned
+                # away with nothing running: capacity, not the host.
+                clock.enter("idle")
                 if not self._waiting:
                     self._work.clear()
                     self._work.wait(timeout=0.1)
@@ -973,7 +1032,7 @@ class EngineScheduler:
                 # streams out as sampled (no K-token flush bursts). Spec
                 # decode has its own emission cadence; leave it alone.
                 thresh = engine.engine_cfg.latency_decode_threshold
-                t_call = time.perf_counter()
+                t_call = clock.enter("stage")
                 self.step_inflight_since = time.monotonic()
                 if hybrid_pf is not None:
                     # Hybrid step: the in-progress prefill's next chunk
@@ -986,7 +1045,7 @@ class EngineScheduler:
                     new_tokens = engine.decode_steps(max_steps=1)
                 else:
                     new_tokens = engine.decode_steps_pipelined()
-                self.stats.record_decode_call(time.perf_counter() - t_call)
+                self.stats.record_decode_call(clock.enter("other") - t_call)
             except Exception as exc:  # noqa: BLE001 — keep the engine loop alive
                 victims = list(active)
                 if hybrid_pf is not None:
@@ -1031,5 +1090,4 @@ class EngineScheduler:
             # bookkeeping before reaping.
             self._poll_hybrid_prefill()
             self._requeue_preempted()
-            for s in self._reapable():
-                self._finish(s)
+            self._reap()

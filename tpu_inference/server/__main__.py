@@ -443,6 +443,10 @@ def main() -> None:
     p.add_argument("--blackbox-retain", type=int, default=8,
                    help="flight-recorder retention cap: newest N "
                         "trigger captures kept per replica")
+    p.add_argument("--profile-dir", default="/tmp/jax-trace",
+                   help="where POST /debug/profile writes its traces "
+                        "(a per-replica directory under it). Operator-"
+                        "chosen — clients never name a path")
     p.add_argument("--debug", action="store_true",
                    help="expose the unauthenticated /debug/* endpoints "
                         "(request timelines, profiler control)")
@@ -465,6 +469,11 @@ def main() -> None:
     select_platform(args.platform,
                     args.cpu_devices or args.dp * args.tp * args.sp)
     enable_compile_cache()
+    # XLA compile counters on /metrics (listeners only: no backend is
+    # initialised by registering them, so the router stays off the chip).
+    from tpu_inference.telemetry import install_compile_monitor
+
+    install_compile_monitor()
 
     if args.debug_nans:
         import jax
@@ -632,7 +641,8 @@ def main() -> None:
                               rpc_deadline_slow_s=args.rpc_deadline_slow_s,
                               poison_max_workers=args.poison_max_workers,
                               blackbox_dir=args.blackbox_dir,
-                              blackbox_retain=args.blackbox_retain),
+                              blackbox_retain=args.blackbox_retain,
+                              profile_dir=args.profile_dir),
                           step_ledger_depth=args.step_ledger_depth,
                           chaos_step_failure_rate=args.chaos_step_failure_rate,
                           chaos_step_wedge_s=args.chaos_step_wedge_s,

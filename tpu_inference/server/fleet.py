@@ -3020,17 +3020,23 @@ class ProcessEngineGroup:
         return aggregate_replica_stats(per,
                                        self.supervision_counters())
 
-    def steps_snapshot(self) -> dict:
+    def steps_snapshot(self, since: Optional[float] = None,
+                       until: Optional[float] = None,
+                       records: bool = False) -> dict:
         """Step-ledger roofline attribution (GET /debug/steps): live
         per-replica reports (cache fallback for downed workers, same
-        stance as stats_snapshot) + the fleet-merged report."""
+        stance as stats_snapshot) + the fleet-merged report, over the
+        trailing 60 s or the ``since`` / ``until`` interval."""
         reports: Dict[str, dict] = {}
+        default = since is None and until is None and not records
         for h in self.workers:
             d = None
             if h.state == UP and h.client is not None:
                 try:
-                    d = h.client.rpc("steps", timeout=30.0)["steps"]
-                    h.last_steps = d
+                    d = h.client.rpc("steps", timeout=30.0, since=since,
+                                     until=until, records=records)["steps"]
+                    if default:
+                        h.last_steps = d
                 except (WorkerGone, TimeoutError, RuntimeError):
                     d = None
             if d is None and h.last_steps:

@@ -123,12 +123,16 @@ def build_engine_group(cfg: FrameworkConfig, load_params=None,
                   for i in range(pcfg.dp)]
     engines = []
     for mesh in meshes:
+        t_load = time.perf_counter()
         params = load_params(mesh) if load_params else None
         draft_params = (load_draft(mesh)
                         if (load_draft and draft_cfg is not None) else None)
+        load_s = time.perf_counter() - t_load
         engines.append(InferenceEngine(
             cfg.model, cfg.engine, params=params, seed=cfg.seed, mesh=mesh,
             draft_cfg=draft_cfg, draft_params=draft_params))
+        if params is not None:
+            engines[-1].note_checkpoint_load(load_s)
     return EngineGroup(engines, cfg.server)
 
 
@@ -216,6 +220,13 @@ class InferenceServer:
         # start() before the boot prints: the subprocess fleet spawns
         # its workers here, and the prints below read worker-0 facts.
         self.group.start()
+        # Serving: the boot's last gauge, set once (in-process replicas;
+        # a subprocess worker sets its own at the end of its boot).
+        ready_s = telemetry.process_age_s()
+        for engine in self.group.engines:
+            tel = getattr(engine, "telemetry", None)
+            if tel is not None:
+                tel.boot_ready_s.set(ready_s)
         scfg = self.cfg.server
         wd = (f"{scfg.step_watchdog_s:g}s" if scfg.step_watchdog_s > 0
               else "off")
@@ -442,9 +453,23 @@ class InferenceServer:
                                  ) -> web.Response:
         """Step-ledger roofline attribution (README "Performance
         attribution"): per-replica + fleet-merged bottleneck verdicts
-        per step kind, cross-checked against tpu_inf_mfu_estimate."""
-        return web.json_response(
-            await asyncio.to_thread(self.group.steps_snapshot))
+        per step kind.
+
+        No parameters: the trailing 60 s. ``since`` / ``until`` (unix
+        seconds) choose the interval instead; ``records=1`` adds the
+        interval's per-dispatch ledger records to each replica's
+        report."""
+        q = request.query
+        try:
+            since = float(q["since"]) if "since" in q else None
+            until = float(q["until"]) if "until" in q else None
+        except ValueError:
+            raise web.HTTPBadRequest(text=json.dumps(
+                {"error": "'since' and 'until' are unix seconds"}),
+                content_type="application/json")
+        return web.json_response(await asyncio.to_thread(
+            self.group.steps_snapshot, since=since, until=until,
+            records=q.get("records") == "1"))
 
     async def handle_debug_blackbox(self, request: web.Request
                                     ) -> web.Response:
@@ -530,10 +555,12 @@ class InferenceServer:
                 jax.profiler.start_trace(trace_dir)
             except RuntimeError as e:     # already started
                 return web.json_response({"error": str(e)}, status=409)
+            telemetry.set_profile_capturing(True)
             self._profile_dir = trace_dir
             return web.json_response({"status": "tracing",
                                       "dir": trace_dir})
         if action == "stop":
+            telemetry.set_profile_capturing(False)
             try:
                 jax.profiler.stop_trace()
             except RuntimeError as e:

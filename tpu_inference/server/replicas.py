@@ -1010,10 +1010,14 @@ class EngineGroup:
             d["health"] = h.snapshot()
         return aggregate_replica_stats(per, self.supervision_counters())
 
-    def steps_snapshot(self) -> dict:
+    def steps_snapshot(self, since: Optional[float] = None,
+                       until: Optional[float] = None,
+                       records: bool = False) -> dict:
         """Step-ledger roofline attribution (GET /debug/steps):
-        per-replica bottleneck verdicts + the fleet-merged report."""
-        reports = {str(i): e.telemetry.steps_report()
+        per-replica bottleneck verdicts + the fleet-merged report, over
+        the trailing 60 s or the ``since`` / ``until`` interval."""
+        reports = {str(i): e.telemetry.steps_report(
+                       since=since, until=until, records=records)
                    for i, e in enumerate(self.engines)}
         return {"replicas": reports,
                 "fleet": telemetry.merge_steps_reports(

@@ -265,6 +265,9 @@ class EngineWorker:
         from tpu_inference.engine.engine import InferenceEngine
         from tpu_inference.engine.scheduler import EngineScheduler
 
+        from tpu_inference import telemetry as _tm
+
+        _tm.install_compile_monitor()
         cfg = self.cfg
         pcfg = cfg.parallel
         mesh = None
@@ -273,6 +276,7 @@ class EngineWorker:
             from tpu_inference.parallel.mesh import build_mesh
             mesh = build_mesh(ParallelConfig(tp=pcfg.tp, sp=pcfg.sp))
         params = None
+        t_load = time.perf_counter()
         if cfg.checkpoint_path:
             from tpu_inference.models import weights
             shardings = None
@@ -282,14 +286,16 @@ class EngineWorker:
             params = weights.load_checkpoint(
                 cfg.model, cfg.checkpoint_path, shardings=shardings,
                 quant=cfg.engine.quant)
+        load_s = time.perf_counter() - t_load
         self.engine = InferenceEngine(cfg.model, cfg.engine, params=params,
                                       seed=cfg.seed, mesh=mesh)
+        if params is not None:
+            self.engine.note_checkpoint_load(load_s)
         self.sched = EngineScheduler(self.engine)
         # Tracing + dashboard-join series: spans this worker records
         # carry its stable replica index, and the registry emits the
         # build_info gauge with config-pure labels (identical across
         # restarts, so the router's carry never sees a label change).
-        from tpu_inference import telemetry as _tm
         self.engine.telemetry.recorder.replica = self.replica
         _tm.emit_build_info(
             self.engine.telemetry.registry,
@@ -349,6 +355,7 @@ class EngineWorker:
         if self.do_warmup:
             self.warmup_s = self.engine.warmup()
         self.sched.start()
+        self.engine.telemetry.boot_ready_s.set(_tm.process_age_s())
 
     # ------------------------------------------------------------ serve
 
@@ -809,12 +816,15 @@ class EngineWorker:
     def _verb_steps(self, conn, obj, blob) -> dict:
         """Step-ledger roofline report (GET /debug/steps): windowed
         per-step-kind bottleneck verdicts from this replica's ring."""
-        return {"steps": self.engine.telemetry.steps_report()}
+        return {"steps": self.engine.telemetry.steps_report(
+            since=obj.get("since"), until=obj.get("until"),
+            records=bool(obj.get("records")))}
 
     def _verb_metrics(self, conn, obj, blob) -> dict:
         from tpu_inference import telemetry
         return {"samples": telemetry.dump_registry(
-            self.engine.telemetry.registry)}
+            self.engine.telemetry.registry)
+            + telemetry.process_counters_dump()}
 
     def _verb_healthz(self, conn, obj, blob) -> dict:
         e = self.engine

@@ -1076,6 +1076,38 @@ def register_fabric(registry: Registry, pool) -> None:
                    fn=lambda: float(pool.bytes_used))
 
 
+def named_program(name: str, fn: Callable) -> Callable:
+    """``fn`` under a stable name for ``jax.jit``: step programs built
+    from bound methods, partials and lambdas all show as
+    ``jit__unknown`` in a profile. One name per role, not per shape
+    (the name is part of the persistent compile cache's key)."""
+    def program(*args):
+        return fn(*args)
+    program.__name__ = program.__qualname__ = name
+    return program
+
+
+# One process-wide flag: a jax.profiler capture is running. The profiler
+# is per-process (all in-process replicas share one jax runtime), so the
+# flag is too. While it is set every LoopClock phase visit is also a
+# ``tpu_inf/<phase>`` TraceAnnotation and every device dispatch a
+# ``tpu_inf/dispatch`` one, so the .xplane.pb carries the program's own
+# spans on the profiler's clock, in the dispatching thread's line. Off a
+# capture the cost is this one flag test per phase change.
+_profile_capturing = False
+
+
+def set_profile_capturing(on: bool) -> None:
+    """Set by whoever starts/stops a jax.profiler capture
+    (capture_jax_profile, POST /debug/profile's legacy start/stop)."""
+    global _profile_capturing
+    _profile_capturing = bool(on)
+
+
+def profile_capturing() -> bool:
+    return _profile_capturing
+
+
 def capture_jax_profile(profile_dir: str, replica: int,
                         seconds: float) -> Dict[str, Any]:
     """THE jax.profiler capture body behind POST /debug/profile, shared
@@ -1089,9 +1121,11 @@ def capture_jax_profile(profile_dir: str, replica: int,
     trace_dir = os.path.join(profile_dir, f"replica{int(replica)}")
     os.makedirs(trace_dir, exist_ok=True)
     jax.profiler.start_trace(trace_dir)
+    set_profile_capturing(True)
     try:
         time.sleep(seconds)
     finally:
+        set_profile_capturing(False)
         jax.profiler.stop_trace()
     return {"dir": trace_dir, "seconds": seconds,
             "replica": int(replica)}
@@ -1155,7 +1189,16 @@ STEP_FIELDS = (
     "kv_swap_bytes",  # host<->device KV tier traffic since last record
     "spec_accepted",  # speculative positions accepted (spec_verify)
     "compile_event",  # 1 = first dispatch of this rung/bucket (compile)
+    # Appended (positional readers of the fields above do not move):
+    "seq",            # monotone dispatch number (the tpu_inf/dispatch
+                      # annotation's ``seq``: joins ledger to trace)
+    "t_enqueue",      # unix: the jitted call began (program enqueued)
+    "t_done",         # unix: the host first observed its result (a
+                      # readback of it or of a later program); 0 = not
+                      # yet observed
 )
+_I_DEVICE_S = STEP_FIELDS.index("device_s")
+_I_SEQ = STEP_FIELDS.index("seq")
 
 
 class StepLedger:
@@ -1178,14 +1221,34 @@ class StepLedger:
              chunk_tokens: int, steps: int, device_s: float,
              staging_s: float, bubble_s: float, kv_read_tokens: int,
              kv_swap_bytes: float, spec_accepted: int,
-             compile_event: bool) -> None:
+             compile_event: bool, seq: int = 0, t_enqueue: float = 0.0,
+             t_done: float = 0.0) -> None:
         self._ring[self._n % self.depth] = (
             time.time(), kind, int(rung), int(slots), int(tokens),
             int(chunk_tokens), int(steps), float(device_s),
             float(staging_s), float(bubble_s), int(kv_read_tokens),
             float(kv_swap_bytes), int(spec_accepted),
-            1 if compile_event else 0)
+            1 if compile_event else 0, int(seq), float(t_enqueue),
+            float(t_done))
         self._n += 1
+
+    def settle(self, seq: int, t_done: float) -> bool:
+        """Dispatch ``seq`` was pushed at enqueue (a prefill chunk whose
+        token nobody reads back at once); its result has now been
+        observed: fill ``t_done`` and make ``device_s`` the true
+        ``t_done - t_enqueue``. Looks at the newest records only — a
+        chunk settles within a dispatch or two."""
+        n = self._n
+        for k in range(1, min(n, self.depth, 32) + 1):
+            i = (n - k) % self.depth
+            r = self._ring[i]
+            if r is not None and r[_I_SEQ] == seq:
+                self._ring[i] = (r[:_I_DEVICE_S]
+                                 + (max(0.0, t_done - r[_I_SEQ + 1]),)
+                                 + r[_I_DEVICE_S + 1:_I_SEQ + 2]
+                                 + (float(t_done),))
+                return True
+        return False
 
     @property
     def count(self) -> int:
@@ -1219,6 +1282,9 @@ class _NullLedger:
 
     def push(self, *a, **k) -> None:
         pass
+
+    def settle(self, seq: int, t_done: float) -> bool:
+        return False
 
     def records(self) -> List[tuple]:
         return []
@@ -1291,9 +1357,6 @@ class StepCostModel:
                 + float(self.kv_token_bytes) * (rec[10] + positions)
                 + rec[11])                   # kv_swap_bytes
 
-    def as_dict(self) -> Dict[str, Any]:
-        return {k: getattr(self, k) for k in self.__slots__}
-
 
 NOT_MEASURED = "not measured"
 
@@ -1336,47 +1399,26 @@ def _finalize_kind(agg: Dict[str, Any], peak_flops: Optional[float],
     return out
 
 
-def _ledger_mfu_ewma(recs: Sequence[tuple], n_params: int,
-                     peak_flops: Optional[float],
-                     bind_unix: Optional[float],
-                     now: float, tau_s: float = 30.0) -> Optional[float]:
-    """Replay the MFU gauge's dt-weighted EWMA (telemetry bind_scheduler:
-    alpha = 1 - exp(-dt/tau), tau ≈ 30 s) over the ledger's (ts, tokens)
-    events, from the gauge's bind time — the apples-to-apples value the
-    /debug/steps cross-check compares against ``tpu_inf_mfu_estimate``.
-    A plain window-average would NOT agree with the gauge over short
-    windows; the EWMA replay does, up to ring truncation (flagged by the
-    caller via ``truncated``)."""
-    import math
-
-    if not recs or not peak_flops:
-        return None
-    rate = 0.0
-    t = bind_unix if bind_unix is not None else recs[0][0]
-    for r in recs:
-        ts, tokens = r[0], r[4]
-        dt = max(1e-6, ts - t)
-        inst = tokens / dt
-        rate += (1.0 - math.exp(-dt / tau_s)) * (inst - rate)
-        t = ts
-    dt = now - t
-    if dt > 1e-3:
-        rate *= math.exp(-dt / tau_s)   # zero-rate tail, gauge-identical
-    return rate * 2.0 * n_params / peak_flops
-
-
 def roofline_report(ledger, model: StepCostModel, *,
                     mfu_gauge: Optional[float] = None,
-                    bind_unix: Optional[float] = None,
                     window_s: float = 60.0,
-                    now: Optional[float] = None) -> Dict[str, Any]:
+                    now: Optional[float] = None,
+                    since: Optional[float] = None,
+                    until: Optional[float] = None,
+                    records: bool = False) -> Dict[str, Any]:
     """One replica's step-attribution report: per-kind roofline sums +
-    bottleneck verdicts over the trailing window, per-rung occupancy,
-    the top time sinks, and the ledger-replayed MFU cross-check."""
+    bottleneck verdicts over the trailing window, per-rung occupancy
+    and the MFU gauge's reading.
+
+    ``since`` / ``until`` (unix seconds, on the records' ``ts``) choose
+    the interval instead of the trailing ``window_s``; ``records`` adds
+    the interval's per-dispatch records themselves (STEP_FIELDS dicts),
+    so a caller can have exactly its own measured window."""
     now = time.time() if now is None else now
     recs = ledger.records()
-    cutoff = now - window_s
-    window = [r for r in recs if r[0] >= cutoff]
+    lo = now - window_s if since is None else since
+    hi = float("inf") if until is None else until
+    window = [r for r in recs if lo <= r[0] <= hi]
     kinds: Dict[str, Dict[str, Any]] = {}
     rungs: Dict[str, Dict[str, float]] = {}
     for r in window:
@@ -1411,24 +1453,11 @@ def roofline_report(ledger, model: StepCostModel, *,
                         "mean_slots": round(ra["slots_sum"]
                                             / max(ra["dispatches"], 1), 2)}
                  for rung, ra in rungs.items()}
-    sinks = sorted(
-        ({"sink": f"{k}.{comp}", "seconds": v[f"{comp}_s"]}
-         for k, v in kinds.items() for comp in ("device", "staging",
-                                                "bubble")
-         if v[f"{comp}_s"] > 0),
-        key=lambda s: -s["seconds"])[:3]
-    ledger_mfu = _ledger_mfu_ewma(recs, model.n_params, model.peak_flops,
-                                  bind_unix, now)
-    mfu = {"gauge": mfu_gauge,
-           "ledger": None if ledger_mfu is None else round(ledger_mfu, 12)}
-    if mfu_gauge and ledger_mfu is not None and mfu_gauge > 0:
-        mfu["agreement"] = round(ledger_mfu / mfu_gauge, 4)
-    else:
-        mfu["agreement"] = None
-    return {
+    out = {
         "enabled": True,
         "ts": round(now, 3),
-        "window_s": window_s,
+        "window_s": window_s if since is None and until is None
+        else round(min(hi, now) - lo, 3),
         "records_window": len(window),
         "records_total": ledger.count,
         "ledger_depth": ledger.depth,
@@ -1437,10 +1466,12 @@ def roofline_report(ledger, model: StepCostModel, *,
                   "hbm_bytes_per_s": model.peak_hbm_bw},
         "kinds": kinds,
         "rung_occupancy": occupancy,
-        "top_sinks": sinks,
         "compile_events": sum(r[13] for r in window),
-        "mfu": mfu,
+        "mfu": {"gauge": mfu_gauge},
     }
+    if records:
+        out["records"] = [dict(zip(STEP_FIELDS, r)) for r in window]
+    return out
 
 
 # Raw per-kind sums merge_steps_reports re-accumulates before
@@ -1455,9 +1486,9 @@ def merge_steps_reports(reports: Sequence[Optional[Dict[str, Any]]]
                         ) -> Dict[str, Any]:
     """Fleet-merged step attribution from per-replica reports: per-kind
     raw sums re-finalized (verdicts recomputed over the pooled window —
-    fractions and verdicts do not average), occupancy pooled, MFU gauge
-    and ledger replay averaged across replicas (MFU is a per-chip
-    utilization; the fleet runs dp chips)."""
+    fractions and verdicts do not average), occupancy pooled, the MFU
+    gauge averaged across replicas (MFU is a per-chip utilization; the
+    fleet runs dp chips)."""
     reports = [r for r in reports if r and r.get("enabled")]
     if not reports:
         return {"enabled": False}
@@ -1483,24 +1514,10 @@ def merge_steps_reports(reports: Sequence[Optional[Dict[str, Any]]]
                         "mean_slots": round(ra["slots_sum"]
                                             / max(ra["dispatches"], 1), 2)}
                  for rung, ra in rungs.items()}
-    sinks = sorted(
-        ({"sink": f"{k}.{comp}", "seconds": v[f"{comp}_s"]}
-         for k, v in kinds.items() for comp in ("device", "staging",
-                                                "bubble")
-         if v[f"{comp}_s"] > 0),
-        key=lambda s: -s["seconds"])[:3]
     gauges = [r["mfu"].get("gauge") for r in reports
               if (r.get("mfu") or {}).get("gauge") is not None]
-    ledgers = [r["mfu"].get("ledger") for r in reports
-               if (r.get("mfu") or {}).get("ledger") is not None]
     mfu = {"gauge": round(sum(gauges) / len(gauges), 12) if gauges
-           else None,
-           "ledger": round(sum(ledgers) / len(ledgers), 12) if ledgers
            else None}
-    if mfu["gauge"] and mfu["ledger"] is not None and mfu["gauge"] > 0:
-        mfu["agreement"] = round(mfu["ledger"] / mfu["gauge"], 4)
-    else:
-        mfu["agreement"] = None
     return {
         "enabled": True,
         "replicas_merged": len(reports),
@@ -1512,7 +1529,6 @@ def merge_steps_reports(reports: Sequence[Optional[Dict[str, Any]]]
         "peaks": {"flops_per_s": peak_flops, "hbm_bytes_per_s": peak_bw},
         "kinds": kinds,
         "rung_occupancy": occupancy,
-        "top_sinks": sinks,
         "compile_events": sum(r.get("compile_events", 0)
                               for r in reports),
         "mfu": mfu,
@@ -1557,6 +1573,11 @@ class FlightRecorder:
         self.stats_fn = stats_fn
         self.periodic_interval_s = max(0.5, float(periodic_interval_s))
         self._last_periodic = 0.0
+        # Heartbeats taken on the caller's (engine) thread, and those
+        # among them skipped because the previous write was still out.
+        self.beats = 0
+        self.beats_skipped = 0
+        self._beat_thread: Optional[threading.Thread] = None
         self._last_by_trigger: Dict[str, float] = {}
         self._lock = threading.Lock()
         self._seq = 0
@@ -1588,13 +1609,26 @@ class FlightRecorder:
         except OSError:
             pass
 
-    def _payload(self, trigger: str) -> Dict[str, Any]:
+    def _steps_raw(self) -> list:
+        """The step section as ``steps_fn`` hands it over: the ledger's
+        raw record tuples (a ring copy — the one part of a capture that
+        has to be taken on the engine thread) or ready dicts."""
+        try:
+            return self.steps_fn() if self.steps_fn is not None else []
+        except Exception:
+            return []
+
+    def _payload(self, trigger: str,
+                 steps: Optional[list] = None) -> Dict[str, Any]:
         payload: Dict[str, Any] = {
             "ts": round(time.time(), 3), "replica": self.replica,
             "pid": os.getpid(), "trigger": trigger,
             "config": self.config}
-        for key, fn, empty in (("steps", self.steps_fn, []),
-                               ("spans", self.spans_fn, []),
+        if steps is None:
+            steps = self._steps_raw()
+        payload["steps"] = [dict(zip(STEP_FIELDS, r))
+                            if isinstance(r, tuple) else r for r in steps]
+        for key, fn, empty in (("spans", self.spans_fn, []),
                                ("stats", self.stats_fn, {})):
             try:
                 payload[key] = fn() if fn is not None else empty
@@ -1642,18 +1676,47 @@ class FlightRecorder:
             except OSError:
                 pass
 
-    def maybe_periodic(self) -> None:
-        """Cheap scheduler-loop hook: refresh the heartbeat capture at
-        most once per interval (two float compares otherwise)."""
-        now = time.time()
-        if now - self._last_periodic < self.periodic_interval_s:
-            return
-        self._last_periodic = now
+    def periodic_due(self) -> bool:
+        """Scheduler-loop hook, every iteration: is a heartbeat due
+        (one clock read and a compare)?"""
+        return time.time() - self._last_periodic >= self.periodic_interval_s
+
+    def maybe_periodic(self) -> bool:
+        """Refresh the heartbeat capture at most once per interval.
+        Only the ledger's ring copy happens here, on the caller's
+        (engine) thread; building the dicts, the spans, the stats,
+        ``json.dump``, ``fsync`` and the rename run on a daemon thread,
+        one write outstanding at most — a beat that finds the previous
+        one still out is skipped and counted. True = a beat was due."""
+        if not self.periodic_due():
+            return False
+        self._last_periodic = time.time()
+        self.beats += 1
+        if self._beat_thread is not None and self._beat_thread.is_alive():
+            self.beats_skipped += 1
+            return True
+        steps = self._steps_raw()
+        self._beat_thread = threading.Thread(
+            target=self._write_beat, args=(steps,),
+            name="blackbox-heartbeat", daemon=True)
+        self._beat_thread.start()
+        return True
+
+    def _write_beat(self, steps: list) -> None:
         try:
             self._write(os.path.join(self.dir, "periodic.json"),
-                        self._payload("periodic"))
+                        self._payload("periodic", steps))
         except Exception:
             pass
+
+    def join_beat(self, timeout: float = 5.0) -> bool:
+        """Wait for the outstanding heartbeat write, if any; True when
+        none is left."""
+        t = self._beat_thread
+        if t is not None:
+            t.join(timeout)
+            return not t.is_alive()
+        return True
 
     def install_atexit(self) -> None:
         import atexit
@@ -1727,7 +1790,7 @@ def attach_flight_recorder(tel: "EngineTelemetry", root_dir: str,
         return spans
 
     fr = FlightRecorder(root_dir, replica, retain=retain, config=config,
-                        steps_fn=lambda: tel.step_ledger.snapshot(),
+                        steps_fn=lambda: tel.step_ledger.records(),
                         spans_fn=spans_fn, stats_fn=stats_fn)
     tel.flight = fr
     fr.install_atexit()
@@ -1753,6 +1816,323 @@ def attach_router_flight_recorder(
 
 
 # ---------------------------------------------------------------------------
+# Loop phase clock (README "Observability": loop phases). ONE clock for
+# the engine thread: ``enter(phase)`` closes the phase being left and
+# opens the next, so the phases are an exclusive and complete partition
+# of the loop's wall — every idle gap, stall and host cost has a name
+# from inside the program, with the profiler off, for the whole run.
+# ---------------------------------------------------------------------------
+
+# phase -> its /metrics family (no labels: scrape parsers sum labels
+# away, so each phase is a family of its own).
+LOOP_FAMILIES = {
+    # nothing to do (the work-event wait)
+    "idle": "tpu_inf_loop_idle_seconds_total",
+    # admission passes, page accounting, requeues
+    "admit": "tpu_inf_loop_admit_seconds_total",
+    # prefix-cache lookup for an admitted prompt
+    "prefix_lookup": "tpu_inf_loop_prefix_lookup_seconds_total",
+    # host arrays + device_put for the next dispatch
+    "stage": "tpu_inf_loop_stage_seconds_total",
+    # inside the jitted call (returns once enqueued)
+    "enqueue": "tpu_inf_loop_enqueue_seconds_total",
+    # blocked in a readback of a dispatched program
+    "device_wait": "tpu_inf_loop_device_wait_seconds_total",
+    # token callbacks to the HTTP side
+    "deliver": "tpu_inf_loop_deliver_seconds_total",
+    # finish: release pages/slot, histograms, spans
+    "reap": "tpu_inf_loop_reap_seconds_total",
+    # host-tier offload / restore / imports
+    "swap": "tpu_inf_loop_swap_seconds_total",
+    # flight recorder's periodic beat (ring copy)
+    "heartbeat": "tpu_inf_loop_heartbeat_seconds_total",
+    # everything between the named sites
+    "other": "tpu_inf_loop_other_seconds_total",
+}
+LOOP_PHASES = tuple(LOOP_FAMILIES)
+# A single visit (not idle) longer than this is a stall: counted, summed
+# and logged once with what the loop was doing.
+LOOP_STALL_S = 1.0
+# Phases in which an empty device is not the host's doing: nothing to
+# run, or the host is itself waiting for the device.
+_NOT_STARVING = frozenset(("idle", "device_wait"))
+
+
+class LoopClock:
+    """Exclusive phase partition of one engine thread's wall.
+
+    Driven from ``EngineScheduler.run`` and the engine's dispatch / sync
+    sites. ``enter`` costs one clock read and one float add; it returns
+    the instant, which callers use in place of clock reads of their own
+    (staging / bubble / dispatch / sync walls come from the same stamps
+    as the phases, so they cannot disagree). The clock accrues only
+    between ``start`` and ``stop`` — an engine driven directly (tests,
+    offline generate) still gets instants from ``enter`` but leaves no
+    open visit behind to grow into a false stall.
+
+    ``starved_s`` is the program's own statement of "the device sat idle
+    because of the host": seconds of phases other than idle/device_wait
+    entered while no dispatched program was unobserved (nothing in
+    flight) and the scheduler had work (``has_work``).
+    """
+
+    def __init__(self, now: Callable[[], float] = time.perf_counter):
+        self._now = now
+        self.seconds: Dict[str, float] = dict.fromkeys(LOOP_PHASES, 0.0)
+        self.phase: Optional[str] = None      # None = not running
+        self._t = 0.0
+        self._starving = False
+        self._ann = None                      # open TraceAnnotation
+        self.starved_s = 0.0
+        self.stalls = 0
+        self.stall_s = 0.0
+        # Set by the scheduler each iteration: a sequence is active or
+        # waiting; and the counts a stall log line carries.
+        self.has_work = False
+        self.active = 0
+        self.waiting = 0
+        # Dispatch numbers: the newest program enqueued, the newest whose
+        # result the host has observed (programs run in order on the
+        # device, so observing N settles everything before it).
+        self.dispatched_seq = 0
+        self.observed_seq = 0
+
+    # ------------------------------------------------------------ driving
+
+    def start(self) -> float:
+        self.phase, self._t = "other", self._now()
+        self._starving = False
+        return self._t
+
+    def stop(self) -> None:
+        if self.phase is not None:
+            self.enter("other")
+            self.phase = None
+        self._close_annotation()
+
+    def enter(self, phase: str) -> float:
+        now = self._now()
+        prev = self.phase
+        if prev is None:                      # not running: only the time
+            return now
+        dt = now - self._t
+        self.seconds[prev] += dt
+        if self._starving:
+            self.starved_s += dt
+        if dt > LOOP_STALL_S and prev != "idle":
+            self._stall(prev, dt)
+        self.phase = phase
+        self._t = now
+        self._starving = (self.has_work
+                          and self.dispatched_seq <= self.observed_seq
+                          and phase not in _NOT_STARVING)
+        if _profile_capturing or self._ann is not None:
+            self._annotate(phase)
+        return now
+
+    def dispatched(self, seq: int) -> float:
+        """The jitted call for dispatch ``seq`` returned: it is in
+        flight. Leaves the enqueue phase; returns the instant."""
+        self.dispatched_seq = seq
+        return self.enter("other")
+
+    def observed(self, seq: int) -> None:
+        """The host read back a result of dispatch ``seq``."""
+        if seq > self.observed_seq:
+            self.observed_seq = seq
+
+    @property
+    def in_flight(self) -> bool:
+        return self.dispatched_seq > self.observed_seq
+
+    # ----------------------------------------------------------- internals
+
+    def _stall(self, phase: str, dt: float) -> None:
+        self.stalls += 1
+        self.stall_s += dt
+        log_event("loop_stall", level="warning", phase=phase,
+                  seconds=round(dt, 4), dispatch=self.dispatched_seq,
+                  in_flight=self.in_flight, active=self.active,
+                  waiting=self.waiting)
+
+    def _close_annotation(self) -> None:
+        ann, self._ann = self._ann, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
+
+    def _annotate(self, phase: str) -> None:
+        self._close_annotation()
+        if _profile_capturing:
+            import jax.profiler
+            self._ann = jax.profiler.TraceAnnotation("tpu_inf/" + phase)
+            self._ann.__enter__()
+
+    # ------------------------------------------------------------- export
+
+    def total_s(self) -> float:
+        return sum(self.seconds.values())
+
+    def register(self, registry: Registry) -> None:
+        """One family per phase + their sum + starved / stall series."""
+        for p, family in LOOP_FAMILIES.items():
+            registry.counter(
+                family,
+                f"Engine-loop wall spent in phase '{p}' (exclusive "
+                "partition: the phase families sum to "
+                "tpu_inf_loop_seconds_total)",
+                fn=lambda p=p: self.seconds[p])
+        registry.counter(
+            "tpu_inf_loop_seconds_total",
+            "Engine-loop wall accounted by the phase clock (sum of the "
+            "tpu_inf_loop_<phase>_seconds_total families)",
+            fn=self.total_s)
+        registry.counter(
+            "tpu_inf_loop_starved_seconds_total",
+            "Loop wall outside idle/device_wait spent while no "
+            "dispatched program was in flight and a sequence was "
+            "active or waiting (the device idle because of the host)",
+            fn=lambda: self.starved_s)
+        registry.counter(
+            "tpu_inf_loop_stalls_total",
+            f"Single phase visits (not idle) longer than "
+            f"{LOOP_STALL_S:g}s; each also logs one loop_stall event",
+            fn=lambda: self.stalls)
+        registry.counter(
+            "tpu_inf_loop_stall_seconds_total",
+            "Wall of the visits counted in tpu_inf_loop_stalls_total",
+            fn=lambda: self.stall_s)
+
+
+class _NullClock:
+    """The clock with telemetry off: accrues and exports nothing. It
+    still tells the time — callers use ``enter`` in place of their own
+    clock reads."""
+
+    __slots__ = ()
+    phase = None
+    in_flight = False
+    has_work = False
+    active = 0
+    waiting = 0
+
+    def __setattr__(self, name, value) -> None:
+        pass
+
+    def start(self) -> float:
+        return time.perf_counter()
+
+    def stop(self) -> None:
+        pass
+
+    def enter(self, phase: str) -> float:
+        return time.perf_counter()
+
+    def dispatched(self, seq: int) -> float:
+        return time.perf_counter()
+
+    def observed(self, seq: int) -> None:
+        pass
+
+
+NULL_CLOCK = _NullClock()
+
+
+# ---------------------------------------------------------------------------
+# XLA compile counters (README "Observability"): jax.monitoring fires
+# '/jax/core/compile/backend_compile_duration' once per compile REQUEST
+# (a persistent-cache hit included: the event wraps compile-or-get-
+# cached) and '/jax/compilation_cache/cache_hits' once per hit, so hits
+# are counted apart and real compiles = requests - hits. Process-wide,
+# like the compiler; a scrape between two instants says whether
+# anything compiled in between, with no log parsing.
+# ---------------------------------------------------------------------------
+
+_XLA_FAMILIES = ("tpu_inf_xla_compiles_total",
+                 "tpu_inf_xla_compile_seconds_total",
+                 "tpu_inf_xla_cache_hits_total")
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_xla_monitor: Optional["XlaCompileMonitor"] = None
+
+
+class XlaCompileMonitor:
+    def __init__(self):
+        self.compiles = 0
+        self.compile_s = 0.0
+        self.cache_hits = 0
+
+    def on_duration(self, event: str, duration: float, **kw) -> None:
+        if event == _COMPILE_EVENT:
+            self.compiles += 1
+            self.compile_s += duration
+
+    def on_event(self, event: str, **kw) -> None:
+        if event == _CACHE_HIT_EVENT:
+            self.cache_hits += 1
+
+    def snapshot(self) -> Tuple[int, float, int]:
+        return self.compiles, self.compile_s, self.cache_hits
+
+
+def install_compile_monitor() -> XlaCompileMonitor:
+    """Register the jax.monitoring listeners once per process (server
+    main and worker boot call this; repeat calls return the same
+    monitor) and expose its counters on every scrape of this process."""
+    global _xla_monitor
+    if _xla_monitor is None:
+        import jax.monitoring
+
+        mon = XlaCompileMonitor()
+        jax.monitoring.register_event_duration_secs_listener(
+            mon.on_duration)
+        jax.monitoring.register_event_listener(mon.on_event)
+        _SELF_REGISTRY.counter(
+            "tpu_inf_xla_compiles_total",
+            "XLA compile requests in this process (persistent-cache "
+            "hits included; see tpu_inf_xla_cache_hits_total)",
+            fn=lambda: mon.compiles)
+        _SELF_REGISTRY.counter(
+            "tpu_inf_xla_compile_seconds_total",
+            "Wall inside XLA compile requests (compile, or the "
+            "persistent cache's retrieval on a hit)",
+            fn=lambda: mon.compile_s)
+        _SELF_REGISTRY.counter(
+            "tpu_inf_xla_cache_hits_total",
+            "Compile requests served from the persistent compilation "
+            "cache", fn=lambda: mon.cache_hits)
+        _xla_monitor = mon
+    return _xla_monitor
+
+
+def compile_monitor() -> Optional[XlaCompileMonitor]:
+    return _xla_monitor
+
+
+def process_counters_dump() -> List[Dict[str, Any]]:
+    """This process's XLA compile counters as dump_registry samples: a
+    subprocess worker appends them to its registry dump so they reach
+    the router's scrape under the worker's replica label."""
+    return [rec for rec in dump_registry(_SELF_REGISTRY)
+            if rec["name"] in _XLA_FAMILIES]
+
+
+def process_age_s() -> float:
+    """Seconds since this process started, by the OS's own record
+    (/proc): imports before this module loaded are counted too."""
+    try:
+        with open("/proc/self/stat") as f:
+            start_ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            uptime = float(f.read().split()[0])
+        return max(0.0, uptime - start_ticks / os.sysconf("SC_CLK_TCK"))
+    except (OSError, ValueError, IndexError):
+        return time.time() - _IMPORT_UNIX
+
+
+_IMPORT_UNIX = time.time()
+
+
+# ---------------------------------------------------------------------------
 # Engine-side bundle
 # ---------------------------------------------------------------------------
 
@@ -1769,6 +2149,8 @@ PHASE_HISTOGRAMS = {
     "kv_swap_s": "kv_swap_s",
     "spec_acceptance_rate": "spec_accept_rate",
     "queue_wait_s": "queue_wait_s",
+    "queue_boundary_wait_s": "queue_boundary_wait_s",
+    "queue_capacity_wait_s": "queue_capacity_wait_s",
     "prefill_phase_s": "prefill_phase_s",
     "decode_phase_s": "decode_phase_s",
     "ttft_s": "ttft_s",
@@ -1825,6 +2207,8 @@ class EngineTelemetry:
         self.step_ledger = NULL_LEDGER
         self.cost_model: Optional[StepCostModel] = None
         self.flight: Optional[FlightRecorder] = None
+        # The engine thread's phase clock (the null object when off).
+        self.clock = LoopClock() if self.enabled else NULL_CLOCK
         if not self.enabled:
             for attr in PHASE_HISTOGRAMS.values():
                 setattr(self, attr, NULL_METRIC)
@@ -1836,9 +2220,36 @@ class EngineTelemetry:
             self.kv_restore_pages = NULL_METRIC
             self.kv_offload_bytes = NULL_METRIC
             self.kv_restore_bytes = NULL_METRIC
+            self.boot_weights_s = self.boot_pool_s = NULL_METRIC
+            self.boot_warmup_s = self.boot_ready_s = NULL_METRIC
             return
         r = self.registry
         register_span_ring(r, self.recorder)
+        self.clock.register(r)
+        r.counter("tpu_inf_loop_heartbeats_total",
+                  "Flight-recorder heartbeats taken by the engine loop "
+                  "(each one visit of the heartbeat phase)",
+                  fn=lambda: self.flight.beats if self.flight else 0)
+        r.counter("tpu_inf_heartbeats_skipped_total",
+                  "Heartbeats skipped because the previous beat's file "
+                  "write was still outstanding on the writer thread",
+                  fn=lambda: (self.flight.beats_skipped
+                              if self.flight else 0))
+        # Boot phases, set once (README "Observability": boot).
+        self.boot_weights_s = r.gauge(
+            "tpu_inf_boot_weights_seconds",
+            "Boot: weights initialised or loaded, quantised and "
+            "enqueued to the device (host wall; boot adds no sync)")
+        self.boot_pool_s = r.gauge(
+            "tpu_inf_boot_pool_seconds",
+            "Boot: KV page pool allocation enqueued (host wall)")
+        self.boot_warmup_s = r.gauge(
+            "tpu_inf_boot_warmup_seconds",
+            "Boot: all warm-up graphs compiled or fetched from the "
+            "persistent cache, and run")
+        self.boot_ready_s = r.gauge(
+            "tpu_inf_boot_ready_seconds",
+            "Boot: process start to serving")
         self.prefill_dispatch_s = r.histogram(
             "tpu_inf_prefill_dispatch_seconds",
             "Host wall time of one prefill dispatch")
@@ -1896,6 +2307,17 @@ class EngineTelemetry:
         self.queue_wait_s = r.histogram(
             "tpu_inf_queue_wait_seconds",
             "Request admission queue wait (enqueue -> prefill start)")
+        self.queue_boundary_wait_s = r.histogram(
+            "tpu_inf_queue_boundary_wait_seconds",
+            "Queue wait, first part: enqueue -> the first admission "
+            "pass that saw the request (waiting for the running "
+            "dispatch to come back)")
+        self.queue_capacity_wait_s = r.histogram(
+            "tpu_inf_queue_capacity_wait_seconds",
+            "Queue wait, second part: first admission pass -> prefill "
+            "start (admission work, and passes the request was turned "
+            "away for slots or pages); the two parts sum to "
+            "tpu_inf_queue_wait_seconds")
         self.prefill_phase_s = r.histogram(
             "tpu_inf_prefill_phase_seconds",
             "Request prefill phase (prefill start -> first token)")
@@ -2117,10 +2539,6 @@ class EngineTelemetry:
         tau_s = 30.0
         state = {"tokens": stats.tokens_generated,
                  "t": time.perf_counter(), "rate": 0.0}
-        # Wall-clock EWMA epoch: the /debug/steps cross-check replays
-        # this gauge's smoothing over the step ledger's timestamps, and
-        # both must integrate from the same origin to agree.
-        self._mfu_bind_unix = time.time()
 
         def _mfu() -> float:
             now = time.perf_counter()
@@ -2145,20 +2563,22 @@ class EngineTelemetry:
         against)."""
         g = getattr(self, "_mfu_gauge", None)
         # 12 decimals, not 6: a small model on a big chip sits at MFU
-        # ~1e-9, and the /debug/steps agreement cross-check needs the
-        # ratio, not a rounded-to-zero pair.
+        # ~1e-9.
         return round(g.collect_value(), 12) if g is not None else None
 
-    def steps_report(self, window_s: float = 60.0) -> Dict[str, Any]:
+    def steps_report(self, window_s: float = 60.0,
+                     since: Optional[float] = None,
+                     until: Optional[float] = None,
+                     records: bool = False) -> Dict[str, Any]:
         """This replica's step-attribution report (the ``steps`` worker
-        RPC verb / GET /debug/steps body)."""
+        RPC verb / GET /debug/steps body; ``since`` / ``until`` /
+        ``records`` are its query parameters)."""
         if not self.enabled or self.cost_model is None:
             return {"enabled": False}
         return roofline_report(
             self.step_ledger, self.cost_model,
             mfu_gauge=self.mfu_estimate(),
-            bind_unix=getattr(self, "_mfu_bind_unix", None),
-            window_s=window_s)
+            window_s=window_s, since=since, until=until, records=records)
 
     def request_finished(self, reason: str) -> None:
         """Per-finish-reason counter (lazy label children)."""
