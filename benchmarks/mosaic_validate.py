@@ -26,9 +26,13 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
+import sys
 import time
 
 import numpy as np
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def main() -> int:
@@ -61,8 +65,14 @@ def main() -> int:
     b = 3
     n_pages = 32
     kv_lens = np.array([5, 17, 41], np.int32)
-    k_pool = rng.standard_normal((n_pages, page, hkv, d)).astype(np.float32)
-    v_pool = rng.standard_normal((n_pages, page, hkv, d)).astype(np.float32)
+    # The kernels take the engine's stacked pool [L, P, page, Hkv, D]
+    # and the layer to read (a quantized pool's scales: that layer's).
+    # Three layers of different contents; every check reads the last.
+    layers, layer = 3, 2
+    k_pool = rng.standard_normal(
+        (layers, n_pages, page, hkv, d)).astype(np.float32)
+    v_pool = rng.standard_normal(
+        (layers, n_pages, page, hkv, d)).astype(np.float32)
     bt = rng.permutation(np.arange(1, 1 + b * mp)).reshape(b, mp).astype(
         np.int32)
 
@@ -101,10 +111,10 @@ def main() -> int:
 
     def swa_decode():
         got = paged_attention(jnp.asarray(q1), jnp.asarray(k_pool),
-                              jnp.asarray(v_pool), jnp.asarray(bt),
+                              jnp.asarray(v_pool), layer, jnp.asarray(bt),
                               jnp.asarray(kv_lens), None, None,
                               sliding_window=window, interpret=False)
-        want = decode_ref(k_pool, v_pool, q1)
+        want = decode_ref(k_pool[layer], v_pool[layer], q1)
         if not np.allclose(np.asarray(got), want, rtol=2e-2, atol=2e-2):
             return f"max abs err {np.abs(np.asarray(got) - want).max():.2e}"
         return None
@@ -112,12 +122,13 @@ def main() -> int:
     def swa_decode8():
         kq, ks = kvc.quantize_kv(jnp.asarray(k_pool))
         vq, vs = kvc.quantize_kv(jnp.asarray(v_pool))
-        got = paged_attention(jnp.asarray(q1), kq, vq, jnp.asarray(bt),
-                              jnp.asarray(kv_lens), ks, vs,
+        got = paged_attention(jnp.asarray(q1), kq, vq, layer,
+                              jnp.asarray(bt), jnp.asarray(kv_lens),
+                              ks[layer], vs[layer],
                               sliding_window=window, interpret=False)
         kd = np.asarray(kq, np.float32) * np.asarray(ks)[..., None]
         vd = np.asarray(vq, np.float32) * np.asarray(vs)[..., None]
-        want = decode_ref(kd, vd, q1)
+        want = decode_ref(kd[layer], vd[layer], q1)
         if not np.allclose(np.asarray(got), want, rtol=5e-2, atol=5e-2):
             return f"max abs err {np.abs(np.asarray(got) - want).max():.2e}"
         return None
@@ -128,14 +139,15 @@ def main() -> int:
         # Mosaic, not just interpret mode.
         kq, ks = kvc.quantize_kv_int4(jnp.asarray(k_pool))
         vq, vs = kvc.quantize_kv_int4(jnp.asarray(v_pool))
-        got = paged_attention(jnp.asarray(q1), kq, vq, jnp.asarray(bt),
-                              jnp.asarray(kv_lens), ks, vs,
+        got = paged_attention(jnp.asarray(q1), kq, vq, layer,
+                              jnp.asarray(bt), jnp.asarray(kv_lens),
+                              ks[layer], vs[layer],
                               sliding_window=window, interpret=False)
         kd = np.asarray(kvc.unpack_int4_kv(kq), np.float32) \
             * np.asarray(ks)[..., None]
         vd = np.asarray(kvc.unpack_int4_kv(vq), np.float32) \
             * np.asarray(vs)[..., None]
-        want = decode_ref(kd, vd, q1)
+        want = decode_ref(kd[layer], vd[layer], q1)
         if not np.allclose(np.asarray(got), want, rtol=5e-2, atol=5e-2):
             return f"max abs err {np.abs(np.asarray(got) - want).max():.2e}"
         return None
@@ -145,8 +157,10 @@ def main() -> int:
     pf_lens = (q_off + s).astype(np.int32)
     mp_pf = 8
     n_pages_pf = 64
-    k_pf = rng.standard_normal((n_pages_pf, page, hkv, d)).astype(np.float32)
-    v_pf = rng.standard_normal((n_pages_pf, page, hkv, d)).astype(np.float32)
+    k_pf = rng.standard_normal(
+        (layers, n_pages_pf, page, hkv, d)).astype(np.float32)
+    v_pf = rng.standard_normal(
+        (layers, n_pages_pf, page, hkv, d)).astype(np.float32)
     bt_pf = rng.permutation(np.arange(1, 1 + b * mp_pf)).reshape(
         b, mp_pf).astype(np.int32)
     qs = rng.standard_normal((b, s, hq, d)).astype(np.float32)
@@ -165,10 +179,10 @@ def main() -> int:
 
     def swa_prefill():
         got = paged_prefill_attention(
-            jnp.asarray(qs), jnp.asarray(k_pf), jnp.asarray(v_pf),
+            jnp.asarray(qs), jnp.asarray(k_pf), jnp.asarray(v_pf), layer,
             jnp.asarray(bt_pf), jnp.asarray(pf_lens), jnp.asarray(q_off),
             None, None, block_q=8, sliding_window=window, interpret=False)
-        want = prefill_ref(k_pf, v_pf)
+        want = prefill_ref(k_pf[layer], v_pf[layer])
         if not np.allclose(np.asarray(got), want, rtol=2e-2, atol=2e-2):
             return f"max abs err {np.abs(np.asarray(got) - want).max():.2e}"
         return None
@@ -177,12 +191,12 @@ def main() -> int:
         kq, ks = kvc.quantize_kv(jnp.asarray(k_pf))
         vq, vs = kvc.quantize_kv(jnp.asarray(v_pf))
         got = paged_prefill_attention(
-            jnp.asarray(qs), kq, vq, jnp.asarray(bt_pf),
-            jnp.asarray(pf_lens), jnp.asarray(q_off), ks, vs, block_q=8,
-            sliding_window=window, interpret=False)
+            jnp.asarray(qs), kq, vq, layer, jnp.asarray(bt_pf),
+            jnp.asarray(pf_lens), jnp.asarray(q_off), ks[layer], vs[layer],
+            block_q=8, sliding_window=window, interpret=False)
         kd = np.asarray(kq, np.float32) * np.asarray(ks)[..., None]
         vd = np.asarray(vq, np.float32) * np.asarray(vs)[..., None]
-        want = prefill_ref(kd, vd)
+        want = prefill_ref(kd[layer], vd[layer])
         if not np.allclose(np.asarray(got), want, rtol=5e-2, atol=5e-2):
             return f"max abs err {np.abs(np.asarray(got) - want).max():.2e}"
         return None
@@ -231,8 +245,6 @@ def main() -> int:
     check("swa_prefill8", swa_prefill8)
     check("ring_swa", ring_swa)
     check("ulysses_swa", ulysses_swa)
-
-    import os
 
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
     with open(args.out, "w") as f:

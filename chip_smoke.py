@@ -663,7 +663,9 @@ def _kernel_errors(cfg: dict, mcfg, interpret: bool = False) -> dict:
     random bf16 pool: the model's heads, window and 16-token pages;
     contexts short, long, and past the window (so the windowed page
     offset is exercised at a non-zero window start); one prefill chunk
-    that starts behind the window's edge."""
+    that starts behind the window's edge. The pool is stacked, three
+    layers of different contents, and the kernels read the middle one
+    (they take the whole stack and address the layer themselves)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -681,12 +683,13 @@ def _kernel_errors(cfg: dict, mcfg, interpret: bool = False) -> dict:
     kv_lens = np.array([100, span // 4 + 7, span - 700, span - 1], np.int32)
     b = len(kv_lens)
     key = jax.random.split(jax.random.PRNGKey(cfg["seed"]), 4)
-    pool_shape = (b * mp + 1, page, hkv, d)
+    layer = 1
+    pool_shape = (3, b * mp + 1, page, hkv, d)
     k_pool = jax.random.normal(key[0], pool_shape, jnp.bfloat16)
     v_pool = jax.random.normal(key[1], pool_shape, jnp.bfloat16)
     tables = jnp.asarray(1 + np.arange(b * mp, dtype=np.int32).reshape(b, mp))
-    kv = kvc.KVPages(k=k_pool[None], v=v_pool[None])
-    k_all, v_all = kvc.gather_kv(kv, 0, tables)
+    k_all, v_all = kvc.gather_kv(kvc.KVPages(k=k_pool, v=v_pool), layer,
+                                 tables)
 
     def err(got, want):
         """Worst |got - want| of any query, as a share of that query's
@@ -705,7 +708,7 @@ def _kernel_errors(cfg: dict, mcfg, interpret: bool = False) -> dict:
         # but the result is not rounded to bf16 on the way out.
         q = jax.random.normal(key[2], (b, hq, d),
                               jnp.bfloat16).astype(jnp.float32)
-        got = paged_attention(q, k_pool, v_pool, tables,
+        got = paged_attention(q, k_pool, v_pool, layer, tables,
                               jnp.asarray(kv_lens), interpret=interpret,
                               sliding_window=win)
         want = dense_causal_attention(
@@ -721,7 +724,7 @@ def _kernel_errors(cfg: dict, mcfg, interpret: bool = False) -> dict:
         qp = jax.random.normal(key[3], (2, s_len, hq, d),
                                jnp.bfloat16).astype(jnp.float32)
         got = paged_prefill_attention(
-            qp, k_pool, v_pool, tables[:2], jnp.asarray(lens),
+            qp, k_pool, v_pool, layer, tables[:2], jnp.asarray(lens),
             jnp.asarray(q_off), interpret=interpret, sliding_window=win)
         want = dense_causal_attention(
             qp, k_all[:2], v_all[:2],

@@ -19,13 +19,38 @@ from tpu_inference.kernels.paged_attention import paged_attention
 from tpu_inference.models import build_model, common
 
 
+# The kernels take the engine's STACKED pool [L, P, page, Hkv, D] and a
+# layer index (and, quantized, that layer's scales). Every pool here has
+# LAYERS layers with different contents (and different scales: layer l's
+# values are (l + 1) times larger), and tests run at the first, a middle
+# and the last layer: a wrong layer offset, or codes of one layer under
+# the scales of another, cannot pass.
+LAYERS = 3
+LAYER_IDS = [0, 1, LAYERS - 1]
+
+
+def _stacked_pool(rng, num_pages, page_size, hkv, d, dtype=jnp.float32):
+    """One stacked [LAYERS, P, page, Hkv, D] pool, each layer different."""
+    x = rng.standard_normal((LAYERS, num_pages, page_size, hkv, d))
+    x *= np.arange(1, LAYERS + 1).reshape(LAYERS, 1, 1, 1, 1)
+    return jnp.asarray(x, dtype)
+
+
+def _quantize_pools(k_pool, v_pool, kv_quant) -> kvc.KVPages:
+    """The stacked pools as the engine would hold them under ``kv_quant``
+    (kv_cache.alloc_kv_pages layouts): codes + per-(token, head) scales."""
+    if kv_quant == "none":
+        return kvc.KVPages(k=k_pool, v=v_pool)
+    quant = kvc.quantize_kv_int4 if kv_quant == "int4" else kvc.quantize_kv
+    (k, ks), (v, vs) = quant(k_pool), quant(v_pool)
+    return kvc.KVPages(k=k, v=v, k_scale=ks, v_scale=vs)
+
+
 def _random_paged_setup(rng, *, b=3, hq=8, hkv=2, d=64, page_size=8,
                         num_pages=32, max_pages=4, dtype=jnp.float32):
-    """Build a pool + block tables with random per-seq lengths."""
-    k_pool = jnp.asarray(rng.standard_normal(
-        (num_pages, page_size, hkv, d)), dtype)
-    v_pool = jnp.asarray(rng.standard_normal(
-        (num_pages, page_size, hkv, d)), dtype)
+    """Build a stacked pool + block tables with random per-seq lengths."""
+    k_pool = _stacked_pool(rng, num_pages, page_size, hkv, d, dtype)
+    v_pool = _stacked_pool(rng, num_pages, page_size, hkv, d, dtype)
     q = jnp.asarray(rng.standard_normal((b, hq, d)), dtype)
     # Distinct physical pages per sequence (page 0 reserved as trash).
     perm = rng.permutation(np.arange(1, num_pages))[:b * max_pages]
@@ -34,20 +59,22 @@ def _random_paged_setup(rng, *, b=3, hq=8, hkv=2, d=64, page_size=8,
     return q, k_pool, v_pool, jnp.asarray(bt), jnp.asarray(kv_len)
 
 
-def _dense_reference(q, k_pool, v_pool, bt, kv_len):
-    kv = kvc.KVPages(k=k_pool[None], v=v_pool[None])
-    k_all, v_all = kvc.gather_kv(kv, 0, bt)
+def _dense_reference(q, k_pool, v_pool, layer, bt, kv_len):
+    kv = kvc.KVPages(k=k_pool, v=v_pool)
+    k_all, v_all = kvc.gather_kv(kv, layer, bt)
     out = common.dense_causal_attention(
         q[:, None], k_all, v_all, q_offset=kv_len - 1, kv_len=kv_len)
     return out[:, 0]
 
 
+@pytest.mark.parametrize("layer", LAYER_IDS)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_paged_attention_matches_dense(dtype):
+def test_paged_attention_matches_dense(dtype, layer):
     rng = np.random.default_rng(0)
     q, k_pool, v_pool, bt, kv_len = _random_paged_setup(rng, dtype=dtype)
-    got = paged_attention(q, k_pool, v_pool, bt, kv_len, interpret=True)
-    want = _dense_reference(q, k_pool, v_pool, bt, kv_len)
+    got = paged_attention(q, k_pool, v_pool, layer, bt, kv_len,
+                          interpret=True)
+    want = _dense_reference(q, k_pool, v_pool, layer, bt, kv_len)
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
@@ -59,8 +86,8 @@ def test_paged_attention_single_token_context():
     rng = np.random.default_rng(1)
     q, k_pool, v_pool, bt, _ = _random_paged_setup(rng, b=2)
     kv_len = jnp.asarray([1, 1], jnp.int32)
-    got = paged_attention(q, k_pool, v_pool, bt, kv_len, interpret=True)
-    want = _dense_reference(q, k_pool, v_pool, bt, kv_len)
+    got = paged_attention(q, k_pool, v_pool, 1, bt, kv_len, interpret=True)
+    want = _dense_reference(q, k_pool, v_pool, 1, bt, kv_len)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
 
@@ -69,10 +96,62 @@ def test_paged_attention_mha():
     """n_rep == 1 (no GQA grouping)."""
     rng = np.random.default_rng(2)
     q, k_pool, v_pool, bt, kv_len = _random_paged_setup(rng, hq=4, hkv=4)
-    got = paged_attention(q, k_pool, v_pool, bt, kv_len, interpret=True)
-    want = _dense_reference(q, k_pool, v_pool, bt, kv_len)
+    got = paged_attention(q, k_pool, v_pool, LAYERS - 1, bt, kv_len,
+                          interpret=True)
+    want = _dense_reference(q, k_pool, v_pool, LAYERS - 1, bt, kv_len)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("layer", LAYER_IDS)
+@pytest.mark.parametrize("window", [0, 11])
+@pytest.mark.parametrize("kv_quant", ["none", "int8", "int4"])
+@pytest.mark.parametrize("kernel", ["decode", "prefill"])
+def test_paged_kernels_read_the_named_layer(kernel, kv_quant, window, layer):
+    """Both kernels, on the stacked pool in each KV layout, windowed and
+    not, attend over layer ``layer``'s pages and scales and no other's:
+    they equal the dense gather of THAT layer (kv_cache.gather_kv, which
+    dequantizes) and differ from every other layer's."""
+    from tpu_inference.kernels.prefill_attention import paged_prefill_attention
+
+    rng = np.random.default_rng(21)
+    b, s, hq, hkv, d, pg, npg, mp = 2, 16, 4, 2, 32, 8, 24, 5
+    kv = _quantize_pools(_stacked_pool(rng, npg, pg, hkv, d),
+                         _stacked_pool(rng, npg, pg, hkv, d), kv_quant)
+    bt = jnp.asarray(rng.permutation(np.arange(1, npg))[:b * mp]
+                     .reshape(b, mp).astype(np.int32))
+    # The codes are read from the stack in place; the scales (1% of the
+    # bytes) are the caller's slice of this layer, as in make_paged_attn.
+    scales = ((kv.k_scale[layer], kv.v_scale[layer]) if kv.quantized
+              else (None, None))
+    if kernel == "decode":
+        q = jnp.asarray(rng.standard_normal((b, 1, hq, d)), jnp.float32)
+        kv_len = jnp.asarray([13, 37], jnp.int32)
+        q_off = kv_len - 1
+        got = paged_attention(q[:, 0], kv.k, kv.v, layer, bt, kv_len,
+                              *scales, interpret=True,
+                              sliding_window=window)[:, None]
+    else:
+        q = jnp.asarray(rng.standard_normal((b, s, hq, d)), jnp.float32)
+        q_off = jnp.asarray([0, 19], jnp.int32)      # fresh + cached prefix
+        kv_len = q_off + s
+        got = paged_prefill_attention(q, kv.k, kv.v, layer, bt, kv_len,
+                                      q_off, *scales, block_q=8,
+                                      interpret=True, sliding_window=window)
+
+    def dense(at_layer):
+        k_all, v_all = kvc.gather_kv(kv, at_layer, bt)
+        return np.asarray(common.dense_causal_attention(
+            q, k_all, v_all, q_offset=q_off, kv_len=kv_len,
+            sliding_window=window))
+
+    # Values grow with the layer number (_stacked_pool), and so does the
+    # f32 rounding of either order of summation.
+    tol = 2e-5 * (layer + 1)
+    np.testing.assert_allclose(np.asarray(got), dense(layer),
+                               rtol=tol, atol=tol)
+    for other in set(range(LAYERS)) - {layer}:
+        assert not np.allclose(np.asarray(got), dense(other), atol=1e-2)
 
 
 def test_engine_pallas_backend_matches_dense():
@@ -144,16 +223,17 @@ def test_engine_pallas_backend_sharded_matches_dense():
     assert got_d == got_p
 
 
+@pytest.mark.parametrize("layer", LAYER_IDS)
 @pytest.mark.parametrize("block_q,q_offsets", [(16, (5, 0)), (8, (0, 13))])
-def test_paged_prefill_attention_matches_dense(block_q, q_offsets):
+def test_paged_prefill_attention_matches_dense(block_q, q_offsets, layer):
     """Flash prefill over pool pages == dense gather+causal attention,
     including cached-prefix offsets and partially-filled last pages."""
     from tpu_inference.kernels.prefill_attention import paged_prefill_attention
 
     rng = np.random.default_rng(7)
     b, s, hq, hkv, d, pg, npg, mp = 2, 32, 8, 2, 64, 8, 64, 8
-    k_pool = jnp.asarray(rng.standard_normal((npg, pg, hkv, d)), jnp.float32)
-    v_pool = jnp.asarray(rng.standard_normal((npg, pg, hkv, d)), jnp.float32)
+    k_pool = _stacked_pool(rng, npg, pg, hkv, d)
+    v_pool = _stacked_pool(rng, npg, pg, hkv, d)
     q = jnp.asarray(rng.standard_normal((b, s, hq, d)), jnp.float32)
     perm = rng.permutation(np.arange(1, npg))[:b * mp]
     bt = jnp.asarray(perm.reshape(b, mp).astype(np.int32))
@@ -161,17 +241,18 @@ def test_paged_prefill_attention_matches_dense(block_q, q_offsets):
     prompt = jnp.asarray([20, 32], jnp.int32)
     kv_len = q_off + prompt
 
-    got = paged_prefill_attention(q, k_pool, v_pool, bt, kv_len, q_off,
-                                  block_q=block_q, interpret=True)
-    kv = kvc.KVPages(k=k_pool[None], v=v_pool[None])
-    k_all, v_all = kvc.gather_kv(kv, 0, bt)
+    got = paged_prefill_attention(q, k_pool, v_pool, layer, bt, kv_len,
+                                  q_off, block_q=block_q, interpret=True)
+    kv = kvc.KVPages(k=k_pool, v=v_pool)
+    k_all, v_all = kvc.gather_kv(kv, layer, bt)
     want = common.dense_causal_attention(q, k_all, v_all, q_offset=q_off,
                                          kv_len=kv_len)
+    tol = 2e-5 * (layer + 1)       # layer l's values are l + 1 times larger
     for i in range(b):
         n = int(prompt[i])                    # padded query rows unused
         np.testing.assert_allclose(np.asarray(got)[i, :n],
                                    np.asarray(want)[i, :n],
-                                   rtol=2e-5, atol=2e-5)
+                                   rtol=tol, atol=tol)
 
 
 def test_paged_prefill_non_power_of_two_bucket():
@@ -180,16 +261,16 @@ def test_paged_prefill_non_power_of_two_bucket():
 
     rng = np.random.default_rng(8)
     b, s, h, d, pg, npg, mp = 1, 24, 4, 32, 8, 16, 4
-    k_pool = jnp.asarray(rng.standard_normal((npg, pg, h, d)), jnp.float32)
-    v_pool = jnp.asarray(rng.standard_normal((npg, pg, h, d)), jnp.float32)
+    k_pool = _stacked_pool(rng, npg, pg, h, d)
+    v_pool = _stacked_pool(rng, npg, pg, h, d)
     q = jnp.asarray(rng.standard_normal((b, s, h, d)), jnp.float32)
     bt = jnp.asarray(np.arange(1, 1 + mp)[None].astype(np.int32))
     kv_len = jnp.asarray([s], jnp.int32)
-    got = paged_prefill_attention(q, k_pool, v_pool, bt, kv_len,
+    got = paged_prefill_attention(q, k_pool, v_pool, 1, bt, kv_len,
                                   jnp.zeros((b,), jnp.int32), block_q=16,
                                   interpret=True)
-    kv = kvc.KVPages(k=k_pool[None], v=v_pool[None])
-    k_all, v_all = kvc.gather_kv(kv, 0, bt)
+    kv = kvc.KVPages(k=k_pool, v=v_pool)
+    k_all, v_all = kvc.gather_kv(kv, 1, bt)
     want = common.dense_causal_attention(q, k_all, v_all, q_offset=0,
                                          kv_len=kv_len)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),

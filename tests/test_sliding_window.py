@@ -134,14 +134,45 @@ def test_config_from_hf_reads_mistral_sliding_window(tmp_path):
     assert config_from_hf(str(tmp_path)).sliding_window == 0
 
 
-@pytest.mark.parametrize("kv_quant", ["none", "int8"])
-def test_windowed_paged_decode_kernel_matches_dense(kv_quant):
+# The kernels take the STACKED pool [L, P, page, Hkv, D] plus a layer
+# index: pools here hold KERNEL_LAYERS layers, each with its own
+# contents and magnitude (so its own scales), and the kernel tests run at
+# the first, a middle and the last layer.
+KERNEL_LAYERS = 3
+
+
+def _stacked_pools(rng, n_pages, page, hkv, d, kv_quant):
+    """(k_in, v_in, ks, vs) stacked [L, ...] under ``kv_quant`` (the
+    kernels take the codes stacked and one layer's scales), and the
+    float32 pools [L, P, page, Hkv, D] the dense reference should see
+    (dequantized when quantized)."""
+    from tpu_inference.engine import kv_cache as kvc
+
+    shape = (KERNEL_LAYERS, n_pages, page, hkv, d)
+    grow = np.arange(1, KERNEL_LAYERS + 1, dtype=np.float32).reshape(
+        -1, 1, 1, 1, 1)
+    k_pool = rng.standard_normal(shape).astype(np.float32) * grow
+    v_pool = rng.standard_normal(shape).astype(np.float32) * grow
+    if kv_quant == "none":
+        return jnp.asarray(k_pool), jnp.asarray(v_pool), None, None, \
+            k_pool, v_pool
+    if kv_quant == "int4":
+        quant, codes = kvc.quantize_kv_int4, kvc.unpack_int4_kv
+    else:
+        quant, codes = kvc.quantize_kv, lambda x: x
+    (kq, ks), (vq, vs) = quant(jnp.asarray(k_pool)), quant(jnp.asarray(v_pool))
+    return (kq, vq, ks, vs,
+            np.asarray(codes(kq), np.float32) * np.asarray(ks)[..., None],
+            np.asarray(codes(vq), np.float32) * np.asarray(vs)[..., None])
+
+
+@pytest.mark.parametrize("layer", [0, 1, KERNEL_LAYERS - 1])
+@pytest.mark.parametrize("kv_quant", ["none", "int8", "int4"])
+def test_windowed_paged_decode_kernel_matches_dense(kv_quant, layer):
     """The Pallas decode kernel's O(window) page walk (relative-page
     grid + offset index maps) == the window-masked dense reference, for
-    ragged kv_lens crossing page boundaries, GQA, and the int8 pool."""
-    import jax
-
-    from tpu_inference.engine import kv_cache as kvc
+    ragged kv_lens crossing page boundaries, GQA, and the int8 / int4
+    pools, at each layer of the stacked pool."""
     from tpu_inference.kernels.paged_attention import paged_attention
 
     rng = np.random.default_rng(11)
@@ -149,29 +180,23 @@ def test_windowed_paged_decode_kernel_matches_dense(kv_quant):
     b = 3
     n_pages = 32
     kv_lens = np.array([5, 17, 41], np.int32)      # <W, >W, >>W
-    k_pool = rng.standard_normal((n_pages, page, hkv, d)).astype(np.float32)
-    v_pool = rng.standard_normal((n_pages, page, hkv, d)).astype(np.float32)
+    # Dense reference sees the dequantized pool of this layer.
+    k_in, v_in, ks, vs, k_pool, v_pool = _stacked_pools(
+        rng, n_pages, page, hkv, d, kv_quant)
+    k_pool, v_pool = k_pool[layer], v_pool[layer]
+    if ks is not None:
+        ks, vs = ks[layer], vs[layer]
     bt = rng.permutation(np.arange(1, 1 + b * mp)).reshape(b, mp).astype(
         np.int32)
     q = rng.standard_normal((b, hq, d)).astype(np.float32)
 
-    ks = vs = None
-    if kv_quant == "int8":
-        kq, ks_ = kvc.quantize_kv(jnp.asarray(k_pool))
-        vq, vs_ = kvc.quantize_kv(jnp.asarray(v_pool))
-        k_in, v_in, ks, vs = kq, vq, ks_, vs_
-        # Dense reference sees the dequantized pool.
-        k_pool = np.asarray(kq, np.float32) * np.asarray(ks_)[..., None]
-        v_pool = np.asarray(vq, np.float32) * np.asarray(vs_)[..., None]
-    else:
-        k_in, v_in = jnp.asarray(k_pool), jnp.asarray(v_pool)
-
-    got = paged_attention(jnp.asarray(q), k_in, v_in, jnp.asarray(bt),
-                          jnp.asarray(kv_lens), ks, vs,
+    got = paged_attention(jnp.asarray(q), k_in, v_in, layer,
+                          jnp.asarray(bt), jnp.asarray(kv_lens), ks, vs,
                           sliding_window=window, interpret=True)
 
     # Dense reference: gather each sequence's pages, window-masked
     # attention with the query at position kv_len-1.
+    tol = 2e-5 * (layer + 1)       # layer l's values are l + 1 times larger
     for i in range(b):
         n = int(kv_lens[i])
         flat = np.concatenate([k_pool[bt[i, j]] for j in range(mp)])[:n]
@@ -182,7 +207,7 @@ def test_windowed_paged_decode_kernel_matches_dense(kv_quant):
             q_offset=n - 1, kv_len=n, sliding_window=window)
         np.testing.assert_allclose(np.asarray(got[i]),
                                    np.asarray(want[0, 0]),
-                                   rtol=2e-5, atol=2e-5,
+                                   rtol=tol, atol=tol,
                                    err_msg=f"seq {i} kv_len {n}")
 
 
@@ -228,12 +253,13 @@ def test_swa_sp_engine_matches_unsharded(sp_attn, swa8, swa8_dense_engine):
     assert got == want
 
 
-@pytest.mark.parametrize("kv_quant", ["none", "int8"])
-def test_windowed_paged_prefill_kernel_matches_dense(kv_quant):
+@pytest.mark.parametrize("layer", [0, 1, KERNEL_LAYERS - 1])
+@pytest.mark.parametrize("kv_quant", ["none", "int8", "int4"])
+def test_windowed_paged_prefill_kernel_matches_dense(kv_quant, layer):
     """The windowed Pallas prefill (per-query-block relative pages) ==
     the window-masked dense reference, including a chunked-prefill
-    q_offset > 0 and the int8 pool."""
-    from tpu_inference.engine import kv_cache as kvc
+    q_offset > 0 and the int8 / int4 pools, at each layer of the stacked
+    pool."""
     from tpu_inference.kernels.prefill_attention import (
         paged_prefill_attention)
 
@@ -243,27 +269,21 @@ def test_windowed_paged_prefill_kernel_matches_dense(kv_quant):
     q_off = np.array([0, 16], np.int32)      # fresh + continued chunk
     kv_lens = q_off + s
     n_pages = 40
-    k_pool = rng.standard_normal((n_pages, page, hkv, d)).astype(np.float32)
-    v_pool = rng.standard_normal((n_pages, page, hkv, d)).astype(np.float32)
+    k_in, v_in, ks, vs, k_pool, v_pool = _stacked_pools(
+        rng, n_pages, page, hkv, d, kv_quant)
+    k_pool, v_pool = k_pool[layer], v_pool[layer]
+    if ks is not None:
+        ks, vs = ks[layer], vs[layer]
     bt = rng.permutation(np.arange(1, 1 + b * mp)).reshape(b, mp).astype(
         np.int32)
     q = rng.standard_normal((b, s, hq, d)).astype(np.float32)
 
-    ks = vs = None
-    if kv_quant == "int8":
-        kq, ks_ = kvc.quantize_kv(jnp.asarray(k_pool))
-        vq, vs_ = kvc.quantize_kv(jnp.asarray(v_pool))
-        k_in, v_in, ks, vs = kq, vq, ks_, vs_
-        k_pool = np.asarray(kq, np.float32) * np.asarray(ks_)[..., None]
-        v_pool = np.asarray(vq, np.float32) * np.asarray(vs_)[..., None]
-    else:
-        k_in, v_in = jnp.asarray(k_pool), jnp.asarray(v_pool)
-
     got = paged_prefill_attention(
-        jnp.asarray(q), k_in, v_in, jnp.asarray(bt), jnp.asarray(kv_lens),
-        jnp.asarray(q_off), ks, vs, block_q=8, sliding_window=window,
-        interpret=True)
+        jnp.asarray(q), k_in, v_in, layer, jnp.asarray(bt),
+        jnp.asarray(kv_lens), jnp.asarray(q_off), ks, vs, block_q=8,
+        sliding_window=window, interpret=True)
 
+    tol = 2e-5 * (layer + 1)
     for i in range(b):
         n = int(kv_lens[i])
         flat = np.concatenate([k_pool[bt[i, j]] for j in range(mp)])[:n]
@@ -273,7 +293,7 @@ def test_windowed_paged_prefill_kernel_matches_dense(kv_quant):
             jnp.asarray(flatv[None]), q_offset=int(q_off[i]), kv_len=n,
             sliding_window=window)
         np.testing.assert_allclose(np.asarray(got[i]), np.asarray(want[0]),
-                                   rtol=2e-5, atol=2e-5,
+                                   rtol=tol, atol=tol,
                                    err_msg=f"seq {i} q_off {q_off[i]}")
 
 

@@ -8,12 +8,19 @@ kernels are compiled here at the published head shapes of the two models
 the roadmap's first cells use — Mistral-7B (32 q / 8 kv heads x 128,
 window 4096) and Phi-3-mini (32 / 32 x 96, window 2047) — with bf16,
 int8 and nibble-packed int4 KV pools, one decode and one prefill each,
-about two seconds a case. Nothing runs: this says a later PR did not
+about two seconds a case. The pools are STACKED ([L, P, page, Hkv, D],
+the layer an int32 operand) as the engine holds them, and a last group
+compiles each kernel inside the model's pattern — a donated pool carried
+through ``lax.scan`` over layers, scattered by ``write_kv`` right before
+the kernel reads it — and reads the chip compiler's own HLO: no
+instruction may produce one layer's pool (a slice XLA materialized) or a
+second copy of the stacked one. Nothing runs: this says a later PR did not
 break what the chip run needs, not that results or times are right
 (chip_smoke.py says that, on the chip).
 """
 
 import os
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +32,7 @@ from tpu_inference.kernels.prefill_attention import paged_prefill_attention
 
 PAGE = 16
 NUM_PAGES = 1024
+LAYERS = 32
 
 # name: (q heads, kv heads, head_dim, sliding window, pages per sequence)
 HEADS = {
@@ -61,17 +69,19 @@ def chip():
 
 
 def _pool(chip, hkv, d, kv_quant):
-    """One layer's K (= V) pool and scale shapes in ``kv_quant``'s layout
-    (engine/kv_cache.py alloc_kv_pages)."""
+    """The stacked K (= V) pool of all LAYERS layers, and the stacked
+    scale pool, in ``kv_quant``'s layout (engine/kv_cache.py
+    alloc_kv_pages). The kernels take the first whole and one layer of
+    the second."""
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
     if kv_quant == "none":
-        return s((NUM_PAGES, PAGE, hkv, d), jnp.bfloat16), None
-    code = (s((NUM_PAGES, PAGE, hkv, d // 2), jnp.uint8)
+        return s((LAYERS, NUM_PAGES, PAGE, hkv, d), jnp.bfloat16), None
+    code = (s((LAYERS, NUM_PAGES, PAGE, hkv, d // 2), jnp.uint8)
             if kv_quant == "int4"
-            else s((NUM_PAGES, PAGE, hkv, d), jnp.int8))
-    return code, s((NUM_PAGES, PAGE, hkv), jnp.float32)
+            else s((LAYERS, NUM_PAGES, PAGE, hkv, d), jnp.int8))
+    return code, s((LAYERS, NUM_PAGES, PAGE, hkv), jnp.float32)
 
 
 @pytest.mark.parametrize("kv_quant", ["none", "int8", "int4"])
@@ -84,17 +94,88 @@ def test_kernel_compiles_for_v5e(chip, kernel, model, kv_quant):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
     pool, scale = _pool(chip, hkv, d, kv_quant)
+    if scale is not None:
+        scale = s(scale.shape[1:], scale.dtype)      # one layer's
     if kernel == "decode":
         b = 8
         lowered = paged_attention.lower(
-            s((b, hq, d), jnp.bfloat16), pool, pool,
+            s((b, hq, d), jnp.bfloat16), pool, pool, s((), jnp.int32),
             s((b, mp), jnp.int32), s((b,), jnp.int32), scale, scale,
             interpret=False, sliding_window=window)
     else:
         b, seq = 1, 512
         lowered = paged_prefill_attention.lower(
-            s((b, seq, hq, d), jnp.bfloat16), pool, pool,
+            s((b, seq, hq, d), jnp.bfloat16), pool, pool, s((), jnp.int32),
             s((b, mp), jnp.int32), s((b,), jnp.int32), s((b,), jnp.int32),
             scale, scale, interpret=False, sliding_window=window)
     compiled = lowered.compile()    # raises what the chip's compiler would
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("layer", ["first", "middle", "last", "scanned"])
+@pytest.mark.parametrize("kv_quant", ["none", "int8"])
+@pytest.mark.parametrize("kernel", ["decode", "prefill"])
+def test_layer_loop_reads_the_pool_in_place(chip, kernel, kv_quant, layer):
+    """The engine's pattern around the kernels, compiled for the v5e: the
+    donated stacked pool is scattered by ``write_kv`` and then read by
+    the kernel at layer ``layer`` — a constant first / middle / last
+    layer, or (as models/llama.py forward_hidden does) the traced index
+    of a ``lax.scan`` over all layers that carries the pool. In the chip
+    compiler's HLO nothing may produce one layer's pool (the
+    ``dynamic-slice`` the per-layer kernel signature cost, 2 x ~100 MB a
+    layer), and no ``copy`` may produce the stacked one (a defensive copy
+    of the whole carry). The scales of the int8 pool are not held to
+    this: the caller slices them (kernels/paged_attention.py says why)."""
+    from tpu_inference.engine import kv_cache as kvc
+
+    # One definition of "a copy of the pool", shared with the rehearsal
+    # that compiles the engine's whole step programs.
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir,
+                                    "benchmarks"))
+    from aot_rehearsal import pool_copies
+
+    hq, hkv, d, window, mp = HEADS["mistral-7b"]
+    b, seq = (8, 1) if kernel == "decode" else (1, 256)
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    pool, scale = _pool(chip, hkv, d, kv_quant)
+    kv = kvc.KVPages(k=pool, v=pool, k_scale=scale, v_scale=scale)
+
+    def one_layer(kv, layer_idx, q, k_new, v_new, bt, kv_len, slots):
+        kv = kvc.write_kv(kv, layer_idx, k_new, v_new, slots)
+        scales = ((kv.k_scale[layer_idx], kv.v_scale[layer_idx])
+                  if kv.quantized else (None, None))
+        if kernel == "decode":
+            out = paged_attention(q[:, 0], kv.k, kv.v, layer_idx, bt, kv_len,
+                                  *scales, sliding_window=window)[:, None]
+        else:
+            out = paged_prefill_attention(
+                q, kv.k, kv.v, layer_idx, bt, kv_len, kv_len - seq,
+                *scales, sliding_window=window)
+        return kv, out
+
+    def step(kv, q, k_new, v_new, bt, kv_len, slots):
+        if layer != "scanned":
+            at = {"first": 0, "middle": LAYERS // 2, "last": LAYERS - 1}
+            return one_layer(kv, jnp.int32(at[layer]), q, k_new, v_new, bt,
+                             kv_len, slots)
+
+        def body(carry, layer_idx):
+            kv, q = carry
+            kv, out = one_layer(kv, layer_idx, q, k_new, v_new, bt, kv_len,
+                                slots)
+            return (kv, out), None
+
+        (kv, q), _ = jax.lax.scan(body, (kv, q), jnp.arange(LAYERS))
+        return kv, q
+
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+        kv, s((b, seq, hq, d), jnp.bfloat16),
+        s((b, seq, hkv, d), jnp.bfloat16), s((b, seq, hkv, d), jnp.bfloat16),
+        s((b, mp), jnp.int32), s((b,), jnp.int32),
+        s((b, seq), jnp.int32)).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    assert pool_copies(hlo, pool.shape) == []

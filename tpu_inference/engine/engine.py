@@ -55,7 +55,16 @@ def make_paged_attn(cfg: ModelConfig, page_size: int, block_tables: jax.Array,
 
     block_tables [B, MP]; positions/valid [B, S]; q_offset/kv_len [B].
 
-    With a mesh, the Pallas decode kernel is shard_map-wrapped over the
+    The Pallas kernels are handed the STACKED pool ``kv.k`` / ``kv.v``
+    ``[L, P, page, Hkv, D]`` whole, with ``layer_idx`` as a scalar
+    operand: the layer is picked inside the kernel's page DMAs.
+    ``layer_idx`` is the traced index of the model's scan over layers, so
+    ``kv.k[layer_idx]`` would be a dynamic_slice that XLA materializes —
+    a copy of one layer's whole pool in front of every kernel call.
+    (A quantized pool's scales, 1% of its bytes, are still sliced per
+    layer: ``_paged_call``.)
+
+    With a mesh, the Pallas kernels are shard_map-wrapped over the
     ``tp`` axis: q shards on the query-head dim and the KV pool on the
     kv-head dim (parallel/shardings.py keeps them aligned), so each chip
     streams only its own head shard's pages — attention output is
@@ -97,75 +106,64 @@ def make_paged_attn(cfg: ModelConfig, page_size: int, block_tables: jax.Array,
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
             check_vma=False)(q, k, v)
 
-    def _scales(kv: KVPages, layer_idx):
-        if not kv.quantized:
-            return None, None
-        return kv.k_scale[layer_idx], kv.v_scale[layer_idx]
-
-    def _sharded_paged_call(kernel, kv: KVPages, layer_idx, lead_args,
-                            lead_specs, out_spec):
-        """shard_map a paged kernel over tp: pool (+ scale pool when the
-        KV is int8-quantized) shards on the kv-head dim; scale operands
-        append conditionally so the quantized/unquantized paths share
-        one spec assembly (same pattern as the kernels' own operand
-        lists)."""
+    def _paged_call(kernel, kv: KVPages, layer_idx, lead_args, lead_specs,
+                    head_spec):
+        """Call a paged kernel on the STACKED pool with the layer index
+        as an operand: the kernel addresses layer ``layer_idx``'s pages
+        itself, so no ``kv.k[layer_idx]`` slice — a copy of one layer's
+        whole pool, since a pallas_call operand is a buffer of its own —
+        is made in front of it. ``kernel(*lead, layer, k, v[, ks, vs])``.
+        The scales of a quantized pool ARE sliced here (1% of the codes'
+        bytes; the stacked scale pool's device layout is not the
+        row-major one a kernel operand needs, so XLA would re-lay-out all
+        of it: kernels/paged_attention.py). Under a mesh the call is
+        shard_mapped over tp: q/out shard on heads (``head_spec``), the
+        pools on the kv-head dim."""
+        args = [*lead_args, layer_idx, kv.k, kv.v]
+        if kv.quantized:
+            args += [kv.k_scale[layer_idx], kv.v_scale[layer_idx]]
+        if mesh is None:
+            return kernel(*args)
         from jax.sharding import PartitionSpec as P
-        pool_p = P(None, None, "tp", None)             # [P, pg, Hkv, D]
-        args = list(lead_args) + [kv.k[layer_idx], kv.v[layer_idx]]
-        specs = list(lead_specs) + [pool_p, pool_p]
+        pool_p = P(None, None, None, "tp", None)       # [L, P, pg, Hkv, D]
+        specs = [head_spec, *lead_specs, P(), pool_p, pool_p]
         if kv.quantized:
             scale_p = P(None, None, "tp")              # [P, pg, Hkv]
-            args += [kv.k_scale[layer_idx], kv.v_scale[layer_idx]]
             specs += [scale_p, scale_p]
         return jax.shard_map(
-            kernel, mesh=mesh, in_specs=tuple(specs), out_specs=out_spec,
+            kernel, mesh=mesh, in_specs=tuple(specs), out_specs=head_spec,
             check_vma=False)(*args)
 
     def _pallas_decode(q1, kv: KVPages, layer_idx):
-        from tpu_inference.kernels.paged_attention import paged_attention
-        win = cfg.sliding_window
-        if mesh is None:
-            ks, vs = _scales(kv, layer_idx)
-            return paged_attention(q1, kv.k[layer_idx], kv.v[layer_idx],
-                                   block_tables, kv_len, ks, vs,
-                                   interpret=interpret, sliding_window=win)
         from jax.sharding import PartitionSpec as P
-        head_p = P(None, "tp", None)                   # q/out [B, H*, D]
 
-        def kernel(q_, bt_, kl_, k_, v_, *scales):
-            ks_, vs_ = scales if scales else (None, None)
-            return paged_attention(q_, k_, v_, bt_, kl_, ks_, vs_,
-                                   interpret=interpret, sliding_window=win)
+        from tpu_inference.kernels.paged_attention import paged_attention
 
-        return _sharded_paged_call(
-            kernel, kv, layer_idx,
-            lead_args=(q1, block_tables, kv_len),
-            lead_specs=(head_p, P(), P()), out_spec=head_p)
+        def kernel(q_, bt_, kl_, layer_, k_, v_, ks_=None, vs_=None):
+            return paged_attention(q_, k_, v_, layer_, bt_, kl_, ks_, vs_,
+                                   interpret=interpret,
+                                   sliding_window=cfg.sliding_window)
+
+        return _paged_call(kernel, kv, layer_idx,
+                           lead_args=(q1, block_tables, kv_len),
+                           lead_specs=(P(), P()),
+                           head_spec=P(None, "tp", None))   # [B, H*, D]
 
     def _pallas_prefill(q, kv: KVPages, layer_idx):
+        from jax.sharding import PartitionSpec as P
+
         from tpu_inference.kernels.prefill_attention import (
             paged_prefill_attention)
-        win = cfg.sliding_window
-        if mesh is None:
-            ks, vs = _scales(kv, layer_idx)
-            return paged_prefill_attention(q, kv.k[layer_idx],
-                                           kv.v[layer_idx], block_tables,
-                                           kv_len, q_offset, ks, vs,
-                                           interpret=interpret,
-                                           sliding_window=win)
-        from jax.sharding import PartitionSpec as P
-        head_p = P(None, None, "tp", None)             # q/out [B, S, H*, D]
 
-        def kernel(q_, bt_, kl_, qo_, k_, v_, *scales):
-            ks_, vs_ = scales if scales else (None, None)
-            return paged_prefill_attention(q_, k_, v_, bt_, kl_, qo_,
-                                           ks_, vs_, interpret=interpret,
-                                           sliding_window=win)
+        def kernel(q_, bt_, kl_, qo_, layer_, k_, v_, ks_=None, vs_=None):
+            return paged_prefill_attention(
+                q_, k_, v_, layer_, bt_, kl_, qo_, ks_, vs_,
+                interpret=interpret, sliding_window=cfg.sliding_window)
 
-        return _sharded_paged_call(
-            kernel, kv, layer_idx,
-            lead_args=(q, block_tables, kv_len, q_offset),
-            lead_specs=(head_p, P(), P(), P()), out_spec=head_p)
+        return _paged_call(kernel, kv, layer_idx,
+                           lead_args=(q, block_tables, kv_len, q_offset),
+                           lead_specs=(P(), P(), P()),
+                           head_spec=P(None, None, "tp", None))  # [B,S,H*,D]
 
     def attn(layer_idx, q, k, v, kv: KVPages):
         slots = kvc.slot_mapping(block_tables, positions, valid, page_size)

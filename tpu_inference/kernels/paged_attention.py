@@ -10,10 +10,18 @@ TPU pattern for decode attention (vLLM's PagedAttention re-designed for
 Mosaic; reference has no analogue — SURVEY.md §2b).
 
 Mechanics:
-- ``PrefetchScalarGridSpec`` with the block table + kv lengths as scalar
-  prefetch: the KV BlockSpec's index_map reads ``block_tables[b, p]`` to
-  pick which physical page the pipeline DMAs next — the gather never
-  materializes.
+- The kernel's K / V operands are the engine's STACKED pool
+  ``[L, P, page, Hkv, D]`` (all layers; bf16, int8 or packed-int4
+  codes), never one layer's slice of it:
+  a ``pallas_call`` operand is a buffer of its own, so ``pool[layer]``
+  under the model's ``lax.scan`` over layers made XLA copy a whole
+  layer's pool (~100 MB, twice a layer) in front of every call. Which
+  layer is read is part of the DMA address instead.
+- ``PrefetchScalarGridSpec`` with the layer index, the block table + kv
+  lengths as scalar prefetch: the KV BlockSpec's index_map returns
+  ``(layer[0], block_tables[b, p], 0, 0, 0)`` to pick which physical
+  page of which layer the pipeline DMAs next — neither the layer slice
+  nor the gather materializes.
 - Grid (B, MP), page index innermost; VMEM scratch (m, l, acc) carries
   the online-softmax state across a sequence's pages and is flushed to
   the output on the last page.
@@ -43,8 +51,9 @@ def _unpack_int4(packed):
     return unpack_int4_kv(packed).astype(jnp.float32)
 
 
-def _decode_kernel(block_tables_ref, kv_len_ref, q_ref, k_ref, v_ref,
-                   *rest, page_size: int, scale: float, quantized: bool,
+def _decode_kernel(layer_ref, block_tables_ref, kv_len_ref, q_ref, k_ref,
+                   v_ref, *rest, page_size: int, scale: float,
+                   quantized: bool,
                    packed: bool = False, sliding_window: int = 0):
     if quantized:
         ks_ref, vs_ref, out_ref, m_ref, l_ref, acc_ref = rest
@@ -123,7 +132,8 @@ def _decode_kernel(block_tables_ref, kv_len_ref, q_ref, k_ref, v_ref,
 
 @functools.partial(jax.jit, static_argnames=("interpret", "sliding_window"))
 def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
-                    block_tables: jax.Array, kv_len: jax.Array,
+                    layer: jax.Array, block_tables: jax.Array,
+                    kv_len: jax.Array,
                     k_scale: jax.Array | None = None,
                     v_scale: jax.Array | None = None,
                     interpret: bool = False,
@@ -131,14 +141,27 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     """Decode attention over the paged KV pool.
 
     q:            [B, Hq, D]   (one query token per sequence)
-    k/v_pages:    [P, page_size, Hkv, D]  (one layer's pool)
+    k/v_pages:    [L, P, page_size, Hkv, D]  (the stacked pool of all
+                  layers, read in place: only the pages the block table
+                  names, in layer ``layer``, leave HBM)
+    layer:        int32 scalar: which layer's pages to read; may be
+                  traced (the model's scan index)
     block_tables: [B, MP] int32 physical page ids (0 = trash page)
     kv_len:       [B] int32 valid tokens per sequence (incl. current)
-    k/v_scale:    [P, page_size, Hkv] f32 — present when the pool holds
-                  int8 codes (engine/kv_cache.py quantize_kv) or uint8
-                  nibble-packed int4 codes (quantize_kv_int4; pool
-                  trailing dim D/2); dequant happens in VMEM after each
-                  page's DMA.
+    k/v_scale:    [P, page_size, Hkv] f32, layer ``layer``'s scales —
+                  present when the pool holds int8 codes
+                  (engine/kv_cache.py quantize_kv) or uint8 nibble-packed
+                  int4 codes (quantize_kv_int4; pool trailing dim D/2);
+                  dequant happens in VMEM after each page's DMA. ONE
+                  layer's, sliced by the caller, and not the stacked
+                  [L, P, page, Hkv]: the chip keeps that f32 array with
+                  the page dim minor-most (its last dim, Hkv, is far
+                  under a 128-lane tile), a kernel operand has to be
+                  row-major, and so XLA re-lays-out whatever it is
+                  handed — one layer's scales (1/L of the scale pool, 1%
+                  of the layer's codes) or, stacked, all L layers' in
+                  front of every call (v5e compile: +0.4 GB of temps at
+                  1024 pages).
     sliding_window > 0 (SWA, Mistral): only the pages overlapping the
     last ``sliding_window`` positions are streamed — the grid's page
     axis shrinks to the window's page span and the index maps offset
@@ -154,12 +177,13 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     # pool's trailing dim is D/2 bytes and the kernel unpacks in VMEM.
     packed = k_pages.dtype == jnp.uint8
     b, hq, d = q.shape
-    _, page_size, hkv, d_pool = k_pages.shape
+    _, _, page_size, hkv, d_pool = k_pages.shape
     n_rep = hq // hkv
     mp = block_tables.shape[1]
     scale = 1.0 / (d ** 0.5)
 
     q_g = q.reshape(b, hkv, n_rep, d)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
 
     if sliding_window:
         # A window of W positions spans at most ceil(W/page)+1 pages
@@ -177,28 +201,28 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         def page_idx(i, p, bt, kl):
             return bt[i, p]
 
-    page_spec = pl.BlockSpec((1, page_size, hkv, d_pool),
-                             lambda i, p, bt, kl: (page_idx(i, p, bt, kl),
-                                                   0, 0, 0))
-    in_specs = [
-        pl.BlockSpec((1, hkv, n_rep, d), lambda i, p, bt, kl: (i, 0, 0, 0)),
-        page_spec,
-        page_spec,
-    ]
+    # Leading layer dim squeezed (None): the kernel body sees one page,
+    # [1, page, Hkv, D], exactly as it did with a per-layer pool.
+    page_spec = pl.BlockSpec(
+        (None, 1, page_size, hkv, d_pool),
+        lambda i, p, ly, bt, kl: (ly[0], page_idx(i, p, bt, kl),
+                                  0, 0, 0))
+    q_spec = pl.BlockSpec((1, hkv, n_rep, d),
+                          lambda i, p, ly, bt, kl: (i, 0, 0, 0))
+    in_specs = [q_spec, page_spec, page_spec]
     operands = [q_g, k_pages, v_pages]
     if quantized:
-        scale_spec = pl.BlockSpec((1, page_size, hkv),
-                                  lambda i, p, bt, kl: (
-                                      page_idx(i, p, bt, kl), 0, 0))
+        scale_spec = pl.BlockSpec(
+            (1, page_size, hkv),
+            lambda i, p, ly, bt, kl: (page_idx(i, p, bt, kl), 0, 0))
         in_specs += [scale_spec, scale_spec]
         operands += [k_scale, v_scale]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,          # block_tables, kv_len
+        num_scalar_prefetch=3,          # layer, block_tables, kv_len
         grid=(b, n_page_axis),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, hkv, n_rep, d),
-                               lambda i, p, bt, kl: (i, 0, 0, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
             pltpu.VMEM((hkv, n_rep), jnp.float32),       # running max
             pltpu.VMEM((hkv, n_rep), jnp.float32),       # running sum
@@ -212,5 +236,5 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, n_rep, d), q.dtype),
         interpret=interpret,
-    )(block_tables, kv_len, *operands)
+    )(layer, block_tables, kv_len, *operands)
     return out.reshape(b, hq, d)

@@ -10,6 +10,12 @@ own KV and any cached prefix are read from the same paged pool (the engine
 writes the current chunk's KV before attending, so pool pages are the
 single source of truth).
 
+Like the decode kernel, this one takes the STACKED pool
+[L, P, page, Hkv, D] plus the layer index as a scalar-prefetch operand:
+the K / V index maps return (layer[0], page, 0, 0, 0), so the layer is
+chosen in the DMA address and XLA never slices (= copies) one layer's
+pool out of the stack in front of the call.
+
 Layout mirrors the decode kernel (kernels/paged_attention.py): grid
 (B, S/bq, MP) with the page index innermost; each instance carries a
 whole query block for every kv head — q viewed [Hkv, bq*R, D] so each
@@ -43,8 +49,9 @@ NEG_INF = -1e30
 from tpu_inference.kernels.paged_attention import _unpack_int4  # noqa: E402
 
 
-def _prefill_kernel(block_tables_ref, kv_len_ref, q_offset_ref, q_ref, k_ref,
-                    v_ref, *rest, page_size: int, block_q: int, n_rep: int,
+def _prefill_kernel(layer_ref, block_tables_ref, kv_len_ref, q_offset_ref,
+                    q_ref, k_ref, v_ref, *rest, page_size: int,
+                    block_q: int, n_rep: int,
                     scale: float, quantized: bool, packed: bool = False,
                     sliding_window: int = 0):
     if quantized:
@@ -126,8 +133,9 @@ def _prefill_kernel(block_tables_ref, kv_len_ref, q_offset_ref, q_ref, k_ref,
 @functools.partial(jax.jit, static_argnames=("block_q", "interpret",
                                              "sliding_window"))
 def paged_prefill_attention(q: jax.Array, k_pages: jax.Array,
-                            v_pages: jax.Array, block_tables: jax.Array,
-                            kv_len: jax.Array, q_offset: jax.Array,
+                            v_pages: jax.Array, layer: jax.Array,
+                            block_tables: jax.Array, kv_len: jax.Array,
+                            q_offset: jax.Array,
                             k_scale: jax.Array | None = None,
                             v_scale: jax.Array | None = None,
                             block_q: int = 128,
@@ -136,14 +144,19 @@ def paged_prefill_attention(q: jax.Array, k_pages: jax.Array,
     """Prefill attention over the paged KV pool.
 
     q:            [B, S, Hq, D]  (the current chunk's queries)
-    k/v_pages:    [P, page_size, Hkv, D]  (one layer's pool; the chunk's
-                  own KV must already be written)
+    k/v_pages:    [L, P, page_size, Hkv, D]  (the stacked pool of all
+                  layers, read in place; the chunk's own KV must already
+                  be written)
+    layer:        int32 scalar: which layer's pages to read; may be
+                  traced (the model's scan index)
     block_tables: [B, MP] int32 physical page ids (0 = trash page)
     kv_len:       [B] total valid tokens (cached prefix + this chunk)
     q_offset:     [B] absolute position of q[:, 0] (= prefix length)
-    k/v_scale:    [P, page_size, Hkv] f32 when the pool is quantized —
-                  int8 codes or uint8 nibble-packed int4 (trailing dim
-                  D/2); dequant happens in VMEM per page.
+    k/v_scale:    [P, page_size, Hkv] f32, layer ``layer``'s scales, when
+                  the pool is quantized — int8 codes or uint8
+                  nibble-packed int4 (trailing dim D/2); dequant happens
+                  in VMEM per page. One layer's, sliced by the caller:
+                  see kernels/paged_attention.py for why not stacked.
     interpret:    Pallas interpret mode (tests on the CPU pass True); the
                   default compiles through Mosaic and needs a TPU.
     Returns [B, S, Hq, D] in q.dtype.
@@ -153,10 +166,11 @@ def paged_prefill_attention(q: jax.Array, k_pages: jax.Array,
     # pool's trailing dim is D/2 bytes and the kernel unpacks in VMEM.
     packed = k_pages.dtype == jnp.uint8
     b, s, hq, d = q.shape
-    _, page_size, hkv, d_pool = k_pages.shape
+    _, _, page_size, hkv, d_pool = k_pages.shape
     n_rep = hq // hkv
     mp = block_tables.shape[1]
     scale = 1.0 / (d ** 0.5)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
     # Largest divisor of s not exceeding block_q (buckets are usually
     # powers of two, but any length must work — e.g. a 192 bucket).
     bq = next(b for b in range(min(block_q, s), 0, -1) if s % b == 0)
@@ -185,31 +199,29 @@ def paged_prefill_attention(q: jax.Array, k_pages: jax.Array,
         def page_idx(i, qb, p, bt, kl, qo):
             return bt[i, p]
 
-    page_spec = pl.BlockSpec((1, page_size, hkv, d_pool),
-                             lambda i, qb, p, bt, kl, qo: (
-                                 page_idx(i, qb, p, bt, kl, qo), 0, 0, 0))
-    in_specs = [
-        pl.BlockSpec((1, 1, hkv, bq * n_rep, d),
-                     lambda i, qb, p, bt, kl, qo: (i, qb, 0, 0, 0)),
-        page_spec,
-        page_spec,
-    ]
+    # Leading layer dim squeezed (None): the kernel body sees one page,
+    # [1, page, Hkv, D], exactly as it did with a per-layer pool.
+    page_spec = pl.BlockSpec(
+        (None, 1, page_size, hkv, d_pool),
+        lambda i, qb, p, ly, bt, kl, qo: (
+            ly[0], page_idx(i, qb, p, bt, kl, qo), 0, 0, 0))
+    q_spec = pl.BlockSpec((1, 1, hkv, bq * n_rep, d),
+                          lambda i, qb, p, ly, bt, kl, qo: (i, qb, 0, 0, 0))
+    in_specs = [q_spec, page_spec, page_spec]
     operands = [q_g, k_pages, v_pages]
     if quantized:
         scale_spec = pl.BlockSpec(
             (1, page_size, hkv),
-            lambda i, qb, p, bt, kl, qo: (
+            lambda i, qb, p, ly, bt, kl, qo: (
                 page_idx(i, qb, p, bt, kl, qo), 0, 0))
         in_specs += [scale_spec, scale_spec]
         operands += [k_scale, v_scale]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,        # block_tables, kv_len, q_offset
+        num_scalar_prefetch=4,    # layer, block_tables, kv_len, q_offset
         grid=(b, n_qb, n_page_axis),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, 1, hkv, bq * n_rep, d),
-            lambda i, qb, p, bt, kl, qo: (i, qb, 0, 0, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
             pltpu.VMEM((hkv, bq * n_rep, 1), jnp.float32),   # running max
             pltpu.VMEM((hkv, bq * n_rep, 1), jnp.float32),   # running sum
@@ -231,7 +243,7 @@ def paged_prefill_attention(q: jax.Array, k_pages: jax.Array,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=48 * 1024 * 1024),
         interpret=interpret,
-    )(block_tables, kv_len, q_offset, *operands)
+    )(layer, block_tables, kv_len, q_offset, *operands)
     return (out.reshape(b, n_qb, hkv, bq, n_rep, d)
             .transpose(0, 1, 3, 2, 4, 5)
             .reshape(b, s, hq, d))
