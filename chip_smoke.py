@@ -105,6 +105,23 @@ SETTINGS = {
     # kernel that kept its scores or softmax sums in bf16 (2^-9 = 2e-3
     # per term) lands above this.
     "kernel_tol": 0.001,
+    # The latent-attention kernels (kernels/mla_attention.py) at Kimi-K2's
+    # shapes, same measure. They feed the MXU in the pool's dtype: the
+    # softmax weights are rounded to bf16 (2^-9 a term) before the
+    # weighted sum, as flash kernels do: 0.0045 (decode) / 0.0080
+    # (prefill) on the v5e (PR 26). 2.5x above that; a wrong layer, page
+    # or mask is off by the output's whole spread (~1).
+    "latent_kernel_tol": 0.02,
+    # The routed-expert layer (models/deepseek_v3.py moe_ffn: router,
+    # grouping, kernels/moe_experts.py) at Kimi-K2's widths against a
+    # plain float32 loop over the held experts on the SAME routing: the
+    # largest error of a token's output row as a share of the rows' rms.
+    # The kernels round silu(g) * u to bf16 between the two matmuls
+    # (2^-9 a term): 0.015 at 32 rows, 0.018-0.021 at 1024 on the v5e
+    # (PR 26, two seeds). 2.5x above that. A layer that loses, swaps or
+    # mis-addresses an expert is off by the routed part's whole spread
+    # (planted there: 1.6 / 2.2 / 2.2; each must read over 10x this).
+    "routed_expert_tol": 0.05,
     # tp4_parity: bf16 at a depth one chip also holds (the tp=1 side;
     # the same 4 layers the tolerance above was measured at), prompts
     # inside one prefill bucket (the one-chip parity does the chunking).
@@ -734,6 +751,172 @@ def _kernel_errors(cfg: dict, mcfg, interpret: bool = False) -> dict:
     return {k: round(v, 5) for k, v in out.items()}
 
 
+def _latent_kernel_errors(cfg: dict, *, heads: int = 64, rank: int = 512,
+                          rope: int = 64, ctx=(100, 5000, 10000),
+                          chunk: int = 512, interpret: bool = False) -> dict:
+    """mla_decode_attention / mla_prefill_attention against the dense
+    float32 form (mla_attention_dense) on the same random bf16 latent
+    pool, at layer 1 of a 3-layer stacked pool: Kimi-K2's 64 heads over
+    one 512 + 64 entry (stored 640 wide), contexts short to 10k, and a
+    chunk behind a cached prefix of ctx[1] tokens."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tpu_inference.kernels import mla_attention as mla
+
+    page, width = 16, -(-(rank + rope) // 128) * 128
+    b = len(ctx)
+    mp = -(-(max(ctx) + chunk) // page)
+    key = jax.random.split(jax.random.PRNGKey(cfg["seed"]), 3)
+    pool = jax.random.normal(key[0], (3, b * mp + 1, page, width),
+                             jnp.bfloat16)
+    tables = jnp.asarray(1 + np.arange(b * mp, dtype=np.int32).reshape(b, mp))
+    scale, layer = 0.13, 1
+
+    def err(got, want):
+        got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+        check(bool(np.isfinite(got).all()), "non-finite kernel output")
+        spread = np.std(want, axis=(-2, -1), keepdims=True)
+        return float(np.max(np.abs(got - want) / spread))
+
+    out = {}
+    with jax.default_matmul_precision("highest"):
+        lens = jnp.asarray(ctx, jnp.int32)
+        q = jax.random.normal(key[1], (b, heads, rank + rope),
+                              jnp.bfloat16).astype(jnp.float32)
+        got = mla.mla_decode_attention(q, pool, layer, tables, lens,
+                                       rank=rank, scale=scale,
+                                       interpret=interpret)
+        want = mla.mla_attention_dense(q[:, None], pool, layer, tables, lens,
+                                       lens - 1, rank=rank, scale=scale)
+        out["latent_decode"] = err(got, want[:, 0])
+        q_off = jnp.asarray([0, ctx[1]], jnp.int32)
+        qp = jax.random.normal(key[2], (2, chunk, heads, rank + rope),
+                               jnp.bfloat16).astype(jnp.float32)
+        got = mla.mla_prefill_attention(qp, pool, layer, tables[:2],
+                                        q_off + chunk, q_off, rank=rank,
+                                        scale=scale, interpret=interpret)
+        want = mla.mla_attention_dense(qp, pool, layer, tables[:2],
+                                       q_off + chunk, q_off, rank=rank,
+                                       scale=scale)
+        out["latent_prefill"] = err(got, want)
+    return {k: round(v, 5) for k, v in out.items()}
+
+
+def _routed_expert_errors(cfg: dict, *, preset: str = "kimi-k2-ep32",
+                          tokens=(32, 1024), idle: int = 5,
+                          interpret: bool = False) -> dict:
+    """The expert layer as the engine runs it (``deepseek_v3.moe_ffn``:
+    the router over all experts, the held pairs grouped into one-expert
+    tiles, ``moe_grouped_experts_gate_up`` / ``_down`` addressing (layer,
+    expert) in the stacked weights) at layer 1 of a 2-layer stack of the
+    preset's widths, on random bf16 activations: a decode step's rows and
+    a prefill chunk's, the last ``idle`` rows not holding a token. Against
+    a plain float32 loop over the held experts with the SAME chosen
+    experts and gates (so no pair changes expert between the two).
+    ``routed_*``: the program's error. ``planted_*``: what a fault in
+    that path reads by the same measure (the smallest over the row
+    counts), to be far over the tolerance."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tpu_inference.config import PRESETS
+    from tpu_inference.kernels import moe_experts
+    from tpu_inference.models import deepseek_v3 as dsv3
+
+    mcfg = PRESETS[preset]()
+    d, f, held = mcfg.d_model, mcfg.moe_d_ff, mcfg.n_local_experts
+    fs, layer = f * mcfg.n_shared_experts, 1
+    key = jax.random.split(jax.random.PRNGKey(cfg["seed"]), 10)
+
+    def mat(k, shape):
+        return jax.jit(lambda k: (0.02 * jax.random.normal(
+            k, shape, jnp.float32)).astype(jnp.bfloat16))(k)
+
+    experts = (mat(key[0], (2, held, d, f)), mat(key[1], (2, held, d, f)),
+               mat(key[2], (2, held, f, d)))
+    lp = {"w_router": mat(key[3], (d, mcfg.n_experts)),
+          "router_bias": 0.01 * jax.random.normal(key[4], (mcfg.n_experts,)),
+          "ws_gate": mat(key[5], (d, fs)), "ws_up": mat(key[6], (d, fs)),
+          "ws_down": mat(key[7], (fs, d))}
+
+    def attn_of(valid):
+        def attn(*a):
+            raise AssertionError("the expert layer calls no attention")
+        attn.pallas, attn.interpret, attn.valid = True, interpret, valid
+        return attn
+
+    @jax.jit
+    def _program(lp, h, valid, experts, moe_layer):
+        return dsv3.moe_ffn(mcfg, lp, experts, moe_layer, h, attn_of(valid))
+
+    def program(h, valid, experts, moe_layer):
+        return _program(lp, h, valid, experts, moe_layer)
+
+    @jax.jit
+    def _plain(lp, experts, h, valid):
+        with jax.default_matmul_precision("highest"):
+            x = h[:, 0].astype(jnp.float32)
+            top, gates = dsv3.route(mcfg, lp, h[:, 0])
+            first = mcfg.ep_rank * held
+            y = dsv3.swiglu(x, *(lp[k].astype(jnp.float32) for k in
+                                 ("ws_gate", "ws_up", "ws_down")))
+            for e in range(held):
+                ge = jnp.sum(jnp.where((top == first + e) & valid, gates,
+                                       0.0), axis=1)
+                y = y + ge[:, None] * dsv3.swiglu(
+                    x, *(w[layer, e].astype(jnp.float32) for w in experts))
+            return y
+
+    def plain(h, valid):
+        return _plain(lp, experts, h, valid)
+
+    def err(got, want, valid):
+        got = np.asarray(got, np.float32)[np.asarray(valid)[:, 0]]
+        want = np.asarray(want, np.float32)[np.asarray(valid)[:, 0]]
+        check(bool(np.isfinite(got).all()), "non-finite expert output")
+        return float(np.max(np.abs(got - want))
+                     / np.sqrt(np.mean(want * want)))
+
+    out = {}
+    planted = {"zero": [], "next_expert": [], "layer_0": []}
+    pairs = 0
+    for n in tokens:
+        h = jax.random.normal(jax.random.fold_in(key[8], n), (n, 1, d),
+                              jnp.bfloat16)
+        valid = (jnp.arange(n) < n - idle)[:, None]
+        want = plain(h, valid)
+        got, stats = program(h, valid, experts, layer)
+        st = dict(zip(dsv3.MOE_STATS, np.asarray(stats)))
+        check(st["tokens"] == n - idle
+              and st["local_pairs"] == st["computed_pairs"],
+              f"routing counts of {n} rows: {st}")
+        pairs += int(st["local_pairs"])
+        out[f"routed_{n}"] = err(got[:, 0], want, valid)
+        rolled = tuple(jnp.roll(w, 1, axis=1) for w in experts)
+        planted["zero"].append(err(
+            plain(h, valid & False), want, valid))
+        planted["next_expert"].append(err(
+            program(h, valid, rolled, layer)[0][:, 0], want, valid))
+        planted["layer_0"].append(err(
+            program(h, valid, experts, 0)[0][:, 0], want, valid))
+    check(pairs > 0, "no pair was routed to a held expert")
+    out.update({f"planted_{k}": min(v) for k, v in planted.items()})
+    return {k: round(v, 5) for k, v in out.items()}
+
+
+def check_routed_experts(errs: dict, tol: float) -> None:
+    for name, e in errs.items():
+        if name.startswith("routed_"):
+            check(e <= tol, f"routed-expert layer vs the plain float32 "
+                            f"loop: {errs}")
+        else:
+            check(e > 10 * tol, f"a planted fault in the routed-expert "
+                                f"path reads like a sound layer: {errs}")
+
+
 def _seeded_prompts(cfg: dict, vocab: int) -> list:
     import numpy as np
 
@@ -757,6 +940,13 @@ def child_parity(cfg: dict) -> dict:
         res["kernel_tol"] = cfg["kernel_tol"]
         check(max(res["kernel_err"].values()) <= cfg["kernel_tol"],
               f"Pallas kernel vs dense float32 attention: {res}")
+        res["latent_kernel_err"] = _latent_kernel_errors(cfg)
+        check(max(res["latent_kernel_err"].values())
+              <= cfg["latent_kernel_tol"],
+              f"latent-attention kernel vs its dense float32 form: {res}")
+        res["routed_expert_err"] = _routed_expert_errors(cfg)
+        check_routed_experts(res["routed_expert_err"],
+                             cfg["routed_expert_tol"])
     return {"ok": True, "layers": cfg["parity_layers"],
             "depth_cut": f"{cfg['parity_layers']} of the model's layers: "
                          "what a float32 reference fits beside on one chip",

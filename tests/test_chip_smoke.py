@@ -174,3 +174,28 @@ def test_fleet_router_never_initialises_a_backend():
     assert out["worker"]["platform"] == "cpu"
     assert out["worker"]["max_batch_size"] == 2     # the sizing ask arrived
     assert out["worker"]["visible_chips"] == "0"    # its chip, by spawn env
+
+
+def test_latent_kernel_check_in_interpret_mode():
+    """The smoke's latent-attention kernel check (run on the chip at
+    Kimi-K2's shapes, at layer 1 of a 3-layer stacked pool) at small
+    shapes through the interpreter: the same code, within its tolerance;
+    a check that reads the wrong layer is off by the whole spread."""
+    errs = chip_smoke._latent_kernel_errors(
+        TINY, heads=4, rank=128, rope=16, ctx=(20, 300, 700), chunk=64,
+        interpret=True)
+    assert set(errs) == {"latent_decode", "latent_prefill"}
+    assert max(errs.values()) <= chip_smoke.SETTINGS["latent_kernel_tol"]
+
+
+def test_routed_expert_check_in_interpret_mode():
+    """The smoke's routed-expert check (run on the chip at Kimi-K2's
+    widths, layer 1 of a 2-layer stack) at the tiny preset through the
+    interpreter: the layer is within the tolerance, rows without a token
+    route nowhere, and each planted fault reads far over it."""
+    errs = chip_smoke._routed_expert_errors(
+        TINY, preset="tiny-kimi", tokens=(8, 64), idle=3, interpret=True)
+    assert set(errs) == {"routed_8", "routed_64", "planted_zero",
+                         "planted_next_expert", "planted_layer_0"}
+    chip_smoke.check_routed_experts(errs,
+                                    chip_smoke.SETTINGS["routed_expert_tol"])

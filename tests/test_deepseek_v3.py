@@ -1,0 +1,463 @@
+"""The DeepSeek-V3 / Kimi-K2 family on the CPU at tiny widths with the
+real structure (tiny-kimi: 1 dense + 2 expert layers, 16 experts top-4 of
+which a rank holds 8, rope / nope split, YaRN on), seeded random weights:
+
+(a) the engine (chunked prefill, batched fused-K decode, a prefix-cache
+    hit over latent pages) against the in-repo plain reference, logits;
+(b) the latent-attention kernels in interpret mode against the dense
+    form, with a cached prefix, at layer > 0 of the stacked pool;
+(c) the shares add up: the routed parts of all expert-parallel ranks plus
+    the shared expert once equal the uncut reference's layer output;
+(d) no pair is dropped under a routing skewed onto one expert;
+(e) the presets' latent / expert / YaRN fields equal the keys of the
+    configuration files;
+(f) what the family does not support is refused at construction.
+"""
+
+import dataclasses
+import importlib.util
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from tpu_inference.config import PRESETS, EngineConfig, YarnScaling
+from tpu_inference.engine.engine import InferenceEngine, Sequence
+from tpu_inference.kernels import mla_attention as mla
+from tpu_inference.kernels import moe_experts
+from tpu_inference.models import deepseek_v3 as dsv3
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _load(path):
+    """A file of bench/ as a module, without putting bench/ on sys.path
+    (its ``tests`` directory would shadow this one's ``tests.conftest``)."""
+    spec = importlib.util.spec_from_file_location(
+        "bench_" + os.path.splitext(os.path.basename(path))[0], path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+REF = _load(os.path.join(REPO, "bench", "references", "deepseek_v3.py"))
+
+
+def config_file(path):
+    with open(os.path.join(REPO, path)) as f:
+        return json.load(f)
+
+
+TINY_FILE = "bench/tests/rehearsal/configs/tiny-kimi.json"
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    cfg = config_file(TINY_FILE)
+    sz = REF.sizes(cfg, 3)
+    weights = jax.tree.map(lambda a: a.astype(jnp.float32),
+                           REF.make_weights(sz, 5))
+    return PRESETS["tiny-kimi"](), sz, weights
+
+
+def engine(mcfg, weights, **kw):
+    ecfg = EngineConfig(**{**dict(num_pages=128, max_pages_per_seq=24,
+                                  max_batch_size=4,
+                                  prefill_buckets=(32, 64)), **kw})
+    return InferenceEngine(mcfg, ecfg, params=weights,
+                           pallas_interpret=kw.get("attn_backend")
+                           == "pallas")
+
+
+def probe_logits(eng, seq, p):
+    """Logits at position p off the pool the serving graphs wrote (as
+    bench/parity.py's probe)."""
+    stream = seq.prompt_tokens + seq.generated
+    pos = jnp.asarray([p], jnp.int32)
+    table = jnp.asarray(eng._block_table_array(seq.pages))[None]
+    attn = eng._paged_attn(eng.model_cfg, table, pos[:, None],
+                           jnp.ones((1, 1), bool), q_offset=pos,
+                           kv_len=pos + 1)
+    hidden, eng.kv = eng.mod.forward_hidden(
+        eng.params, eng.model_cfg, jnp.asarray([[stream[p]]], jnp.int32),
+        pos[:, None], eng.kv, attn)
+    return np.asarray(eng.mod.unembed(eng.params, eng.model_cfg,
+                                      hidden[:, 0])[0])
+
+
+# ------------------------------------------------------------------ (a)
+@pytest.mark.parametrize("backend", ["dense", "pallas"])
+def test_engine_matches_the_reference(tiny, backend):
+    mcfg, sz, weights = tiny
+    eng = engine(mcfg, weights, attn_backend=backend)
+    rng = np.random.default_rng(3)
+    shared = [int(t) for t in rng.integers(0, 512, 48)]
+    prompts = [shared + [int(t) for t in rng.integers(0, 512, n)]
+               for n in (20, 100)]          # 68: two chunks; 148: three
+    seqs = [Sequence(request_id=i, prompt_tokens=p, max_new_tokens=8)
+            for i, p in enumerate(prompts)]
+    for s in seqs:
+        eng.prefill(s)
+    while any(len(s.generated) < 5 for s in seqs):
+        eng.decode_steps()                  # both lanes, fused K
+
+    def check(s):
+        n = len(s.prompt_tokens)
+        at = [n - 1, n + 3]
+        ref = REF.logits(weights, sz, (s.prompt_tokens + s.generated)[:n + 4],
+                         at)
+        for p, r in zip(at, ref):
+            err = (probe_logits(eng, s, p) - r) / np.std(r)
+            assert np.sqrt(np.mean(err ** 2)) < 1e-4, (backend, n, p)
+        # Greedy tokens are the reference's argmax.
+        full = REF.logits(weights, sz, (s.prompt_tokens + s.generated)[:n + 4],
+                          list(range(n - 1, n + 4)))
+        assert [int(np.argmax(r)) for r in full] == s.generated[:5]
+
+    for s in seqs:
+        check(s)
+        eng.release(s)
+    # A new stream behind the shared prefix: its pages come from the cache.
+    hit = Sequence(request_id=9, max_new_tokens=8, prompt_tokens=shared + [
+        int(t) for t in rng.integers(0, 512, 30)])
+    eng.prefill(hit)
+    assert hit.cached_tokens >= 48
+    while len(hit.generated) < 5:
+        eng.decode_steps()
+    check(hit)
+    # Routing counts came out with the tokens; nothing dropped.
+    st = dict(zip(dsv3.MOE_STATS, eng.moe_stats))
+    assert st["tokens"] > 0 and st["local_pairs"] > 0
+    assert st["local_pairs"] == st["computed_pairs"]
+    assert eng.moe_stats[len(dsv3.MOE_STATS):].sum() == st["local_pairs"]
+
+
+# ------------------------------------------------------------------ (b)
+@pytest.mark.parametrize("pages_per_step", [3, 16])
+def test_latent_kernels_match_the_dense_form(pages_per_step):
+    L, P, pg, R, Dr, H = 3, 40, 16, 128, 16, 4
+    k = jax.random.split(jax.random.PRNGKey(0), 3)
+    pool = jax.random.normal(k[0], (L, P, pg, 256), jnp.float32)
+    B, S, MP = 2, 32, 8
+    bt = jnp.asarray(np.random.RandomState(0).permutation(
+        np.arange(1, P))[:B * MP].reshape(B, MP), jnp.int32)
+    q = jax.random.normal(k[1], (B, S, H, R + Dr))
+    # Sequence 0: a 32-token chunk behind a 40-token cached prefix;
+    # sequence 1: 20 valid tokens of a padded chunk behind 3.
+    q_off = jnp.asarray([40, 3], jnp.int32)
+    kv_len = q_off + jnp.asarray([32, 20])
+    for layer in (1, 2):
+        want = mla.mla_attention_dense(q, pool, layer, bt, kv_len, q_off,
+                                       rank=R, scale=0.1)
+        got = mla.mla_prefill_attention(
+            q, pool, layer, bt, kv_len, q_off, rank=R, scale=0.1, block_q=8,
+            pages_per_step=pages_per_step, interpret=True)
+        np.testing.assert_allclose(got[0], want[0], atol=2e-5)
+        np.testing.assert_allclose(got[1, :20], want[1, :20], atol=2e-5)
+    kv_len = jnp.asarray([100, 17], jnp.int32)
+    want = mla.mla_attention_dense(q[:, :1], pool, 2, bt, kv_len, kv_len - 1,
+                                   rank=R, scale=0.1)[:, 0]
+    got = mla.mla_decode_attention(q[:, 0], pool, 2, bt, kv_len, rank=R,
+                                   scale=0.1, pages_per_step=pages_per_step,
+                                   interpret=True)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+def test_absorbed_attention_is_the_expanded_attention(tiny):
+    """forward() with the latent contract's dense attention equals the
+    reference's expanded attention on a whole stream (no cache)."""
+    mcfg, sz, weights = tiny
+    toks = np.random.default_rng(1).integers(0, 512, 70)
+    got, _ = dsv3.forward(weights, mcfg, jnp.asarray(toks)[None],
+                          jnp.arange(70)[None], None,
+                          dsv3.make_dense_attn(mcfg))
+    want = REF.logits(weights, sz, list(toks), [10, 69])
+    np.testing.assert_allclose(np.asarray(got[0])[[10, 69]], want,
+                               atol=5e-5)
+
+
+# ------------------------------------------------------------------ (c)
+def test_the_shares_add_up(tiny):
+    """One expert layer: sum over ranks of (routed part of the rank) +
+    shared expert once == the uncut layer (every expert on one rank)."""
+    mcfg, sz, weights = tiny
+    ep = mcfg.ep_size
+    full_sz = dict(sz, held=sz["experts"], first_held=0)
+    full = REF.make_weights(full_sz, 11)
+    lp = jax.tree.map(lambda a: a[0].astype(jnp.float32), full["moe"])
+    h = jax.random.normal(jax.random.PRNGKey(2), (1, 37, mcfg.d_model))
+
+    def routed_plus_shared(cfg, lp_rank):
+        experts = tuple(lp_rank[k][None] for k in ("we_gate", "we_up",
+                                                   "we_down"))
+        out, stats = dsv3.moe_ffn(cfg, lp_rank, experts, 0, h,
+                                  dsv3.make_dense_attn(cfg))
+        return out[0], stats
+
+    uncut, _ = routed_plus_shared(
+        dataclasses.replace(mcfg, ep_size=1, ep_rank=0), lp)
+    shared = dsv3.swiglu(h[0], lp["ws_gate"], lp["ws_up"], lp["ws_down"])
+    held = mcfg.n_local_experts
+    parts, pairs = [], 0
+    for rank in range(ep):
+        lp_rank = dict(lp, **{k: lp[k][rank * held:(rank + 1) * held]
+                              for k in ("we_gate", "we_up", "we_down")})
+        out, stats = routed_plus_shared(
+            dataclasses.replace(mcfg, ep_rank=rank), lp_rank)
+        parts.append(out - shared)
+        pairs += int(stats[1])
+    assert pairs == 37 * mcfg.n_experts_per_tok     # every pair somewhere
+    np.testing.assert_allclose(sum(parts) + shared, uncut, atol=1e-5)
+    # ... and the uncut layer is the reference's layer.
+    x = jnp.zeros((512, mcfg.d_model)).at[:37].set(h[0])
+    ref_sz = dict(full_sz, layers=1, dense_layers=0)
+    sc = jax.nn.sigmoid(x @ lp["w_router"])
+    _, top = jax.lax.top_k(sc + lp["router_bias"][None], sz["top_k"])
+    g = jnp.take_along_axis(sc, top, 1)
+    g = g / g.sum(1, keepdims=True) * ref_sz["route_scale"]
+    want = REF._swiglu(x, lp["ws_gate"], lp["ws_up"], lp["ws_down"])
+    for e in range(sz["experts"]):
+        ge = jnp.where(top == e, g, 0.0).sum(1)
+        want = want + ge[:, None] * REF._swiglu(
+            x, lp["we_gate"][e], lp["we_up"][e], lp["we_down"][e])
+    np.testing.assert_allclose(uncut, want[:37], atol=1e-5)
+
+
+def test_rows_without_a_token_route_nowhere(tiny):
+    """A padded bucket's tail and an idle decode lane (``attn.valid``
+    False) send no pair to an expert and count in no statistic; the rows
+    that hold a token get what they got."""
+    mcfg, _, weights = tiny
+    lp = jax.tree.map(lambda a: a[0], weights["moe"])
+    experts = tuple(lp[k][None] for k in ("we_gate", "we_up", "we_down"))
+    h = jax.random.normal(jax.random.PRNGKey(4), (2, 9, mcfg.d_model))
+    valid = jnp.arange(9)[None, :] < jnp.asarray([[9], [4]])
+
+    def run(valid):
+        attn = dsv3.make_dense_attn(mcfg)
+        attn.valid = valid
+        return dsv3.moe_ffn(mcfg, lp, experts, 0, h, attn)
+
+    full, st_full = run(None)
+    part, st_part = run(valid)
+    at = dict(zip(dsv3.MOE_STATS, range(len(dsv3.MOE_STATS))))
+    assert int(st_full[at["tokens"]]) == 18
+    assert int(st_part[at["tokens"]]) == 13
+    assert int(st_part[at["local_pairs"]]) < int(st_full[at["local_pairs"]])
+    assert int(st_part[at["local_pairs"]]) == int(
+        st_part[at["computed_pairs"]])
+    np.testing.assert_allclose(part[valid], full[valid], atol=1e-6)
+    # A row without a token keeps only the shared expert's output.
+    shared = dsv3.swiglu(h[1, 5:], lp["ws_gate"], lp["ws_up"], lp["ws_down"])
+    np.testing.assert_allclose(part[1, 5:], shared, atol=1e-6)
+
+
+def test_the_references_bias_decides_the_held_experts(tiny):
+    """bench/references/deepseek_v3.py make_weights: per layer the same
+    HELD_CHOSEN held experts are among every token's k and no other held
+    expert ever is, whatever the scores; the rest of the k are the
+    token's own."""
+    mcfg, sz, weights = tiny
+    chosen = min(REF.HELD_CHOSEN, sz["top_k"] // 2, sz["held"])
+    bias = np.asarray(weights["moe"]["router_bias"])
+    held = slice(sz["first_held"], sz["first_held"] + sz["held"])
+    assert ((bias[:, held] == REF.HELD_MARGIN).sum(1) == chosen).all()
+    assert ((bias[:, held] == -REF.HELD_MARGIN).sum(1)
+            == sz["held"] - chosen).all()
+    assert np.abs(np.delete(bias, np.r_[held], axis=1)).max() < 0.1
+    lp = jax.tree.map(lambda a: a[0], weights["moe"])
+    x = jax.random.normal(jax.random.PRNGKey(6), (200, mcfg.d_model)) * 3
+    top, gates = dsv3.route(mcfg, lp, x)
+    top = np.asarray(top)
+    is_held = (top >= sz["first_held"]) & (top < sz["first_held"] + sz["held"])
+    assert (is_held.sum(1) == chosen).all()
+    assert len(np.unique(top[is_held])) == chosen
+    assert len(np.unique(top[~is_held])) > sz["top_k"] - chosen   # tokens differ
+    assert np.asarray(gates).std() > 0.05
+
+
+# ------------------------------------------------------------------ (d)
+@pytest.mark.parametrize("pallas", [False, True])
+def test_no_pair_is_dropped_under_skew(pallas):
+    """Every token sends all its k choices to held experts, 3/4 of them to
+    expert 0: far over a round's rows, so several rounds run."""
+    t, k, held, d, f = 96, 4, 8, 128, 128
+    rng = np.random.default_rng(0)
+    top = np.stack([np.zeros(t), np.zeros(t) + (np.arange(t) % 2),
+                    np.zeros(t), rng.integers(1, held, t)], 1).astype(
+                        np.int32)
+    top[:, 2] = np.where(np.arange(t) % 4 == 0, 5, 0)
+    gates = rng.random((t, k)).astype(np.float32)
+    expected = t * k * held / 64           # as if 64 experts shared them
+    groups = moe_experts.group_pairs(jnp.asarray(top), jnp.asarray(gates),
+                                     held, expected)
+    assert int(groups.counts.sum()) == t * k
+    # More tiles in use than a round holds: several rounds run.
+    assert int(groups.n_tiles) * groups.tm > groups.round_rows
+    ks = jax.random.split(jax.random.PRNGKey(1), 4)
+    x = jax.random.normal(ks[0], (t, d))
+    wg, wu = (0.1 * jax.random.normal(kk, (2, held, d, f)) for kk in ks[1:3])
+    wd = 0.1 * jax.random.normal(ks[3], (2, held, f, d))
+    y, done = moe_experts.grouped_experts(x, groups, wg, wu, wd, 1,
+                                          pallas=pallas, interpret=True)
+    assert int(done) == t * k
+    want = np.zeros((t, d), np.float32)
+    for i in range(t):
+        for j in range(k):
+            e = top[i, j]
+            hh = jax.nn.silu(x[i] @ wg[1, e]) * (x[i] @ wu[1, e])
+            want[i] += gates[i, j] * np.asarray(hh @ wd[1, e])
+    np.testing.assert_allclose(y, want, atol=2e-4)
+
+
+def test_pairs_of_absent_experts_are_left_out():
+    top = jnp.asarray([[8, 3], [8, 8], [1, 8]], jnp.int32)   # 8 = not here
+    groups = moe_experts.group_pairs(top, jnp.ones((3, 2), jnp.float32), 8,
+                                     1.0)
+    assert groups.counts.tolist() == [0, 1, 0, 1, 0, 0, 0, 0]
+    assert int(groups.n_tiles) == 2
+    rows = np.asarray(groups.row_token)
+    assert sorted(rows[rows < 3].tolist()) == [0, 2]
+
+
+# ------------------------------------------------------------------ (e)
+@pytest.mark.parametrize("preset,path", [
+    ("kimi-k2-ep32", "bench/configs/kimi-k2-ep32-bf16.json"),
+    ("tiny-kimi", TINY_FILE)])
+def test_preset_equals_the_configuration_file(preset, path):
+    m, f = PRESETS[preset](), config_file(path)
+    rs = f["rope_scaling"]
+    pairs = {
+        "q_lora_rank": m.q_lora_rank, "kv_lora_rank": m.kv_lora_rank,
+        "qk_nope_head_dim": m.qk_nope_head_dim,
+        "qk_rope_head_dim": m.qk_rope_head_dim, "v_head_dim": m.v_head_dim,
+        "first_k_dense_replace": m.first_k_dense,
+        "moe_intermediate_size": m.moe_d_ff,
+        "n_shared_experts": m.n_shared_experts,
+        "n_routed_experts": m.n_local_experts,
+        "num_experts_per_tok": m.n_experts_per_tok,
+        "routed_scaling_factor": m.routed_scaling_factor,
+        "norm_topk_prob": m.norm_topk_prob, "scoring_func": m.moe_scoring,
+        "hidden_size": m.d_model, "intermediate_size": m.d_ff,
+        "num_hidden_layers": m.n_layers, "vocab_size": m.vocab_size,
+        "num_attention_heads": m.n_heads, "rope_theta": m.rope_theta,
+        "rms_norm_eps": m.norm_eps, "n_group": 1, "topk_group": 1,
+    }
+    assert {k: f[k] for k in pairs} == pairs
+    assert f["published"]["n_routed_experts"] == m.n_experts
+    assert f["deployment"]["expert_parallel"] == m.ep_size
+    assert f["deployment"]["rank"] == m.ep_rank
+    assert rs["type"] == "yarn" and m.rope_scaling == YarnScaling(
+        factor=rs["factor"],
+        original_max_len=rs["original_max_position_embeddings"],
+        beta_fast=rs["beta_fast"], beta_slow=rs["beta_slow"],
+        mscale=rs["mscale"], mscale_all_dim=rs["mscale_all_dim"])
+    sz = REF.sizes(f, f["num_hidden_layers"])
+    shapes = jax.tree.map(lambda s: s, REF._shapes(sz),
+                          is_leaf=lambda x: isinstance(x, tuple))
+    assert shapes == dsv3.param_shapes(m)
+
+
+def test_the_cut_is_the_stated_one():
+    f = config_file("bench/configs/kimi-k2-ep32-bf16.json")
+    assert f["reduced"] == ["num_hidden_layers", "n_routed_experts",
+                            "vocab_size"]
+    assert f["published"] == {"num_hidden_layers": 61,
+                              "n_routed_experts": 384, "vocab_size": 163840}
+    assert (f["published"]["n_routed_experts"]
+            == f["n_routed_experts"] * f["deployment"]["expert_parallel"])
+    assert (f["published"]["vocab_size"]
+            == f["vocab_size"] * f["deployment"]["vocab_shards"])
+    from tpu_inference.engine import autosize
+    m = PRESETS["kimi-k2-ep32"]()
+    assert autosize.weight_bytes(m) == pytest.approx(9.70e9, rel=0.005)
+    assert autosize.kv_bytes_per_token(m) == 7 * 640 * 2
+    assert autosize.active_param_count(m) < autosize.estimate_param_count(m)
+
+
+def test_yarn_frequencies_and_scale():
+    from tpu_inference.models.common import rope_frequencies
+    m = PRESETS["kimi-k2-ep32"]()
+    inv = np.asarray(rope_frequencies(64, m.rope_theta, m.rope_scaling))
+    plain = np.asarray(rope_frequencies(64, m.rope_theta, None))
+    np.testing.assert_allclose(inv[:20], plain[:20], rtol=1e-6)
+    np.testing.assert_allclose(inv[20:], plain[20:] / 32, rtol=1e-6)
+    assert dsv3.softmax_scale(m) == pytest.approx(0.130861, rel=1e-5)
+    f = config_file("bench/configs/kimi-k2-ep32-bf16.json")
+    np.testing.assert_allclose(REF._yarn_inv_freq(REF.sizes(f, 7)), inv,
+                               rtol=1e-6)
+
+
+# ------------------------------------------------------------------ (f)
+@pytest.mark.parametrize("kw,needle", [
+    (dict(kv_quant="int8"), "kv_quant"),
+    (dict(num_speculative_tokens=3, spec_mode="ngram"), "speculative"),
+    (dict(host_cache_pages=8), "host KV tier"),
+    (dict(quant="int4"), "int4"),
+    (dict(role="prefill"), "role"),
+])
+def test_unsupported_is_refused_at_construction(kw, needle):
+    with pytest.raises(ValueError, match=needle):
+        InferenceEngine(PRESETS["tiny-kimi"](),
+                        EngineConfig(num_pages=32, max_pages_per_seq=8, **kw))
+
+
+def test_tp_is_refused_at_construction():
+    from tpu_inference.config import ParallelConfig
+    from tpu_inference.parallel.mesh import build_mesh
+    mesh = build_mesh(ParallelConfig(tp=2), devices=jax.devices()[:2])
+    with pytest.raises(ValueError, match="tp / sp"):
+        InferenceEngine(PRESETS["tiny-kimi"](),
+                        EngineConfig(num_pages=32, max_pages_per_seq=8),
+                        mesh=mesh)
+
+
+def test_int8_weights_run_and_differ(tiny):
+    """The parity control's path: every QUANT_KEYS leaf int8, the grouped
+    kernels widening the codes; close to, and not equal to, float32."""
+    mcfg, sz, weights = tiny
+    toks = [int(t) for t in np.random.default_rng(4).integers(0, 512, 40)]
+    out = {}
+    for quant in ("none", "int8"):
+        eng = engine(mcfg, weights, quant=quant, attn_backend="pallas")
+        s = Sequence(request_id=0, prompt_tokens=toks, max_new_tokens=4)
+        eng.prefill(s)
+        out[quant] = probe_logits(eng, s, 39)
+    err = (out["int8"] - out["none"]) / np.std(out["none"])
+    assert 1e-3 < np.sqrt(np.mean(err ** 2)) < 0.1
+
+
+# -------------------------------------------------- the comparison's control
+@pytest.fixture(scope="module")
+def planted():
+    """bench/planted_fault.py on the tiny configuration: parity.py's run
+    of one seed with each fault planted in the routed-expert path."""
+    import subprocess
+    import sys
+
+    p = subprocess.run(
+        [sys.executable, os.path.join(REPO, "bench", "planted_fault.py"),
+         "--manifest", os.path.join(REPO, "bench", "tests", "rehearsal",
+                                    "BENCHMARK_kimi.json"),
+         "--workload", "tiny-kimi_tiny-doc-reask", "--seeds", "2147483700"],
+        capture_output=True, text=True, timeout=900,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    lines = [json.loads(ln) for ln in p.stdout.splitlines()
+             if ln.startswith("{")]
+    return p.returncode, {ln["fault"]: ln for ln in lines if "fault" in ln}
+
+
+@pytest.mark.parametrize("fault", ["routed_zero", "next_expert",
+                                   "first_layer", "gates_doubled"])
+def test_a_planted_routed_fault_reads_not_correct(planted, fault):
+    """The comparison that decides ``correct`` sees the routed experts: a
+    routed part that is zero, computed with another expert's or another
+    layer's weights, or gated wrongly is over the limits by far."""
+    rc, by_fault = planted
+    assert rc == 0
+    res = by_fault[fault]
+    assert res["ok"] is False
+    assert res["rms"] > 20 * res["limit"]["rms"]

@@ -410,6 +410,34 @@ def test_chaos_injection():
     _run(srv, scenario)
 
 
+@pytest.mark.parametrize("ignore_eos", [False, True])
+def test_ignore_eos_generates_to_max_tokens(ignore_eos):
+    """--ignore-eos: a request whose sampled token is the tokenizer's EOS
+    goes on to max_tokens (load tests on random weights); without it the
+    request stops there."""
+    cfg = FrameworkConfig(
+        model=tiny_llama(vocab_size=512),
+        engine=EngineConfig(page_size=8, num_pages=32, max_pages_per_seq=4,
+                            max_batch_size=2, prefill_buckets=(16,)),
+        server=ServerConfig(model_name="t", tokenizer="byte", warmup=False,
+                            ignore_eos=ignore_eos))
+    srv = InferenceServer(cfg)
+    ask = {"model": "m", "prompt": "abc", "max_tokens": 6, "stream": False,
+           "temperature": 0}
+
+    async def scenario(client):
+        first = await (await client.post("/api/generate", json=ask)).json()
+        assert first["eval_count"] == 6
+        # Greedy is deterministic: make the third token the EOS.
+        srv.tokenizer.eos_token_id = first["context"][-4]
+        again = await (await client.post("/api/generate", json=ask)).json()
+        assert (again["eval_count"] == 6) if ignore_eos else (
+            again["eval_count"] < 6)
+        assert again["done_reason"] == ("length" if ignore_eos else "stop")
+
+    _run(srv, scenario)
+
+
 @pytest.mark.parametrize("quant,kv_quant", [
     ("none", "none"),
     # The quantized-replica combination re-proves what test_quant and

@@ -179,3 +179,116 @@ def test_layer_loop_reads_the_pool_in_place(chip, kernel, kv_quant, layer):
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo
     assert pool_copies(hlo, pool.shape) == []
+
+
+# ---------------------------------------------------------------------------
+# Kimi-K2 / DeepSeek-V3: the latent-attention kernels and the grouped expert
+# matmuls at the published widths (64 heads over one 512 + 64 entry stored
+# 640 wide; experts 7168 x 2048, 12 held in each of 6 layers).
+# ---------------------------------------------------------------------------
+
+KIMI = dict(heads=64, rank=512, rope=64, width=640, layers=7, mp=672,
+            d=7168, f=2048, held=12, expert_layers=6)
+
+
+@pytest.mark.parametrize("kernel,b,seq", [("decode", 32, 1),
+                                          ("prefill", 1, 1024),
+                                          ("prefill", 4, 64)])
+def test_latent_kernel_compiles_for_v5e_and_reads_the_pool_in_place(
+        chip, kernel, b, seq):
+    """Inside the model's pattern: the donated stacked latent pool carried
+    through a scan over layers, scattered by ``write_latent`` right before
+    the kernel reads it at the scan's index. No instruction may produce
+    one layer's pool or copy the stacked one (a 576-wide pool WOULD be
+    copied whole: kernels/mla_attention.py)."""
+    from tpu_inference.engine import kv_cache as kvc
+    from tpu_inference.kernels import mla_attention as mla
+
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir,
+                                    "benchmarks"))
+    from aot_rehearsal import pool_copies
+
+    k = KIMI
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    pool = s((k["layers"], NUM_PAGES * 8, PAGE, k["width"]), jnp.bfloat16)
+    kv = kvc.KVPages(k=pool, v=None)
+
+    def step(kv, q, entry, bt, kv_len, slots):
+        def body(carry, layer_idx):
+            kv, q = carry
+            kv = kvc.write_latent(kv, layer_idx, entry, slots)
+            if kernel == "decode":
+                out = mla.mla_decode_attention(
+                    q[:, 0], kv.k, layer_idx, bt, kv_len, rank=k["rank"],
+                    scale=0.13)[:, None]
+            else:
+                out = mla.mla_prefill_attention(
+                    q, kv.k, layer_idx, bt, kv_len, kv_len - seq,
+                    rank=k["rank"], scale=0.13)
+            pad = jnp.zeros(out.shape[:-1] + (k["rope"],), out.dtype)
+            return (kv, jnp.concatenate([out, pad], -1)), None
+
+        (kv, q), _ = jax.lax.scan(body, (kv, q), jnp.arange(k["layers"]))
+        return kv, q
+
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+        kv, s((b, seq, k["heads"], k["rank"] + k["rope"]), jnp.bfloat16),
+        s((b, seq, k["rank"] + k["rope"]), jnp.bfloat16),
+        s((b, k["mp"]), jnp.int32), s((b,), jnp.int32),
+        s((b, seq), jnp.int32)).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    assert pool_copies(hlo, pool.shape) == []
+
+
+@pytest.mark.parametrize("tokens", [32, 1024])
+@pytest.mark.parametrize("quant", ["none", "int8"])
+def test_grouped_experts_compile_for_v5e_without_a_weight_copy(
+        chip, tokens, quant):
+    """The dropless expert layer under the scan over expert layers: the
+    stacked expert weights stay out of the scan's xs and the kernels
+    address (layer, expert) themselves, so no instruction may produce one
+    layer's [E, K, N] weights."""
+    import re
+
+    from tpu_inference.kernels import moe_experts
+    from tpu_inference.models.quant import QuantizedArray
+
+    k = KIMI
+    le, e, d, f = k["expert_layers"], k["held"], k["d"], k["f"]
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def weight(kk, n):
+        if quant == "none":
+            return s((le, e, kk, n), jnp.bfloat16)
+        return QuantizedArray(q=s((le, e, kk, n), jnp.int8),
+                              scale=s((le, e, 1, n), jnp.float32))
+
+    def step(x, top_local, gates, wg, wu, wd):
+        def body(x, layer):
+            groups = moe_experts.group_pairs(top_local, gates, e,
+                                             tokens * 8 * e / 384)
+            y, _ = moe_experts.grouped_experts(x, groups, wg, wu, wd, layer,
+                                               pallas=True)
+            return (x + y.astype(x.dtype)), None
+
+        return jax.lax.scan(body, x, jnp.arange(le))[0]
+
+    compiled = jax.jit(step).lower(
+        s((tokens, d), jnp.bfloat16), s((tokens, 8), jnp.int32),
+        s((tokens, 8), jnp.float32), weight(d, f), weight(d, f),
+        weight(f, d)).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") >= 2
+    one_layer = {f"{e},{d},{f}", f"{e},{f},{d}", f"1,{e},{d},{f}",
+                 f"1,{e},{f},{d}"}
+    made = [m for m in re.findall(
+        r"^\s*(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]*)\]\S* ([\w\-]+)\(", hlo,
+        re.M) if m[0] in one_layer and m[1] not in (
+            "parameter", "get-tuple-element", "bitcast", "tuple", "while")]
+    assert made == []

@@ -11,7 +11,7 @@ dict keys (`url`, `model`, `temperature`, `max_tokens`, `trace_path`,
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Any, Optional
 
 import jax.numpy as jnp
 
@@ -34,15 +34,36 @@ class RopeScaling:
 
 
 @dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rope rescale (DeepSeek-V3 / Kimi-K2 ``rope_scaling`` of type
+    "yarn"): channels that turn more than ``beta_fast`` times over
+    ``original_max_len`` keep their frequency, those that turn fewer than
+    ``beta_slow`` times run ``factor`` x slower, a linear ramp between.
+    ``mscale`` / ``mscale_all_dim`` scale cos/sin by the ratio of their
+    ``0.1 * m * ln(factor) + 1`` terms (1 when equal) and the attention
+    softmax by the square of ``mscale_all_dim``'s
+    (models/common.py rope_frequencies, yarn_mscale)."""
+
+    factor: float = 32.0
+    original_max_len: int = 4096
+    beta_fast: float = 1.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     """Architecture hyperparameters for a decoder-only transformer.
 
     Covers Llama-style (RMSNorm/RoPE/GQA/SwiGLU), Mixtral (adds MoE fields)
-    and GPT-2 (LayerNorm/learned-positional/GELU) families.
+    GPT-2 (LayerNorm/learned-positional/GELU) and DeepSeek-V3 / Kimi-K2
+    (latent attention, sigmoid-routed experts beside a shared one, a
+    chip's share of an expert-parallel deployment) families.
     """
 
     name: str = "llama"
-    family: str = "llama"  # "llama" | "mixtral" | "gpt2"
+    family: str = "llama"  # "llama" | "mixtral" | "gpt2" | "deepseek_v3"
     vocab_size: int = 32000
     d_model: int = 4096
     n_layers: int = 32
@@ -53,7 +74,8 @@ class ModelConfig:
     rope_theta: float = 500000.0
     # Llama-3.1+ checkpoints rescale rope frequencies (rope_type
     # "llama3" in HF config.json); None = vanilla rope.
-    rope_scaling: Optional[RopeScaling] = None
+    # or YarnScaling (rope_type "yarn": DeepSeek-V3 / Kimi-K2).
+    rope_scaling: Optional[Any] = None
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     # MoE (Mixtral family); n_experts == 0 means dense FFN.
@@ -84,11 +106,53 @@ class ModelConfig:
     # Gemma-7B decouples head_dim from d_model/n_heads (3072/16 heads but
     # head_dim 256). 0 = derive from d_model // n_heads.
     head_dim_override: int = 0
+    # --- family "deepseek_v3" (DeepSeek-V3 / Kimi-K2 block) ---
+    # Latent attention: the cache holds ONE (kv_lora_rank +
+    # qk_rope_head_dim)-wide entry per token per layer, shared by all
+    # query heads. kv_lora_rank == 0 means K and V per kv head.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # The first ``first_k_dense`` layers carry a dense SwiGLU of width
+    # d_ff; the rest route to experts of width moe_d_ff beside
+    # n_shared_experts always-on ones of the same width. n_experts is
+    # the router's width (every routed expert of a layer, on any chip).
+    first_k_dense: int = 0
+    moe_d_ff: int = 0
+    n_shared_experts: int = 0
+    # Router: "softmax" (Mixtral) | "sigmoid" scores with a selection
+    # bias added for the top-k only (DeepSeek-V3 noaux_tc); gates are
+    # the chosen scores, normalised over the chosen when norm_topk_prob,
+    # times routed_scaling_factor. The deepseek_v3 family runs the
+    # published pair only (sigmoid, normalised): validate().
+    moe_scoring: str = "softmax"
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    # This chip's share of an expert-parallel deployment: ep_size chips
+    # share each layer's routed experts; rank ep_rank holds experts
+    # [ep_rank * n_experts / ep_size, ...). What the absent experts
+    # would add is left out (no exchange on one chip); no routed pair
+    # that lands on a held expert is dropped.
+    ep_size: int = 1
+    ep_rank: int = 0
     dtype: jnp.dtype = jnp.bfloat16
 
     @property
     def head_dim(self) -> int:
         return self.head_dim_override or self.d_model // self.n_heads
+
+    @property
+    def latent_dim(self) -> int:
+        """Width of one latent cache entry (0 = K/V per kv head)."""
+        return (self.kv_lora_rank + self.qk_rope_head_dim
+                if self.kv_lora_rank else 0)
+
+    @property
+    def n_local_experts(self) -> int:
+        """Routed experts of one layer held on this chip."""
+        return self.n_experts // self.ep_size
 
     @property
     def n_rep(self) -> int:
@@ -101,6 +165,14 @@ class ModelConfig:
         assert self.n_heads % self.n_kv_heads == 0
         if self.n_experts:
             assert self.n_experts_per_tok <= self.n_experts
+        if self.family == "deepseek_v3":
+            assert self.kv_lora_rank and self.qk_rope_head_dim % 2 == 0
+            assert 0 <= self.first_k_dense <= self.n_layers
+            assert self.n_experts % self.ep_size == 0
+            assert 0 <= self.ep_rank < self.ep_size
+            # The one routing a preset has: models/deepseek_v3.py route()
+            # implements no other until a configuration needs it.
+            assert self.moe_scoring == "sigmoid" and self.norm_topk_prob
 
 
 # ---------------------------------------------------------------------------
@@ -193,6 +265,30 @@ def gemma_7b() -> ModelConfig:
     )
 
 
+def kimi_k2_ep32() -> ModelConfig:
+    """Kimi-K2-Instruct (the DeepSeek-V3 block) as ONE chip's share of a
+    32-way expert-parallel deployment, at every published width: 64
+    heads of latent attention, a router over all 384 experts of which
+    this chip (rank 0) holds 12, one shared expert, YaRN rope. Depth is
+    1 dense + 6 expert layers (further layers lie on later pipeline
+    stages) and the vocabulary is this chip's 1/8 (rows 0..20479):
+    bench/configs/kimi-k2-ep32-bf16.json states the cut."""
+    return ModelConfig(
+        name="kimi-k2-ep32", family="deepseek_v3", vocab_size=20480,
+        d_model=7168, n_layers=7, n_heads=64, n_kv_heads=64, d_ff=18432,
+        max_seq_len=131072, rope_theta=50000.0,
+        rope_scaling=YarnScaling(factor=32.0, original_max_len=4096,
+                                 beta_fast=1.0, beta_slow=1.0,
+                                 mscale=1.0, mscale_all_dim=1.0),
+        norm_eps=1e-6, q_lora_rank=1536, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        first_k_dense=1, moe_d_ff=2048, n_shared_experts=1,
+        n_experts=384, n_experts_per_tok=8, moe_scoring="sigmoid",
+        norm_topk_prob=True, routed_scaling_factor=2.827,
+        ep_size=32, ep_rank=0,
+    )
+
+
 def gpt2_small() -> ModelConfig:
     return ModelConfig(
         name="gpt2", family="gpt2", vocab_size=50257, d_model=768,
@@ -270,6 +366,25 @@ def tiny_phi3(vocab_size: int = 512) -> ModelConfig:
                                sliding_window=8)
 
 
+def tiny_kimi(vocab_size: int = 512) -> ModelConfig:
+    """The Kimi-K2 / DeepSeek-V3 structure at test widths: 1 dense + 2
+    expert layers, 16 routed experts top-4 of which this chip (rank 0 of
+    2) holds 8, a shared expert, rope / nope split, YaRN on."""
+    return ModelConfig(
+        name="tiny-kimi", family="deepseek_v3", vocab_size=vocab_size,
+        d_model=128, n_layers=3, n_heads=4, n_kv_heads=4, d_ff=256,
+        max_seq_len=4096, rope_theta=10000.0,
+        rope_scaling=YarnScaling(factor=8.0, original_max_len=128,
+                                 beta_fast=1.0, beta_slow=1.0),
+        norm_eps=1e-6, q_lora_rank=64, kv_lora_rank=128,
+        qk_nope_head_dim=32, qk_rope_head_dim=16, v_head_dim=32,
+        first_k_dense=1, moe_d_ff=128, n_shared_experts=1, n_experts=16,
+        n_experts_per_tok=4, moe_scoring="sigmoid", norm_topk_prob=True,
+        routed_scaling_factor=2.5, ep_size=2, ep_rank=0,
+        dtype=jnp.float32,
+    )
+
+
 def tiny_gpt2(vocab_size: int = 512) -> ModelConfig:
     return ModelConfig(
         name="tiny-gpt2", family="gpt2", vocab_size=vocab_size, d_model=128,
@@ -289,6 +404,7 @@ PRESETS = {
     "gemma-7b": gemma_7b,
     "phi-3-mini": phi3_mini,
     "gpt2": gpt2_small,
+    "kimi-k2-ep32": kimi_k2_ep32,
     "tiny-llama": tiny_llama,
     "tiny-llama-fatkv": tiny_llama_fatkv,
     "tiny-qwen2": tiny_qwen2,
@@ -297,6 +413,7 @@ PRESETS = {
     "tiny-mistral": tiny_mistral,
     "tiny-phi3": tiny_phi3,
     "tiny-gpt2": tiny_gpt2,
+    "tiny-kimi": tiny_kimi,
 }
 
 
@@ -871,6 +988,10 @@ class ServerConfig:
     # Class assumed for requests without an X-Priority header:
     # "interactive" | "batch" | "background".
     default_class: str = "interactive"
+    # Generate to ``max_tokens`` whatever is sampled: no request stops on
+    # the tokenizer's EOS (CLI --ignore-eos). For load tests on random
+    # weights, whose greedy argmax hits the EOS id by chance.
+    ignore_eos: bool = False
     # Per-class router-side deferral queues: when the fleet is at the
     # admission cap, batch/background requests park in a bounded
     # deferral queue (drained as load drops) instead of shedding 429,
@@ -957,7 +1078,8 @@ def model_config_from_dict(d: dict) -> ModelConfig:
         d["dtype"] = getattr(jnp, dtype)
     rs = d.get("rope_scaling")
     if isinstance(rs, dict):
-        d["rope_scaling"] = RopeScaling(**rs)
+        d["rope_scaling"] = (YarnScaling if "beta_fast" in rs
+                             else RopeScaling)(**rs)
     return ModelConfig(**d)
 
 

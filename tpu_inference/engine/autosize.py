@@ -82,8 +82,23 @@ def chip_spec(device=None) -> Optional[ChipSpec]:
             f"{', '.join(sorted(CHIP_SPECS))})") from None
 
 
+def active_param_count(model_cfg) -> int:
+    """Parameters one token position multiplies through: for a routed
+    model, its expected share of the experts HELD HERE."""
+    from tpu_inference.models.registry import family_fn
+
+    own = family_fn(model_cfg, "param_count")
+    return own(model_cfg, True) if own else estimate_param_count(model_cfg)
+
+
 def estimate_param_count(model_cfg) -> int:
-    """Parameter count from the architecture config (norms elided)."""
+    """Parameter count from the architecture config (norms elided); a
+    family whose module counts its own (``param_count``) is asked."""
+    from tpu_inference.models.registry import family_fn
+
+    own = family_fn(model_cfg, "param_count")
+    if own:
+        return own(model_cfg, False)
     d, f, L, V = (model_cfg.d_model, model_cfg.d_ff, model_cfg.n_layers,
                   model_cfg.vocab_size)
     kv_w = model_cfg.n_kv_heads * model_cfg.head_dim
@@ -127,6 +142,11 @@ def kv_bytes_per_token(model_cfg, kv_quant: str = "none") -> int:
     kv-head) f32 scale; int4: nibble-packed codes (D/2 bytes) + the
     same f32 scale — engine/kv_cache.py layouts."""
     L = model_cfg.n_layers
+    if model_cfg.latent_dim:
+        # One latent entry per token per layer for all heads, at the
+        # pool's stored (lane-padded) width; never quantized.
+        from tpu_inference.engine.kv_cache import latent_width
+        return L * latent_width(model_cfg) * 2
     hkv = model_cfg.n_kv_heads
     d = model_cfg.head_dim
     if kv_quant == "int8":
@@ -242,7 +262,11 @@ def auto_host_cache_pages(model_cfg, *, kv_quant: str = "none",
     byte cost in the serving kv_quant layout. The reserve keeps the OS,
     the Python heap, and tokenizer/weight staging out of the tier's
     budget; 0 when the machine has no headroom (the tier then simply
-    stays off rather than inviting the OOM killer)."""
+    stays off rather than inviting the OOM killer). 0 too for a latent
+    (MLA) pool: the tier's page copies assume K and V pools, so 'auto'
+    leaves it off there and an explicit size is refused by the engine."""
+    if model_cfg.latent_dim:
+        return 0
     avail = (detect_host_ram_bytes() if host_ram_bytes is None
              else int(host_ram_bytes))
     budget = max(0, int((avail - reserve_bytes) * fraction))

@@ -43,7 +43,49 @@ from tpu_inference.engine.sampling import (
     sample,
 )
 from tpu_inference.engine.speculative import NGRAM_SCAN_CAP, ngram_propose
-from tpu_inference.models.registry import build_model, get_model_fns
+from tpu_inference.models.registry import (build_model, family_fn,
+                                          get_model_fns)
+
+
+def make_latent_attn(cfg: ModelConfig, page_size: int,
+                     block_tables: jax.Array, positions: jax.Array,
+                     valid: jax.Array, q_offset: jax.Array,
+                     kv_len: jax.Array, attn_backend: str = "dense",
+                     interpret: bool = False):
+    """make_paged_attn for a latent (MLA) pool: the AttentionFn's second
+    shape (models/common.py). ``attn(layer, q [B,S,H,R+Dr], entry
+    [B,S,R+Dr], None, kv)`` writes the entries into the pool, then
+    attends in the absorbed form over the pages — the chunk's own tokens
+    and any cached prefix alike — and returns the weighted latents
+    [B,S,H,R]. The kernels get the stacked pool and the layer index
+    (kernels/mla_attention.py). ``attn.pallas`` / ``attn.interpret`` tell
+    the model's expert layer which grouped matmul to run
+    (kernels/moe_experts.py), ``attn.valid`` which rows hold a token."""
+    from tpu_inference.kernels import mla_attention as mla
+
+    rank, scale = cfg.kv_lora_rank, family_fn(cfg, "softmax_scale")(cfg)
+    pallas = attn_backend == "pallas"
+
+    def attn(layer_idx, q, entry, v, kv: KVPages):
+        del v
+        slots = kvc.slot_mapping(block_tables, positions, valid, page_size)
+        kv = kvc.write_latent(kv, layer_idx, entry, slots)
+        if pallas and q.shape[1] == 1:
+            out = mla.mla_decode_attention(
+                q[:, 0], kv.k, layer_idx, block_tables, kv_len, rank=rank,
+                scale=scale, interpret=interpret)[:, None]
+        elif pallas:
+            out = mla.mla_prefill_attention(
+                q, kv.k, layer_idx, block_tables, kv_len, q_offset,
+                rank=rank, scale=scale, interpret=interpret)
+        else:
+            out = mla.mla_attention_dense(
+                q, kv.k, layer_idx, block_tables, kv_len, q_offset,
+                rank=rank, scale=scale)
+        return out, kv
+
+    attn.pallas, attn.interpret, attn.valid = pallas, interpret, valid
+    return attn
 
 
 def make_paged_attn(cfg: ModelConfig, page_size: int, block_tables: jax.Array,
@@ -86,6 +128,12 @@ def make_paged_attn(cfg: ModelConfig, page_size: int, block_tables: jax.Array,
     CPU); the serving path compiles them (False).
     """
     from tpu_inference.models.common import dense_causal_attention
+
+    if cfg.latent_dim:
+        return make_latent_attn(cfg, page_size, block_tables, positions,
+                                valid, q_offset, kv_len,
+                                attn_backend=attn_backend,
+                                interpret=interpret)
 
     def _sp_prefill(q, k, v):
         from functools import partial as _partial
@@ -186,6 +234,36 @@ def make_paged_attn(cfg: ModelConfig, page_size: int, block_tables: jax.Array,
         return out, kv
 
     return attn
+
+
+def _refuse_unsupported_latent(model_cfg: ModelConfig,
+                               engine_cfg: EngineConfig, mesh,
+                               draft_cfg) -> None:
+    """What the latent-attention / routed-expert family does not run
+    yet, said at construction and not at the first request."""
+    what = []
+    if mesh is not None and any(int(mesh.shape.get(ax, 1)) > 1
+                                for ax in ("tp", "sp")):
+        what.append("tp / sp > 1 (no param shardings, no sharded latent "
+                    "pool, no expert exchange: parallel/shardings.py)")
+    if engine_cfg.kv_quant != "none":
+        what.append(f"kv_quant={engine_cfg.kv_quant!r} (the latent pool "
+                    "is stored in the model dtype)")
+    if (engine_cfg.num_speculative_tokens > 0 or draft_cfg is not None
+            or engine_cfg.spec_mode != "draft"):
+        what.append("speculative decoding (draft or ngram)")
+    if engine_cfg.host_cache_pages:
+        what.append("the host KV tier (host_cache_pages > 0: offload / "
+                    "restore / serialize assume K and V pools)")
+    if engine_cfg.quant == "int4":
+        what.append("quant='int4' (the grouped expert kernels take bf16 "
+                    "or int8 weights)")
+    if engine_cfg.role != "mixed":
+        what.append(f"role={engine_cfg.role!r} (P/D handoff serializes K "
+                    "and V pages)")
+    if what:
+        raise ValueError(f"{model_cfg.name} (latent attention) does not "
+                         "support: " + "; ".join(what))
 
 
 class ChaosStepError(RuntimeError):
@@ -351,6 +429,9 @@ class InferenceEngine:
         t_boot = time.perf_counter()
         self.model_cfg = model_cfg
         self.engine_cfg = engine_cfg
+        if model_cfg.latent_dim:
+            _refuse_unsupported_latent(model_cfg, engine_cfg, mesh,
+                                       draft_cfg)
         self.mod = get_model_fns(model_cfg)
         # Resolve the attention backend: constructor arg wins, then
         # EngineConfig; "auto" = the Pallas paged kernels on a TPU, the
@@ -439,12 +520,19 @@ class InferenceEngine:
         # dispatch donates self.kv, so other threads (health, hello)
         # must never touch the array to ask.
         self._devices = sorted(self.kv.k.devices(), key=lambda d: d.id)
+        # Expert-routing counts (family deepseek_v3), summed off the
+        # decode readbacks: models/deepseek_v3.py MOE_STATS + one slot
+        # per held expert. None for every other family.
+        self.moe_stats = (np.zeros(self.kv.aux.shape, np.int64)
+                          if self.kv.aux is not None else None)
         t_pool = time.perf_counter()
         self.allocator = PageAllocator(engine_cfg.num_pages)
         # Step-phase telemetry (telemetry.py): dispatch/bubble histograms
         # + read-through page/param gauges. TPU_INF_TELEMETRY=0 swaps in
         # no-op metrics (the overhead-comparison arm).
         self.telemetry = telemetry.EngineTelemetry(self)
+        if self.moe_stats is not None:
+            self.telemetry.bind_moe(self)
         # Boot phases (gauges set once; a caller that loaded a
         # checkpoint itself adds its load time to the first).
         self.boot_s = {"weights": t_weights - t_boot,
@@ -906,6 +994,12 @@ class InferenceEngine:
             toks = jnp.where(act, toks, tokens)
             window = roll_window(window, toks, act)
             out = jnp.where(act, toks, -1)
+            if kv.aux is not None:
+                # The model's routing counts since the last emission (a
+                # prefill's included) leave with the step's tokens: the
+                # one readback there is (_fold_moe_stats).
+                out = jnp.concatenate([out, kv.aux])
+                kv = kv._replace(aux=jnp.zeros_like(kv.aux))
             alive = alive & jnp.where(act, toks != eos_ids, True)
             ctx_lens = ctx_lens + act.astype(jnp.int32)
             return (kv, toks, ctx_lens, alive, window), out
@@ -1382,6 +1476,13 @@ class InferenceEngine:
                                      chunk_tokens=chunk_tokens)
         self._note_decode_entry(t0)
         return out, seq, t0, self._note_decode_exit(t0, t1)
+
+    def _fold_moe_stats(self, outs: np.ndarray) -> None:
+        """The routing counts that rode a decode readback ``outs``
+        [K, rung + n] behind the lanes' tokens (_decode_multi_fn)."""
+        if self.moe_stats is not None:
+            self.moe_stats += outs[:, -len(self.moe_stats):].sum(
+                axis=0, dtype=np.int64)
 
     def _next_key(self) -> jax.Array:
         self._step_count += 1
@@ -2829,6 +2930,7 @@ class InferenceEngine:
         self._note_decode_entry(t0)
         # Synchronous round: the call's wall is enqueue + readback.
         outs, _, t_done = self._wait(dseq, lambda: np.asarray(outs))  # [K, B]
+        self._fold_moe_stats(outs)
         dt = self._note_decode_exit(t0, t_done)
         kv_read = sum(s.ctx_len for s in active_seqs) * k_steps
 
@@ -3119,6 +3221,7 @@ class InferenceEngine:
         outs, t0, t_done = self._wait(call["seq"], read)
         sync_dt = t_done - t0
         if outs is not None:
+            self._fold_moe_stats(outs)
             # Chunk-only waits stay out of decode_sync_s (pure prefill
             # device time, not a decode sync).
             self.telemetry.decode_sync_s.observe(sync_dt)
@@ -3392,6 +3495,7 @@ class InferenceEngine:
                                         for rid in active_seqs}
         for outs in outs_all:
             outs = np.asarray(outs)
+            self._fold_moe_stats(outs)
             for seq in active_seqs:
                 got = [int(t) for t in outs[:, seq.slot] if t >= 0]
                 seq.ctx_len += len(got)

@@ -52,12 +52,22 @@ class KVPages(NamedTuple):
     (uint8 = packed int4, int8 = int8), which stays static under jit —
     a bool field here would become a traced pytree leaf inside the
     decode-step carry.
+
+    A LATENT pool (DeepSeek-V3 / Kimi-K2 attention, ``latent_width``
+    below) is ``k`` alone, ``[L, num_pages, page_size, W]``: one entry
+    per token per layer for all heads, ``v`` None. ``aux`` is an int32
+    vector of counters the MODEL defines and adds to inside any graph
+    (its family module's ``n_aux_stats``; models/deepseek_v3.py: routing
+    counts); the decode graphs emit it behind the step's tokens and
+    clear it, so a prefill's counts leave with the next readback and no
+    graph gains an output or a sync of its own.
     """
 
     k: jax.Array
-    v: jax.Array
+    v: Optional[jax.Array]
     k_scale: Optional[jax.Array] = None
     v_scale: Optional[jax.Array] = None
+    aux: Optional[jax.Array] = None
 
     @property
     def num_pages(self) -> int:
@@ -72,8 +82,50 @@ class KVPages(NamedTuple):
         return self.k_scale is not None
 
     @property
+    def latent(self) -> bool:
+        return self.v is None
+
+    @property
     def packed_int4(self) -> bool:
         return self.k.dtype == jnp.uint8
+
+
+def latent_width(model_cfg: ModelConfig) -> int:
+    """Stored width of one latent entry: kv_lora_rank + qk_rope_head_dim
+    rounded up to the chip's 128 lanes (576 -> 640; the tail stays zero).
+    A 576-wide pool would be kept page-dim-minor by the v5e compiler and
+    copied whole in front of every kernel call
+    (kernels/mla_attention.py)."""
+    return -(-model_cfg.latent_dim // 128) * 128
+
+
+def alloc_latent_pages(model_cfg: ModelConfig, engine_cfg: EngineConfig,
+                       dtype=None) -> KVPages:
+    """A latent pool, with the model's counter vector beside it where
+    its family module asks for one (``n_aux_stats``)."""
+    from tpu_inference.models.registry import family_fn
+
+    n_aux = family_fn(model_cfg, "n_aux_stats")
+    if engine_cfg.kv_quant != "none":
+        raise ValueError(
+            f"{model_cfg.name}: kv_quant={engine_cfg.kv_quant!r} is not "
+            "implemented for a latent (MLA) pool; use kv_quant='none'")
+    shape = (model_cfg.n_layers, engine_cfg.num_pages, engine_cfg.page_size,
+             latent_width(model_cfg))
+    pool = jax.jit(lambda: jnp.zeros(shape, dtype or model_cfg.dtype))()
+    return KVPages(k=pool, v=None, aux=jnp.zeros(
+        (n_aux(model_cfg),), jnp.int32) if n_aux else None)
+
+
+def write_latent(kv: KVPages, layer_idx: jax.Array, entry: jax.Array,
+                 slots: jax.Array) -> KVPages:
+    """Scatter latent entries [B, S, R + Dr] into the pool at ``slots``."""
+    L, P, pg, W = kv.k.shape
+    entry = jnp.pad(entry.astype(kv.k.dtype),
+                    ((0, 0), (0, 0), (0, W - entry.shape[-1])))
+    flat = kv.k.reshape(L, P * pg, W).at[layer_idx, slots.reshape(-1)].set(
+        entry.reshape(-1, W))
+    return kv._replace(k=flat.reshape(L, P, pg, W))
 
 
 def alloc_kv_pages(model_cfg: ModelConfig, engine_cfg: EngineConfig,
@@ -81,6 +133,9 @@ def alloc_kv_pages(model_cfg: ModelConfig, engine_cfg: EngineConfig,
                    scale_sharding=None) -> KVPages:
     """Allocate the pool; with ``sharding`` each chip materializes only its
     shard (never the full replicated pool — at 70B scale that would OOM)."""
+    if model_cfg.latent_dim:
+        assert sharding is None, "a latent pool is not sharded"
+        return alloc_latent_pages(model_cfg, engine_cfg, dtype)
     shape = (model_cfg.n_layers, engine_cfg.num_pages, engine_cfg.page_size,
              model_cfg.n_kv_heads, model_cfg.head_dim)
     dtype = dtype or model_cfg.dtype

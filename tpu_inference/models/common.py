@@ -8,17 +8,23 @@ Conventions:
 - Attention is *injected*: model forward passes take an ``AttentionFn``
   ``attn(layer_idx, q, k, v, kv) -> (out, kv)`` with q [B,S,Hq,D] and
   k/v [B,S,Hkv,D]; the engine's paged-cache attention, the dense causal
-  test path, and the Pallas kernels all fit this signature.
+  test path, and the Pallas kernels all fit this signature. Latent
+  attention (models/deepseek_v3.py) uses the same call with a second
+  shape: q [B,S,H,R+Dr] is the ABSORBED query (latent part | rope part),
+  k [B,S,R+Dr] the one cache entry per token, v None; the result is the
+  per-head weighted sum of latents [B,S,H,R].
 """
 
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import Any, Callable, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from tpu_inference.config import YarnScaling
 from tpu_inference.models.quant import qdot
 
 # attn(layer_idx, q, k, v, kv_state) -> (attn_out, kv_state)
@@ -71,6 +77,8 @@ def rope_frequencies(head_dim: int, theta: float,
     """
     exponent = jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim
     inv_freq = 1.0 / (theta ** exponent)
+    if isinstance(scaling, YarnScaling):
+        return _yarn_frequencies(inv_freq, head_dim, theta, scaling)
     if scaling is not None:
         wavelen = 2.0 * jnp.pi / inv_freq
         smooth = ((scaling.original_max_len / wavelen
@@ -87,6 +95,31 @@ def rope_frequencies(head_dim: int, theta: float,
     return inv_freq
 
 
+def _yarn_frequencies(inv_freq: jax.Array, dim: int, theta: float,
+                      sc: YarnScaling) -> jax.Array:
+    """YaRN (DeepSeek-V3 ``_compute_yarn_parameters``): channel i keeps
+    its frequency below the ramp, runs ``factor`` x slower above it.
+    The ramp spans the channels that turn ``beta_fast`` .. ``beta_slow``
+    times over the original context (floor / ceil, clamped to the
+    table); an empty ramp is widened by 0.001 as published."""
+    def turns_to_dim(turns):
+        return (dim * math.log(sc.original_max_len / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(turns_to_dim(sc.beta_fast)), 0)
+    high = min(math.ceil(turns_to_dim(sc.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return inv_freq / sc.factor * ramp + inv_freq * (1.0 - ramp)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature term, 0.1 * m * ln(factor) + 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
 def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
                scaling=None) -> jax.Array:
     """Rotary position embedding.
@@ -100,6 +133,10 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
     angles = positions[..., None].astype(jnp.float32) * inv_freq  # [B,S,half]
     cos = jnp.cos(angles)[:, :, None, :]                      # [B,S,1,half]
     sin = jnp.sin(angles)[:, :, None, :]
+    if isinstance(scaling, YarnScaling):
+        m = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        cos, sin = cos * m, sin * m
     x1 = x[..., :half].astype(jnp.float32)
     x2 = x[..., half:].astype(jnp.float32)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)

@@ -63,6 +63,12 @@ GROUP_SIZE = 128
 QUANT_KEYS = frozenset({
     "wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "lm_head",
     "w_qkv", "w_proj", "w_fc", "w_out",
+    # models/deepseek_v3.py: query / latent down- and up-projections,
+    # the shared expert, the routed experts (int8 only: the grouped
+    # kernels widen in VMEM). ``wkv_b`` stays in the model dtype: it is
+    # used absorbed, as two per-head einsums, not through qdot.
+    "wq_a", "wq_b", "wkv_a", "ws_gate", "ws_up", "ws_down",
+    "we_gate", "we_up", "we_down",
 })
 
 

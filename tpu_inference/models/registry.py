@@ -11,9 +11,17 @@ from tpu_inference.config import ModelConfig
 
 
 def get_model_fns(cfg: ModelConfig) -> types.ModuleType:
-    from tpu_inference.models import gpt2, llama, mixtral
+    from tpu_inference.models import deepseek_v3, gpt2, llama, mixtral
 
-    return {"llama": llama, "mixtral": mixtral, "gpt2": gpt2}[cfg.family]
+    return {"llama": llama, "mixtral": mixtral, "gpt2": gpt2,
+            "deepseek_v3": deepseek_v3}[cfg.family]
+
+
+def family_fn(cfg: ModelConfig, name: str):
+    """A function the family's module defines where it differs from the
+    dense default (``param_count``, ``attn_pair_dim``, ``n_aux_stats``),
+    or None: shared layers ask here and never name a family."""
+    return getattr(get_model_fns(cfg), name, None)
 
 
 def build_model(cfg: ModelConfig, seed: int = 0,

@@ -130,6 +130,10 @@ def main() -> None:
                         "interactive lanes preempt batch/background "
                         "ones at the admission watermark instead of "
                         "shedding 429")
+    p.add_argument("--ignore-eos", action="store_true",
+                   help="never stop a request on the tokenizer's EOS: "
+                        "generate to max_tokens (load tests on random "
+                        "weights, whose argmax hits EOS by chance)")
     p.add_argument("--class-queue-depth", type=int, default=0,
                    help="per-class deferral queue depth: over the "
                         "admission cap, batch/background requests park "
@@ -612,6 +616,7 @@ def main() -> None:
                               autoscale_idle_window_s=(
                                   args.autoscale_idle_window_s),
                               default_class=args.default_class,
+                              ignore_eos=args.ignore_eos,
                               class_queue_depth=args.class_queue_depth,
                               step_watchdog_s=args.step_watchdog_s,
                               quarantine_after_failures=args.quarantine_after,

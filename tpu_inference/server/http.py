@@ -837,7 +837,8 @@ class InferenceServer:
                        top_p=top_p, top_k=top_k, seed=seed,
                        repeat_penalty=repeat_penalty,
                        repeat_last_n=repeat_last_n,
-                       eos_token_id=self.tokenizer.eos_token_id,
+                       eos_token_id=(None if self.cfg.server.ignore_eos
+                                     else self.tokenizer.eos_token_id),
                        trace_id=trace_id, priority_class=pcls)
         telemetry.log_event(
             "request_received", level="info", request_id=trace_id,

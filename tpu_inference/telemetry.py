@@ -1323,6 +1323,10 @@ class StepCostModel:
                  head_dim: int, weight_bytes: int, kv_token_bytes: int,
                  peak_flops: Optional[float],
                  peak_hbm_bw: Optional[float]):
+        # ``n_params``: parameters a token position multiplies through
+        # (all of a dense model's; a routed model's ACTIVE ones).
+        # ``head_dim``: an attention pair costs 4 x n_heads x head_dim
+        # FLOPs (latent attention: (latent entry + latent rank) / 2).
         self.n_params = int(n_params)
         self.n_layers = int(n_layers)
         self.n_heads = int(n_heads)
@@ -1337,8 +1341,16 @@ class StepCostModel:
         from tpu_inference.engine import autosize
         mcfg, ecfg = engine.model_cfg, engine.engine_cfg
         chip = autosize.chip_spec()
-        return cls(n_params=engine.n_params, n_layers=mcfg.n_layers,
-                   n_heads=mcfg.n_heads, head_dim=mcfg.head_dim,
+        from tpu_inference.models.registry import family_fn
+        # A routed model: a token multiplies through its ACTIVE
+        # parameters, not every expert the chip stores; latent attention
+        # has its own cost per pair. The family's module says.
+        own = family_fn(mcfg, "param_count")
+        n_params = own(mcfg, True) if own else engine.n_params
+        pair = family_fn(mcfg, "attn_pair_dim")
+        head_dim = pair(mcfg) if pair else mcfg.head_dim
+        return cls(n_params=n_params, n_layers=mcfg.n_layers,
+                   n_heads=mcfg.n_heads, head_dim=head_dim,
                    weight_bytes=autosize.weight_bytes(mcfg, ecfg.quant),
                    kv_token_bytes=autosize.kv_bytes_per_token(
                        mcfg, ecfg.kv_quant),
@@ -2474,6 +2486,48 @@ class EngineTelemetry:
         r.counter("tpu_inf_spec_throttles_total",
                   "Sequences throttled to γ=0 by the acceptance EWMA",
                   fn=lambda: engine.spec_throttles_total)
+
+    def bind_moe(self, engine) -> None:
+        """Read-through expert-routing counters (family deepseek_v3):
+        the model counts on the device (models/deepseek_v3.py MOE_STATS),
+        the counts ride the decode token readback out, the engine sums
+        them in ``engine.moe_stats`` (engine._fold_moe_stats)."""
+        if not self.enabled:
+            return
+        from tpu_inference.models.deepseek_v3 import MOE_STATS
+
+        r, st = self.registry, engine.moe_stats
+        at = {name: i for i, name in enumerate(MOE_STATS)}
+        r.counter("tpu_inf_moe_tokens_total",
+                  "Token positions routed, summed over expert layers",
+                  fn=lambda: int(st[at["tokens"]]))
+        r.counter("tpu_inf_moe_local_pairs_total",
+                  "Routed (token, expert) pairs whose expert this chip "
+                  "holds", fn=lambda: int(st[at["local_pairs"]]))
+        r.counter("tpu_inf_moe_dropped_pairs_total",
+                  "Local pairs the grouped expert rounds did not compute "
+                  "(dropless: stays 0)",
+                  fn=lambda: int(st[at["local_pairs"]]
+                                 - st[at["computed_pairs"]]))
+        r.counter("tpu_inf_moe_busiest_expert_pairs_total",
+                  "Pairs on the busiest held expert of each expert-layer "
+                  "call (a decode step's or a prefill chunk's), summed: "
+                  "over local_pairs / held experts it is the load "
+                  "imbalance the grouped matmul sees",
+                  fn=lambda: int(st[at["busiest_pairs"]]))
+        r.counter("tpu_inf_moe_distinct_experts_total",
+                  "Held experts with at least one pair, summed over "
+                  "decode steps and expert layers",
+                  fn=lambda: int(st[at["distinct_experts"]]))
+        r.counter("tpu_inf_moe_decode_layer_steps_total",
+                  "Decode steps x expert layers behind "
+                  "tpu_inf_moe_distinct_experts_total",
+                  fn=lambda: int(st[at["decode_layers"]]))
+        for e in range(len(st) - len(MOE_STATS)):
+            r.counter("tpu_inf_moe_expert_pairs_total",
+                      "Routed pairs per held expert",
+                      fn=lambda e=e: int(st[len(MOE_STATS) + e]),
+                      expert=str(e))
 
     def bind_host_pool(self, pool) -> None:
         """Read-through metrics over the host-RAM KV tier's capacity
