@@ -232,7 +232,11 @@ def main() -> int:
         m = compiled.memory_analysis()
         text = compiled.as_text()
         # (the HLO is one device's program: its shard of the pool)
-        copies = (pool_copies(text, kv.k.sharding.shard_shape(kv.k.shape))
+        # ... and the decode kernel's view of it, [L, P, page * Hkv, D].
+        shard = kv.k.sharding.shard_shape(kv.k.shape)
+        merged = shard[:2] + (shard[2] * shard[3],) + shard[4:]
+        copies = (pool_copies(text, shard)
+                  + (pool_copies(text, merged) if len(shard) == 5 else [])
                   if graph != "swap" else [])
         failed += bool(copies)
         if args.dump_hlo:

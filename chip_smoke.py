@@ -697,7 +697,13 @@ def _kernel_errors(cfg: dict, mcfg, interpret: bool = False) -> dict:
     win, page = mcfg.sliding_window, 16
     span = (win or 4096) + 1024
     mp = span // page
-    kv_lens = np.array([100, span // 4 + 7, span - 700, span - 1], np.int32)
+    # The decode kernel walks 256-token blocks from the window's first
+    # page: 1024 and win + 256 end on a block's last token (before and
+    # after the window binds); 0 is an idle lane of the rung, which reads
+    # nothing and must come back 0.
+    kv_lens = np.array([100, span // 4 + 7, 0, 1024, (win or 4096) + 256,
+                        span - 700, span - 1], np.int32)
+    live = kv_lens > 0
     b = len(kv_lens)
     key = jax.random.split(jax.random.PRNGKey(cfg["seed"]), 4)
     layer = 1
@@ -728,11 +734,12 @@ def _kernel_errors(cfg: dict, mcfg, interpret: bool = False) -> dict:
         got = paged_attention(q, k_pool, v_pool, layer, tables,
                               jnp.asarray(kv_lens), interpret=interpret,
                               sliding_window=win)
+        seen = jnp.asarray(np.maximum(kv_lens, 1))
         want = dense_causal_attention(
-            q[:, None], k_all, v_all,
-            q_offset=jnp.asarray(kv_lens - 1), kv_len=jnp.asarray(kv_lens),
+            q[:, None], k_all, v_all, q_offset=seen - 1, kv_len=seen,
             sliding_window=win)[:, 0]
-        out["decode"] = err(got, want)
+        check(not np.asarray(got)[~live].any(), "an idle lane's rows are not 0")
+        out["decode"] = err(got[live], want[live])
         # Prefill: a fresh 512-token chunk, and one whose first query
         # sits 96 tokens before the context passes the window.
         s_len = 512

@@ -154,9 +154,114 @@ def test_paged_kernels_read_the_named_layer(kernel, kv_quant, window, layer):
         assert not np.allclose(np.asarray(got), dense(other), atol=1e-2)
 
 
-def test_engine_pallas_backend_matches_dense():
-    """Full engine generation with the Pallas decode kernel == dense path."""
-    model_cfg = cfgs.tiny_llama(vocab_size=256)
+# ---------------------------------------------------------------------------
+# The decode kernel walks a lane's pages a BLOCK at a time (PR 27): 16
+# pages of 16 tokens here, so 256 tokens a block and, with 40 pages a
+# sequence, a last block that is half there. What a block makes new:
+# contexts that end on a block's last token, one token into the next, and
+# inside a later block's first page; a window whose first page sits in the
+# middle of what would be a block of the table; lanes of the rung that hold
+# no sequence (kv_len 0: nothing read, rows 0) or one token on the trash
+# page, between live ones.
+# ---------------------------------------------------------------------------
+
+BLOCK_PAGE, BLOCK_MP = 16, 40
+BLOCK_CASES = {
+    "block_edges": (0, [256, 257, 521, 640, 15]),
+    "idle_lanes": (0, [300, 0, 1, 256, 0, 521]),
+    "window_mid_block": (300, [600, 299, 0, 640, 316, 1]),
+    "window_one_block": (100, [400, 1, 128, 639]),
+}
+
+
+def _block_setup(rng, kv_lens, kv_quant, d, strangers_nan=False):
+    """Stacked pools (each layer different) in ``kv_quant``'s layout and
+    block tables that name exactly the pages a lane's tokens fill, the
+    rest 0 (the trash page), as the engine's allocator leaves them.
+    ``strangers_nan``: every page no row names, bar page 0, is NaN."""
+    # 8 KV heads at head_dim 128: an int8 page's scales fill 128 lanes,
+    # as the kernel's own page copies need them to.
+    hq, hkv = (16, 8) if d == 128 else (8, 2)
+    need = [-(-n // BLOCK_PAGE) for n in kv_lens]
+    n_pages = 1 + sum(need) + 7
+    dtype = jnp.bfloat16 if kv_quant == "bf16" else jnp.float32
+    k_pool = _stacked_pool(rng, n_pages, BLOCK_PAGE, hkv, d, dtype)
+    v_pool = _stacked_pool(rng, n_pages, BLOCK_PAGE, hkv, d, dtype)
+    ids = iter(rng.permutation(np.arange(1, n_pages)))
+    bt = np.zeros((len(kv_lens), BLOCK_MP), np.int32)
+    for i, n in enumerate(need):
+        bt[i, :n] = [next(ids) for _ in range(n)]
+    if strangers_nan:
+        strange = np.setdiff1d(np.arange(1, n_pages), bt.ravel())
+        assert strange.size >= 7
+        k_pool = k_pool.at[:, strange].set(jnp.nan)
+        v_pool = v_pool.at[:, strange].set(jnp.nan)
+    kv = _quantize_pools(k_pool, v_pool,
+                         "none" if kv_quant == "bf16" else kv_quant)
+    q = jnp.asarray(rng.standard_normal((len(kv_lens), hq, d)), dtype)
+    return q, kv, jnp.asarray(bt), jnp.asarray(kv_lens, jnp.int32)
+
+
+def _check_blocks(q, kv, bt, kv_len, window, layer, tol):
+    scales = ((kv.k_scale[layer], kv.v_scale[layer]) if kv.quantized
+              else (None, None))
+    got = np.asarray(paged_attention(
+        q, kv.k, kv.v, layer, bt, kv_len, *scales, interpret=True,
+        sliding_window=window), np.float32)
+    k_all, v_all = kvc.gather_kv(kv, layer, bt)
+    live = np.asarray(kv_len) > 0
+    seen = jnp.maximum(kv_len, 1)
+    want = np.asarray(common.dense_causal_attention(
+        q[:, None], k_all, v_all, q_offset=seen - 1, kv_len=seen,
+        sliding_window=window)[:, 0], np.float32)
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got[live], want[live], rtol=tol, atol=tol)
+    # A lane without a sequence read nothing: its (discarded) rows are 0.
+    assert not got[~live].any()
+
+
+# The kernel copies pages by hand where the pool's minor dim is whole
+# 128-lane tiles (head_dim 128 as float, or as int8 with 8 KV heads) and
+# lets the pipeline fetch them where it is not (head_dim 32 here; int4's
+# D / 2 bytes).
+@pytest.mark.parametrize("kv_quant,d", [
+    ("none", 128), ("bf16", 128), ("int8", 128), ("int4", 128),
+    ("none", 32), ("int8", 32)])
+@pytest.mark.parametrize("case", sorted(BLOCK_CASES))
+def test_paged_attention_walks_blocks_of_pages(case, kv_quant, d):
+    window, kv_lens = BLOCK_CASES[case]
+    rng = np.random.default_rng(27)
+    q, kv, bt, kv_len = _block_setup(rng, kv_lens, kv_quant, d)
+    _check_blocks(q, kv, bt, kv_len, window, layer=1,
+                  tol=4e-2 if kv_quant == "bf16" else 1e-4)
+
+
+@pytest.mark.parametrize("kv_quant,d", [("none", 128), ("bf16", 128),
+                                        ("none", 32)])
+@pytest.mark.parametrize("case", sorted(BLOCK_CASES))
+def test_paged_attention_fetches_no_page_a_lane_does_not_own(case, kv_quant,
+                                                             d):
+    """Every pool page that no block-table row names (trash page 0
+    excepted) is NaN: a page fetched and multiplied, even by a weight of
+    0, shows as NaN in the result."""
+    window, kv_lens = BLOCK_CASES[case]
+    rng = np.random.default_rng(28)
+    q, kv, bt, kv_len = _block_setup(rng, kv_lens, kv_quant, d,
+                                     strangers_nan=True)
+    _check_blocks(q, kv, bt, kv_len, window, layer=LAYERS - 1,
+                  tol=4e-2 if kv_quant == "bf16" else 1e-4)
+
+
+@pytest.mark.parametrize("head_dim", [32, 128])
+def test_engine_pallas_backend_matches_dense(head_dim):
+    """Full engine generation with the Pallas decode kernel == dense path:
+    at tiny-llama's own head size (pages through the pipeline) and at 128
+    (pages copied by hand, as every served model's are); a rung of 4 with
+    3 prompts, so one lane is idle (kv_len 0) throughout."""
+    import dataclasses
+
+    model_cfg = dataclasses.replace(cfgs.tiny_llama(vocab_size=256),
+                                    head_dim_override=head_dim)
     ecfg = cfgs.EngineConfig(page_size=8, num_pages=64, max_pages_per_seq=16,
                              max_batch_size=4, prefill_buckets=(16, 32),
                              decode_steps_per_call=4)

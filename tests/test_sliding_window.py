@@ -166,20 +166,37 @@ def _stacked_pools(rng, n_pages, page, hkv, d, kv_quant):
             np.asarray(codes(vq), np.float32) * np.asarray(vs)[..., None])
 
 
+# (page, pages a sequence, head_dim, window, kv_lens). "pages": a window
+# of a page and a half, contexts <W, >W, >>W. "blocks" (PR 27: the kernel
+# walks 16 pages a block, counted from the window's first page): a window
+# of 19 pages = a block and a bit, whose first page sits at table
+# positions 18, 0, 21 and 1 (none a multiple of 16); contexts that end a
+# block's last token past the window's first page, one token on, and
+# before the window binds; head_dim 128, so the pages are copied by hand.
+WINDOW_GEOMETRY = {
+    "pages": (8, 6, 16, 11, [5, 17, 41]),
+    "blocks": (16, 40, 128, 300, [600, 299, 640, 316, 544, 545]),
+}
+
+
 @pytest.mark.parametrize("layer", [0, 1, KERNEL_LAYERS - 1])
 @pytest.mark.parametrize("kv_quant", ["none", "int8", "int4"])
-def test_windowed_paged_decode_kernel_matches_dense(kv_quant, layer):
-    """The Pallas decode kernel's O(window) page walk (relative-page
-    grid + offset index maps) == the window-masked dense reference, for
-    ragged kv_lens crossing page boundaries, GQA, and the int8 / int4
-    pools, at each layer of the stacked pool."""
+@pytest.mark.parametrize("geometry", sorted(WINDOW_GEOMETRY))
+def test_windowed_paged_decode_kernel_matches_dense(geometry, kv_quant, layer):
+    """The Pallas decode kernel's O(window) page walk (blocks of pages
+    counted from the window's first page) == the window-masked dense
+    reference, for ragged kv_lens crossing page and block boundaries,
+    GQA, and the int8 / int4 pools, at each layer of the stacked pool."""
     from tpu_inference.kernels.paged_attention import paged_attention
 
     rng = np.random.default_rng(11)
-    page, mp, hq, hkv, d, window = 8, 6, 4, 2, 16, 11
-    b = 3
-    n_pages = 32
-    kv_lens = np.array([5, 17, 41], np.int32)      # <W, >W, >>W
+    page, mp, d, window, kv_lens = WINDOW_GEOMETRY[geometry]
+    # 8 KV heads at head_dim 128: an int8 page's scales fill 128 lanes,
+    # which the kernel's own page copies need.
+    hq, hkv = (16, 8) if d == 128 else (4, 2)
+    kv_lens = np.array(kv_lens, np.int32)
+    b = len(kv_lens)
+    n_pages = 2 + b * mp
     # Dense reference sees the dequantized pool of this layer.
     k_in, v_in, ks, vs, k_pool, v_pool = _stacked_pools(
         rng, n_pages, page, hkv, d, kv_quant)

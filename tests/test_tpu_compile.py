@@ -4,11 +4,13 @@ The TPU compiler is installed beside jax and compiles for a chip that is
 described, not attached (``jax.experimental.topologies``). Interpret mode
 (tests/test_kernels.py) checks what the kernels compute; only Mosaic
 checks what the chip accepts: tile alignment, VMEM, partitioning. So the
-kernels are compiled here at the published head shapes of the two models
-the roadmap's first cells use — Mistral-7B (32 q / 8 kv heads x 128,
-window 4096) and Phi-3-mini (32 / 32 x 96, window 2047) — with bf16,
-int8 and nibble-packed int4 KV pools, one decode and one prefill each,
-about two seconds a case. The pools are STACKED ([L, P, page, Hkv, D],
+kernels are compiled here at the published head shapes of the models the
+cells serve — Mistral-7B (32 q / 8 kv heads x 128, window 4096) and
+Qwen2-7B (28 / 4 x 128, no window) — and of Phi-3-mini (32 / 32 x 96,
+window 2047: MHA, and a head size that is no whole 128-lane tile), with
+bf16, int8 and nibble-packed int4 KV pools, decode at the base rung and
+at the widest a configuration serves and one prefill each, about two
+seconds a case. The pools are STACKED ([L, P, page, Hkv, D],
 the layer an int32 operand) as the engine holds them, and a last group
 compiles each kernel inside the model's pattern — a donated pool carried
 through ``lax.scan`` over layers, scattered by ``write_kv`` right before
@@ -34,10 +36,12 @@ PAGE = 16
 NUM_PAGES = 1024
 LAYERS = 32
 
-# name: (q heads, kv heads, head_dim, sliding window, pages per sequence)
+# name: (q heads, kv heads, head_dim, sliding window, pages per sequence,
+#        widest decode rung)
 HEADS = {
-    "mistral-7b": (32, 8, 128, 4096, 320),
-    "phi-3-mini": (32, 32, 96, 2047, 256),
+    "mistral-7b": (32, 8, 128, 4096, 320, 18),
+    "phi-3-mini": (32, 32, 96, 2047, 256, 16),
+    "qwen2-7b": (28, 4, 128, 0, 192, 32),
 }
 
 
@@ -86,9 +90,9 @@ def _pool(chip, hkv, d, kv_quant):
 
 @pytest.mark.parametrize("kv_quant", ["none", "int8", "int4"])
 @pytest.mark.parametrize("model", sorted(HEADS))
-@pytest.mark.parametrize("kernel", ["decode", "prefill"])
+@pytest.mark.parametrize("kernel", ["decode", "decode-widest", "prefill"])
 def test_kernel_compiles_for_v5e(chip, kernel, model, kv_quant):
-    hq, hkv, d, window, mp = HEADS[model]
+    hq, hkv, d, window, mp, widest = HEADS[model]
 
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
@@ -96,8 +100,8 @@ def test_kernel_compiles_for_v5e(chip, kernel, model, kv_quant):
     pool, scale = _pool(chip, hkv, d, kv_quant)
     if scale is not None:
         scale = s(scale.shape[1:], scale.dtype)      # one layer's
-    if kernel == "decode":
-        b = 8
+    if kernel != "prefill":
+        b = 8 if kernel == "decode" else widest
         lowered = paged_attention.lower(
             s((b, hq, d), jnp.bfloat16), pool, pool, s((), jnp.int32),
             s((b, mp), jnp.int32), s((b,), jnp.int32), scale, scale,
@@ -134,7 +138,7 @@ def test_layer_loop_reads_the_pool_in_place(chip, kernel, kv_quant, layer):
                                     "benchmarks"))
     from aot_rehearsal import pool_copies
 
-    hq, hkv, d, window, mp = HEADS["mistral-7b"]
+    hq, hkv, d, window, mp, _ = HEADS["mistral-7b"]
     b, seq = (8, 1) if kernel == "decode" else (1, 256)
 
     def s(shape, dtype):
@@ -179,6 +183,10 @@ def test_layer_loop_reads_the_pool_in_place(chip, kernel, kv_quant, layer):
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo
     assert pool_copies(hlo, pool.shape) == []
+    # The decode kernel views the pool [L, P, page * Hkv, D]: the same
+    # bytes, so that view must not be made by an instruction either.
+    merged = pool.shape[:2] + (PAGE * hkv, pool.shape[-1])
+    assert pool_copies(hlo, merged) == []
 
 
 # ---------------------------------------------------------------------------

@@ -192,8 +192,11 @@ def make_paged_attn(cfg: ModelConfig, page_size: int, block_tables: jax.Array,
                                    interpret=interpret,
                                    sliding_window=cfg.sliding_window)
 
+        # A lane whose token is padding (an idle lane of the rung) reads
+        # nothing: its rows are thrown away.
         return _paged_call(kernel, kv, layer_idx,
-                           lead_args=(q1, block_tables, kv_len),
+                           lead_args=(q1, block_tables,
+                                      jnp.where(valid[:, 0], kv_len, 0)),
                            lead_specs=(P(), P()),
                            head_spec=P(None, "tp", None))   # [B, H*, D]
 

@@ -12,21 +12,61 @@ Mosaic; reference has no analogue — SURVEY.md §2b).
 Mechanics:
 - The kernel's K / V operands are the engine's STACKED pool
   ``[L, P, page, Hkv, D]`` (all layers; bf16, int8 or packed-int4
-  codes), never one layer's slice of it:
-  a ``pallas_call`` operand is a buffer of its own, so ``pool[layer]``
-  under the model's ``lax.scan`` over layers made XLA copy a whole
-  layer's pool (~100 MB, twice a layer) in front of every call. Which
-  layer is read is part of the DMA address instead.
-- ``PrefetchScalarGridSpec`` with the layer index, the block table + kv
-  lengths as scalar prefetch: the KV BlockSpec's index_map returns
-  ``(layer[0], block_tables[b, p], 0, 0, 0)`` to pick which physical
-  page of which layer the pipeline DMAs next — neither the layer slice
-  nor the gather materializes.
-- Grid (B, MP), page index innermost; VMEM scratch (m, l, acc) carries
-  the online-softmax state across a sequence's pages and is flushed to
-  the output on the last page.
-- GQA folded in-kernel: q viewed [Hkv, n_rep, D], each KV head's page
-  serves its n_rep query heads via one MXU contraction.
+  codes), never one layer's slice of it: a ``pallas_call`` operand is a
+  buffer of its own, so ``pool[layer]`` under the model's ``lax.scan``
+  over layers made XLA copy a whole layer's pool (~100 MB, twice a layer)
+  in front of every call. The layer, the block table and the kv lengths
+  are scalar-prefetched, so layer and physical page are part of each
+  page DMA's address.
+- A BLOCK of ``pages_per_step`` pages at a time (about 256 tokens, at most
+  ~1 MiB of K + V: ``_pages_per_step``, from the operand shapes), counted
+  from the window's first page, wherever in the block table that is.
+  Before PR 27 it was one 16-token page a grid step on a grid of
+  ``(B, window pages)``: 2056 steps a call at rung 8, ~97% of them empty
+  in a chat cell, each with a transpose and an f32 copy of K and V.
+- How a block's pages arrive (``_dma_kernel``): the pool stays in HBM
+  (``pl.ANY``), the grid is ``(B,)``, one step a lane of the rung, and
+  the lane's blocks are a loop of as many trips as it HAS blocks, from
+  the window's first page to its last token's page. Each trip copies its
+  pages into one half of a VMEM double buffer (``make_async_copy``, a DMA
+  a page a pool) while the other half is computed; the next lane's first
+  block is started during this lane's last, and which half comes next is
+  carried from lane to lane in SMEM. A lane with ``kv_len`` 0 (an idle
+  lane of the rung) runs no trip, a block's pages past the lane's last
+  are not copied, and no page the block table does not name for a visible
+  position ever leaves HBM. Measured on the v5e against the same block
+  fed by the pipeline (16 page operands a pool, each with its own index
+  map: kernels/mla_attention.py's way): 27 against 392 us a call for
+  three short lanes of eight, 163 against 460 us for six full-window ones
+  (PERF.md, PR 27): a grid step evaluates every operand's index map
+  whether it fetches or not, ~2.9 us a step with 32 of them.
+- The pipeline-fed form (``_pipelined_kernel``, grid ``(B, blocks)``) is
+  kept for the pools Mosaic cannot copy from by hand: it slices a ref only
+  along whole 128-lane tiles, so a page whose minor dim is no multiple of
+  128 (head_dim 96; int4's D / 2 bytes; the ``[1, page * Hkv]`` scales of
+  an int8 page under 8 KV heads) cannot be addressed in ``pl.ANY``. One
+  block body (``_attend``) serves both; the choice is read off the
+  operand shapes. No cell serves such a pool.
+- No transpose, no float32 copy of K or V. The pool is viewed
+  ``[L, P, page * Hkv, D]`` (the same bytes: merging the two dims above
+  the minor one keeps the chip's tiling, so XLA makes no copy), and a
+  block is a ``[T * Hkv, D]`` matrix whose row ``t * Hkv + h`` is token
+  t's head h. ALL query heads are scored against all of its rows in one
+  MXU contraction in the pool's dtype with float32 accumulation, and a
+  mask keeps, for query head r, the columns of its own KV head
+  (``col % Hkv == r // n_rep``) beside the length / window mask. The MXU
+  has to take every K and V tile once whichever heads ask, so the
+  cross-head products cost it nothing; the softmax runs on Hkv times the
+  needed columns, which is cheap beside a per-head relayout.
+- Softmax state (max, sum, acc) is float32. The weights go to the MXU as
+  TWO pool-dtype halves (``p = hi + lo``, both bf16, stacked on the row
+  dim so V is loaded once): V is exact in its dtype, so the sum carries
+  ~16 bits of p. One rounding of p to bf16 is 5e-3 to 7e-3 of the
+  output's spread off (the rounding alone, 100 to 4096 keys), over
+  ``chip_smoke.py``'s 1e-3; the two halves read 1.2e-05 on the chip.
+- Quantized pools: the codes go to the MXU as they are (int8 and int4
+  codes are exact in bf16) and the per-(token, head) scales multiply the
+  score COLUMNS (K) and the weights (V) instead of the codes.
 """
 
 from __future__ import annotations
@@ -38,96 +78,259 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tpu_inference.kernels.mla_attention import mxu_precision
+
 NEG_INF = -1e30
+
+# A block of pages: about this many tokens, at most this many bytes of
+# K + V codes (one of the two VMEM buffers).
+BLOCK_TOKENS = 256
+BLOCK_BYTES = 1 << 20
+
+
+def _pages_per_step(page_size: int, page_bytes: int, n_page_axis: int) -> int:
+    """Pages a block holds, from what the call can see: the page's tokens
+    and bytes (``page * Hkv * D_pool * itemsize``) and how many pages a
+    lane can need at all (``mp``, or the window's span)."""
+    return max(1, min(BLOCK_TOKENS // page_size,
+                      BLOCK_BYTES // (2 * page_bytes), n_page_axis))
+
+
+def _int_div(x, n: int):
+    """x // n and x % n of non-negative int32 vectors; shifts where n is
+    a power of two (every head count served)."""
+    if n & (n - 1) == 0:
+        return x >> (n.bit_length() - 1), x & (n - 1)
+    return jax.lax.div(x, n), jax.lax.rem(x, n)
 
 
 def _unpack_int4(packed):
     """uint8 nibble-packed [..., D//2] -> f32 [..., D]. ONE copy of the
     packing contract (engine/kv_cache.py unpack_int4_kv: integer
-    compare/select sign extension, Mosaic-friendly); the f32 cast is
-    this kernel's consumption dtype."""
+    compare/select sign extension, Mosaic-friendly); float32 is what both
+    paged kernels widen the codes to first."""
     from tpu_inference.engine.kv_cache import unpack_int4_kv
 
     return unpack_int4_kv(packed).astype(jnp.float32)
 
 
-def _decode_kernel(layer_ref, block_tables_ref, kv_len_ref, q_ref, k_ref,
-                   v_ref, *rest, page_size: int, scale: float,
-                   quantized: bool,
-                   packed: bool = False, sliding_window: int = 0):
-    if quantized:
-        ks_ref, vs_ref, out_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        out_ref, m_ref, l_ref, acc_ref = rest
-    b = pl.program_id(0)
-    p = pl.program_id(1)
-    num_pages = pl.num_programs(1)
+def _codes(block, packed: bool, dtype):
+    """A block's codes ``[nps, rows, D_pool]`` as the ``[nps * rows, D]``
+    matrix the MXU takes, in ``dtype``."""
+    if packed:
+        # int4: one uint8 read of half a page's bytes, unpacked in VMEM.
+        block = _unpack_int4(block)
+    if block.dtype != dtype:
+        # Integer codes reach bf16 through float32 (exact either way).
+        block = block.astype(jnp.float32).astype(dtype)
+    return block.reshape(-1, block.shape[-1])
 
-    @pl.when(p == 0)
+
+def _attend(carry, q, k, v, k_scale, v_scale, *, start, kv_len, n_kv: int,
+            n_rep: int, scale: float, sliding_window: int):
+    """Fold one block into the online-softmax state ``carry`` = (max
+    [Hq, 1], sum [Hq, 1], acc [Hq, D]), all float32.
+
+    q [Hq, D]; k, v [T * Hkv, D] in q's dtype, row ``t * Hkv + h`` token
+    ``start + t``'s KV head h; k_scale / v_scale [1, T * Hkv] float32 or
+    None. Every query head meets every row on the MXU; the mask keeps
+    query head r the columns of KV head r // n_rep at visible positions.
+    """
+    m_prev, l_prev, acc = carry
+    hq = q.shape[0]
+    prec = mxu_precision(q.dtype)
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), precision=prec,
+        preferred_element_type=jnp.float32) * scale        # [Hq, T * Hkv]
+    if k_scale is not None:
+        s = s * k_scale
+    col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    tok, head = _int_div(col, n_kv)
+    pos = start + tok
+    valid = ((row >= head * n_rep) & (row < (head + 1) * n_rep)
+             & (pos < kv_len))
+    if sliding_window:
+        # The window's edge can fall inside its first page.
+        valid = valid & (pos >= kv_len - sliding_window)
+    s = jnp.where(valid, s, NEG_INF)
+
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    # where(): a row without a column here keeps m at NEG_INF, and
+    # exp(0) = 1 would count its masked columns.
+    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+    l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+    if v_scale is not None:
+        p = jnp.where(valid, p * v_scale, 0.0)
+    if v.dtype == jnp.float32:
+        o = jnp.dot(p, v, precision=prec, preferred_element_type=jnp.float32)
+    else:
+        # p = hi + lo, both in V's dtype and stacked on the row dim (V
+        # goes through the MXU once): ~16 bits of p against V's exact
+        # values, where hi alone is ~6e-3 of the output's spread off.
+        hi = p.astype(v.dtype)
+        lo = (p - hi.astype(jnp.float32)).astype(v.dtype)
+        o = jnp.dot(jnp.concatenate([hi, lo], axis=0), v, precision=prec,
+                    preferred_element_type=jnp.float32)
+        o = o[:hq] + o[hq:]
+    return m_new, l_new, acc * alpha + o
+
+
+def _first_page(kv_len, page_size: int, sliding_window: int):
+    """Block-table position of the first page a lane reads: 0, or the
+    page the window starts in (wherever in the table that is: blocks are
+    counted from it, so a window needs no alignment)."""
+    if not sliding_window:
+        return 0
+    return jnp.maximum(kv_len - sliding_window, 0) // page_size
+
+
+def _lanes(refs):
+    """Per-page [1, rows] scale tiles side by side: [1, T * Hkv]."""
+    return jnp.concatenate(list(refs), axis=1)
+
+
+def _dma_kernel(layer_ref, bt_ref, kv_len_ref, q_ref, *rest,
+                pages_per_step: int, page_size: int, max_pages: int,
+                quantized: bool, packed: bool, sliding_window: int,
+                **attend):
+    """Grid (B,): the lane's blocks in a loop of as many trips as it has
+    blocks, the pages copied from the pool in HBM by hand."""
+    nps = pages_per_step
+    if quantized:
+        (k_hbm, v_hbm, ks_hbm, vs_hbm, out_ref,
+         k_buf, v_buf, ks_buf, vs_buf, sem, slot_ref) = rest
+        scales = ((ks_hbm, ks_buf), (vs_hbm, vs_buf))
+    else:
+        k_hbm, v_hbm, out_ref, k_buf, v_buf, sem, slot_ref = rest
+        scales = ()
+    b = pl.program_id(0)
+    layer = layer_ref[0]
+
+    def span(lane):
+        """(first page, pages) of the block-table positions ``lane``
+        reads: from the window's first page to its last token's page."""
+        kv_len = kv_len_ref[lane]
+        first = _first_page(kv_len, page_size, sliding_window)
+        last = jnp.minimum((kv_len - 1) // page_size, max_pages - 1)
+        return first, jnp.where(kv_len > 0, last - first + 1, 0)
+
+    def block_dmas(lane, j, slot, wait=False):
+        """Start, or wait for, the copies of ``lane``'s block j into
+        buffer ``slot``: one DMA a page a pool, none for a position past
+        the lane's last page. Start and wait walk the same descriptors
+        over the same pages."""
+        first, n_pages = span(lane)
+
+        def page_dmas(n, carry):
+            page = bt_ref[lane, first + j * nps + n]
+            copies = [pltpu.make_async_copy(
+                hbm.at[layer, page], buf.at[slot, n], sem.at[slot, i])
+                for i, (hbm, buf) in enumerate(((k_hbm, k_buf),
+                                                (v_hbm, v_buf)))]
+            copies += [pltpu.make_async_copy(
+                hbm.at[page], buf.at[slot, n], sem.at[slot, 2 + i])
+                for i, (hbm, buf) in enumerate(scales)]
+            for c in copies:
+                c.wait() if wait else c.start()
+            return carry
+
+        jax.lax.fori_loop(0, jnp.clip(n_pages - j * nps, 0, nps),
+                          page_dmas, 0)
+
+    @pl.when(b == 0)
+    def _first_lane():
+        # What a partial block leaves of a buffer is multiplied by
+        # weights of 0: it has to be finite, which fresh VMEM need not be.
+        v_buf[...] = jnp.zeros_like(v_buf)
+        slot_ref[0] = 0
+
+    kv_len = kv_len_ref[b]
+    first, n_pages = span(b)
+    n_blocks = (n_pages + nps - 1) // nps
+    slot0 = slot_ref[0]
+    more_lanes = b + 1 < pl.num_programs(0)
+    q = q_ref[0]                                           # [Hq, D]
+
+    # A lane's first block is started by the lane before it, during its
+    # last block or, if that lane is idle, here; lane 0 starts its own.
+    @pl.when(((b == 0) & (n_blocks > 0)) | ((n_blocks == 0) & more_lanes))
+    def _first_block():
+        block_dmas(jnp.where(n_blocks == 0, b + 1, b), 0, slot0)
+
+    def block(j, carry):
+        slot = (slot0 + j) % 2
+        last_block = j + 1 == n_blocks
+
+        # The next block, or the next lane's first, flies while this one
+        # is computed.
+        @pl.when(~last_block | more_lanes)
+        def _next_block():
+            block_dmas(jnp.where(last_block, b + 1, b),
+                       jnp.where(last_block, 0, j + 1), 1 - slot)
+
+        block_dmas(b, j, slot, wait=True)
+        return _attend(
+            carry, q, _codes(k_buf[slot], packed, q.dtype),
+            _codes(v_buf[slot], packed, q.dtype),
+            _lanes(ks_buf[slot, n] for n in range(nps)) if quantized else None,
+            _lanes(vs_buf[slot, n] for n in range(nps)) if quantized else None,
+            start=(first + j * nps) * page_size, kv_len=kv_len,
+            sliding_window=sliding_window, **attend)
+
+    _, l, acc = jax.lax.fori_loop(
+        0, n_blocks, block,
+        (jnp.full((q.shape[0], 1), NEG_INF, jnp.float32),
+         jnp.zeros((q.shape[0], 1), jnp.float32),
+         jnp.zeros(q.shape, jnp.float32)))
+    slot_ref[0] = (slot0 + n_blocks) % 2
+    # A lane that read nothing (kv_len 0) gives 0, not NaN.
+    out_ref[0] = (acc / jnp.maximum(l, 1e-20)).astype(out_ref.dtype)
+
+
+def _pipelined_kernel(layer_ref, bt_ref, kv_len_ref, q_ref, *rest,
+                      pages_per_step: int, page_size: int, quantized: bool,
+                      packed: bool, sliding_window: int, **attend):
+    """Grid (B, blocks): the block's pages arrive as ``pages_per_step``
+    operands a pool, each fetched by the pipeline under its own index map
+    (kernels/mla_attention.py's way)."""
+    del layer_ref, bt_ref
+    nps = pages_per_step
+    n_in = (4 if quantized else 2) * nps
+    k_refs, v_refs, ks_refs, vs_refs = (
+        rest[i * nps:min((i + 1) * nps, n_in)] for i in range(4))
+    out_ref, m_ref, l_ref, acc_ref = rest[n_in:]
+    b, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(j == 0)
     def _init():
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     kv_len = kv_len_ref[b]
-    if sliding_window:
-        # Grid position p is RELATIVE to the window's first page (the
-        # BlockSpec index maps apply the same offset), so decode reads
-        # O(window) pages however long the context is — the property
-        # SWA models (Mistral) are built around.
-        win_start = jnp.maximum(kv_len - sliding_window, 0)
-        page_start = (win_start // page_size + p) * page_size
-    else:
-        page_start = p * page_size
+    start = (_first_page(kv_len, page_size, sliding_window)
+             + j * nps) * page_size
 
-    @pl.when(page_start < kv_len)
+    @pl.when(start < kv_len)
     def _accumulate():
-        q = q_ref[0].astype(jnp.float32)                  # [Hkv, R, D]
-        # Mosaic requires dot_general batch dims at matching positions, so
-        # bring the kv-head dim to the front before the batched contractions.
-        if packed:
-            # int4: one uint8 read of half a page's bytes, unpacked in VMEM.
-            k = _unpack_int4(k_ref[0]).transpose(1, 0, 2)    # [Hkv, pg, D]
-            v = _unpack_int4(v_ref[0]).transpose(1, 0, 2)
-        else:
-            k = k_ref[0].astype(jnp.float32).transpose(1, 0, 2)  # [Hkv,pg,D]
-            v = v_ref[0].astype(jnp.float32).transpose(1, 0, 2)
-        if quantized:
-            # int8 codes * per-(token, head) scale — dequant in VMEM, so
-            # HBM sees one int8 read of the page.
-            k = k * ks_ref[0].astype(jnp.float32).transpose(1, 0)[:, :, None]
-            v = v * vs_ref[0].astype(jnp.float32).transpose(1, 0)[:, :, None]
+        q = q_ref[0]
 
-        # scores[h, r, t] = <q[h, r], k[h, t]> * scale
-        s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale    # [Hkv, R, pg]
-        pos = page_start + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, dimension=2)
-        valid = pos < kv_len
-        if sliding_window:
-            # Window edge can fall inside this page.
-            valid = jnp.logical_and(valid, pos >= kv_len - sliding_window)
-        s = jnp.where(valid, s, NEG_INF)
+        def codes(refs):
+            return _codes(jnp.stack([r[0] for r in refs]), packed, q.dtype)
 
-        m_prev = m_ref[:]                                  # [Hkv, R]
-        l_prev = l_ref[:]
-        m_cur = jnp.max(s, axis=2)                         # [Hkv, R]
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        pr = jnp.exp(s - m_new[:, :, None])                # [Hkv, R, pg]
-        # o[h, r, d] = sum_t pr[h, r, t] * v[h, t, d]
-        o = jax.lax.dot_general(
-            pr, v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)            # [Hkv, R, D]
-        m_ref[:] = m_new
-        l_ref[:] = l_prev * alpha + jnp.sum(pr, axis=2)
-        acc_ref[:] = acc_ref[:] * alpha[:, :, None] + o
+        m_ref[:], l_ref[:], acc_ref[:] = _attend(
+            (m_ref[:], l_ref[:], acc_ref[:]), q, codes(k_refs), codes(v_refs),
+            _lanes(r[0] for r in ks_refs) if quantized else None,
+            _lanes(r[0] for r in vs_refs) if quantized else None,
+            start=start, kv_len=kv_len, sliding_window=sliding_window,
+            **attend)
 
-    @pl.when(p == num_pages - 1)
+    @pl.when(j == pl.num_programs(1) - 1)
     def _flush():
-        denom = jnp.maximum(l_ref[:], 1e-20)[:, :, None]
-        out_ref[0] = (acc_ref[:] / denom).astype(out_ref.dtype)
+        out_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:], 1e-20)
+                      ).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "sliding_window"))
@@ -143,30 +346,34 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     q:            [B, Hq, D]   (one query token per sequence)
     k/v_pages:    [L, P, page_size, Hkv, D]  (the stacked pool of all
                   layers, read in place: only the pages the block table
-                  names, in layer ``layer``, leave HBM)
+                  names for visible positions, in layer ``layer``, leave
+                  HBM)
     layer:        int32 scalar: which layer's pages to read; may be
                   traced (the model's scan index)
     block_tables: [B, MP] int32 physical page ids (0 = trash page)
-    kv_len:       [B] int32 valid tokens per sequence (incl. current)
+    kv_len:       [B] int32 valid tokens per sequence (incl. current);
+                  0 for a lane that holds no sequence: it reads nothing
+                  and its rows come back 0
     k/v_scale:    [P, page_size, Hkv] f32, layer ``layer``'s scales —
                   present when the pool holds int8 codes
                   (engine/kv_cache.py quantize_kv) or uint8 nibble-packed
                   int4 codes (quantize_kv_int4; pool trailing dim D/2);
-                  dequant happens in VMEM after each page's DMA. ONE
-                  layer's, sliced by the caller, and not the stacked
-                  [L, P, page, Hkv]: the chip keeps that f32 array with
-                  the page dim minor-most (its last dim, Hkv, is far
-                  under a 128-lane tile), a kernel operand has to be
-                  row-major, and so XLA re-lays-out whatever it is
+                  they scale score columns and weights in VMEM after each
+                  page's DMA. ONE layer's, sliced by the caller, and not
+                  the stacked [L, P, page, Hkv]: the chip keeps that f32
+                  array with the page dim minor-most (its last dim, Hkv,
+                  is far under a 128-lane tile), a kernel operand has to
+                  be row-major, and so XLA re-lays-out whatever it is
                   handed — one layer's scales (1/L of the scale pool, 1%
                   of the layer's codes) or, stacked, all L layers' in
                   front of every call (v5e compile: +0.4 GB of temps at
                   1024 pages).
     sliding_window > 0 (SWA, Mistral): only the pages overlapping the
-    last ``sliding_window`` positions are streamed — the grid's page
-    axis shrinks to the window's page span and the index maps offset
-    into the block table from the window's first page, so decode cost
-    is O(window), not O(context).
+    last ``sliding_window`` positions are streamed — a lane's blocks
+    start at the window's first page, wherever in the block table that
+    is, so decode cost is O(window), not O(context).
+    The MXU works in the pool's dtype (q's for integer codes): q is cast
+    to it, which is exact for the bf16 activations serving hands over.
     interpret: run in Pallas interpret mode (tests on the CPU pass True).
     The default compiles through Mosaic and so needs a TPU — the backend
     is never consulted to pick a slower mode quietly.
@@ -177,64 +384,79 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     # pool's trailing dim is D/2 bytes and the kernel unpacks in VMEM.
     packed = k_pages.dtype == jnp.uint8
     b, hq, d = q.shape
-    _, _, page_size, hkv, d_pool = k_pages.shape
-    n_rep = hq // hkv
+    n_layers, n_pool, page_size, hkv, d_pool = k_pages.shape
     mp = block_tables.shape[1]
-    scale = 1.0 / (d ** 0.5)
+    rows = page_size * hkv
+    # A window of W positions spans at most ceil(W/page)+1 pages when
+    # unaligned to page boundaries.
+    n_page_axis = (min(mp, -(-sliding_window // page_size) + 1)
+                   if sliding_window else mp)
+    nps = _pages_per_step(page_size, rows * d_pool * k_pages.dtype.itemsize,
+                          n_page_axis)
+    cdt = (k_pages.dtype if jnp.issubdtype(k_pages.dtype, jnp.floating)
+           else q.dtype)
+    # [page, Hkv] -> one row dim: the same bytes in HBM (no copy).
+    pools = [x.reshape(n_layers, n_pool, rows, d_pool)
+             for x in (k_pages, v_pages)]
+    scales = ([x.reshape(n_pool, 1, rows) for x in (k_scale, v_scale)]
+              if quantized else [])
+    static = dict(pages_per_step=nps, page_size=page_size, n_kv=hkv,
+                  n_rep=hq // hkv, scale=1.0 / (d ** 0.5),
+                  quantized=quantized, packed=packed,
+                  sliding_window=sliding_window)
+    # Mosaic slices a ref it copies from only along whole 128-lane tiles,
+    # so the kernel can copy pages by hand only where a page's codes (and
+    # its scales, [1, page * Hkv]) have a minor dim that is a multiple of
+    # 128: D = 128 as bf16, or as int8 with 8 KV heads. The others (D =
+    # 96; int4's D / 2 bytes; int8 under 8 KV heads) get their pages from
+    # the pipeline.
+    by_hand = d_pool % 128 == 0 and (not quantized or rows % 128 == 0)
 
-    q_g = q.reshape(b, hkv, n_rep, d)
-    layer = jnp.asarray(layer, jnp.int32).reshape(1)
-
-    if sliding_window:
-        # A window of W positions spans at most ceil(W/page)+1 pages
-        # when unaligned to page boundaries.
-        n_page_axis = min(mp, -(-sliding_window // page_size) + 1)
-
-        def page_idx(i, p, bt, kl):
-            start = jnp.maximum(kl[i] - sliding_window, 0) // page_size
-            # Clamp: relative pages past the sequence's last page are
-            # compute-masked in the kernel; the DMA just needs a legal id.
-            return bt[i, jnp.minimum(start + p, mp - 1)]
+    if by_hand:
+        grid = (b,)
+        hbm = pl.BlockSpec(memory_space=pl.ANY)
+        in_specs = [hbm] * len(pools + scales)
+        operands = pools + scales
+        scratch = [pltpu.VMEM((2, nps, rows, d_pool), x.dtype) for x in pools]
+        scratch += [pltpu.VMEM((2, nps, 1, rows), jnp.float32)
+                    for _ in scales]
+        scratch += [pltpu.SemaphoreType.DMA((2, 4)),   # [buffer, operand]
+                    pltpu.SMEM((1,), jnp.int32)]       # buffer to use next
+        kernel = functools.partial(_dma_kernel, max_pages=mp, **static)
+        semantics = ("arbitrary",)
     else:
-        n_page_axis = mp
+        def page(n, i, j, bt, kl):
+            # Past the lane's last page, stay on it: the pipeline fetches
+            # nothing new and the block is skipped.
+            last = jnp.maximum(kl[i] - 1, 0) // page_size
+            at = _first_page(kl[i], page_size, sliding_window) + j * nps + n
+            return bt[i, jnp.minimum(jnp.minimum(at, last), mp - 1)]
 
-        def page_idx(i, p, bt, kl):
-            return bt[i, p]
+        grid = (b, -(-n_page_axis // nps))
+        in_specs = 2 * [pl.BlockSpec(
+            (None, 1, rows, d_pool),
+            lambda i, j, ly, bt, kl, n=n: (ly[0], page(n, i, j, bt, kl), 0, 0))
+            for n in range(nps)]
+        in_specs += len(scales) * [pl.BlockSpec(
+            (1, 1, rows),
+            lambda i, j, ly, bt, kl, n=n: (page(n, i, j, bt, kl), 0, 0))
+            for n in range(nps)]
+        operands = [x for x in pools + scales for _ in range(nps)]
+        scratch = [pltpu.VMEM((hq, 1), jnp.float32),       # running max
+                   pltpu.VMEM((hq, 1), jnp.float32),       # running sum
+                   pltpu.VMEM((hq, d), jnp.float32)]       # running out
+        kernel = functools.partial(_pipelined_kernel, **static)
+        semantics = ("parallel", "arbitrary")
 
-    # Leading layer dim squeezed (None): the kernel body sees one page,
-    # [1, page, Hkv, D], exactly as it did with a per-layer pool.
-    page_spec = pl.BlockSpec(
-        (None, 1, page_size, hkv, d_pool),
-        lambda i, p, ly, bt, kl: (ly[0], page_idx(i, p, bt, kl),
-                                  0, 0, 0))
-    q_spec = pl.BlockSpec((1, hkv, n_rep, d),
-                          lambda i, p, ly, bt, kl: (i, 0, 0, 0))
-    in_specs = [q_spec, page_spec, page_spec]
-    operands = [q_g, k_pages, v_pages]
-    if quantized:
-        scale_spec = pl.BlockSpec(
-            (1, page_size, hkv),
-            lambda i, p, ly, bt, kl: (page_idx(i, p, bt, kl), 0, 0))
-        in_specs += [scale_spec, scale_spec]
-        operands += [k_scale, v_scale]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,          # layer, block_tables, kv_len
-        grid=(b, n_page_axis),
-        in_specs=in_specs,
-        out_specs=q_spec,
-        scratch_shapes=[
-            pltpu.VMEM((hkv, n_rep), jnp.float32),       # running max
-            pltpu.VMEM((hkv, n_rep), jnp.float32),       # running sum
-            pltpu.VMEM((hkv, n_rep, d), jnp.float32),    # running out
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(_decode_kernel, page_size=page_size, scale=scale,
-                          quantized=quantized, packed=packed,
-                          sliding_window=sliding_window),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, n_rep, d), q.dtype),
-        interpret=interpret,
-    )(layer, block_tables, kv_len, *operands)
-    return out.reshape(b, hq, d)
+    lane_spec = pl.BlockSpec((1, hq, d), lambda i, *_: (i, 0, 0))
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,          # layer, block_tables, kv_len
+            grid=grid, in_specs=[lane_spec] + in_specs, out_specs=lane_spec,
+            scratch_shapes=scratch),
+        out_shape=jax.ShapeDtypeStruct((b, hq, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
+        interpret=interpret, name="paged_attention",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), block_tables, kv_len,
+      q.astype(cdt), *operands)
