@@ -377,35 +377,51 @@ def test_check_numerics():
         engine.check_numerics()
 
 
-def test_decode_steps_chained_matches_sync():
-    """Dispatch-ahead decode (device-chained carry tokens, one final
-    sync) produces exactly the synchronous loop's tokens."""
+def test_decode_steps_folds_calls_in_flight_first():
+    """Mixing entry points: decode_steps() called with pipelined calls
+    still in flight folds them first, hands their tokens back ahead of
+    its own round's, and the streams equal the all-synchronous ones."""
     model_cfg = cfgs.tiny_llama(vocab_size=256)
-    ecfg = cfgs.EngineConfig(page_size=8, num_pages=128, max_pages_per_seq=16,
-                             max_batch_size=4, prefill_buckets=(16,),
-                             decode_steps_per_call=4, max_new_tokens=64,
-                             enable_prefix_cache=False)
     params, _ = build_model(model_cfg, seed=0)
     rng = np.random.default_rng(5)
     prompts = [rng.integers(0, 256, size=n).tolist() for n in (5, 9, 12)]
 
-    sync = InferenceEngine(model_cfg, ecfg, params=params)
-    seqs_a = [Sequence(request_id=i, prompt_tokens=p, max_new_tokens=33)
-              for i, p in enumerate(prompts)]
-    for s in seqs_a:
-        sync.prefill(s)
-    for _ in range(8):
-        sync.decode_steps()
+    def run(depth, mixed):
+        ecfg = cfgs.EngineConfig(
+            page_size=8, num_pages=128, max_pages_per_seq=16,
+            max_batch_size=4, prefill_buckets=(16,),
+            decode_steps_per_call=4, decode_pipeline_depth=depth,
+            enable_prefix_cache=False)
+        engine = InferenceEngine(model_cfg, ecfg, params=params)
+        seqs = [Sequence(request_id=i, prompt_tokens=p,
+                         max_new_tokens=(33, 21, 14)[i], eos_token_id=7)
+                for i, p in enumerate(prompts)]
+        for s in seqs:
+            engine.prefill(s)
+        delivered = {s.request_id: list(s.generated) for s in seqs}
+        peak = 0
+        for it in range(40):
+            if mixed and it % 3 < 2:
+                out = engine.decode_steps_pipelined()
+                peak = max(peak, len(engine._inflight))
+            else:
+                out = engine.decode_steps(1 if mixed and it % 2 else None)
+                assert not engine.pipeline_pending
+            for rid, toks in out.items():
+                delivered[rid].extend(toks)
+            if all(s.done for s in seqs) and not engine.pipeline_pending:
+                break
+        assert all(s.done for s in seqs)
+        return ([s.generated for s in seqs],
+                [s.finish_reason for s in seqs], delivered, peak)
 
-    chained = InferenceEngine(model_cfg, ecfg, params=params)
-    seqs_b = [Sequence(request_id=i, prompt_tokens=p, max_new_tokens=33)
-              for i, p in enumerate(prompts)]
-    for s in seqs_b:
-        chained.prefill(s)
-    out = chained.decode_steps_chained(8)
-    assert [s.generated for s in seqs_a] == [s.generated for s in seqs_b]
-    assert sorted(out) == [0, 1, 2] and all(len(v) == 32
-                                            for v in out.values())
+    gen_sync, fin_sync, out_sync, _ = run(depth=1, mixed=False)
+    gen_mix, fin_mix, out_mix, peak = run(depth=3, mixed=True)
+    assert peak == 2, "the mixed run never had calls in flight"
+    assert (gen_mix, fin_mix) == (gen_sync, fin_sync)
+    # Nothing a drain folded was lost on the way to the caller.
+    assert out_mix == dict(enumerate(gen_mix))
+    assert out_sync == dict(enumerate(gen_sync))
 
 
 def test_decode_steps_pipelined_matches_sync():

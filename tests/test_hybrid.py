@@ -329,8 +329,8 @@ def test_hybrid_chunk_only_call_then_decode_staging(model_and_params):
                     prompt_tokens=rng.integers(0, VOCAB, 90).tolist(),
                     max_new_tokens=4)
     eng.prefill_begin(long)
-    eng.hybrid_step_pipelined(long)        # decode grant + chunk 1
-    eng.hybrid_step_pipelined(long)        # s1 fully covered: chunk-only
+    eng.decode_steps_pipelined(long)        # decode grant + chunk 1
+    eng.decode_steps_pipelined(long)        # s1 fully covered: chunk-only
     assert any(c["outs"] is None for c in eng._inflight), \
         "setup failed to produce a chunk-only call"
     # A fresh lane becomes stageable with the chunk-only call still in
@@ -339,12 +339,12 @@ def test_hybrid_chunk_only_call_then_decode_staging(model_and_params):
                   prompt_tokens=rng.integers(0, VOCAB, 5).tolist(),
                   max_new_tokens=12)
     eng.prefill(s3)
-    eng.hybrid_step_pipelined(long)        # would raise before the fix
+    eng.decode_steps_pipelined(long)        # would raise before the fix
     for _ in range(50):
         eng.drain_pipeline()
         if long.prefill_prompt is None:
             break
-        eng.hybrid_step_pipelined(long)
+        eng.decode_steps_pipelined(long)
     assert long.prefill_prompt is None and long.generated
     eng.drain_pipeline()
     for s in list(eng.slots):

@@ -523,6 +523,56 @@ def test_engine_records_carry_monotone_seq_and_observed_times():
     assert 1 <= stall.count <= 4 and stall.sum > 0
 
 
+@pytest.mark.parametrize("max_steps", [None, 1])
+@pytest.mark.parametrize("depth", [1, 2, 3])
+def test_synchronous_round_is_one_record_at_any_depth(depth, max_steps):
+    """decode_steps() is the depth-1 case of the one staged round,
+    whatever depth the engine serves at: nothing is left in flight, each
+    round pushes exactly one decode record, observed, whose device_s
+    runs from its enqueue to its readback and whose steps are the cap;
+    a cap of 1 runs the one-step program."""
+    engine = _tiny_engine(decode_pipeline_depth=depth,
+                          decode_steps_per_call=8)
+    from tpu_inference.engine.engine import Sequence
+
+    ran = {"one": 0, "multi": 0}
+    assert engine._decode_one_jit is not engine._decode_multi_jit
+
+    def spy(name):
+        real = getattr(engine, f"_decode_{name}_jit")
+
+        def call(*args):
+            ran[name] += 1
+            return real(*args)
+        setattr(engine, f"_decode_{name}_jit", call)
+    spy("one"), spy("multi")
+
+    engine.prefill_many([
+        Sequence(request_id=i, prompt_tokens=list(range(3, 9 + i)),
+                 max_new_tokens=20) for i in range(3)])
+    ledger = engine.telemetry.step_ledger
+    rounds = 0
+    while engine.active_sequences():
+        before = len(ledger.snapshot())
+        out = engine.decode_steps(max_steps)
+        rounds += 1
+        assert not engine.pipeline_pending
+        assert out and all(len(t) <= (max_steps or 8) for t in out.values())
+        assert len(ledger.snapshot()) == before + 1
+    recs = [r for r in ledger.snapshot() if r["kind"] == "decode"]
+    assert len(recs) == rounds
+    for r in recs:
+        assert r["t_done"] > r["t_enqueue"] > 0
+        assert r["device_s"] == pytest.approx(r["t_done"] - r["t_enqueue"],
+                                              abs=1e-3)
+        assert r["steps"] == (max_steps or 8) and r["slots"] == 3
+    assert sum(r["tokens"] for r in recs) == 3 * 19
+    assert ran == ({"one": rounds, "multi": 0} if max_steps == 1
+                   else {"one": 0, "multi": rounds})
+    assert engine.telemetry.decode_sync_s.count == rounds
+    assert engine.telemetry.decode_dispatches.value == rounds
+
+
 def test_steps_report_interval_and_records():
     led = StepLedger(depth=32)
     for i in range(10):

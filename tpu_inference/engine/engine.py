@@ -269,6 +269,14 @@ def _refuse_unsupported_latent(model_cfg: ModelConfig,
                          "support: " + "; ".join(what))
 
 
+def _extend(result: Dict[int, List[int]], more: Dict[int, List[int]]
+            ) -> Dict[int, List[int]]:
+    """Append ``more``'s per-request tokens to ``result``'s, in place."""
+    for rid, toks in more.items():
+        result.setdefault(rid, []).extend(toks)
+    return result
+
+
 class ChaosStepError(RuntimeError):
     """Injected engine-step failure (EngineConfig.chaos_step_failure_rate).
 
@@ -548,6 +556,7 @@ class InferenceEngine:
         # yet: (seq, enqueue instant, stalled decode lanes?).
         self._dispatch_seq = 0
         self._unsettled: List[Tuple[int, float, bool]] = []
+        self._device_free_at = 0.0        # instant of the last readback
         # Host-side bubble tracking: perf_counter at the end of the last
         # decode dispatch, None when the decode streak broke (idle batch
         # or an interleaved prefill) so cross-idle gaps never count.
@@ -1421,9 +1430,12 @@ class InferenceEngine:
         non-final chunk's token) get their ``t_done`` and true
         ``device_s`` here, from a readback the engine performs anyway —
         for a non-final chunk that is the next readback behind it, so
-        its ``t_done`` is an upper bound."""
+        its ``t_done`` is an upper bound. ``now`` is also the earliest
+        instant the device is known free for what is still in flight
+        (_sync_oldest's ``device_s``)."""
         tel = self.telemetry
         tel.clock.observed(seq)
+        self._device_free_at = now
         while self._unsettled and self._unsettled[0][0] <= seq:
             chunk_seq, t_enq, stalled = self._unsettled.pop(0)
             tel.step_ledger.settle(chunk_seq, tel.recorder.to_unix(now))
@@ -1443,42 +1455,36 @@ class InferenceEngine:
         self._observed(seq, t_done)
         return out, t_wait, t_done
 
-    def _note_decode_entry(self, now: float) -> None:
-        """Record the host-side bubble since the last decode dispatch
-        ended (if the decode streak is unbroken); ``now`` is the instant
-        the dispatch's jitted call began."""
-        last = self._last_decode_end
-        self._pending_bubble = 0.0
-        if last is not None and self.telemetry.enabled:
-            gap = now - last
-            self.telemetry.dispatch_bubble_s.observe(gap)
-            self._pending_bubble = gap     # step-ledger host-bound input
-
-    def _note_decode_exit(self, t0: float, now: float) -> float:
-        """Record one decode dispatch's host wall (``t0`` .. ``now``,
-        the clock's own stamps) and refresh the bubble reference point.
-        The streak survives only while some sequence is still live —
-        cross-idle gaps are not bubbles. Returns the dispatch wall (the
-        step ledger's device_s input)."""
-        dt = now - t0
-        tel = self.telemetry
-        tel.decode_dispatch_s.observe(dt)
-        tel.decode_dispatches.inc()
-        self._last_decode_end = (
-            now if any(s is not None and not s.done for s in self.slots)
-            else None)
-        return dt
-
     def _run_decode(self, kind: str, jitted, args: tuple, *, rung: int,
                     slots: int, tokens: int, chunk_tokens: int = 0
                     ) -> Tuple[Any, int, float, float]:
-        """``_run`` for a dispatch with decode lanes: bubble before it,
-        dispatch wall after. Returns (outputs, seq, t0, dispatch wall)."""
+        """``_run`` for a dispatch with decode lanes, and the one place
+        that notes the host-side bubble before it (the gap since the
+        last decode dispatch or sync ended, while the decode streak is
+        unbroken) and its enqueue wall after. Returns (outputs, seq,
+        the instant the jitted call began, the enqueue wall)."""
         out, seq, t0, t1 = self._run(kind, jitted, args, rung=rung,
                                      slots=slots, tokens=tokens,
                                      chunk_tokens=chunk_tokens)
-        self._note_decode_entry(t0)
-        return out, seq, t0, self._note_decode_exit(t0, t1)
+        tel = self.telemetry
+        last = self._last_decode_end
+        self._pending_bubble = 0.0
+        if last is not None and tel.enabled:
+            tel.dispatch_bubble_s.observe(t0 - last)
+            self._pending_bubble = t0 - last   # step-ledger host-bound input
+        tel.decode_dispatch_s.observe(t1 - t0)
+        tel.decode_dispatches.inc()
+        self._decode_streak(t1)
+        return out, seq, t0, t1 - t0
+
+    def _decode_streak(self, now: float) -> None:
+        """Refresh the bubble's reference point: ``now`` ends a decode
+        enqueue or a blocking sync (device time, not a host bubble). The
+        streak survives only while some sequence is still live —
+        cross-idle gaps are not bubbles."""
+        self._last_decode_end = (
+            now if any(s is not None and not s.done for s in self.slots)
+            else None)
 
     def _fold_moe_stats(self, outs: np.ndarray) -> None:
         """The routing counts that rode a decode readback ``outs``
@@ -1983,9 +1989,7 @@ class InferenceEngine:
         """Steps this lane may advance in one fused call — folds the
         generation budget, the context cap, and KV-page headroom — and
         allocates the pages it needs. ``pred_*`` override ctx/generated
-        with predicted values while dispatch-ahead calls are in flight.
-        Shared by the sync and pipelined decode paths so grant semantics
-        can't diverge."""
+        with predicted values while dispatch-ahead calls are in flight."""
         ecfg = self.engine_cfg
         ctx = seq.ctx_len if pred_ctx is None else pred_ctx
         done = len(seq.generated) if pred_done is None else pred_done
@@ -2012,8 +2016,7 @@ class InferenceEngine:
 
     def _fold_lane(self, seq: Sequence, toks) -> List[int]:
         """Fold device-produced tokens (iterable of ints, -1 = no token)
-        into one sequence's host state; stops at done/-1. Shared by every
-        decode sync path."""
+        into one sequence's host state; stops at done/-1."""
         got: List[int] = []
         for tok in toks:
             if seq.done or tok < 0:
@@ -2669,7 +2672,7 @@ class InferenceEngine:
                      t_done: float = 0.0) -> None:
         """Push one per-dispatch record into the step ledger, folding in
         the staged bubble/staging micros (unless the caller captured
-        them at stage time — pipelined calls push at SYNC, by which
+        them at stage time — decode rounds push at SYNC, by which
         point the scratch belongs to a newer dispatch) and the KV-swap
         byte delta since the previous record. ``t_enqueue`` / ``t_done``
         are the loop clock's instants (perf counter; recorded as unix on
@@ -2762,7 +2765,7 @@ class InferenceEngine:
                     buf["bt_key"][i] = None
 
     def _stage_batch(self, active_seqs: List[Sequence], rung: int):
-        """Fill the per-slot host arrays shared by both decode entry points:
+        """Fill the per-slot host arrays of one decode dispatch:
         (tokens, ctx_lens, block_tables, temps, top_ps, top_ks, seeds,
         rpens, rlasts, windows) — [rung]-shaped ([rung, W] for windows).
 
@@ -2839,122 +2842,121 @@ class InferenceEngine:
                 buf["rpens"].copy(), buf["rlasts"].copy(),
                 buf["windows"].copy())
 
+    # ------------------------------------------------------------------
+    # The decode round: stage -> enqueue -> (later) sync -> fold, written
+    # once. How many calls may be in flight (decode_pipeline_depth), a
+    # step cap and a prefill chunk riding along are data of that round:
+    # the synchronous round is its depth-1 case.
+    # ------------------------------------------------------------------
+
     def decode_step(self) -> Dict[int, int]:
         """One batched decode step (single-step view of the fused graph:
         ``allowed`` is capped at 1, so lanes advance exactly one token).
         Returns {request_id: new_token}. Prefer decode_steps() in serving
         loops — this exists for tests and fine-grained stepping."""
-        return {rid: toks[0]
+        return {rid: toks[-1]
                 for rid, toks in self.decode_steps(max_steps=1).items()}
 
     def decode_steps(self, max_steps: Optional[int] = None
                      ) -> Dict[int, List[int]]:
-        """Up to ``decode_steps_per_call`` fused decode steps in ONE device
-        dispatch. Returns {request_id: [tokens generated, in order]}.
+        """One synchronous round: up to ``decode_steps_per_call`` fused
+        decode steps in ONE device dispatch, read back before returning.
+        Returns {request_id: [tokens generated, in order]}.
 
         Per-sequence ``allowed`` folds the generation budget, the context
         cap, and KV-page headroom, so the device never writes a slot the
         host hasn't provisioned. EOS stops a lane on device; the host's
         ``_maybe_finish`` stays the source of truth for finish state.
         ``max_steps`` additionally caps every lane (decode_step uses 1).
+        Calls a pipelined entry point left in flight are folded first
+        (their tokens lead the returned lists), so entry points mix.
         """
         self._chaos_step_gate()
-        if self._inflight:
-            # Mixing entry points: fold any dispatch-ahead state first so
-            # ctx/pages bookkeeping stays consistent (tokens surface in
-            # seq.generated; callers that care use decode_steps_pipelined
-            # exclusively).
-            self.drain_pipeline()
+        result = self.drain_pipeline()
         if self.spec_draft:
-            return self._spec_decode_steps(max_steps)
-        if self.spec_ngram:
-            return self._ngram_decode_steps(max_steps)
-        return self._plain_decode_steps(max_steps)
+            return _extend(result, self._spec_decode_steps(max_steps))
+        return _extend(result, self._round(1, max_steps=max_steps))
 
-    def _plain_decode_steps(self, max_steps: Optional[int] = None
-                            ) -> Dict[int, List[int]]:
-        """The non-speculative fused-K decode round (decode_steps body);
-        also the dispatch ngram spec degrades to when NO slot has a
-        proposal this round — plain fused decode is strictly better than
-        a verify round that could only emit one token per lane."""
-        ecfg = self.engine_cfg
-        self.telemetry.clock.enter("stage")
-        k_steps = max(1, ecfg.decode_steps_per_call)
-        if max_steps is not None:
-            k_steps = min(k_steps, max_steps)
-        self._compact_slots()         # step the ladder down when possible
-        active_seqs = self.active_sequences()
-        if not active_seqs:
-            return {}
+    def decode_steps_pipelined(self, prefill_seq: Optional[Sequence] = None
+                               ) -> Dict[int, List[int]]:
+        """Serving step: stage one round, then sync only the oldest call
+        once ``decode_pipeline_depth`` are in flight. At depth 1 that is
+        the call just staged (a synchronous round); deeper, token
+        delivery lags dispatch by depth-1 calls and device compute
+        overlaps all host work in between.
 
-        # Watermark check first: under optimistic admission, pressure
-        # preempts the most-recently-admitted lanes BEFORE any grants,
-        # so the surviving lanes advance at full k_steps.
-        active_seqs = self._preempt_for_pressure(active_seqs, k_steps)
-        allowed_by_slot: Dict[int, int] = {}
-        for seq in active_seqs:
-            steps = self._grant_decode_steps(seq, k_steps)
-            if steps <= 0:
-                # No budget/room should have finished already; zero pool
-                # slack preempts (optimistic) or fails safely (reserve).
-                self._starved(seq)
-                continue
-            allowed_by_slot[seq.slot] = steps
-        active_seqs = [s for s in active_seqs
-                       if not s.done and s.slot >= 0]
-        if not active_seqs:
-            return {}
+        With ``prefill_seq`` (a sequence mid-incremental-prefill;
+        EngineConfig.hybrid_prefill) its next chunk rides the round's
+        dispatch, so running lanes keep producing tokens instead of
+        stalling a chunk wall per chunk. Once the prompt is fully staged
+        further calls stage plain decode rounds and the final chunk's
+        sampled token folds at its sync — the caller observes completion
+        as ``prefill_seq.prefill_prompt is None``.
+        Returns the tokens folded by this call (possibly {}).
+        """
+        assert prefill_seq is None or not self.spec_enabled, \
+            "hybrid steps don't compose with speculative decoding"
+        if self.spec_draft:
+            return self.decode_steps()         # gate runs inside
+        if (self.admission == "optimistic" and self.under_pressure
+                and (prefill_seq is None or self.active_sequences())):
+            # Watermark pressure settles first: in-flight calls hold
+            # predicted-ctx page grants, so a preemption decision waits
+            # for a synchronous round (which drains, then preempts as
+            # it stages; the chaos gate runs inside it). The chunk then
+            # advances SERIALLY: its pages were all allocated at
+            # prefill_begin, so it cannot deepen the shortage, and
+            # skipping it would starve the prefill for as long as
+            # pressure holds. (With no lanes there is nothing to settle
+            # and the round below carries the chunk.)
+            result = self.decode_steps()
+            if (prefill_seq is not None and not prefill_seq.done
+                    and prefill_seq.prefill_prompt is not None):
+                self.prefill_step(prefill_seq)
+            return result
+        self._chaos_step_gate()
+        return self._round(max(1, self.engine_cfg.decode_pipeline_depth),
+                           prefill_seq)
 
-        # Dispatch at the smallest compiled rung covering the batch.
-        b = self._rung_for_slots(active_seqs)
-        self._note_rung(b)
-        (tokens, ctx_lens, bts, temps, top_ps, top_ks, seeds,
-         rpens, rlasts, windows) = self._stage_batch(active_seqs, b)
-        allowed = np.zeros((b,), np.int32)
-        eos_ids = np.full((b,), -1, np.int32)
-        for seq in active_seqs:
-            allowed[seq.slot] = allowed_by_slot[seq.slot]
-            if seq.eos_token_id is not None:
-                eos_ids[seq.slot] = seq.eos_token_id
-
-        # k_steps==1 runs the 1-iteration graph (one forward per visible
-        # token) instead of masking K-1 steps of the fused graph.
-        decode = self._decode_one_jit if k_steps == 1 else \
-            self._decode_multi_jit
-        args = (
-            self.params, self.kv, jnp.asarray(tokens), jnp.asarray(ctx_lens),
-            jnp.asarray(bts), jnp.asarray(allowed), jnp.asarray(eos_ids),
-            self._next_key(), jnp.asarray(temps), jnp.asarray(top_ps),
-            jnp.asarray(top_ks), jnp.asarray(seeds), jnp.asarray(rpens),
-            jnp.asarray(rlasts), jnp.asarray(windows))
-        (self.kv, outs, _, _), dseq, t0, _ = self._run(
-            "decode", decode, args, rung=b, slots=len(active_seqs),
-            tokens=int(allowed.sum()))
-        self._note_decode_entry(t0)
-        # Synchronous round: the call's wall is enqueue + readback.
-        outs, _, t_done = self._wait(dseq, lambda: np.asarray(outs))  # [K, B]
-        self._fold_moe_stats(outs)
-        dt = self._note_decode_exit(t0, t_done)
-        kv_read = sum(s.ctx_len for s in active_seqs) * k_steps
-
+    def _round(self, depth: int, prefill_seq: Optional[Sequence] = None,
+               max_steps: Optional[int] = None) -> Dict[int, List[int]]:
+        """Stage one round and enqueue it, then sync the oldest call in
+        flight once there are ``depth`` of them, or when nothing could
+        be staged."""
         result: Dict[int, List[int]] = {}
-        for seq in active_seqs:
-            got = self._fold_lane(
-                seq, (int(outs[s, seq.slot]) for s in range(k_steps)))
-            if got:
-                result[seq.request_id] = got
-        if self.telemetry.enabled:
-            n_tokens = sum(len(t) for t in result.values())
-            self.telemetry.tokens_per_dispatch.observe(n_tokens)
-            self._ledger_push("decode", rung=b, slots=len(active_seqs),
-                              tokens=n_tokens, steps=k_steps,
-                              device_s=dt, kv_read=kv_read,
-                              seq=dseq, t_enqueue=t0, t_done=t_done)
+        if self.spec_ngram or self._pipeline_rung_blocked():
+            # Proposals need the previous round's accepted tokens (spec
+            # rounds cannot chain blind like plain decode carries), and
+            # a batch that outgrew the in-flight rung settles, then
+            # grows.
+            result = self.drain_pipeline()
+        if self.spec_ngram:
+            call = self._stage_ngram_call(max_steps)
+        else:
+            call = self._stage_decode_call(prefill_seq, max_steps)
+        if call is not None:
+            self._inflight.append(call)
+        if self._inflight and (len(self._inflight) >= depth or call is None):
+            _extend(result, self._sync_oldest())
         return result
 
-    # ------------------------------------------------------------------
-    # Pipelined decode (dispatch-ahead serving loop)
-    # ------------------------------------------------------------------
+    @property
+    def pipeline_pending(self) -> bool:
+        return bool(self._inflight)
+
+    def abort_pipeline(self) -> None:
+        """Discard in-flight calls WITHOUT folding (decode-error
+        recovery): after an error their outputs are suspect, and leaving
+        stale entries would poison ctx prediction / carry tokens for
+        whatever request reuses those slots next."""
+        self._inflight.clear()
+
+    def drain_pipeline(self) -> Dict[int, List[int]]:
+        """Sync every in-flight call (idle/finish/shutdown path)."""
+        result: Dict[int, List[int]] = {}
+        while self._inflight:
+            _extend(result, self._sync_oldest())
+        return result
 
     def _hybrid_chunk_cap(self, decode_tokens: int) -> int:
         """Chunk-token cap for one hybrid step: the serial chunk cap,
@@ -3011,24 +3013,29 @@ class InferenceEngine:
                 "prefill": {"seq": chunk["seq"], "prompt": chunk["prompt"],
                             "final": chunk["final"], "tok": p_tok}}
         if self.telemetry.enabled:
-            dt = t1 - t0
-            self.telemetry.prefill_dispatch_s.observe(dt)
+            self.telemetry.prefill_dispatch_s.observe(t1 - t0)
             self.telemetry.prefill_dispatches.inc()
             off = int(chunk["prefix_len"][0])
             call["ledger"] = {
                 "kind": "prefill_chunk", "rung": 0, "slots": 1,
                 "tokens": 1 if chunk["final"] else 0,
-                "chunk_tokens": c, "steps": 1, "dispatch_s": dt,
+                "chunk_tokens": c, "steps": 1,
                 "staging_s": 0.0, "bubble_s": 0.0,
                 "kv_read": c * off + c * (c + 1) // 2,
-                "compile": chunk["bucket"]
+                "compile_event": chunk["bucket"]
                 not in self._prefill_buckets_seen}
             self._prefill_buckets_seen.add(chunk["bucket"])
         return call
 
-    def _stage_decode_call(self, prefill_seq: Optional[Sequence] = None):
-        """Stage one fused-decode dispatch from current host state plus
-        the ctx deltas of still-in-flight calls (predicted ctx).
+    def _stage_decode_call(self, prefill_seq: Optional[Sequence] = None,
+                           max_steps: Optional[int] = None
+                           ) -> Optional[dict]:
+        """Stage and enqueue one fused-decode dispatch (non-blocking)
+        from current host state plus the ctx deltas of still-in-flight
+        calls (predicted ctx). With nothing in flight those deltas are
+        empty and the device is handed exactly the host-known state: the
+        synchronous round. ``max_steps`` caps every lane's grant; a cap
+        of 1 runs the one-step graph (the scheduler's latency mode).
 
         With ``prefill_seq`` (a sequence mid-incremental-prefill), its
         next chunk rides the same dispatch: the hybrid graph advances
@@ -3036,15 +3043,17 @@ class InferenceEngine:
         fusion is value-identical to the serial order), and the call
         chains into the pipeline exactly like a plain decode call.
 
-        Returns None when nothing can advance. Page/budget/room logic
-        mirrors decode_steps, evaluated at the predicted positions; lanes
-        that stop mid-flight (EOS) waste at most their staged steps,
-        whose tokens the sync step discards (KV garbage at dead positions
-        is always rewritten by a later owner before being attended).
+        Returns None when nothing can advance. Grants are evaluated at
+        the predicted positions; lanes that stop mid-flight (EOS) waste
+        at most their staged steps, whose tokens the sync step discards
+        (KV garbage at dead positions is always rewritten by a later
+        owner before being attended).
         """
         ecfg = self.engine_cfg
         self.telemetry.clock.enter("stage")
         k_steps = max(1, ecfg.decode_steps_per_call)
+        if max_steps is not None:
+            k_steps = min(k_steps, max_steps)
         if not self._inflight:
             self._compact_slots()     # rung can step down between bursts
         # Predicted per-slot ctx advance from unsynced calls.
@@ -3055,6 +3064,13 @@ class InferenceEngine:
         active_seqs = self.active_sequences()
         if not active_seqs and prefill_seq is None:
             return None
+        if not self._inflight:
+            # Watermark check first: under optimistic admission,
+            # pressure preempts the most-recently-admitted lanes BEFORE
+            # any grants, so the surviving lanes advance at full
+            # k_steps. Only with nothing in flight (in-flight calls hold
+            # predicted-ctx page grants): decode_steps drains to here.
+            active_seqs = self._preempt_for_pressure(active_seqs, k_steps)
         allowed_by_slot: Dict[int, int] = {}
         staged: List[Sequence] = []
         for seq in active_seqs:
@@ -3067,9 +3083,9 @@ class InferenceEngine:
                     # Nothing in flight can finish it and the pool has
                     # zero slack: preempt (optimistic; lag == 0 means no
                     # in-flight call touches it, so eviction is safe) or
-                    # fail the sequence (decode_steps's oom semantics).
-                    # Budget/room exhaustion can't land here —
-                    # _maybe_finish already marked those done.
+                    # fail the sequence with "oom". Budget/room
+                    # exhaustion can't land here — _maybe_finish already
+                    # marked those done.
                     self._starved(seq)
                 continue                      # ahead calls may still emit
             allowed_by_slot[seq.slot] = steps
@@ -3100,8 +3116,8 @@ class InferenceEngine:
         # the staged slots, never below any in-flight call's rung —
         # carry folds are element-wise over [rung] arrays, so every
         # in-flight call must share one width. Growth past the in-flight
-        # rung is handled by the callers (they drain first); shrink lags
-        # the pipeline depth, then steps down here.
+        # rung is handled by _round (it drains first); shrink lags the
+        # pipeline depth, then steps down here.
         b = self._rung_for_slots(active_seqs)
         for call in self._inflight:
             b = max(b, call["rung"])
@@ -3138,15 +3154,19 @@ class InferenceEngine:
             jnp.asarray(top_ks), jnp.asarray(seeds), jnp.asarray(rpens),
             jnp.asarray(rlasts), window_d)
         granted = int(allowed.sum())
-        # Non-blocking dispatch: the wall recorded here is host dispatch
-        # overhead; the device wait surfaces in decode_sync_s at
+        # Non-blocking dispatch: the wall _run_decode records is the
+        # enqueue; the device wait surfaces in decode_sync_s at
         # _sync_oldest.
         if chunk is None:
-            ((self.kv, outs, final, final_window), dseq, t0,
-             dispatch_dt) = self._run_decode(
-                "decode", self._decode_multi_jit,
-                (self.params, self.kv, *decode_args),
-                rung=b, slots=len(staged), tokens=granted)
+            # k_steps == 1 runs the 1-iteration graph (one forward per
+            # visible token) instead of masking K-1 steps of the fused
+            # graph.
+            decode = self._decode_one_jit if k_steps == 1 else \
+                self._decode_multi_jit
+            (self.kv, outs, final, final_window), dseq, t0, _ = \
+                self._run_decode(
+                    "decode", decode, (self.params, self.kv, *decode_args),
+                    rung=b, slots=len(staged), tokens=granted)
             p_tok = None
         else:
             ((self.kv, p_tok, outs, final, final_window), dseq, t0,
@@ -3169,20 +3189,18 @@ class InferenceEngine:
                                "final": chunk["final"], "tok": p_tok}
         if self.telemetry.enabled:
             # Step-ledger metadata captured at STAGE time (the scratch
-            # micros belong to this dispatch); the record is pushed at
-            # sync with device_s = dispatch + sync wall and the folded
-            # token count.
+            # micros belong to this dispatch); _sync_oldest pushes the
+            # record with the folded token count and the device wall.
             kv_read = sum(int(ctx_lens[s.slot]) * allowed_by_slot[s.slot]
                           for s in staged)
-            compile_ev = self._last_compile_event
-            self._last_compile_event = False
+            scratch = self._take_stage_scratch()
             if chunk is not None:
                 c = chunk["chunk_tokens"]
                 off = int(chunk["prefix_len"][0])
                 kv_read += c * off + c * (c + 1) // 2
                 hkey = ("hybrid", chunk["bucket"])
-                compile_ev = compile_ev or (
-                    hkey not in self._prefill_buckets_seen)
+                if hkey not in self._prefill_buckets_seen:
+                    scratch["compile_event"] = True
                 self._prefill_buckets_seen.add(hkey)
             call["ledger"] = {
                 "kind": "decode" if chunk is None else "hybrid",
@@ -3192,26 +3210,36 @@ class InferenceEngine:
                 else 0,
                 "chunk_tokens": 0 if chunk is None
                 else chunk["chunk_tokens"],
-                "steps": k_steps, "dispatch_s": dispatch_dt,
-                "staging_s": self._last_staging_s,
-                "bubble_s": self._pending_bubble,
-                "kv_read": kv_read, "compile": compile_ev}
-            self._last_staging_s = 0.0
-            self._pending_bubble = 0.0
+                "steps": k_steps, "kv_read": kv_read, **scratch}
         return call
 
+    def _take_stage_scratch(self) -> dict:
+        """The staging wall, bubble and compile flag of the dispatch
+        just enqueued, for its ledger record: taken now because the
+        record lands at sync, by which point the scratch belongs to a
+        newer dispatch."""
+        scratch = {"staging_s": self._last_staging_s,
+                   "bubble_s": self._pending_bubble,
+                   "compile_event": self._last_compile_event}
+        self._last_staging_s = self._pending_bubble = 0.0
+        self._last_compile_event = False
+        return scratch
+
     def _sync_oldest(self) -> Dict[int, List[int]]:
-        """Block on the oldest in-flight call and fold its tokens into
-        host state; tokens for lanes that finished in an earlier call are
-        discarded (their compute was speculative)."""
+        """Block on the oldest in-flight call, fold its tokens into host
+        state and push its step-ledger record — the one writer of
+        decode / hybrid / n-gram verify records. Tokens for lanes that
+        finished in an earlier call are discarded (their compute was
+        speculative)."""
         call = self._inflight.pop(0)
-        if call.get("spec"):
-            # ngram spec round staged into the pipeline: its fold is
-            # emission-shaped (accept-prefix + caps), not K-step-shaped.
-            return self._sync_spec_call(call)
+        tel = self.telemetry
         pf = call.get("prefill")
+        spec = call.get("spec", False)
 
         def read():
+            if spec:                       # n-gram verify round
+                return (np.asarray(call["emitted"]),      # [B, γ+1]
+                        np.asarray(call["n_accepted"]))
             if call["outs"] is not None:
                 return np.asarray(call["outs"])       # [K, B]
             # Chunk-only call (no decode half): the blocking sync is on
@@ -3221,31 +3249,38 @@ class InferenceEngine:
                 jax.block_until_ready(pf["tok"])
             return None
 
-        outs, t0, t_done = self._wait(call["seq"], read)
-        sync_dt = t_done - t0
+        # The device could not start this call before the readback that
+        # preceded it (programs run in order): with nothing else in
+        # flight that is before its enqueue, and device_s runs from the
+        # enqueue's start to this readback's end.
+        t_start = max(call["t_enqueue"], self._device_free_at)
+        outs, t_wait, t_done = self._wait(call["seq"], read)
         if outs is not None:
-            self._fold_moe_stats(outs)
             # Chunk-only waits stay out of decode_sync_s (pure prefill
             # device time, not a decode sync).
-            self.telemetry.decode_sync_s.observe(sync_dt)
-        # The blocking sync is DEVICE time (already in decode_sync_s):
-        # refresh the bubble reference point so the next decode entry
-        # measures only host work after it — without this,
-        # dispatch-ahead mode would re-count every device step as
-        # "host-side bubble" and the phase_breakdown would blame the
-        # host for a busy device.
-        self._last_decode_end = (
-            t_done
-            if any(s is not None and not s.done for s in self.slots)
-            else None)
-        result: Dict[int, List[int]] = {}
-        for slot, seq in call["seqs"].items():
-            if seq.done or self.slots[seq.slot] is not seq:
-                continue
-            got = self._fold_lane(
-                seq, (int(outs[s, slot]) for s in range(outs.shape[0])))
-            if got:
-                result[seq.request_id] = got
+            tel.decode_sync_s.observe(t_done - t_wait)
+        # The blocking sync is DEVICE time: refresh the bubble reference
+        # point so the next decode entry measures only host work after
+        # it — without this, dispatch-ahead mode would re-count every
+        # device step as "host-side bubble".
+        self._decode_streak(t_done)
+        acc0 = self.spec_accepted
+        if spec:
+            # Its fold is emission-shaped (accept-prefix + caps), not
+            # K-step-shaped.
+            result = self._fold_spec_emissions(
+                call["seqs"], call["allowed"], call["n_prop"], *outs)
+        else:
+            if outs is not None:
+                self._fold_moe_stats(outs)
+            result = {}
+            for slot, seq in call["seqs"].items():
+                if seq.done or self.slots[seq.slot] is not seq:
+                    continue
+                got = self._fold_lane(
+                    seq, (int(outs[s, slot]) for s in range(outs.shape[0])))
+                if got:
+                    result[seq.request_id] = got
         if pf is not None:
             # Hybrid call: the chunk's offset advanced at stage time; only
             # the FINAL chunk has host work left — fold its sampled token
@@ -3259,42 +3294,18 @@ class InferenceEngine:
                 self._prefill_finish(seq, pf["prompt"],
                                      int(np.asarray(pf["tok"])[0]))
                 seq.prefill_prompt = None
-        n_tokens = sum(len(t) for t in result.values())
-        if self.telemetry.enabled and outs is not None:
-            self.telemetry.tokens_per_dispatch.observe(n_tokens)
         led = call.get("ledger")
-        if led is not None and self.telemetry.enabled:
-            # Pipelined record lands at SYNC with the true device wall
-            # (non-blocking dispatch + the blocking sync) and the folded
-            # token count; the stage-time micros rode along in ``led``.
-            tokens = (led["tokens"] if led["kind"] == "prefill_chunk"
-                      else n_tokens + led["tokens"])
-            self._ledger_push(
-                led["kind"], rung=led["rung"], slots=led["slots"],
-                tokens=tokens, chunk_tokens=led["chunk_tokens"],
-                steps=led["steps"],
-                # a chunk's device_s is enqueue -> observed; a decode
-                # call's stays its dispatch + sync walls
-                device_s=(t_done - call["t_enqueue"]
-                          if led["kind"] == "prefill_chunk"
-                          else led["dispatch_s"] + sync_dt),
-                kv_read=led["kv_read"], staging_s=led["staging_s"],
-                bubble_s=led["bubble_s"], compile_event=led["compile"],
-                seq=call["seq"], t_enqueue=call["t_enqueue"],
-                t_done=t_done)
-        return result
-
-    def _pressure_settle_round(self) -> Dict[int, List[int]]:
-        """Optimistic admission under watermark pressure: settle device
-        state before any preemption decision — in-flight calls hold
-        predicted-ctx page grants — then run one synchronous round,
-        which preempts as needed (and runs the chaos gate itself:
-        gating in the caller too would double the injected failure rate
-        on this branch). Shared by the plain and hybrid pipelined
-        entry points so the pressure semantics cannot drift."""
-        result = self.drain_pipeline()
-        for rid, toks in self.decode_steps().items():
-            result.setdefault(rid, []).extend(toks)
+        if tel.enabled:
+            n_tokens = sum(len(t) for t in result.values())
+            if outs is not None:
+                tel.tokens_per_dispatch.observe(n_tokens)
+            if led is not None:
+                led["tokens"] += n_tokens
+                self._ledger_push(
+                    **led, device_s=t_done - t_start,
+                    spec_accepted=self.spec_accepted - acc0,
+                    seq=call["seq"], t_enqueue=call["t_enqueue"],
+                    t_done=t_done)
         return result
 
     def _pipeline_rung_blocked(self) -> bool:
@@ -3322,202 +3333,6 @@ class InferenceEngine:
         if not active:
             return False
         return self._rung_for_slots(active) > cap
-
-    def decode_steps_pipelined(self) -> Dict[int, List[int]]:
-        """Dispatch-ahead serving step: keep up to
-        ``decode_pipeline_depth`` fused-decode calls in flight; sync only
-        the oldest. Token delivery lags dispatch by depth-1 calls, and
-        device compute overlaps all host work in between.
-        Falls back to the synchronous path when depth <= 1 or spec is on.
-        """
-        depth = self.engine_cfg.decode_pipeline_depth
-        if depth <= 1 or self.spec_draft:
-            return self.decode_steps()         # gate runs inside
-        if self.admission == "optimistic" and self.under_pressure:
-            return self._pressure_settle_round()
-        self._chaos_step_gate()
-        if self.spec_ngram:
-            return self._ngram_steps_pipelined()
-        result: Dict[int, List[int]] = {}
-        if self._pipeline_rung_blocked():
-            result = self.drain_pipeline()     # settle, then grow rung
-        call = self._stage_decode_call()
-        if call is not None:
-            self._inflight.append(call)
-        if not self._inflight:
-            return result
-        if len(self._inflight) >= depth or call is None:
-            for rid, toks in self._sync_oldest().items():
-                result.setdefault(rid, []).extend(toks)
-        return result
-
-    def hybrid_step_pipelined(self, seq: Sequence) -> Dict[int, List[int]]:
-        """Serving step while ``seq`` is mid-incremental-prefill: advance
-        its next chunk AND the decode lanes in ONE fused dispatch
-        (EngineConfig.hybrid_prefill), so running lanes keep producing
-        tokens instead of stalling a chunk wall per chunk.
-
-        Chains into the same dispatch-ahead pipeline as plain decode
-        calls: with depth > 1 the call is non-blocking and only the
-        oldest in-flight call is synced; with depth <= 1 it dispatches
-        and syncs immediately (synchronous mode). Once the prompt is
-        fully staged, further calls degrade to plain decode staging and
-        the final chunk's sampled token folds at its sync — the caller
-        observes completion as ``seq.prefill_prompt is None``.
-        Returns decode tokens folded by this call (possibly {}).
-        """
-        assert not self.spec_enabled, \
-            "hybrid steps don't compose with speculative decoding"
-        depth = max(1, self.engine_cfg.decode_pipeline_depth)
-        if (self.admission == "optimistic" and self.under_pressure
-                and self.active_sequences()):
-            # Pressure settles first (drain + one synchronous preempting
-            # round), then the chunk advances SERIALLY: its pages were
-            # all allocated at prefill_begin, so it cannot deepen the
-            # shortage, and skipping it would starve the prefill for as
-            # long as pressure holds — a liveness regression vs serial
-            # mode, which advances one chunk per iteration regardless.
-            # (The active_sequences guard also protects direct
-            # engine-API drivers: with no lanes there is nothing to
-            # settle and the plain staging path below handles the
-            # chunk.)
-            result = self._pressure_settle_round()
-            if seq.prefill_prompt is not None and not seq.done:
-                self.prefill_step(seq)
-            return result
-        self._chaos_step_gate()
-        result: Dict[int, List[int]] = {}
-        if self._pipeline_rung_blocked():
-            result = self.drain_pipeline()     # settle, then grow rung
-        call = self._stage_decode_call(prefill_seq=seq)
-        if call is not None:
-            self._inflight.append(call)
-        if not self._inflight:
-            return result
-        if depth <= 1 or len(self._inflight) >= depth or call is None:
-            for rid, toks in self._sync_oldest().items():
-                result.setdefault(rid, []).extend(toks)
-        return result
-
-    @property
-    def pipeline_pending(self) -> bool:
-        return bool(self._inflight)
-
-    def abort_pipeline(self) -> None:
-        """Discard in-flight calls WITHOUT folding (decode-error
-        recovery): after an error their outputs are suspect, and leaving
-        stale entries would poison ctx prediction / carry tokens for
-        whatever request reuses those slots next."""
-        self._inflight.clear()
-
-    def drain_pipeline(self) -> Dict[int, List[int]]:
-        """Sync every in-flight call (idle/finish/shutdown path)."""
-        result: Dict[int, List[int]] = {}
-        while self._inflight:
-            for rid, toks in self._sync_oldest().items():
-                result.setdefault(rid, []).extend(toks)
-        return result
-
-    def decode_steps_chained(self, n_calls: int) -> Dict[int, List[int]]:
-        """Dispatch-ahead decode: ``n_calls`` fused-decode dispatches
-        back-to-back, each consuming the previous call's device-resident
-        final carry tokens — ZERO host syncs until the end (then one).
-
-        This removes the host round trip from the decode critical
-        path (SURVEY.md §7 hard part 3); with K fused steps per call the
-        device runs n_calls*K tokens per lane uninterrupted. Constraints
-        of the mode: pages are pre-provisioned for the full run (raises
-        MemoryError if the pool can't hold it), EOS/budget do not stop
-        lanes early (bench / fixed-length batch mode — callers cap
-        n_calls*K by the remaining budget).
-        """
-        ecfg = self.engine_cfg
-        k_steps = max(1, ecfg.decode_steps_per_call)
-        active_seqs = self.active_sequences()
-        if not active_seqs:
-            return {}
-        total = n_calls * k_steps
-        for seq in active_seqs:
-            budget = seq.max_new_tokens - len(seq.generated)
-            room = ecfg.max_context - 1 - seq.ctx_len
-            if total > min(budget, room):
-                # No mid-run stopping in this mode: the caller must size
-                # n_calls*K within every lane's budget AND context room
-                # (decode_steps folds these into `allowed` per step; here
-                # they would overflow the block table / clamp positions).
-                raise ValueError(
-                    f"decode_steps_chained: n_calls*K={total} exceeds "
-                    f"seq {seq.request_id}'s budget={budget} or context "
-                    f"room={room}")
-            need = kvc.pages_needed(total, ecfg.page_size,
-                                    already=seq.ctx_len)
-            if need > 0:
-                seq.pages.extend(self._allocate_reclaiming(need))
-
-        self._compact_slots()
-        b = self._rung_for_slots(active_seqs)
-        self._note_rung(b)
-        (tokens, ctx_lens, bts, temps, top_ps, top_ks, seeds,
-         rpens, rlasts, windows) = self._stage_batch(active_seqs, b)
-        allowed = np.zeros((b,), np.int32)
-        for seq in active_seqs:
-            allowed[seq.slot] = k_steps
-        no_eos = jnp.full((b,), -1, jnp.int32)
-        allowed_d = jnp.asarray(allowed)
-        bts_d = jnp.asarray(bts)
-        temps_d, top_ps_d = jnp.asarray(temps), jnp.asarray(top_ps)
-        top_ks_d, seeds_d = jnp.asarray(top_ks), jnp.asarray(seeds)
-        rpens_d, rlasts_d = jnp.asarray(rpens), jnp.asarray(rlasts)
-
-        tokens_dev = jnp.asarray(tokens)
-        window_dev = jnp.asarray(windows)
-        outs_all = []
-        kv_read = sum(s.ctx_len for s in active_seqs) * total
-        dispatch_wall = 0.0
-        t_first = 0.0
-        for c in range(n_calls):
-            ((self.kv, outs, tokens_dev, window_dev), dseq, t0,
-             dt) = self._run_decode(
-                "decode", self._decode_multi_jit,
-                (self.params, self.kv, tokens_dev,
-                 jnp.asarray(ctx_lens + c * allowed, np.int32), bts_d,
-                 allowed_d, no_eos, self._next_key(), temps_d, top_ps_d,
-                 top_ks_d, seeds_d, rpens_d, rlasts_d, window_dev),
-                rung=b, slots=len(active_seqs), tokens=int(allowed.sum()))
-            outs_all.append(outs)
-            dispatch_wall += dt
-            t_first = t_first or t0
-        _, t_sync, t_done = self._wait(       # the run's one readback
-            dseq, lambda: jax.block_until_ready(tokens_dev))
-        sync_dt = t_done - t_sync
-        self.telemetry.decode_sync_s.observe(sync_dt)
-        # Device wait, not host bubble (same rationale as _sync_oldest).
-        self._last_decode_end = t_done
-
-        result: Dict[int, List[int]] = {rid.request_id: []
-                                        for rid in active_seqs}
-        for outs in outs_all:
-            outs = np.asarray(outs)
-            self._fold_moe_stats(outs)
-            for seq in active_seqs:
-                got = [int(t) for t in outs[:, seq.slot] if t >= 0]
-                seq.ctx_len += len(got)
-                seq.generated.extend(got)
-                if seq.first_token_time == 0.0:
-                    seq.first_token_time = time.perf_counter()
-                result[seq.request_id].extend(got)
-        for seq in active_seqs:
-            self._maybe_finish(seq, seq.last_token)
-        if self.telemetry.enabled:
-            # One record for the whole chained run (the mode's unit of
-            # dispatch from the host's point of view: one sync).
-            self._ledger_push(
-                "decode", rung=b, slots=len(active_seqs),
-                tokens=sum(len(t) for t in result.values()),
-                steps=total, device_s=dispatch_wall + sync_dt,
-                kv_read=kv_read, seq=dseq, t_enqueue=t_first,
-                t_done=t_done)
-        return result
 
     def _spec_grant(self, active_seqs: List[Sequence], s_len: int,
                     max_steps: Optional[int]) -> Tuple[List[Sequence],
@@ -3600,7 +3415,7 @@ class InferenceEngine:
         # sampler consumes randomness at a data-dependent rate, so a
         # position-keyed stream would not reproduce anyway); spec uses the
         # engine-global key.
-        out, dseq, t0, _ = self._run(
+        out, dseq, t0, _ = self._run_decode(
             "spec_verify", self._spec_jit,
             (self.params, self.draft_params, self.kv, self.draft_kv,
              jnp.asarray(tokens), jnp.asarray(ctx_lens), jnp.asarray(bts),
@@ -3608,12 +3423,12 @@ class InferenceEngine:
              jnp.asarray(temps), jnp.asarray(top_ps), jnp.asarray(top_ks)),
             rung=b, slots=len(active_seqs),
             tokens=s_len * len(active_seqs))
-        self._note_decode_entry(t0)
         self.kv, self.draft_kv = out.kv, out.draft_kv
-        (emitted, n_acc), _, t_done = self._wait(dseq, lambda: (
+        (emitted, n_acc), t_wait, t_done = self._wait(dseq, lambda: (
             np.asarray(out.emitted),                        # [B, gamma+1]
             np.asarray(out.n_accepted)))
-        dt = self._note_decode_exit(t0, t_done)
+        self.telemetry.decode_sync_s.observe(t_done - t_wait)
+        self._decode_streak(t_done)
         # Pre-fold context: the verify forward read the cache at the ctx
         # the lanes ENTERED the round with.
         kv_read = sum(s.ctx_len for s in active_seqs) * s_len
@@ -3654,7 +3469,7 @@ class InferenceEngine:
             self.telemetry.tokens_per_dispatch.observe(n_toks)
             self._ledger_push(
                 "spec_verify", rung=b, slots=len(active_seqs),
-                tokens=n_toks, device_s=dt, kv_read=kv_read,
+                tokens=n_toks, device_s=t_done - t0, kv_read=kv_read,
                 spec_accepted=self.spec_accepted - acc0,
                 seq=dseq, t_enqueue=t0, t_done=t_done)
         return result
@@ -3802,7 +3617,7 @@ class InferenceEngine:
         """Stage + dispatch one verify-only round at the smallest ladder
         rung covering the batch and the compiled width ``s_len``
         (non-blocking). Returns (VerifyRoundOut, {slot: n_proposed},
-        rung)."""
+        rung, the dispatch's number, the instant its call began)."""
         ecfg = self.engine_cfg
         gamma = s_len - 1
         b = self._rung_for_slots(active_seqs)
@@ -3825,7 +3640,7 @@ class InferenceEngine:
         # consumes randomness at a data-dependent rate, so a position-
         # keyed stream would not reproduce anyway); greedy — where the
         # byte-identity guarantee lives — is unaffected.
-        out, dseq, t0, dt = self._run_decode(
+        out, dseq, t0, _ = self._run_decode(
             "spec_verify", self._verify_jit,
             (self.params, self.kv, jnp.asarray(tokens),
              jnp.asarray(ctx_lens), jnp.asarray(bts), jnp.asarray(cap),
@@ -3836,29 +3651,22 @@ class InferenceEngine:
             rung=b, slots=len(active_seqs),
             tokens=s_len * len(active_seqs))
         self.kv = out.kv
-        # Stash the (non-blocking) dispatch wall, the dispatch's number
-        # and instant, and the cache-read estimate for whichever caller
-        # pushes this round's ledger record (sync path: after the fold;
-        # pipelined: at sync).
-        self._last_verify_dt = dt
-        self._last_verify = (dseq, t0)
-        self._last_verify_kv_read = (
-            sum(s.ctx_len for s in active_seqs) * s_len)
         self.spec_rounds_total += 1
         if self.telemetry.enabled:
             full = ecfg.num_speculative_tokens
             gammas = [s.spec_gamma if s.spec_gamma >= 0 else full
                       for s in active_seqs]
             self.telemetry.spec_gamma_g.set(sum(gammas) / len(gammas))
-        return out, {s.slot: int(n_prop[s.slot]) for s in active_seqs}, b
+        return (out, {s.slot: int(n_prop[s.slot]) for s in active_seqs}, b,
+                dseq, t0)
 
     def _fold_spec_emissions(self, seqs: Dict[int, Sequence],
                              emit_by_slot: Dict[int, int],
                              prop_by_slot: Dict[int, int],
                              emitted: np.ndarray, n_acc: np.ndarray
                              ) -> Dict[int, List[int]]:
-        """Fold one verify round's emissions into host state (shared by
-        the sync and dispatch-ahead ngram paths): emit caps truncate at
+        """Fold one n-gram verify round's emissions into host state
+        (_sync_oldest's ``spec`` arm): emit caps truncate at
         budget/pool limits, EOS stops a lane mid-round via
         _maybe_finish, and each lane's acceptance updates its adaptive
         γ. Lanes cancelled/preempted while the call was in flight are
@@ -3892,92 +3700,42 @@ class InferenceEngine:
             self._spec_update_adaptive(seq, drafted, accepted)
             if got:
                 result[seq.request_id] = got
-        if self.telemetry.enabled:
-            self.telemetry.tokens_per_dispatch.observe(
-                sum(len(t) for t in result.values()))
         return result
 
-    def _ngram_decode_steps(self, max_steps: Optional[int] = None
-                            ) -> Dict[int, List[int]]:
-        """One synchronous draft-free spec round: propose (host numpy),
-        verify-accept (one target forward at the current ladder rung),
-        fold. Rounds where NO slot proposes — cold streams, throttled
-        streams, no history echo — run the plain fused-K decode graph
-        instead, so ngram spec is never slower than plain decode."""
+    def _stage_ngram_call(self, max_steps: Optional[int] = None
+                          ) -> Optional[dict]:
+        """Stage one draft-free spec round (non-blocking): propose (host
+        numpy), then enqueue the verify-accept forward at the current
+        ladder rung and width. The call enters ``_inflight`` like a
+        plain decode call, so at depth > 1 the host overlaps its device
+        time with scheduler work and the NEXT round's n-gram matching.
+        Rounds where NO slot proposes — cold streams, throttled streams,
+        no history echo — stage the plain fused-K round instead, so
+        ngram spec is never slower than plain decode. The pipeline is
+        empty here (_round drains first)."""
         ecfg = self.engine_cfg
         s_len = ecfg.num_speculative_tokens + 1
         self._compact_slots()         # rung steps down when occupancy drops
         active_seqs = self.active_sequences()
         if not active_seqs:
-            return {}
+            return None
         active_seqs = self._preempt_for_pressure(active_seqs, s_len)
         active_seqs = [s for s in active_seqs
                        if not s.done and s.slot >= 0]
         if not active_seqs:
-            return {}
+            return None
         proposals = self._gate_mixed_batch(
             active_seqs, self._ngram_proposals(active_seqs))
         if not proposals:
             self.spec_fallback_rounds += 1
-            return self._plain_decode_steps(max_steps)
+            return self._stage_decode_call(max_steps=max_steps)
         s_len = self._spec_width_for(proposals)
         active_seqs, emit_by_slot = self._spec_grant(active_seqs, s_len,
                                                      max_steps)
         if not active_seqs:
-            return {}
-        out, prop_by_slot, rung = self._dispatch_verify(active_seqs,
-                                                        proposals, s_len)
-        acc0 = self.spec_accepted
-        dseq, t0 = self._last_verify
-        (emitted, n_acc), _, t_done = self._wait(dseq, lambda: (
-            np.asarray(out.emitted), np.asarray(out.n_accepted)))
-        result = self._fold_spec_emissions(
-            {s.slot: s for s in active_seqs}, emit_by_slot, prop_by_slot,
-            emitted, n_acc)
-        if self.telemetry.enabled:
-            self._ledger_push(
-                "spec_verify", rung=rung, slots=len(active_seqs),
-                tokens=sum(len(t) for t in result.values()),
-                device_s=self._last_verify_dt,
-                kv_read=self._last_verify_kv_read,
-                spec_accepted=self.spec_accepted - acc0,
-                seq=dseq, t_enqueue=t0, t_done=t_done)
-        return result
-
-    def _stage_ngram_call(self) -> Optional[dict]:
-        """Stage one spec round non-blocking for the dispatch-ahead
-        pipeline (PR-4's hybrid-chunk pattern): the verify dispatch
-        enters ``_inflight`` and the host overlaps its device time with
-        scheduler work — admission, prefetch, callbacks, and the NEXT
-        round's n-gram matching. Rounds with no proposals stage a plain
-        fused-K decode call instead (the same dispatch-ahead machinery).
-        Caller guarantees the pipeline is empty (proposals need the
-        previous round's accepted tokens, so spec chains at depth 1 of
-        staging: sync round N, stage round N+1)."""
-        ecfg = self.engine_cfg
-        s_len = ecfg.num_speculative_tokens + 1
-        self._compact_slots()
-        active_seqs = self.active_sequences()
-        if not active_seqs:
             return None
-        active_seqs = self._preempt_for_pressure(active_seqs, s_len)
-        active_seqs = [s for s in active_seqs
-                       if not s.done and s.slot >= 0]
-        if not active_seqs:
-            return None
-        proposals = self._gate_mixed_batch(
-            active_seqs, self._ngram_proposals(active_seqs))
-        if not proposals:
-            self.spec_fallback_rounds += 1
-            return self._stage_decode_call()
-        s_len = self._spec_width_for(proposals)
-        active_seqs, emit_by_slot = self._spec_grant(active_seqs, s_len,
-                                                     None)
-        if not active_seqs:
-            return None
-        out, prop_by_slot, rung = self._dispatch_verify(active_seqs,
-                                                        proposals, s_len)
-        dseq, t0 = self._last_verify
+        out, prop_by_slot, rung, dseq, t0 = self._dispatch_verify(
+            active_seqs, proposals, s_len)
         call = {"spec": True, "emitted": out.emitted,
                 "seq": dseq, "t_enqueue": t0,
                 "n_accepted": out.n_accepted,
@@ -3986,68 +3744,15 @@ class InferenceEngine:
                 "rung": rung, "outs": None, "final": None,
                 "final_window": None}
         if self.telemetry.enabled:
-            # Stage-time micros ride on the call; the record lands at
-            # sync with the true device wall (see _sync_spec_call).
+            # Stage-time micros ride on the call; _sync_oldest pushes
+            # the record. The verify forward reads the cache at the ctx
+            # the lanes ENTER the round with.
             call["ledger"] = {
                 "kind": "spec_verify", "rung": rung,
                 "slots": len(active_seqs), "tokens": 0,
-                "chunk_tokens": 0, "steps": 1,
-                "dispatch_s": self._last_verify_dt,
-                "staging_s": self._last_staging_s,
-                "bubble_s": self._pending_bubble,
-                "kv_read": self._last_verify_kv_read,
-                "compile": self._last_compile_event}
-            self._last_staging_s = 0.0
-            self._pending_bubble = 0.0
-            self._last_compile_event = False
+                "kv_read": sum(s.ctx_len for s in active_seqs) * s_len,
+                **self._take_stage_scratch()}
         return call
-
-    def _sync_spec_call(self, call: dict) -> Dict[int, List[int]]:
-        """Block on an in-flight spec round and fold its emissions
-        (the _sync_oldest arm for ``spec`` calls)."""
-        (emitted, n_acc), t0, t_done = self._wait(call["seq"], lambda: (
-            np.asarray(call["emitted"]),                # [B, γ+1] blocks
-            np.asarray(call["n_accepted"])))
-        sync_dt = t_done - t0
-        self.telemetry.decode_sync_s.observe(sync_dt)
-        # Device wait, not host bubble (same rationale as _sync_oldest).
-        self._last_decode_end = (
-            t_done
-            if any(s is not None and not s.done for s in self.slots)
-            else None)
-        acc0 = self.spec_accepted
-        result = self._fold_spec_emissions(call["seqs"], call["allowed"],
-                                           call["n_prop"], emitted, n_acc)
-        led = call.get("ledger")
-        if led is not None and self.telemetry.enabled:
-            self._ledger_push(
-                led["kind"], rung=led["rung"], slots=led["slots"],
-                tokens=sum(len(t) for t in result.values()),
-                steps=led["steps"],
-                device_s=led["dispatch_s"] + sync_dt,
-                kv_read=led["kv_read"], staging_s=led["staging_s"],
-                bubble_s=led["bubble_s"], compile_event=led["compile"],
-                spec_accepted=self.spec_accepted - acc0,
-                seq=call["seq"], t_enqueue=call["t_enqueue"],
-                t_done=t_done)
-        return result
-
-    def _ngram_steps_pipelined(self) -> Dict[int, List[int]]:
-        """Dispatch-ahead serving step for ngram spec: sync the in-flight
-        round (its accepted tokens seed the next proposals — spec rounds
-        cannot chain blind like plain decode carries), then stage the
-        next round non-blocking. At steady state one verify dispatch is
-        always in flight while the host does scheduler work + the next
-        round's n-gram matching — the PR-7 host bubble hides behind the
-        device just like plain dispatch-ahead."""
-        result: Dict[int, List[int]] = {}
-        if self._inflight:
-            for rid, toks in self._sync_oldest().items():
-                result.setdefault(rid, []).extend(toks)
-        call = self._stage_ngram_call()
-        if call is not None:
-            self._inflight.append(call)
-        return result
 
     # ------------------------------------------------------------------
     # Convenience batch generation (tests, bench, config-1 path)

@@ -1034,17 +1034,16 @@ class EngineScheduler:
                 thresh = engine.engine_cfg.latency_decode_threshold
                 t_call = clock.enter("stage")
                 self.step_inflight_since = time.monotonic()
-                if hybrid_pf is not None:
-                    # Hybrid step: the in-progress prefill's next chunk
-                    # rides the decode dispatch instead of stalling it.
-                    new_tokens = engine.hybrid_step_pipelined(hybrid_pf.seq)
-                elif (0 < len(active) <= thresh and not self._waiting
+                if (0 < len(active) <= thresh and not self._waiting
                         and self._prefilling is None
                         and not engine.pipeline_pending
                         and not engine.spec_enabled):
                     new_tokens = engine.decode_steps(max_steps=1)
                 else:
-                    new_tokens = engine.decode_steps_pipelined()
+                    # Hybrid step: the in-progress prefill's next chunk
+                    # rides the decode dispatch instead of stalling it.
+                    new_tokens = engine.decode_steps_pipelined(
+                        hybrid_pf.seq if hybrid_pf is not None else None)
                 self.stats.record_decode_call(clock.enter("other") - t_call)
             except Exception as exc:  # noqa: BLE001 — keep the engine loop alive
                 victims = list(active)

@@ -2164,6 +2164,13 @@ class ProcessEngineGroup:
             entry = self._entry_for(rid, h, client)
             if entry is None:
                 return
+            if obj.get("counters") and h.last_stats:
+                # The worker's engine counters as of this finish: the
+                # stats cache is refreshed by the monitor thread, which
+                # a respawn holds for seconds, and a caller that reads
+                # supervision_counters() on its request's finish must
+                # find what the request did (a swap-in resume).
+                h.last_stats = {**h.last_stats, **obj["counters"]}
             retryable = (reason in _RETRYABLE
                          and not entry.tokens
                          and entry.attempts

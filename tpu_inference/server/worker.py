@@ -762,6 +762,14 @@ class EngineWorker:
                 "resume_base": sq.resume_base,
                 "prefill_s": round(max(0.0, first - start), 6),
                 "decode_s": round(max(0.0, fin - first), 6),
+                # The engine counters the router's supervision view
+                # sums (stats snapshot keys): what this request did to
+                # them arrives with its finish, not a stats tick later.
+                "counters": {
+                    "preemptions": self.engine.preemptions_total,
+                    "recompute_resumes": self.engine.resumes_total,
+                    "swap_in_resumes": self.engine.swap_in_resumes,
+                    "pd_adoptions": self.engine.adoptions_in},
                 # Completed spans ride the finish frame back to the
                 # router's trace assembly (README "Observability").
                 "trace": tid,

@@ -1181,7 +1181,9 @@ STEP_FIELDS = (
     "chunk_tokens",   # prompt tokens processed (prefill/hybrid chunk)
     "steps",          # device loop iterations (fused-K; weights stream
                       # from HBM once per iteration)
-    "device_s",       # device wall (dispatch + sync for pipelined calls)
+    "device_s",       # device wall: from the call's enqueue, or the
+                      # readback before it if later (it ran behind what
+                      # was in flight), to its own readback
     "staging_s",      # host batch-staging wall (_stage_batch micro)
     "bubble_s",       # host gap before the dispatch (device-idle
                       # exposure while lanes were active)
@@ -2176,12 +2178,11 @@ class EngineTelemetry:
     Engine phases (observed by engine/engine.py):
     - ``prefill_dispatch_s``: host wall of one prefill dispatch
       (staging + device call + the blocking first-token readback).
-    - ``decode_dispatch_s``: host wall of one fused-decode engine call
-      (sync mode: includes the device wait; dispatch-ahead mode: the
-      non-blocking dispatch only — the device wait shows up in
-      ``decode_sync_s`` instead).
-    - ``decode_sync_s``: host wall blocked syncing a dispatch-ahead
-      call's outputs.
+    - ``decode_dispatch_s``: host wall of enqueueing one decode call
+      (non-blocking at every pipeline depth; the device wait is
+      ``decode_sync_s``).
+    - ``decode_sync_s``: host wall blocked reading a decode call's
+      outputs back (at depth 1 right behind its enqueue).
     - ``dispatch_bubble_s``: host-side gap between consecutive decode
       engine calls while sequences were active — scheduler bookkeeping,
       token callbacks, admission: the time the device could sit idle
@@ -2267,10 +2268,10 @@ class EngineTelemetry:
             "Host wall time of one prefill dispatch")
         self.decode_dispatch_s = r.histogram(
             "tpu_inf_decode_dispatch_seconds",
-            "Host wall time of one fused-decode engine call")
+            "Host wall time of enqueueing one decode call")
         self.decode_sync_s = r.histogram(
             "tpu_inf_decode_sync_seconds",
-            "Host wall blocked syncing a dispatch-ahead decode call")
+            "Host wall blocked reading one decode call's outputs back")
         self.dispatch_bubble_s = r.histogram(
             "tpu_inf_dispatch_bubble_seconds",
             "Host-side gap between consecutive decode calls with active "
