@@ -680,7 +680,8 @@ def _kernel_errors(cfg: dict, mcfg, interpret: bool = False) -> dict:
     random bf16 pool: the model's heads, window and 16-token pages;
     contexts short, long, and past the window (so the windowed page
     offset is exercised at a non-zero window start); one prefill chunk
-    that starts behind the window's edge. The pool is stacked, three
+    that starts behind the window's edge, and the doc cell's 1024-token
+    chunk at offsets 0 and 3072. The pool is stacked, three
     layers of different contents, and the kernels read the middle one
     (they take the whole stack and address the layer themselves)."""
     import jax
@@ -755,6 +756,23 @@ def _kernel_errors(cfg: dict, mcfg, interpret: bool = False) -> dict:
             q_offset=jnp.asarray(q_off), kv_len=jnp.asarray(lens),
             sliding_window=win)
         out["prefill"] = err(got, want)
+        # The doc cell's chunk: 1024 tokens whose query blocks see no
+        # block of pages before their own (offset 0), and 1024 tokens
+        # behind three chunks of a document (offset 3072 under Mistral's
+        # 4096 window: 13 to 17 blocks of 256 tokens a query block).
+        s_len = 1024
+        q_off = np.array([0, (win or 4096) - s_len], np.int32)
+        lens = q_off + s_len
+        qp = jax.random.normal(key[3], (2, s_len, hq, d),
+                               jnp.bfloat16).astype(jnp.float32)
+        got = paged_prefill_attention(
+            qp, k_pool, v_pool, layer, tables[:2], jnp.asarray(lens),
+            jnp.asarray(q_off), interpret=interpret, sliding_window=win)
+        want = dense_causal_attention(
+            qp, k_all[:2], v_all[:2],
+            q_offset=jnp.asarray(q_off), kv_len=jnp.asarray(lens),
+            sliding_window=win)
+        out["prefill_doc_chunk"] = err(got, want)
     return {k: round(v, 5) for k, v in out.items()}
 
 

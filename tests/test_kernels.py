@@ -328,36 +328,83 @@ def test_engine_pallas_backend_sharded_matches_dense():
     assert got_d == got_p
 
 
-@pytest.mark.parametrize("layer", LAYER_IDS)
-@pytest.mark.parametrize("block_q,q_offsets", [(16, (5, 0)), (8, (0, 13))])
-def test_paged_prefill_attention_matches_dense(block_q, q_offsets, layer):
+# Shapes of test_paged_prefill_attention_matches_dense. Defaults: 2
+# sequences of a 32-row chunk, 8 q / 2 kv heads x 64, 8-token pages (a
+# page that is not whole 128-lane tiles: the pipeline-fed side), block_q
+# 128. ``q_off`` / ``prompt``: per sequence, the cached prefix and the
+# chunk's real rows (kv_len = their sum; 0 + 0 is a row of the batch that
+# holds no sequence). head_dim 128 pools are copied by hand, a block of
+# 256 tokens at a time (PR 29), so contexts there run to several blocks.
+PREFILL_SHAPES = {
+    "offsets-bq16": dict(block_q=16, q_off=(5, 0), prompt=(20, 32)),
+    "offsets-bq8": dict(block_q=8, q_off=(0, 13), prompt=(20, 32)),
+    # Contexts that end mid-block and mid-page (321 = a block + 4 pages +
+    # 1 token), on a block's last token (512), and one token on (513).
+    "mid-block-mid-page": dict(d=128, pg=16, block_q=16, mp=40,
+                               q_off=(300, 480, 481), prompt=(21, 32, 32)),
+    # A batch with an all-padding row between two live ones, and a live
+    # one whose last query block is all padding: 0, not NaN.
+    "padding-row": dict(d=128, pg=16, block_q=8, mp=24,
+                        q_off=(270, 0, 0), prompt=(32, 0, 5)),
+    # Qwen2's heads (4 kv x 7 q: a row's token is row // 7) behind a
+    # cached prefix.
+    "qwen2-heads": dict(hq=28, hkv=4, d=128, pg=16, mp=24,
+                        q_off=(300, 40), prompt=(32, 17)),
+    # A verification call: a handful of query rows.
+    "verify-4-rows": dict(s=4, d=128, pg=16, mp=24, q_off=(290, 37),
+                          prompt=(4, 4)),
+    "head-dim-96": dict(hq=4, hkv=4, d=96, pg=16, mp=24, q_off=(300, 0),
+                        prompt=(32, 20)),
+    # 16-token pages x 8 kv heads: the scales are whole tiles, by hand.
+    "int8-by-hand": dict(hq=16, hkv=8, d=128, pg=16, mp=24, q_off=(300, 0),
+                         prompt=(32, 20), kv_quant="int8"),
+    "int8-pipelined": dict(d=128, pg=16, mp=24, q_off=(300, 0),
+                           prompt=(32, 20), kv_quant="int8"),
+    "int4": dict(d=128, pg=16, mp=24, q_off=(300, 0), prompt=(32, 20),
+                 kv_quant="int4"),
+}
+
+
+@pytest.mark.parametrize("shape,layer", [
+    (name, layer) for name in PREFILL_SHAPES
+    for layer in (LAYER_IDS if name.startswith("offsets") else [1])])
+def test_paged_prefill_attention_matches_dense(shape, layer):
     """Flash prefill over pool pages == dense gather+causal attention,
     including cached-prefix offsets and partially-filled last pages."""
     from tpu_inference.kernels.prefill_attention import paged_prefill_attention
 
+    c = dict(s=32, hq=8, hkv=2, d=64, pg=8, mp=8, block_q=128,
+             kv_quant="none")
+    c.update(PREFILL_SHAPES[shape])
     rng = np.random.default_rng(7)
-    b, s, hq, hkv, d, pg, npg, mp = 2, 32, 8, 2, 64, 8, 64, 8
-    k_pool = _stacked_pool(rng, npg, pg, hkv, d)
-    v_pool = _stacked_pool(rng, npg, pg, hkv, d)
-    q = jnp.asarray(rng.standard_normal((b, s, hq, d)), jnp.float32)
+    b, s, mp = len(c["q_off"]), c["s"], c["mp"]
+    npg = 1 + b * mp
+    kv = _quantize_pools(
+        _stacked_pool(rng, npg, c["pg"], c["hkv"], c["d"]),
+        _stacked_pool(rng, npg, c["pg"], c["hkv"], c["d"]), c["kv_quant"])
+    q = jnp.asarray(rng.standard_normal((b, s, c["hq"], c["d"])), jnp.float32)
     perm = rng.permutation(np.arange(1, npg))[:b * mp]
     bt = jnp.asarray(perm.reshape(b, mp).astype(np.int32))
-    q_off = jnp.asarray(q_offsets, jnp.int32)
-    prompt = jnp.asarray([20, 32], jnp.int32)
-    kv_len = q_off + prompt
+    q_off = jnp.asarray(c["q_off"], jnp.int32)
+    prompt = np.asarray(c["prompt"])
+    kv_len = q_off + jnp.asarray(prompt, jnp.int32)
+    scales = ((kv.k_scale[layer], kv.v_scale[layer]) if kv.quantized
+              else (None, None))
 
-    got = paged_prefill_attention(q, k_pool, v_pool, layer, bt, kv_len,
-                                  q_off, block_q=block_q, interpret=True)
-    kv = kvc.KVPages(k=k_pool, v=v_pool)
+    got = np.asarray(paged_prefill_attention(
+        q, kv.k, kv.v, layer, bt, kv_len, q_off, *scales,
+        block_q=c["block_q"], interpret=True))
     k_all, v_all = kvc.gather_kv(kv, layer, bt)
     want = common.dense_causal_attention(q, k_all, v_all, q_offset=q_off,
                                          kv_len=kv_len)
+    assert np.isfinite(got).all()
     tol = 2e-5 * (layer + 1)       # layer l's values are l + 1 times larger
     for i in range(b):
         n = int(prompt[i])                    # padded query rows unused
-        np.testing.assert_allclose(np.asarray(got)[i, :n],
-                                   np.asarray(want)[i, :n],
+        np.testing.assert_allclose(got[i, :n], np.asarray(want)[i, :n],
                                    rtol=tol, atol=tol)
+        if n == 0:
+            assert not got[i].any()           # a row without a sequence
 
 
 def test_paged_prefill_non_power_of_two_bucket():

@@ -270,22 +270,42 @@ def test_swa_sp_engine_matches_unsharded(sp_attn, swa8, swa8_dense_engine):
     assert got == want
 
 
-@pytest.mark.parametrize("layer", [0, 1, KERNEL_LAYERS - 1])
-@pytest.mark.parametrize("kv_quant", ["none", "int8", "int4"])
-def test_windowed_paged_prefill_kernel_matches_dense(kv_quant, layer):
-    """The windowed Pallas prefill (per-query-block relative pages) ==
-    the window-masked dense reference, including a chunked-prefill
-    q_offset > 0 and the int8 / int4 pools, at each layer of the stacked
-    pool."""
+# (page, pages a sequence, head_dim, window, chunk rows, block_q, q_off).
+# "pages": the window is a page and a quarter, a fresh and a continued
+# chunk. "blocks" (PR 29: a query block folds 256-token blocks counted
+# from the first page its window reaches): head_dim 128, so the pages are
+# copied by hand; a window of 300 whose first position falls inside a
+# page (201 = 12 * 16 + 9) and whose per-row edges all fall inside the
+# first block, beside a sequence the window does not bind yet. "doc": the
+# doc cell's geometry: a query block with no visible block before its own
+# (offset 0) beside one at offset 3072 under Mistral's 4096 window.
+PREFILL_WINDOW_GEOMETRY = {
+    "pages": (8, 8, 16, 10, 24, 8, (0, 16)),
+    "blocks": (16, 40, 128, 300, 64, 32, (500, 100)),
+    "doc": (16, 208, 128, 4096, 256, 128, (0, 3072)),
+}
+
+
+@pytest.mark.parametrize("geometry,kv_quant,layer", [
+    ("pages", quant, layer) for quant in ("none", "int8", "int4")
+    for layer in (0, 1, KERNEL_LAYERS - 1)] + [
+    ("blocks", "none", 1), ("blocks", "int8", 1), ("doc", "none", 1)])
+def test_windowed_paged_prefill_kernel_matches_dense(geometry, kv_quant,
+                                                     layer):
+    """The windowed Pallas prefill (a query block's blocks start at the
+    first page its window reaches) == the window-masked dense reference,
+    including a chunked-prefill q_offset > 0 and the int8 / int4 pools,
+    at each layer of the stacked pool."""
     from tpu_inference.kernels.prefill_attention import (
         paged_prefill_attention)
 
     rng = np.random.default_rng(13)
-    page, mp, hq, hkv, d, window = 8, 8, 4, 2, 16, 10
-    b, s = 2, 24                 # current chunk length
-    q_off = np.array([0, 16], np.int32)      # fresh + continued chunk
+    page, mp, d, window, s, block_q, q_off = PREFILL_WINDOW_GEOMETRY[geometry]
+    hq, hkv = 4, 2
+    q_off = np.array(q_off, np.int32)        # fresh + continued chunk
+    b = len(q_off)
     kv_lens = q_off + s
-    n_pages = 40
+    n_pages = 8 + b * mp
     k_in, v_in, ks, vs, k_pool, v_pool = _stacked_pools(
         rng, n_pages, page, hkv, d, kv_quant)
     k_pool, v_pool = k_pool[layer], v_pool[layer]
@@ -297,7 +317,7 @@ def test_windowed_paged_prefill_kernel_matches_dense(kv_quant, layer):
 
     got = paged_prefill_attention(
         jnp.asarray(q), k_in, v_in, layer, jnp.asarray(bt),
-        jnp.asarray(kv_lens), jnp.asarray(q_off), ks, vs, block_q=8,
+        jnp.asarray(kv_lens), jnp.asarray(q_off), ks, vs, block_q=block_q,
         sliding_window=window, interpret=True)
 
     tol = 2e-5 * (layer + 1)

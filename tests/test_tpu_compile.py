@@ -9,9 +9,11 @@ cells serve — Mistral-7B (32 q / 8 kv heads x 128, window 4096) and
 Qwen2-7B (28 / 4 x 128, no window) — and of Phi-3-mini (32 / 32 x 96,
 window 2047: MHA, and a head size that is no whole 128-lane tile), with
 bf16, int8 and nibble-packed int4 KV pools, decode at the base rung and
-at the widest a configuration serves and one prefill each, about two
-seconds a case. The pools are STACKED ([L, P, page, Hkv, D],
-the layer an int32 operand) as the engine holds them, and a last group
+at the widest a configuration serves, prefill of one 512-token row and
+of the largest graph a cell warms up (1 x 1024 Mistral under its window,
+4 x 512 Qwen2), about two seconds a case. The pools are STACKED
+([L, P, page, Hkv, D], the layer an int32 operand) as the engine holds
+them, and a last group
 compiles each kernel inside the model's pattern — a donated pool carried
 through ``lax.scan`` over layers, scattered by ``write_kv`` right before
 the kernel reads it — and reads the chip compiler's own HLO: no
@@ -37,11 +39,11 @@ NUM_PAGES = 1024
 LAYERS = 32
 
 # name: (q heads, kv heads, head_dim, sliding window, pages per sequence,
-#        widest decode rung)
+#        widest decode rung, largest prefill graph (rows, tokens))
 HEADS = {
-    "mistral-7b": (32, 8, 128, 4096, 320, 18),
-    "phi-3-mini": (32, 32, 96, 2047, 256, 16),
-    "qwen2-7b": (28, 4, 128, 0, 192, 32),
+    "mistral-7b": (32, 8, 128, 4096, 320, 18, (1, 1024)),
+    "phi-3-mini": (32, 32, 96, 2047, 256, 16, (1, 1024)),
+    "qwen2-7b": (28, 4, 128, 0, 192, 32, (4, 512)),
 }
 
 
@@ -90,9 +92,10 @@ def _pool(chip, hkv, d, kv_quant):
 
 @pytest.mark.parametrize("kv_quant", ["none", "int8", "int4"])
 @pytest.mark.parametrize("model", sorted(HEADS))
-@pytest.mark.parametrize("kernel", ["decode", "decode-widest", "prefill"])
+@pytest.mark.parametrize("kernel", ["decode", "decode-widest", "prefill",
+                                    "prefill-widest"])
 def test_kernel_compiles_for_v5e(chip, kernel, model, kv_quant):
-    hq, hkv, d, window, mp, widest = HEADS[model]
+    hq, hkv, d, window, mp, widest, widest_prefill = HEADS[model]
 
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
@@ -100,14 +103,14 @@ def test_kernel_compiles_for_v5e(chip, kernel, model, kv_quant):
     pool, scale = _pool(chip, hkv, d, kv_quant)
     if scale is not None:
         scale = s(scale.shape[1:], scale.dtype)      # one layer's
-    if kernel != "prefill":
+    if not kernel.startswith("prefill"):
         b = 8 if kernel == "decode" else widest
         lowered = paged_attention.lower(
             s((b, hq, d), jnp.bfloat16), pool, pool, s((), jnp.int32),
             s((b, mp), jnp.int32), s((b,), jnp.int32), scale, scale,
             interpret=False, sliding_window=window)
     else:
-        b, seq = 1, 512
+        b, seq = (1, 512) if kernel == "prefill" else widest_prefill
         lowered = paged_prefill_attention.lower(
             s((b, seq, hq, d), jnp.bfloat16), pool, pool, s((), jnp.int32),
             s((b, mp), jnp.int32), s((b,), jnp.int32), s((b,), jnp.int32),
@@ -116,10 +119,16 @@ def test_kernel_compiles_for_v5e(chip, kernel, model, kv_quant):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("layer", ["first", "middle", "last", "scanned"])
-@pytest.mark.parametrize("kv_quant", ["none", "int8"])
-@pytest.mark.parametrize("kernel", ["decode", "prefill"])
-def test_layer_loop_reads_the_pool_in_place(chip, kernel, kv_quant, layer):
+@pytest.mark.parametrize("kernel,kv_quant,layer,model", [
+    (kernel, kv_quant, layer, "mistral-7b")
+    for kernel in ("decode", "prefill") for kv_quant in ("none", "int8")
+    for layer in ("first", "middle", "last", "scanned")] + [
+    # Qwen2's int8 pool (4 KV heads: scales of half a tile) is fed by the
+    # pipeline; Mistral's pools above are copied by hand.
+    (kernel, "int8", layer, "qwen2-7b")
+    for kernel in ("decode", "prefill") for layer in ("middle", "scanned")])
+def test_layer_loop_reads_the_pool_in_place(chip, kernel, kv_quant, layer,
+                                            model):
     """The engine's pattern around the kernels, compiled for the v5e: the
     donated stacked pool is scattered by ``write_kv`` and then read by
     the kernel at layer ``layer`` — a constant first / middle / last
@@ -138,7 +147,7 @@ def test_layer_loop_reads_the_pool_in_place(chip, kernel, kv_quant, layer):
                                     "benchmarks"))
     from aot_rehearsal import pool_copies
 
-    hq, hkv, d, window, mp, _ = HEADS["mistral-7b"]
+    hq, hkv, d, window, mp, _, _ = HEADS[model]
     b, seq = (8, 1) if kernel == "decode" else (1, 256)
 
     def s(shape, dtype):
@@ -183,8 +192,8 @@ def test_layer_loop_reads_the_pool_in_place(chip, kernel, kv_quant, layer):
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo
     assert pool_copies(hlo, pool.shape) == []
-    # The decode kernel views the pool [L, P, page * Hkv, D]: the same
-    # bytes, so that view must not be made by an instruction either.
+    # Both kernels view the pool [L, P, page * Hkv, D]: the same bytes,
+    # so that view must not be made by an instruction either.
     merged = pool.shape[:2] + (PAGE * hkv, pool.shape[-1])
     assert pool_copies(hlo, merged) == []
 

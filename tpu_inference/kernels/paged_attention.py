@@ -191,6 +191,84 @@ def _lanes(refs):
     return jnp.concatenate(list(refs), axis=1)
 
 
+def _page_copies(layer, bt_ref, codes, scales, sem):
+    """``copies(lane, at, count, slot, wait=False)``: start, or wait for,
+    the copies of block-table positions ``at .. at + count`` of row
+    ``lane`` into half ``slot`` of the VMEM double buffers: one DMA a
+    page a pool. ``codes`` / ``scales`` pair each pool in HBM with its
+    buffer ``[2, pages, ...]``. Start and wait walk the same descriptors
+    over the same pages; issued from a loop, not unrolled (PR 27: 16
+    pages x 3 sites unrolled cost 0.3 s of tracing a warm-up graph)."""
+
+    def copies(lane, at, count, slot, wait=False):
+        def page_dmas(n, carry):
+            page = bt_ref[lane, at + n]
+            dmas = [pltpu.make_async_copy(
+                hbm.at[layer, page], buf.at[slot, n], sem.at[slot, i])
+                for i, (hbm, buf) in enumerate(codes)]
+            dmas += [pltpu.make_async_copy(
+                hbm.at[page], buf.at[slot, n], sem.at[slot, 2 + i])
+                for i, (hbm, buf) in enumerate(scales)]
+            for c in dmas:
+                c.wait() if wait else c.start()
+            return carry
+
+        jax.lax.fori_loop(0, count, page_dmas, 0)
+
+    return copies
+
+
+def _walk_blocks(span, copies, slot_ref, pages_per_step: int, fold, init):
+    """Fold the blocks of this step of a 1-D grid: a loop of as many
+    trips as the step HAS blocks, each block's pages copied into one half
+    of the double buffer while the other half is folded.
+
+    ``span(step)`` -> (block-table row, first position in it, pages) of
+    what grid step ``step`` reads, from scalars alone; ``copies`` as
+    ``_page_copies`` makes it; ``fold(carry, slot, first page of the
+    block)`` -> carry. A step's first block is started by the step before
+    it, during its last block or, if that step has none, in its place;
+    step 0 starts its own. Which half comes next is carried from step to
+    step in ``slot_ref`` (SMEM), which the caller zeroes at step 0. The
+    scalar arithmetic is lax's, not jnp's: every jnp call traces a jit of
+    its own, and a boot traces and lowers each warm-up graph before it
+    can ask the compile cache."""
+    nps = pages_per_step
+    g = pl.program_id(0)
+    more_steps = g + 1 < pl.num_programs(0)
+    _, first, n_pages = span(g)
+    n_blocks = jax.lax.div(n_pages + nps - 1, nps)
+    slot0 = slot_ref[0]
+
+    def block_dmas(step, j, slot, wait=False):
+        lane, first, n_pages = span(step)
+        # None for a position past the step's last page.
+        copies(lane, first + j * nps,
+               jax.lax.clamp(0, n_pages - j * nps, nps), slot, wait)
+
+    @pl.when(((g == 0) & (n_blocks > 0)) | ((n_blocks == 0) & more_steps))
+    def _first_block():
+        block_dmas(jax.lax.select(n_blocks == 0, g + 1, g), 0, slot0)
+
+    def block(j, carry):
+        slot = jax.lax.rem(slot0 + j, 2)
+        last_block = j + 1 == n_blocks
+
+        # The next block, or the next step's first, flies while this one
+        # is computed.
+        @pl.when(~last_block | more_steps)
+        def _next_block():
+            block_dmas(jax.lax.select(last_block, g + 1, g),
+                       jax.lax.select(last_block, 0, j + 1), 1 - slot)
+
+        block_dmas(g, j, slot, wait=True)
+        return fold(carry, slot, first + j * nps)
+
+    carry = jax.lax.fori_loop(0, n_blocks, block, init)
+    slot_ref[0] = jax.lax.rem(slot0 + n_blocks, 2)
+    return carry
+
+
 def _dma_kernel(layer_ref, bt_ref, kv_len_ref, q_ref, *rest,
                 pages_per_step: int, page_size: int, max_pages: int,
                 quantized: bool, packed: bool, sliding_window: int,
@@ -206,38 +284,15 @@ def _dma_kernel(layer_ref, bt_ref, kv_len_ref, q_ref, *rest,
         k_hbm, v_hbm, out_ref, k_buf, v_buf, sem, slot_ref = rest
         scales = ()
     b = pl.program_id(0)
-    layer = layer_ref[0]
 
     def span(lane):
-        """(first page, pages) of the block-table positions ``lane``
-        reads: from the window's first page to its last token's page."""
+        """(lane, first page, pages) of the block-table positions
+        ``lane`` reads: from the window's first page to its last token's
+        page."""
         kv_len = kv_len_ref[lane]
         first = _first_page(kv_len, page_size, sliding_window)
         last = jnp.minimum((kv_len - 1) // page_size, max_pages - 1)
-        return first, jnp.where(kv_len > 0, last - first + 1, 0)
-
-    def block_dmas(lane, j, slot, wait=False):
-        """Start, or wait for, the copies of ``lane``'s block j into
-        buffer ``slot``: one DMA a page a pool, none for a position past
-        the lane's last page. Start and wait walk the same descriptors
-        over the same pages."""
-        first, n_pages = span(lane)
-
-        def page_dmas(n, carry):
-            page = bt_ref[lane, first + j * nps + n]
-            copies = [pltpu.make_async_copy(
-                hbm.at[layer, page], buf.at[slot, n], sem.at[slot, i])
-                for i, (hbm, buf) in enumerate(((k_hbm, k_buf),
-                                                (v_hbm, v_buf)))]
-            copies += [pltpu.make_async_copy(
-                hbm.at[page], buf.at[slot, n], sem.at[slot, 2 + i])
-                for i, (hbm, buf) in enumerate(scales)]
-            for c in copies:
-                c.wait() if wait else c.start()
-            return carry
-
-        jax.lax.fori_loop(0, jnp.clip(n_pages - j * nps, 0, nps),
-                          page_dmas, 0)
+        return lane, first, jnp.where(kv_len > 0, last - first + 1, 0)
 
     @pl.when(b == 0)
     def _first_lane():
@@ -247,44 +302,25 @@ def _dma_kernel(layer_ref, bt_ref, kv_len_ref, q_ref, *rest,
         slot_ref[0] = 0
 
     kv_len = kv_len_ref[b]
-    first, n_pages = span(b)
-    n_blocks = (n_pages + nps - 1) // nps
-    slot0 = slot_ref[0]
-    more_lanes = b + 1 < pl.num_programs(0)
     q = q_ref[0]                                           # [Hq, D]
 
-    # A lane's first block is started by the lane before it, during its
-    # last block or, if that lane is idle, here; lane 0 starts its own.
-    @pl.when(((b == 0) & (n_blocks > 0)) | ((n_blocks == 0) & more_lanes))
-    def _first_block():
-        block_dmas(jnp.where(n_blocks == 0, b + 1, b), 0, slot0)
-
-    def block(j, carry):
-        slot = (slot0 + j) % 2
-        last_block = j + 1 == n_blocks
-
-        # The next block, or the next lane's first, flies while this one
-        # is computed.
-        @pl.when(~last_block | more_lanes)
-        def _next_block():
-            block_dmas(jnp.where(last_block, b + 1, b),
-                       jnp.where(last_block, 0, j + 1), 1 - slot)
-
-        block_dmas(b, j, slot, wait=True)
+    def fold(carry, slot, first_page):
         return _attend(
             carry, q, _codes(k_buf[slot], packed, q.dtype),
             _codes(v_buf[slot], packed, q.dtype),
             _lanes(ks_buf[slot, n] for n in range(nps)) if quantized else None,
             _lanes(vs_buf[slot, n] for n in range(nps)) if quantized else None,
-            start=(first + j * nps) * page_size, kv_len=kv_len,
+            start=first_page * page_size, kv_len=kv_len,
             sliding_window=sliding_window, **attend)
 
-    _, l, acc = jax.lax.fori_loop(
-        0, n_blocks, block,
+    _, l, acc = _walk_blocks(
+        span, _page_copies(layer_ref[0], bt_ref, ((k_hbm, k_buf),
+                                                  (v_hbm, v_buf)),
+                           scales, sem),
+        slot_ref, nps, fold,
         (jnp.full((q.shape[0], 1), NEG_INF, jnp.float32),
          jnp.zeros((q.shape[0], 1), jnp.float32),
          jnp.zeros(q.shape, jnp.float32)))
-    slot_ref[0] = (slot0 + n_blocks) % 2
     # A lane that read nothing (kv_len 0) gives 0, not NaN.
     out_ref[0] = (acc / jnp.maximum(l, 1e-20)).astype(out_ref.dtype)
 
