@@ -675,6 +675,19 @@ def _compare(got: list, ref, prompts: list, tol: dict) -> dict:
     return res
 
 
+def _attn_error(got, want) -> float:
+    """Worst |got - want| of any query, as a share of that query's own
+    output spread (over heads x dims): queries that attend to 10 tokens
+    and to 4000 differ 20x in output scale."""
+    import numpy as np
+
+    check(str(got.dtype) == "float32", f"kernel output is {got.dtype}")
+    got, want = np.asarray(got), np.asarray(want)
+    check(bool(np.isfinite(got).all()), "non-finite kernel output")
+    spread = np.std(want, axis=(-2, -1), keepdims=True)
+    return float(np.max(np.abs(got - want) / spread))
+
+
 def _kernel_errors(cfg: dict, mcfg, interpret: bool = False) -> dict:
     """Both Pallas kernels against dense float32 attention on the same
     random bf16 pool: the model's heads, window and 16-token pages;
@@ -715,16 +728,7 @@ def _kernel_errors(cfg: dict, mcfg, interpret: bool = False) -> dict:
     k_all, v_all = kvc.gather_kv(kvc.KVPages(k=k_pool, v=v_pool), layer,
                                  tables)
 
-    def err(got, want):
-        """Worst |got - want| of any query, as a share of that query's
-        own output spread (over heads x dims): queries that attend to 10
-        tokens and to 4000 differ 20x in output scale."""
-        check(got.dtype == jnp.float32, f"kernel output is {got.dtype}")
-        got, want = np.asarray(got), np.asarray(want)
-        check(bool(np.isfinite(got).all()), "non-finite kernel output")
-        spread = np.std(want, axis=(-2, -1), keepdims=True)
-        return float(np.max(np.abs(got - want) / spread))
-
+    err = _attn_error
     out = {}
     with jax.default_matmul_precision("highest"):
         # bf16 values held in f32: the kernels return q's dtype, so the
@@ -773,6 +777,67 @@ def _kernel_errors(cfg: dict, mcfg, interpret: bool = False) -> dict:
             q_offset=jnp.asarray(q_off), kv_len=jnp.asarray(lens),
             sliding_window=win)
         out["prefill_doc_chunk"] = err(got, want)
+    return {k: round(v, 5) for k, v in out.items()}
+
+
+def _mha_kernel_errors(cfg: dict, *, heads: int = 16, d: int = 128,
+                       slots: int = 16, lanes: int = 12, ctx: int = 330,
+                       rows: int = 384, interpret: bool = False) -> dict:
+    """The two GQA kernels at an MHA shape (Ouro-2.6B's: 16 KV heads, one
+    query head each, head size 128) against dense float32 attention: a
+    stacked pool of ``slots`` (pass, layer) slots read at a slot past
+    the first pass; decode at ``lanes`` lanes of about ``ctx`` tokens, one
+    of them idle; a prefill chunk of ``rows`` rows at offset 0 and behind
+    a cached prefix that ends inside a page block."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tpu_inference.engine import kv_cache as kvc
+    from tpu_inference.kernels.paged_attention import paged_attention
+    from tpu_inference.kernels.prefill_attention import (
+        paged_prefill_attention)
+    from tpu_inference.models.common import dense_causal_attention
+
+    page = 16
+    q_off = np.array([0, ctx - 7], np.int32)
+    mp = -(-(int(q_off[1]) + rows) // page)
+    slot = slots // 2 + 1
+    kv_lens = np.array([ctx + 5 * i for i in range(lanes)], np.int32)
+    kv_lens[lanes // 2] = 0
+    live = kv_lens > 0
+    key = jax.random.split(jax.random.PRNGKey(cfg["seed"] + 1), 4)
+    pool_shape = (slots, lanes * mp + 1, page, heads, d)
+    k_pool = jax.random.normal(key[0], pool_shape, jnp.bfloat16)
+    v_pool = jax.random.normal(key[1], pool_shape, jnp.bfloat16)
+    tables = jnp.asarray(1 + np.arange(lanes * mp,
+                                       dtype=np.int32).reshape(lanes, mp))
+    k_all, v_all = kvc.gather_kv(kvc.KVPages(k=k_pool, v=v_pool), slot,
+                                 tables)
+
+    err = _attn_error
+    out = {}
+    with jax.default_matmul_precision("highest"):
+        q = jax.random.normal(key[2], (lanes, heads, d),
+                              jnp.bfloat16).astype(jnp.float32)
+        got = paged_attention(q, k_pool, v_pool, slot, tables,
+                              jnp.asarray(kv_lens), interpret=interpret)
+        seen = jnp.asarray(np.maximum(kv_lens, 1))
+        want = dense_causal_attention(q[:, None], k_all, v_all,
+                                      q_offset=seen - 1, kv_len=seen)[:, 0]
+        check(not np.asarray(got)[~live].any(),
+              "an idle lane's rows are not 0")
+        out["mha_decode"] = err(got[live], want[live])
+        lens = q_off + rows
+        qp = jax.random.normal(key[3], (2, rows, heads, d),
+                               jnp.bfloat16).astype(jnp.float32)
+        got = paged_prefill_attention(
+            qp, k_pool, v_pool, slot, tables[:2], jnp.asarray(lens),
+            jnp.asarray(q_off), interpret=interpret)
+        want = dense_causal_attention(
+            qp, k_all[:2], v_all[:2], q_offset=jnp.asarray(q_off),
+            kv_len=jnp.asarray(lens))
+        out["mha_prefill"] = err(got, want)
     return {k: round(v, 5) for k, v in out.items()}
 
 
@@ -965,6 +1030,9 @@ def child_parity(cfg: dict) -> dict:
         res["kernel_tol"] = cfg["kernel_tol"]
         check(max(res["kernel_err"].values()) <= cfg["kernel_tol"],
               f"Pallas kernel vs dense float32 attention: {res}")
+        res["mha_kernel_err"] = _mha_kernel_errors(cfg)
+        check(max(res["mha_kernel_err"].values()) <= cfg["kernel_tol"],
+              f"Pallas kernels at the MHA shape vs dense float32: {res}")
         res["latent_kernel_err"] = _latent_kernel_errors(cfg)
         check(max(res["latent_kernel_err"].values())
               <= cfg["latent_kernel_tol"],

@@ -188,6 +188,18 @@ def test_latent_kernel_check_in_interpret_mode():
     assert max(errs.values()) <= chip_smoke.SETTINGS["latent_kernel_tol"]
 
 
+def test_mha_kernel_check_in_interpret_mode():
+    """The smoke's check of the two GQA kernels at an MHA shape (run on
+    the chip at Ouro-2.6B's: 16 heads x 128, a 16-slot pool, 12 lanes of
+    ~330 tokens, 384 prefill rows) at a small shape through the
+    interpreter: the same code, within the kernels' tolerance."""
+    errs = chip_smoke._mha_kernel_errors(
+        TINY, heads=4, d=32, slots=6, lanes=5, ctx=70, rows=128,
+        interpret=True)
+    assert set(errs) == {"mha_decode", "mha_prefill"}
+    assert max(errs.values()) <= chip_smoke.SETTINGS["kernel_tol"]
+
+
 def test_routed_expert_check_in_interpret_mode():
     """The smoke's routed-expert check (run on the chip at Kimi-K2's
     widths, layer 1 of a 2-layer stack) at the tiny preset through the

@@ -33,14 +33,18 @@ def _equations(jaxpr):
             yield from _equations(sub)
 
 
-@pytest.mark.parametrize("kv_quant", ["none", "int8"])
-def test_no_step_program_slices_a_layer_of_the_pool(kv_quant):
+@pytest.mark.parametrize("preset,kv_quant", [
+    ("tiny-llama", "none"), ("tiny-llama", "int8"),
+    # A looped stack: the pool's leading dim is pass x layer slots and the
+    # kernels are handed slot pass * L + l, still as an operand.
+    ("tiny-ouro", "none")])
+def test_no_step_program_slices_a_layer_of_the_pool(preset, kv_quant):
     ecfg = cfgs.EngineConfig(
         page_size=8, num_pages=48, max_pages_per_seq=6, max_batch_size=4,
         prefill_buckets=(16, 32), decode_steps_per_call=4,
         hybrid_prefill=True, kv_quant=kv_quant, attn_backend="pallas")
-    eng = InferenceEngine(cfgs.tiny_llama(vocab_size=256), ecfg, seed=0,
-                          pallas_interpret=True)
+    eng = InferenceEngine(cfgs.PRESETS[preset](vocab_size=256), ecfg,
+                          seed=0, pallas_interpret=True)
     # One layer of the code pool, with and without the unit layer dim a
     # dynamic_slice leaves. (A quantized pool's SCALES are sliced per
     # layer on purpose, 1% of the bytes: engine.make_paged_attn.)

@@ -249,6 +249,8 @@ def test_aux_routes(server):
         assert show["details"]["family"] == "llama"
         info = show["model_info"]
         assert info["llama.context_length"] > 0
+        # The pass count beside the block count (1 = not a looped stack).
+        assert info["llama.block_count"] == 2 and info["llama.loop_steps"] == 1
         assert info["general.parameter_count"] > 0
         # SWA composition rules surface here (full-attention model:
         # window 0, no eviction, prefix cache on).

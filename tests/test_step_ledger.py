@@ -444,14 +444,15 @@ def test_new_step_fields_are_appended_and_old_indexes_hold():
            "device_s", "staging_s", "bubble_s", "kv_read_tokens",
            "kv_swap_bytes", "spec_accepted", "compile_event")
     assert STEP_FIELDS[:len(old)] == old
-    assert STEP_FIELDS[len(old):] == ("seq", "t_enqueue", "t_done")
+    assert STEP_FIELDS[len(old):] == ("seq", "t_enqueue", "t_done",
+                                      "layer_passes")
     led = StepLedger(depth=8)
     # A caller that knows nothing of the new fields still pushes.
     led.push("decode", 4, 2, 7, 0, 8, 0.25, 0.01, 0.02, 99, 0.0, 0, False)
     r = led.records()[0]
     assert len(r) == len(STEP_FIELDS)
     assert (r[1], r[4], r[7], r[10], r[13]) == ("decode", 7, 0.25, 99, 0)
-    assert r[14:] == (0, 0.0, 0.0)
+    assert r[14:] == (0, 0.0, 0.0, 0)
 
 
 def test_settle_fills_t_done_and_true_device_s():

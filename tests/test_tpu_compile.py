@@ -44,6 +44,9 @@ HEADS = {
     "mistral-7b": (32, 8, 128, 4096, 320, 18, (1, 1024)),
     "phi-3-mini": (32, 32, 96, 2047, 256, 16, (1, 1024)),
     "qwen2-7b": (28, 4, 128, 0, 192, 32, (4, 512)),
+    # MHA, one query head a KV head (a looped stack's pool has 192 slots
+    # for LAYERS; the kernels address one of them either way).
+    "ouro-2.6b": (16, 16, 128, 0, 52, 12, (4, 512)),
 }
 
 

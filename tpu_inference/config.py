@@ -57,13 +57,15 @@ class ModelConfig:
     """Architecture hyperparameters for a decoder-only transformer.
 
     Covers Llama-style (RMSNorm/RoPE/GQA/SwiGLU), Mixtral (adds MoE fields)
-    GPT-2 (LayerNorm/learned-positional/GELU) and DeepSeek-V3 / Kimi-K2
+    GPT-2 (LayerNorm/learned-positional/GELU), DeepSeek-V3 / Kimi-K2
     (latent attention, sigmoid-routed experts beside a shared one, a
-    chip's share of an expert-parallel deployment) families.
+    chip's share of an expert-parallel deployment) and Ouro (a looped
+    stack: the layers run ``loop_steps`` times a token) families.
     """
 
     name: str = "llama"
-    family: str = "llama"  # "llama" | "mixtral" | "gpt2" | "deepseek_v3"
+    # "llama" | "mixtral" | "gpt2" | "deepseek_v3" | "ouro"
+    family: str = "llama"
     vocab_size: int = 32000
     d_model: int = 4096
     n_layers: int = 32
@@ -137,6 +139,20 @@ class ModelConfig:
     # that lands on a held expert is dropped.
     ep_size: int = 1
     ep_rank: int = 0
+    # --- family "ouro" (a looped stack, models/ouro.py) ---
+    # The n_layers layers run loop_steps times a token with the SAME
+    # weights; the final norm closes every pass and its output is the
+    # next pass's input. Each (pass, layer) keeps K / V of its own:
+    # n_kv_slots below, derived and never stored.
+    loop_steps: int = 1
+    # A second RMSNorm on each branch's OUTPUT (x + norm(attn(norm(x))),
+    # the same around the FFN): four norms a layer.
+    sandwich_norm: bool = False
+    # A token leaves at the first pass whose cumulative exit probability
+    # reaches this (models/ouro.exit_probabilities). 1.0, as published,
+    # is the last pass for every token; per-token depth is not served
+    # (the engine refuses < 1).
+    early_exit_threshold: float = 1.0
     dtype: jnp.dtype = jnp.bfloat16
 
     @property
@@ -148,6 +164,12 @@ class ModelConfig:
         """Width of one latent cache entry (0 = K/V per kv head)."""
         return (self.kv_lora_rank + self.qk_rope_head_dim
                 if self.kv_lora_rank else 0)
+
+    @property
+    def n_kv_slots(self) -> int:
+        """Leading dim of the KV pool: one slot per (pass, layer), pass
+        major (slot = pass * n_layers + layer); n_layers unlooped."""
+        return self.n_layers * self.loop_steps
 
     @property
     def n_local_experts(self) -> int:
@@ -165,6 +187,10 @@ class ModelConfig:
         assert self.n_heads % self.n_kv_heads == 0
         if self.n_experts:
             assert self.n_experts_per_tok <= self.n_experts
+        assert self.loop_steps >= 1 and 0.0 <= self.early_exit_threshold <= 1.0
+        # Only the looped family's forward runs passes / output norms.
+        assert self.family == "ouro" or (self.loop_steps == 1
+                                         and not self.sandwich_norm)
         if self.family == "deepseek_v3":
             assert self.kv_lora_rank and self.qk_rope_head_dim % 2 == 0
             assert 0 <= self.first_k_dense <= self.n_layers
@@ -289,6 +315,21 @@ def kimi_k2_ep32() -> ModelConfig:
     )
 
 
+def ouro_2_6b() -> ModelConfig:
+    """Ouro-2.6B (ByteDance), a looped LM, at every published size: 48
+    layers run 4 times a token with shared weights, MHA 16 x 128, four
+    RMSNorms a layer, the final norm after every pass; 192 KV slots
+    (1.5 MiB of bf16 K / V a token).
+    bench/configs/ouro-2.6b-bf16.json lists what is assumed."""
+    return ModelConfig(
+        name="ouro-2.6b", family="ouro", vocab_size=49152, d_model=2048,
+        n_layers=48, n_heads=16, n_kv_heads=16, d_ff=5632,
+        max_seq_len=65536, rope_theta=1000000.0, norm_eps=1e-6,
+        head_dim_override=128, loop_steps=4, sandwich_norm=True,
+        early_exit_threshold=1.0,
+    )
+
+
 def gpt2_small() -> ModelConfig:
     return ModelConfig(
         name="gpt2", family="gpt2", vocab_size=50257, d_model=768,
@@ -394,6 +435,17 @@ def tiny_gpt2(vocab_size: int = 512) -> ModelConfig:
     )
 
 
+def tiny_ouro(vocab_size: int = 512) -> ModelConfig:
+    """The Ouro structure at test widths: 2 layers x 3 passes (6 KV
+    slots), MHA, sandwich norms, an exit gate."""
+    return ModelConfig(
+        name="tiny-ouro", family="ouro", vocab_size=vocab_size, d_model=128,
+        n_layers=2, n_heads=4, n_kv_heads=4, d_ff=256, max_seq_len=1024,
+        rope_theta=10000.0, norm_eps=1e-6, loop_steps=3,
+        sandwich_norm=True, dtype=jnp.float32,
+    )
+
+
 PRESETS = {
     "llama-3-8b": llama3_8b,
     "llama-3.1-8b": llama31_8b,
@@ -405,6 +457,7 @@ PRESETS = {
     "phi-3-mini": phi3_mini,
     "gpt2": gpt2_small,
     "kimi-k2-ep32": kimi_k2_ep32,
+    "ouro-2.6b": ouro_2_6b,
     "tiny-llama": tiny_llama,
     "tiny-llama-fatkv": tiny_llama_fatkv,
     "tiny-qwen2": tiny_qwen2,
@@ -414,6 +467,7 @@ PRESETS = {
     "tiny-phi3": tiny_phi3,
     "tiny-gpt2": tiny_gpt2,
     "tiny-kimi": tiny_kimi,
+    "tiny-ouro": tiny_ouro,
 }
 
 

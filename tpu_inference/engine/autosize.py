@@ -135,13 +135,26 @@ def weight_bytes(model_cfg, quant: str = "none") -> int:
     return n * itemsize
 
 
+def weight_read_bytes(model_cfg, quant: str = "none") -> int:
+    """Weight bytes ONE decode step reads: the resident bytes, plus the
+    looped layers once more for every pass after the first (the
+    embedding and head are read once)."""
+    wb = weight_bytes(model_cfg, quant)
+    if model_cfg.loop_steps == 1:
+        return wb
+    d, V = model_cfg.d_model, model_cfg.vocab_size
+    embed = V * d * (1 if model_cfg.tie_embeddings else 2) * 2
+    return wb + (model_cfg.loop_steps - 1) * (wb - embed)
+
+
 def kv_bytes_per_token(model_cfg, kv_quant: str = "none") -> int:
-    """Pool bytes one token occupies across all layers (K and V).
+    """Pool bytes one token occupies across all KV slots (K and V; L =
+    layers, times the passes of a looped stack).
 
     bf16: 2 * L * Hkv * D * 2; int8: codes (1 byte) + a per-(token,
     kv-head) f32 scale; int4: nibble-packed codes (D/2 bytes) + the
     same f32 scale — engine/kv_cache.py layouts."""
-    L = model_cfg.n_layers
+    L = model_cfg.n_kv_slots      # one per (pass, layer) of a looped stack
     if model_cfg.latent_dim:
         # One latent entry per token per layer for all heads, at the
         # pool's stored (lane-padded) width; never quantized.
@@ -263,9 +276,11 @@ def auto_host_cache_pages(model_cfg, *, kv_quant: str = "none",
     the Python heap, and tokenizer/weight staging out of the tier's
     budget; 0 when the machine has no headroom (the tier then simply
     stays off rather than inviting the OOM killer). 0 too for a latent
-    (MLA) pool: the tier's page copies assume K and V pools, so 'auto'
-    leaves it off there and an explicit size is refused by the engine."""
-    if model_cfg.latent_dim:
+    (MLA) pool (the tier's page copies assume K and V pools) and for a
+    looped stack (a page of pass x layer slots is tens of MiB to copy
+    out at every eviction; untested): 'auto' leaves it off there and an
+    explicit size is refused by the engine."""
+    if model_cfg.latent_dim or model_cfg.loop_steps > 1:
         return 0
     avail = (detect_host_ram_bytes() if host_ram_bytes is None
              else int(host_ram_bytes))

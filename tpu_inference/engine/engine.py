@@ -239,34 +239,55 @@ def make_paged_attn(cfg: ModelConfig, page_size: int, block_tables: jax.Array,
     return attn
 
 
-def _refuse_unsupported_latent(model_cfg: ModelConfig,
-                               engine_cfg: EngineConfig, mesh,
-                               draft_cfg) -> None:
-    """What the latent-attention / routed-expert family does not run
-    yet, said at construction and not at the first request."""
+# Why each is refused: (for a latent pool, for a looped stack).
+_WHY_NOT = {
+    "tp": ("no param shardings, no sharded latent pool, no expert "
+           "exchange: parallel/shardings.py",
+           "no param shardings for the output norms and the exit gate: "
+           "parallel/shardings.py"),
+    "kv_quant": ("the latent pool is stored in the model dtype",
+                 "no test holds a quantized pool of pass x layer slots to "
+                 "the reference"),
+    "host": ("offload / restore / serialize assume K and V pools",
+             "a page of pass x layer slots is tens of MiB to copy out at "
+             "every eviction, untested"),
+    "role": ("P/D handoff serializes K and V pages",
+             "P/D handoff of pages of pass x layer slots is untested"),
+}
+
+
+def _refuse_unsupported(model_cfg: ModelConfig, engine_cfg: EngineConfig,
+                        mesh, draft_cfg) -> None:
+    """What a latent pool (the latent-attention / routed-expert family)
+    or a looped stack (``loop_steps`` > 1) does not run yet, said at
+    construction and not at the first request."""
+    latent, looped = bool(model_cfg.latent_dim), model_cfg.loop_steps > 1
+    if not (latent or looped):
+        return
+    why = {k: v[0 if latent else 1] for k, v in _WHY_NOT.items()}
     what = []
+    if model_cfg.early_exit_threshold < 1.0:
+        what.append(f"early_exit_threshold={model_cfg.early_exit_threshold}"
+                    " < 1 (per-token depth: every token runs every pass)")
     if mesh is not None and any(int(mesh.shape.get(ax, 1)) > 1
                                 for ax in ("tp", "sp")):
-        what.append("tp / sp > 1 (no param shardings, no sharded latent "
-                    "pool, no expert exchange: parallel/shardings.py)")
+        what.append(f"tp / sp > 1 ({why['tp']})")
     if engine_cfg.kv_quant != "none":
-        what.append(f"kv_quant={engine_cfg.kv_quant!r} (the latent pool "
-                    "is stored in the model dtype)")
+        what.append(f"kv_quant={engine_cfg.kv_quant!r} ({why['kv_quant']})")
     if (engine_cfg.num_speculative_tokens > 0 or draft_cfg is not None
             or engine_cfg.spec_mode != "draft"):
         what.append("speculative decoding (draft or ngram)")
     if engine_cfg.host_cache_pages:
-        what.append("the host KV tier (host_cache_pages > 0: offload / "
-                    "restore / serialize assume K and V pools)")
-    if engine_cfg.quant == "int4":
+        what.append(f"the host KV tier (host_cache_pages > 0: {why['host']})")
+    if latent and engine_cfg.quant == "int4":
         what.append("quant='int4' (the grouped expert kernels take bf16 "
                     "or int8 weights)")
     if engine_cfg.role != "mixed":
-        what.append(f"role={engine_cfg.role!r} (P/D handoff serializes K "
-                    "and V pages)")
+        what.append(f"role={engine_cfg.role!r} ({why['role']})")
     if what:
-        raise ValueError(f"{model_cfg.name} (latent attention) does not "
-                         "support: " + "; ".join(what))
+        kind = "latent attention" if latent else "a looped stack"
+        raise ValueError(f"{model_cfg.name} ({kind}) does not support: "
+                         + "; ".join(what))
 
 
 def _extend(result: Dict[int, List[int]], more: Dict[int, List[int]]
@@ -440,9 +461,7 @@ class InferenceEngine:
         t_boot = time.perf_counter()
         self.model_cfg = model_cfg
         self.engine_cfg = engine_cfg
-        if model_cfg.latent_dim:
-            _refuse_unsupported_latent(model_cfg, engine_cfg, mesh,
-                                       draft_cfg)
+        _refuse_unsupported(model_cfg, engine_cfg, mesh, draft_cfg)
         self.mod = get_model_fns(model_cfg)
         # Resolve the attention backend: constructor arg wins, then
         # EngineConfig; "auto" = the Pallas paged kernels on a TPU, the
@@ -2699,7 +2718,8 @@ class InferenceEngine:
             staging_s, bubble_s, kv_read, swap, spec_accepted,
             compile_event, seq=seq,
             t_enqueue=to_unix(t_enqueue) if t_enqueue else 0.0,
-            t_done=to_unix(t_done) if t_done else 0.0)
+            t_done=to_unix(t_done) if t_done else 0.0,
+            layer_passes=max(1, steps) * self.model_cfg.n_kv_slots)
 
     def _compact_slots(self) -> None:
         """Step-down helper: relocate bound sequences out of high slots

@@ -110,8 +110,8 @@ def alloc_latent_pages(model_cfg: ModelConfig, engine_cfg: EngineConfig,
         raise ValueError(
             f"{model_cfg.name}: kv_quant={engine_cfg.kv_quant!r} is not "
             "implemented for a latent (MLA) pool; use kv_quant='none'")
-    shape = (model_cfg.n_layers, engine_cfg.num_pages, engine_cfg.page_size,
-             latent_width(model_cfg))
+    shape = (model_cfg.n_kv_slots, engine_cfg.num_pages,
+             engine_cfg.page_size, latent_width(model_cfg))
     pool = jax.jit(lambda: jnp.zeros(shape, dtype or model_cfg.dtype))()
     return KVPages(k=pool, v=None, aux=jnp.zeros(
         (n_aux(model_cfg),), jnp.int32) if n_aux else None)
@@ -136,8 +136,8 @@ def alloc_kv_pages(model_cfg: ModelConfig, engine_cfg: EngineConfig,
     if model_cfg.latent_dim:
         assert sharding is None, "a latent pool is not sharded"
         return alloc_latent_pages(model_cfg, engine_cfg, dtype)
-    shape = (model_cfg.n_layers, engine_cfg.num_pages, engine_cfg.page_size,
-             model_cfg.n_kv_heads, model_cfg.head_dim)
+    shape = (model_cfg.n_kv_slots, engine_cfg.num_pages,
+             engine_cfg.page_size, model_cfg.n_kv_heads, model_cfg.head_dim)
     dtype = dtype or model_cfg.dtype
     if engine_cfg.kv_quant not in ("none", "int8", "int4"):
         raise ValueError(f"unknown kv_quant mode {engine_cfg.kv_quant!r}; "

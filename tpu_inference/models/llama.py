@@ -78,7 +78,10 @@ def decoder_block(cfg: ModelConfig, layer_idx: jax.Array, lp: dict,
                   x: jax.Array, positions: jax.Array, kv: Any,
                   attn: AttentionFn):
     """One transformer block. x: [B, S, D]. Public: parallel/pipeline.py
-    runs per-stage layer slabs through it."""
+    runs per-stage layer slabs through it, models/ouro.py runs it once a
+    (pass, layer) with ``layer_idx`` the KV slot. With
+    ``cfg.sandwich_norm`` each branch's output passes a norm of its own
+    (``attn_out_norm`` / ``ffn_out_norm``) before the residual add."""
     b, s, d = x.shape
     hd = cfg.head_dim
 
@@ -97,12 +100,17 @@ def decoder_block(cfg: ModelConfig, layer_idx: jax.Array, lp: dict,
 
     attn_out, kv = attn(layer_idx, q, k, v, kv)
     attn_out = attn_out.reshape(b, s, cfg.n_heads * hd)
-    x = x + qdot(attn_out, lp["wo"]).astype(x.dtype)
+    a = qdot(attn_out, lp["wo"]).astype(x.dtype)
+    if cfg.sandwich_norm:
+        a = rms_norm(a, lp["attn_out_norm"], cfg.norm_eps, cfg.norm_offset)
+    x = x + a
 
     h = rms_norm(x, lp["ffn_norm"], cfg.norm_eps, cfg.norm_offset)
-    x = x + swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"],
-                   act=cfg.hidden_act)
-    return x, kv
+    m = swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"],
+               act=cfg.hidden_act)
+    if cfg.sandwich_norm:
+        m = rms_norm(m, lp["ffn_out_norm"], cfg.norm_eps, cfg.norm_offset)
+    return x + m, kv
 
 
 def embed_tokens(params: dict, cfg: ModelConfig,

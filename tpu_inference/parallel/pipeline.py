@@ -57,6 +57,10 @@ def pp_forward(params: dict, cfg: ModelConfig, tokens: jax.Array,
     fills the pipe). Total steps = n_micro + pp - 1.
     """
     pp = mesh.shape["pp"]
+    if cfg.loop_steps > 1:
+        raise ValueError(
+            f"pp_forward runs each stage's layer slab once: a looped stack "
+            f"({cfg.name}, loop_steps={cfg.loop_steps}) is not supported")
     if cfg.family != "llama":
         raise ValueError(
             f"pp_forward supports the llama family (got {cfg.family!r}); "

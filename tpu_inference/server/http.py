@@ -350,6 +350,8 @@ class InferenceServer:
                 f"{mc.family}.context_length": ec.max_context,
                 f"{mc.family}.embedding_length": mc.d_model,
                 f"{mc.family}.block_count": mc.n_layers,
+                # Passes of the stack a token runs (a looped model).
+                f"{mc.family}.loop_steps": mc.loop_steps,
                 f"{mc.family}.attention.head_count": mc.n_heads,
                 f"{mc.family}.attention.head_count_kv": mc.n_kv_heads,
                 f"{mc.family}.vocab_size": mc.vocab_size,

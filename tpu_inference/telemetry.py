@@ -1198,6 +1198,9 @@ STEP_FIELDS = (
     "t_done",         # unix: the host first observed its result (a
                       # readback of it or of a later program); 0 = not
                       # yet observed
+    "layer_passes",   # layer applications the dispatch ran: steps x KV
+                      # slots (layers, times the passes of a looped
+                      # stack: each reads the layer's weights once)
 )
 _I_DEVICE_S = STEP_FIELDS.index("device_s")
 _I_SEQ = STEP_FIELDS.index("seq")
@@ -1224,14 +1227,14 @@ class StepLedger:
              staging_s: float, bubble_s: float, kv_read_tokens: int,
              kv_swap_bytes: float, spec_accepted: int,
              compile_event: bool, seq: int = 0, t_enqueue: float = 0.0,
-             t_done: float = 0.0) -> None:
+             t_done: float = 0.0, layer_passes: int = 0) -> None:
         self._ring[self._n % self.depth] = (
             time.time(), kind, int(rung), int(slots), int(tokens),
             int(chunk_tokens), int(steps), float(device_s),
             float(staging_s), float(bubble_s), int(kv_read_tokens),
             float(kv_swap_bytes), int(spec_accepted),
             1 if compile_event else 0, int(seq), float(t_enqueue),
-            float(t_done))
+            float(t_done), int(layer_passes))
         self._n += 1
 
     def settle(self, seq: int, t_done: float) -> bool:
@@ -1248,7 +1251,7 @@ class StepLedger:
                 self._ring[i] = (r[:_I_DEVICE_S]
                                  + (max(0.0, t_done - r[_I_SEQ + 1]),)
                                  + r[_I_DEVICE_S + 1:_I_SEQ + 2]
-                                 + (float(t_done),))
+                                 + (float(t_done),) + r[_I_SEQ + 3:])
                 return True
         return False
 
@@ -1310,11 +1313,12 @@ class StepCostModel:
       tokens + prompt chunk tokens).
     - attention FLOPs: 4 x n_heads x head_dim per layer per (query
       position, context token) pair (QK^T + AV, 2 multiply-adds each).
-    - HBM bytes: resident weight bytes once per device loop iteration
-      (fused-K decode streams the weights K times) + KV bytes for every
-      context token attended (at the active kv_quant's per-token
-      footprint) + KV bytes written for new positions + host<->device
-      swap traffic.
+    - HBM bytes: the weight bytes a step READS once per device loop
+      iteration (fused-K decode streams the weights K times; a looped
+      stack reads its layers once a pass, so more than it stores) + KV
+      bytes for every context token attended (at the active kv_quant's
+      per-token footprint) + KV bytes written for new positions +
+      host<->device swap traffic.
     """
 
     __slots__ = ("n_params", "n_layers", "n_heads", "head_dim",
@@ -1326,7 +1330,10 @@ class StepCostModel:
                  peak_flops: Optional[float],
                  peak_hbm_bw: Optional[float]):
         # ``n_params``: parameters a token position multiplies through
-        # (all of a dense model's; a routed model's ACTIVE ones).
+        # (all of a dense model's; a routed model's ACTIVE ones; a
+        # looped stack's layers once a pass). ``n_layers``: attention
+        # applications a token (layers x passes). ``weight_bytes``: what
+        # one step reads.
         # ``head_dim``: an attention pair costs 4 x n_heads x head_dim
         # FLOPs (latent attention: (latent entry + latent rank) / 2).
         self.n_params = int(n_params)
@@ -1351,9 +1358,9 @@ class StepCostModel:
         n_params = own(mcfg, True) if own else engine.n_params
         pair = family_fn(mcfg, "attn_pair_dim")
         head_dim = pair(mcfg) if pair else mcfg.head_dim
-        return cls(n_params=n_params, n_layers=mcfg.n_layers,
+        return cls(n_params=n_params, n_layers=mcfg.n_kv_slots,
                    n_heads=mcfg.n_heads, head_dim=head_dim,
-                   weight_bytes=autosize.weight_bytes(mcfg, ecfg.quant),
+                   weight_bytes=autosize.weight_read_bytes(mcfg, ecfg.quant),
                    kv_token_bytes=autosize.kv_bytes_per_token(
                        mcfg, ecfg.kv_quant),
                    peak_flops=chip and chip.peak_bf16_flops,
@@ -2413,6 +2420,17 @@ class EngineTelemetry:
                   fn=lambda: engine.migrate_in_bytes)
         r.gauge("tpu_inf_model_params", "Model parameter count",
                 fn=lambda: engine.n_params)
+        mcfg = engine.model_cfg
+        r.gauge("tpu_inf_model_loop_steps",
+                "Passes of the layer stack a token runs (1 = unlooped)",
+                fn=lambda: mcfg.loop_steps)
+        r.gauge("tpu_inf_kv_layer_slots",
+                "Leading dim of the KV pool: layers x passes",
+                fn=lambda: mcfg.n_kv_slots)
+        kv_token_bytes = self.cost_model.kv_token_bytes
+        r.gauge("tpu_inf_kv_bytes_per_token",
+                "KV pool bytes one token occupies over all slots",
+                fn=lambda: kv_token_bytes)
         r.gauge("tpu_inf_active_sequences", "Bound decode slots",
                 fn=lambda: sum(s is not None for s in engine.slots))
         # Batch ladder (README "Batch ladder"): which compiled decode
@@ -2591,6 +2609,11 @@ class EngineTelemetry:
         if chip is None:
             return
         peak = chip.peak_bf16_flops
+        # A looped stack multiplies a token through its layers once a
+        # pass: more parameters than it stores.
+        flop_params = (self.cost_model.n_params
+                       if engine.model_cfg.loop_steps > 1
+                       else engine.n_params)
         tau_s = 30.0
         state = {"tokens": stats.tokens_generated,
                  "t": time.perf_counter(), "rate": 0.0}
@@ -2604,7 +2627,7 @@ class EngineTelemetry:
                 alpha = 1.0 - math.exp(-dt / tau_s)
                 state["rate"] += alpha * (inst - state["rate"])
                 state["tokens"], state["t"] = tok, now
-            return state["rate"] * 2 * engine.n_params / peak
+            return state["rate"] * 2 * flop_params / peak
 
         self._mfu_gauge = r.gauge(
             "tpu_inf_mfu_estimate",
