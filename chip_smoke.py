@@ -842,13 +842,17 @@ def _mha_kernel_errors(cfg: dict, *, heads: int = 16, d: int = 128,
 
 
 def _latent_kernel_errors(cfg: dict, *, heads: int = 64, rank: int = 512,
-                          rope: int = 64, ctx=(100, 5000, 10000),
+                          rope: int = 64,
+                          ctx=(100, 0, 5000, 8200, 0, 0, 10000, 3333),
                           chunk: int = 512, interpret: bool = False) -> dict:
     """mla_decode_attention / mla_prefill_attention against the dense
     float32 form (mla_attention_dense) on the same random bf16 latent
     pool, at layer 1 of a 3-layer stacked pool: Kimi-K2's 64 heads over
-    one 512 + 64 entry (stored 640 wide), contexts short to 10k, and a
-    chunk behind a cached prefix of ctx[1] tokens."""
+    one 512 + 64 entry (stored 640 wide), pages scattered, lanes of
+    uneven contexts short to 10k with idle lanes (ctx 0: they must come
+    back 0) among them, and a batch of chunks: one at offset 0, one row
+    without a sequence, one behind a cached prefix of the second live
+    lane's tokens."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -861,8 +865,11 @@ def _latent_kernel_errors(cfg: dict, *, heads: int = 64, rank: int = 512,
     key = jax.random.split(jax.random.PRNGKey(cfg["seed"]), 3)
     pool = jax.random.normal(key[0], (3, b * mp + 1, page, width),
                              jnp.bfloat16)
-    tables = jnp.asarray(1 + np.arange(b * mp, dtype=np.int32).reshape(b, mp))
+    tables = jnp.asarray(1 + np.random.RandomState(cfg["seed"] % 2**31)
+                         .permutation(b * mp).astype(np.int32)
+                         .reshape(b, mp))
     scale, layer = 0.13, 1
+    live = np.asarray(ctx) > 0
 
     def err(got, want):
         got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
@@ -875,22 +882,28 @@ def _latent_kernel_errors(cfg: dict, *, heads: int = 64, rank: int = 512,
         lens = jnp.asarray(ctx, jnp.int32)
         q = jax.random.normal(key[1], (b, heads, rank + rope),
                               jnp.bfloat16).astype(jnp.float32)
-        got = mla.mla_decode_attention(q, pool, layer, tables, lens,
-                                       rank=rank, scale=scale,
-                                       interpret=interpret)
-        want = mla.mla_attention_dense(q[:, None], pool, layer, tables, lens,
-                                       lens - 1, rank=rank, scale=scale)
-        out["latent_decode"] = err(got, want[:, 0])
-        q_off = jnp.asarray([0, ctx[1]], jnp.int32)
-        qp = jax.random.normal(key[2], (2, chunk, heads, rank + rope),
+        got = np.asarray(mla.mla_decode_attention(
+            q, pool, layer, tables, lens, rank=rank, scale=scale,
+            interpret=interpret))
+        want = np.asarray(mla.mla_attention_dense(
+            q[:, None], pool, layer, tables, lens, lens - 1, rank=rank,
+            scale=scale))[:, 0]
+        check(not got[~live].any(), "an idle lane's rows are not 0")
+        out["latent_decode"] = err(got[live], want[live])
+        first, second = np.flatnonzero(live)[:2]
+        rows = jnp.asarray([first, np.flatnonzero(~live)[0], second])
+        q_off = jnp.asarray([0, 0, ctx[second]], jnp.int32)
+        kv_len = jnp.asarray([chunk, 0, ctx[second] + chunk], jnp.int32)
+        qp = jax.random.normal(key[2], (3, chunk, heads, rank + rope),
                                jnp.bfloat16).astype(jnp.float32)
-        got = mla.mla_prefill_attention(qp, pool, layer, tables[:2],
-                                        q_off + chunk, q_off, rank=rank,
-                                        scale=scale, interpret=interpret)
-        want = mla.mla_attention_dense(qp, pool, layer, tables[:2],
-                                       q_off + chunk, q_off, rank=rank,
-                                       scale=scale)
-        out["latent_prefill"] = err(got, want)
+        got = np.asarray(mla.mla_prefill_attention(
+            qp, pool, layer, tables[rows], kv_len, q_off, rank=rank,
+            scale=scale, interpret=interpret))
+        want = np.asarray(mla.mla_attention_dense(
+            qp, pool, layer, tables[rows], kv_len, q_off, rank=rank,
+            scale=scale))
+        check(not got[1].any(), "a row without a sequence is not 0")
+        out["latent_prefill"] = err(got[::2], want[::2])
     return {k: round(v, 5) for k, v in out.items()}
 
 

@@ -182,8 +182,8 @@ def test_latent_kernel_check_in_interpret_mode():
     shapes through the interpreter: the same code, within its tolerance;
     a check that reads the wrong layer is off by the whole spread."""
     errs = chip_smoke._latent_kernel_errors(
-        TINY, heads=4, rank=128, rope=16, ctx=(20, 300, 700), chunk=64,
-        interpret=True)
+        TINY, heads=4, rank=128, rope=16, ctx=(20, 0, 300, 0, 700, 41),
+        chunk=64, interpret=True)
     assert set(errs) == {"latent_decode", "latent_prefill"}
     assert max(errs.values()) <= chip_smoke.SETTINGS["latent_kernel_tol"]
 

@@ -136,34 +136,65 @@ def test_engine_matches_the_reference(tiny, backend):
 
 
 # ------------------------------------------------------------------ (b)
-@pytest.mark.parametrize("pages_per_step", [3, 16])
-def test_latent_kernels_match_the_dense_form(pages_per_step):
-    L, P, pg, R, Dr, H = 3, 40, 16, 128, 16, 4
-    k = jax.random.split(jax.random.PRNGKey(0), 3)
-    pool = jax.random.normal(k[0], (L, P, pg, 256), jnp.float32)
-    B, S, MP = 2, 32, 8
-    bt = jnp.asarray(np.random.RandomState(0).permutation(
-        np.arange(1, P))[:B * MP].reshape(B, MP), jnp.int32)
+@pytest.mark.parametrize("mp,block_bytes,block", [
+    (8, 3 * 16 * 256 * 4, 3),       # 3 pages a block: does not divide mp
+    (8, 1 << 30, 8),                # the rule stops at mp: one block a lane
+    (21, 16 * 16 * 256 * 4, 16),    # 16-page blocks, a partial second one
+])
+def test_latent_kernels_match_the_dense_form(monkeypatch, mp, block_bytes,
+                                             block):
+    """Both entry points on the hand-copied page feed (interpret mode)
+    against the dense form. The block size comes from page bytes and
+    ``mp`` (``_block_pages``); the cases make the rule pick 3, mp and 16
+    pages a block."""
+    L, P, pg, R, Dr, H, W = 3, 200, 16, 128, 16, 4, 256
+    monkeypatch.setattr(mla, "BLOCK_BYTES", block_bytes)
+    assert mla._block_pages(pg, pg * W * 4, H, mp) == block
+    k = jax.random.split(jax.random.PRNGKey(mp), 3)
+    pool = jax.random.normal(k[0], (L, P, pg, W), jnp.float32)
+    B, S = 2, 32
+    perm = np.random.RandomState(0).permutation(np.arange(1, P - 1))
+
+    def table(rows, lens):
+        """Scattered pages; every position past a lane's last page names
+        the NaN page or a page that does not exist: never read."""
+        bt = perm[:rows * mp].reshape(rows, mp).copy()
+        for i, n in enumerate(lens):
+            bt[i, -(-n // pg):] = [P - 1, P + 7][i % 2]
+        return jnp.asarray(bt, jnp.int32)
+
+    poisoned = pool.at[:, P - 1].set(jnp.nan)
     q = jax.random.normal(k[1], (B, S, H, R + Dr))
     # Sequence 0: a 32-token chunk behind a 40-token cached prefix;
     # sequence 1: 20 valid tokens of a padded chunk behind 3.
     q_off = jnp.asarray([40, 3], jnp.int32)
     kv_len = q_off + jnp.asarray([32, 20])
+    bt = table(B, kv_len.tolist())
     for layer in (1, 2):
-        want = mla.mla_attention_dense(q, pool, layer, bt, kv_len, q_off,
-                                       rank=R, scale=0.1)
+        want = mla.mla_attention_dense(q, pool, layer, jnp.minimum(bt, P - 2),
+                                       kv_len, q_off, rank=R, scale=0.1)
         got = mla.mla_prefill_attention(
-            q, pool, layer, bt, kv_len, q_off, rank=R, scale=0.1, block_q=8,
-            pages_per_step=pages_per_step, interpret=True)
+            q, poisoned, layer, bt, kv_len, q_off, rank=R, scale=0.1,
+            block_q=8, interpret=True)
         np.testing.assert_allclose(got[0], want[0], atol=2e-5)
         np.testing.assert_allclose(got[1, :20], want[1, :20], atol=2e-5)
-    kv_len = jnp.asarray([100, 17], jnp.int32)
-    want = mla.mla_attention_dense(q[:, :1], pool, 2, bt, kv_len, kv_len - 1,
-                                   rank=R, scale=0.1)[:, 0]
-    got = mla.mla_decode_attention(q[:, 0], pool, 2, bt, kv_len, rank=R,
-                                   scale=0.1, pages_per_step=pages_per_step,
-                                   interpret=True)
-    np.testing.assert_allclose(got, want, atol=2e-5)
+    # Decode, lanes of unequal length: an idle lane (kv_len 0) first,
+    # between live ones and last, a partial last block, exactly one
+    # block, one token, a lane that fills its block table.
+    lens = [0, 100, 0, block * pg, 1, mp * pg, 17, 0]
+    kv_len = jnp.asarray(lens, jnp.int32)
+    bt = table(len(lens), lens)
+    qd = jax.random.normal(k[2], (len(lens), H, R + Dr))
+    for layer in (0, 2):
+        want = mla.mla_attention_dense(
+            qd[:, None], pool, layer, jnp.minimum(bt, P - 2), kv_len,
+            kv_len - 1, rank=R, scale=0.1)[:, 0]
+        got = mla.mla_decode_attention(qd, poisoned, layer, bt, kv_len,
+                                       rank=R, scale=0.1, interpret=True)
+        live = np.asarray(kv_len) > 0
+        np.testing.assert_allclose(got[live], want[live], atol=2e-5)
+        # A lane that read nothing gives 0, not NaN.
+        assert not np.asarray(got[~live]).any()
 
 
 def test_absorbed_attention_is_the_expanded_attention(tiny):

@@ -212,6 +212,7 @@ KIMI = dict(heads=64, rank=512, rope=64, width=640, layers=7, mp=672,
 
 
 @pytest.mark.parametrize("kernel,b,seq", [("decode", 32, 1),
+                                          ("decode", 8, 1),
                                           ("prefill", 1, 1024),
                                           ("prefill", 4, 64)])
 def test_latent_kernel_compiles_for_v5e_and_reads_the_pool_in_place(

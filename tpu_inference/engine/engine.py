@@ -71,9 +71,12 @@ def make_latent_attn(cfg: ModelConfig, page_size: int,
         slots = kvc.slot_mapping(block_tables, positions, valid, page_size)
         kv = kvc.write_latent(kv, layer_idx, entry, slots)
         if pallas and q.shape[1] == 1:
+            # A lane whose token is padding (an idle lane of the rung)
+            # reads nothing: its rows are thrown away.
             out = mla.mla_decode_attention(
-                q[:, 0], kv.k, layer_idx, block_tables, kv_len, rank=rank,
-                scale=scale, interpret=interpret)[:, None]
+                q[:, 0], kv.k, layer_idx, block_tables,
+                jnp.where(valid[:, 0], kv_len, 0), rank=rank, scale=scale,
+                interpret=interpret)[:, None]
         elif pallas:
             out = mla.mla_prefill_attention(
                 q, kv.k, layer_idx, block_tables, kv_len, q_offset,

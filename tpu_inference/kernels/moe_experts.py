@@ -39,7 +39,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from tpu_inference.kernels.mla_attention import mxu_precision
+from tpu_inference.kernels import mxu_precision
 from tpu_inference.models.quant import QuantizedArray
 
 

@@ -36,8 +36,8 @@ Mechanics:
   are not copied, and no page the block table does not name for a visible
   position ever leaves HBM. Measured on the v5e against the same block
   fed by the pipeline (16 page operands a pool, each with its own index
-  map: kernels/mla_attention.py's way): 27 against 392 us a call for
-  three short lanes of eight, 163 against 460 us for six full-window ones
+  map: ``_pipelined_kernel`` below): 27 against 392 us a call for three
+  short lanes of eight, 163 against 460 us for six full-window ones
   (PERF.md, PR 27): a grid step evaluates every operand's index map
   whether it fetches or not, ~2.9 us a step with 32 of them.
 - The pipeline-fed form (``_pipelined_kernel``, grid ``(B, blocks)``) is
@@ -78,7 +78,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from tpu_inference.kernels.mla_attention import mxu_precision
+from tpu_inference.kernels import mxu_precision
 
 NEG_INF = -1e30
 
@@ -330,7 +330,7 @@ def _pipelined_kernel(layer_ref, bt_ref, kv_len_ref, q_ref, *rest,
                       packed: bool, sliding_window: int, **attend):
     """Grid (B, blocks): the block's pages arrive as ``pages_per_step``
     operands a pool, each fetched by the pipeline under its own index map
-    (kernels/mla_attention.py's way)."""
+    (what the latent kernel did too, before PR 31)."""
     del layer_ref, bt_ref
     nps = pages_per_step
     n_in = (4 if quantized else 2) * nps
