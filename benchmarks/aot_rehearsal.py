@@ -77,6 +77,9 @@ def main() -> int:
                     choices=("none", "int8", "int4"))
     ap.add_argument("--tp", type=int, default=1, choices=(1, 2, 4))
     ap.add_argument("--max-pages-per-seq", type=int, default=320)
+    ap.add_argument("--target-ctx", type=int, default=0,
+                    help="as the server's flag: what 'auto' sizes the "
+                         "batch against (0: half the context cap)")
     ap.add_argument("--hbm-bytes", type=float, default=16.91e9,
                     help="what the chip reports as memory_stats()"
                          "['bytes_limit'] (v5e: 16.91e9)")
@@ -114,7 +117,7 @@ def main() -> int:
         mcfg, EngineConfig(quant=args.quant, attn_backend="pallas",
                            max_pages_per_seq=mp),
         dict(max_batch_size="auto", num_pages="auto", decode_ladder="auto",
-             target_ctx=0, batch_cap=32, speculative=False),
+             target_ctx=args.target_ctx, batch_cap=32, speculative=False),
         tp=args.tp, hbm_bytes=args.hbm_bytes)
 
     # The stand-in: any small engine on the Pallas backend (its
@@ -129,6 +132,9 @@ def main() -> int:
     finally:
         jax.default_backend = real_backend
     eng.model_cfg, eng.engine_cfg = mcfg, ecfg
+    # (a block-table row holds a table a kind where the real model has
+    # a pool a kind; the one-layer stand-in has one)
+    eng.bt_width = mp * (2 if kvc.num_window_pages(mcfg, ecfg) else 1)
 
     shapes = jax.eval_shape(
         (lambda: init_quantized_params(mcfg, 0, args.quant))
@@ -168,12 +174,14 @@ def main() -> int:
 
     def prefill_args(p, bucket):
         return (arr((p, bucket), i32), arr((p,), i32), arr((p,), i32),
-                arr((p, mp), i32), key, arr((p,), f32), arr((p,), f32),
+                arr((p, eng.bt_width), i32), key, arr((p,), f32),
+                arr((p,), f32),
                 arr((p,), i32), arr((p,), i32), arr((p,), f32),
                 arr((p,), i32), arr((p, PENALTY_WINDOW), i32))
 
     def decode_args(b):
-        return (arr((b,), i32), arr((b,), i32), arr((b, mp), i32),
+        return (arr((b,), i32), arr((b,), i32),
+                arr((b, eng.bt_width), i32),
                 arr((b,), i32), arr((b,), i32), key, arr((b,), f32),
                 arr((b,), f32), arr((b,), i32), arr((b,), i32),
                 arr((b,), f32), arr((b,), i32),
@@ -238,6 +246,10 @@ def main() -> int:
         copies = (pool_copies(text, shard)
                   + (pool_copies(text, merged) if len(shard) == 5 else [])
                   if graph != "swap" else [])
+        if kv.wk is not None:       # the window kind's pool, both views
+            wshape = tuple(kv.wk.shape)
+            copies += (pool_copies(text, wshape) + pool_copies(
+                text, wshape[:2] + (wshape[2] * wshape[3],) + wshape[4:]))
         failed += bool(copies)
         if args.dump_hlo:
             os.makedirs(args.dump_hlo, exist_ok=True)

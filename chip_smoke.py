@@ -841,6 +841,85 @@ def _mha_kernel_errors(cfg: dict, *, heads: int = 16, d: int = 128,
     return {k: round(v, 5) for k, v in out.items()}
 
 
+def _mixed_kernel_errors(cfg: dict, *, d: int = 128, kv_heads: int = 8,
+                         kinds=((72, 512), (48, 0)), slots: int = 9,
+                         lanes: int = 6, ctx: int = 1400, rows: int = 512,
+                         interpret: bool = False) -> dict:
+    """The two GQA kernels at the shapes of a stack of mixed kinds
+    (Laguna-S-2.1's: 72 query heads over a window of 512 and 48 over the
+    whole context, on 8 KV heads x 128: ``n_rep`` 9 and 6, a window of
+    two 256-token blocks) against dense float32 attention: decode at
+    ``lanes`` lanes from a few tokens to several windows long, one idle;
+    a prefill chunk of ``rows`` rows at offset 0 and behind a prefix
+    longer than the window that ends inside a page block. The pages
+    behind a windowed lane's window are the trash page, as the engine
+    leaves them (released while the sequence runs)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tpu_inference.engine import kv_cache as kvc
+    from tpu_inference.kernels.paged_attention import paged_attention
+    from tpu_inference.kernels.prefill_attention import (
+        paged_prefill_attention)
+    from tpu_inference.models.common import dense_causal_attention
+
+    page = 16
+    q_off = np.array([0, ctx - 7], np.int32)
+    mp = -(-(int(q_off[1]) + rows) // page)
+    slot = slots // 2 + 1
+    kv_lens = np.array([3 + (ctx * i) // (lanes - 1) for i in range(lanes)],
+                       np.int32)
+    kv_lens[lanes // 2] = 0
+    live = kv_lens > 0
+    key = jax.random.split(jax.random.PRNGKey(cfg["seed"] + 2), 4)
+    pool_shape = (slots, lanes * mp + 1, page, kv_heads, d)
+    k_pool = jax.random.normal(key[0], pool_shape, jnp.bfloat16)
+    v_pool = jax.random.normal(key[1], pool_shape, jnp.bfloat16)
+    tables = 1 + np.arange(lanes * mp, dtype=np.int32).reshape(lanes, mp)
+    k_all, v_all = kvc.gather_kv(kvc.KVPages(k=k_pool, v=v_pool), slot,
+                                 jnp.asarray(tables))
+
+    def released(tables, first_query, window):
+        """The tables with each lane's pages behind its window zeroed."""
+        if not window:
+            return jnp.asarray(tables)
+        out = tables.copy()
+        for lane, q0 in enumerate(first_query):
+            out[lane, :max(0, int(q0) - window) // page] = 0
+        return jnp.asarray(out)
+
+    out = {}
+    with jax.default_matmul_precision("highest"):
+        for heads, window in kinds:
+            tag = f"h{heads}w{window}"
+            q = jax.random.normal(key[2], (lanes, heads, d),
+                                  jnp.bfloat16).astype(jnp.float32)
+            seen = np.maximum(kv_lens, 1)
+            got = paged_attention(
+                q, k_pool, v_pool, slot, released(tables, seen - 1, window),
+                jnp.asarray(kv_lens), interpret=interpret,
+                sliding_window=window)
+            want = dense_causal_attention(
+                q[:, None], k_all, v_all, q_offset=jnp.asarray(seen - 1),
+                kv_len=jnp.asarray(seen), sliding_window=window)[:, 0]
+            check(not np.asarray(got)[~live].any(),
+                  "an idle lane's rows are not 0")
+            out[tag + "_decode"] = _attn_error(got[live], want[live])
+            lens = q_off + rows
+            qp = jax.random.normal(key[3], (2, rows, heads, d),
+                                   jnp.bfloat16).astype(jnp.float32)
+            got = paged_prefill_attention(
+                qp, k_pool, v_pool, slot, released(tables[:2], q_off, window),
+                jnp.asarray(lens), jnp.asarray(q_off), interpret=interpret,
+                sliding_window=window)
+            want = dense_causal_attention(
+                qp, k_all[:2], v_all[:2], q_offset=jnp.asarray(q_off),
+                kv_len=jnp.asarray(lens), sliding_window=window)
+            out[tag + "_prefill"] = _attn_error(got, want)
+    return {k: round(v, 5) for k, v in out.items()}
+
+
 def _latent_kernel_errors(cfg: dict, *, heads: int = 64, rank: int = 512,
                           rope: int = 64,
                           ctx=(100, 0, 5000, 8200, 0, 0, 10000, 3333),
@@ -1046,6 +1125,10 @@ def child_parity(cfg: dict) -> dict:
         res["mha_kernel_err"] = _mha_kernel_errors(cfg)
         check(max(res["mha_kernel_err"].values()) <= cfg["kernel_tol"],
               f"Pallas kernels at the MHA shape vs dense float32: {res}")
+        res["mixed_kernel_err"] = _mixed_kernel_errors(cfg)
+        check(max(res["mixed_kernel_err"].values()) <= cfg["kernel_tol"],
+              f"Pallas kernels at the mixed-kind shapes vs dense float32: "
+              f"{res}")
         res["latent_kernel_err"] = _latent_kernel_errors(cfg)
         check(max(res["latent_kernel_err"].values())
               <= cfg["latent_kernel_tol"],

@@ -200,6 +200,20 @@ def test_mha_kernel_check_in_interpret_mode():
     assert max(errs.values()) <= chip_smoke.SETTINGS["kernel_tol"]
 
 
+def test_mixed_kernel_check_in_interpret_mode():
+    """The smoke's mixed-kind kernel check (run on the chip at
+    Laguna-S-2.1's: 72 heads over a window of 512 and 48 over everything,
+    on 8 KV heads x 128) at a small shape through the interpreter: the
+    same code, a window and no window, n_rep 3 and 2, the pages behind a
+    window the trash page."""
+    errs = chip_smoke._mixed_kernel_errors(
+        TINY, d=32, kv_heads=3, kinds=((9, 24), (6, 0)), slots=4, lanes=5,
+        ctx=90, rows=64, interpret=True)
+    assert set(errs) == {"h9w24_decode", "h9w24_prefill", "h6w0_decode",
+                         "h6w0_prefill"}
+    assert max(errs.values()) <= chip_smoke.SETTINGS["kernel_tol"]
+
+
 def test_routed_expert_check_in_interpret_mode():
     """The smoke's routed-expert check (run on the chip at Kimi-K2's
     widths, layer 1 of a 2-layer stack) at the tiny preset through the

@@ -50,6 +50,16 @@ class YarnScaling:
     beta_slow: float = 1.0
     mscale: float = 1.0
     mscale_all_dim: float = 1.0
+    # A stated cos/sin multiplier (HF ``attention_factor``); 0 = the
+    # ratio of the two mscale terms above.
+    attention_factor: float = 0.0
+
+
+# Attention kinds of a stack whose layers differ (ModelConfig.layer_types):
+# "full" attends causally over the whole context, "window" over the last
+# ``sliding_window`` keys. Each kind has a KV pool of its own
+# (engine/kv_cache.py).
+LAYER_KINDS = ("full", "window")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -59,12 +69,15 @@ class ModelConfig:
     Covers Llama-style (RMSNorm/RoPE/GQA/SwiGLU), Mixtral (adds MoE fields)
     GPT-2 (LayerNorm/learned-positional/GELU), DeepSeek-V3 / Kimi-K2
     (latent attention, sigmoid-routed experts beside a shared one, a
-    chip's share of an expert-parallel deployment) and Ouro (a looped
-    stack: the layers run ``loop_steps`` times a token) families.
+    chip's share of an expert-parallel deployment), Ouro (a looped
+    stack: the layers run ``loop_steps`` times a token) and Laguna
+    (full and window attention layers with different head counts in one
+    stack, a per-head output gate, routed experts beside a shared one)
+    families.
     """
 
     name: str = "llama"
-    # "llama" | "mixtral" | "gpt2" | "deepseek_v3" | "ouro"
+    # "llama" | "mixtral" | "gpt2" | "deepseek_v3" | "ouro" | "laguna"
     family: str = "llama"
     vocab_size: int = 32000
     d_model: int = 4096
@@ -153,6 +166,25 @@ class ModelConfig:
     # is the last pass for every token; per-token depth is not served
     # (the engine refuses < 1).
     early_exit_threshold: float = 1.0
+    # --- layer kinds within one model (models/laguna.py) ---
+    # layer_types[l] is layer l's attention kind, one of LAYER_KINDS;
+    # () = one kind for the whole model (every other family: its
+    # sliding_window, if any, holds for all layers). A depth cut keeps a
+    # prefix, so the tuple may be longer than n_layers. A "window" layer
+    # attends over ``sliding_window`` keys with ``window_n_heads`` query
+    # heads and plain rope of ``window_rope_theta`` on every dim; a
+    # "full" layer has ``n_heads`` and ``rope_theta`` / ``rope_scaling``
+    # on the first ``partial_rotary_factor`` of each head's dims.
+    layer_types: tuple = ()
+    window_n_heads: int = 0
+    window_rope_theta: float = 0.0
+    # Share of each head's dims that rope turns (the leading ones; the
+    # rest pass through). 1.0 = all.
+    partial_rotary_factor: float = 1.0
+    # Output gate on the attention heads: "per_head" multiplies head h's
+    # output by sigmoid(x_normed . w_h) before the output projection
+    # (gated attention, head-wise); "none" = no gate.
+    attn_gate: str = "none"
     dtype: jnp.dtype = jnp.bfloat16
 
     @property
@@ -171,6 +203,16 @@ class ModelConfig:
         major (slot = pass * n_layers + layer); n_layers unlooped."""
         return self.n_layers * self.loop_steps
 
+    def kind_layers(self, kind: str) -> tuple:
+        """Indices of the layers of attention kind ``kind`` (a kind's KV
+        slot is a layer's place in this tuple)."""
+        return tuple(l for l, k in enumerate(self.layer_types[:self.n_layers])
+                     if k == kind)
+
+    def kind_heads(self, kind: str) -> int:
+        """Query heads of a layer of ``kind``."""
+        return self.window_n_heads if kind == "window" else self.n_heads
+
     @property
     def n_local_experts(self) -> int:
         """Routed experts of one layer held on this chip."""
@@ -187,15 +229,32 @@ class ModelConfig:
         assert self.n_heads % self.n_kv_heads == 0
         if self.n_experts:
             assert self.n_experts_per_tok <= self.n_experts
+            # This chip's share: the held experts divide the router's
+            # width, and the rank names one of the shares.
+            assert self.n_experts % self.ep_size == 0
+            assert 0 <= self.ep_rank < self.ep_size
+            assert 0 <= self.first_k_dense <= self.n_layers
+            assert self.moe_scoring in ("softmax", "sigmoid")
+        rot = self.head_dim * self.partial_rotary_factor
+        assert 0 < rot <= self.head_dim and rot == int(rot) and rot % 2 == 0
+        assert self.attn_gate in ("none", "per_head")
+        if self.layer_types:
+            kinds = self.layer_types[:self.n_layers]
+            assert len(kinds) == self.n_layers, \
+                "layer_types names fewer layers than n_layers"
+            assert set(kinds) <= set(LAYER_KINDS)
+            if "window" in kinds:
+                assert self.sliding_window > 0 and self.window_rope_theta > 0
+                assert self.window_n_heads > 0
+                assert self.window_n_heads % self.n_kv_heads == 0
+            # One KV slot a layer, one latent-free pool a kind.
+            assert self.loop_steps == 1 and not self.kv_lora_rank
         assert self.loop_steps >= 1 and 0.0 <= self.early_exit_threshold <= 1.0
         # Only the looped family's forward runs passes / output norms.
         assert self.family == "ouro" or (self.loop_steps == 1
                                          and not self.sandwich_norm)
         if self.family == "deepseek_v3":
             assert self.kv_lora_rank and self.qk_rope_head_dim % 2 == 0
-            assert 0 <= self.first_k_dense <= self.n_layers
-            assert self.n_experts % self.ep_size == 0
-            assert 0 <= self.ep_rank < self.ep_size
             # The one routing a preset has: models/deepseek_v3.py route()
             # implements no other until a configuration needs it.
             assert self.moe_scoring == "sigmoid" and self.norm_topk_prob
@@ -330,6 +389,35 @@ def ouro_2_6b() -> ModelConfig:
     )
 
 
+def laguna_s_ep8() -> ModelConfig:
+    """Laguna-S-2.1 (poolside) as ONE chip's share of an EP8 x PP4
+    deployment, at every published width: layers 0-11 of 48 (a full
+    attention layer with 48 query heads, then three window-512 layers
+    with 72, three times over; 8 KV heads x 128 throughout), layer 0 a
+    dense SwiGLU of 12288, layers 1-11 a router over all 256 experts of
+    which this chip (rank 0 of 8) holds 32, top-10, one shared expert;
+    a per-head sigmoid output gate; YaRN rope on half of each head on a
+    full layer, plain rope on a window layer; vocabulary rows 0..12543.
+    bench/configs/laguna-s-ep8-bf16.json states the cut and what is
+    assumed."""
+    period = ("full", "window", "window", "window")
+    return ModelConfig(
+        name="laguna-s-ep8", family="laguna", vocab_size=12544,
+        d_model=3072, n_layers=12, n_heads=48, n_kv_heads=8, d_ff=12288,
+        max_seq_len=1048576, rope_theta=500000.0,
+        rope_scaling=YarnScaling(factor=128.0, original_max_len=8192,
+                                 beta_fast=32.0, beta_slow=1.0,
+                                 attention_factor=1.4852030263919618),
+        norm_eps=1e-6, head_dim_override=128, sliding_window=512,
+        layer_types=period * 12, window_n_heads=72,
+        window_rope_theta=10000.0, partial_rotary_factor=0.5,
+        attn_gate="per_head", first_k_dense=1, moe_d_ff=1024,
+        n_shared_experts=1, n_experts=256, n_experts_per_tok=10,
+        moe_scoring="sigmoid", norm_topk_prob=True,
+        routed_scaling_factor=2.5, ep_size=8, ep_rank=0,
+    )
+
+
 def gpt2_small() -> ModelConfig:
     return ModelConfig(
         name="gpt2", family="gpt2", vocab_size=50257, d_model=768,
@@ -446,6 +534,30 @@ def tiny_ouro(vocab_size: int = 512) -> ModelConfig:
     )
 
 
+def tiny_laguna(vocab_size: int = 512) -> ModelConfig:
+    """The Laguna structure at test widths: two periods of (full, window,
+    window, window) with 6 / 9 query heads on 3 KV heads, window 8,
+    layer 0 dense, then 16 routed experts top-3 of which this chip
+    (rank 0 of 4) holds 4, a shared expert, the per-head gate, YaRN on
+    half of a full layer's head dims."""
+    return ModelConfig(
+        name="tiny-laguna", family="laguna", vocab_size=vocab_size,
+        d_model=96, n_layers=8, n_heads=6, n_kv_heads=3, d_ff=256,
+        max_seq_len=4096, rope_theta=10000.0,
+        rope_scaling=YarnScaling(factor=8.0, original_max_len=64,
+                                 beta_fast=8.0, beta_slow=1.0,
+                                 attention_factor=1.2),
+        norm_eps=1e-6, head_dim_override=32, sliding_window=8,
+        layer_types=("full", "window", "window", "window") * 2,
+        window_n_heads=9, window_rope_theta=1000.0,
+        partial_rotary_factor=0.5, attn_gate="per_head", first_k_dense=1,
+        moe_d_ff=64, n_shared_experts=1, n_experts=16,
+        n_experts_per_tok=3, moe_scoring="sigmoid", norm_topk_prob=True,
+        routed_scaling_factor=2.5, ep_size=4, ep_rank=0,
+        dtype=jnp.float32,
+    )
+
+
 PRESETS = {
     "llama-3-8b": llama3_8b,
     "llama-3.1-8b": llama31_8b,
@@ -458,6 +570,7 @@ PRESETS = {
     "gpt2": gpt2_small,
     "kimi-k2-ep32": kimi_k2_ep32,
     "ouro-2.6b": ouro_2_6b,
+    "laguna-s-ep8": laguna_s_ep8,
     "tiny-llama": tiny_llama,
     "tiny-llama-fatkv": tiny_llama_fatkv,
     "tiny-qwen2": tiny_qwen2,
@@ -468,6 +581,7 @@ PRESETS = {
     "tiny-gpt2": tiny_gpt2,
     "tiny-kimi": tiny_kimi,
     "tiny-ouro": tiny_ouro,
+    "tiny-laguna": tiny_laguna,
 }
 
 
@@ -503,6 +617,11 @@ class EngineConfig:
     # Paged KV cache.
     page_size: int = 16               # tokens per KV page
     num_pages: int = 512              # pool size (per chip, per model)
+    # A model whose layers differ in kind (ModelConfig.layer_types) has a
+    # second pool for its window layers; ``num_pages`` is then the full
+    # kind's. 0 = every lane's window span (engine.window_span_pages),
+    # which is also what 'auto' sizing gives it.
+    num_window_pages: int = 0
     max_pages_per_seq: int = 64       # => max context = page_size * this
     # Continuous batching.
     max_batch_size: int = 8           # decode slots in the batched graph
@@ -1134,6 +1253,8 @@ def model_config_from_dict(d: dict) -> ModelConfig:
     if isinstance(rs, dict):
         d["rope_scaling"] = (YarnScaling if "beta_fast" in rs
                              else RopeScaling)(**rs)
+    if "layer_types" in d:
+        d["layer_types"] = tuple(d["layer_types"])
     return ModelConfig(**d)
 
 

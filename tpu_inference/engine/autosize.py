@@ -35,6 +35,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional
 
+from tpu_inference.engine.kv_cache import window_span_pages
+
 
 @dataclasses.dataclass(frozen=True)
 class ChipSpec:
@@ -147,14 +149,19 @@ def weight_read_bytes(model_cfg, quant: str = "none") -> int:
     return wb + (model_cfg.loop_steps - 1) * (wb - embed)
 
 
-def kv_bytes_per_token(model_cfg, kv_quant: str = "none") -> int:
+def kv_bytes_per_token(model_cfg, kv_quant: str = "none",
+                       kind: Optional[str] = None) -> int:
     """Pool bytes one token occupies across all KV slots (K and V; L =
-    layers, times the passes of a looped stack).
+    layers, times the passes of a looped stack). ``kind``: across one
+    kind's layers only (a model whose layers differ in kind has a pool
+    a kind, ModelConfig.layer_types).
 
     bf16: 2 * L * Hkv * D * 2; int8: codes (1 byte) + a per-(token,
     kv-head) f32 scale; int4: nibble-packed codes (D/2 bytes) + the
     same f32 scale — engine/kv_cache.py layouts."""
     L = model_cfg.n_kv_slots      # one per (pass, layer) of a looped stack
+    if kind is not None:
+        L = len(model_cfg.kind_layers(kind))
     if model_cfg.latent_dim:
         # One latent entry per token per layer for all heads, at the
         # pool's stored (lane-padded) width; never quantized.
@@ -179,6 +186,9 @@ class AutoSizing:
     kv_pool_bytes_per_chip: int
     kv_bytes_per_token: int
     target_ctx: int
+    # A model with a pool a kind: the window kind's pool (every lane's
+    # span); num_pages / kv_bytes_per_token are then the full kind's.
+    num_window_pages: int = 0
 
 
 def auto_size(model_cfg, *, hbm_bytes: float, quant: str = "none",
@@ -187,12 +197,19 @@ def auto_size(model_cfg, *, hbm_bytes: float, quant: str = "none",
               target_ctx: Optional[int] = None, batch_cap: int = 32,
               reserve_frac: float = 0.15,
               activation_headroom: int = 512 << 20,
-              speculative: bool = False) -> AutoSizing:
+              speculative: bool = False,
+              window_span: int = 0) -> AutoSizing:
     """Size ``max_batch_size`` and ``num_pages`` for the chip.
 
     Raises ValueError when the weights alone exceed the per-chip budget
     (the caller should quantize, raise tp, or pick a bigger chip) or
     when the KV budget can't hold even one full-length sequence.
+
+    A model whose layers differ in kind (``layer_types``) has a pool a
+    kind: the window kind's holds ``window_span`` pages
+    (kv_cache.window_span_pages) for each lane of the batch, whatever
+    the context, the full kind's gets the rest of the budget, and the
+    batch is the largest whose lanes fit both at ``target_ctx``.
     """
     hbm = float(hbm_bytes)
     wb = weight_bytes(model_cfg, quant)
@@ -206,6 +223,12 @@ def auto_size(model_cfg, *, hbm_bytes: float, quant: str = "none",
             f"activation headroom exceed {usable / 1e9:.1f} GB usable HBM "
             f"({hbm / 1e9:.0f} GB chip); use --quant int8, more tp, or a "
             "bigger chip")
+    if model_cfg.layer_types and window_span:
+        return _auto_size_kinds(
+            model_cfg, budget=budget, hbm=hbm, per_chip_w=per_chip_w,
+            kv_quant=kv_quant, page_size=page_size,
+            max_pages_per_seq=max_pages_per_seq, target_ctx=target_ctx,
+            batch_cap=batch_cap, window_span=window_span)
     kv_tok = kv_bytes_per_token(model_cfg, kv_quant)
     tokens = int(budget // (kv_tok / tp))
     num_pages = tokens // page_size
@@ -245,6 +268,37 @@ def auto_size(model_cfg, *, hbm_bytes: float, quant: str = "none",
         kv_bytes_per_token=kv_tok, target_ctx=ctx)
 
 
+def _auto_size_kinds(model_cfg, *, budget: float, hbm: float,
+                     per_chip_w: int, kv_quant: str, page_size: int,
+                     max_pages_per_seq: int, target_ctx: Optional[int],
+                     batch_cap: int, window_span: int) -> AutoSizing:
+    """auto_size for a pool a kind (one chip: tp is refused)."""
+    full_tok = kv_bytes_per_token(model_cfg, kv_quant, kind="full")
+    win_tok = kv_bytes_per_token(model_cfg, kv_quant, kind="window")
+    ctx = int(target_ctx) if target_ctx else (page_size * max_pages_per_seq
+                                              // 2)
+    ctx = max(1, min(ctx, page_size * max_pages_per_seq))
+    for batch in range(batch_cap, 0, -1):
+        win_pages = batch * window_span + 1
+        rest = budget - win_pages * page_size * win_tok
+        num_pages = min(int(rest // (full_tok * page_size)),
+                        4 * batch_cap * max_pages_per_seq)
+        if (num_pages >= max_pages_per_seq + 1
+                and (num_pages - 1) * page_size // ctx >= batch):
+            return AutoSizing(
+                max_batch_size=batch, num_pages=num_pages,
+                hbm_bytes=int(hbm), weight_bytes_per_chip=int(per_chip_w),
+                kv_pool_bytes_per_chip=int(
+                    page_size * (num_pages * full_tok + win_pages * win_tok)),
+                kv_bytes_per_token=full_tok, target_ctx=ctx,
+                num_window_pages=win_pages)
+    raise ValueError(
+        f"{model_cfg.name}: KV budget ({budget / 1e9:.2f} GB/chip) holds "
+        f"no lane's window span ({window_span} pages) beside one "
+        f"full-length sequence ({max_pages_per_seq} pages of the full "
+        "kind); lower --max-pages-per-seq")
+
+
 def detect_host_ram_bytes() -> int:
     """Available host RAM in bytes: /proc/meminfo MemAvailable (the
     kernel's own estimate of allocatable-without-swapping memory),
@@ -278,9 +332,11 @@ def auto_host_cache_pages(model_cfg, *, kv_quant: str = "none",
     stays off rather than inviting the OOM killer). 0 too for a latent
     (MLA) pool (the tier's page copies assume K and V pools) and for a
     looped stack (a page of pass x layer slots is tens of MiB to copy
-    out at every eviction; untested): 'auto' leaves it off there and an
-    explicit size is refused by the engine."""
-    if model_cfg.latent_dim or model_cfg.loop_steps > 1:
+    out at every eviction; untested) and for a pool a layer kind (a page
+    of one kind has no twin in the other): 'auto' leaves it off there and
+    an explicit size is refused by the engine."""
+    if (model_cfg.latent_dim or model_cfg.loop_steps > 1
+            or model_cfg.layer_types):
         return 0
     avail = (detect_host_ram_bytes() if host_ram_bytes is None
              else int(host_ram_bytes))
@@ -494,12 +550,18 @@ def resolve_sizing(model_cfg, engine_cfg, req: Optional[dict], *,
             page_size=engine_cfg.page_size,
             max_pages_per_seq=engine_cfg.max_pages_per_seq,
             target_ctx=req["target_ctx"] or None,
-            batch_cap=req["batch_cap"], speculative=req["speculative"])
+            batch_cap=req["batch_cap"], speculative=req["speculative"],
+            window_span=window_span_pages(model_cfg, engine_cfg)
+            if model_cfg.layer_types else 0)
         mbs = sz.max_batch_size if mbs == "auto" else mbs
         pages = sz.num_pages if pages == "auto" else pages
         import sys
 
+        # (The window kind's pool is not set here: left at 0 it is every
+        # lane's span at the batch that is served, kv_cache.num_window_pages.)
         print(f"[autosize] {model_cfg.name}: batch={mbs} num_pages={pages} "
+              + (f"num_window_pages={sz.num_window_pages} "
+                 if sz.num_window_pages else "") +
               f"(hbm {sz.hbm_bytes / 1e9:.2f} GB, weights/chip "
               f"{sz.weight_bytes_per_chip / 1e9:.2f} GB, kv pool/chip "
               f"{sz.kv_pool_bytes_per_chip / 1e9:.2f} GB, target ctx "

@@ -95,10 +95,13 @@ def make_paged_attn(cfg: ModelConfig, page_size: int, block_tables: jax.Array,
                     positions: jax.Array, valid: jax.Array,
                     q_offset: jax.Array, kv_len: jax.Array,
                     attn_backend: str = "dense", mesh: Optional[Any] = None,
-                    sp_mode: Optional[str] = None, interpret: bool = False):
+                    sp_mode: Optional[str] = None, interpret: bool = False,
+                    sliding_window: Optional[int] = None):
     """AttentionFn that writes new K/V into the paged pool then attends.
 
     block_tables [B, MP]; positions/valid [B, S]; q_offset/kv_len [B].
+    ``sliding_window`` overrides ``cfg.sliding_window`` (one kind of a
+    model whose layers differ: make_kind_attn).
 
     The Pallas kernels are handed the STACKED pool ``kv.k`` / ``kv.v``
     ``[L, P, page, Hkv, D]`` whole, with ``layer_idx`` as a scalar
@@ -137,6 +140,11 @@ def make_paged_attn(cfg: ModelConfig, page_size: int, block_tables: jax.Array,
                                 valid, q_offset, kv_len,
                                 attn_backend=attn_backend,
                                 interpret=interpret)
+    if cfg.layer_types and sliding_window is None:
+        return make_kind_attn(cfg, page_size, block_tables, positions, valid,
+                              q_offset, kv_len, attn_backend=attn_backend,
+                              interpret=interpret)
+    window = cfg.sliding_window if sliding_window is None else sliding_window
 
     def _sp_prefill(q, k, v):
         from functools import partial as _partial
@@ -153,7 +161,7 @@ def make_paged_attn(cfg: ModelConfig, page_size: int, block_tables: jax.Array,
         spec = P(None, "sp", "tp", None)       # [B, S, H, D]: seq × heads
         return jax.shard_map(
             _partial(sp_local, axis_name="sp",
-                     sliding_window=cfg.sliding_window),
+                     sliding_window=window),
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
             check_vma=False)(q, k, v)
 
@@ -193,7 +201,7 @@ def make_paged_attn(cfg: ModelConfig, page_size: int, block_tables: jax.Array,
         def kernel(q_, bt_, kl_, layer_, k_, v_, ks_=None, vs_=None):
             return paged_attention(q_, k_, v_, layer_, bt_, kl_, ks_, vs_,
                                    interpret=interpret,
-                                   sliding_window=cfg.sliding_window)
+                                   sliding_window=window)
 
         # A lane whose token is padding (an idle lane of the rung) reads
         # nothing: its rows are thrown away.
@@ -212,7 +220,7 @@ def make_paged_attn(cfg: ModelConfig, page_size: int, block_tables: jax.Array,
         def kernel(q_, bt_, kl_, qo_, layer_, k_, v_, ks_=None, vs_=None):
             return paged_prefill_attention(
                 q_, k_, v_, layer_, bt_, kl_, qo_, ks_, vs_,
-                interpret=interpret, sliding_window=cfg.sliding_window)
+                interpret=interpret, sliding_window=window)
 
         return _paged_call(kernel, kv, layer_idx,
                            lead_args=(q, block_tables, kv_len, q_offset),
@@ -236,38 +244,94 @@ def make_paged_attn(cfg: ModelConfig, page_size: int, block_tables: jax.Array,
         k_all, v_all = kvc.gather_kv(kv, layer_idx, block_tables)
         out = dense_causal_attention(q, k_all, v_all, q_offset=q_offset,
                                      kv_len=kv_len,
-                                     sliding_window=cfg.sliding_window)
+                                     sliding_window=window)
         return out, kv
 
     return attn
 
 
-# Why each is refused: (for a latent pool, for a looped stack).
+def make_kind_attn(cfg: ModelConfig, page_size: int, block_tables: jax.Array,
+                   positions: jax.Array, valid: jax.Array,
+                   q_offset: jax.Array, kv_len: jax.Array,
+                   attn_backend: str = "dense", interpret: bool = False):
+    """make_paged_attn for a model whose layers differ in kind
+    (``cfg.layer_types``): one AttentionFn a kind under ``attn.kinds``,
+    each over its own pool (``kv.k`` / ``kv.v`` full, ``kv.wk`` /
+    ``kv.wv`` window) and its own block table, with the kind's window
+    static. ``block_tables`` is [B, 2 * MP]: the full kind's table, then
+    the window kind's. The layer index a kind's function takes is the
+    layer's place among its kind (its slot in that pool).
+    ``attn.pallas`` / ``attn.interpret`` / ``attn.valid`` as
+    make_latent_attn's."""
+    mp = block_tables.shape[1] // 2
+    pallas = attn_backend == "pallas"
+
+    def of(table, window, pool_k, pool_v):
+        inner = make_paged_attn(
+            cfg, page_size, table, positions, valid, q_offset, kv_len,
+            attn_backend=attn_backend, interpret=interpret,
+            sliding_window=window)
+
+        def kind_attn(slot, q, k, v, kv: KVPages):
+            out, sub = inner(slot, q, k, v, KVPages(
+                k=getattr(kv, pool_k), v=getattr(kv, pool_v)))
+            return out, kv._replace(**{pool_k: sub.k, pool_v: sub.v})
+
+        return kind_attn
+
+    def attn(*_):
+        raise TypeError("a stack of mixed kinds calls attn.kinds[kind]")
+
+    attn.kinds = {
+        "full": of(block_tables[:, :mp], 0, "k", "v"),
+        "window": of(block_tables[:, mp:], cfg.sliding_window, "wk", "wv")}
+    attn.pallas, attn.interpret, attn.valid = pallas, interpret, valid
+    return attn
+
+
+# Why each is refused: (for a latent pool, for a looped stack, for a
+# stack of mixed kinds with a pool a kind).
 _WHY_NOT = {
     "tp": ("no param shardings, no sharded latent pool, no expert "
            "exchange: parallel/shardings.py",
            "no param shardings for the output norms and the exit gate: "
-           "parallel/shardings.py"),
+           "parallel/shardings.py",
+           "no param shardings for parameters stacked per kind, no sharded "
+           "per-kind pools, no expert exchange: parallel/shardings.py"),
     "kv_quant": ("the latent pool is stored in the model dtype",
                  "no test holds a quantized pool of pass x layer slots to "
-                 "the reference"),
+                 "the reference",
+                 "per-kind pools are stored in the model dtype: "
+                 "kv_cache.alloc_kind_pages"),
     "host": ("offload / restore / serialize assume K and V pools",
              "a page of pass x layer slots is tens of MiB to copy out at "
-             "every eviction, untested"),
+             "every eviction, untested",
+             "offload / restore copy one pool; a page of the full kind "
+             "has no window-kind twin to restore"),
     "role": ("P/D handoff serializes K and V pages",
-             "P/D handoff of pages of pass x layer slots is untested"),
+             "P/D handoff of pages of pass x layer slots is untested",
+             "P/D handoff serializes one pool's pages, not a table a kind"),
+    # Not an error: the cache is left off with this line (a one-kind
+    # window model does the same, __init__ below).
+    "prefix": (None, None,
+               "a hit would need the full kind's pages of the prefix AND "
+               "the window kind's last sliding_window tokens before its "
+               "end, which are released while a sequence runs"),
 }
 
 
 def _refuse_unsupported(model_cfg: ModelConfig, engine_cfg: EngineConfig,
                         mesh, draft_cfg) -> None:
-    """What a latent pool (the latent-attention / routed-expert family)
-    or a looped stack (``loop_steps`` > 1) does not run yet, said at
-    construction and not at the first request."""
+    """What a latent pool (the latent-attention / routed-expert family),
+    a looped stack (``loop_steps`` > 1) or a stack of mixed kinds
+    (``layer_types``) does not run yet, said at construction and not at
+    the first request."""
     latent, looped = bool(model_cfg.latent_dim), model_cfg.loop_steps > 1
-    if not (latent or looped):
+    kinds = bool(model_cfg.layer_types)
+    if not (latent or looped or kinds):
         return
-    why = {k: v[0 if latent else 1] for k, v in _WHY_NOT.items()}
+    why = {k: v[0 if latent else 1 if looped else 2]
+           for k, v in _WHY_NOT.items()}
     what = []
     if model_cfg.early_exit_threshold < 1.0:
         what.append(f"early_exit_threshold={model_cfg.early_exit_threshold}"
@@ -282,13 +346,14 @@ def _refuse_unsupported(model_cfg: ModelConfig, engine_cfg: EngineConfig,
         what.append("speculative decoding (draft or ngram)")
     if engine_cfg.host_cache_pages:
         what.append(f"the host KV tier (host_cache_pages > 0: {why['host']})")
-    if latent and engine_cfg.quant == "int4":
+    if (latent or kinds) and engine_cfg.quant == "int4":
         what.append("quant='int4' (the grouped expert kernels take bf16 "
                     "or int8 weights)")
     if engine_cfg.role != "mixed":
         what.append(f"role={engine_cfg.role!r} ({why['role']})")
     if what:
-        kind = "latent attention" if latent else "a looped stack"
+        kind = ("latent attention" if latent else "a looped stack"
+                if looped else "layers of mixed kinds")
         raise ValueError(f"{model_cfg.name} ({kind}) does not support: "
                          + "; ".join(what))
 
@@ -335,8 +400,9 @@ class Sequence:
     # can never alias a stale cached row (engine._stage_batch).
     pages_version: int = 0
     ctx_len: int = 0                       # tokens currently in KV
-    # SWA eviction cursor: pages[:evicted_pages] are behind the window,
-    # freed, and zeroed (engine._evict_behind_window).
+    # SWA eviction cursor: pages[:evicted_pages] (pages.window[:...] where
+    # the model has a pool a kind) are behind the window, freed, and
+    # zeroed (engine._evict_behind_window).
     evicted_pages: int = 0
     cached_tokens: int = 0                 # prefix-cache hit length
     # Tiered KV cache (README "Tiered KV cache"): device pages restored
@@ -560,6 +626,15 @@ class InferenceEngine:
                           if self.kv.aux is not None else None)
         t_pool = time.perf_counter()
         self.allocator = PageAllocator(engine_cfg.num_pages)
+        # A model whose layers differ in kind has a second pool, for its
+        # window layers, with an allocator and a block table a sequence
+        # of its own (kvc.KindPages); ``allocator`` is then the full
+        # kind's. None: the model has one kind.
+        n_win = kvc.num_window_pages(model_cfg, engine_cfg)
+        self.win_allocator = PageAllocator(n_win) if n_win else None
+        self.window_span = (kvc.window_span_pages(model_cfg, engine_cfg)
+                            if n_win else 0)
+        self.window_pages_released = 0    # behind-window frees, lifetime
         # Step-phase telemetry (telemetry.py): dispatch/bubble histograms
         # + read-through page/param gauges. TPU_INF_TELEMETRY=0 swaps in
         # no-op metrics (the overhead-comparison arm).
@@ -708,7 +783,10 @@ class InferenceEngine:
         swa_binds = bool(model_cfg.sliding_window) and (
             engine_cfg.max_context > model_cfg.sliding_window)
         self.host_pool = None
-        if engine_cfg.enable_prefix_cache and not swa_binds:
+        if engine_cfg.enable_prefix_cache and self.win_allocator is not None:
+            print(f"[engine] {model_cfg.name}: prefix cache disabled — "
+                  + _WHY_NOT["prefix"][2])
+        elif engine_cfg.enable_prefix_cache and not swa_binds:
             # SWA models run WITHOUT the prefix cache (vLLM makes the
             # same exclusion): behind-window pages are evicted while a
             # sequence runs (_evict_behind_window), and a cached prefix
@@ -742,6 +820,9 @@ class InferenceEngine:
                   "behind-window pages, which doesn't compose with "
                   "cached prefixes (multi-turn requests re-prefill)")
         self.max_pages = engine_cfg.max_pages_per_seq
+        # Width of a block-table row: a table a kind, side by side.
+        self.bt_width = self.max_pages * (1 if self.win_allocator is None
+                                          else 2)
         # Cold-start evidence (device_info): wall seconds of the last
         # warmup() and how many graphs it ran.
         self.warmup_s = 0.0
@@ -1164,7 +1245,7 @@ class InferenceEngine:
         prefill_batch_sizes = (self._prefill_batch_sizes if warm_prefill
                                else ())
         for p in prefill_batch_sizes:
-            bt = jnp.zeros((p, self.max_pages), jnp.int32)
+            bt = jnp.zeros((p, self.bt_width), jnp.int32)
             one = jnp.ones((p,), jnp.int32)
             zero = jnp.zeros((p,), jnp.int32)
             tz = jnp.zeros((p,), jnp.float32)
@@ -1200,7 +1281,7 @@ class InferenceEngine:
             graphs' decode half so the two call shapes cannot drift
             apart."""
             return (jnp.zeros((b,), jnp.int32), jnp.zeros((b,), jnp.int32),
-                    jnp.zeros((b, self.max_pages), jnp.int32),
+                    jnp.zeros((b, self.bt_width), jnp.int32),
                     jnp.zeros((b,), jnp.int32),
                     jnp.full((b,), -1, jnp.int32), self._next_key(),
                     jnp.zeros((b,), jnp.float32),
@@ -1218,7 +1299,7 @@ class InferenceEngine:
                 f"spec_round b={b}", self._spec_jit, self.params,
                 self.draft_params, self.kv, self.draft_kv,
                 jnp.zeros((b,), jnp.int32), jnp.zeros((b,), jnp.int32),
-                jnp.zeros((b, self.max_pages), jnp.int32),
+                jnp.zeros((b, self.bt_width), jnp.int32),
                 jnp.zeros((b,), jnp.int32), jnp.zeros((b,), bool),
                 self._next_key(), jnp.zeros((b,), jnp.float32),
                 jnp.ones((b,), jnp.float32), jnp.zeros((b,), jnp.int32))
@@ -1266,7 +1347,7 @@ class InferenceEngine:
                         self._verify_jit, self.params, self.kv,
                         jnp.zeros((b,), jnp.int32),
                         jnp.zeros((b,), jnp.int32),
-                        jnp.zeros((b, self.max_pages), jnp.int32),
+                        jnp.zeros((b, self.bt_width), jnp.int32),
                         jnp.zeros((b,), jnp.int32), jnp.zeros((b,), bool),
                         jnp.zeros((b, width - 1), jnp.int32),
                         jnp.zeros((b,), jnp.int32), self._next_key(),
@@ -1288,7 +1369,7 @@ class InferenceEngine:
             # reachable_buckets x rungs.
             bucket_cap = ecfg.bucket_for(
                 min(ecfg.chunk_tokens_cap, ecfg.max_context))
-            bt1 = jnp.zeros((1, self.max_pages), jnp.int32)
+            bt1 = jnp.zeros((1, self.bt_width), jnp.int32)
             one1, zero1 = jnp.ones((1,), jnp.int32), jnp.zeros((1,), jnp.int32)
             for bucket in ecfg.prefill_buckets:
                 if bucket > ecfg.max_context or bucket > bucket_cap:
@@ -1544,7 +1625,9 @@ class InferenceEngine:
         base = self._prefill_tokens(seq)
         total = len(base) + seq.max_new_tokens - seq.resume_base
         need = kvc.pages_needed(total, ecfg.page_size)
-        if self.swa_evict:
+        if self.swa_evict and self.win_allocator is None:
+            # (With a pool a kind this is the full kind's need, which no
+            # window bounds: _window_pages_reserved is the other's.)
             # Dispatch-ahead can grant depth*K tokens of head pages
             # before eviction (at the fold) catches up — include them.
             win = self.model_cfg.sliding_window
@@ -1580,6 +1663,43 @@ class InferenceEngine:
             ecfg.page_size)
         need = max(1, prompt_pages + ecfg.optimistic_headroom_pages)
         return min(full, need, self.max_pages)
+
+    def _window_pages_reserved(self, seq: Sequence) -> int:
+        """Window-kind pages a sequence is charged for its whole life
+        (0: the model has one kind): its tokens' pages, or the window's
+        span where behind-window release bounds them."""
+        if self.win_allocator is None:
+            return 0
+        total = (len(self._prefill_tokens(seq)) + seq.max_new_tokens
+                 - seq.resume_base)
+        need = min(kvc.pages_needed(total, self.engine_cfg.page_size),
+                   self.max_pages)
+        return min(need, self.window_span) if self.swa_evict else need
+
+    def admission_need(self, seq: Sequence) -> np.ndarray:
+        """Pages a request is charged at admission, a kind: [full (the
+        only kind of most models), window]."""
+        return np.asarray([self._pages_for_admission(seq),
+                           self._window_pages_reserved(seq)], np.int64)
+
+    def admission_fits(self, want: np.ndarray, headroom: int = 0) -> bool:
+        """Whether ``want`` pages a kind (admission_need, summed over the
+        requests one pass selects) can be granted. A model of one kind
+        compares with what is free or evictable now. With a pool a kind
+        every bound sequence's WHOLE charge is held back too, taken or
+        not yet, so that no pool can run out under a sequence that was
+        admitted: pressure in either kind is a wait at admission."""
+        room = self._free_plus_evictable() - headroom
+        if self.win_allocator is None:
+            return room >= want[0]
+        bound = [s for s in self.slots if s is not None and not s.done]
+        held = sum((self.admission_need(s) for s in bound),
+                   np.zeros(2, np.int64))
+        usable = np.asarray([self.engine_cfg.num_pages - 1,
+                             self.win_allocator.num_pages - 1])
+        left = usable - held
+        return bool(min(room, left[0] - headroom) >= want[0]
+                    and min(self.win_allocator.num_free, left[1]) >= want[1])
 
     def _free_plus_evictable(self) -> int:
         n = self.allocator.num_free
@@ -2024,6 +2144,10 @@ class InferenceEngine:
         if steps > 0:
             need = kvc.pages_needed(steps, ecfg.page_size, already=ctx)
             grantable = self._free_plus_evictable()
+            if self.win_allocator is not None:
+                # Both tables grow by the same pages: the scarcer kind
+                # bounds the grant.
+                grantable = min(grantable, self.win_allocator.num_free)
             if need > grantable:
                 # Pool pressure: advance only as far as the slack in the
                 # current last page plus the pages we can still grant.
@@ -2034,6 +2158,9 @@ class InferenceEngine:
                         if steps > 0 else 0)
             if need > 0:
                 seq.pages.extend(self._allocate_reclaiming(need))
+                if self.win_allocator is not None:
+                    seq.pages.window.extend(
+                        self.win_allocator.allocate(need))
         return steps
 
     def _fold_lane(self, seq: Sequence, toks) -> List[int]:
@@ -2052,17 +2179,48 @@ class InferenceEngine:
         return got
 
     def can_admit(self, seq: Sequence) -> bool:
-        return bool(self.free_slots()) and (
-            self._free_plus_evictable() >= self._pages_for_admission(seq))
+        return bool(self.free_slots()) and self.admission_fits(
+            self.admission_need(seq))
 
     def can_ever_admit(self, seq: Sequence) -> bool:
-        """False if the request exceeds the pool even when fully idle."""
-        return self._pages_reserved(seq) <= self.engine_cfg.num_pages - 1
+        """False if the request exceeds a pool even when fully idle."""
+        return (self._pages_reserved(seq) <= self.engine_cfg.num_pages - 1
+                and (self.win_allocator is None
+                     or self._window_pages_reserved(seq)
+                     <= self.win_allocator.num_pages - 1))
 
     def _block_table_array(self, pages: List[int]) -> np.ndarray:
-        bt = np.zeros((self.max_pages,), np.int32)
+        """One block-table row: ``pages`` by position, and behind it the
+        window kind's table where ``pages`` carries one
+        (kvc.KindPages)."""
+        bt = np.zeros((self.bt_width,), np.int32)
         bt[:len(pages)] = pages
+        window = getattr(pages, "window", ())
+        bt[self.max_pages:self.max_pages + len(window)] = window
         return bt
+
+    def _window_pages_for(self, seq: Sequence, start: int, end: int) -> None:
+        """A pool a kind: before tokens [start, end) are written, release
+        the window-kind pages no query from ``start`` on can see and take
+        those up to ``end`` (admission held them back: admission_fits).
+        The full kind's pages of a prompt are taken whole at
+        _prefill_setup; the window kind's a chunk at a time, so a long
+        prompt never holds more of them than window + chunk."""
+        if self.win_allocator is None:
+            return
+        if self.swa_evict:
+            self._evict_behind_window(seq, start)
+        window = seq.pages.window
+        need = kvc.pages_needed(end, self.engine_cfg.page_size) - len(window)
+        if need > 0:
+            window.extend(self.win_allocator.allocate(need))
+
+    def _free_pages(self, seq: Sequence) -> None:
+        """Give back every page a sequence holds, of either kind."""
+        self.allocator.free(seq.pages)
+        if self.win_allocator is not None:
+            self.win_allocator.free(getattr(seq.pages, "window", ()))
+        seq.pages = []
 
     def _prefill_setup(self, seq: Sequence, slot: int) -> List[int]:
         """Allocate pages (with prefix-cache reuse), bind the slot, and
@@ -2099,10 +2257,13 @@ class InferenceEngine:
         n_new = kvc.pages_needed(len(prompt), ecfg.page_size) - len(shared)
         try:
             seq.pages = shared + self._allocate_reclaiming(n_new)
+            if self.win_allocator is not None:
+                seq.pages = kvc.KindPages(seq.pages)
         except MemoryError:
             self.allocator.free(shared)
             raise
         seq.pages_version += 1        # staging block-table rows re-key
+        seq.evicted_pages = 0         # the window's cursor is the list's
         # Swap accounting AFTER the allocation can no longer fail: a
         # MemoryError-and-requeue retry must not double-count one
         # logical resume/restore in the span and counters.
@@ -2147,6 +2308,7 @@ class InferenceEngine:
         final chunk's sample is kept, so mid-chunk windows don't matter).
         """
         chunk = prompt[offset:offset + chunk_cap]
+        self._window_pages_for(seq, offset, offset + len(chunk))
         bucket = self.engine_cfg.bucket_for(len(chunk))
         toks = np.zeros((1, bucket), np.int32)
         toks[0, :len(chunk)] = chunk
@@ -2313,7 +2475,7 @@ class InferenceEngine:
         toks = np.zeros((p, bucket), np.int32)
         plen = np.ones((p,), np.int32)
         pref = np.zeros((p,), np.int32)
-        bts = np.zeros((p, self.max_pages), np.int32)
+        bts = np.zeros((p, self.bt_width), np.int32)
         temps = np.zeros((p,), np.float32)
         top_ps = np.ones((p,), np.float32)
         top_ks = np.zeros((p,), np.int32)
@@ -2323,6 +2485,7 @@ class InferenceEngine:
         wins = np.full((p, PENALTY_WINDOW), -1, np.int32)
         for i, (seq, prompt) in enumerate(group):
             chunk = prompt[seq.cached_tokens:]
+            self._window_pages_for(seq, seq.cached_tokens, len(prompt))
             toks[i, :len(chunk)] = chunk
             plen[i] = len(chunk)
             pref[i] = seq.cached_tokens
@@ -2423,9 +2586,13 @@ class InferenceEngine:
         elif self.swa_evict:
             self._evict_behind_window(seq)
 
-    def _evict_behind_window(self, seq: Sequence) -> None:
-        """Free KV pages entirely behind the sliding window; the block-
-        table slot becomes the trash page (0). No windowed reader ever
+    def _evict_behind_window(self, seq: Sequence,
+                             ctx: Optional[int] = None) -> None:
+        """Free KV pages entirely behind the sliding window of a query at
+        ``ctx`` (default: the next token's); the block-table slot becomes
+        the trash page (0). With a pool a kind these are the WINDOW
+        kind's pages (a full layer's are never released while the
+        sequence runs). No windowed reader ever
         touches them: the Pallas kernels' page grids start at the
         window's first page, and the dense path gathers-then-masks.
         In-flight dispatch-ahead calls staged with higher predicted ctx
@@ -2433,12 +2600,17 @@ class InferenceEngine:
         reader. The per-sequence cursor makes total work O(pages freed)
         over a sequence's life, not O(pages) per accepted token."""
         win = self.model_cfg.sliding_window
-        first_needed = max(0, seq.ctx_len - win) // self.engine_cfg.page_size
+        ctx = seq.ctx_len if ctx is None else ctx
+        first_needed = max(0, ctx - win) // self.engine_cfg.page_size
+        pages, allocator = seq.pages, self.allocator
+        if self.win_allocator is not None:
+            pages, allocator = seq.pages.window, self.win_allocator
         j = seq.evicted_pages
-        while j < min(first_needed, len(seq.pages)):
-            if seq.pages[j]:
-                self.allocator.free([seq.pages[j]])
-                seq.pages[j] = 0
+        while j < min(first_needed, len(pages)):
+            if pages[j]:
+                allocator.free([pages[j]])
+                pages[j] = 0
+                self.window_pages_released += 1
             j += 1
         seq.evicted_pages = j
 
@@ -2503,8 +2675,7 @@ class InferenceEngine:
         """Free a finished sequence's pages and slot, publishing its full
         pages to the prefix cache first."""
         self._publish_to_cache(seq)
-        self.allocator.free(seq.pages)
-        seq.pages = []
+        self._free_pages(seq)
         seq.prefill_prompt = None          # cancel/error mid-prefill
         if seq.slot >= 0 and self.slots[seq.slot] is seq:
             self.slots[seq.slot] = None
@@ -2527,8 +2698,7 @@ class InferenceEngine:
                    for call in self._inflight), \
             "preempt of a sequence with dispatch-ahead calls in flight"
         self._publish_to_cache(seq)
-        self.allocator.free(seq.pages)
-        seq.pages = []
+        self._free_pages(seq)
         if seq.slot >= 0 and self.slots[seq.slot] is seq:
             self.slots[seq.slot] = None
         self._stage_forget(seq)
@@ -2760,7 +2930,7 @@ class InferenceEngine:
             buf = {
                 "tokens": np.zeros((rung,), np.int32),
                 "ctx": np.zeros((rung,), np.int32),
-                "bts": np.zeros((rung, self.max_pages), np.int32),
+                "bts": np.zeros((rung, self.bt_width), np.int32),
                 "temps": np.zeros((rung,), np.float32),
                 "top_ps": np.ones((rung,), np.float32),
                 "top_ks": np.zeros((rung,), np.int32),
@@ -2805,7 +2975,7 @@ class InferenceEngine:
             # Legacy rebuild-per-dispatch (the bubble comparison arm).
             tokens = np.zeros((rung,), np.int32)
             ctx_lens = np.zeros((rung,), np.int32)
-            bts = np.zeros((rung, self.max_pages), np.int32)
+            bts = np.zeros((rung, self.bt_width), np.int32)
             temps = np.zeros((rung,), np.float32)
             top_ps = np.ones((rung,), np.float32)
             top_ks = np.zeros((rung,), np.int32)
@@ -2852,10 +3022,7 @@ class InferenceEngine:
             key = (seq.pages_version, len(seq.pages), seq.evicted_pages)
             if bt_key[i] != key:
                 bt_key[i] = key
-                row = buf["bts"][i]
-                n = len(seq.pages)
-                row[:n] = seq.pages
-                row[n:] = 0
+                buf["bts"][i] = self._block_table_array(seq.pages)
             if buf["rpens"][i] != 1.0:
                 buf["windows"][i] = self._penalty_window_row(seq)
         self._last_staging_s = clock.enter("stage") - t_stage

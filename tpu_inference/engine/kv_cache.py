@@ -61,6 +61,12 @@ class KVPages(NamedTuple):
     counts); the decode graphs emit it behind the step's tokens and
     clear it, so a prefill's counts leave with the next readback and no
     graph gains an output or a sync of its own.
+
+    A model whose layers differ in kind (``ModelConfig.layer_types``)
+    has a pool a kind: ``k`` / ``v`` are the FULL kind's, ``[n_full,
+    P_full, ...]``, and ``wk`` / ``wv`` the WINDOW kind's, ``[n_window,
+    P_window, ...]``, each with its own allocator and its own block
+    table a sequence (``KindPages``). None for a model of one kind.
     """
 
     k: jax.Array
@@ -68,6 +74,8 @@ class KVPages(NamedTuple):
     k_scale: Optional[jax.Array] = None
     v_scale: Optional[jax.Array] = None
     aux: Optional[jax.Array] = None
+    wk: Optional[jax.Array] = None
+    wv: Optional[jax.Array] = None
 
     @property
     def num_pages(self) -> int:
@@ -128,6 +136,65 @@ def write_latent(kv: KVPages, layer_idx: jax.Array, entry: jax.Array,
     return kv._replace(k=flat.reshape(L, P, pg, W))
 
 
+class KindPages(list):
+    """A sequence's pages where the model has a pool a kind: the list
+    itself is the FULL kind's block table (what ``Sequence.pages`` is
+    for every model), ``window`` the WINDOW kind's, indexed by position
+    like the other, with 0 (the trash page) where a page behind the
+    window was released."""
+
+    def __init__(self, full=(), window=()):
+        super().__init__(full)
+        self.window: List[int] = list(window)
+
+
+def window_span_pages(model_cfg: ModelConfig,
+                      engine_cfg: EngineConfig) -> int:
+    """The most window-kind pages one sequence holds: the window, the
+    tokens written before a release catches up (a prefill chunk, or the
+    decode steps granted ahead), a page of misalignment at each end."""
+    ahead = max(engine_cfg.chunk_tokens_cap,
+                engine_cfg.decode_steps_per_call
+                * max(1, engine_cfg.decode_pipeline_depth))
+    span = -(-(model_cfg.sliding_window + ahead) // engine_cfg.page_size) + 2
+    return min(span, engine_cfg.max_pages_per_seq)
+
+
+def num_window_pages(model_cfg: ModelConfig, engine_cfg: EngineConfig) -> int:
+    """Pages of the window kind's pool (0: the model has one kind):
+    ``engine_cfg.num_window_pages``, or every lane's span + trash."""
+    if "window" not in model_cfg.layer_types[:model_cfg.n_layers]:
+        return 0
+    return engine_cfg.num_window_pages or (
+        engine_cfg.max_batch_size * window_span_pages(model_cfg, engine_cfg)
+        + 1)
+
+
+def alloc_kind_pages(model_cfg: ModelConfig, engine_cfg: EngineConfig,
+                     dtype=None) -> KVPages:
+    """A pool a kind, with the model's counter vector beside them."""
+    from tpu_inference.models.registry import family_fn
+
+    if engine_cfg.kv_quant != "none":
+        raise ValueError(
+            f"{model_cfg.name}: kv_quant={engine_cfg.kv_quant!r} is not "
+            "implemented for per-kind pools; use kv_quant='none'")
+    dtype = dtype or model_cfg.dtype
+    tail = (engine_cfg.page_size, model_cfg.n_kv_heads, model_cfg.head_dim)
+
+    def pool(kind, pages):
+        shape = (len(model_cfg.kind_layers(kind)), pages) + tail
+        return jax.jit(lambda: jnp.zeros(shape, dtype))()
+
+    n_aux = family_fn(model_cfg, "n_aux_stats")
+    n_win = num_window_pages(model_cfg, engine_cfg)
+    return KVPages(
+        k=pool("full", engine_cfg.num_pages),
+        v=pool("full", engine_cfg.num_pages),
+        wk=pool("window", n_win), wv=pool("window", n_win),
+        aux=jnp.zeros((n_aux(model_cfg),), jnp.int32) if n_aux else None)
+
+
 def alloc_kv_pages(model_cfg: ModelConfig, engine_cfg: EngineConfig,
                    dtype=None, sharding=None,
                    scale_sharding=None) -> KVPages:
@@ -136,6 +203,9 @@ def alloc_kv_pages(model_cfg: ModelConfig, engine_cfg: EngineConfig,
     if model_cfg.latent_dim:
         assert sharding is None, "a latent pool is not sharded"
         return alloc_latent_pages(model_cfg, engine_cfg, dtype)
+    if model_cfg.layer_types:
+        assert sharding is None, "per-kind pools are not sharded"
+        return alloc_kind_pages(model_cfg, engine_cfg, dtype)
     shape = (model_cfg.n_kv_slots, engine_cfg.num_pages,
              engine_cfg.page_size, model_cfg.n_kv_heads, model_cfg.head_dim)
     dtype = dtype or model_cfg.dtype
@@ -300,6 +370,9 @@ class PageAllocator:
         # engine-thread writes, GIL-atomic reads from scrape threads.
         self.pages_allocated_total = 0
         self.pages_freed_total = 0
+        # The most pages ever out at once (a gauge: how full the pool
+        # has been, whenever it is scraped).
+        self.peak_in_use = 0
 
     @property
     def num_free(self) -> int:
@@ -333,6 +406,8 @@ class PageAllocator:
         for p in pages:
             self._refs[p] = 1
         self.pages_allocated_total += n
+        self.peak_in_use = max(self.peak_in_use,
+                               self.num_pages - 1 - len(self._free))
         return pages
 
     def share(self, page: int) -> int:

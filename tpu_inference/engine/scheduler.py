@@ -25,6 +25,8 @@ import threading
 import time
 from typing import Callable, Deque, Dict, List, Optional
 
+import numpy as np
+
 from tpu_inference import telemetry
 from tpu_inference.config import class_rank
 from tpu_inference.engine import kv_cache as kvc
@@ -515,7 +517,7 @@ class EngineScheduler:
         batch: List[_Pending] = []
         start_chunked: Optional[_Pending] = None
         start_adopt: Optional[_Pending] = None
-        reserved = 0
+        reserved = np.zeros(2, np.int64)      # pages a kind: [full, window]
         t_pass = self.engine.telemetry.clock.enter("admit")
         with self._lock:
             engine = self.engine
@@ -540,8 +542,10 @@ class EngineScheduler:
                 # candidate must fit on top of those already selected.
                 # reserve mode charges the worst case; optimistic the
                 # prompt footprint + headroom (engine._pages_for_admission).
-                need = self.engine._pages_for_admission(pending.seq)
-                if self.engine._free_plus_evictable() < reserved + need:
+                # A model with a pool a kind is charged in both, and waits
+                # on either (engine.admission_fits).
+                need = engine.admission_need(pending.seq)
+                if not engine.admission_fits(reserved + need):
                     break
                 # Batch-ladder pool-vs-lanes guard: growing the batch
                 # past the BASE rung must leave at least
@@ -554,8 +558,8 @@ class EngineScheduler:
                 # rung, admission keeps the legacy gate.
                 if (headroom > 0
                         and bound + len(batch) + 1 > base_rung
-                        and engine._free_plus_evictable()
-                        < reserved + need + headroom):
+                        and not engine.admission_fits(reserved + need,
+                                                      headroom)):
                     break
                 if pending.seq.adopt_kv is not None:
                     # P/D handoff adoption: no prefill dispatch — the KV
@@ -567,7 +571,7 @@ class EngineScheduler:
                     self._waiting.popleft()
                     self._callbacks[pending.seq.request_id] = pending
                     start_adopt = pending
-                    reserved += need
+                    reserved = reserved + need
                     break
                 if self._needs_chunking(pending.seq):
                     if self._prefilling is not None:
@@ -577,13 +581,13 @@ class EngineScheduler:
                     self._waiting.popleft()
                     self._callbacks[pending.seq.request_id] = pending
                     start_chunked = pending
-                    reserved += need
+                    reserved = reserved + need
                     break
                 self._waiting.popleft()
                 # Register before releasing the lock so cancel() always
                 # finds the request in _waiting or _callbacks.
                 self._callbacks[pending.seq.request_id] = pending
-                reserved += need
+                reserved = reserved + need
                 batch.append(pending)
         # Queue-wait swap-in (README "Tiered KV cache"): the head-of-
         # queue request's host-tier pages start restoring into cache-

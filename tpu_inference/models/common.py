@@ -121,21 +121,27 @@ def yarn_mscale(factor: float, mscale: float) -> float:
 
 
 def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
-               scaling=None) -> jax.Array:
+               scaling=None, rotary_dim: int = 0) -> jax.Array:
     """Rotary position embedding.
 
     x: [B, S, H, D]; positions: [B, S] int32. Uses the half-split pairing
     (first half with second half), matching HF Llama's rotate_half.
     ``scaling`` forwards to rope_frequencies (Llama-3.1 rescale).
+    ``rotary_dim`` < D turns the first ``rotary_dim`` dims of each head
+    only (paired within themselves); the rest pass through.
     """
+    if rotary_dim and rotary_dim < x.shape[-1]:
+        turned = apply_rope(x[..., :rotary_dim], positions, theta, scaling)
+        return jnp.concatenate([turned, x[..., rotary_dim:]], axis=-1)
     half = x.shape[-1] // 2
     inv_freq = rope_frequencies(x.shape[-1], theta, scaling)  # [half]
     angles = positions[..., None].astype(jnp.float32) * inv_freq  # [B,S,half]
     cos = jnp.cos(angles)[:, :, None, :]                      # [B,S,1,half]
     sin = jnp.sin(angles)[:, :, None, :]
     if isinstance(scaling, YarnScaling):
-        m = (yarn_mscale(scaling.factor, scaling.mscale)
-             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        m = scaling.attention_factor or (
+            yarn_mscale(scaling.factor, scaling.mscale)
+            / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
         cos, sin = cos * m, sin * m
     x1 = x[..., :half].astype(jnp.float32)
     x2 = x[..., half:].astype(jnp.float32)

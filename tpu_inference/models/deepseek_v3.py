@@ -196,11 +196,13 @@ def route(cfg: ModelConfig, lp: dict, x2: jax.Array):
     logits = jnp.dot(x2.astype(jnp.float32),
                      lp["w_router"].astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    scores = jax.nn.sigmoid(logits)
+    scores = (jax.nn.sigmoid(logits) if cfg.moe_scoring == "sigmoid"
+              else jax.nn.softmax(logits, axis=-1))
     _, top_idx = jax.lax.top_k(scores + lp["router_bias"][None, :],
                                cfg.n_experts_per_tok)
     gates = jnp.take_along_axis(scores, top_idx, axis=1)
-    gates = gates / (jnp.sum(gates, axis=1, keepdims=True) + 1e-20)
+    if cfg.norm_topk_prob:
+        gates = gates / (jnp.sum(gates, axis=1, keepdims=True) + 1e-20)
     return top_idx, gates * cfg.routed_scaling_factor
 
 

@@ -1319,16 +1319,24 @@ class StepCostModel:
       bytes for every context token attended (at the active kv_quant's
       per-token footprint) + KV bytes written for new positions +
       host<->device swap traffic.
+    - a model whose layers differ in kind: ``n_layers`` / ``n_heads`` /
+      ``kv_token_bytes`` are the FULL kind's, and the window kind's
+      layers (``window_layers`` of ``window_heads``, ``kv_window_token_
+      bytes`` a token) count a query's pairs at min(context, window):
+      a record's pairs are capped at window x its query positions.
     """
 
     __slots__ = ("n_params", "n_layers", "n_heads", "head_dim",
                  "weight_bytes", "kv_token_bytes", "peak_flops",
-                 "peak_hbm_bw")
+                 "peak_hbm_bw", "window", "window_layers", "window_heads",
+                 "kv_window_token_bytes")
 
     def __init__(self, *, n_params: int, n_layers: int, n_heads: int,
                  head_dim: int, weight_bytes: int, kv_token_bytes: int,
                  peak_flops: Optional[float],
-                 peak_hbm_bw: Optional[float]):
+                 peak_hbm_bw: Optional[float], window: int = 0,
+                 window_layers: int = 0, window_heads: int = 0,
+                 kv_window_token_bytes: int = 0):
         # ``n_params``: parameters a token position multiplies through
         # (all of a dense model's; a routed model's ACTIVE ones; a
         # looped stack's layers once a pass). ``n_layers``: attention
@@ -1344,6 +1352,10 @@ class StepCostModel:
         self.kv_token_bytes = int(kv_token_bytes)
         self.peak_flops = peak_flops
         self.peak_hbm_bw = peak_hbm_bw
+        self.window = int(window)
+        self.window_layers = int(window_layers)
+        self.window_heads = int(window_heads)
+        self.kv_window_token_bytes = int(kv_window_token_bytes)
 
     @classmethod
     def from_engine(cls, engine) -> "StepCostModel":
@@ -1358,24 +1370,38 @@ class StepCostModel:
         n_params = own(mcfg, True) if own else engine.n_params
         pair = family_fn(mcfg, "attn_pair_dim")
         head_dim = pair(mcfg) if pair else mcfg.head_dim
-        return cls(n_params=n_params, n_layers=mcfg.n_kv_slots,
+        kinds = {}
+        if mcfg.layer_types:
+            kinds = dict(
+                window=mcfg.sliding_window,
+                window_layers=len(mcfg.kind_layers("window")),
+                window_heads=mcfg.window_n_heads,
+                kv_window_token_bytes=autosize.kv_bytes_per_token(
+                    mcfg, ecfg.kv_quant, kind="window"))
+        return cls(n_params=n_params,
+                   n_layers=mcfg.n_kv_slots - kinds.get("window_layers", 0),
                    n_heads=mcfg.n_heads, head_dim=head_dim,
                    weight_bytes=autosize.weight_read_bytes(mcfg, ecfg.quant),
                    kv_token_bytes=autosize.kv_bytes_per_token(
-                       mcfg, ecfg.kv_quant),
+                       mcfg, ecfg.kv_quant,
+                       kind="full" if kinds else None),
                    peak_flops=chip and chip.peak_bf16_flops,
-                   peak_hbm_bw=chip and chip.hbm_bw)
+                   peak_hbm_bw=chip and chip.hbm_bw, **kinds)
 
     def flops(self, rec: tuple) -> float:
         positions = rec[4] + rec[5]          # tokens + chunk_tokens
         return (2.0 * self.n_params * positions
-                + 4.0 * self.n_layers * self.n_heads * self.head_dim
-                * rec[10])                   # kv_read_tokens
+                + 4.0 * self.head_dim
+                * (self.n_layers * self.n_heads * rec[10]  # kv_read_tokens
+                   + self.window_layers * self.window_heads
+                   * min(rec[10], self.window * positions)))
 
     def hbm_bytes(self, rec: tuple) -> float:
         positions = rec[4] + rec[5]
         return (float(self.weight_bytes) * max(1, rec[6])   # steps
                 + float(self.kv_token_bytes) * (rec[10] + positions)
+                + float(self.kv_window_token_bytes)
+                * (min(rec[10], self.window * positions) + positions)
                 + rec[11])                   # kv_swap_bytes
 
 
@@ -2429,8 +2455,46 @@ class EngineTelemetry:
                 fn=lambda: mcfg.n_kv_slots)
         kv_token_bytes = self.cost_model.kv_token_bytes
         r.gauge("tpu_inf_kv_bytes_per_token",
-                "KV pool bytes one token occupies over all slots",
-                fn=lambda: kv_token_bytes)
+                "KV pool bytes one token occupies over all slots (a "
+                "model with a pool a kind: of both kinds, while inside "
+                "the window)",
+                fn=lambda: (kv_token_bytes
+                            + self.cost_model.kv_window_token_bytes))
+        if engine.win_allocator is not None:
+            # A pool a kind (no labels: a scrape sums labels away).
+            wall = engine.win_allocator
+            wtotal = wall.num_pages - 1
+            r.gauge("tpu_inf_kv_full_pages_total",
+                    "Allocatable pages of the full-attention kind's pool",
+                    fn=lambda: total)
+            r.gauge("tpu_inf_kv_full_pages_in_use",
+                    "Pages of the full-attention kind's pool in use",
+                    fn=lambda: total - alloc.num_free)
+            r.gauge("tpu_inf_kv_window_pages_total",
+                    "Allocatable pages of the window kind's pool",
+                    fn=lambda: wtotal)
+            r.gauge("tpu_inf_kv_window_pages_in_use",
+                    "Pages of the window kind's pool in use",
+                    fn=lambda: wtotal - wall.num_free)
+            r.gauge("tpu_inf_kv_full_pages_peak",
+                    "Most pages of the full-attention kind's pool in use "
+                    "at once since boot", fn=lambda: alloc.peak_in_use)
+            r.gauge("tpu_inf_kv_window_pages_peak",
+                    "Most pages of the window kind's pool in use at once "
+                    "since boot", fn=lambda: wall.peak_in_use)
+            r.counter("tpu_inf_kv_window_pages_released_total",
+                      "Window-kind pages released behind the window "
+                      "while their sequence ran (prefill chunks and "
+                      "decode)",
+                      fn=lambda: engine.window_pages_released)
+            kv_window_bytes = self.cost_model.kv_window_token_bytes
+            r.gauge("tpu_inf_kv_full_bytes_per_token",
+                    "Full-kind pool bytes one token occupies",
+                    fn=lambda: kv_token_bytes)
+            r.gauge("tpu_inf_kv_window_bytes_per_token",
+                    "Window-kind pool bytes one token occupies (a "
+                    "sequence holds at most the window's span of them)",
+                    fn=lambda: kv_window_bytes)
         r.gauge("tpu_inf_active_sequences", "Bound decode slots",
                 fn=lambda: sum(s is not None for s in engine.slots))
         # Batch ladder (README "Batch ladder"): which compiled decode
