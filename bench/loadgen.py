@@ -5,7 +5,10 @@ request, the arrival instant of every streamed token line on this
 process's monotonic clock, relative to window open. Open loop: each
 request sleeps until its due instant and is sent whatever the server is
 doing. Closed loop: ``clients`` workers each send the next request of the
-queue when their last one ended, until the window closes.
+queue when their last one ended, until the window closes. The queue is
+made longer than any window draws (``traffic.closed_loop``); a worker that
+finds it empty before the close leaves, and the instant is kept under
+``queue`` (``run.py`` fails such a run: its load was not the cell's).
 """
 
 from __future__ import annotations
@@ -69,11 +72,13 @@ async def _sleep_until(t: float) -> None:
 async def _run(base: str, loop_kind: str, reqs: list, warm_lap_s: float,
                seconds: float, drain_s: float, clients: int,
                on_open: Optional[Callable[[], Awaitable[None]]],
-               during: Optional[Callable[[float], Awaitable[None]]]
+               during: Optional[Callable[[float], Awaitable[None]]],
+               on_close: Optional[Callable[[], Awaitable[None]]] = None
                ) -> dict:
     t_start = time.monotonic()
     t_open = t_start + warm_lap_s
     records = [_record(r) for r in reqs]
+    queue_state = None
     conn = aiohttp.TCPConnector(limit=0)
     timeout = aiohttp.ClientTimeout(total=None, sock_read=300)
     async with aiohttp.ClientSession(connector=conn,
@@ -86,13 +91,17 @@ async def _run(base: str, loop_kind: str, reqs: list, warm_lap_s: float,
                      for q, r in zip(reqs, records)]
         else:
             queue = iter(zip(reqs, records))
+            queue_state = {"drawn": 0, "queued": len(reqs), "dry_s": None}
 
             async def client():
                 while time.monotonic() < t_open + seconds:
                     try:
                         req, rec = next(queue)
                     except StopIteration:
+                        if queue_state["dry_s"] is None:
+                            queue_state["dry_s"] = time.monotonic() - t_open
                         return
+                    queue_state["drawn"] += 1
                     await _send(session, base, req, rec, t_open)
             tasks = [asyncio.create_task(client()) for _ in range(clients)]
         side = []
@@ -108,16 +117,24 @@ async def _run(base: str, loop_kind: str, reqs: list, warm_lap_s: float,
             if not t.done():
                 t.cancel()
         await asyncio.gather(*tasks, return_exceptions=True)
+        if on_close is not None:
+            # Before waiting for ``during``: what outlasts the window (a
+            # profiler still writing its trace) is no part of it.
+            side.append(asyncio.create_task(on_close()))
         side_out = await asyncio.gather(*side, return_exceptions=True)
     return {"records": records, "t_open": t_open,
-            "warm_lap_s": warm_lap_s, "side": side_out}
+            "warm_lap_s": warm_lap_s, "side": side_out,
+            "queue": queue_state}
 
 
 def run(base: str, loop_kind: str, reqs: list, warm_lap_s: float,
         seconds: float, drain_s: float = 0.0, clients: int = 0,
-        on_open=None, during=None) -> dict:
+        on_open=None, during=None, on_close=None) -> dict:
     """Run one warm lap + window. ``on_open`` (a coroutine function) runs
-    at window open, ``during(t_open)`` beside the window; their results
-    (or exceptions) come back under 'side'."""
+    at window open, ``during(t_open)`` beside the window, ``on_close`` once
+    the window (and an open loop's drain) is over, whether ``during`` has
+    returned or not; their results (or exceptions) come back under 'side'
+    in that order; a closed loop's queue count
+    ({drawn, queued, dry_s}) under 'queue'."""
     return asyncio.run(_run(base, loop_kind, reqs, warm_lap_s, seconds,
-                            drain_s, clients, on_open, during))
+                            drain_s, clients, on_open, during, on_close))

@@ -15,6 +15,9 @@ Which records COUNT:
                what is still running when it closes is cut, not failed).
                One that ended in an error inside the window is ``failed``.
 A failed request misses every limit.
+
+``in_flight_mean`` (a request's life: waiting, decoding) counts EVERY
+record for the part of it inside the window, counted or not.
 """
 
 from __future__ import annotations
@@ -94,6 +97,42 @@ def tokens_in_window(records: List[dict], seconds: float) -> int:
                if 0.0 <= t < seconds)
 
 
+def _overlap(a: float, b: float, seconds: float) -> float:
+    return max(0.0, min(b, seconds) - max(a, 0.0))
+
+
+def in_flight_mean(records: List[dict], seconds: float,
+                   phase: str) -> Optional[float]:
+    """Time average over [0, seconds) of the requests in ``phase``, from
+    EVERY record (a request that straddles the open or the close counts
+    for the part inside): 'waiting' = sent and no token yet, 'decoding' =
+    between its first and its last token. A request that was still
+    running when the window closed (no done record, no error: the
+    client cut it) held its phase to the close. With the clients between
+    two requests these close a closed loop's account: decoding + waiting
+    + turn-round = clients, and ``out_tok_s`` ~ decoding / mean gap."""
+    if phase not in ("waiting", "decoding"):
+        raise ValueError(f"in_flight_mean knows no phase {phase!r}")
+    if seconds <= 0:
+        return None
+    total = 0.0
+    for r in records:
+        if r.get("sent_s") is None:
+            continue
+        tokens = r.get("token_s") or ()
+        end = r["done_s"] if r.get("done_s") is not None \
+            else r.get("failed_s")
+        if phase == "waiting":
+            a = r["sent_s"]
+            b = tokens[0] if tokens else (seconds if end is None else end)
+        elif tokens:
+            a, b = tokens[0], seconds if end is None else tokens[-1]
+        else:
+            continue
+        total += _overlap(a, b, seconds)
+    return total / seconds
+
+
 def met_limits(rec: dict, ttft_limit_s: float, tpot_limit_s: float) -> bool:
     if not finished(rec):
         return False
@@ -127,3 +166,25 @@ def end_to_end(records: List[dict], split: Dict[str, List[dict]],
         "attempted": len(ok) + len(split["failed"]),
         "failed": len(split["failed"]),
     }
+
+
+def accounts_read(specs: List[dict], metrics_open: Dict[str, float],
+                  metrics_end: Dict[str, float]) -> Dict[str, float]:
+    """A metric file may say ``"account": {"name", "total"}``: its
+    numerator (``args.num``, a /metrics family) is one part of a
+    partition of ``total``. For each account, the percent of the total's
+    delta over the window that the given files' numerators read between
+    them: 100 when every part is read by some metric, less when the
+    program keeps a part nobody reads."""
+    def delta(family):
+        return metrics_end.get(family, 0.0) - metrics_open.get(family, 0.0)
+
+    parts: Dict[str, set] = {}
+    totals: Dict[str, str] = {}
+    for spec in specs:
+        acc = spec.get("account")
+        if acc:
+            totals[acc["name"]] = acc["total"]
+            parts.setdefault(acc["name"], set()).add(spec["args"]["num"])
+    return {name: 100.0 * sum(map(delta, nums)) / delta(totals[name])
+            for name, nums in parts.items() if delta(totals[name]) > 0}

@@ -3,6 +3,10 @@
 import metrics as M     # bench/ is on sys.path wherever a reader is loaded
 
 
+IN_FLIGHT = {"streams_decoding_mean": "decoding",
+             "clients_waiting_mean": "waiting"}
+
+
 def read(ctx, stat):
     ok, failed = ctx["ok"], ctx["failed"]
     traffic = ctx["traffic"]
@@ -10,6 +14,9 @@ def read(ctx, stat):
         return M.mean([M.ttft(r) for r in ok])
     if stat == "ttft_p90_s":
         return M.percentile([M.ttft(r) for r in ok], 90.0)
+    if stat in IN_FLIGHT:
+        return M.in_flight_mean(ctx["records"], ctx["seconds"],
+                                IN_FLIGHT[stat])
     if stat == "gen_late_p99_s":
         late = [r["sent_s"] - r["due_s"] for r in ok + failed
                 if r.get("due_s") is not None and r["sent_s"] is not None]

@@ -55,10 +55,33 @@ def say(**kv) -> None:
     print(json.dumps(kv), flush=True)
 
 
+COMPARED = {}                # every number compared, beside its limit
+
+
+def compare(**pairs) -> None:
+    """``name=[number, limit]``: said at once, and kept for the result
+    line's last key and the last lines of stderr."""
+    COMPARED.update(pairs)
+    say(compared=pairs)
+
+
 def schedule(traffic: dict, seed: int, seconds: float) -> list:
     if traffic["loop"] == "open":
         return T.open_loop(traffic, seed, seconds)
     return T.closed_loop(traffic, seed)
+
+
+def server_flags(cfg: dict, traffic: dict) -> list:
+    """The configuration's server flags, with ``--ignore-eos`` where the
+    MIX says ``"ignore_eos": true``: every answer of that mix runs to its
+    ``num_predict``. (Greedy argmax over random weights reaches the byte
+    tokenizer's EOS id in a few answers of a hundred, WHICH ones follows
+    the seed, and each vacates a lane early: the seed then changes the
+    work. The program has the switch for the whole server only.)"""
+    flags = list(cfg["serving"]["flags"])
+    if traffic.get("ignore_eos") and "--ignore-eos" not in flags:
+        flags.append("--ignore-eos")
+    return flags
 
 
 def run_window(server: Server, traffic: dict, reqs: list, seconds: float,
@@ -70,6 +93,15 @@ def run_window(server: Server, traffic: dict, reqs: list, seconds: float,
     async def on_open():
         snap["log_open"] = server.log_size()
         snap["metrics_open"] = await asyncio.to_thread(server.metrics)
+
+    async def on_close():
+        # At the close, not once the profiler has returned: a trace that
+        # takes longer to write than the rest of the window would add the
+        # seconds after the close, in which the loop idles, to every
+        # share of the loop's wall.
+        snap["metrics_end"] = await asyncio.to_thread(server.metrics)
+        snap["steps"] = await asyncio.to_thread(server.get_json,
+                                                "/debug/steps")
 
     async def profile(t_open: float):
         await asyncio.sleep(max(0.0, t_open + TRACE_AT * seconds
@@ -86,18 +118,28 @@ def run_window(server: Server, traffic: dict, reqs: list, seconds: float,
                       float(traffic["warm_lap_s"]), seconds,
                       float(traffic.get("drain_s", 0.0)),
                       int(traffic.get("clients", 0)),
-                      on_open=on_open, during=profile if trace else None)
+                      on_open=on_open, during=profile if trace else None,
+                      on_close=on_close)
     for res in out["side"]:
         if isinstance(res, BaseException):
             raise BenchFailure(f"a scrape beside the window failed: {res!r}")
     snap["compiled_in_window"] = server.compiles_since(snap["log_open"])
     snap["compiles_in_window"] = len(snap["compiled_in_window"])
-    snap["metrics_end"] = server.metrics()
-    snap["steps"] = server.get_json("/debug/steps")
     snap["profile"] = out["side"][1] if trace else None
     snap["records"] = out["records"]
     snap["t_open"] = out["t_open"]
+    snap["queue"] = out["queue"]
     return snap
+
+
+def check_queue(queue: dict) -> None:
+    """A closed loop's queue has to outlast the window: a client that
+    found it empty left, the load was lighter than the cell's, and a
+    faster program would read a LOWER ``out_tok_s``. No result then."""
+    compare(queue_drawn=[queue["drawn"], queue["queued"]])
+    if queue["dry_s"] is not None:
+        raise BenchFailure(f"closed queue ran dry at {queue['dry_s']:.1f} s:"
+                           f" {queue['drawn']} of {queue['queued']} drawn")
 
 
 def child(cmd: list, env: dict, log_path: str, timeout: float) -> list:
@@ -188,7 +230,8 @@ def main() -> int:
     shutil.rmtree(out_dir, ignore_errors=True)
     os.makedirs(out_dir)
 
-    server = Server(REPO, cfg["serving"]["flags"], out_dir)
+    flags = server_flags(cfg, traffic)
+    server = Server(REPO, flags, out_dir)
     try:
         reqs = schedule(traffic, args.seed, args.seconds)
         boot_s = server.wait_ready()
@@ -202,6 +245,8 @@ def main() -> int:
                                f"cell asks for {cell['chips']}")
         log_ready = server.log_size()
         win = run_window(server, traffic, reqs, args.seconds, bool(args.trace))
+        if win["queue"] is not None:
+            check_queue(win["queue"])
         warm_compiles = (len(server.compiles_since(log_ready))
                          - win["compiles_in_window"])
         dev = server.device()            # after load: the peak is real
@@ -229,10 +274,20 @@ def main() -> int:
                                   default=None),
                 "gap_max_s": max((g for r in split["ok"]
                                   for g in M.gaps(r)), default=None),
+                # The client's account of its requests over the window.
+                "clients_waiting_mean": M.in_flight_mean(
+                    records, args.seconds, "waiting"),
+                "streams_decoding_mean": M.in_flight_mean(
+                    records, args.seconds, "decoding"),
                 "boot_s": boot_s, "compiles_in_window":
                     win["compiles_in_window"],
                 "compiles_in_warm_lap": warm_compiles,
                 "compiled_in_window": win["compiled_in_window"][:12],
+                # A traced run: when the profiler was asked and when it
+                # had written its trace (it may outlast the window).
+                "profile_s": [win["profile"]["start_s"],
+                              win["profile"]["end_s"]]
+                if win["profile"] else None,
                 "errors": sorted({r["error"] for r in split["failed"]
                                   if r["error"]})[:5]})
     say(candidates={k: v for k, v in e2e.items()})
@@ -259,12 +314,19 @@ def main() -> int:
                "profile": win["profile"], "trace": trace, "device": device,
                "peaks": man.peaks(dev["kind"]) if dev["platform"] != "cpu"
                else None}
+        specs = []
         for m in man.metrics_of("per_layer", cell["name"]):
             spec = man.layer_metric(m["name"])
+            specs.append(spec)
             value = man.reader(spec["reader"])(ctx, **spec.get("args", {}))
             if value is not None:
                 result["metrics"][m["name"]] = {"value": value,
                                                 "unit": m["unit"]}
+        # How much of each account (the engine loop's wall) this cell's
+        # metric files read between them: said, not part of ``correct``.
+        for name, pct in M.accounts_read(specs, win["metrics_open"],
+                                         win["metrics_end"]).items():
+            compare(**{name + "_read_pct": [pct, 100.0]})
     else:
         for m in man.metrics_of("end_to_end", cell["name"]):
             if e2e.get(m["name"]) is None:
@@ -274,30 +336,36 @@ def main() -> int:
                                             "unit": m["unit"]}
 
     served_ok = e2e["attempted"] > 0 and win["compiles_in_window"] == 0
-    # A request the server counted differently than it was sent.
+    # A request the server counted differently than it was sent; under
+    # --ignore-eos an answer that ends before its length is one.
+    to_length = "--ignore-eos" in flags
     miscounted = [r["index"] for r in split["ok"]
                   if r["prompt_eval_count"] != r["prompt_tokens"]
-                  or not (1 <= (r["eval_count"] or 0) <= r["answer_tokens"])
+                  or not ((r["answer_tokens"] if to_length else 1)
+                          <= (r["eval_count"] or 0) <= r["answer_tokens"])
                   or len(r["token_s"]) not in (r["eval_count"],
                                                r["eval_count"] + 1)]
-    say(compared={"compiles_in_window": [win["compiles_in_window"], 0],
-                  "miscounted_requests": [len(miscounted), 0]})
+    compare(compiles_in_window=[win["compiles_in_window"], 0],
+            miscounted_requests=[len(miscounted), 0])
     lines = child([sys.executable, os.path.join(HERE, "parity.py"),
                    "--manifest", man.path, "--workload", cell["name"],
                    "--seeds", str(args.seed)], dict(os.environ),
                   os.path.join(out_dir, "parity.log"), 1100.0)
     *seeds, summary = lines
     for s in seeds:
-        say(compared={"logit_err_rms": [s["rms"], s["limit"]["rms"]],
-                      "logit_err_max": [s["max"], s["limit"]["max"]],
-                      "token_gap": [s["token_gap"], s["limit"]["max"]],
-                      "sizes_wrong": [s["sizes_wrong"], []],
-                      "cached_tokens": s["cached_tokens"]})
+        compare(logit_err_rms=[s["rms"], s["limit"]["rms"]],
+                logit_err_max=[s["max"], s["limit"]["max"]],
+                token_gap=[s["token_gap"], s["limit"]["max"]],
+                sizes_wrong=[s["sizes_wrong"], []],
+                cached_tokens=s["cached_tokens"])
     pdev = summary["device"]
     if (pdev["platform"], pdev["kind"]) != (dev["platform"], dev["kind"]):
         raise BenchFailure(f"parity ran on {pdev}, the server on {dev}")
     result["correct"] = bool(summary["ok"] and served_ok and not miscounted)
+    result["compared"] = COMPARED
     print(json.dumps(result), flush=True)
+    for name, pair in COMPARED.items():
+        print(f"compared {name}: {json.dumps(pair)}", file=sys.stderr)
     return 0
 
 

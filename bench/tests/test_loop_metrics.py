@@ -128,3 +128,11 @@ def test_cpu_rehearsal_prints_the_new_metrics(tmp_path):
         == got["compiles_in_window"]["value"] == 0
     assert got["boot_ready_s"]["value"] >= got["boot_warmup_s"]["value"] > 0
     assert got["queue_boundary_wait_ms"]["value"] >= 0
+    # With the rehearsal manifest's own five shares (PR 35) the copy reads
+    # all eleven phases of the loop's clock: the account is whole.
+    assert last["compared"]["loop_read_pct"] == [pytest.approx(100.0,
+                                                               abs=0.5), 100.0]
+    phases = [got[n]["value"] for n in got
+              if n.startswith("loop_") and n.endswith("_share")
+              and n != "loop_starved_share"]
+    assert len(phases) == 10 and sum(phases) <= 100.0 + 1e-6

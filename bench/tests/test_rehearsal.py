@@ -29,6 +29,7 @@ def run(*args, manifest=None, timeout=600):
      {"ttft_mean_s", "gap_p99_s", "tpot_p50_s", "setup_s"}),
     ("tiny-mistral_tiny-closed", 0, {"tpot_p50_s", "out_tok_s", "setup_s"}),
     ("tiny-mistral_tiny-chat", 1, None),
+    ("tiny-mistral_tiny-closed", 1, None),
 ])
 def test_rehearsal_result_line(cell, trace, names):
     p = run("--workload", cell, "--seed", str(2**31 + 12345), "--seconds",
@@ -45,8 +46,26 @@ def test_rehearsal_result_line(cell, trace, names):
         assert dev["busy_s"] > 0 and dev["window_s"] >= dev["busy_s"]
         assert set(last["breakdown"]) == {"device_ops", "idle_gaps"}
         assert 0 < len(last["breakdown"]["device_ops"]) <= 10
-        names = {"queue_wait_mean_ms", "decode_batch_mean", "decode_step_ms",
-                 "compiles_in_window", "ttft_p90_s"}
+        names = {"decode_batch_mean", "decode_step_ms", "compiles_in_window",
+                 "loop_enqueue_share", "loop_reap_share", "loop_other_share",
+                 "loop_idle_share", "loop_swap_share"}
+        closed_only = {"streams_decoding_mean", "clients_waiting_mean",
+                       "ttft_mean_s.batch", "ttft_p90_s.batch",
+                       "queue_wait_mean_ms.batch",
+                       "queue_boundary_wait_ms.batch",
+                       "queue_capacity_wait_ms.batch"}
+        if cell.endswith("closed"):
+            names |= closed_only
+            got = {k: last["metrics"][k]["value"] for k in closed_only}
+            # 3 clients, each waiting, decoding or turning round.
+            assert 2.5 < (got["streams_decoding_mean"]
+                          + got["clients_waiting_mean"]) <= 3.0
+            assert got["queue_wait_mean_ms.batch"] == pytest.approx(
+                got["queue_boundary_wait_ms.batch"]
+                + got["queue_capacity_wait_ms.batch"], rel=0.02)
+        else:
+            names |= {"queue_wait_mean_ms", "ttft_p90_s"}
+            assert not closed_only & set(last["metrics"])
         assert last["metrics"]["compiles_in_window"]["value"] == 0
         # No chip, no peaks: roofline shares are absent, not made up.
         assert not any(k.endswith("_roofline") for k in last["metrics"])
@@ -59,6 +78,20 @@ def test_rehearsal_result_line(cell, trace, names):
                 for line in p.stdout.splitlines() if '"compared"' in line]
     assert any("logit_err_rms" in c for c in compared), \
         "every run prints each number compared beside its limit"
+    # ... and again under the result line's last key and as the last
+    # lines of stderr.
+    assert list(last)[-1] == "compared"
+    assert {k for c in compared for k in c} == set(last["compared"])
+    tail = p.stderr.strip().splitlines()[-len(last["compared"]):]
+    assert [t.split()[1].rstrip(":") for t in tail] == list(last["compared"])
+    assert ("queue_drawn" in last["compared"]) == cell.endswith("closed")
+    if cell.endswith("closed"):
+        drawn, queued = last["compared"]["queue_drawn"]
+        assert 0 < drawn < queued == 4 * 200
+    if trace:
+        # The rehearsal manifest reads five of the eleven phases.
+        pct, whole = last["compared"]["loop_read_pct"]
+        assert 0.0 < pct <= whole == 100.0
 
 
 def test_the_real_command_refuses_without_a_chip():

@@ -123,7 +123,7 @@ def test_closed_loop_first_answers_are_staggered():
     first = [r.answer_tokens for r in reqs[:t["clients"]]]
     assert first == sorted(first) or len(set(first)) > t["clients"] // 2
     assert all(r.due_s is None for r in reqs)
-    assert len(reqs) == t["requests"]
+    assert len(reqs) == T.LAPS * t["requests"], "laps of the period"
 
 
 def test_a_traffic_file_missing_a_key_is_refused(tmp_path):
@@ -133,3 +133,37 @@ def test_a_traffic_file_missing_a_key_is_refused(tmp_path):
     p.write_text(json.dumps(bad))
     with pytest.raises(ValueError):
         T.load(str(p))
+
+
+def test_a_mix_that_ignores_eos_starts_the_server_with_the_flag():
+    """A chance EOS under random weights ends an answer early on some
+    seeds and not on others: a mix may say every answer runs to its
+    length, and the configuration's own flags are left as they are."""
+    import run
+    cfg = {"serving": {"flags": ["--model", "m", "--quant", "int8"]}}
+    assert run.server_flags(cfg, {"ignore_eos": True}) == \
+        cfg["serving"]["flags"] + ["--ignore-eos"]
+    assert run.server_flags(cfg, {}) == cfg["serving"]["flags"]
+    assert run.server_flags(cfg, {"ignore_eos": False}) == \
+        cfg["serving"]["flags"]
+    already = {"serving": {"flags": ["--model", "m", "--ignore-eos"]}}
+    assert run.server_flags(already, {"ignore_eos": True}).count(
+        "--ignore-eos") == 1
+    assert cfg["serving"]["flags"] == ["--model", "m", "--quant", "int8"]
+
+
+def test_every_closed_cell_runs_its_answers_to_their_length():
+    """The seed must not change a closed cell's work: each runs under
+    --ignore-eos, by its mix or by its configuration."""
+    import run
+    from manifest import Manifest
+    man = Manifest(os.path.join(os.path.dirname(os.path.dirname(HERE)),
+                                "BENCHMARK.json"))
+    closed = 0
+    for cell in man.data["workloads"]:
+        mix = T.load(man.traffic_path(cell))
+        if mix["loop"] == "closed":
+            closed += 1
+            assert "--ignore-eos" in run.server_flags(man.config(cell), mix), \
+                cell["name"]
+    assert closed == 4

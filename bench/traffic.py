@@ -18,6 +18,9 @@ cell comparable is fixed here, not drawn:
   with the order shuffled per seed inside blocks of 8, ``tpot_p50_s`` read
   3-4% apart between seeds and repeated to 0.4% within one).
 
+A closed loop's queue repeats that base order lap after lap
+(``closed_loop``), so that no window drains it.
+
 The seed draws the ``u_i`` and makes every prompt's bytes, so no two
 seeds send the same schedule. The program under test sees only the
 generated requests. No JAX, no program import.
@@ -34,6 +37,10 @@ from typing import List, Optional
 import numpy as np
 
 BLOCK = 8
+# Laps of a closed mix's ``requests`` made up front: the fastest cell
+# draws 1.2 laps in warm lap + window (doc-reask: 711 of a period of 600,
+# PERF.md section 2), so a program 3x as fast still finds a queue.
+LAPS = 4
 _NORMAL = statistics.NormalDist()
 # Printable bytes the server's byte tokenizer maps to one token each.
 _ALPHABET = np.frombuffer(b"abcdefghijklmnopqrstuvwxyz ,.", dtype=np.uint8)
@@ -141,18 +148,26 @@ def open_loop(traffic: dict, seed: int, seconds: float,
     return _finish(reqs, traffic, seed)
 
 
-def closed_loop(traffic: dict, seed: int) -> List[Request]:
-    """The queue the clients draw from, in order: ``requests`` pairs
-    (more than any window completes) in the fixed base order: a window
-    cuts a slice out of the stream, so which lengths fall inside it must
-    not depend on the seed (measured: with the order shuffled per seed,
+def closed_loop(traffic: dict, seed: int, laps: int = LAPS) -> List[Request]:
+    """The queue the clients draw from, in order: ``requests`` pairs in
+    the fixed base order, repeated lap after lap, so that no window can
+    drain it (a client that found it empty would leave, and a FASTER
+    program would read a lower ``out_tok_s``; ``run.py`` fails a run in
+    which that happens). ``requests`` is the period. A window cuts a
+    slice out of the stream, so which lengths fall inside it must not
+    depend on the seed (measured: with the order shuffled per seed,
     ``tpot_p50_s`` of 16 documents spread 4-5% across seeds and repeated
     to 0.1% within one). The seed makes the bytes.
     The first ``clients`` answers are cut to (j+1)/clients of their length
-    so that clients which start together do not stay in phase."""
+    so that clients which start together do not stay in phase: lap 0
+    only. Request ``i`` of a later lap takes the uncut pair of
+    ``i mod requests``, keeps ``index = i`` (the shared prompts go on
+    round-robin) and draws its bytes further along the seed's stream, so
+    a repeated pair never repeats a prompt. Everything is made here,
+    before the window: nothing is drawn or encoded inside it."""
     n, c = int(traffic["requests"]), int(traffic["clients"])
     pairs = length_pairs(traffic, n)
-    reqs = [Request(i, None, p, a, -1) for i, (p, a) in enumerate(pairs)]
+    reqs = [Request(i, None, *pairs[i % n], -1) for i in range(laps * n)]
     for j, r in enumerate(reqs[:c]):
         r.answer_tokens = max(traffic["answer"]["min"] // 2,
                               r.answer_tokens * (j + 1) // c)
