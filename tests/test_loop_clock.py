@@ -218,3 +218,37 @@ def test_scheduler_loop_is_fully_partitioned(monkeypatch):
     assert clock.dispatched_seq >= 4           # 1+ prefill, 3+ decode calls
     assert clock.observed_seq == clock.dispatched_seq
     assert 0 <= clock.starved_s <= total
+
+
+def test_every_phase_of_the_clock_is_read_by_the_benchmark_in_every_cell():
+    """The tier-1 copy of bench/tests/test_loop_account.py's first case
+    (that suite is not in the tier-1 run): each family of LOOP_FAMILIES
+    is the numerator of exactly one per-layer metric of the loop's
+    account in every cell of BENCHMARK.json, so a phase renamed, dropped
+    or added here (``deliver`` among them) cannot go unread there."""
+    import os
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    root = os.path.join(repo, manifest["paths"][0], "layer_metrics")
+
+    def spec(name):
+        for stem in (name, name.split(".")[0]):
+            path = os.path.join(root, stem + ".json")
+            if os.path.exists(path):
+                with open(path) as f:
+                    return json.load(f)
+        raise AssertionError(f"no metric file for {name}")
+
+    families = sorted(LOOP_FAMILIES.values())
+    assert len(families) == 11
+    for cell in (w["name"] for w in manifest["workloads"]):
+        specs = [spec(m["name"]) for m in manifest["per_layer"]
+                 if "workloads" not in m or cell in m["workloads"]]
+        loop = [s for s in specs
+                if s.get("account", {}).get("name") == "loop"]
+        assert all(s["account"]["total"] == "tpu_inf_loop_seconds_total"
+                   for s in loop)
+        assert sorted(s["args"]["num"] for s in loop) == families, \
+            f"{cell}: each phase once, none unread, none twice"

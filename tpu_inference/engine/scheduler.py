@@ -8,7 +8,9 @@ Design:
 - One dedicated engine thread runs the device loop (JAX dispatch blocks the
   caller, so it must stay off the asyncio event loop). The aiohttp server
   submits requests from any thread; token/finish callbacks fire on the
-  engine thread and the server trampolines them onto its event loop.
+  engine thread, the server's only queue for its event loop, and
+  ``on_delivered`` wakes that loop once a turn (the delivery contract is
+  at ``TokenCallback`` below).
 - FCFS admission with **worst-case page reservation**: a request is admitted
   only when a decode slot is free and the pool can hold its prompt plus its
   full generation budget (OOM-safe admission control, SURVEY.md §5).
@@ -32,7 +34,15 @@ from tpu_inference.config import class_rank
 from tpu_inference.engine import kv_cache as kvc
 from tpu_inference.engine.engine import InferenceEngine, Sequence
 
-# on_token(seq, token_id); on_finish(seq)
+# on_token(seq, token_id); on_finish(seq). The delivery contract: both
+# fire on the engine thread (submit's rejections on the caller's), per
+# request its tokens in order and then its finish, once. A callback may
+# only queue what it is given for another thread, because the scheduler
+# says when a delivery is over: it calls ``on_delivered`` once for all
+# that a turn of the loop handed out (a decode dispatch's tokens and the
+# finishes reaped after it; a prefill dispatch's first tokens), and
+# always before the loop next stages a dispatch, waits on the device or
+# sleeps. Nothing handed out waits across any of those.
 TokenCallback = Callable[[Sequence, int], None]
 FinishCallback = Callable[[Sequence], None]
 
@@ -50,6 +60,10 @@ class SchedulerStats:
     tokens_prefix_cached: int = 0      # prompt tokens served from KV reuse
     requests_finished: int = 0
     requests_rejected: int = 0
+    # Tokens handed to on_token, and the times on_delivered had
+    # something to post: their ratio is the tokens a wake-up carries.
+    deliver_tokens: int = 0
+    deliver_wakeups: int = 0
     step_failures: int = 0             # prefill/decode dispatch exceptions
     preemptions: int = 0               # sequences evicted for pool pressure
     batch_occupancy_sum: float = 0.0
@@ -233,6 +247,12 @@ class EngineScheduler:
         # router resumes it on a decode worker; False keeps it decoding
         # here (mixed fallback, e.g. nothing exportable).
         self.on_prefill_handoff: Optional[Callable[[Sequence], bool]] = None
+        # Delivery hook (set by the HTTP server, whose callbacks only
+        # queue): called on the engine thread when a delivery is over
+        # (the contract at TokenCallback above); posts what was handed
+        # out since its last call and returns whether there was any.
+        self.on_delivered: Optional[Callable[[], bool]] = None
+        self._unposted = False      # handed out since on_delivered ran
 
     # ---------------------------------------------- supervision plumbing
 
@@ -430,6 +450,8 @@ class EngineScheduler:
             tel.queue_boundary_wait_s.observe(boundary)
             tel.queue_capacity_wait_s.observe(capacity)
         tel.clock.enter("deliver")
+        self.stats.deliver_tokens += 1
+        self._unposted = True
         pending.on_token(seq, seq.generated[-1])
         tel.clock.enter("admit")
         if (not seq.done and seq.handoff_after_prefill
@@ -518,6 +540,9 @@ class EngineScheduler:
         start_chunked: Optional[_Pending] = None
         start_adopt: Optional[_Pending] = None
         reserved = np.zeros(2, np.int64)      # pages a kind: [full, window]
+        # What the chunk above delivered does not wait behind the
+        # prefill dispatch this pass may make.
+        self._post_deliveries()
         t_pass = self.engine.telemetry.clock.enter("admit")
         with self._lock:
             engine = self.engine
@@ -769,6 +794,7 @@ class EngineScheduler:
         with self._lock:
             self.recent.append(self._timeline(seq))
         if pending is not None:
+            self._unposted = True
             pending.on_finish(seq)
 
     def _observe_finish(self, seq: Sequence) -> None:
@@ -932,9 +958,29 @@ class EngineScheduler:
         clock.enter("deliver")
         for rid, toks in new_tokens.items():
             pending = self._callbacks.get(rid)
-            if pending is not None:
+            if pending is not None and toks:
+                self.stats.deliver_tokens += len(toks)
+                self._unposted = True
                 for tok in toks:
                     pending.on_token(pending.seq, tok)
+        clock.enter("other")
+
+    def _post_deliveries(self) -> None:
+        """A delivery is over: have ``on_delivered`` post what this
+        thread handed out since the last call (nothing: no call). The
+        loop calls this wherever it is about to stage a dispatch, wait
+        on the device or sleep, and at the end of every turn, so one
+        turn's tokens and finishes cost one wake-up and none of them
+        waits behind the device. Leaves the clock in ``other``."""
+        if not self._unposted:
+            return
+        self._unposted = False
+        if self.on_delivered is None:
+            return
+        clock = self.engine.telemetry.clock
+        clock.enter("deliver")
+        if self.on_delivered():
+            self.stats.deliver_wakeups += 1
         clock.enter("other")
 
     def _reap(self) -> None:
@@ -968,11 +1014,18 @@ class EngineScheduler:
         try:
             self._run_loop(clock)
         finally:
+            self._post_deliveries()
             clock.stop()
 
     def _run_loop(self, clock) -> None:
         engine = self.engine
+        # An empty first delivery: on_delivered learns from it that this
+        # thread posts for itself (server/http.py DeliveryOutbox).
+        self._unposted = True
         while not self._stop.is_set():
+            # The turn that just ended (decode tokens, the finishes
+            # reaped after them, an error path's) is posted here, once.
+            self._post_deliveries()
             # Work for the device exists: what the clock needs to call
             # host time with nothing in flight "starved" (and what a
             # loop_stall event reports).
@@ -998,6 +1051,9 @@ class EngineScheduler:
             engine.apply_pending_imports()
             self._admit()
             clock.enter("other")
+            # A prefill dispatch's first tokens go out together, before
+            # the decode dispatch (or the drain) that follows.
+            self._post_deliveries()
             active = engine.active_sequences()
             if not active:
                 # Flush any dispatch-ahead calls, then reap
@@ -1008,6 +1064,7 @@ class EngineScheduler:
                     # chunk (e.g. every decode lane finished mid-chunks).
                     self._poll_hybrid_prefill()
                 self._reap()
+                self._post_deliveries()
                 if self._prefilling is not None:
                     continue          # next iteration runs the next chunk
                 # Idle also when requests wait that admission turned
@@ -1030,6 +1087,7 @@ class EngineScheduler:
                 self._prefilling = None
                 self._finish(hybrid_pf.seq)
                 hybrid_pf = None
+                self._post_deliveries()
             try:
                 # Latency mode: with a near-empty batch and nothing queued
                 # or in flight, run the single-step graph so each token

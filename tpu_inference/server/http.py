@@ -33,10 +33,12 @@ distribution) are reported in a ``warnings`` list on the terminal record.
 from __future__ import annotations
 
 import asyncio
+import collections
 import datetime
 import itertools
 import json
 import random
+import threading
 import time
 import uuid
 from typing import Any, Optional
@@ -55,6 +57,51 @@ from tpu_inference.server.tokenizer import (IncrementalDecoder, StopMatcher,
 def _now_iso() -> str:
     return (datetime.datetime.now(datetime.timezone.utc)
             .strftime("%Y-%m-%dT%H:%M:%S.%f000Z"))
+
+
+class DeliveryOutbox:
+    """What other threads hand to one event loop's streams, and the
+    wake-up that carries it across.
+
+    ``put`` appends ``(stream's queue, item)`` from any thread: no
+    syscall, no ``Handle``, no GIL release. The loop, woken by ONE
+    ``call_soon_threadsafe``, drains everything appended so far into
+    the per-request ``asyncio.Queue``s, in the order it was appended
+    (one FIFO: a stream's finish never overtakes its tokens).
+
+    Who posts the wake-up: a thread that calls ``post`` says it will
+    keep calling it whenever a delivery of its own is over (an engine
+    thread, through ``EngineScheduler.on_delivered``), so from then on
+    its puts wait for its post: a turn's ~190 tokens cross in one
+    wake-up. Every other thread (a submit's rejection on the caller's
+    thread, the watchdog's failover, shutdown's force-finish, the
+    process fleet's reader) is posted for at each put, so nothing is
+    ever left in the outbox for want of a later call.
+    """
+
+    def __init__(self, loop: asyncio.AbstractEventLoop):
+        self._loop = loop
+        self._items: collections.deque = collections.deque()
+        self._thread = threading.local()    # .posts: post() was called
+
+    def put(self, queue: asyncio.Queue, item: tuple) -> None:
+        self._items.append((queue, item))
+        if not getattr(self._thread, "posts", False):
+            self._loop.call_soon_threadsafe(self._drain)
+
+    def post(self) -> bool:
+        """Wake the loop if anything is in the outbox; says whether."""
+        self._thread.posts = True
+        pending = bool(self._items)
+        if pending:
+            self._loop.call_soon_threadsafe(self._drain)
+        return pending
+
+    def _drain(self) -> None:
+        items = self._items
+        while items:
+            queue, item = items.popleft()
+            queue.put_nowait(item)
 
 
 def build_engine_group(cfg: FrameworkConfig, load_params=None,
@@ -177,6 +224,7 @@ class InferenceServer:
                                  is not None else
                                  int((time.perf_counter() - t0) * 1e9))
         self._ids = itertools.count()
+        self._outbox: Optional[DeliveryOutbox] = None   # set at startup
 
     @property
     def engine(self):
@@ -217,6 +265,13 @@ class InferenceServer:
         if self.cfg.server.warmup:
             secs = self.group.warmup()
             print(f"engine warmup: compiled all graphs in {secs:.1f}s")
+        # One outbox a running loop (tests cycle the app over loops).
+        # In-process engine threads post a turn's deliveries themselves;
+        # the subprocess fleet has no scheduler here, and its reader
+        # thread is posted for at each put.
+        self._outbox = DeliveryOutbox(asyncio.get_running_loop())
+        for sched in getattr(self.group, "schedulers", ()):
+            sched.on_delivered = self._outbox.post
         # start() before the boot prints: the subprocess fleet spawns
         # its workers here, and the prints below read worker-0 facts.
         self.group.start()
@@ -848,14 +903,14 @@ class InferenceServer:
             prompt_tokens=len(prompt_ids), max_tokens=max_tokens,
             priority_class=pcls, stream=stream)
 
-        loop = asyncio.get_running_loop()
+        outbox = self._outbox
         queue: asyncio.Queue = asyncio.Queue()
 
         def on_token(s: Sequence, tok: int) -> None:
-            loop.call_soon_threadsafe(queue.put_nowait, ("token", tok))
+            outbox.put(queue, ("token", tok))
 
         def on_finish(s: Sequence) -> None:
-            loop.call_soon_threadsafe(queue.put_nowait, ("finish", s))
+            outbox.put(queue, ("finish", s))
 
         try:
             # to_thread: under --fleet subprocess, submit does routing

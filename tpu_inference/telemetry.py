@@ -1885,7 +1885,7 @@ LOOP_FAMILIES = {
     "enqueue": "tpu_inf_loop_enqueue_seconds_total",
     # blocked in a readback of a dispatched program
     "device_wait": "tpu_inf_loop_device_wait_seconds_total",
-    # token callbacks to the HTTP side
+    # token callbacks to the HTTP side and the wake-up that carries them
     "deliver": "tpu_inf_loop_deliver_seconds_total",
     # finish: release pages/slot, histograms, spans
     "reap": "tpu_inf_loop_reap_seconds_total",
@@ -2652,6 +2652,15 @@ class EngineTelemetry:
         r.counter("tpu_inf_step_failures_total",
                   "Prefill/decode dispatch exceptions",
                   fn=lambda: stats.step_failures)
+        # How often the hand-off to the HTTP loop engages: read beside
+        # tpu_inf_loop_deliver_seconds_total, the engine thread's time in it.
+        r.counter("tpu_inf_deliver_tokens_total",
+                  "Tokens handed to a request's on_token callback",
+                  fn=lambda: stats.deliver_tokens)
+        r.counter("tpu_inf_deliver_wakeups_total",
+                  "Wake-ups of the HTTP event loop posted by the engine "
+                  "thread (one a delivery: a turn's tokens and finishes)",
+                  fn=lambda: stats.deliver_wakeups)
         r.gauge("tpu_inf_queue_depth", "Requests waiting for admission",
                 fn=lambda: len(sched._waiting))
         # Derived MFU estimate: decoded-token rate x ~2 FLOPs/param/
