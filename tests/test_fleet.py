@@ -833,6 +833,9 @@ def test_worker_profile_rpc_captures_trace(pd_fleet, tmp_path):
     assert os.path.isdir(r["dir"])
     # jax wrote a plugins/profile capture under the dir.
     assert any(os.scandir(r["dir"]))
+    # The worker's loop clock over the captured seconds rides the RPC.
+    assert 0.3 <= r["loop"]["loop_wall_s"] < 1.3
+    assert "tpu_inf_loop_starved_stage_seconds_total" in r["loop"]
 
 
 _WARMUP_COMPILE_COUNTER = """

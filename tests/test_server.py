@@ -329,6 +329,13 @@ def test_debug_requests_and_profile(server, profile_dir):
         assert any(os.scandir(profile_dir))     # trace artifacts written
         resp = await client.post("/debug/profile", json={"action": "bogus"})
         assert resp.status == 400
+        # The timed form answers with the loop clock over its seconds.
+        resp = await client.post("/debug/profile", json={"seconds": 0.2})
+        assert resp.status == 200
+        rec = await resp.json()
+        assert {"status", "dir", "seconds", "replica", "loop"} <= set(rec)
+        assert 0.2 <= rec["loop"]["loop_wall_s"] < 1.2
+        assert "tpu_inf_loop_stage_put_seconds_total" in rec["loop"]
 
     _run(server, scenario)
 

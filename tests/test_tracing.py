@@ -375,7 +375,8 @@ def test_group_health_and_stats_carry_slo(group):
 
 def _profile_events(trace_dir):
     """{event name: [its stats dicts]} of the host planes of the one
-    .xplane.pb under ``trace_dir``."""
+    .xplane.pb under ``trace_dir``; each dict also holds the event's
+    ``_line``, ``_start_ns`` and ``_end_ns``."""
     import glob
 
     from jax.profiler import ProfileData
@@ -387,7 +388,10 @@ def _profile_events(trace_dir):
             for ev in line.events:
                 if ev.name.startswith("tpu_inf/"):
                     events.setdefault(ev.name, []).append(
-                        {k: v for k, v in ev.stats})
+                        {**{k: v for k, v in ev.stats},
+                         "_line": (plane.name, line.name),
+                         "_start_ns": ev.start_ns,
+                         "_end_ns": ev.start_ns + ev.duration_ns})
     return events
 
 
@@ -419,7 +423,8 @@ def test_profile_holds_phase_and_dispatch_annotations(tmp_path):
         feeder = threading.Thread(target=lambda: [one(r) for r in (2, 3, 4)])
         out = {}
         prof = threading.Thread(target=lambda: out.update(
-            telemetry.capture_jax_profile(str(tmp_path), 0, 1.0)))
+            telemetry.capture_jax_profile(str(tmp_path), 0, 1.0,
+                                          engine.telemetry)))
         prof.start()
         time.sleep(0.2)
         feeder.start()
@@ -433,8 +438,25 @@ def test_profile_holds_phase_and_dispatch_annotations(tmp_path):
     events = _profile_events(out["dir"])
     for phase in ("stage", "enqueue", "device_wait", "deliver"):
         assert events.get("tpu_inf/" + phase), (phase, sorted(events))
-    assert set(events) <= {"tpu_inf/dispatch"} | {
+    parts = {"tpu_inf/stage/" + p for p in ("pages", "fill", "put")}
+    assert set(events) <= {"tpu_inf/dispatch"} | parts | {
         "tpu_inf/" + p for p in telemetry.LOOP_PHASES}
+    # Each marked part of stage lies inside a tpu_inf/stage visit of the
+    # same thread (``rest`` is the visit's own uncovered time).
+    assert parts <= set(events), sorted(events)
+    stages = events["tpu_inf/stage"]
+    for name in parts:
+        for ev in events[name]:
+            assert any(s["_line"] == ev["_line"]
+                       and s["_start_ns"] <= ev["_start_ns"]
+                       and ev["_end_ns"] <= s["_end_ns"] for s in stages), ev
+    # The clock's own statement about the captured second.
+    loop = out["loop"]
+    assert 1.0 <= loop["loop_wall_s"] < 2.0
+    assert loop["tpu_inf_decode_dispatches_total"] >= len(
+        [d for d in events["tpu_inf/dispatch"] if d["kind"] == "decode"]) - 1
+    assert 0 < loop["tpu_inf_loop_stage_put_seconds_total"] \
+        <= loop["tpu_inf_loop_stage_seconds_total"] + 1e-6
     dispatches = events["tpu_inf/dispatch"]
     seqs = sorted(int(d["seq"]) for d in dispatches)
     assert seqs and seqs == sorted(set(seqs))

@@ -2308,7 +2308,10 @@ class InferenceEngine:
         final chunk's sample is kept, so mid-chunk windows don't matter).
         """
         chunk = prompt[offset:offset + chunk_cap]
+        clock = self.telemetry.clock
+        clock.part("pages")
         self._window_pages_for(seq, offset, offset + len(chunk))
+        clock.part("fill")
         bucket = self.engine_cfg.bucket_for(len(chunk))
         toks = np.zeros((1, bucket), np.int32)
         toks[0, :len(chunk)] = chunk
@@ -2336,6 +2339,7 @@ class InferenceEngine:
         """Device operands for a staged chunk, in _prefill_fn order
         (tokens .. penalty window, with a fresh key) — shared by every
         dispatch site that consumes _stage_chunk_arrays."""
+        self.telemetry.clock.part("put")
         return (jnp.asarray(st["tokens"]), jnp.asarray(st["prompt_len"]),
                 jnp.asarray(st["prefix_len"]),
                 jnp.asarray(st["block_table"]), self._next_key(),
@@ -2470,7 +2474,12 @@ class InferenceEngine:
         prompt_len=1 with an all-zero block table, so their single write
         lands on the trash page and their sampled token is discarded.
         """
-        self.telemetry.clock.enter("stage")
+        clock = self.telemetry.clock
+        clock.enter("stage")
+        clock.part("pages")
+        for seq, prompt in group:
+            self._window_pages_for(seq, seq.cached_tokens, len(prompt))
+        clock.part("fill")
         p = next(s for s in self._prefill_batch_sizes if s >= len(group))
         toks = np.zeros((p, bucket), np.int32)
         plen = np.ones((p,), np.int32)
@@ -2485,7 +2494,6 @@ class InferenceEngine:
         wins = np.full((p, PENALTY_WINDOW), -1, np.int32)
         for i, (seq, prompt) in enumerate(group):
             chunk = prompt[seq.cached_tokens:]
-            self._window_pages_for(seq, seq.cached_tokens, len(prompt))
             toks[i, :len(chunk)] = chunk
             plen[i] = len(chunk)
             pref[i] = seq.cached_tokens
@@ -2500,6 +2508,7 @@ class InferenceEngine:
         self._last_decode_end = None     # prefill breaks the decode streak
         n = len(group)
         chunk_tokens = int(plen[:n].sum())
+        clock.part("put")
         args = (self.params, self.kv, jnp.asarray(toks), jnp.asarray(plen),
                 jnp.asarray(pref), jnp.asarray(bts), self._next_key(),
                 jnp.asarray(temps), jnp.asarray(top_ps),
@@ -2968,9 +2977,13 @@ class InferenceEngine:
         buffers mutate next step. Rows of freed slots go stale, which is
         benign: their ``allowed`` is 0, so the graph masks every read
         and write (writes land on the trash page) and their token is
-        discarded (-1)."""
+        discarded (-1).
+
+        Runs inside the caller's ``stage`` visit, as its ``fill`` part;
+        the step ledger's ``staging_s`` is the wall between the mark
+        that opens the part here and the one before the copies."""
         clock = self.telemetry.clock
-        t_stage = clock.enter("stage")
+        t_stage = clock.part("fill")
         if not self._stage_reuse:
             # Legacy rebuild-per-dispatch (the bubble comparison arm).
             tokens = np.zeros((rung,), np.int32)
@@ -2994,10 +3007,7 @@ class InferenceEngine:
                 rpens[i], rlasts[i] = self._penalty_arrays(seq)
                 if rpens[i] != 1.0:
                     windows[i] = self._penalty_window_row(seq)
-            # Re-entering the phase reads the clock once: the ledger's
-            # staging_s stays this function's wall, while the stage
-            # phase runs on through the caller's device_puts.
-            self._last_staging_s = clock.enter("stage") - t_stage
+            self._last_staging_s = clock.part("fill") - t_stage
             return (tokens, ctx_lens, bts, temps, top_ps, top_ks, seeds,
                     rpens, rlasts, windows)
         buf = self._stage_buffers(rung)
@@ -3025,7 +3035,7 @@ class InferenceEngine:
                 buf["bts"][i] = self._block_table_array(seq.pages)
             if buf["rpens"][i] != 1.0:
                 buf["windows"][i] = self._penalty_window_row(seq)
-        self._last_staging_s = clock.enter("stage") - t_stage
+        self._last_staging_s = clock.part("fill") - t_stage
         return (buf["tokens"].copy(), buf["ctx"].copy(), buf["bts"].copy(),
                 buf["temps"].copy(), buf["top_ps"].copy(),
                 buf["top_ks"].copy(), buf["seeds"].copy(),
@@ -3240,7 +3250,9 @@ class InferenceEngine:
         owner before being attended).
         """
         ecfg = self.engine_cfg
-        self.telemetry.clock.enter("stage")
+        clock = self.telemetry.clock
+        clock.enter("stage")
+        clock.part("pages")          # compaction, preemption, grants
         k_steps = max(1, ecfg.decode_steps_per_call)
         if max_steps is not None:
             k_steps = min(k_steps, max_steps)
@@ -3321,6 +3333,7 @@ class InferenceEngine:
             ctx_lens[seq.slot] = seq.ctx_len + ahead.get(seq.slot, 0)
             if seq.eos_token_id is not None:
                 eos_ids[seq.slot] = seq.eos_token_id
+        clock.part("put")
         tokens_d = jnp.asarray(tokens)
         window_d = jnp.asarray(windows)
         # Each continuing lane consumes the carry token (and penalty
@@ -3592,6 +3605,8 @@ class InferenceEngine:
         b = ecfg.max_batch_size       # draft spec runs single-rung (top)
         # Seeds and repetition penalties are not plumbed into spec rounds
         # (rejection sampling needs the unmodified target distribution).
+        clock = self.telemetry.clock
+        clock.enter("stage")
         (tokens, ctx_lens, bts, temps, top_ps, top_ks,
          _seeds, _rpens, _rlasts, _windows) = self._stage_batch(active_seqs,
                                                                b)
@@ -3605,6 +3620,7 @@ class InferenceEngine:
         # sampler consumes randomness at a data-dependent rate, so a
         # position-keyed stream would not reproduce anyway); spec uses the
         # engine-global key.
+        clock.part("put")
         out, dseq, t0, _ = self._run_decode(
             "spec_verify", self._spec_jit,
             (self.params, self.draft_params, self.kv, self.draft_kv,
@@ -3812,6 +3828,8 @@ class InferenceEngine:
         gamma = s_len - 1
         b = self._rung_for_slots(active_seqs)
         self._note_rung(b)
+        clock = self.telemetry.clock
+        clock.enter("stage")
         (tokens, ctx_lens, bts, temps, top_ps, top_ks,
          _seeds, rpens, rlasts, windows) = self._stage_batch(active_seqs, b)
         cap = np.zeros((b,), np.int32)
@@ -3830,6 +3848,7 @@ class InferenceEngine:
         # consumes randomness at a data-dependent rate, so a position-
         # keyed stream would not reproduce anyway); greedy — where the
         # byte-identity guarantee lives — is unaffected.
+        clock.part("put")
         out, dseq, t0, _ = self._run_decode(
             "spec_verify", self._verify_jit,
             (self.params, self.kv, jnp.asarray(tokens),

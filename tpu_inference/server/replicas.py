@@ -999,9 +999,11 @@ class EngineGroup:
     def capture_profile(self, replica: int, seconds: float) -> dict:
         """POST /debug/profile {"seconds": N}: run a jax.profiler
         capture in this process (all in-process replicas share one jax
-        runtime, so the replica argument only names the trace dir)."""
+        runtime: the replica argument names the trace dir and whose loop
+        clock the response's ``loop`` reads)."""
         return telemetry.capture_jax_profile(
-            self.server_cfg.profile_dir, replica, seconds)
+            self.server_cfg.profile_dir, replica, seconds,
+            self.engines[replica].telemetry)
 
     def stats_snapshot(self) -> dict:
         """Aggregate counters + per-replica breakdown."""

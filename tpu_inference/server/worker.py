@@ -911,7 +911,7 @@ class EngineWorker:
         from tpu_inference import telemetry
         return telemetry.capture_jax_profile(
             self.cfg.server.profile_dir, self.replica,
-            float(obj.get("seconds", 3.0)))
+            float(obj.get("seconds", 3.0)), self.engine.telemetry)
 
     def _verb_chaos(self, conn, obj, blob) -> dict:
         e = self.engine

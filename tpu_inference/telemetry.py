@@ -1108,13 +1108,20 @@ def profile_capturing() -> bool:
     return _profile_capturing
 
 
-def capture_jax_profile(profile_dir: str, replica: int,
-                        seconds: float) -> Dict[str, Any]:
+def capture_jax_profile(profile_dir: str, replica: int, seconds: float,
+                        tel: "EngineTelemetry") -> Dict[str, Any]:
     """THE jax.profiler capture body behind POST /debug/profile, shared
     by the worker's profile RPC verb and the in-process group: clamp,
     trace into a per-replica dir under the OPERATOR's profile_dir
     (never a client-chosen path), return where it landed. Serving
-    continues while the profiler runs — that is the point."""
+    continues while the profiler runs — that is the point.
+
+    ``tel`` is the traced replica's telemetry: its loop clock is read
+    right after the trace starts and right before it stops, and
+    ``"loop"`` is the delta of every ``tpu_inf_loop_*`` family and the
+    two dispatch counters over those seconds, with the seconds
+    themselves as ``loop_wall_s`` — the program's statement about the
+    interval the device trace covers (absent with telemetry off)."""
     import jax
 
     seconds = min(max(0.1, float(seconds)), 60.0)
@@ -1122,13 +1129,17 @@ def capture_jax_profile(profile_dir: str, replica: int,
     os.makedirs(trace_dir, exist_ok=True)
     jax.profiler.start_trace(trace_dir)
     set_profile_capturing(True)
+    before = tel.loop_snapshot()
     try:
         time.sleep(seconds)
     finally:
+        after = tel.loop_snapshot()
         set_profile_capturing(False)
         jax.profiler.stop_trace()
-    return {"dir": trace_dir, "seconds": seconds,
-            "replica": int(replica)}
+    out = {"dir": trace_dir, "seconds": seconds, "replica": int(replica)}
+    if after:
+        out["loop"] = {k: v - before.get(k, 0.0) for k, v in after.items()}
+    return out
 
 
 def emit_build_info(registry: Registry, *, device: Dict[str, Any],
@@ -1903,34 +1914,91 @@ LOOP_STALL_S = 1.0
 # Phases in which an empty device is not the host's doing: nothing to
 # run, or the host is itself waiting for the device.
 _NOT_STARVING = frozenset(("idle", "device_wait"))
+# The other nine: host work. Starved seconds and seconds off the CPU are
+# kept for these. phase -> the family of its starved seconds (unlabelled,
+# as the phases' own; spelled out so the README's catalog can be held to
+# them).
+STARVED_FAMILIES = {
+    "admit": "tpu_inf_loop_starved_admit_seconds_total",
+    "prefix_lookup": "tpu_inf_loop_starved_prefix_lookup_seconds_total",
+    "stage": "tpu_inf_loop_starved_stage_seconds_total",
+    "enqueue": "tpu_inf_loop_starved_enqueue_seconds_total",
+    "deliver": "tpu_inf_loop_starved_deliver_seconds_total",
+    "reap": "tpu_inf_loop_starved_reap_seconds_total",
+    "swap": "tpu_inf_loop_starved_swap_seconds_total",
+    "heartbeat": "tpu_inf_loop_starved_heartbeat_seconds_total",
+    "other": "tpu_inf_loop_starved_other_seconds_total",
+}
+HOST_PHASES = tuple(STARVED_FAMILIES)
+assert set(HOST_PHASES) == set(LOOP_PHASES) - _NOT_STARVING
+# What the off-CPU account tells apart: the thread's CPU clock is read
+# only where a phase of one class follows a phase of another.
+_CPU_CLASS = {p: 0 if p in _NOT_STARVING else 2 if p == "stage" else 1
+              for p in LOOP_PHASES}
+# part of ``stage`` -> its family: an exact partition of the stage phase,
+# marked where the work happens (``LoopClock.part``).
+STAGE_FAMILIES = {
+    # grants, window eviction, preemption: before any array is touched
+    "pages": "tpu_inf_loop_stage_pages_seconds_total",
+    # writing the host arrays of a dispatch, and their copies
+    "fill": "tpu_inf_loop_stage_fill_seconds_total",
+    # jnp.asarray / device_put of a dispatch's operands, its key, carries
+    "put": "tpu_inf_loop_stage_put_seconds_total",
+    # whatever of stage no mark covers: a hole shows as a number
+    "rest": "tpu_inf_loop_stage_rest_seconds_total",
+}
+STAGE_PARTS = tuple(STAGE_FAMILIES)
 
 
 class LoopClock:
     """Exclusive phase partition of one engine thread's wall.
 
     Driven from ``EngineScheduler.run`` and the engine's dispatch / sync
-    sites. ``enter`` costs one clock read and one float add; it returns
-    the instant, which callers use in place of clock reads of their own
-    (staging / bubble / dispatch / sync walls come from the same stamps
-    as the phases, so they cannot disagree). The clock accrues only
-    between ``start`` and ``stop`` — an engine driven directly (tests,
-    offline generate) still gets instants from ``enter`` but leaves no
-    open visit behind to grow into a false stall.
+    sites. ``enter`` costs one clock read and a few float adds; it
+    returns the instant, which callers use in place of clock reads of
+    their own (staging / bubble / dispatch / sync walls come from the
+    same stamps as the phases, so they cannot disagree). The clock
+    accrues only between ``start`` and ``stop`` — an engine driven
+    directly (tests, offline generate) still gets instants from
+    ``enter`` but leaves no open visit behind to grow into a false stall.
 
-    ``starved_s`` is the program's own statement of "the device sat idle
-    because of the host": seconds of phases other than idle/device_wait
-    entered while no dispatched program was unobserved (nothing in
-    flight) and the scheduler had work (``has_work``).
+    ``starved`` is the program's own statement of "the device sat idle
+    because of the host", by what the host was doing: seconds of each
+    phase other than idle/device_wait entered while no dispatched
+    program was unobserved (nothing in flight) and the scheduler had
+    work (``has_work``). ``starved_s`` is their sum.
+
+    ``stage_parts`` partitions the ``stage`` phase: every visit opens in
+    ``rest`` and ``part(name)`` switches (one clock read, one add), so
+    the parts sum to ``seconds["stage"]`` and unmarked work is a number.
+
+    ``host_offcpu_s`` is the wall of the nine host phases less the
+    engine thread's own CPU time in them (waiting for the GIL another
+    thread holds, or blocked in the runtime); ``stage_offcpu_s`` the
+    same for ``stage`` alone. The thread's CPU clock is a system call
+    (5-6 us on the chip's host, where the wall's read is 0.08), so it
+    is read only where a run of host phases, or of ``stage``, begins or
+    ends: about four times a loop turn.
     """
 
-    def __init__(self, now: Callable[[], float] = time.perf_counter):
+    def __init__(self, now: Callable[[], float] = time.perf_counter,
+                 thread_time: Callable[[], float] = time.thread_time):
         self._now = now
+        self._cpu_now = thread_time
         self.seconds: Dict[str, float] = dict.fromkeys(LOOP_PHASES, 0.0)
+        self.starved: Dict[str, float] = dict.fromkeys(HOST_PHASES, 0.0)
+        self.stage_parts: Dict[str, float] = dict.fromkeys(STAGE_PARTS, 0.0)
+        self.host_offcpu_s = 0.0
+        self.stage_offcpu_s = 0.0
         self.phase: Optional[str] = None      # None = not running
         self._t = 0.0
+        self._cpu_t = self._cpu = 0.0         # wall / thread CPU time at
+        #                                       the last CPU-clock read
+        self._part: Optional[str] = None      # open part; None = not in stage
+        self._pt = 0.0
         self._starving = False
         self._ann = None                      # open TraceAnnotation
-        self.starved_s = 0.0
+        self._part_ann = None                 # ... of a part, nested in it
         self.stalls = 0
         self.stall_s = 0.0
         # Set by the scheduler each iteration: a sequence is active or
@@ -1948,14 +2016,18 @@ class LoopClock:
 
     def start(self) -> float:
         self.phase, self._t = "other", self._now()
+        self._cpu_t, self._cpu = self._t, self._cpu_now()
         self._starving = False
         return self._t
 
     def stop(self) -> None:
         if self.phase is not None:
-            self.enter("other")
+            self._cpu_run("other", self.enter("other"))
             self.phase = None
         self._close_annotation()
+
+    def now(self) -> float:
+        return self._now()
 
     def enter(self, phase: str) -> float:
         now = self._now()
@@ -1965,16 +2037,34 @@ class LoopClock:
         dt = now - self._t
         self.seconds[prev] += dt
         if self._starving:
-            self.starved_s += dt
+            self.starved[prev] += dt
+        if self._part is not None:            # prev is stage
+            self.stage_parts[self._part] += now - self._pt
+        if _CPU_CLASS[prev] != _CPU_CLASS[phase]:
+            self._cpu_run(prev, now)
         if dt > LOOP_STALL_S and prev != "idle":
             self._stall(prev, dt)
         self.phase = phase
-        self._t = now
+        self._t = self._pt = now
+        self._part = "rest" if phase == "stage" else None
         self._starving = (self.has_work
                           and self.dispatched_seq <= self.observed_seq
                           and phase not in _NOT_STARVING)
         if _profile_capturing or self._ann is not None:
             self._annotate(phase)
+        return now
+
+    def part(self, name: str) -> float:
+        """Inside a ``stage`` visit: the work from here on is ``name``
+        (one of STAGE_PARTS), until the next mark or the phase's end.
+        Outside one (an engine driven directly, another phase) only the
+        time. Returns the instant, as ``enter`` does."""
+        now = self._now()
+        if self._part is not None:
+            self.stage_parts[self._part] += now - self._pt
+            self._part, self._pt = name, now
+            if _profile_capturing or self._part_ann is not None:
+                self._annotate_part(name)
         return now
 
     def dispatched(self, seq: int) -> float:
@@ -1992,7 +2082,25 @@ class LoopClock:
     def in_flight(self) -> bool:
         return self.dispatched_seq > self.observed_seq
 
+    @property
+    def starved_s(self) -> float:
+        return sum(self.starved.values())
+
     # ----------------------------------------------------------- internals
+
+    def _cpu_run(self, prev: str, now: float) -> None:
+        """A run of phases of ``prev``'s class ends at ``now``: read the
+        thread's CPU clock and, for a run of host phases, add its wall
+        less its CPU time to the off-CPU account (to ``stage``'s too
+        where the run was one). Signed, run by run: the two clocks are
+        not read at one instant, and over many runs that cancels."""
+        cpu = self._cpu_now()
+        if prev not in _NOT_STARVING:
+            off = (now - self._cpu_t) - (cpu - self._cpu)
+            self.host_offcpu_s += off
+            if prev == "stage":
+                self.stage_offcpu_s += off
+        self._cpu_t, self._cpu = now, cpu
 
     def _stall(self, phase: str, dt: float) -> None:
         self.stalls += 1
@@ -2002,7 +2110,13 @@ class LoopClock:
                   in_flight=self.in_flight, active=self.active,
                   waiting=self.waiting)
 
+    def _close_part_annotation(self) -> None:
+        ann, self._part_ann = self._part_ann, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
+
     def _close_annotation(self) -> None:
+        self._close_part_annotation()         # nested: the inner one first
         ann, self._ann = self._ann, None
         if ann is not None:
             ann.__exit__(None, None, None)
@@ -2014,40 +2128,78 @@ class LoopClock:
             self._ann = jax.profiler.TraceAnnotation("tpu_inf/" + phase)
             self._ann.__enter__()
 
+    def _annotate_part(self, name: str) -> None:
+        """``tpu_inf/stage/<part>`` inside the open ``tpu_inf/stage``
+        (a capture that began mid-visit has none open: no part either).
+        ``rest`` is the stage annotation's own uncovered time."""
+        self._close_part_annotation()
+        if _profile_capturing and self._ann is not None and name != "rest":
+            import jax.profiler
+            self._part_ann = jax.profiler.TraceAnnotation(
+                "tpu_inf/stage/" + name)
+            self._part_ann.__enter__()
+
     # ------------------------------------------------------------- export
 
     def total_s(self) -> float:
         return sum(self.seconds.values())
 
-    def register(self, registry: Registry) -> None:
-        """One family per phase + their sum + starved / stall series."""
-        for p, family in LOOP_FAMILIES.items():
-            registry.counter(
-                family,
+    def families(self) -> List[Tuple[str, str, Callable[[], float]]]:
+        """(family, help, read) of everything the clock exports: one
+        family per phase + their sum; starved seconds and their nine
+        parts; the parts of stage; off-CPU and stall series."""
+        out = [(family,
                 f"Engine-loop wall spent in phase '{p}' (exclusive "
                 "partition: the phase families sum to "
                 "tpu_inf_loop_seconds_total)",
-                fn=lambda p=p: self.seconds[p])
-        registry.counter(
+                lambda p=p: self.seconds[p])
+               for p, family in LOOP_FAMILIES.items()]
+        out.append((
             "tpu_inf_loop_seconds_total",
             "Engine-loop wall accounted by the phase clock (sum of the "
             "tpu_inf_loop_<phase>_seconds_total families)",
-            fn=self.total_s)
-        registry.counter(
+            self.total_s))
+        out.append((
             "tpu_inf_loop_starved_seconds_total",
             "Loop wall outside idle/device_wait spent while no "
             "dispatched program was in flight and a sequence was "
             "active or waiting (the device idle because of the host)",
-            fn=lambda: self.starved_s)
-        registry.counter(
+            lambda: self.starved_s))
+        out += [(family,
+                 f"The part of tpu_inf_loop_starved_seconds_total spent "
+                 f"in phase '{p}' (the nine parts sum to it)",
+                 lambda p=p: self.starved[p])
+                for p, family in STARVED_FAMILIES.items()]
+        out += [(family,
+                 f"The part of tpu_inf_loop_stage_seconds_total spent in "
+                 f"'{part}' (exclusive partition of the stage phase)",
+                 lambda part=part: self.stage_parts[part])
+                for part, family in STAGE_FAMILIES.items()]
+        out.append((
+            "tpu_inf_loop_host_offcpu_seconds_total",
+            "Wall of the nine host phases (all but idle/device_wait) "
+            "less the engine thread's own CPU time in them: waiting for "
+            "the GIL or blocked in the runtime",
+            lambda: self.host_offcpu_s))
+        out.append((
+            "tpu_inf_loop_stage_offcpu_seconds_total",
+            "The part of tpu_inf_loop_host_offcpu_seconds_total spent "
+            "in the stage phase",
+            lambda: self.stage_offcpu_s))
+        out.append((
             "tpu_inf_loop_stalls_total",
             f"Single phase visits (not idle) longer than "
             f"{LOOP_STALL_S:g}s; each also logs one loop_stall event",
-            fn=lambda: self.stalls)
-        registry.counter(
+            lambda: self.stalls))
+        out.append((
             "tpu_inf_loop_stall_seconds_total",
             "Wall of the visits counted in tpu_inf_loop_stalls_total",
-            fn=lambda: self.stall_s)
+            lambda: self.stall_s))
+        return out
+
+    def register(self, registry: Registry) -> None:
+        for family, help_, read in self.families():
+            registry.counter(family, help_, fn=read)
 
 
 class _NullClock:
@@ -2072,6 +2224,9 @@ class _NullClock:
         pass
 
     def enter(self, phase: str) -> float:
+        return time.perf_counter()
+
+    def part(self, name: str) -> float:
         return time.perf_counter()
 
     def dispatched(self, seq: int) -> float:
@@ -2747,3 +2902,19 @@ class EngineTelemetry:
             return {}
         return {key: getattr(self, attr).phase_snapshot()
                 for key, attr in PHASE_HISTOGRAMS.items()}
+
+    def loop_snapshot(self) -> Dict[str, float]:
+        """Every family of the loop clock as /metrics would render it
+        now, the decode and prefill dispatch counters, and the clock's
+        own time as ``loop_wall_s``: two of these bracket an interval
+        (capture_jax_profile). Empty when disabled. A phase visit
+        accrues when it ends, so a difference misses the visit open at
+        each edge."""
+        if not self.enabled:
+            return {}
+        snap = {family: float(read())
+                for family, _, read in self.clock.families()}
+        for counter in (self.decode_dispatches, self.prefill_dispatches):
+            snap[counter.name] = float(counter.value)
+        snap["loop_wall_s"] = self.clock.now()
+        return snap
