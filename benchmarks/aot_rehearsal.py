@@ -105,8 +105,8 @@ def main() -> int:
     from tpu_inference.config import PRESETS, EngineConfig, ParallelConfig
     from tpu_inference.engine import autosize
     from tpu_inference.engine import kv_cache as kvc
+    from tpu_inference.engine import staging
     from tpu_inference.engine.engine import InferenceEngine
-    from tpu_inference.engine.sampling import PENALTY_WINDOW
     from tpu_inference.models.quant import init_quantized_params
 
     topo = topologies.get_topology_desc(platform="tpu",
@@ -135,6 +135,8 @@ def main() -> int:
     # (a block-table row holds a table a kind where the real model has
     # a pool a kind; the one-layer stand-in has one)
     eng.bt_width = mp * (2 if kvc.num_window_pages(mcfg, ecfg) else 1)
+    eng._decode_layout = staging.decode_layout(eng.bt_width)
+    eng._prefill_layouts.clear()
 
     shapes = jax.eval_shape(
         (lambda: init_quantized_params(mcfg, 0, args.quant))
@@ -169,23 +171,16 @@ def main() -> int:
     def arr(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=small)
 
+    # What the engine hands its step programs: the base key and one
+    # packed int32 operand a dispatch (engine/staging.py).
     key = arr((2,), jnp.uint32)
-    i32, f32 = jnp.int32, jnp.float32
+    i32 = jnp.int32
 
-    def prefill_args(p, bucket):
-        return (arr((p, bucket), i32), arr((p,), i32), arr((p,), i32),
-                arr((p, eng.bt_width), i32), key, arr((p,), f32),
-                arr((p,), f32),
-                arr((p,), i32), arr((p,), i32), arr((p,), f32),
-                arr((p,), i32), arr((p, PENALTY_WINDOW), i32))
+    def prefill_operand(p, bucket):
+        return arr((p, eng._prefill_layout(bucket).width), i32)
 
-    def decode_args(b):
-        return (arr((b,), i32), arr((b,), i32),
-                arr((b, eng.bt_width), i32),
-                arr((b,), i32), arr((b,), i32), key, arr((b,), f32),
-                arr((b,), f32), arr((b,), i32), arr((b,), i32),
-                arr((b,), f32), arr((b,), i32),
-                arr((b, PENALTY_WINDOW), i32))
+    def decode_operand(b):
+        return arr((b, eng._decode_layout.width), i32)
 
     graphs = list(args.graphs)
     if graphs == ["warmup"]:
@@ -206,19 +201,19 @@ def main() -> int:
         kind, _, spec = graph.partition(":")
         if kind == "prefill":
             p, bucket = map(int, spec.split("x"))
-            return eng._prefill_jit.lower(params, kv,
-                                          *prefill_args(p, bucket))
+            return eng._prefill_jit.lower(params, kv, key,
+                                          prefill_operand(p, bucket))
         if kind == "decode":
-            return eng._decode_multi_jit.lower(params, kv,
-                                               *decode_args(int(spec)))
+            return eng._decode_multi_jit.lower(params, kv, key,
+                                               decode_operand(int(spec)))
         if kind == "decode1":
-            return eng._decode_one_jit.lower(params, kv,
-                                             *decode_args(int(spec)))
+            return eng._decode_one_jit.lower(params, kv, key,
+                                             decode_operand(int(spec)))
         if kind == "hybrid":
             bucket, b = map(int, spec.split("x"))
-            return eng._hybrid_jit.lower(params, kv,
-                                         *prefill_args(1, bucket),
-                                         *decode_args(b))
+            return eng._hybrid_jit.lower(params, kv, key,
+                                         prefill_operand(1, bucket),
+                                         decode_operand(b))
         if kind == "swap":          # the restore scatter (kv_cache.py)
             idx = arr((kvc.SWAP_CHUNK,), i32)
             data = jax.ShapeDtypeStruct(

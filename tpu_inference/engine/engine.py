@@ -35,6 +35,7 @@ import numpy as np
 from tpu_inference import telemetry
 from tpu_inference.config import EngineConfig, ModelConfig
 from tpu_inference.engine import kv_cache as kvc
+from tpu_inference.engine import staging
 from tpu_inference.engine.kv_cache import KVPages, PageAllocator
 from tpu_inference.engine.sampling import (
     PENALTY_WINDOW,
@@ -827,7 +828,20 @@ class InferenceEngine:
         # warmup() and how many graphs it ran.
         self.warmup_s = 0.0
         self.warmup_graphs = 0
-        self._base_key = jax.random.PRNGKey(seed)
+        # Step programs take their small operands as ONE packed int32
+        # array a dispatch (engine/staging.py), put where the program
+        # expects it: replicated over the mesh, or the default device.
+        self._operand_sharding = None
+        if mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec
+            self._operand_sharding = NamedSharding(mesh, PartitionSpec())
+        self._decode_layout = staging.decode_layout(self.bt_width)
+        self._prefill_layouts: Dict[int, staging.PackedLayout] = {}
+        # A dispatch's sampling key is fold_in(base key, its step
+        # number), folded INSIDE the program from the number that rides
+        # in the packed operand; the base key lives on the device.
+        self._base_key = jax.device_put(jax.random.PRNGKey(seed),
+                                        self._operand_sharding)
         self._step_count = 0
         # Batch ladder (README "Batch ladder"): the decode graphs are
         # compiled at every rung; dispatch uses the smallest rung that
@@ -862,10 +876,10 @@ class InferenceEngine:
         self._last_staging_s = 0.0
         self._last_swap_bytes_total = 0.0
         self._last_compile_event = False
-        # Host staging reuse (the per-dispatch bubble shrinker): per-rung
-        # persistent arrays, refreshed incrementally. Device hand-off
-        # always copies — jnp.asarray aliases numpy memory on CPU, and
-        # these buffers mutate next step while a dispatch may still read.
+        # Host staging reuse (the per-dispatch bubble shrinker): one
+        # persistent packed operand a rung, refreshed incrementally. The
+        # device gets a copy — device_put aliases numpy memory on CPU,
+        # and the rows mutate next step while a dispatch may still read.
         self._stage_reuse = engine_cfg.stage_host_reuse
         self._stage_bufs: Dict[int, dict] = {}
         self.slots: List[Optional[Sequence]] = [None] * engine_cfg.max_batch_size
@@ -876,12 +890,15 @@ class InferenceEngine:
         self._embed_lock = threading.Lock()
 
         k_fused = max(1, engine_cfg.decode_steps_per_call)
+        # The compiled programs are thin wrappers (_prefill_packed,
+        # _decode_packed, _hybrid_packed): unpack the operand, fold the
+        # key, call the bodies below.
         self._prefill_jit = jax.jit(
-            telemetry.named_program("tpu_inf_prefill", self._prefill_fn),
+            telemetry.named_program("tpu_inf_prefill", self._prefill_packed),
             donate_argnums=(1,))
         self._decode_multi_jit = jax.jit(
             telemetry.named_program("tpu_inf_decode_k" + str(k_fused),
-                                    self._decode_multi_fn),
+                                    self._decode_packed),
             donate_argnums=(1,))
         # Hybrid prefill-decode steps (EngineConfig.hybrid_prefill): one
         # fused dispatch advances a [1, S] prefill chunk AND the [B]
@@ -889,7 +906,7 @@ class InferenceEngine:
         # graph per prefill bucket; the decode half keeps the fused-K
         # shape, so compile count matches the serial path's.
         self._hybrid_jit = jax.jit(
-            telemetry.named_program("tpu_inf_hybrid", self._hybrid_step_fn),
+            telemetry.named_program("tpu_inf_hybrid", self._hybrid_packed),
             donate_argnums=(1,))
         # Single-step decode graph: a 1-iteration scan, so a token leaves
         # the device every step instead of every K — the scheduler's
@@ -904,8 +921,20 @@ class InferenceEngine:
             self._decode_one_jit = jax.jit(
                 telemetry.named_program(
                     "tpu_inf_decode_1",
-                    partial(self._decode_multi_fn, k_steps=1)),
+                    partial(self._decode_packed, k_steps=1)),
                 donate_argnums=(1,))
+        # Deeper than 1, the fused-K and hybrid programs take the newest
+        # in-flight call's carry (final tokens, final window) as two more
+        # operands and fold it in the graph; with nothing in flight they
+        # are handed this one, which no lane reads (``carried`` all 0).
+        # The one-step program only runs on a drained pipeline.
+        self._null_carry: Dict[int, tuple] = {}
+        if engine_cfg.decode_pipeline_depth > 1:
+            for b in ladder:
+                self._null_carry[b] = jax.device_put(
+                    (np.zeros((b,), np.int32),
+                     np.full((b, PENALTY_WINDOW), -1, np.int32)),
+                    self._operand_sharding)
         # Sequence-parallel prefill (ring attention over the sp axis) for
         # fresh full-prompt chunks on an sp>1 mesh.
         self.sp = 1 if mesh is None else int(mesh.shape.get("sp", 1))
@@ -929,7 +958,8 @@ class InferenceEngine:
             self._prefill_sp_jit = jax.jit(
                 telemetry.named_program(
                     "tpu_inf_prefill_sp",
-                    partial(self._prefill_fn, sp_mode=engine_cfg.sp_attn)),
+                    partial(self._prefill_packed,
+                            sp_mode=engine_cfg.sp_attn)),
                 donate_argnums=(1,))
 
         # Speculative decoding (BASELINE.json config 4): a draft model with
@@ -1164,6 +1194,61 @@ class InferenceEngine:
             d_rlast, d_window)
         return kv, p_tok, outs, final, final_window
 
+    # -- The programs the engine compiles: (params, kv, base_key, packed
+    # -- operand[s][, carry]) -> unpack, fold the key -> the bodies above.
+
+    def _prefill_layout(self, bucket: int) -> staging.PackedLayout:
+        layout = self._prefill_layouts.get(bucket)
+        if layout is None:
+            layout = self._prefill_layouts[bucket] = staging.prefill_layout(
+                bucket, self.bt_width)
+        return layout
+
+    def _prefill_operands(self, base_key, packed) -> tuple:
+        """_prefill_fn's operands (tokens .. window) out of a packed
+        prefill operand ``[p, width]``."""
+        f = self._prefill_layout(staging.prefill_bucket(
+            packed.shape[1], self.bt_width)).unpack(packed)
+        return (f["tokens"], f["prompt_len"], f["prefix_len"],
+                f["block_table"], jax.random.fold_in(base_key, f["step"][0]),
+                f["temp"], f["top_p"], f["top_k"], f["seed"], f["rpen"],
+                f["rlast"], f["window"])
+
+    def _decode_operands(self, base_key, packed, carry) -> tuple:
+        """_decode_multi_fn's operands (tokens .. window) out of a
+        packed decode operand ``[rung, width]``. Each ``carried`` lane
+        takes the token and penalty window of ``carry`` (the final
+        tokens and window of the newest call in flight); lanes in no
+        in-flight call keep their host-known state."""
+        f = self._decode_layout.unpack(packed)
+        tokens, window = f["tokens"], f["windows"]
+        if carry is not None:
+            carried = f["carried"] != 0
+            tokens = jnp.where(carried, carry[0], tokens)
+            window = jnp.where(carried[:, None], carry[1], window)
+        return (tokens, f["ctx"], f["bts"], f["allowed"], f["eos_ids"],
+                jax.random.fold_in(base_key, f["step"][0]), f["temps"],
+                f["top_ps"], f["top_ks"], f["seeds"], f["rpens"],
+                f["rlasts"], window)
+
+    def _prefill_packed(self, params, kv: KVPages, base_key, packed,
+                        sp_mode=None):
+        return self._prefill_fn(
+            params, kv, *self._prefill_operands(base_key, packed),
+            sp_mode=sp_mode)
+
+    def _decode_packed(self, params, kv: KVPages, base_key, packed,
+                       carry=None, k_steps: Optional[int] = None):
+        return self._decode_multi_fn(
+            params, kv, *self._decode_operands(base_key, packed, carry),
+            k_steps=k_steps)
+
+    def _hybrid_packed(self, params, kv: KVPages, base_key, p_packed,
+                       d_packed, carry=None):
+        return self._hybrid_step_fn(
+            params, kv, *self._prefill_operands(base_key, p_packed),
+            *self._decode_operands(base_key, d_packed, carry))
+
     # ------------------------------------------------------------------
     # Host-side orchestration
     # ------------------------------------------------------------------
@@ -1244,52 +1329,50 @@ class InferenceEngine:
         warm_decode = self.role != "prefill"
         prefill_batch_sizes = (self._prefill_batch_sizes if warm_prefill
                                else ())
+        def operand(layout, lanes):
+            """A warm-up operand: every lane at its layout's defaults
+            (nothing allowed, or a 1-token prompt on the trash page),
+            with the next step number."""
+            host = layout.blank(lanes)
+            layout.views(host)["step"][:] = self._next_step()
+            return self._put_operands(host)[0]
+
         for p in prefill_batch_sizes:
-            bt = jnp.zeros((p, self.bt_width), jnp.int32)
-            one = jnp.ones((p,), jnp.int32)
-            zero = jnp.zeros((p,), jnp.int32)
-            tz = jnp.zeros((p,), jnp.float32)
-            tp = jnp.ones((p,), jnp.float32)
-            tk = jnp.zeros((p,), jnp.int32)
-            sd = jnp.full((p,), -1, jnp.int32)
-            rp = jnp.ones((p,), jnp.float32)
-            rl = jnp.zeros((p,), jnp.int32)
-            win = jnp.full((p, PENALTY_WINDOW), -1, jnp.int32)
             for bucket in ecfg.prefill_buckets:
                 if bucket > ecfg.max_context:
                     continue
-                toks = jnp.zeros((p, bucket), jnp.int32)
+                layout = self._prefill_layout(bucket)
                 shape = f"{p}x{bucket}"
                 self.kv, _, _ = run(
-                    f"prefill {shape}",
-                    self._prefill_jit, self.params, self.kv, toks, one,
-                    zero, bt, self._next_key(), tz, tp, tk, sd, rp, rl, win)
+                    f"prefill {shape}", self._prefill_jit, self.params,
+                    self.kv, self._base_key, operand(layout, p))
                 if self.sp > 1 and bucket % self.sp == 0:
                     self.kv, _, _ = run(
-                        f"prefill_sp {shape}",
-                        self._prefill_sp_jit, self.params, self.kv, toks,
-                        one, zero, bt, self._next_key(), tz, tp, tk, sd, rp,
-                        rl, win)
+                        f"prefill_sp {shape}", self._prefill_sp_jit,
+                        self.params, self.kv, self._base_key,
+                        operand(layout, p))
                 if self.spec_draft:
                     self.draft_kv = run(
                         f"draft_prefill {shape}",
                         self._draft_prefill_jit, self.draft_params,
-                        self.draft_kv, toks, one, zero, bt)
-        def decode_half_args(b):
-            """Decode-graph warmup operands (tokens .. penalty window) at
-            rung ``b`` — shared by the plain decode graphs and the hybrid
-            graphs' decode half so the two call shapes cannot drift
-            apart."""
-            return (jnp.zeros((b,), jnp.int32), jnp.zeros((b,), jnp.int32),
-                    jnp.zeros((b, self.bt_width), jnp.int32),
-                    jnp.zeros((b,), jnp.int32),
-                    jnp.full((b,), -1, jnp.int32), self._next_key(),
-                    jnp.zeros((b,), jnp.float32),
-                    jnp.ones((b,), jnp.float32),
-                    jnp.zeros((b,), jnp.int32),
-                    jnp.full((b,), -1, jnp.int32),
-                    jnp.ones((b,), jnp.float32), jnp.zeros((b,), jnp.int32),
-                    jnp.full((b, PENALTY_WINDOW), -1, jnp.int32))
+                        self.draft_kv, jnp.zeros((p, bucket), jnp.int32),
+                        jnp.ones((p,), jnp.int32),
+                        jnp.zeros((p,), jnp.int32),
+                        jnp.zeros((p, self.bt_width), jnp.int32))
+
+        def warm_carry(label, jitted, b, *operands):
+            """One decode-side graph at rung ``b``. Deeper than 1 the
+            program takes a carry: warmed on the null one, then run
+            once more on its own outputs, which is what serving hands
+            it (no second graph unless their placement differs)."""
+            carry = self._null_carry.get(b)
+            if carry is None:
+                return run(label, jitted, self.params, self.kv,
+                           self._base_key, *operands)
+            out = run(label, jitted, self.params, self.kv, self._base_key,
+                      *operands, carry)
+            return jitted(self.params, out[0], self._base_key, *operands,
+                          out[-2:])
 
         if not warm_decode:
             return self._warmup_done(t0, graphs, timeline)
@@ -1305,32 +1388,24 @@ class InferenceEngine:
                 jnp.ones((b,), jnp.float32), jnp.zeros((b,), jnp.int32))
             self.kv, self.draft_kv = out.kv, out.draft_kv
         else:
-            decodes = [self._decode_multi_jit]
-            if self._decode_one_jit is not self._decode_multi_jit:
-                # The 1-step graph is a second full decode compile, but
-                # decode_step()/decode_steps(max_steps=1) route to it
-                # regardless of latency mode — warm it whenever it's a
-                # distinct graph or a first single-step call pays a full
-                # XLA compile mid-serving (ADVICE r3).
-                decodes.append(self._decode_one_jit)
             # EVERY ladder rung compiles here: continuous batching moves
             # between rung graphs as occupancy changes, and a rung first
             # reached mid-serving must find its executable warm (the
             # mid-serving-compile failure mode ADVICE r3 flagged).
             for b in self.ladder:
-                for decode in decodes:
-                    self.kv, _, _, _ = run(f"decode b={b}", decode,
-                                           self.params, self.kv,
-                                           *decode_half_args(b))
-                if ecfg.decode_pipeline_depth > 1:
-                    # Dispatch-ahead carry folds run jnp.where at [b] /
-                    # [b, W] outside any jit — warm those tiny graphs
-                    # per rung too.
-                    carried = jnp.zeros((b,), bool)
-                    tok = jnp.zeros((b,), jnp.int32)
-                    win = jnp.full((b, PENALTY_WINDOW), -1, jnp.int32)
-                    jnp.where(carried, tok, tok)
-                    jnp.where(carried[:, None], win, win)
+                self.kv = warm_carry(
+                    f"decode b={b}", self._decode_multi_jit, b,
+                    operand(self._decode_layout, b))[0]
+                if self._decode_one_jit is not self._decode_multi_jit:
+                    # The 1-step graph is a second full decode compile,
+                    # but decode_step()/decode_steps(max_steps=1) route
+                    # to it regardless of latency mode — warm it whenever
+                    # it's a distinct graph or a first single-step call
+                    # pays a full XLA compile mid-serving (ADVICE r3).
+                    self.kv, _, _, _ = run(
+                        f"decode b={b}", self._decode_one_jit, self.params,
+                        self.kv, self._base_key,
+                        operand(self._decode_layout, b))
         if self.spec_ngram:
             # The verify-only graph compiles at EVERY ladder rung x
             # EVERY active verify width (the full γ+1 round AND the
@@ -1369,24 +1444,14 @@ class InferenceEngine:
             # reachable_buckets x rungs.
             bucket_cap = ecfg.bucket_for(
                 min(ecfg.chunk_tokens_cap, ecfg.max_context))
-            bt1 = jnp.zeros((1, self.bt_width), jnp.int32)
-            one1, zero1 = jnp.ones((1,), jnp.int32), jnp.zeros((1,), jnp.int32)
             for bucket in ecfg.prefill_buckets:
                 if bucket > ecfg.max_context or bucket > bucket_cap:
                     continue
                 for b in self.ladder:
-                    self.kv, _, _, _, _ = run(
-                        f"hybrid 1x{bucket} b={b}",
-                        self._hybrid_jit, self.params, self.kv,
-                        jnp.zeros((1, bucket), jnp.int32), one1, zero1, bt1,
-                        self._next_key(), jnp.zeros((1,), jnp.float32),
-                        jnp.ones((1,), jnp.float32),
-                        jnp.zeros((1,), jnp.int32),
-                        jnp.full((1,), -1, jnp.int32),
-                        jnp.ones((1,), jnp.float32),
-                        jnp.zeros((1,), jnp.int32),
-                        jnp.full((1, PENALTY_WINDOW), -1, jnp.int32),
-                        *decode_half_args(b))
+                    self.kv = warm_carry(
+                        f"hybrid 1x{bucket} b={b}", self._hybrid_jit, b,
+                        operand(self._prefill_layout(bucket), 1),
+                        operand(self._decode_layout, b))[0]
         return self._warmup_done(t0, graphs, timeline)
 
     def _warmup_done(self, t0: float, graphs: int,
@@ -1596,9 +1661,28 @@ class InferenceEngine:
             self.moe_stats += outs[:, -len(self.moe_stats):].sum(
                 axis=0, dtype=np.int64)
 
-    def _next_key(self) -> jax.Array:
+    def _next_step(self) -> int:
+        """The next dispatch's step number: its sampling key is
+        fold_in(base key, this), folded inside the step program."""
         self._step_count += 1
-        return jax.random.fold_in(self._base_key, self._step_count)
+        return self._step_count
+
+    def _next_key(self) -> jax.Array:
+        """The same key folded eagerly, for the programs that take their
+        operands one by one (the speculative rounds)."""
+        return jax.random.fold_in(self._base_key, self._next_step())
+
+    def _put_operands(self, *hosts: np.ndarray) -> tuple:
+        """Hand one step program its packed host operands: the one
+        place a dispatch's small operands cross to the device, one
+        transfer an array (one a dispatch; a hybrid call's two). The
+        arrays must not be written again: on the CPU the device array
+        may alias the numpy memory."""
+        tel = self.telemetry
+        tel.stage_dispatches.inc()
+        tel.stage_transfers.inc(len(hosts))
+        return tuple(jax.device_put(h, self._operand_sharding)
+                     for h in hosts)
 
     def free_slots(self) -> List[int]:
         return [i for i, s in enumerate(self.slots) if s is None]
@@ -2296,6 +2380,22 @@ class InferenceEngine:
         return (self.sp > 1 and offset == 0 and chunk_len == prompt_len
                 and bucket % self.sp == 0)
 
+    def _fill_prefill_lane(self, f: dict, i: int, seq: Sequence,
+                           chunk: List[int], offset: int) -> None:
+        """Lane ``i`` of a packed prefill operand's fields ``f``:
+        ``chunk`` of ``seq``'s prompt behind ``offset`` cached tokens.
+        First sampled token's penalty window = the prompt tail."""
+        f["tokens"][i, :len(chunk)] = chunk
+        f["prompt_len"][i] = len(chunk)
+        f["prefix_len"][i] = offset
+        f["block_table"][i] = self._block_table_array(seq.pages)
+        f["temp"][i] = seq.temperature
+        f["top_p"][i] = seq.top_p
+        f["top_k"][i], f["seed"][i] = self._sampling_arrays(seq)
+        f["rpen"][i], f["rlast"][i] = self._penalty_arrays(seq)
+        if f["rpen"][i] != 1.0:
+            f["window"][i] = self._penalty_window_row(seq)
+
     def _stage_chunk_arrays(self, seq: Sequence, prompt: List[int],
                             offset: int, chunk_cap: int) -> dict:
         """Host arrays for one prefill chunk at ``offset`` — the SINGLE
@@ -2313,40 +2413,23 @@ class InferenceEngine:
         self._window_pages_for(seq, offset, offset + len(chunk))
         clock.part("fill")
         bucket = self.engine_cfg.bucket_for(len(chunk))
-        toks = np.zeros((1, bucket), np.int32)
-        toks[0, :len(chunk)] = chunk
-        top_k, rseed = self._sampling_arrays(seq)
-        rpen, rlast = self._penalty_arrays(seq)
-        win = np.full((1, PENALTY_WINDOW), -1, np.int32)
-        if rpen != 1.0:
-            win[0] = self._penalty_window_row(seq)
-        return {
-            "seq": seq, "prompt": prompt, "chunk_tokens": len(chunk),
-            "bucket": bucket, "tokens": toks,
-            "prompt_len": np.asarray([len(chunk)], np.int32),
-            "prefix_len": np.asarray([offset], np.int32),
-            "block_table": self._block_table_array(seq.pages)[None],
-            "temp": np.asarray([seq.temperature], np.float32),
-            "top_p": np.asarray([seq.top_p], np.float32),
-            "top_k": np.asarray([top_k], np.int32),
-            "seed": np.asarray([rseed], np.int32),
-            "rpen": np.asarray([rpen], np.float32),
-            "rlast": np.asarray([rlast], np.int32),
-            "window": win,
-        }
+        layout = self._prefill_layout(bucket)
+        packed = layout.blank(1)
+        st = layout.views(packed)
+        self._fill_prefill_lane(st, 0, seq, chunk, offset)
+        # The fields stay readable by name (the draft's prefill, the
+        # ledger); the program gets ``packed``, which they are views of.
+        st.update(seq=seq, prompt=prompt, chunk_tokens=len(chunk),
+                  bucket=bucket, packed=packed)
+        return st
 
-    def _chunk_device_args(self, st: dict) -> tuple:
-        """Device operands for a staged chunk, in _prefill_fn order
-        (tokens .. penalty window, with a fresh key) — shared by every
-        dispatch site that consumes _stage_chunk_arrays."""
+    def _chunk_host_operand(self, st: dict) -> np.ndarray:
+        """A staged chunk's packed operand with a fresh step number —
+        shared by every dispatch site that consumes
+        _stage_chunk_arrays."""
         self.telemetry.clock.part("put")
-        return (jnp.asarray(st["tokens"]), jnp.asarray(st["prompt_len"]),
-                jnp.asarray(st["prefix_len"]),
-                jnp.asarray(st["block_table"]), self._next_key(),
-                jnp.asarray(st["temp"]), jnp.asarray(st["top_p"]),
-                jnp.asarray(st["top_k"]), jnp.asarray(st["seed"]),
-                jnp.asarray(st["rpen"]), jnp.asarray(st["rlast"]),
-                jnp.asarray(st["window"]))
+        st["step"][:] = self._next_step()
+        return st["packed"]
 
     def _prefill_one_chunk(self, seq: Sequence, prompt: List[int],
                            offset: int) -> Tuple[int, Any]:
@@ -2368,7 +2451,8 @@ class InferenceEngine:
         stalled = bool(self.active_sequences())
         self._last_decode_end = None     # prefill breaks the decode streak
         c = st["chunk_tokens"]
-        args = (self.params, self.kv, *self._chunk_device_args(st))
+        args = (self.params, self.kv, self._base_key,
+                *self._put_operands(self._chunk_host_operand(st)))
         (self.kv, tok, _), dseq, t0, t1 = self._run(
             "prefill_chunk", prefill, args, slots=1, chunk_tokens=c)
         if self.spec_draft:
@@ -2481,46 +2565,29 @@ class InferenceEngine:
             self._window_pages_for(seq, seq.cached_tokens, len(prompt))
         clock.part("fill")
         p = next(s for s in self._prefill_batch_sizes if s >= len(group))
-        toks = np.zeros((p, bucket), np.int32)
-        plen = np.ones((p,), np.int32)
-        pref = np.zeros((p,), np.int32)
-        bts = np.zeros((p, self.bt_width), np.int32)
-        temps = np.zeros((p,), np.float32)
-        top_ps = np.ones((p,), np.float32)
-        top_ks = np.zeros((p,), np.int32)
-        seeds = np.full((p,), -1, np.int32)
-        rpens = np.ones((p,), np.float32)
-        rlasts = np.zeros((p,), np.int32)
-        wins = np.full((p, PENALTY_WINDOW), -1, np.int32)
+        layout = self._prefill_layout(bucket)
+        packed = layout.blank(p)
+        f = layout.views(packed)
+        plen, pref = f["prompt_len"], f["prefix_len"]
         for i, (seq, prompt) in enumerate(group):
-            chunk = prompt[seq.cached_tokens:]
-            toks[i, :len(chunk)] = chunk
-            plen[i] = len(chunk)
-            pref[i] = seq.cached_tokens
-            bts[i] = self._block_table_array(seq.pages)
-            temps[i] = seq.temperature
-            top_ps[i] = seq.top_p
-            top_ks[i], seeds[i] = self._sampling_arrays(seq)
-            rpens[i], rlasts[i] = self._penalty_arrays(seq)
-            if rpens[i] != 1.0:
-                wins[i] = self._penalty_window_row(seq)
+            self._fill_prefill_lane(f, i, seq, prompt[seq.cached_tokens:],
+                                    seq.cached_tokens)
         prefill = self._prefill_sp_jit if use_sp else self._prefill_jit
         self._last_decode_end = None     # prefill breaks the decode streak
         n = len(group)
         chunk_tokens = int(plen[:n].sum())
         clock.part("put")
-        args = (self.params, self.kv, jnp.asarray(toks), jnp.asarray(plen),
-                jnp.asarray(pref), jnp.asarray(bts), self._next_key(),
-                jnp.asarray(temps), jnp.asarray(top_ps),
-                jnp.asarray(top_ks), jnp.asarray(seeds),
-                jnp.asarray(rpens), jnp.asarray(rlasts), jnp.asarray(wins))
+        f["step"][:] = self._next_step()
+        args = (self.params, self.kv, self._base_key,
+                *self._put_operands(packed))
         (self.kv, tok, _), dseq, t0, _ = self._run(
             "prefill_chunk", prefill, args, slots=n, tokens=n,
             chunk_tokens=chunk_tokens)
         if self.spec_draft:
             self.draft_kv = self._draft_prefill_jit(
-                self.draft_params, self.draft_kv, jnp.asarray(toks),
-                jnp.asarray(plen), jnp.asarray(pref), jnp.asarray(bts))
+                self.draft_params, self.draft_kv, jnp.asarray(f["tokens"]),
+                jnp.asarray(plen), jnp.asarray(pref),
+                jnp.asarray(f["block_table"]))
         toks_out, _, t_done = self._wait(dseq, lambda: np.asarray(tok))
         if self.telemetry.enabled:
             dt = t_done - t0                 # includes the token readback
@@ -2930,26 +2997,17 @@ class InferenceEngine:
             seq.slot = j
 
     def _stage_buffers(self, rung: int) -> dict:
-        """Persistent per-rung staging arrays (stage_host_reuse). Rows
-        refresh incrementally: per-dispatch fields (token, ctx) always;
-        sampling params only when the slot's occupant changes; the
-        block-table row only when its (len, evicted) key moves."""
+        """The persistent packed operand of a rung (stage_host_reuse)
+        and the views its rows are written through. Rows refresh
+        incrementally: per-dispatch fields (token, ctx) always; sampling
+        params only when the slot's occupant changes; the block-table
+        row only when its (len, evicted) key moves."""
         buf = self._stage_bufs.get(rung)
         if buf is None:
-            buf = {
-                "tokens": np.zeros((rung,), np.int32),
-                "ctx": np.zeros((rung,), np.int32),
-                "bts": np.zeros((rung, self.bt_width), np.int32),
-                "temps": np.zeros((rung,), np.float32),
-                "top_ps": np.ones((rung,), np.float32),
-                "top_ks": np.zeros((rung,), np.int32),
-                "seeds": np.full((rung,), -1, np.int32),
-                "rpens": np.ones((rung,), np.float32),
-                "rlasts": np.zeros((rung,), np.int32),
-                "windows": np.full((rung, PENALTY_WINDOW), -1, np.int32),
-                "owner": [None] * rung,
-                "bt_key": [None] * rung,
-            }
+            packed = self._decode_layout.blank(rung)
+            buf = self._decode_layout.views(packed)
+            buf.update(packed=packed, owner=[None] * rung,
+                       bt_key=[None] * rung)
             self._stage_bufs[rung] = buf
         return buf
 
@@ -2966,52 +3024,35 @@ class InferenceEngine:
                     owner[i] = None
                     buf["bt_key"][i] = None
 
-    def _stage_batch(self, active_seqs: List[Sequence], rung: int):
-        """Fill the per-slot host arrays of one decode dispatch:
-        (tokens, ctx_lens, block_tables, temps, top_ps, top_ks, seeds,
-        rpens, rlasts, windows) — [rung]-shaped ([rung, W] for windows).
+    def _stage_batch(self, active_seqs: List[Sequence], rung: int
+                     ) -> Tuple[np.ndarray, dict]:
+        """Fill the per-slot fields of one decode dispatch (tokens, ctx,
+        bts, temps, top_ps, top_ks, seeds, rpens, rlasts, windows) in a
+        packed ``[rung, width]`` operand; returns that host array and
+        the views of its fields (engine/staging.py), every other field
+        at its default.
 
-        With ``stage_host_reuse`` (default) the arrays persist across
-        dispatches and only changed rows are rewritten; the device gets
-        COPIES because jnp.asarray aliases numpy memory on CPU and the
-        buffers mutate next step. Rows of freed slots go stale, which is
-        benign: their ``allowed`` is 0, so the graph masks every read
-        and write (writes land on the trash page) and their token is
-        discarded (-1).
+        With ``stage_host_reuse`` (default) the operand persists across
+        dispatches and only changed rows are rewritten; the dispatch
+        gets ONE COPY, because device_put aliases numpy memory on CPU
+        and the rows mutate next step. Rows of freed slots go stale,
+        which is benign: their ``allowed`` is 0, so the graph masks
+        every read and write (writes land on the trash page) and their
+        token is discarded (-1). Without it the operand is rebuilt from
+        its defaults each dispatch (the bubble comparison arm).
 
         Runs inside the caller's ``stage`` visit, as its ``fill`` part;
         the step ledger's ``staging_s`` is the wall between the mark
-        that opens the part here and the one before the copies."""
+        that opens the part here and the one before the copy."""
         clock = self.telemetry.clock
         t_stage = clock.part("fill")
-        if not self._stage_reuse:
-            # Legacy rebuild-per-dispatch (the bubble comparison arm).
-            tokens = np.zeros((rung,), np.int32)
-            ctx_lens = np.zeros((rung,), np.int32)
-            bts = np.zeros((rung, self.bt_width), np.int32)
-            temps = np.zeros((rung,), np.float32)
-            top_ps = np.ones((rung,), np.float32)
-            top_ks = np.zeros((rung,), np.int32)
-            seeds = np.full((rung,), -1, np.int32)
-            rpens = np.ones((rung,), np.float32)
-            rlasts = np.zeros((rung,), np.int32)
-            windows = np.full((rung, PENALTY_WINDOW), -1, np.int32)
-            for seq in active_seqs:
-                i = seq.slot
-                tokens[i] = seq.last_token
-                ctx_lens[i] = seq.ctx_len
-                bts[i] = self._block_table_array(seq.pages)
-                temps[i] = seq.temperature
-                top_ps[i] = seq.top_p
-                top_ks[i], seeds[i] = self._sampling_arrays(seq)
-                rpens[i], rlasts[i] = self._penalty_arrays(seq)
-                if rpens[i] != 1.0:
-                    windows[i] = self._penalty_window_row(seq)
-            self._last_staging_s = clock.part("fill") - t_stage
-            return (tokens, ctx_lens, bts, temps, top_ps, top_ks, seeds,
-                    rpens, rlasts, windows)
-        buf = self._stage_buffers(rung)
-        owner, bt_key = buf["owner"], buf["bt_key"]
+        if self._stage_reuse:
+            buf = self._stage_buffers(rung)
+            owner, bt_key = buf["owner"], buf["bt_key"]
+        else:
+            packed = self._decode_layout.blank(rung)
+            buf = self._decode_layout.views(packed)
+            owner, bt_key = [None] * rung, [None] * rung
         for seq in active_seqs:
             i = seq.slot
             buf["tokens"][i] = seq.last_token
@@ -3036,11 +3077,10 @@ class InferenceEngine:
             if buf["rpens"][i] != 1.0:
                 buf["windows"][i] = self._penalty_window_row(seq)
         self._last_staging_s = clock.part("fill") - t_stage
-        return (buf["tokens"].copy(), buf["ctx"].copy(), buf["bts"].copy(),
-                buf["temps"].copy(), buf["top_ps"].copy(),
-                buf["top_ks"].copy(), buf["seeds"].copy(),
-                buf["rpens"].copy(), buf["rlasts"].copy(),
-                buf["windows"].copy())
+        if not self._stage_reuse:
+            return packed, buf
+        packed = buf["packed"].copy()
+        return packed, self._decode_layout.views(packed)
 
     # ------------------------------------------------------------------
     # The decode round: stage -> enqueue -> (later) sync -> fold, written
@@ -3203,7 +3243,8 @@ class InferenceEngine:
         stalled are covered by in-flight work."""
         self._last_decode_end = None   # prefill breaks the decode streak
         c = chunk["chunk_tokens"]
-        args = (self.params, self.kv, *self._chunk_device_args(chunk))
+        args = (self.params, self.kv, self._base_key,
+                *self._put_operands(self._chunk_host_operand(chunk)))
         (self.kv, p_tok, _), dseq, t0, t1 = self._run(
             "prefill_chunk", self._prefill_jit, args, slots=1,
             chunk_tokens=c)
@@ -3324,59 +3365,60 @@ class InferenceEngine:
         for call in self._inflight:
             b = max(b, call["rung"])
         self._note_rung(b)
-        (tokens, ctx_lens, bts, temps, top_ps, top_ks, seeds,
-         rpens, rlasts, windows) = self._stage_batch(active_seqs, b)
-        allowed = np.zeros((b,), np.int32)
-        eos_ids = np.full((b,), -1, np.int32)
+        packed, f = self._stage_batch(active_seqs, b)
+        allowed, ctx_lens = f["allowed"], f["ctx"]
         for seq in staged:
             allowed[seq.slot] = allowed_by_slot[seq.slot]
             ctx_lens[seq.slot] = seq.ctx_len + ahead.get(seq.slot, 0)
             if seq.eos_token_id is not None:
-                eos_ids[seq.slot] = seq.eos_token_id
-        clock.part("put")
-        tokens_d = jnp.asarray(tokens)
-        window_d = jnp.asarray(windows)
+                f["eos_ids"][seq.slot] = seq.eos_token_id
         # Each continuing lane consumes the carry token (and penalty
-        # window) of the NEWEST in-flight call that advanced it
-        # (oldest-to-newest fold: later calls overwrite); lanes in no
-        # in-flight call (fresh prefills) keep their host-known state.
-        for call in self._inflight:
-            if call["final"] is None:
-                continue    # chunk-only call: no decode half, no carry
-            carried = np.zeros((b,), bool)
-            for slot in call["allowed"]:
-                carried[slot] = True
-            carried_d = jnp.asarray(carried)
-            tokens_d = jnp.where(carried_d, call["final"], tokens_d)
-            window_d = jnp.where(carried_d[:, None], call["final_window"],
-                                 window_d)
-        decode_args = (
-            tokens_d, jnp.asarray(ctx_lens),
-            jnp.asarray(bts), jnp.asarray(allowed), jnp.asarray(eos_ids),
-            self._next_key(), jnp.asarray(temps), jnp.asarray(top_ps),
-            jnp.asarray(top_ks), jnp.asarray(seeds), jnp.asarray(rpens),
-            jnp.asarray(rlasts), window_d)
+        # window) of the NEWEST in-flight call that advanced it; lanes
+        # in no in-flight call (fresh prefills) keep their host-known
+        # state. The newest call with a decode half carries them all: a
+        # lane it did not advance left it as it came in, from the call
+        # before (every call in flight was staged on the ones before it,
+        # at one rung). The fold runs inside the program.
+        # k_steps == 1 runs the 1-iteration graph (one forward per
+        # visible token) instead of masking K-1 steps of the fused graph.
+        program = self._hybrid_jit if chunk is not None else (
+            self._decode_one_jit if k_steps == 1 else self._decode_multi_jit)
+        one_step = (chunk is None and k_steps == 1
+                    and ecfg.decode_steps_per_call > 1)
+        carry = ()
+        if self._null_carry and not one_step:
+            carry = (self._null_carry[b],)
+            for call in self._inflight:
+                if call["final"] is None:
+                    continue    # chunk-only call: no decode half, no carry
+                carry = ((call["final"], call["final_window"]),)
+                for slot in call["allowed"]:
+                    f["carried"][slot] = 1
+        else:
+            assert all(c["final"] is None for c in self._inflight), \
+                "a carry in flight and a program that takes none"
+        clock.part("put")
+        f["step"][:] = self._next_step()
         granted = int(allowed.sum())
         # Non-blocking dispatch: the wall _run_decode records is the
         # enqueue; the device wait surfaces in decode_sync_s at
         # _sync_oldest.
         if chunk is None:
-            # k_steps == 1 runs the 1-iteration graph (one forward per
-            # visible token) instead of masking K-1 steps of the fused
-            # graph.
-            decode = self._decode_one_jit if k_steps == 1 else \
-                self._decode_multi_jit
             (self.kv, outs, final, final_window), dseq, t0, _ = \
                 self._run_decode(
-                    "decode", decode, (self.params, self.kv, *decode_args),
+                    "decode", program,
+                    (self.params, self.kv, self._base_key,
+                     *self._put_operands(packed), *carry),
                     rung=b, slots=len(staged), tokens=granted)
             p_tok = None
         else:
+            # The decode half's step number was drawn first.
             ((self.kv, p_tok, outs, final, final_window), dseq, t0,
              dispatch_dt) = self._run_decode(
-                "hybrid", self._hybrid_jit,
-                (self.params, self.kv, *self._chunk_device_args(chunk),
-                 *decode_args),
+                "hybrid", program,
+                (self.params, self.kv, self._base_key,
+                 *self._put_operands(self._chunk_host_operand(chunk),
+                                     packed), *carry),
                 rung=b, slots=len(staged), tokens=granted,
                 chunk_tokens=chunk["chunk_tokens"])
             self.hybrid_steps_total += 1
@@ -3607,9 +3649,7 @@ class InferenceEngine:
         # (rejection sampling needs the unmodified target distribution).
         clock = self.telemetry.clock
         clock.enter("stage")
-        (tokens, ctx_lens, bts, temps, top_ps, top_ks,
-         _seeds, _rpens, _rlasts, _windows) = self._stage_batch(active_seqs,
-                                                               b)
+        _, f = self._stage_batch(active_seqs, b)
         cap = np.zeros((b,), np.int32)
         active = np.zeros((b,), bool)
         for seq in active_seqs:
@@ -3624,9 +3664,10 @@ class InferenceEngine:
         out, dseq, t0, _ = self._run_decode(
             "spec_verify", self._spec_jit,
             (self.params, self.draft_params, self.kv, self.draft_kv,
-             jnp.asarray(tokens), jnp.asarray(ctx_lens), jnp.asarray(bts),
-             jnp.asarray(cap), jnp.asarray(active), self._next_key(),
-             jnp.asarray(temps), jnp.asarray(top_ps), jnp.asarray(top_ks)),
+             jnp.asarray(f["tokens"]), jnp.asarray(f["ctx"]),
+             jnp.asarray(f["bts"]), jnp.asarray(cap), jnp.asarray(active),
+             self._next_key(), jnp.asarray(f["temps"]),
+             jnp.asarray(f["top_ps"]), jnp.asarray(f["top_ks"])),
             rung=b, slots=len(active_seqs),
             tokens=s_len * len(active_seqs))
         self.kv, self.draft_kv = out.kv, out.draft_kv
@@ -3830,8 +3871,7 @@ class InferenceEngine:
         self._note_rung(b)
         clock = self.telemetry.clock
         clock.enter("stage")
-        (tokens, ctx_lens, bts, temps, top_ps, top_ks,
-         _seeds, rpens, rlasts, windows) = self._stage_batch(active_seqs, b)
+        _, f = self._stage_batch(active_seqs, b)
         cap = np.zeros((b,), np.int32)
         act = np.zeros((b,), bool)
         drafts = np.zeros((b, gamma), np.int32)
@@ -3851,12 +3891,13 @@ class InferenceEngine:
         clock.part("put")
         out, dseq, t0, _ = self._run_decode(
             "spec_verify", self._verify_jit,
-            (self.params, self.kv, jnp.asarray(tokens),
-             jnp.asarray(ctx_lens), jnp.asarray(bts), jnp.asarray(cap),
+            (self.params, self.kv, jnp.asarray(f["tokens"]),
+             jnp.asarray(f["ctx"]), jnp.asarray(f["bts"]), jnp.asarray(cap),
              jnp.asarray(act), jnp.asarray(drafts), jnp.asarray(n_prop),
-             self._next_key(), jnp.asarray(temps), jnp.asarray(top_ps),
-             jnp.asarray(top_ks), jnp.asarray(rpens), jnp.asarray(rlasts),
-             jnp.asarray(windows)),
+             self._next_key(), jnp.asarray(f["temps"]),
+             jnp.asarray(f["top_ps"]), jnp.asarray(f["top_ks"]),
+             jnp.asarray(f["rpens"]), jnp.asarray(f["rlasts"]),
+             jnp.asarray(f["windows"])),
             rung=b, slots=len(active_seqs),
             tokens=s_len * len(active_seqs))
         self.kv = out.kv

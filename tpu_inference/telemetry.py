@@ -2416,6 +2416,7 @@ class EngineTelemetry:
             self.decode_dispatches = NULL_METRIC
             self.prefill_dispatches = NULL_METRIC
             self.hybrid_steps = NULL_METRIC
+            self.stage_dispatches = self.stage_transfers = NULL_METRIC
             self.spec_gamma_g = NULL_METRIC
             self.kv_offload_pages = NULL_METRIC
             self.kv_restore_pages = NULL_METRIC
@@ -2540,6 +2541,16 @@ class EngineTelemetry:
         self.hybrid_steps = r.counter(
             "tpu_inf_hybrid_steps_total",
             "Hybrid prefill+decode fused dispatches issued")
+        # What the put part of the stage phase is made of: read beside
+        # tpu_inf_loop_stage_put_seconds_total, the time it takes.
+        self.stage_dispatches = r.counter(
+            "tpu_inf_stage_dispatches_total",
+            "Step programs (prefill, decode, hybrid) handed packed "
+            "operands")
+        self.stage_transfers = r.counter(
+            "tpu_inf_stage_transfers_total",
+            "Host arrays put on the device as those programs' operands "
+            "(one a dispatch, two for a hybrid call)")
         if engine is not None:
             self.bind_engine(engine)
 
