@@ -80,6 +80,9 @@ def main() -> int:
     ap.add_argument("--target-ctx", type=int, default=0,
                     help="as the server's flag: what 'auto' sizes the "
                          "batch against (0: half the context cap)")
+    ap.add_argument("--batch-cap", type=int, default=32,
+                    help="the server's --batch-cap (what 'auto' may size "
+                         "the batch up to)")
     ap.add_argument("--hbm-bytes", type=float, default=16.91e9,
                     help="what the chip reports as memory_stats()"
                          "['bytes_limit'] (v5e: 16.91e9)")
@@ -117,12 +120,18 @@ def main() -> int:
         mcfg, EngineConfig(quant=args.quant, attn_backend="pallas",
                            max_pages_per_seq=mp),
         dict(max_batch_size="auto", num_pages="auto", decode_ladder="auto",
-             target_ctx=args.target_ctx, batch_cap=32, speculative=False),
+             target_ctx=args.target_ctx, batch_cap=args.batch_cap,
+             speculative=False),
         tp=args.tp, hbm_bytes=args.hbm_bytes)
 
     # The stand-in: any small engine on the Pallas backend (its
     # constructor asks jax which backend this is — answer for the chip).
-    tiny = dataclasses.replace(mcfg, n_layers=1, d_model=256, n_heads=2,
+    # (A stack whose kinds follow from its depth needs eight layers to
+    # hold every kind: its state slots and its cross kind make the
+    # stand-in's block-table row and programs the real model's.)
+    stateful = bool(kvc.num_state_slots(mcfg, ecfg))
+    tiny = dataclasses.replace(mcfg, n_layers=8 if stateful else 1,
+                               d_model=256, n_heads=2,
                                n_kv_heads=2, d_ff=256, vocab_size=512)
     real_backend = jax.default_backend
     jax.default_backend = lambda: "tpu"
@@ -134,7 +143,8 @@ def main() -> int:
     eng.model_cfg, eng.engine_cfg = mcfg, ecfg
     # (a block-table row holds a table a kind where the real model has
     # a pool a kind; the one-layer stand-in has one)
-    eng.bt_width = mp * (2 if kvc.num_window_pages(mcfg, ecfg) else 1)
+    eng.bt_width = (mp * (2 if kvc.num_window_pages(mcfg, ecfg) else 1)
+                    + stateful)
     eng._decode_layout = staging.decode_layout(eng.bt_width)
     eng._prefill_layouts.clear()
 

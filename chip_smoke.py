@@ -920,6 +920,52 @@ def _mixed_kernel_errors(cfg: dict, *, d: int = 128, kv_heads: int = 8,
     return {k: round(v, 5) for k, v in out.items()}
 
 
+def _sambay_kernel_errors(cfg: dict, *, d_inner: int = 5120, n_state: int = 16,
+                          lanes: int = 3, rows: int = 256,
+                          pairs: dict = None,
+                          interpret: bool = False) -> dict:
+    """What a SambaY stack (Phi-4-mini-flash-reasoning) adds to the
+    kernels: the selective-scan kernel at its scan width and state size
+    against a ``lax.scan`` over time in float32 (rows of a whole chunk, a
+    length that ends inside a time block, and none: its state must leave
+    as it entered), as shares of the outputs' spread; and the two GQA
+    kernels at its PAIR-head shapes (40 padded query heads on 10 heads x
+    128, ``n_rep`` 4; a window of 512 and none)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tpu_inference.kernels.selective_scan import (
+        selective_scan, selective_scan_reference)
+
+    ks = jax.random.split(jax.random.PRNGKey(cfg["seed"] + 4), 7)
+    shape = (lanes, rows, d_inner)
+    x = jax.random.normal(ks[0], shape, jnp.bfloat16)
+    dt = jax.nn.softplus(jax.random.normal(ks[1], shape) - 3.0)
+    bm = jax.random.normal(ks[2], (lanes, rows, n_state))
+    cm = jax.random.normal(ks[3], (lanes, rows, n_state))
+    a_t = -jnp.exp(jnp.log(jnp.arange(1.0, n_state + 1))[:, None]
+                   + 0.1 * jax.random.normal(ks[4], (n_state, d_inner)))
+    d_skip = 1.0 + 0.1 * jax.random.normal(ks[5], (d_inner,))
+    h0 = jax.random.normal(ks[6], (lanes, n_state, d_inner))
+    lens = jnp.asarray(([rows, rows // 2 + 3] + [0] * lanes)[:lanes],
+                       jnp.int32)
+    args = (x.astype(jnp.float32), dt, bm, cm, a_t, d_skip, h0, lens)
+    y, h = selective_scan(*args, interpret=interpret)
+    yr, hr = selective_scan_reference(*args)
+    live = (np.arange(rows)[None] < np.asarray(lens)[:, None])[..., None]
+    check(float(jnp.abs(h[-1] - h0[-1]).max()) == 0.0 or lanes < 3,
+          "a lane with no valid position changed its state")
+    out = {"scan_y": float(np.abs(np.where(live, y - yr, 0)).max()
+                           / np.std(np.asarray(yr)[:1])),
+           "scan_h": float(jnp.abs(h - hr).max() / jnp.std(hr))}
+    pairs = dict(dict(kv_heads=10, kinds=((40, 512), (40, 0)), slots=8),
+                 **(pairs or {}))
+    out.update({"pair_" + k: v for k, v in _mixed_kernel_errors(
+        cfg, interpret=interpret, **pairs).items()})
+    return {k: round(v, 6) for k, v in out.items()}
+
+
 def _latent_kernel_errors(cfg: dict, *, heads: int = 64, rank: int = 512,
                           rope: int = 64,
                           ctx=(100, 0, 5000, 8200, 0, 0, 10000, 3333),
@@ -1129,6 +1175,10 @@ def child_parity(cfg: dict) -> dict:
         check(max(res["mixed_kernel_err"].values()) <= cfg["kernel_tol"],
               f"Pallas kernels at the mixed-kind shapes vs dense float32: "
               f"{res}")
+        res["sambay_kernel_err"] = _sambay_kernel_errors(cfg)
+        check(max(res["sambay_kernel_err"].values()) <= cfg["kernel_tol"],
+              f"selective scan vs lax.scan / GQA kernels at the pair-head "
+              f"shapes vs dense float32: {res}")
         res["latent_kernel_err"] = _latent_kernel_errors(cfg)
         check(max(res["latent_kernel_err"].values())
               <= cfg["latent_kernel_tol"],

@@ -214,6 +214,22 @@ def test_mixed_kernel_check_in_interpret_mode():
     assert max(errs.values()) <= chip_smoke.SETTINGS["kernel_tol"]
 
 
+def test_sambay_kernel_check_in_interpret_mode():
+    """The smoke's SambaY check (run on the chip at Phi-4-mini-flash's:
+    a scan 5120 wide with 16 states; 40 padded query heads on 10 pair
+    heads x 128) at a small shape through the interpreter: the scan
+    kernel with a whole row, one that ends inside a block and an idle
+    one, and the GQA kernels at n_rep 4 on an odd count of heads."""
+    errs = chip_smoke._sambay_kernel_errors(
+        TINY, d_inner=1024, n_state=8, rows=32, interpret=True,
+        pairs=dict(d=32, kv_heads=5, kinds=((20, 24), (20, 0)), slots=3,
+                   lanes=4, ctx=70, rows=32))
+    assert set(errs) == {"scan_y", "scan_h", "pair_h20w24_decode",
+                         "pair_h20w24_prefill", "pair_h20w0_decode",
+                         "pair_h20w0_prefill"}
+    assert max(errs.values()) <= chip_smoke.SETTINGS["kernel_tol"]
+
+
 def test_routed_expert_check_in_interpret_mode():
     """The smoke's routed-expert check (run on the chip at Kimi-K2's
     widths, layer 1 of a 2-layer stack) at the tiny preset through the

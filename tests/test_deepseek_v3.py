@@ -129,10 +129,10 @@ def test_engine_matches_the_reference(tiny, backend):
         eng.decode_steps()
     check(hit)
     # Routing counts came out with the tokens; nothing dropped.
-    st = dict(zip(dsv3.MOE_STATS, eng.moe_stats))
+    st = dict(zip(dsv3.MOE_STATS, eng.aux_stats))
     assert st["tokens"] > 0 and st["local_pairs"] > 0
     assert st["local_pairs"] == st["computed_pairs"]
-    assert eng.moe_stats[len(dsv3.MOE_STATS):].sum() == st["local_pairs"]
+    assert eng.aux_stats[len(dsv3.MOE_STATS):].sum() == st["local_pairs"]
 
 
 # ------------------------------------------------------------------ (b)

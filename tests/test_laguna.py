@@ -148,7 +148,7 @@ def test_engine_matches_the_reference(scoring, backend):
         eng.release(s)
     assert eng.allocator.num_free == eng.engine_cfg.num_pages - 1
     assert eng.win_allocator.num_free == eng.win_allocator.num_pages - 1
-    st = dict(zip(dsv3.MOE_STATS, eng.moe_stats))
+    st = dict(zip(dsv3.MOE_STATS, eng.aux_stats))
     assert st["tokens"] > 0 and st["local_pairs"] == st["computed_pairs"] > 0
 
 
@@ -252,6 +252,30 @@ def test_the_references_bias_decides_the_held_experts():
 
 
 # ------------------------------------------------------------------ (c)
+def test_merged_row_pools_decode_what_five_dim_pools_decode():
+    """``ModelConfig.pool_rows_merged`` is a layout of a stack of kinds'
+    pools, not one family's: this stack on pools ``[slots, P, page *
+    heads, width]`` (written a page at a time in a prefill, a token at a
+    time in a decode step) samples the tokens it samples on five-dim
+    ones, over chunked prompts that release window pages."""
+    mcfg, _, weights = tiny()
+    rng = np.random.default_rng(3)
+    prompts = [[int(t) for t in rng.integers(0, 512, n)] for n in (11, 70)]
+    got = {}
+    for merged in (False, True):
+        eng = engine(dataclasses.replace(mcfg, pool_rows_merged=merged),
+                     weights)
+        assert eng.kv.wk.ndim == (4 if merged else 5)
+        seqs = [Sequence(request_id=i, prompt_tokens=p, max_new_tokens=10)
+                for i, p in enumerate(prompts)]
+        for s in seqs:
+            eng.prefill(s)
+        while not all(s.done for s in seqs):
+            eng.decode_steps()
+        got[merged] = [s.generated for s in seqs]
+    assert got[True] == got[False]
+
+
 def test_window_pages_are_released_and_reused_while_full_pages_stay():
     mcfg, _, _ = tiny()
     eng = engine(mcfg, num_window_pages=3 * 8 + 1)      # three lanes' spans
@@ -321,7 +345,7 @@ def test_exhaustion_of_either_kind_makes_admission_wait(short):
     seqs = [Sequence(request_id=i, max_new_tokens=20, prompt_tokens=[
         int(t) for t in rng.integers(0, 512, 70)]) for i in range(2)]
     need = eng.admission_need(seqs[0])
-    assert list(need) == [-(-90 // 4), 8]
+    assert list(need) == [-(-90 // 4), 8, 0]       # (no state slots)
     assert eng.can_ever_admit(seqs[0])
     overlapped, finished = [], threading.Event()
 

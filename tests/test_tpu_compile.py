@@ -313,3 +313,25 @@ def test_grouped_experts_compile_for_v5e_without_a_weight_copy(
         re.M) if m[0] in one_layer and m[1] not in (
             "parameter", "get-tuple-element", "bitcast", "tuple", "while")]
     assert made == []
+
+
+@pytest.mark.parametrize("lanes,rows", [(1, 1024), (4, 1024), (4, 64)])
+def test_selective_scan_compiles_at_the_published_width(chip, lanes, rows):
+    """The state-space layers' prefill kernel at Phi-4-mini-flash's scan
+    (5120 channels, 16 states, bf16 activations, a float32 state): slabs
+    of 8 x 128 channels, time blocks of 128 (one block where the bucket is
+    shorter), B and C rows read as aligned tiles."""
+    from tpu_inference.kernels.selective_scan import selective_scan
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    d, n = 5120, 16
+    compiled = jax.jit(selective_scan).lower(
+        sds((lanes, rows, d), jnp.bfloat16), sds((lanes, rows, d),
+                                                jnp.float32),
+        sds((lanes, rows, n), jnp.float32), sds((lanes, rows, n),
+                                                jnp.float32),
+        sds((n, d), jnp.float32), sds((d,), jnp.float32),
+        sds((lanes, n, d), jnp.float32), sds((lanes,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()

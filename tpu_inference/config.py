@@ -55,11 +55,28 @@ class YarnScaling:
     attention_factor: float = 0.0
 
 
-# Attention kinds of a stack whose layers differ (ModelConfig.layer_types):
-# "full" attends causally over the whole context, "window" over the last
-# ``sliding_window`` keys. Each kind has a KV pool of its own
-# (engine/kv_cache.py).
-LAYER_KINDS = ("full", "window")
+# Kinds of a stack whose layers differ (ModelConfig.layer_types). Three
+# hold sequence state (engine/kv_cache.py): "full" attends causally over
+# the whole context and "window" over the last ``sliding_window`` keys,
+# each with a KV pool of its own; "ssm" (a selective scan, models/sambay.py)
+# holds a fixed-size state a SEQUENCE, which a token advances. Two hold
+# none: "gmu" gates the last ssm layer's scan output of the same token,
+# "cross" attends with a query of its own over the "full" layer's keys
+# and values.
+LAYER_KINDS = ("full", "window", "ssm", "gmu", "cross")
+
+
+def sambay_layer_kinds(n_layers: int) -> tuple:
+    """SambaY's kinds, derived from the depth as the source's modeling
+    file derives them: up to the middle, even layers scan and odd ones
+    attend over the window; layer n/2 + 1 is the one full-attention
+    layer; behind it even layers are gated memory units and odd ones
+    cross-attend."""
+    mid = n_layers // 2
+    return tuple(("ssm" if l % 2 == 0 else "window") if l <= mid
+                 else "full" if l == mid + 1
+                 else ("gmu" if l % 2 == 0 else "cross")
+                 for l in range(n_layers))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -77,7 +94,8 @@ class ModelConfig:
     """
 
     name: str = "llama"
-    # "llama" | "mixtral" | "gpt2" | "deepseek_v3" | "ouro" | "laguna"
+    # "llama" | "mixtral" | "gpt2" | "deepseek_v3" | "ouro" | "laguna" |
+    # "sambay"
     family: str = "llama"
     vocab_size: int = 32000
     d_model: int = 4096
@@ -185,11 +203,67 @@ class ModelConfig:
     # output by sigmoid(x_normed . w_h) before the output projection
     # (gated attention, head-wise); "none" = no gate.
     attn_gate: str = "none"
+    # --- family "sambay" (models/sambay.py): state-space layers, one
+    # full-attention layer whose K / V the cross layers read, gated
+    # memory units, differential attention. ``layer_types`` is DERIVED
+    # from ``n_layers`` (sambay_layer_kinds), so a depth cut gives a
+    # whole small model. The scan's sizes (Mamba-1): d_inner =
+    # ssm_expand * d_model, ssm_d_state, ssm_d_conv, ssm_dt_rank (0 =
+    # ceil(d_model / 16)).
+    ssm_expand: int = 2
+    ssm_d_state: int = 16
+    ssm_d_conv: int = 4
+    ssm_dt_rank: int = 0
+    # Differential attention: the KV pool holds PAIR heads, head j of the
+    # first half beside head j of the second (models/sambay.py), so a
+    # pool entry has n_kv_heads / 2 heads of 2 x head_dim.
+    diff_attn: bool = False
+    # A KV pool is ALLOCATED with a page's (position, head) rows as one
+    # dim, ``[slots, P, page * heads, width]``: the attention kernels'
+    # own view of it. With 8 (or 4, 16) KV heads the chip lays the
+    # five-dim pool out that way by itself; with the 10 pair heads of
+    # Phi-4-mini-flash it pads the heads to 16 or transposes them behind
+    # the page dim, and copies the whole pool in front of every kernel
+    # call. Only a stack of mixed kinds reads it (engine.make_kind_attn).
+    pool_rows_merged: bool = False
     dtype: jnp.dtype = jnp.bfloat16
+
+    def __post_init__(self):
+        if self.family == "sambay":
+            object.__setattr__(self, "layer_types",
+                               sambay_layer_kinds(self.n_layers))
 
     @property
     def head_dim(self) -> int:
         return self.head_dim_override or self.d_model // self.n_heads
+
+    @property
+    def d_inner(self) -> int:
+        """Width of a state-space layer's scan."""
+        return self.ssm_expand * self.d_model
+
+    @property
+    def dt_rank(self) -> int:
+        return self.ssm_dt_rank or -(-self.d_model // 16)
+
+    @property
+    def pool_kv_heads(self) -> int:
+        """KV heads of a pool entry as stored."""
+        return self.n_kv_heads // 2 if self.diff_attn else self.n_kv_heads
+
+    @property
+    def pool_head_dim(self) -> int:
+        """Width of a pool entry's head as stored."""
+        return self.head_dim * 2 if self.diff_attn else self.head_dim
+
+    def state_bytes_per_seq(self) -> int:
+        """Bytes of per-sequence state the "ssm" layers hold (0: none):
+        float32 h [d_state, d_inner] and the conv tail [d_conv - 1,
+        d_inner] in the model dtype, a layer."""
+        n = len(self.kind_layers("ssm"))
+        return n * self.d_inner * (
+            self.ssm_d_state * 4
+            + (self.ssm_d_conv - 1) * jnp.dtype(self.dtype).itemsize)
 
     @property
     def latent_dim(self) -> int:
@@ -243,12 +317,25 @@ class ModelConfig:
             assert len(kinds) == self.n_layers, \
                 "layer_types names fewer layers than n_layers"
             assert set(kinds) <= set(LAYER_KINDS)
-            if "window" in kinds:
+            if self.family == "sambay":
+                # Whole (ssm, window) pairs up to the middle, the middle
+                # layer a scan (its output feeds the memory units), whole
+                # (gmu, cross) pairs behind the full layer.
+                assert self.n_layers >= 8 and self.n_layers % 4 == 0
+                assert self.sliding_window > 0 and self.n_kv_heads % 2 == 0
+                # models/sambay.py writes differential attention only.
+                assert self.diff_attn
+                assert self.n_rep * 2 * self.pool_kv_heads == self.n_heads
+            elif "window" in kinds:
+                assert set(kinds) <= {"full", "window"}
                 assert self.sliding_window > 0 and self.window_rope_theta > 0
                 assert self.window_n_heads > 0
                 assert self.window_n_heads % self.n_kv_heads == 0
             # One KV slot a layer, one latent-free pool a kind.
             assert self.loop_steps == 1 and not self.kv_lora_rank
+        # Pair heads and merged rows are a stack of mixed kinds' pools'.
+        assert self.layer_types or not (self.diff_attn
+                                        or self.pool_rows_merged)
         assert self.loop_steps >= 1 and 0.0 <= self.early_exit_threshold <= 1.0
         # Only the looped family's forward runs passes / output norms.
         assert self.family == "ouro" or (self.loop_steps == 1
@@ -418,6 +505,23 @@ def laguna_s_ep8() -> ModelConfig:
     )
 
 
+def phi4_mini_flash() -> ModelConfig:
+    """Phi-4-mini-flash-reasoning (microsoft; SambaY, arXiv:2507.06607)
+    whole, at every published size: 32 layers of which 9 are Mamba-1
+    scans (d_inner 5120, state 16), 8 attend over a 512-token window, one
+    over the whole context, 7 are gated memory units and 7 cross-attend
+    over that one layer's K / V; differential attention, 40 query / 20 KV
+    heads of 64; LayerNorm with bias; no positions of any kind.
+    bench/configs/phi4-mini-flash-bf16.json lists what is assumed."""
+    return ModelConfig(
+        name="phi4-mini-flash", family="sambay", vocab_size=200064,
+        d_model=2560, n_layers=32, n_heads=40, n_kv_heads=20, d_ff=10240,
+        max_seq_len=262144, rope_theta=0.0, norm_eps=1e-5,
+        tie_embeddings=True, sliding_window=512, diff_attn=True,
+        pool_rows_merged=True,
+    )
+
+
 def gpt2_small() -> ModelConfig:
     return ModelConfig(
         name="gpt2", family="gpt2", vocab_size=50257, d_model=768,
@@ -558,6 +662,20 @@ def tiny_laguna(vocab_size: int = 512) -> ModelConfig:
     )
 
 
+def tiny_sambay(vocab_size: int = 512) -> ModelConfig:
+    """The SambaY structure at test widths: 8 layers holding every kind
+    (3 ssm, 2 window, 1 full, 1 gmu, 1 cross), window 8, 8 query / 4 KV
+    heads of 16, scan width 128 with state 8."""
+    return ModelConfig(
+        name="tiny-sambay", family="sambay", vocab_size=vocab_size,
+        d_model=64, n_layers=8, n_heads=8, n_kv_heads=4, d_ff=128,
+        max_seq_len=4096, rope_theta=0.0, norm_eps=1e-5,
+        tie_embeddings=True, sliding_window=8, head_dim_override=16,
+        ssm_d_state=8, diff_attn=True, pool_rows_merged=True,
+        dtype=jnp.float32,
+    )
+
+
 PRESETS = {
     "llama-3-8b": llama3_8b,
     "llama-3.1-8b": llama31_8b,
@@ -571,6 +689,7 @@ PRESETS = {
     "kimi-k2-ep32": kimi_k2_ep32,
     "ouro-2.6b": ouro_2_6b,
     "laguna-s-ep8": laguna_s_ep8,
+    "phi4-mini-flash": phi4_mini_flash,
     "tiny-llama": tiny_llama,
     "tiny-llama-fatkv": tiny_llama_fatkv,
     "tiny-qwen2": tiny_qwen2,
@@ -582,6 +701,7 @@ PRESETS = {
     "tiny-kimi": tiny_kimi,
     "tiny-ouro": tiny_ouro,
     "tiny-laguna": tiny_laguna,
+    "tiny-sambay": tiny_sambay,
 }
 
 
@@ -842,6 +962,12 @@ class EngineConfig:
     # strands a request. Per-worker roles come from
     # ServerConfig.worker_roles; this field is what one engine sees.
     role: str = "mixed"
+    # The float32 rows a step program handed to ``sample`` are also an
+    # OUTPUT of it, and the engine files them on the sequence
+    # (``Sequence.kept_logits``: position -> [V], the last 4 x
+    # decode_steps_per_call positions): what bench/probes/kept.py reads.
+    # A static branch: off, every program lowers as it did without it.
+    keep_logits: bool = False
 
     @property
     def max_context(self) -> int:

@@ -167,8 +167,10 @@ def kv_bytes_per_token(model_cfg, kv_quant: str = "none",
         # pool's stored (lane-padded) width; never quantized.
         from tpu_inference.engine.kv_cache import latent_width
         return L * latent_width(model_cfg) * 2
-    hkv = model_cfg.n_kv_heads
-    d = model_cfg.head_dim
+    # (As stored: a differential-attention pool holds pair heads, half
+    # as many and twice as wide. The same bytes.)
+    hkv = model_cfg.pool_kv_heads
+    d = model_cfg.pool_head_dim
     if kv_quant == "int8":
         return 2 * L * hkv * (d + 4)
     if kv_quant == "int4":
@@ -272,15 +274,20 @@ def _auto_size_kinds(model_cfg, *, budget: float, hbm: float,
                      per_chip_w: int, kv_quant: str, page_size: int,
                      max_pages_per_seq: int, target_ctx: Optional[int],
                      batch_cap: int, window_span: int) -> AutoSizing:
-    """auto_size for a pool a kind (one chip: tp is refused)."""
+    """auto_size for a pool a kind (one chip: tp is refused). Three
+    things to fit where the model has state-space layers: every lane's
+    window span, every lane's state slot (a fixed size a sequence), and
+    the full kind's pages with what is left."""
     full_tok = kv_bytes_per_token(model_cfg, kv_quant, kind="full")
     win_tok = kv_bytes_per_token(model_cfg, kv_quant, kind="window")
+    state = model_cfg.state_bytes_per_seq()
     ctx = int(target_ctx) if target_ctx else (page_size * max_pages_per_seq
                                               // 2)
     ctx = max(1, min(ctx, page_size * max_pages_per_seq))
     for batch in range(batch_cap, 0, -1):
         win_pages = batch * window_span + 1
-        rest = budget - win_pages * page_size * win_tok
+        rest = (budget - win_pages * page_size * win_tok
+                - (batch + 1) * state)
         num_pages = min(int(rest // (full_tok * page_size)),
                         4 * batch_cap * max_pages_per_seq)
         if (num_pages >= max_pages_per_seq + 1
@@ -289,7 +296,8 @@ def _auto_size_kinds(model_cfg, *, budget: float, hbm: float,
                 max_batch_size=batch, num_pages=num_pages,
                 hbm_bytes=int(hbm), weight_bytes_per_chip=int(per_chip_w),
                 kv_pool_bytes_per_chip=int(
-                    page_size * (num_pages * full_tok + win_pages * win_tok)),
+                    page_size * (num_pages * full_tok + win_pages * win_tok)
+                    + (batch + 1) * state),
                 kv_bytes_per_token=full_tok, target_ctx=ctx,
                 num_window_pages=win_pages)
     raise ValueError(

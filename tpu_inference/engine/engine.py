@@ -97,12 +97,15 @@ def make_paged_attn(cfg: ModelConfig, page_size: int, block_tables: jax.Array,
                     q_offset: jax.Array, kv_len: jax.Array,
                     attn_backend: str = "dense", mesh: Optional[Any] = None,
                     sp_mode: Optional[str] = None, interpret: bool = False,
-                    sliding_window: Optional[int] = None):
+                    sliding_window: Optional[int] = None,
+                    write: bool = True, **kind_args):
     """AttentionFn that writes new K/V into the paged pool then attends.
 
     block_tables [B, MP]; positions/valid [B, S]; q_offset/kv_len [B].
     ``sliding_window`` overrides ``cfg.sliding_window`` (one kind of a
-    model whose layers differ: make_kind_attn).
+    model whose layers differ: make_kind_attn, which also takes
+    ``kind_args``). ``write=False``: a kind that READS another's pool
+    (it is handed no K / V and scatters nothing).
 
     The Pallas kernels are handed the STACKED pool ``kv.k`` / ``kv.v``
     ``[L, P, page, Hkv, D]`` whole, with ``layer_idx`` as a scalar
@@ -144,7 +147,7 @@ def make_paged_attn(cfg: ModelConfig, page_size: int, block_tables: jax.Array,
     if cfg.layer_types and sliding_window is None:
         return make_kind_attn(cfg, page_size, block_tables, positions, valid,
                               q_offset, kv_len, attn_backend=attn_backend,
-                              interpret=interpret)
+                              interpret=interpret, **kind_args)
     window = cfg.sliding_window if sliding_window is None else sliding_window
 
     def _sp_prefill(q, k, v):
@@ -229,8 +232,10 @@ def make_paged_attn(cfg: ModelConfig, page_size: int, block_tables: jax.Array,
                            head_spec=P(None, None, "tp", None))  # [B,S,H*,D]
 
     def attn(layer_idx, q, k, v, kv: KVPages):
-        slots = kvc.slot_mapping(block_tables, positions, valid, page_size)
-        kv = kvc.write_kv(kv, layer_idx, k, v, slots)
+        if write:
+            slots = kvc.slot_mapping(block_tables, positions, valid,
+                                     page_size)
+            kv = kvc.write_kv(kv, layer_idx, k, v, slots)
         if attn_backend == "pallas" and q.shape[1] == 1:
             return _pallas_decode(q[:, 0], kv, layer_idx)[:, None], kv
         if sp_mode and q.shape[1] > 1:
@@ -251,112 +256,279 @@ def make_paged_attn(cfg: ModelConfig, page_size: int, block_tables: jax.Array,
     return attn
 
 
+class PagedState:
+    """``attn.state`` over the state slots (kv_cache.KVPages.conv /
+    .ssm_h): what a state-space layer reads a lane's state through and
+    writes it back through. ONE rule for everything that must not
+    advance a state, said here: a position that is not ``valid`` (a
+    padded position of a prefill, a masked step of a fused decode call,
+    an idle lane) advances nothing — the model's scan reads ``dt = 0``
+    there and keeps the conv tail (``lens`` counts a lane's valid
+    positions) — and a lane with NO valid position writes to slot 0, the
+    trash slot, because the row it was staged with may be stale and its
+    slot somebody else's by now. A lane whose call starts at position 0
+    (a prompt's first chunk, a recompute-resume's) reads zeros."""
+
+    def __init__(self, slots, valid, q_offset, pallas: bool,
+                 interpret: bool):
+        self.lens = jnp.sum(valid, axis=1).astype(jnp.int32)
+        self.slots = slots
+        self.slots_w = jnp.where(self.lens > 0, slots, 0)
+        self.fresh = q_offset == 0
+        self.pallas, self.interpret = pallas, interpret
+
+    def read(self, layer, kv: KVPages):
+        keep = ~self.fresh[:, None, None]
+        tail, h = kv.conv[layer, self.slots], kv.ssm_h[layer, self.slots]
+        return jnp.where(keep, tail, 0), jnp.where(keep, h, 0.0)
+
+    def write(self, layer, tail, h, kv: KVPages) -> KVPages:
+        return kv._replace(
+            conv=kv.conv.at[layer, self.slots_w].set(
+                tail.astype(kv.conv.dtype)),
+            ssm_h=kv.ssm_h.at[layer, self.slots_w].set(h))
+
+    def scan(self, x, dt, b, c, a_t, d_skip, h0):
+        """A chunk's selective scan: the kernel, or off the kernel's
+        backend the same function as a ``lax.scan``."""
+        from tpu_inference.kernels import selective_scan as ss
+
+        if self.pallas:
+            return ss.selective_scan(x, dt, b, c, a_t, d_skip, h0,
+                                     self.lens, interpret=self.interpret)
+        return ss.selective_scan_reference(x, dt, b, c, a_t, d_skip, h0,
+                                           self.lens)
+
+
 def make_kind_attn(cfg: ModelConfig, page_size: int, block_tables: jax.Array,
                    positions: jax.Array, valid: jax.Array,
                    q_offset: jax.Array, kv_len: jax.Array,
-                   attn_backend: str = "dense", interpret: bool = False):
+                   attn_backend: str = "dense", interpret: bool = False,
+                   cross_at: Optional[jax.Array] = None):
     """make_paged_attn for a model whose layers differ in kind
     (``cfg.layer_types``): one AttentionFn a kind under ``attn.kinds``,
     each over its own pool (``kv.k`` / ``kv.v`` full, ``kv.wk`` /
     ``kv.wv`` window) and its own block table, with the kind's window
     static. ``block_tables`` is [B, 2 * MP]: the full kind's table, then
-    the window kind's. The layer index a kind's function takes is the
-    layer's place among its kind (its slot in that pool).
-    ``attn.pallas`` / ``attn.interpret`` / ``attn.valid`` as
+    the window kind's; where the model has state-space layers one more
+    column behind them holds the lane's STATE SLOT, and ``attn.state``
+    (PagedState) reads and writes it. The layer index a kind's function
+    takes is the layer's place among its kind (its slot in that pool).
+    A "cross" kind reads the full kind's table and pool, SLOT 0 of it
+    whatever its own place among the cross layers (the one full layer's:
+    a place past the pool's one slot is an address outside it, which the
+    chip's kernels halt on), and writes nothing; with ``cross_at`` [B] (a prefill that runs the cross layers
+    for one position a row) its one query sits at ``q_offset +
+    cross_at``. ``attn.pallas`` / ``attn.interpret`` / ``attn.valid`` as
     make_latent_attn's."""
-    mp = block_tables.shape[1] // 2
+    kinds = cfg.layer_types[:cfg.n_layers]
+    stateful = "ssm" in kinds
+    mp = (block_tables.shape[1] - stateful) // 2
     pallas = attn_backend == "pallas"
 
-    def of(table, window, pool_k, pool_v):
+    def of(table, window, pool_k, pool_v, write=True, at=None):
+        pos, ok, off, length = positions, valid, q_offset, kv_len
+        if at is not None:
+            off = q_offset + at
+            pos, ok = off[:, None], jnp.ones((off.shape[0], 1), bool)
+            length = off + 1
         inner = make_paged_attn(
-            cfg, page_size, table, positions, valid, q_offset, kv_len,
+            cfg, page_size, table, pos, ok, off, length,
             attn_backend=attn_backend, interpret=interpret,
-            sliding_window=window)
+            sliding_window=window,
+            write=write and not cfg.pool_rows_merged)
 
         def kind_attn(slot, q, k, v, kv: KVPages):
+            slot = slot if write else 0
             out, sub = inner(slot, q, k, v, KVPages(
                 k=getattr(kv, pool_k), v=getattr(kv, pool_v)))
+            if not write:
+                return out, kv
             return out, kv._replace(**{pool_k: sub.k, pool_v: sub.v})
 
-        return kind_attn
+        def merged_attn(slot, q, k, v, kv: KVPages):
+            """The same over pools allocated with merged rows
+            (ModelConfig.pool_rows_merged): written here, a window a
+            token, and read through a five-dim VIEW (the kernels merge
+            the rows again: the two reshapes fold away)."""
+            pk, pv = getattr(kv, pool_k), getattr(kv, pool_v)
+            slot = slot if write else 0
+            if write:
+                b, s, h, d = k.shape
+                if s % page_size:
+                    # (a decode step) a window a token
+                    starts = kvc.slot_mapping(table, pos, ok, page_size) * h
+                    shape = (b * s, h, d)
+                else:
+                    # A prefill chunk starts on a page's edge (__init__
+                    # holds the buckets to it): a window a PAGE. Rows of
+                    # a last page behind the chunk's valid tokens get
+                    # padding's K / V; nothing reads a row at or past
+                    # kv_len, and the token that comes to sit there
+                    # writes it first.
+                    starts = kvc.page_starts(
+                        table, off, jnp.sum(ok, axis=1), s // page_size,
+                        page_size, page_size * h)
+                    shape = (b * s // page_size, page_size * h, d)
+                pk = kvc.write_kv_rows(pk, slot, k.reshape(shape), starts)
+                pv = kvc.write_kv_rows(pv, slot, v.reshape(shape), starts)
+                kv = kv._replace(**{pool_k: pk, pool_v: pv})
+            view = pk.shape[:2] + (page_size, cfg.pool_kv_heads,
+                                   cfg.pool_head_dim)
+            out, _ = inner(slot, q, None, None, KVPages(
+                k=pk.reshape(view), v=pv.reshape(view)))
+            return out, kv
+
+        return merged_attn if cfg.pool_rows_merged else kind_attn
 
     def attn(*_):
         raise TypeError("a stack of mixed kinds calls attn.kinds[kind]")
 
     attn.kinds = {
         "full": of(block_tables[:, :mp], 0, "k", "v"),
-        "window": of(block_tables[:, mp:], cfg.sliding_window, "wk", "wv")}
+        "window": of(block_tables[:, mp:2 * mp], cfg.sliding_window,
+                     "wk", "wv")}
+    if "cross" in kinds:
+        attn.kinds["cross"] = of(block_tables[:, :mp], 0, "k", "v",
+                                 write=False, at=cross_at)
+    if stateful:
+        attn.state = PagedState(block_tables[:, 2 * mp], valid, q_offset,
+                                pallas, interpret)
     attn.pallas, attn.interpret, attn.valid = pallas, interpret, valid
     return attn
 
 
-# Why each is refused: (for a latent pool, for a looped stack, for a
-# stack of mixed kinds with a pool a kind).
+# Why each is refused, keyed by what the model is: "latent" (a latent
+# pool), "looped" (a looped stack), "kinds" (a stack of mixed kinds with a
+# pool a kind), "state" (state-space layers: a state a sequence, which a
+# token advances; such a model has kinds too, and this column speaks for
+# it).
 _WHY_NOT = {
-    "tp": ("no param shardings, no sharded latent pool, no expert "
-           "exchange: parallel/shardings.py",
-           "no param shardings for the output norms and the exit gate: "
-           "parallel/shardings.py",
-           "no param shardings for parameters stacked per kind, no sharded "
-           "per-kind pools, no expert exchange: parallel/shardings.py"),
-    "kv_quant": ("the latent pool is stored in the model dtype",
-                 "no test holds a quantized pool of pass x layer slots to "
-                 "the reference",
-                 "per-kind pools are stored in the model dtype: "
-                 "kv_cache.alloc_kind_pages"),
-    "host": ("offload / restore / serialize assume K and V pools",
-             "a page of pass x layer slots is tens of MiB to copy out at "
-             "every eviction, untested",
-             "offload / restore copy one pool; a page of the full kind "
-             "has no window-kind twin to restore"),
-    "role": ("P/D handoff serializes K and V pages",
-             "P/D handoff of pages of pass x layer slots is untested",
-             "P/D handoff serializes one pool's pages, not a table a kind"),
+    "tp": {
+        "latent": "no param shardings, no sharded latent pool, no expert "
+                  "exchange: parallel/shardings.py",
+        "looped": "no param shardings for the output norms and the exit "
+                  "gate: parallel/shardings.py",
+        "kinds": "no param shardings for parameters stacked per kind, no "
+                 "sharded per-kind pools, no expert exchange: "
+                 "parallel/shardings.py",
+        "state": "no param shardings for parameters stacked per kind, no "
+                 "sharded per-kind pools or state slots: "
+                 "parallel/shardings.py (pipeline stages: "
+                 "parallel/pipeline.pp_forward runs the llama family)"},
+    "kv_quant": {
+        "latent": "the latent pool is stored in the model dtype",
+        "looped": "no test holds a quantized pool of pass x layer slots "
+                  "to the reference",
+        "kinds": "per-kind pools are stored in the model dtype: "
+                 "kv_cache.alloc_kind_pages",
+        "state": "per-kind pools are stored in the model dtype and the "
+                 "scan's state in float32: kv_cache.alloc_kind_pages"},
+    "host": {
+        "latent": "offload / restore / serialize assume K and V pools",
+        "looped": "a page of pass x layer slots is tens of MiB to copy "
+                  "out at every eviction, untested",
+        "kinds": "offload / restore copy one pool; a page of the full "
+                 "kind has no window-kind twin to restore",
+        "state": "offload / restore copy one pool's pages; a restored "
+                 "prefix would need the scan's state at its end, which "
+                 "no page holds"},
+    "role": {
+        "latent": "P/D handoff serializes K and V pages",
+        "looped": "P/D handoff of pages of pass x layer slots is untested",
+        "kinds": "P/D handoff serializes one pool's pages, not a table a "
+                 "kind",
+        "state": "P/D handoff serializes one pool's pages: neither a "
+                 "table a kind nor the sequence's state slot "
+                 "(export_sequence_kv* / adopt_sequence refuse too)"},
+    "quant": {
+        "latent": "the grouped expert kernels take bf16 or int8 weights",
+        "kinds": "the grouped expert kernels take bf16 or int8 weights",
+        "state": "no test holds int4 projections around a recurrence to "
+                 "the reference (int8 quantizes the projections and "
+                 "leaves the scan's own parameters, A_log, D, the dt "
+                 "and conv weights, as they are)"},
+    "spec": {
+        "state": "a rejected draft would already have advanced the "
+                 "sequence's state, and no snapshot is kept to go back "
+                 "to"},
+    "hybrid": {
+        "state": "a prefill chunk and the decode lanes in one program "
+                 "both scatter into the state slots: untested"},
     # Not an error: the cache is left off with this line (a one-kind
     # window model does the same, __init__ below).
-    "prefix": (None, None,
-               "a hit would need the full kind's pages of the prefix AND "
-               "the window kind's last sliding_window tokens before its "
-               "end, which are released while a sequence runs"),
+    "prefix": {
+        "kinds": "a hit would need the full kind's pages of the prefix "
+                 "AND the window kind's last sliding_window tokens before "
+                 "its end, which are released while a sequence runs",
+        "state": "a hit would need a snapshot of every state-space "
+                 "layer's state at the prefix's end (and the window "
+                 "kind's last sliding_window tokens before it): none is "
+                 "kept"},
 }
+
+_WHAT_IT_IS = {"latent": "latent attention", "looped": "a looped stack",
+               "kinds": "layers of mixed kinds",
+               "state": "state-space layers"}
+
+
+def model_is(model_cfg: ModelConfig) -> Optional[str]:
+    """The column of ``_WHY_NOT`` that speaks for this model (None: a
+    plain stack, nothing is refused for what it is)."""
+    kinds = model_cfg.layer_types[:model_cfg.n_layers]
+    return ("latent" if model_cfg.latent_dim else
+            "looped" if model_cfg.loop_steps > 1 else
+            "state" if "ssm" in kinds else "kinds" if kinds else None)
 
 
 def _refuse_unsupported(model_cfg: ModelConfig, engine_cfg: EngineConfig,
                         mesh, draft_cfg) -> None:
     """What a latent pool (the latent-attention / routed-expert family),
-    a looped stack (``loop_steps`` > 1) or a stack of mixed kinds
-    (``layer_types``) does not run yet, said at construction and not at
-    the first request."""
-    latent, looped = bool(model_cfg.latent_dim), model_cfg.loop_steps > 1
-    kinds = bool(model_cfg.layer_types)
-    if not (latent or looped or kinds):
-        return
-    why = {k: v[0 if latent else 1 if looped else 2]
-           for k, v in _WHY_NOT.items()}
+    a looped stack (``loop_steps`` > 1), a stack of mixed kinds
+    (``layer_types``) or one with state-space layers does not run yet,
+    said at construction and not at the first request."""
     what = []
+    spec = (engine_cfg.num_speculative_tokens > 0 or draft_cfg is not None
+            or engine_cfg.spec_mode != "draft")
+    if engine_cfg.keep_logits:
+        # Whatever the model is: the programs that file rows are the
+        # prefill and the fused-K decode.
+        if spec:
+            what.append("speculative decoding (the speculative rounds "
+                        "sample inside programs that keep no rows)")
+        if engine_cfg.role != "mixed":
+            what.append(f"role={engine_cfg.role!r} (a handed-off sequence "
+                        "carries no kept rows)")
+        if what:
+            raise ValueError("keep_logits does not support: "
+                             + "; ".join(what))
+    is_ = model_is(model_cfg)
+    if is_ is None:
+        return
+    why = {k: v.get(is_) for k, v in _WHY_NOT.items()}
     if model_cfg.early_exit_threshold < 1.0:
         what.append(f"early_exit_threshold={model_cfg.early_exit_threshold}"
                     " < 1 (per-token depth: every token runs every pass)")
     if mesh is not None and any(int(mesh.shape.get(ax, 1)) > 1
-                                for ax in ("tp", "sp")):
-        what.append(f"tp / sp > 1 ({why['tp']})")
+                                for ax in ("tp", "sp", "pp")):
+        what.append(f"tp / sp / pp > 1 ({why['tp']})" if is_ == "state"
+                    else f"tp / sp > 1 ({why['tp']})")
     if engine_cfg.kv_quant != "none":
         what.append(f"kv_quant={engine_cfg.kv_quant!r} ({why['kv_quant']})")
-    if (engine_cfg.num_speculative_tokens > 0 or draft_cfg is not None
-            or engine_cfg.spec_mode != "draft"):
-        what.append("speculative decoding (draft or ngram)")
+    if spec:
+        what.append("speculative decoding (draft or ngram"
+                    + (f": {why['spec']})" if why["spec"] else ")"))
     if engine_cfg.host_cache_pages:
         what.append(f"the host KV tier (host_cache_pages > 0: {why['host']})")
-    if (latent or kinds) and engine_cfg.quant == "int4":
-        what.append("quant='int4' (the grouped expert kernels take bf16 "
-                    "or int8 weights)")
+    if why["quant"] and engine_cfg.quant == "int4":
+        what.append(f"quant='int4' ({why['quant']})")
+    if why["hybrid"] and engine_cfg.hybrid_prefill:
+        what.append(f"hybrid_prefill ({why['hybrid']})")
     if engine_cfg.role != "mixed":
         what.append(f"role={engine_cfg.role!r} ({why['role']})")
     if what:
-        kind = ("latent attention" if latent else "a looped stack"
-                if looped else "layers of mixed kinds")
-        raise ValueError(f"{model_cfg.name} ({kind}) does not support: "
-                         + "; ".join(what))
+        raise ValueError(f"{model_cfg.name} ({_WHAT_IT_IS[is_]}) does not "
+                         "support: " + "; ".join(what))
 
 
 def _extend(result: Dict[int, List[int]], more: Dict[int, List[int]]
@@ -510,6 +682,10 @@ class Sequence:
     # where speculation paid off without a span per round.
     spec_rounds: int = 0
     spec_accepted_toks: int = 0
+    # EngineConfig.keep_logits: position -> the float32 row [V] the step
+    # program handed to ``sample`` for the token after that position (the
+    # last 4 x decode_steps_per_call positions). None: not kept.
+    kept_logits: Optional[Dict[int, np.ndarray]] = None
 
     @property
     def last_token(self) -> int:
@@ -620,10 +796,12 @@ class InferenceEngine:
         # dispatch donates self.kv, so other threads (health, hello)
         # must never touch the array to ask.
         self._devices = sorted(self.kv.k.devices(), key=lambda d: d.id)
-        # Expert-routing counts (family deepseek_v3), summed off the
-        # decode readbacks: models/deepseek_v3.py MOE_STATS + one slot
-        # per held expert. None for every other family.
-        self.moe_stats = (np.zeros(self.kv.aux.shape, np.int64)
+        # What the model counts on the device (``kv.aux``; a family's
+        # ``n_aux_stats``), summed off the decode readbacks: expert
+        # routing (models/deepseek_v3.py MOE_STATS + one slot per held
+        # expert), the positions a prefill ran (models/sambay.py
+        # AUX_STATS). None for a family that counts nothing.
+        self.aux_stats = (np.zeros(self.kv.aux.shape, np.int64)
                           if self.kv.aux is not None else None)
         t_pool = time.perf_counter()
         self.allocator = PageAllocator(engine_cfg.num_pages)
@@ -636,11 +814,28 @@ class InferenceEngine:
         self.window_span = (kvc.window_span_pages(model_cfg, engine_cfg)
                             if n_win else 0)
         self.window_pages_released = 0    # behind-window frees, lifetime
+        # State-space layers: a state slot a sequence, from admission to
+        # release (kvc.StateSlots; ``seq.pages.state``). It rides behind
+        # the block tables, one more column of a row: lanes move between
+        # dispatches (_compact_slots) and the state must not.
+        if model_cfg.pool_rows_merged and any(
+                b % engine_cfg.page_size
+                for b in (*engine_cfg.prefill_buckets,
+                          engine_cfg.chunk_tokens_cap)):
+            raise ValueError(
+                f"{model_cfg.name}: prefill buckets and the chunk cap have "
+                f"to be multiples of the page size ({engine_cfg.page_size}"
+                "): a chunk's K / V are written a whole page at a time")
+        n_state = kvc.num_state_slots(model_cfg, engine_cfg)
+        self.state_slots = kvc.StateSlots(n_state) if n_state else None
+        self.keep_logits = engine_cfg.keep_logits
         # Step-phase telemetry (telemetry.py): dispatch/bubble histograms
         # + read-through page/param gauges. TPU_INF_TELEMETRY=0 swaps in
         # no-op metrics (the overhead-comparison arm).
         self.telemetry = telemetry.EngineTelemetry(self)
-        if self.moe_stats is not None:
+        if self.state_slots is not None:
+            self.telemetry.bind_state(self)
+        elif self.aux_stats is not None:
             self.telemetry.bind_moe(self)
         # Boot phases (gauges set once; a caller that loaded a
         # checkpoint itself adds its load time to the first).
@@ -786,7 +981,7 @@ class InferenceEngine:
         self.host_pool = None
         if engine_cfg.enable_prefix_cache and self.win_allocator is not None:
             print(f"[engine] {model_cfg.name}: prefix cache disabled — "
-                  + _WHY_NOT["prefix"][2])
+                  + _WHY_NOT["prefix"][model_is(model_cfg)])
         elif engine_cfg.enable_prefix_cache and not swa_binds:
             # SWA models run WITHOUT the prefix cache (vLLM makes the
             # same exclusion): behind-window pages are evicted while a
@@ -821,9 +1016,11 @@ class InferenceEngine:
                   "behind-window pages, which doesn't compose with "
                   "cached prefixes (multi-turn requests re-prefill)")
         self.max_pages = engine_cfg.max_pages_per_seq
-        # Width of a block-table row: a table a kind, side by side.
-        self.bt_width = self.max_pages * (1 if self.win_allocator is None
-                                          else 2)
+        # Width of a block-table row: a table a kind, side by side, and
+        # behind them the state slot where the model has one.
+        self.bt_width = (self.max_pages * (1 if self.win_allocator is None
+                                           else 2)
+                         + (self.state_slots is not None))
         # Cold-start evidence (device_info): wall seconds of the last
         # warmup() and how many graphs it ran.
         self.warmup_s = 0.0
@@ -1039,14 +1236,15 @@ class InferenceEngine:
     # ------------------------------------------------------------------
 
     def _paged_attn(self, cfg: ModelConfig, block_tables, positions, valid,
-                    q_offset, kv_len, sp_mode: Optional[str] = None):
+                    q_offset, kv_len, sp_mode: Optional[str] = None,
+                    **kind_args):
         """make_paged_attn bound to this engine's page size, attention
         backend, mesh and kernel mode."""
         return make_paged_attn(
             cfg, self.engine_cfg.page_size, block_tables, positions, valid,
             q_offset=q_offset, kv_len=kv_len,
             attn_backend=self.attn_backend, mesh=self.mesh, sp_mode=sp_mode,
-            interpret=self._pallas_interpret)
+            interpret=self._pallas_interpret, **kind_args)
 
     def _prefill_fn(self, params, kv: KVPages, tokens, prompt_len, prefix_len,
                     block_table, key, temperature, top_p, top_k, seed,
@@ -1064,6 +1262,18 @@ class InferenceEngine:
         valid = ar < prompt_len[:, None]
         total_len = prefix_len + prompt_len
         positions = jnp.minimum(positions, self.engine_cfg.max_context - 1)
+        if "cross" in cfg.layer_types[:cfg.n_layers]:
+            # The layers behind the one full-attention layer write no
+            # state: the program runs them for the sampled position only.
+            at = prompt_len - 1
+            attn = self._paged_attn(cfg, block_table, positions, valid,
+                                    q_offset=prefix_len, kv_len=total_len,
+                                    cross_at=at)
+            hidden, kv = self.mod.forward_hidden(
+                params, cfg, tokens, positions, kv, attn, cross_at=at)
+            return self._sample_prefill(params, kv, hidden[:, 0], total_len,
+                                        key, temperature, top_p, top_k, seed,
+                                        rpen, rlast, window)
         attn = self._paged_attn(cfg, block_table, positions, valid,
                                 q_offset=prefix_len, kv_len=total_len,
                                 sp_mode=sp_mode)
@@ -1072,6 +1282,15 @@ class InferenceEngine:
         last = jnp.take_along_axis(
             hidden, (prompt_len - 1)[:, None, None].astype(jnp.int32), axis=1
         )[:, 0]                                                  # [1, D]
+        return self._sample_prefill(params, kv, last, total_len, key,
+                                    temperature, top_p, top_k, seed, rpen,
+                                    rlast, window)
+
+    def _sample_prefill(self, params, kv, last, total_len, key, temperature,
+                        top_p, top_k, seed, rpen, rlast, window):
+        """A prefill's tail: the sampled position's hidden state [P, D]
+        -> (kv, first token [P], its float32 logits [P, V])."""
+        cfg = self.model_cfg
         logits = self.mod.unembed(params, cfg, last)             # [1, V]
         sp = SamplingParams(temperature=temperature, top_p=top_p,
                             top_k=top_k, seed=seed)
@@ -1139,14 +1358,20 @@ class InferenceEngine:
             toks = jnp.where(act, toks, tokens)
             window = roll_window(window, toks, act)
             out = jnp.where(act, toks, -1)
+            if ecfg.keep_logits:
+                # The rows leave with the tokens: outs is then (tokens
+                # [K, B(+n)], logits [K, B, V]).
+                kept = logits.astype(jnp.float32)
             if kv.aux is not None:
                 # The model's routing counts since the last emission (a
                 # prefill's included) leave with the step's tokens: the
-                # one readback there is (_fold_moe_stats).
+                # one readback there is (_fold_aux_stats).
                 out = jnp.concatenate([out, kv.aux])
                 kv = kv._replace(aux=jnp.zeros_like(kv.aux))
             alive = alive & jnp.where(act, toks != eos_ids, True)
             ctx_lens = ctx_lens + act.astype(jnp.int32)
+            if ecfg.keep_logits:
+                out = (out, kept)
             return (kv, toks, ctx_lens, alive, window), out
 
         if k_steps is None:
@@ -1184,10 +1409,12 @@ class InferenceEngine:
         decode tail matches _decode_multi_fn so hybrid calls chain into
         the same dispatch-ahead pipeline as plain decode calls.
         """
-        kv, p_tok, _ = self._prefill_fn(
+        kv, p_tok, p_logits = self._prefill_fn(
             params, kv, p_tokens, p_prompt_len, p_prefix_len, p_block_table,
             p_key, p_temp, p_top_p, p_top_k, p_seed, p_rpen, p_rlast,
             p_window)
+        if self.engine_cfg.keep_logits:
+            p_tok = (p_tok, p_logits)
         kv, outs, final, final_window = self._decode_multi_fn(
             params, kv, d_tokens, d_ctx_lens, d_block_tables, d_allowed,
             d_eos_ids, d_key, d_temp, d_top_p, d_top_k, d_seed, d_rpen,
@@ -1654,11 +1881,11 @@ class InferenceEngine:
             now if any(s is not None and not s.done for s in self.slots)
             else None)
 
-    def _fold_moe_stats(self, outs: np.ndarray) -> None:
-        """The routing counts that rode a decode readback ``outs``
+    def _fold_aux_stats(self, outs: np.ndarray) -> None:
+        """The model's counts that rode a decode readback ``outs``
         [K, rung + n] behind the lanes' tokens (_decode_multi_fn)."""
-        if self.moe_stats is not None:
-            self.moe_stats += outs[:, -len(self.moe_stats):].sum(
+        if self.aux_stats is not None:
+            self.aux_stats += outs[:, -len(self.aux_stats):].sum(
                 axis=0, dtype=np.int64)
 
     def _next_step(self) -> int:
@@ -1761,10 +1988,12 @@ class InferenceEngine:
         return min(need, self.window_span) if self.swa_evict else need
 
     def admission_need(self, seq: Sequence) -> np.ndarray:
-        """Pages a request is charged at admission, a kind: [full (the
-        only kind of most models), window]."""
+        """What a request is charged at admission: pages a kind, [full
+        (the only kind of most models), window], and state slots (one
+        where the model has state-space layers)."""
         return np.asarray([self._pages_for_admission(seq),
-                           self._window_pages_reserved(seq)], np.int64)
+                           self._window_pages_reserved(seq),
+                           int(self.state_slots is not None)], np.int64)
 
     def admission_fits(self, want: np.ndarray, headroom: int = 0) -> bool:
         """Whether ``want`` pages a kind (admission_need, summed over the
@@ -1772,13 +2001,18 @@ class InferenceEngine:
         compares with what is free or evictable now. With a pool a kind
         every bound sequence's WHOLE charge is held back too, taken or
         not yet, so that no pool can run out under a sequence that was
-        admitted: pressure in either kind is a wait at admission."""
+        admitted: pressure in either kind is a wait at admission. A
+        model with state-space layers also needs a free state slot a
+        request."""
         room = self._free_plus_evictable() - headroom
         if self.win_allocator is None:
             return room >= want[0]
+        if self.state_slots is not None and \
+                self.state_slots.num_free < want[2]:
+            return False        # a state slot a sequence
         bound = [s for s in self.slots if s is not None and not s.done]
         held = sum((self.admission_need(s) for s in bound),
-                   np.zeros(2, np.int64))
+                   np.zeros(3, np.int64))[:2]
         usable = np.asarray([self.engine_cfg.num_pages - 1,
                              self.win_allocator.num_pages - 1])
         left = usable - held
@@ -2063,6 +2297,14 @@ class InferenceEngine:
         gen = seq.generated[seq.resume_base:]
         return base + (gen[:-1] if drop_last else gen)
 
+    def _refuse_state_export(self) -> None:
+        """A sequence's pages are not all of it where a token advances a
+        state: nothing exports or adopts the state slot."""
+        if self.state_slots is not None:
+            raise ValueError(
+                f"{self.model_cfg.name} (state-space layers) does not "
+                f"support KV export / adoption: {_WHY_NOT['role']['state']}")
+
     def export_sequence_kv(self, seq: Sequence
                            ) -> Tuple[List[bytes], List["kvc.HostKVPage"]]:
         """Drain-time migration export: (chain digests, host page
@@ -2076,6 +2318,7 @@ class InferenceEngine:
         the partial last page recomputes at the destination). Call with
         the scheduler stopped and the pipeline drained — it reads the
         live pool."""
+        self._refuse_state_export()
         from tpu_inference.engine.prefix_cache import _chain_hashes
         if not seq.pages or seq.ctx_len <= 0:
             return [], []
@@ -2112,6 +2355,7 @@ class InferenceEngine:
         caller then keeps the sequence local instead of handing off.
         Engine thread only; the offload's device_get orders after any
         in-flight dispatch by data dependency."""
+        self._refuse_state_export()
         from tpu_inference.engine.prefix_cache import _chain_hashes
         if not seq.pages or seq.ctx_len <= 0:
             return [], [], 0
@@ -2137,6 +2381,7 @@ class InferenceEngine:
         token). Raises on a malformed blob or pool shortfall; the
         scheduler's fallback then clears adopt_kv and recompute-resumes
         through the ordinary prefill path instead."""
+        self._refuse_state_export()
         host_pages, ctx_len = seq.adopt_kv
         ecfg = self.engine_cfg
         expected = -(-ctx_len // ecfg.page_size)
@@ -2247,13 +2492,28 @@ class InferenceEngine:
                         self.win_allocator.allocate(need))
         return steps
 
-    def _fold_lane(self, seq: Sequence, toks) -> List[int]:
+    def _keep(self, seq: Sequence, pos: int, row) -> None:
+        """File the float32 row a program sampled the token after
+        position ``pos`` from (EngineConfig.keep_logits); the last 4 x K
+        positions stay."""
+        held = seq.kept_logits
+        if held is None:
+            held = seq.kept_logits = {}
+        held[pos] = np.asarray(row, np.float32)
+        cap = 4 * max(1, self.engine_cfg.decode_steps_per_call)
+        for p in sorted(held)[:-cap]:
+            del held[p]
+
+    def _fold_lane(self, seq: Sequence, toks, rows=None) -> List[int]:
         """Fold device-produced tokens (iterable of ints, -1 = no token)
-        into one sequence's host state; stops at done/-1."""
+        into one sequence's host state; stops at done/-1. ``rows``: the
+        steps' logits [K, V], filed as the tokens are folded."""
         got: List[int] = []
-        for tok in toks:
+        for i, tok in enumerate(toks):
             if seq.done or tok < 0:
                 break
+            if rows is not None:
+                self._keep(seq, seq.ctx_len, rows[i])
             seq.ctx_len += 1
             seq.generated.append(tok)
             if seq.first_token_time == 0.0:
@@ -2281,6 +2541,8 @@ class InferenceEngine:
         bt[:len(pages)] = pages
         window = getattr(pages, "window", ())
         bt[self.max_pages:self.max_pages + len(window)] = window
+        if self.state_slots is not None:
+            bt[-1] = getattr(pages, "state", 0)
         return bt
 
     def _window_pages_for(self, seq: Sequence, start: int, end: int) -> None:
@@ -2304,6 +2566,8 @@ class InferenceEngine:
         self.allocator.free(seq.pages)
         if self.win_allocator is not None:
             self.win_allocator.free(getattr(seq.pages, "window", ()))
+        if self.state_slots is not None:
+            self.state_slots.free(getattr(seq.pages, "state", 0))
         seq.pages = []
 
     def _prefill_setup(self, seq: Sequence, slot: int) -> List[int]:
@@ -2346,6 +2610,11 @@ class InferenceEngine:
         except MemoryError:
             self.allocator.free(shared)
             raise
+        if self.state_slots is not None:
+            # As many slots as lanes, so a free lane has one. Nothing is
+            # zeroed here: a chunk at position 0 reads zeros in the
+            # slot's place (PagedState.fresh).
+            seq.pages.state = self.state_slots.allocate()
         seq.pages_version += 1        # staging block-table rows re-key
         seq.evicted_pages = 0         # the window's cursor is the list's
         # Swap accounting AFTER the allocation can no longer fail: a
@@ -2362,8 +2631,11 @@ class InferenceEngine:
         return prompt
 
     def _prefill_finish(self, seq: Sequence, prompt: List[int],
-                        first: int) -> None:
-        """Common post-prefill bookkeeping for one sequence."""
+                        first: int, logits=None) -> None:
+        """Common post-prefill bookkeeping for one sequence. ``logits``
+        [V]: the row ``first`` was sampled from (keep_logits)."""
+        if self.keep_logits and logits is not None:
+            self._keep(seq, len(prompt) - 1, logits)
         seq.ctx_len = len(prompt)
         seq.generated.append(first)
         if seq.first_token_time == 0.0:
@@ -2388,6 +2660,8 @@ class InferenceEngine:
         f["tokens"][i, :len(chunk)] = chunk
         f["prompt_len"][i] = len(chunk)
         f["prefix_len"][i] = offset
+        if offset == 0 and self.state_slots is not None:
+            self.state_slots.resets_total += 1
         f["block_table"][i] = self._block_table_array(seq.pages)
         f["temp"][i] = seq.temperature
         f["top_p"][i] = seq.top_p
@@ -2453,8 +2727,10 @@ class InferenceEngine:
         c = st["chunk_tokens"]
         args = (self.params, self.kv, self._base_key,
                 *self._put_operands(self._chunk_host_operand(st)))
-        (self.kv, tok, _), dseq, t0, t1 = self._run(
+        (self.kv, tok, lg), dseq, t0, t1 = self._run(
             "prefill_chunk", prefill, args, slots=1, chunk_tokens=c)
+        if self.keep_logits:
+            tok = (tok, lg)      # read together at the final chunk
         if self.spec_draft:
             # Mirror the chunk into the draft model's KV (same pages).
             self.draft_kv = self._draft_prefill_jit(
@@ -2499,8 +2775,17 @@ class InferenceEngine:
         tok = dseq = None
         while offset < len(prompt):
             offset, tok, dseq = self._prefill_one_chunk(seq, prompt, offset)
+        self._prefill_finish(seq, prompt, *self._read_first(dseq, tok))
+
+    def _read_first(self, dseq: int, tok) -> tuple:
+        """A one-lane prefill's sampled token off the device, and with
+        keep_logits (``tok`` is then (token, logits)) its row."""
+        if isinstance(tok, tuple):
+            (first, row), _, _ = self._wait(
+                dseq, lambda: (int(tok[0][0]), np.asarray(tok[1][0])))
+            return first, row
         first, _, _ = self._wait(dseq, lambda: int(tok[0]))
-        self._prefill_finish(seq, prompt, first)
+        return (first,)
 
     # -- Incremental (interleavable) prefill: one chunk per call, so the
     # -- scheduler can run decode steps between a long prompt's chunks
@@ -2535,8 +2820,7 @@ class InferenceEngine:
             seq, prompt, seq.prefill_offset)
         if seq.prefill_offset < len(prompt):
             return False
-        first, _, _ = self._wait(dseq, lambda: int(tok[0]))
-        self._prefill_finish(seq, prompt, first)
+        self._prefill_finish(seq, prompt, *self._read_first(dseq, tok))
         seq.prefill_prompt = None
         return True
 
@@ -2580,7 +2864,7 @@ class InferenceEngine:
         f["step"][:] = self._next_step()
         args = (self.params, self.kv, self._base_key,
                 *self._put_operands(packed))
-        (self.kv, tok, _), dseq, t0, _ = self._run(
+        (self.kv, tok, lg), dseq, t0, _ = self._run(
             "prefill_chunk", prefill, args, slots=n, tokens=n,
             chunk_tokens=chunk_tokens)
         if self.spec_draft:
@@ -2602,8 +2886,9 @@ class InferenceEngine:
                 compile_event=graph_key not in self._prefill_buckets_seen,
                 seq=dseq, t_enqueue=t0, t_done=t_done)
             self._prefill_buckets_seen.add(graph_key)
+        rows = np.asarray(lg) if self.keep_logits else [None] * n
         for i, (seq, prompt) in enumerate(group):
-            self._prefill_finish(seq, prompt, int(toks_out[i]))
+            self._prefill_finish(seq, prompt, int(toks_out[i]), rows[i])
 
     def prefill_many(self, seqs: List[Sequence]) -> None:
         """Admit several sequences, batching same-bucket single-chunk
@@ -3245,9 +3530,11 @@ class InferenceEngine:
         c = chunk["chunk_tokens"]
         args = (self.params, self.kv, self._base_key,
                 *self._put_operands(self._chunk_host_operand(chunk)))
-        (self.kv, p_tok, _), dseq, t0, t1 = self._run(
+        (self.kv, p_tok, lg), dseq, t0, t1 = self._run(
             "prefill_chunk", self._prefill_jit, args, slots=1,
             chunk_tokens=c)
+        if self.keep_logits:
+            p_tok = (p_tok, lg)
         call = {"outs": None, "final": None, "final_window": None,
                 "allowed": {}, "seqs": {}, "rung": 0,
                 "seq": dseq, "t_enqueue": t0,
@@ -3486,7 +3773,8 @@ class InferenceEngine:
                 return (np.asarray(call["emitted"]),      # [B, γ+1]
                         np.asarray(call["n_accepted"]))
             if call["outs"] is not None:
-                return np.asarray(call["outs"])       # [K, B]
+                # [K, B]; with keep_logits (tokens, logits [K, B, V])
+                return jax.tree.map(np.asarray, call["outs"])
             # Chunk-only call (no decode half): the blocking sync is on
             # the chunk's sampled token instead (the pipeline's ordering
             # needs it: a later call may release pages it writes).
@@ -3500,6 +3788,9 @@ class InferenceEngine:
         # enqueue's start to this readback's end.
         t_start = max(call["t_enqueue"], self._device_free_at)
         outs, t_wait, t_done = self._wait(call["seq"], read)
+        rows = None
+        if self.keep_logits and not spec and outs is not None:
+            outs, rows = outs            # (tokens, logits [K, B, V])
         if outs is not None:
             # Chunk-only waits stay out of decode_sync_s (pure prefill
             # device time, not a decode sync).
@@ -3517,13 +3808,14 @@ class InferenceEngine:
                 call["seqs"], call["allowed"], call["n_prop"], *outs)
         else:
             if outs is not None:
-                self._fold_moe_stats(outs)
+                self._fold_aux_stats(outs)
             result = {}
             for slot, seq in call["seqs"].items():
                 if seq.done or self.slots[seq.slot] is not seq:
                     continue
                 got = self._fold_lane(
-                    seq, (int(outs[s, slot]) for s in range(outs.shape[0])))
+                    seq, (int(outs[s, slot]) for s in range(outs.shape[0])),
+                    None if rows is None else rows[:, slot])
                 if got:
                     result[seq.request_id] = got
         if pf is not None:
@@ -3536,8 +3828,14 @@ class InferenceEngine:
             if (pf["final"] and not seq.done
                     and seq.prefill_prompt is not None
                     and seq.slot >= 0 and self.slots[seq.slot] is seq):
-                self._prefill_finish(seq, pf["prompt"],
-                                     int(np.asarray(pf["tok"])[0]))
+                tok = pf["tok"]
+                if isinstance(tok, tuple):
+                    self._prefill_finish(seq, pf["prompt"],
+                                         int(np.asarray(tok[0])[0]),
+                                         np.asarray(tok[1])[0])
+                else:
+                    self._prefill_finish(seq, pf["prompt"],
+                                         int(np.asarray(tok)[0]))
                 seq.prefill_prompt = None
         led = call.get("ledger")
         if tel.enabled:

@@ -67,6 +67,17 @@ class KVPages(NamedTuple):
     P_full, ...]``, and ``wk`` / ``wv`` the WINDOW kind's, ``[n_window,
     P_window, ...]``, each with its own allocator and its own block
     table a sequence (``KindPages``). None for a model of one kind.
+
+    A model with state-space layers (kind "ssm") also holds a state a
+    SEQUENCE, which a token advances and no page table addresses:
+    ``conv`` ``[n_ssm, S + 1, d_conv - 1, d_inner]`` (the conv's last
+    inputs, model dtype) and ``ssm_h`` ``[n_ssm, S + 1, d_state,
+    d_inner]`` (float32, state-major so that d_inner lies on the lanes),
+    S state slots handed out by ``StateSlots``; slot 0 is the TRASH slot,
+    as page 0 is the trash page: a lane that advances nothing in a call
+    writes there. (Fields beside the pools, not a mapping by kind: the
+    kinds' states differ in shape, indexing and allocator, so a mapping
+    would move each special case to its reader and remove none.)
     """
 
     k: jax.Array
@@ -76,6 +87,8 @@ class KVPages(NamedTuple):
     aux: Optional[jax.Array] = None
     wk: Optional[jax.Array] = None
     wv: Optional[jax.Array] = None
+    conv: Optional[jax.Array] = None
+    ssm_h: Optional[jax.Array] = None
 
     @property
     def num_pages(self) -> int:
@@ -143,9 +156,11 @@ class KindPages(list):
     like the other, with 0 (the trash page) where a page behind the
     window was released."""
 
-    def __init__(self, full=(), window=()):
+    def __init__(self, full=(), window=(), state: int = 0):
         super().__init__(full)
         self.window: List[int] = list(window)
+        # The sequence's state slot (StateSlots; 0: the model has none).
+        self.state = state
 
 
 def window_span_pages(model_cfg: ModelConfig,
@@ -170,9 +185,50 @@ def num_window_pages(model_cfg: ModelConfig, engine_cfg: EngineConfig) -> int:
         + 1)
 
 
+class StateSlots:
+    """Host-side allocator of per-sequence state slots: a sequence holds
+    one from admission to release. Slot 0 is the trash slot and never
+    handed out."""
+
+    def __init__(self, num_slots: int):
+        self.num_slots = num_slots            # trash slot included
+        self._free: List[int] = list(range(num_slots - 1, 0, -1))
+        self.peak_in_use = 0
+        self.resets_total = 0     # first chunks: states started from zeros
+
+    @property
+    def num_free(self) -> int:
+        return len(self._free)
+
+    @property
+    def in_use(self) -> int:
+        return self.num_slots - 1 - len(self._free)
+
+    def allocate(self) -> int:
+        if not self._free:
+            raise MemoryError("state slots exhausted")
+        slot = self._free.pop()
+        self.peak_in_use = max(self.peak_in_use, self.in_use)
+        return slot
+
+    def free(self, slot: int) -> None:
+        if slot:
+            assert slot not in self._free, f"double free of state slot {slot}"
+            self._free.append(slot)
+
+
+def num_state_slots(model_cfg: ModelConfig, engine_cfg: EngineConfig) -> int:
+    """State slots of a model with state-space layers, trash slot
+    included (0: the model has none): one a lane."""
+    if "ssm" not in model_cfg.layer_types[:model_cfg.n_layers]:
+        return 0
+    return engine_cfg.max_batch_size + 1
+
+
 def alloc_kind_pages(model_cfg: ModelConfig, engine_cfg: EngineConfig,
                      dtype=None) -> KVPages:
-    """A pool a kind, with the model's counter vector beside them."""
+    """A pool a kind (and the state-space layers' slots, where the model
+    has any), with the model's counter vector beside them."""
     from tpu_inference.models.registry import family_fn
 
     if engine_cfg.kv_quant != "none":
@@ -180,19 +236,33 @@ def alloc_kind_pages(model_cfg: ModelConfig, engine_cfg: EngineConfig,
             f"{model_cfg.name}: kv_quant={engine_cfg.kv_quant!r} is not "
             "implemented for per-kind pools; use kv_quant='none'")
     dtype = dtype or model_cfg.dtype
-    tail = (engine_cfg.page_size, model_cfg.n_kv_heads, model_cfg.head_dim)
+    tail = (engine_cfg.page_size, model_cfg.pool_kv_heads,
+            model_cfg.pool_head_dim)
+    if model_cfg.pool_rows_merged:
+        tail = (tail[0] * tail[1], tail[2])
+
+    def zeros(shape, dt=dtype):
+        return jax.jit(lambda: jnp.zeros(shape, dt))()
 
     def pool(kind, pages):
-        shape = (len(model_cfg.kind_layers(kind)), pages) + tail
-        return jax.jit(lambda: jnp.zeros(shape, dtype))()
+        return zeros((len(model_cfg.kind_layers(kind)), pages) + tail)
 
     n_aux = family_fn(model_cfg, "n_aux_stats")
     n_win = num_window_pages(model_cfg, engine_cfg)
+    state = {}
+    n_slots = num_state_slots(model_cfg, engine_cfg)
+    if n_slots:
+        lead = (len(model_cfg.kind_layers("ssm")), n_slots)
+        state = dict(
+            conv=zeros(lead + (model_cfg.ssm_d_conv - 1, model_cfg.d_inner)),
+            ssm_h=zeros(lead + (model_cfg.ssm_d_state, model_cfg.d_inner),
+                        jnp.float32))
     return KVPages(
         k=pool("full", engine_cfg.num_pages),
         v=pool("full", engine_cfg.num_pages),
         wk=pool("window", n_win), wv=pool("window", n_win),
-        aux=jnp.zeros((n_aux(model_cfg),), jnp.int32) if n_aux else None)
+        aux=jnp.zeros((n_aux(model_cfg),), jnp.int32) if n_aux else None,
+        **state)
 
 
 def alloc_kv_pages(model_cfg: ModelConfig, engine_cfg: EngineConfig,
@@ -313,6 +383,40 @@ def write_kv(kv: KVPages, layer_idx: jax.Array, k_new: jax.Array,
     vf = vf.at[layer_idx, flat].set(v_new.reshape(-1, H, D).astype(kv.v.dtype))
     return KVPages(k=kf.reshape(L, P, pg, H, D), v=vf.reshape(L, P, pg, H, D),
                    k_scale=k_scale, v_scale=v_scale)
+
+
+def write_kv_rows(pool: jax.Array, layer_idx: jax.Array, new: jax.Array,
+                  starts: jax.Array) -> jax.Array:
+    """write_kv for ONE pool allocated with merged rows (``[L, P, page *
+    H, D]``, ModelConfig.pool_rows_merged): window w of ``new`` [W, rows,
+    D] goes to rows ``starts[w] ..`` of layer ``layer_idx``'s pages laid
+    end to end. A window is one token's ``[H, D]`` (a decode step) or one
+    page's ``[page * H, D]`` (a prefill chunk: a sixteenth of the
+    windows, each a whole tile; the chip runs a scatter a window at a
+    time)."""
+    L, P, R, D = pool.shape
+    at = starts.reshape(-1)
+    idx = jnp.stack([jnp.broadcast_to(layer_idx, at.shape), at], axis=1)
+    dims = jax.lax.ScatterDimensionNumbers(
+        update_window_dims=(1, 2), inserted_window_dims=(0,),
+        scatter_dims_to_operand_dims=(0, 1))
+    flat = jax.lax.scatter(pool.reshape(L, P * R, D), idx.astype(jnp.int32),
+                           new.astype(pool.dtype), dims)
+    return flat.reshape(L, P, R, D)
+
+
+def page_starts(block_tables: jax.Array, first_pos: jax.Array,
+                n_valid: jax.Array, n_pages: int, page_size: int,
+                rows: int) -> jax.Array:
+    """Row starts [B, n_pages] of the pages a chunk of ``n_pages`` whole
+    pages writes from position ``first_pos`` [B] (a multiple of the page
+    size) on; a page with no valid token (``n_valid`` [B] of the chunk's
+    are) is the trash page."""
+    j = jnp.arange(n_pages)[None, :]
+    at = jnp.minimum(first_pos[:, None] // page_size + j,
+                     block_tables.shape[1] - 1)
+    pages = jnp.take_along_axis(block_tables, at, axis=1)
+    return jnp.where(j * page_size < n_valid[:, None], pages, 0) * rows
 
 
 def gather_kv(kv: KVPages, layer_idx: jax.Array,

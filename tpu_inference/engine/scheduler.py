@@ -539,7 +539,8 @@ class EngineScheduler:
         batch: List[_Pending] = []
         start_chunked: Optional[_Pending] = None
         start_adopt: Optional[_Pending] = None
-        reserved = np.zeros(2, np.int64)      # pages a kind: [full, window]
+        # pages a kind and state slots: [full, window, state]
+        reserved = np.zeros(3, np.int64)
         # What the chunk above delivered does not wait behind the
         # prefill dispatch this pass may make.
         self._post_deliveries()

@@ -69,6 +69,10 @@ QUANT_KEYS = frozenset({
     # used absorbed, as two per-head einsums, not through qdot.
     "wq_a", "wq_b", "wkv_a", "ws_gate", "ws_up", "ws_down",
     "we_gate", "we_up", "we_down",
+    # models/sambay.py: the projections (the fused MLP, the mixers' in /
+    # out, the attention's query and output). The scan's own parameters
+    # (conv taps, W_x, W_dt and its bias, A_log, D) stay in their dtype.
+    "w1", "w2", "w_in", "w_q", "w_o",
 })
 
 

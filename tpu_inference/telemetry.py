@@ -1335,19 +1335,26 @@ class StepCostModel:
       layers (``window_layers`` of ``window_heads``, ``kv_window_token_
       bytes`` a token) count a query's pairs at min(context, window):
       a record's pairs are capped at window x its query positions.
+    - ``full_readers``: layers that READ the full kind's pool a token
+      (1: each layer its own slots; a model whose cross layers read one
+      layer's K / V: that layer and its readers). Each reads every
+      visible token; the writes are the one layer's.
+    - ``state_bytes``: bytes of per-sequence state (state-space layers)
+      a lane's step reads and writes back, whether or not it advances.
     """
 
     __slots__ = ("n_params", "n_layers", "n_heads", "head_dim",
                  "weight_bytes", "kv_token_bytes", "peak_flops",
                  "peak_hbm_bw", "window", "window_layers", "window_heads",
-                 "kv_window_token_bytes")
+                 "kv_window_token_bytes", "full_readers", "state_bytes")
 
     def __init__(self, *, n_params: int, n_layers: int, n_heads: int,
                  head_dim: int, weight_bytes: int, kv_token_bytes: int,
                  peak_flops: Optional[float],
                  peak_hbm_bw: Optional[float], window: int = 0,
                  window_layers: int = 0, window_heads: int = 0,
-                 kv_window_token_bytes: int = 0):
+                 kv_window_token_bytes: int = 0, full_readers: int = 1,
+                 state_bytes: int = 0):
         # ``n_params``: parameters a token position multiplies through
         # (all of a dense model's; a routed model's ACTIVE ones; a
         # looped stack's layers once a pass). ``n_layers``: attention
@@ -1367,6 +1374,8 @@ class StepCostModel:
         self.window_layers = int(window_layers)
         self.window_heads = int(window_heads)
         self.kv_window_token_bytes = int(kv_window_token_bytes)
+        self.full_readers = int(full_readers)
+        self.state_bytes = int(state_bytes)
 
     @classmethod
     def from_engine(cls, engine) -> "StepCostModel":
@@ -1386,11 +1395,17 @@ class StepCostModel:
             kinds = dict(
                 window=mcfg.sliding_window,
                 window_layers=len(mcfg.kind_layers("window")),
-                window_heads=mcfg.window_n_heads,
+                window_heads=mcfg.window_n_heads or mcfg.n_heads,
                 kv_window_token_bytes=autosize.kv_bytes_per_token(
-                    mcfg, ecfg.kv_quant, kind="window"))
+                    mcfg, ecfg.kv_quant, kind="window"),
+                full_readers=(len(mcfg.kind_layers("full"))
+                              + len(mcfg.kind_layers("cross")))
+                // max(1, len(mcfg.kind_layers("full"))),
+                state_bytes=mcfg.state_bytes_per_seq())
+        full = len(mcfg.kind_layers("full")) if kinds else 0
         return cls(n_params=n_params,
-                   n_layers=mcfg.n_kv_slots - kinds.get("window_layers", 0),
+                   n_layers=(full * kinds["full_readers"] if kinds else
+                             mcfg.n_kv_slots),
                    n_heads=mcfg.n_heads, head_dim=head_dim,
                    weight_bytes=autosize.weight_read_bytes(mcfg, ecfg.quant),
                    kv_token_bytes=autosize.kv_bytes_per_token(
@@ -1409,10 +1424,13 @@ class StepCostModel:
 
     def hbm_bytes(self, rec: tuple) -> float:
         positions = rec[4] + rec[5]
+        lanes = rec[2] or rec[3]             # rung (decode) or slots
         return (float(self.weight_bytes) * max(1, rec[6])   # steps
-                + float(self.kv_token_bytes) * (rec[10] + positions)
+                + float(self.kv_token_bytes)
+                * (rec[10] * self.full_readers + positions)
                 + float(self.kv_window_token_bytes)
                 * (min(rec[10], self.window * positions) + positions)
+                + 2.0 * self.state_bytes * lanes * max(1, rec[6])
                 + rec[11])                   # kv_swap_bytes
 
 
@@ -2740,12 +2758,12 @@ class EngineTelemetry:
         """Read-through expert-routing counters (family deepseek_v3):
         the model counts on the device (models/deepseek_v3.py MOE_STATS),
         the counts ride the decode token readback out, the engine sums
-        them in ``engine.moe_stats`` (engine._fold_moe_stats)."""
+        them in ``engine.aux_stats`` (engine._fold_aux_stats)."""
         if not self.enabled:
             return
         from tpu_inference.models.deepseek_v3 import MOE_STATS
 
-        r, st = self.registry, engine.moe_stats
+        r, st = self.registry, engine.aux_stats
         at = {name: i for i, name in enumerate(MOE_STATS)}
         r.counter("tpu_inf_moe_tokens_total",
                   "Token positions routed, summed over expert layers",
@@ -2777,6 +2795,44 @@ class EngineTelemetry:
                       "Routed pairs per held expert",
                       fn=lambda e=e: int(st[len(MOE_STATS) + e]),
                       expert=str(e))
+
+    def bind_state(self, engine) -> None:
+        """Read-through metrics of a model with state-space layers: the
+        state slots (engine/kv_cache.py StateSlots) and what its prefill
+        programs ran for, counted on the device (models/sambay.py
+        AUX_STATS; the counts ride the decode token readback out as the
+        routing counts do)."""
+        if not self.enabled:
+            return
+        from tpu_inference.models.sambay import AUX_STATS
+
+        r, slots, st = self.registry, engine.state_slots, engine.aux_stats
+        at = {name: i for i, name in enumerate(AUX_STATS)}
+        r.gauge("tpu_inf_state_slots_total",
+                "Allocatable per-sequence state slots (state-space "
+                "layers)", fn=lambda: slots.num_slots - 1)
+        r.gauge("tpu_inf_state_slots_in_use",
+                "State slots held by a sequence", fn=lambda: slots.in_use)
+        r.gauge("tpu_inf_state_slots_peak",
+                "Most state slots held at once since boot",
+                fn=lambda: slots.peak_in_use)
+        r.gauge("tpu_inf_state_bytes_per_seq",
+                "Bytes of state one sequence's slot holds over all "
+                "state-space layers",
+                fn=lambda: engine.model_cfg.state_bytes_per_seq())
+        r.counter("tpu_inf_state_resets_total",
+                  "Prefill chunks at position 0 (a prompt's first, a "
+                  "recompute-resume's): states started from zeros",
+                  fn=lambda: slots.resets_total)
+        r.counter("tpu_inf_prefill_positions_total",
+                  "Prompt positions the prefill programs ran the layers "
+                  "up to the full-attention one for (counted in the "
+                  "graph)", fn=lambda: int(st[at["prefill_positions"]]))
+        r.counter("tpu_inf_prefill_cross_positions_total",
+                  "Positions the prefill programs ran the layers BEHIND "
+                  "the full-attention one for (one a prompt chunk when "
+                  "the skip works)",
+                  fn=lambda: int(st[at["prefill_cross_positions"]]))
 
     def bind_host_pool(self, pool) -> None:
         """Read-through metrics over the host-RAM KV tier's capacity
