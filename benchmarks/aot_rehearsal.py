@@ -70,6 +70,66 @@ def pool_copies(hlo: str, pool_shape) -> list:
         or (shape == whole and op == "copy")]
 
 
+def param_copies(hlo: str, params, device_laid: bool = False) -> list:
+    """What must not be in a step program's HLO either: a ``copy`` of a
+    whole stacked weight, a leaf ``[L, ..]`` of ``params`` (arrays or
+    shapes, as the program takes them) whose layers are matrices. The
+    compiler puts one in front of the layer loop, once a dispatch, where
+    the stored layout is not the one the loop's contraction reads
+    (models/quant.py STORED_TRANSPOSED: the stacks that feed attention
+    are stored ``[.., N, K]`` for that reason). Counted: a ``copy``
+    whose result has such a leaf's dtype and shape and whose operand IS
+    a parameter of the program (seen through asynchronous copies and
+    slices and plumbing) that the device holds row-major, the last dim
+    minor-most: the orientation is then the engine's to choose. One
+    layer's slice inside the loop is the projection's read and stays.
+    With ``device_laid``, instead, the copies of parameters the device
+    itself holds otherwise (a stack with a narrow last dim, 72 or 192 or
+    576 wide, arrives ``{1,2,0}`` whichever way it is stored; a few MB,
+    for information). Each entry names the parameter (its ``op_name``)
+    and the MB the copy writes. tests/test_tpu_compile.py uses it too."""
+    import re
+
+    import jax
+
+    short = {"int8": "s8", "bfloat16": "bf16", "float32": "f32",
+             "float16": "f16"}
+    stacks = {(short.get(str(x.dtype), str(x.dtype)),
+               ",".join(map(str, x.shape))): x.size * x.dtype.itemsize
+              for x in jax.tree.leaves(params)
+              if len(x.shape) >= 3 and sum(d > 1 for d in x.shape[1:]) >= 2}
+    # name -> (dtype, dims, minor-to-major, op, first operand, op_name)
+    inst = {m[0]: m[1:] for m in re.findall(
+        r"^\s*(?:ROOT )?(%[\w.\-]+) = \(*(\w+)\[([\d,]*)\](?:\{([\d,]*))?\S*"
+        r"(?: [^=]*?\))? ([\w\-]+)\((%[\w.\-]+)?"
+        r"(?:.*?op_name=\"([^\"]*)\")?", hlo, re.M)}
+    through = {"copy-start", "copy-done", "slice-start", "slice-done",
+               "custom-call", "bitcast", "get-tuple-element"}
+
+    def source(name):
+        """The entry parameter ``name`` is a view or an asynchronous copy
+        of: (its op_name, held row-major?)."""
+        while name in inst:
+            _, dims, order, op, operand, meta = inst[name]
+            if op == "parameter":
+                n = dims.count(",") + 1
+                row_major = order == ",".join(map(str, range(n - 1, -1, -1)))
+                return (meta.replace("\\'", "'"), row_major) if meta else None
+            if op not in through:
+                return None
+            name = operand
+        return None
+
+    out = []
+    for name, (dtype, dims, _, op, operand, _) in inst.items():
+        src = (source(operand) if op == "copy" and (dtype, dims) in stacks
+               else None)
+        if src and src[1] != device_laid:
+            out.append(f"copy {name} {dtype}[{dims}] of {src[0]}: "
+                       f"{stacks[dtype, dims] / 1e6:.1f} MB")
+    return out
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--model", default="mistral-7b")
@@ -110,7 +170,8 @@ def main() -> int:
     from tpu_inference.engine import kv_cache as kvc
     from tpu_inference.engine import staging
     from tpu_inference.engine.engine import InferenceEngine
-    from tpu_inference.models.quant import init_quantized_params
+    from tpu_inference.models.quant import (init_quantized_params,
+                                            store_transposed)
 
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name=args.topology)
@@ -152,6 +213,10 @@ def main() -> int:
         (lambda: init_quantized_params(mcfg, 0, args.quant))
         if args.quant != "none"
         else (lambda: eng.mod.init_params(mcfg, jax.random.PRNGKey(0))))
+    # ... as the engine holds them: the stored orientation, from the
+    # helper its constructor calls.
+    shapes = jax.eval_shape(
+        lambda p: store_transposed(p, mcfg.family)[0], shapes)
     kv_shapes = jax.eval_shape(lambda: kvc.alloc_kv_pages(mcfg, ecfg))
 
     def sds(tree, shardings):
@@ -255,7 +320,10 @@ def main() -> int:
             wshape = tuple(kv.wk.shape)
             copies += (pool_copies(text, wshape) + pool_copies(
                 text, wshape[:2] + (wshape[2] * wshape[3],) + wshape[4:]))
-        failed += bool(copies)
+        pcopies, laid = ((param_copies(text, params),
+                          param_copies(text, params, device_laid=True))
+                         if graph != "swap" else ([], []))
+        failed += bool(copies or pcopies)
         if args.dump_hlo:
             os.makedirs(args.dump_hlo, exist_ok=True)
             with open(os.path.join(args.dump_hlo,
@@ -270,7 +338,8 @@ def main() -> int:
                                 - m.alias_size_in_bytes) / 1e9, 3),
             "temp_GB": round(m.temp_size_in_bytes / 1e9, 3),
             "tpu_custom_call": text.count("tpu_custom_call"),
-            "pool_copies": copies,
+            "pool_copies": copies, "param_copies": pcopies,
+            "param_copies_device_laid": laid,
             "collectives": {c: text.count(f" {c}(") for c in (
                 "all-reduce", "all-gather", "all-to-all",
                 "collective-permute") if f" {c}(" in text}}), flush=True)

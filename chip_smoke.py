@@ -1229,7 +1229,9 @@ def child_tp4_parity(cfg: dict) -> dict:
             # Placement, read off the arrays: every weight matrix and the
             # pool have one shard on each of the four devices, and a
             # tp-sharded one holds a quarter of it.
-            for label, arr in (("wq", eng.params["blocks"]["wq"]),
+            # (wq is stored [L, N, K], a one-child node: its array)
+            for label, arr in (("wq", jax.tree.leaves(
+                                    eng.params["blocks"]["wq"])[0]),
                                ("w_down", eng.params["blocks"]["w_down"]),
                                ("kv pool", eng.kv.k)):
                 devs = {s.device.id for s in arr.addressable_shards}

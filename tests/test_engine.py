@@ -370,8 +370,9 @@ def test_check_numerics():
     engine.check_numerics()               # clean: no raise
 
     poisoned = jax.tree.map(lambda x: x, engine.params)
-    poisoned["blocks"]["wq"] = poisoned["blocks"]["wq"].at[0, 0, 0].set(
-        jnp.nan)
+    # (wq is stored transposed, a one-child node: poison its array)
+    poisoned["blocks"]["wq"] = jax.tree.map(
+        lambda x: x.at[0, 0, 0].set(jnp.nan), poisoned["blocks"]["wq"])
     engine.params = poisoned
     with pytest.raises(FloatingPointError, match="wq"):
         engine.check_numerics()

@@ -335,3 +335,86 @@ def test_selective_scan_compiles_at_the_published_width(chip, lanes, rows):
         sds((n, d), jnp.float32), sds((d,), jnp.float32),
         sds((lanes, n, d), jnp.float32), sds((lanes,), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# ---------------------------------------------------------------------------
+# The weight stacks in a step program: stored [.., N, K] (models/quant.py
+# STORED_TRANSPOSED), the chip compiler reads one layer's matrix in place;
+# stored as published it copies the whole stack in front of the layer
+# loop, once a dispatch.
+# ---------------------------------------------------------------------------
+
+def _forward_hlo(chip, name, quant_mode, rows, stored=True):
+    """The chip compiler's HLO of ``name``'s forward over its stacked
+    weights (cache-free attention: the projections are what is read
+    here) on ``rows`` = (sequences, tokens each), the weights in the
+    engine's stored orientation or, planted, left as published. One
+    token a sequence is the decode program's shape: eight steps in one
+    call, each fed the last one's tokens, as the fused-K scan runs them
+    (the whole-stack copy is hoisted out of THAT loop)."""
+    from tpu_inference.config import PRESETS
+    from tpu_inference.models import laguna, quant
+    from tpu_inference.models.common import make_dense_attn
+    from tpu_inference.models.registry import get_model_fns
+
+    cfg = PRESETS[name]()
+    mod = get_model_fns(cfg)
+    shapes = jax.eval_shape(
+        (lambda: quant.init_quantized_params(cfg, 0, quant_mode))
+        if quant_mode != "none"
+        else (lambda: mod.init_params(cfg, jax.random.PRNGKey(0))))
+    if stored:
+        shapes = jax.eval_shape(
+            lambda p: quant.store_transposed(p, cfg.family)[0], shapes)
+    params = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=chip), shapes)
+    attn = (laguna.make_dense_attn(cfg) if cfg.family == "laguna"
+            else make_dense_attn(cfg.sliding_window))
+
+    def forward(params, tokens, positions):
+        hidden, _ = mod.forward_hidden(params, cfg, tokens, positions, None,
+                                       attn)
+        return mod.unembed(params, cfg, hidden[:, -1])
+
+    def program(params, tokens, positions):
+        if rows[1] > 1:
+            return forward(params, tokens, positions)
+
+        def step(tokens, k):
+            nxt = jnp.argmax(forward(params, tokens, positions + k), -1)
+            return nxt[:, None].astype(jnp.int32), nxt
+
+        return jax.lax.scan(step, tokens, jnp.arange(8))[1]
+
+    ids = jax.ShapeDtypeStruct(rows, jnp.int32, sharding=chip)
+    return jax.jit(program).lower(params, ids, ids).compile().as_text(), params
+
+
+@pytest.mark.parametrize("name,quant_mode,rows", [
+    ("mistral-7b", "int8", (8, 1)), ("mistral-7b", "int8", (1, 1024)),
+    ("tiny-ouro", "none", (4, 1)), ("tiny-ouro", "none", (1, 32)),
+    ("tiny-laguna", "none", (4, 1)), ("tiny-laguna", "none", (1, 32))])
+def test_no_program_copies_a_stored_weight_stack(chip, name, quant_mode,
+                                                 rows):
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir,
+                                    "benchmarks"))
+    from aot_rehearsal import param_copies
+
+    hlo, params = _forward_hlo(chip, name, quant_mode, rows)
+    assert param_copies(hlo, params) == []
+
+
+def test_a_stack_left_as_published_is_copied_and_seen(chip):
+    """The guard bites: Mistral's q / k / v stacks left ``[L, K, N]`` are
+    copied whole in front of the layer loop of a decode-shaped program,
+    and ``param_copies`` names all three."""
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir,
+                                    "benchmarks"))
+    from aot_rehearsal import param_copies
+
+    hlo, params = _forward_hlo(chip, "mistral-7b", "int8", (8, 1),
+                               stored=False)
+    found = param_copies(hlo, params)
+    assert len(found) == 3, found
+    for leaf in ("wq", "wk", "wv"):
+        assert any(f"['{leaf}']" in c for c in found), (leaf, found)

@@ -44,6 +44,7 @@ from tpu_inference.engine.sampling import (
     sample,
 )
 from tpu_inference.engine.speculative import NGRAM_SCAN_CAP, ngram_propose
+from tpu_inference.models.quant import store_transposed
 from tpu_inference.models.registry import (build_model, family_fn,
                                           get_model_fns)
 
@@ -747,6 +748,7 @@ class InferenceEngine:
             from tpu_inference.models.quant import quantize_params
             return quantize_params(p, engine_cfg.quant)
 
+        built = params is None      # the arrays are this engine's alone
         if params is None:
             if engine_cfg.quant != "none":
                 # Leaf-by-leaf init+quantize: peak device memory stays
@@ -777,18 +779,27 @@ class InferenceEngine:
             params = shd.shard_params(params, model_cfg, mesh)
             kv_sh = shd.kv_sharding(mesh)
             kv_scale_sh = shd.kv_scale_sharding(mesh)
-        self.params = params
+        # Weights enter as published, [.., K, N]; the stacks that feed
+        # attention are STORED [.., N, K] (models/quant.py
+        # STORED_TRANSPOSED), swapped here once, and contracted on their
+        # last dim: the layout every decode program would otherwise copy
+        # them into once a dispatch. Whoever reads ``engine.params`` as a
+        # checkpoint holds it goes through ``quant.published``.
+        self.params, n_swapped = store_transposed(params, model_cfg.family,
+                                                  owned=built)
+        del params
         # Boot phases are host walls: no sync is added to time them, so
         # whatever the device still owes on the weights when their last
         # program is enqueued lands in the phases after (warm-up ends
         # in the boot's one block_until_ready).
         t_weights = time.perf_counter()
-        self.n_params = int(sum(x.size for x in jax.tree.leaves(params)))
+        self.n_params = int(sum(x.size
+                                for x in jax.tree.leaves(self.params)))
         # Resident bytes of the (possibly quantized) weights — global
         # logical size, independent of sharding. Reported by /api/ps and
         # used by bench.py's hbm_util roofline math.
         self.weight_bytes = int(sum(x.nbytes
-                                    for x in jax.tree.leaves(params)))
+                                    for x in jax.tree.leaves(self.params)))
         self.attn_backend = backend
         self.kv = kvc.alloc_kv_pages(model_cfg, engine_cfg, sharding=kv_sh,
                                      scale_sharding=kv_scale_sh)
@@ -843,6 +854,7 @@ class InferenceEngine:
                        "pool": t_pool - t_weights}
         self.telemetry.boot_weights_s.set(self.boot_s["weights"])
         self.telemetry.boot_pool_s.set(self.boot_s["pool"])
+        self.telemetry.weight_stacks_transposed.set(n_swapped)
         # Monotone dispatch number of the step programs (the ledger's
         # ``seq``, the tpu_inf/dispatch annotation's ``seq``), and the
         # prefill chunks enqueued whose result no readback has covered
@@ -1217,7 +1229,8 @@ class InferenceEngine:
                 from tpu_inference.parallel import shardings as _shd
                 draft_params = _shd.shard_params(draft_params, draft_cfg,
                                                  mesh)
-            self.draft_params = draft_params
+            self.draft_params, _ = store_transposed(draft_params,
+                                                    draft_cfg.family)
             self.draft_kv = kvc.alloc_kv_pages(draft_cfg, engine_cfg,
                                                sharding=kv_sh,
                                                scale_sharding=kv_scale_sh)

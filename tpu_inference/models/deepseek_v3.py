@@ -51,7 +51,7 @@ from tpu_inference.models.common import (
     swiglu,
     yarn_mscale,
 )
-from tpu_inference.models.quant import qdot
+from tpu_inference.models.quant import qdot, split_heads
 
 # kv.aux slots, then one per held expert (its routed pairs).
 MOE_STATS = ("tokens", "local_pairs", "computed_pairs", "busiest_pairs",
@@ -176,7 +176,7 @@ def latent_attention(cfg: ModelConfig, layer_idx, lp: dict, h: jax.Array,
         k_rope = apply_rope(ckv[..., None, r:], positions, cfg.rope_theta,
                             cfg.rope_scaling)[:, :, 0]
         entry = jnp.concatenate([c, k_rope], axis=-1)
-    w_kvb = lp["wkv_b"].reshape(r, nh, dn + dv)                   # bf16
+    w_kvb = split_heads(lp["wkv_b"], nh)              # bf16 [r, nh, dn+dv]
     with jax.named_scope("mla_absorb_q"):
         q_lat = jnp.einsum("bshn,rhn->bshr", q[..., :dn], w_kvb[..., :dn],
                            preferred_element_type=jnp.float32

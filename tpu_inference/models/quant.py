@@ -30,6 +30,18 @@ Design:
   group count alone: G == 1 scales the contraction *output* (exact
   because the scale is constant along the contracted axis), G > 1 runs a
   grouped contraction and folds the per-group partial sums.
+- STORED orientation. Weights are published, initialised, quantized,
+  loaded and sharded as ``[.., K, N]`` (contraction dim second to last).
+  The engine, once, when it takes its weights, stores the stacks that
+  feed attention (``STORED_TRANSPOSED``, one entry a family) swapped,
+  ``[.., N, K]``: row-major with the contraction dim minor-most is the
+  layout the chip compiler reads a layer's matrix in when the
+  activation has few rows, and a stack stored the other way round is
+  copied whole in front of the layer loop, once a dispatch. A swapped
+  leaf says so in its TYPE (``QuantizedArray.transposed``, a static
+  field; ``Transposed``, a one-child node, for a plain stack), so it
+  rides ``lax.scan`` and tree maps like any leaf and ``qdot`` contracts
+  it on its last dim. No model file names a layout.
 - Under tensor parallelism GSPMD shards the grouped partials like any
   einsum; for G == 1 it may place the all-reduce before or after the
   scale — both are exact.
@@ -75,6 +87,20 @@ QUANT_KEYS = frozenset({
     "w1", "w2", "w_in", "w_q", "w_o",
 })
 
+# The stacks the engine stores transposed, ``[.., N, K]`` (see the module
+# docstring), by family and leaf name: those the v5e compiler's HLO of
+# the decode programs shows copied whole in front of the layer loop
+# (``benchmarks/aot_rehearsal.py``'s ``param_copies``; the table is in
+# PERF.md section 5), read off the HLO and not off a model's name. A
+# family with no entry stores every leaf as published.
+STORED_TRANSPOSED = {
+    "llama": frozenset({"wq", "wk", "wv"}),
+    "ouro": frozenset({"wq", "wk", "wv"}),
+    "laguna": frozenset({"wq", "wk", "wv"}),
+    "deepseek_v3": frozenset({"wq_b", "wkv_b"}),
+    "sambay": frozenset({"w_q"}),
+}
+
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
@@ -83,10 +109,14 @@ class QuantizedArray:
 
     int8: scale [..., 1, out] (axis -2 reduced). int4: scale
     [..., G, out] with G groups along the contraction dim.
+    ``transposed`` (static: part of the tree's structure): ``q`` is
+    stored ``[..., out, in]``; the scale is as above either way.
     """
 
     q: jax.Array
     scale: jax.Array
+    transposed: bool = dataclasses.field(default=False,
+                                         metadata=dict(static=True))
 
     @property
     def shape(self):
@@ -103,6 +133,32 @@ class QuantizedArray:
     @property
     def ndim(self):
         return self.q.ndim
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class Transposed:
+    """A plain weight stored ``[..., out, in]``: the published
+    ``[..., in, out]`` with its last two dims swapped. A square stack's
+    shape cannot say which it is, so the type does."""
+
+    w: jax.Array
+
+    @property
+    def shape(self):
+        return self.w.shape
+
+    @property
+    def dtype(self):
+        return self.w.dtype
+
+    @property
+    def size(self):
+        return self.w.size
+
+    @property
+    def ndim(self):
+        return self.w.ndim
 
 
 def _contract_dtype(act_dtype):
@@ -131,13 +187,18 @@ def pack_int4(codes: jax.Array) -> jax.Array:
     return (lo & jnp.int8(0x0F)) | (hi << 4)
 
 
-def unpack_int4(packed: jax.Array) -> jax.Array:
+def unpack_int4(packed: jax.Array, transposed: bool = False) -> jax.Array:
     """Packed int8 [..., in // 2, out] -> sign-extended int8 codes
-    [..., in, out]. Two arithmetic shifts per nibble — elementwise, so
-    XLA fuses the unpack into the consuming matmul's operand read."""
-    *lead, half, out = packed.shape
+    [..., in, out] (``transposed``: [..., out, in // 2] -> [..., out,
+    in]; the pairs lie along the contraction dim either way). Two
+    arithmetic shifts per nibble — elementwise, so XLA fuses the unpack
+    into the consuming matmul's operand read."""
     lo = (packed << 4) >> 4                      # sign-extend low nibble
     hi = packed >> 4                             # arithmetic: sign-extends
+    if transposed:
+        *lead, out, half = packed.shape
+        return jnp.stack([lo, hi], axis=-1).reshape(*lead, out, 2 * half)
+    *lead, half, out = packed.shape
     return jnp.stack([lo, hi], axis=-2).reshape(*lead, 2 * half, out)
 
 
@@ -168,6 +229,8 @@ def quantize_array(w: jax.Array, mode: str = "int8") -> QuantizedArray:
 
 
 def dequantize(w: QuantizedArray, dtype=jnp.float32) -> jax.Array:
+    """The weight in the published orientation, [..., in, out]."""
+    w = published(w)
     ngrp = w.scale.shape[-2]
     if ngrp == 1:
         return (w.q.astype(jnp.float32) * w.scale).astype(dtype)
@@ -178,30 +241,58 @@ def dequantize(w: QuantizedArray, dtype=jnp.float32) -> jax.Array:
     return full.reshape(codes.shape).astype(dtype)
 
 
-def qdot(x: jax.Array, w: Any) -> jax.Array:
-    """``x @ w`` with f32 accumulation; w may be a QuantizedArray.
+def _dot_stored(x: jax.Array, w: jax.Array, transposed: bool) -> jax.Array:
+    """x [..., in] against one layer's matrix as it is stored ([in, out],
+    or [out, in] ``transposed``: contracted on its last dim), f32 out."""
+    if transposed:
+        return jax.lax.dot_general(
+            x, w, (((x.ndim - 1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+    return jnp.dot(x, w, preferred_element_type=jnp.float32)
 
-    x: [..., in]; w: [in, out] (or quantized). Returns f32 [..., out].
+
+def qdot(x: jax.Array, w: Any) -> jax.Array:
+    """``x @ w`` with f32 accumulation; w may be a QuantizedArray, and
+    stored transposed (its type says so: see the module docstring).
+
+    x: [..., in]; w: [in, out] as published. Returns f32 [..., out].
     """
+    if isinstance(w, Transposed):
+        return _dot_stored(x, w.w, True)
     if isinstance(w, QuantizedArray):
         ngrp = w.scale.shape[-2]
         if ngrp == 1:
-            y = jnp.dot(x, w.q.astype(x.dtype),
-                        preferred_element_type=jnp.float32)
+            y = _dot_stored(x, w.q.astype(x.dtype), w.transposed)
             return y * w.scale[..., 0, :]
         # Grouped (int4): unpack the nibble-packed codes (fuses into the
         # operand read), contract each group separately, fold the
         # per-group partials with their own scales. HBM still reads only
         # the packed 4-bit codes + the small scale table.
-        codes = unpack_int4(w.q)
-        gsz = codes.shape[-2] // ngrp
+        codes = unpack_int4(w.q, w.transposed)
         ct = _contract_dtype(x.dtype)
+        if w.transposed:                   # [out, in]: groups along the last
+            out, gsz = codes.shape[-2], codes.shape[-1] // ngrp
+            eq, qg = "...gi,ogi->...go", codes.reshape(out, ngrp, gsz)
+        else:
+            out, gsz = codes.shape[-1], codes.shape[-2] // ngrp
+            eq, qg = "...gi,gio->...go", codes.reshape(ngrp, gsz, out)
         xg = x.reshape(x.shape[:-1] + (ngrp, gsz)).astype(ct)
-        qg = codes.reshape(ngrp, gsz, codes.shape[-1]).astype(ct)
-        y = jnp.einsum("...gi,gio->...go", xg, qg,
+        y = jnp.einsum(eq, xg, qg.astype(ct),
                        preferred_element_type=jnp.float32)
         return jnp.sum(y * w.scale, axis=-2)
     return jnp.dot(x, w, preferred_element_type=jnp.float32)
+
+
+def split_heads(w: Any, heads: int) -> jax.Array:
+    """One layer's plain ``[in, heads * d]`` matrix as ``[in, heads, d]``,
+    for a per-head einsum (models/deepseek_v3.py's absorbed ``wkv_b``),
+    whichever way it is stored. A ``Transposed`` one is swapped back in
+    name only: the compiler takes a transpose as a change of layout, so
+    the loop reads the stored bytes as it read its own copy of the
+    published stack."""
+    if isinstance(w, Transposed):
+        w = jnp.swapaxes(w.w, -1, -2)
+    return w.reshape(w.shape[0], heads, -1)
 
 
 def qeinsum(eq: str, a: jax.Array, w: Any) -> jax.Array:
@@ -234,6 +325,90 @@ def qeinsum(eq: str, a: jax.Array, w: Any) -> jax.Array:
     return jnp.einsum(eq, a, w, preferred_element_type=jnp.float32)
 
 
+def _leaf_name(path) -> str:
+    last = path[-1]
+    return last.key if hasattr(last, "key") else str(last)
+
+
+def _is_weight(x) -> bool:
+    return isinstance(x, (QuantizedArray, Transposed))
+
+
+def is_transposed(leaf) -> bool:
+    """Whether a params leaf is stored ``[.., N, K]``: its type says."""
+    return isinstance(leaf, Transposed) or getattr(leaf, "transposed", False)
+
+
+def transposed_spec(spec, ndim: int):
+    """A PartitionSpec of a published ``[.., K, N]`` weight, for the same
+    weight stored ``[.., N, K]``: each dim keeps its mesh axis."""
+    entries = list(spec) + [None] * (ndim - len(spec))
+    entries[-1], entries[-2] = entries[-2], entries[-1]
+    return type(spec)(*entries)
+
+
+def store_transposed(params: dict, family: str, owned: bool = False):
+    """The engine's one step from published to stored orientation:
+    swap the last two dims of every ``STORED_TRANSPOSED[family]`` leaf of
+    ``params`` on the device (a jitted transpose a distinct shape; a
+    sharded leaf's dims keep their mesh axes) and say so in the leaf's
+    type. Quantization has run before, on the published orientation: the
+    codes move, the scale does not. With ``owned`` (the caller made the
+    arrays and holds no other use of them) each swapped input is freed
+    at once, so the peak is one leaf over the weights. Returns (params,
+    the number of stacks swapped). Abstract trees work too
+    (``jax.eval_shape``: benchmarks/aot_rehearsal.py takes the stored
+    shapes from here, as the engine does)."""
+    from jax.sharding import NamedSharding
+
+    names = STORED_TRANSPOSED.get(family, frozenset())
+    swaps: dict = {}
+    n = 0
+
+    def swap(x):
+        sh = getattr(x, "sharding", None)   # (None on an abstract leaf)
+        out = (NamedSharding(sh.mesh, transposed_spec(sh.spec, x.ndim))
+               if isinstance(sh, NamedSharding) else None)
+        if out not in swaps:
+            def tpu_inf_store_transposed(a):
+                return jnp.swapaxes(a, -1, -2)
+            swaps[out] = jax.jit(tpu_inf_store_transposed,
+                                 out_shardings=out)
+        y = swaps[out](x)
+        if owned:
+            x.delete()
+        return y
+
+    def store(path, leaf):
+        nonlocal n
+        if _leaf_name(path) not in names or is_transposed(leaf):
+            return leaf
+        n += 1
+        if isinstance(leaf, QuantizedArray):
+            return QuantizedArray(q=swap(leaf.q), scale=leaf.scale,
+                                  transposed=True)
+        return Transposed(swap(leaf))
+
+    stored = jax.tree_util.tree_map_with_path(store, params,
+                                              is_leaf=_is_weight)
+    return stored, n
+
+
+def published(tree: Any) -> Any:
+    """``tree`` (a params tree, or one leaf) with every weight the engine
+    stores transposed back in the published ``[.., K, N]`` orientation:
+    for whoever reads ``engine.params`` as a checkpoint would hold it."""
+    def back(leaf):
+        if isinstance(leaf, Transposed):
+            return jnp.swapaxes(leaf.w, -1, -2)
+        if isinstance(leaf, QuantizedArray) and leaf.transposed:
+            return QuantizedArray(q=jnp.swapaxes(leaf.q, -1, -2),
+                                  scale=leaf.scale)
+        return leaf
+
+    return jax.tree.map(back, tree, is_leaf=_is_weight)
+
+
 def quantize_params(params: dict, mode: str = "int8") -> dict:
     """Quantize the matmul weights of a params pytree (QUANT_KEYS leaves).
 
@@ -250,9 +425,7 @@ def quantize_params(params: dict, mode: str = "int8") -> dict:
     quant_jit = jax.jit(functools.partial(quantize_array, mode=mode))
 
     def maybe_quant(path, leaf):
-        last = path[-1]
-        name = last.key if hasattr(last, "key") else str(last)
-        if name in QUANT_KEYS:
+        if _leaf_name(path) in QUANT_KEYS:
             return quant_jit(leaf)
         return leaf
 
@@ -289,13 +462,9 @@ def init_quantized_params(model_cfg, seed: int = 0,
     leaves, treedef = jax.tree_util.tree_flatten_with_path(shapes)
     key = jax.random.PRNGKey(seed)
 
-    def name_of(path):
-        last = path[-1]
-        return last.key if hasattr(last, "key") else str(last)
-
     out = []
     for path, sds in leaves:
-        name = name_of(path)
+        name = _leaf_name(path)
         key, sub = jax.random.split(key)
         if name in QUANT_KEYS:
             out.append(jax.jit(

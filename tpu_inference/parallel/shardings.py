@@ -29,7 +29,8 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from tpu_inference.config import ModelConfig
-from tpu_inference.models.quant import QuantizedArray
+from tpu_inference.models.quant import (QuantizedArray, Transposed,
+                                        transposed_spec)
 
 
 def _llama_specs(cfg: ModelConfig) -> dict:
@@ -151,7 +152,10 @@ def param_shardings(cfg: ModelConfig, mesh: Mesh,
     leaves). With ``params`` (possibly holding int8 ``QuantizedArray``
     leaves, models/quant.py) the result mirrors the actual params tree:
     the quantized payload takes the weight's spec, the scale the same
-    spec with its reduced contraction dim unsharded.
+    spec with its reduced contraction dim unsharded. A leaf stored
+    transposed (the engine's own orientation, ``quant.store_transposed``)
+    takes the spec with its last two entries swapped: each dim keeps
+    its axis.
     """
     validate_tp(cfg, mesh.shape.get("tp", 1))
     specs = param_specs(cfg)
@@ -160,6 +164,9 @@ def param_shardings(cfg: ModelConfig, mesh: Mesh,
                             is_leaf=lambda x: isinstance(x, P))
 
     def mk(spec: P, leaf: Any):
+        if isinstance(leaf, Transposed):
+            return Transposed(NamedSharding(
+                mesh, transposed_spec(spec, leaf.ndim)))
         if isinstance(leaf, QuantizedArray):
             sspec = _scale_spec(spec, leaf)
             ngrp = leaf.scale.shape[-2]
@@ -178,9 +185,12 @@ def param_shardings(cfg: ModelConfig, mesh: Mesh,
                         f"divide evenly; use a tp that divides the group "
                         f"count (dim/{2 * leaf.q.shape[-2] // ngrp}, "
                         "codes nibble-packed) or --quant int8")
+            if leaf.transposed:
+                spec = transposed_spec(spec, leaf.q.ndim)
             return QuantizedArray(
                 q=NamedSharding(mesh, spec),
-                scale=NamedSharding(mesh, sspec))
+                scale=NamedSharding(mesh, sspec),
+                transposed=leaf.transposed)
         return NamedSharding(mesh, spec)
 
     return jax.tree.map(mk, specs, params,

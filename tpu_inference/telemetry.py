@@ -2442,6 +2442,7 @@ class EngineTelemetry:
             self.kv_restore_bytes = NULL_METRIC
             self.boot_weights_s = self.boot_pool_s = NULL_METRIC
             self.boot_warmup_s = self.boot_ready_s = NULL_METRIC
+            self.weight_stacks_transposed = NULL_METRIC
             return
         r = self.registry
         register_span_ring(r, self.recorder)
@@ -2470,6 +2471,11 @@ class EngineTelemetry:
         self.boot_ready_s = r.gauge(
             "tpu_inf_boot_ready_seconds",
             "Boot: process start to serving")
+        self.weight_stacks_transposed = r.gauge(
+            "tpu_inf_weight_stacks_transposed",
+            "Weight stacks the engine stores transposed, [.., N, K] "
+            "(models/quant.py STORED_TRANSPOSED; set once, where the "
+            "swap is done)")
         self.prefill_dispatch_s = r.histogram(
             "tpu_inf_prefill_dispatch_seconds",
             "Host wall time of one prefill dispatch")
