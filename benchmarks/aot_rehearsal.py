@@ -19,6 +19,10 @@ PR 25) — and a graph that has one counts as refused.
                                              # graph (16 of them, ~25 s each)
     python benchmarks/aot_rehearsal.py --tp 4 --quant none     # bf16, 2x2 mesh
     python benchmarks/aot_rehearsal.py --graphs prefill:4x512 decode:16
+    python benchmarks/aot_rehearsal.py --model smallthinker-21b-pp4 \
+        --quant none --max-pages-per-seq 512 --target-ctx 1024 \
+        --batch-cap 64       # a pool a kind, the window kind's sized on
+                             # live tokens: 18 graphs, 64 lanes
 
 Compile EVERY graph the warm-up will run: the v5e compiler refused
 exactly one (prefill 4x512: the kernel's VMEM plus an operand XLA

@@ -1045,7 +1045,10 @@ def _routed_expert_errors(cfg: dict, *, preset: str = "kimi-k2-ep32",
     experts and gates (so no pair changes expert between the two).
     ``routed_*``: the program's error. ``planted_*``: what a fault in
     that path reads by the same measure (the smallest over the row
-    counts), to be far over the tolerance."""
+    counts), to be far over the tolerance. A preset that holds EVERY
+    expert of a layer with no shared one beside them
+    (``smallthinker-21b-pp4``: 64 held, top-6, relu) runs the same
+    check: 6 rows an expert at 64 tokens, 96 at 1024."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -1065,10 +1068,17 @@ def _routed_expert_errors(cfg: dict, *, preset: str = "kimi-k2-ep32",
 
     experts = (mat(key[0], (2, held, d, f)), mat(key[1], (2, held, d, f)),
                mat(key[2], (2, held, f, d)))
-    lp = {"w_router": mat(key[3], (d, mcfg.n_experts)),
-          "router_bias": 0.01 * jax.random.normal(key[4], (mcfg.n_experts,)),
-          "ws_gate": mat(key[5], (d, fs)), "ws_up": mat(key[6], (d, fs)),
-          "ws_down": mat(key[7], (fs, d))}
+    lp = {"w_router": mat(key[3], (d, mcfg.n_experts))}
+    if mcfg.moe_scoring == "sigmoid":       # the selection bias is its
+        lp["router_bias"] = 0.01 * jax.random.normal(key[4],
+                                                     (mcfg.n_experts,))
+    if fs:      # (a preset with no shared expert has no such leaves)
+        lp.update(ws_gate=mat(key[5], (d, fs)), ws_up=mat(key[6], (d, fs)),
+                  ws_down=mat(key[7], (fs, d)))
+    act = moe_experts.ACTS[mcfg.moe_act]
+
+    def expert(x, wg, wu, wd):
+        return (act(x @ wg) * (x @ wu)) @ wd
 
     def attn_of(valid):
         def attn(*a):
@@ -1089,12 +1099,13 @@ def _routed_expert_errors(cfg: dict, *, preset: str = "kimi-k2-ep32",
             x = h[:, 0].astype(jnp.float32)
             top, gates = dsv3.route(mcfg, lp, h[:, 0])
             first = mcfg.ep_rank * held
-            y = dsv3.swiglu(x, *(lp[k].astype(jnp.float32) for k in
-                                 ("ws_gate", "ws_up", "ws_down")))
+            y = (dsv3.swiglu(x, *(lp[k].astype(jnp.float32) for k in
+                                  ("ws_gate", "ws_up", "ws_down")))
+                 if fs else jnp.zeros_like(x))
             for e in range(held):
                 ge = jnp.sum(jnp.where((top == first + e) & valid, gates,
                                        0.0), axis=1)
-                y = y + ge[:, None] * dsv3.swiglu(
+                y = y + ge[:, None] * expert(
                     x, *(w[layer, e].astype(jnp.float32) for w in experts))
             return y
 
@@ -1186,6 +1197,20 @@ def child_parity(cfg: dict) -> dict:
         res["routed_expert_err"] = _routed_expert_errors(cfg)
         check_routed_experts(res["routed_expert_err"],
                              cfg["routed_expert_tol"])
+        # Every expert of a layer held (64, top-6, relu), and the GQA
+        # kernels at that stack's shapes: 28 query heads on 4 KV heads,
+        # a 4096-token window and none (the rope-less full layers).
+        res["all_held_expert_err"] = _routed_expert_errors(
+            cfg, preset="smallthinker-21b-pp4", tokens=(64, 1024))
+        check_routed_experts(res["all_held_expert_err"],
+                             cfg["routed_expert_tol"])
+        res["nope_window_kernel_err"] = _mixed_kernel_errors(
+            cfg, kv_heads=4, kinds=((28, 4096), (28, 0)), lanes=4,
+            ctx=5000, rows=1024)
+        check(max(res["nope_window_kernel_err"].values())
+              <= cfg["kernel_tol"],
+              f"Pallas kernels at 28 heads on 4 KV heads, window 4096 and "
+              f"none, vs dense float32: {res}")
     return {"ok": True, "layers": cfg["parity_layers"],
             "depth_cut": f"{cfg['parity_layers']} of the model's layers: "
                          "what a float32 reference fits beside on one chip",

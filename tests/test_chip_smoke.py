@@ -214,6 +214,26 @@ def test_mixed_kernel_check_in_interpret_mode():
     assert max(errs.values()) <= chip_smoke.SETTINGS["kernel_tol"]
 
 
+def test_all_held_expert_and_nope_window_checks_in_interpret_mode():
+    """The smoke's checks for a stage that holds every expert of its
+    layers (run on the chip at SmallThinker-21B's: 64 held, top-6, relu,
+    no shared expert; 28 query heads on 4 KV heads over a window of 4096
+    and over everything) at the tiny preset through the interpreter."""
+    errs = chip_smoke._routed_expert_errors(
+        TINY, preset="tiny-smallthinker", tokens=(8, 64), idle=3,
+        interpret=True)
+    assert set(errs) == {"routed_8", "routed_64", "planted_zero",
+                         "planted_next_expert", "planted_layer_0"}
+    chip_smoke.check_routed_experts(errs,
+                                    chip_smoke.SETTINGS["routed_expert_tol"])
+    errs = chip_smoke._mixed_kernel_errors(
+        TINY, d=16, kv_heads=2, kinds=((8, 32), (8, 0)), slots=4, lanes=4,
+        ctx=90, rows=64, interpret=True)
+    assert set(errs) == {"h8w32_decode", "h8w32_prefill", "h8w0_decode",
+                         "h8w0_prefill"}
+    assert max(errs.values()) <= chip_smoke.SETTINGS["kernel_tol"]
+
+
 def test_sambay_kernel_check_in_interpret_mode():
     """The smoke's SambaY check (run on the chip at Phi-4-mini-flash's:
     a scan 5120 wide with 16 states; 40 padded query heads on 10 pair

@@ -90,12 +90,14 @@ class ModelConfig:
     stack: the layers run ``loop_steps`` times a token) and Laguna
     (full and window attention layers with different head counts in one
     stack, a per-head output gate, routed experts beside a shared one)
+    and SmallThinker (rope-less full layers among window ones, every
+    layer routed from its pre-attention norm to ReLU-gated experts)
     families.
     """
 
     name: str = "llama"
     # "llama" | "mixtral" | "gpt2" | "deepseek_v3" | "ouro" | "laguna" |
-    # "sambay"
+    # "sambay" | "smallthinker"
     family: str = "llama"
     vocab_size: int = 32000
     d_model: int = 4096
@@ -203,6 +205,22 @@ class ModelConfig:
     # output by sigmoid(x_normed . w_h) before the output projection
     # (gated attention, head-wise); "none" = no gate.
     attn_gate: str = "none"
+    # Kinds whose layers apply NO rope: a query sees no position signal
+    # there (NoPE; models/smallthinker.py's full layers). () = every
+    # kind takes its rope.
+    nope_kinds: tuple = ()
+    # Where an expert layer's router reads: "ffn_norm" (the experts' own
+    # input, the post-attention norm) | "attn_norm" (the normed LAYER
+    # input: the routing is known before attention runs).
+    router_input: str = "ffn_norm"
+    # The routed experts' gate activation: act(x Wg) * (x Wu). "silu"
+    # (SwiGLU) | "relu" (ReGLU: an expert's hidden row is sparse).
+    moe_act: str = "silu"
+    # The expert layers also count the rows the grouped kernels ran,
+    # whole tiles, beside the real pairs (models/deepseek_v3.py
+    # ROW_STATS: one more aux stat, so a preset that had none keeps its
+    # step programs as they were).
+    moe_row_stats: bool = False
     # --- family "sambay" (models/sambay.py): state-space layers, one
     # full-attention layer whose K / V the cross layers read, gated
     # memory units, differential attention. ``layer_types`` is DERIVED
@@ -312,6 +330,9 @@ class ModelConfig:
         rot = self.head_dim * self.partial_rotary_factor
         assert 0 < rot <= self.head_dim and rot == int(rot) and rot % 2 == 0
         assert self.attn_gate in ("none", "per_head")
+        assert self.router_input in ("ffn_norm", "attn_norm")
+        assert self.moe_act in ("silu", "relu")
+        assert set(self.nope_kinds) <= set(self.layer_types)
         if self.layer_types:
             kinds = self.layer_types[:self.n_layers]
             assert len(kinds) == self.n_layers, \
@@ -505,6 +526,32 @@ def laguna_s_ep8() -> ModelConfig:
     )
 
 
+def smallthinker_21b_pp4() -> ModelConfig:
+    """SmallThinker-21BA3B-Instruct (PowerInfer) as ONE stage of a
+    four-stage pipeline, at every published width: 12 of 52 layers
+    (three whole periods of one full-attention layer WITHOUT rope and
+    three window-4096 layers with rope of theta 1.5e6; 28 query / 4 KV
+    heads of 128), every layer a router over 64 experts of width 768,
+    ALL of them held here, top-6, the gates a softmax over the six
+    chosen logits, the router fed the PRE-attention norm, ReLU-gated
+    experts, no shared expert, no dense layer; the embedding and the
+    untied head over 151,936 ids both here, so that the stage serves
+    alone. bench/configs/smallthinker-21b-pp4-bf16.json states the cut
+    and what is assumed."""
+    return ModelConfig(
+        name="smallthinker-21b-pp4", family="smallthinker",
+        vocab_size=151936, d_model=2560, n_layers=12, n_heads=28,
+        n_kv_heads=4, d_ff=0, max_seq_len=16384, rope_theta=1500000.0,
+        norm_eps=1e-6, head_dim_override=128, sliding_window=4096,
+        layer_types=("full", "window", "window", "window") * 13,
+        window_n_heads=28, window_rope_theta=1500000.0,
+        nope_kinds=("full",), router_input="attn_norm", moe_act="relu",
+        moe_row_stats=True, moe_d_ff=768, n_experts=64, n_experts_per_tok=6,
+        moe_scoring="softmax", norm_topk_prob=True,
+        routed_scaling_factor=1.0, ep_size=1, ep_rank=0,
+    )
+
+
 def phi4_mini_flash() -> ModelConfig:
     """Phi-4-mini-flash-reasoning (microsoft; SambaY, arXiv:2507.06607)
     whole, at every published size: 32 layers of which 9 are Mamba-1
@@ -662,6 +709,26 @@ def tiny_laguna(vocab_size: int = 512) -> ModelConfig:
     )
 
 
+def tiny_smallthinker(vocab_size: int = 512) -> ModelConfig:
+    """The SmallThinker structure at test widths: two periods of (full
+    without rope, window x 3 with rope), 8 query / 2 KV heads of 16,
+    window 8, every layer routed from its pre-attention norm to top-3
+    of 8 ReLU-gated experts, all held, no shared expert, no dense
+    layer."""
+    return ModelConfig(
+        name="tiny-smallthinker", family="smallthinker",
+        vocab_size=vocab_size, d_model=64, n_layers=8, n_heads=8,
+        n_kv_heads=2, d_ff=0, max_seq_len=4096, rope_theta=10000.0,
+        norm_eps=1e-6, head_dim_override=16, sliding_window=8,
+        layer_types=("full", "window", "window", "window") * 2,
+        window_n_heads=8, window_rope_theta=10000.0,
+        nope_kinds=("full",), router_input="attn_norm", moe_act="relu",
+        moe_row_stats=True, moe_d_ff=32, n_experts=8, n_experts_per_tok=3,
+        moe_scoring="softmax", norm_topk_prob=True,
+        routed_scaling_factor=1.0, dtype=jnp.float32,
+    )
+
+
 def tiny_sambay(vocab_size: int = 512) -> ModelConfig:
     """The SambaY structure at test widths: 8 layers holding every kind
     (3 ssm, 2 window, 1 full, 1 gmu, 1 cross), window 8, 8 query / 4 KV
@@ -690,6 +757,7 @@ PRESETS = {
     "ouro-2.6b": ouro_2_6b,
     "laguna-s-ep8": laguna_s_ep8,
     "phi4-mini-flash": phi4_mini_flash,
+    "smallthinker-21b-pp4": smallthinker_21b_pp4,
     "tiny-llama": tiny_llama,
     "tiny-llama-fatkv": tiny_llama_fatkv,
     "tiny-qwen2": tiny_qwen2,
@@ -702,6 +770,7 @@ PRESETS = {
     "tiny-ouro": tiny_ouro,
     "tiny-laguna": tiny_laguna,
     "tiny-sambay": tiny_sambay,
+    "tiny-smallthinker": tiny_smallthinker,
 }
 
 
@@ -1379,8 +1448,9 @@ def model_config_from_dict(d: dict) -> ModelConfig:
     if isinstance(rs, dict):
         d["rope_scaling"] = (YarnScaling if "beta_fast" in rs
                              else RopeScaling)(**rs)
-    if "layer_types" in d:
-        d["layer_types"] = tuple(d["layer_types"])
+    for k in ("layer_types", "nope_kinds"):
+        if k in d:
+            d[k] = tuple(d[k])
     return ModelConfig(**d)
 
 

@@ -35,7 +35,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional
 
-from tpu_inference.engine.kv_cache import window_span_pages
+from tpu_inference.engine.kv_cache import (window_span_pages,
+                                           written_ahead_tokens)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -189,7 +190,9 @@ class AutoSizing:
     kv_bytes_per_token: int
     target_ctx: int
     # A model with a pool a kind: the window kind's pool (every lane's
-    # span); num_pages / kv_bytes_per_token are then the full kind's.
+    # span, or fewer where the lanes' contexts lie under the window:
+    # _auto_size_kinds); num_pages / kv_bytes_per_token are then the
+    # full kind's.
     num_window_pages: int = 0
 
 
@@ -200,7 +203,7 @@ def auto_size(model_cfg, *, hbm_bytes: float, quant: str = "none",
               reserve_frac: float = 0.15,
               activation_headroom: int = 512 << 20,
               speculative: bool = False,
-              window_span: int = 0) -> AutoSizing:
+              window_span: int = 0, written_ahead: int = 0) -> AutoSizing:
     """Size ``max_batch_size`` and ``num_pages`` for the chip.
 
     Raises ValueError when the weights alone exceed the per-chip budget
@@ -209,9 +212,13 @@ def auto_size(model_cfg, *, hbm_bytes: float, quant: str = "none",
 
     A model whose layers differ in kind (``layer_types``) has a pool a
     kind: the window kind's holds ``window_span`` pages
-    (kv_cache.window_span_pages) for each lane of the batch, whatever
-    the context, the full kind's gets the rest of the budget, and the
-    batch is the largest whose lanes fit both at ``target_ctx``.
+    (kv_cache.window_span_pages) for each lane of the batch where
+    ``target_ctx`` reaches the window, the full kind's gets the rest of
+    the budget, and the batch is the largest whose lanes fit both at
+    ``target_ctx``. Where ``target_ctx`` + ``written_ahead`` tokens
+    (kv_cache.written_ahead_tokens) lie under the window a lane holds
+    the same tokens in both kinds, and both pools are sized on them
+    (_auto_size_kinds).
     """
     hbm = float(hbm_bytes)
     wb = weight_bytes(model_cfg, quant)
@@ -230,7 +237,8 @@ def auto_size(model_cfg, *, hbm_bytes: float, quant: str = "none",
             model_cfg, budget=budget, hbm=hbm, per_chip_w=per_chip_w,
             kv_quant=kv_quant, page_size=page_size,
             max_pages_per_seq=max_pages_per_seq, target_ctx=target_ctx,
-            batch_cap=batch_cap, window_span=window_span)
+            batch_cap=batch_cap, window_span=window_span,
+            written_ahead=written_ahead)
     kv_tok = kv_bytes_per_token(model_cfg, kv_quant)
     tokens = int(budget // (kv_tok / tp))
     num_pages = tokens // page_size
@@ -273,25 +281,50 @@ def auto_size(model_cfg, *, hbm_bytes: float, quant: str = "none",
 def _auto_size_kinds(model_cfg, *, budget: float, hbm: float,
                      per_chip_w: int, kv_quant: str, page_size: int,
                      max_pages_per_seq: int, target_ctx: Optional[int],
-                     batch_cap: int, window_span: int) -> AutoSizing:
+                     batch_cap: int, window_span: int,
+                     written_ahead: int = 0) -> AutoSizing:
     """auto_size for a pool a kind (one chip: tp is refused). Three
     things to fit where the model has state-space layers: every lane's
     window span, every lane's state slot (a fixed size a sequence), and
-    the full kind's pages with what is left."""
+    the full kind's pages with what is left.
+
+    Where a lane at ``target_ctx`` (plus what it writes ahead of a
+    release) stays UNDER the window, the span is pages nobody fills: a
+    lane holds the same tokens in both kinds, so the window kind's pool
+    is sized as the full kind's is, on live tokens. The budget then buys
+    both kinds the same count of pages (the window kind's never more
+    than every lane's span, never less than one), and the batch is the
+    largest whose lanes find ``target_ctx`` tokens in each: the full
+    kind's condition as it always was, held for both. (The chunk
+    written ahead decides only WHETHER a lane stays under the window;
+    it is one prompt's transient, which admission has charged, and the
+    full kind's condition never counted it either.) A smaller window
+    pool is a wait at admission, never a failed allocation:
+    engine.admission_fits holds back every bound sequence's whole
+    charge a kind."""
     full_tok = kv_bytes_per_token(model_cfg, kv_quant, kind="full")
     win_tok = kv_bytes_per_token(model_cfg, kv_quant, kind="window")
     state = model_cfg.state_bytes_per_seq()
     ctx = int(target_ctx) if target_ctx else (page_size * max_pages_per_seq
                                               // 2)
     ctx = max(1, min(ctx, page_size * max_pages_per_seq))
+    lane = min(window_span, -(-(ctx + written_ahead) // page_size) + 2)
     for batch in range(batch_cap, 0, -1):
         win_pages = batch * window_span + 1
+        if lane < window_span:
+            even = int((budget - (batch + 1) * state)
+                       // ((full_tok + win_tok) * page_size))
+            win_pages = min(win_pages, even)
         rest = (budget - win_pages * page_size * win_tok
                 - (batch + 1) * state)
         num_pages = min(int(rest // (full_tok * page_size)),
                         4 * batch_cap * max_pages_per_seq)
+        # (Under the window a lane's tokens lie in both pools.)
+        holds = num_pages if lane == window_span else min(num_pages,
+                                                          win_pages)
         if (num_pages >= max_pages_per_seq + 1
-                and (num_pages - 1) * page_size // ctx >= batch):
+                and win_pages - 1 >= window_span
+                and (holds - 1) * page_size // ctx >= batch):
             return AutoSizing(
                 max_batch_size=batch, num_pages=num_pages,
                 hbm_bytes=int(hbm), weight_bytes_per_chip=int(per_chip_w),
@@ -560,13 +593,19 @@ def resolve_sizing(model_cfg, engine_cfg, req: Optional[dict], *,
             target_ctx=req["target_ctx"] or None,
             batch_cap=req["batch_cap"], speculative=req["speculative"],
             window_span=window_span_pages(model_cfg, engine_cfg)
-            if model_cfg.layer_types else 0)
+            if model_cfg.layer_types else 0,
+            written_ahead=written_ahead_tokens(engine_cfg))
         mbs = sz.max_batch_size if mbs == "auto" else mbs
         pages = sz.num_pages if pages == "auto" else pages
         import sys
 
-        # (The window kind's pool is not set here: left at 0 it is every
-        # lane's span at the batch that is served, kv_cache.num_window_pages.)
+        # (The window kind's pool is set only where it was sized on live
+        # tokens, under every lane's span: left at 0 it is every lane's
+        # span at the batch that is served, kv_cache.num_window_pages.)
+        if pages == sz.num_pages and 0 < sz.num_window_pages < (
+                mbs * window_span_pages(model_cfg, engine_cfg) + 1):
+            engine_cfg = dataclasses.replace(
+                engine_cfg, num_window_pages=sz.num_window_pages)
         print(f"[autosize] {model_cfg.name}: batch={mbs} num_pages={pages} "
               + (f"num_window_pages={sz.num_window_pages} "
                  if sz.num_window_pages else "") +

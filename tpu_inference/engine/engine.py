@@ -825,6 +825,12 @@ class InferenceEngine:
         self.window_span = (kvc.window_span_pages(model_cfg, engine_cfg)
                             if n_win else 0)
         self.window_pages_released = 0    # behind-window frees, lifetime
+        # Pages a kind that admission held back at its last pass
+        # (pages_booked) and the most it has at once since boot: stored
+        # by the engine loop, so that a metrics scrape reads two arrays
+        # and never walks the live sequences from its own thread.
+        self.pages_booked_seen = np.zeros(2, np.int64)
+        self.pages_booked_peak = np.zeros(2, np.int64)
         # State-space layers: a state slot a sequence, from admission to
         # release (kvc.StateSlots; ``seq.pages.state``). It rides behind
         # the block tables, one more column of a row: lanes move between
@@ -1730,6 +1736,7 @@ class InferenceEngine:
         valid tokens). Returns [N, d_model] f32."""
         from tpu_inference.models.common import make_dense_attn
 
+        self._refuse_dense_forward("embed_many")
         ecfg = self.engine_cfg
         if not batch:
             return np.zeros((0, self.model_cfg.d_model), np.float32)
@@ -1772,6 +1779,18 @@ class InferenceEngine:
             out.append(np.asarray(pooled)[:len(chunk)])
         return np.concatenate(out, axis=0)
 
+    def _refuse_dense_forward(self, what: str) -> None:
+        """embed_many / check_numerics run the model cache-free through
+        ``common.make_dense_attn``, ONE attention function for every
+        layer: a stack of kinds calls ``attn.kinds[kind]`` and would
+        fail inside the trace with a TypeError."""
+        if self.model_cfg.layer_types:
+            raise ValueError(
+                f"{self.model_cfg.name} (layers of mixed kinds) does not "
+                f"support {what}: its cache-free forward needs an "
+                "attention function a kind (the family's "
+                "make_dense_attn), which this path does not build")
+
     def check_numerics(self) -> None:
         """Numerics sanitizer (SURVEY.md §5 race/sanitizer tier).
 
@@ -1786,6 +1805,7 @@ class InferenceEngine:
 
         from tpu_inference.models.common import make_dense_attn
 
+        self._refuse_dense_forward("check_numerics")
         leaves = jax.tree_util.tree_flatten_with_path(self.params)[0]
         bad = [jax.tree_util.keystr(path) for path, x in leaves
                if not bool(jnp.isfinite(x).all())]
@@ -2023,14 +2043,21 @@ class InferenceEngine:
         if self.state_slots is not None and \
                 self.state_slots.num_free < want[2]:
             return False        # a state slot a sequence
-        bound = [s for s in self.slots if s is not None and not s.done]
-        held = sum((self.admission_need(s) for s in bound),
-                   np.zeros(3, np.int64))[:2]
         usable = np.asarray([self.engine_cfg.num_pages - 1,
                              self.win_allocator.num_pages - 1])
-        left = usable - held
+        booked = self.pages_booked_seen = self.pages_booked()
+        self.pages_booked_peak = np.maximum(self.pages_booked_peak, booked)
+        left = usable - booked
         return bool(min(room, left[0] - headroom) >= want[0]
                     and min(self.win_allocator.num_free, left[1]) >= want[1])
+
+    def pages_booked(self) -> np.ndarray:
+        """Pages a kind, [full, window], that the bound sequences are
+        charged for their whole lives (admission_need), taken or not
+        yet. The engine loop's to call: it reads live sequences."""
+        bound = [s for s in self.slots if s is not None and not s.done]
+        return sum((self.admission_need(s) for s in bound),
+                   np.zeros(3, np.int64))[:2]
 
     def _free_plus_evictable(self) -> int:
         n = self.allocator.num_free

@@ -163,15 +163,21 @@ class KindPages(list):
         self.state = state
 
 
+def written_ahead_tokens(engine_cfg: EngineConfig) -> int:
+    """Tokens a sequence writes before a behind-window release catches
+    up: a prefill chunk, or the decode steps granted ahead."""
+    return max(engine_cfg.chunk_tokens_cap,
+               engine_cfg.decode_steps_per_call
+               * max(1, engine_cfg.decode_pipeline_depth))
+
+
 def window_span_pages(model_cfg: ModelConfig,
                       engine_cfg: EngineConfig) -> int:
     """The most window-kind pages one sequence holds: the window, the
-    tokens written before a release catches up (a prefill chunk, or the
-    decode steps granted ahead), a page of misalignment at each end."""
-    ahead = max(engine_cfg.chunk_tokens_cap,
-                engine_cfg.decode_steps_per_call
-                * max(1, engine_cfg.decode_pipeline_depth))
-    span = -(-(model_cfg.sliding_window + ahead) // engine_cfg.page_size) + 2
+    tokens written before a release catches up, a page of misalignment
+    at each end."""
+    span = -(-(model_cfg.sliding_window + written_ahead_tokens(engine_cfg))
+             // engine_cfg.page_size) + 2
     return min(span, engine_cfg.max_pages_per_seq)
 
 

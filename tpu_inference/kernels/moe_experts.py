@@ -43,6 +43,10 @@ from tpu_inference.kernels import mxu_precision
 from tpu_inference.models.quant import QuantizedArray
 
 
+# The gate's activation, act(x Wg) * (x Wu): SwiGLU or ReGLU.
+ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
 def _split(w: Any):
     """(codes or weights, per-output-channel scale or None)."""
     if isinstance(w, QuantizedArray):
@@ -79,7 +83,7 @@ def _tile_maps(n_k: int):
 
 
 def _gate_up_kernel(ly_ref, te_ref, na_ref, x_ref, wg_ref, wu_ref, *rest,
-                    quantized: bool):
+                    quantized: bool, act: str):
     if quantized:
         sg_ref, su_ref, out_ref, ag_ref, au_ref = rest
     else:
@@ -106,7 +110,7 @@ def _gate_up_kernel(ly_ref, te_ref, na_ref, x_ref, wg_ref, wu_ref, *rest,
         g, u = ag_ref[:], au_ref[:]
         if quantized:
             g, u = g * sg_ref[:], u * su_ref[:]
-        h = jax.nn.silu(g) * u
+        h = ACTS[act](g) * u
         out_ref[:] = jnp.where(live, h, 0.0).astype(out_ref.dtype)
 
 
@@ -183,14 +187,15 @@ def _grouped_call(kernel, name, x, weights, layer, tile_expert, n_tiles, *,
 
 
 def moe_grouped_experts_gate_up(x, w_gate, w_up, layer, tile_expert,
-                                n_tiles, *, tm: int,
+                                n_tiles, *, tm: int, act: str = "silu",
                                 interpret: bool = False):
     """x [M, D] (rows in tiles of ``tm``, tile t all for expert
     tile_expert[t]); w_gate / w_up [Le, E, D, F] (or int8
-    QuantizedArray) -> silu(x Wg) * (x Wu) [M, F] in x.dtype; rows of
-    tiles >= n_tiles are zero."""
-    return _grouped_call(_gate_up_kernel, "moe_grouped_experts_gate_up", x,
-                         (w_gate, w_up), layer, tile_expert, n_tiles, tm=tm,
+    QuantizedArray) -> act(x Wg) * (x Wu) [M, F] in x.dtype (``act``:
+    "silu" | "relu", static); rows of tiles >= n_tiles are zero."""
+    return _grouped_call(functools.partial(_gate_up_kernel, act=act),
+                         "moe_grouped_experts_gate_up", x, (w_gate, w_up),
+                         layer, tile_expert, n_tiles, tm=tm,
                          out_dtype=x.dtype, n_acc=2, interpret=interpret)
 
 
@@ -210,7 +215,7 @@ def _tile_weights(w, layer, tile_expert):
 
 
 def grouped_ffn_xla(x, w_gate, w_up, w_down, layer, tile_expert, n_tiles,
-                    *, tm: int):
+                    *, tm: int, act: str = "silu"):
     """The two kernels' function in plain XLA (the ``dense`` backend off
     the chip; gathers every tile's expert weights, so for tests only)."""
     xt = x.reshape(-1, tm, x.shape[-1]).astype(jnp.float32)
@@ -218,7 +223,7 @@ def grouped_ffn_xla(x, w_gate, w_up, w_down, layer, tile_expert, n_tiles,
                                                      tile_expert))
     u = jnp.einsum("tmk,tkn->tmn", xt, _tile_weights(w_up, layer,
                                                      tile_expert))
-    h = (jax.nn.silu(g) * u).astype(x.dtype).astype(jnp.float32)
+    h = (ACTS[act](g) * u).astype(x.dtype).astype(jnp.float32)
     y = jnp.einsum("tmf,tfd->tmd", h, _tile_weights(w_down, layer,
                                                     tile_expert))
     live = (jnp.arange(xt.shape[0]) < n_tiles)[:, None, None]
@@ -283,9 +288,11 @@ def group_pairs(top_local: jax.Array, gates: jax.Array, n_held: int,
 
 
 def grouped_experts(x: jax.Array, groups: PairGroups, w_gate, w_up, w_down,
-                    layer, *, pallas: bool, interpret: bool = False):
+                    layer, *, pallas: bool, interpret: bool = False,
+                    act: str = "silu"):
     """x [T, D] -> (sum over local pairs of gate * E_e(x) [T, D] float32,
-    pairs computed). Runs ceil(tiles in use / tiles a round) rounds."""
+    pairs computed). Runs ceil(tiles in use / tiles a round) rounds.
+    ``act`` is the gate's activation (ACTS), static."""
     t, d = x.shape
     tm, rr = groups.tm, groups.round_rows
     rt = rr // tm
@@ -302,13 +309,13 @@ def grouped_experts(x: jax.Array, groups: PairGroups, w_gate, w_up, w_down,
         with jax.named_scope("moe_grouped_experts"):
             if pallas:
                 h = moe_grouped_experts_gate_up(xr, w_gate, w_up, layer, te,
-                                                n_act, tm=tm,
+                                                n_act, tm=tm, act=act,
                                                 interpret=interpret)
                 yr = moe_grouped_experts_down(h, w_down, layer, te, n_act,
                                               tm=tm, interpret=interpret)
             else:
                 yr = grouped_ffn_xla(xr, w_gate, w_up, w_down, layer, te,
-                                     n_act, tm=tm)
+                                     n_act, tm=tm, act=act)
         y = y.at[tok].add(yr * gate[:, None], mode="drop")
         return r + 1, y, done + jnp.sum(tok < t).astype(jnp.int32)
 

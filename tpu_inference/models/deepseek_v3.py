@@ -56,10 +56,16 @@ from tpu_inference.models.quant import qdot, split_heads
 # kv.aux slots, then one per held expert (its routed pairs).
 MOE_STATS = ("tokens", "local_pairs", "computed_pairs", "busiest_pairs",
              "distinct_experts", "decode_layers")
+# Behind the per-expert slots, for a preset that asks
+# (``cfg.moe_row_stats``): the rows the grouped kernels ran, whole
+# tiles, beside the real pairs among them (``computed_pairs``): what
+# kernels/moe_experts.tile_rows costs.
+ROW_STATS = ("tile_rows",)
 
 
 def n_moe_stats(cfg: ModelConfig) -> int:
-    return len(MOE_STATS) + cfg.n_local_experts
+    return (len(MOE_STATS) + cfg.n_local_experts
+            + (len(ROW_STATS) if cfg.moe_row_stats else 0))
 
 
 # What the shared layers ask a family module for, where it differs from
@@ -198,8 +204,12 @@ def route(cfg: ModelConfig, lp: dict, x2: jax.Array):
                      precision=jax.lax.Precision.HIGHEST)
     scores = (jax.nn.sigmoid(logits) if cfg.moe_scoring == "sigmoid"
               else jax.nn.softmax(logits, axis=-1))
-    _, top_idx = jax.lax.top_k(scores + lp["router_bias"][None, :],
-                               cfg.n_experts_per_tok)
+    # (A router with no selection bias has no such leaf: the top-k is
+    # taken by the scores themselves.)
+    bias = lp.get("router_bias")
+    _, top_idx = jax.lax.top_k(
+        scores if bias is None else scores + bias[None, :],
+        cfg.n_experts_per_tok)
     gates = jnp.take_along_axis(scores, top_idx, axis=1)
     if cfg.norm_topk_prob:
         gates = gates / (jnp.sum(gates, axis=1, keepdims=True) + 1e-20)
@@ -207,8 +217,12 @@ def route(cfg: ModelConfig, lp: dict, x2: jax.Array):
 
 
 def moe_ffn(cfg: ModelConfig, lp: dict, experts: tuple, moe_layer,
-            h: jax.Array, attn: AttentionFn):
-    """h [B, S, D] -> (shared expert + this chip's routed part, stats)."""
+            h: jax.Array, attn: AttentionFn, routing=None):
+    """h [B, S, D] -> (shared expert + this chip's routed part, stats).
+    ``routing``: ``route``'s result where the architecture routes from
+    another input than the experts' (``cfg.router_input``; the caller
+    computed it there), else h is routed here. A layer with no shared
+    expert (``lp`` has no ``ws_*``) is its routed part alone."""
     b, s, d = h.shape
     x2 = h.reshape(b * s, d)
     n_held = cfg.n_local_experts
@@ -218,7 +232,7 @@ def moe_ffn(cfg: ModelConfig, lp: dict, experts: tuple, moe_layer,
     valid = getattr(attn, "valid", None)
     n_tokens = b * s if valid is None else jnp.sum(valid)
     with jax.named_scope("moe_router"):
-        top_idx, gates = route(cfg, lp, x2)
+        top_idx, gates = route(cfg, lp, x2) if routing is None else routing
         first = cfg.ep_rank * n_held
         held = (top_idx >= first) & (top_idx < first + n_held)
         if valid is not None:
@@ -229,17 +243,23 @@ def moe_ffn(cfg: ModelConfig, lp: dict, experts: tuple, moe_layer,
     routed, computed = moe_experts.grouped_experts(
         x2, groups, *experts, moe_layer,
         pallas=getattr(attn, "pallas", False),
-        interpret=getattr(attn, "interpret", False))
-    with jax.named_scope("moe_shared_expert"):
-        shared = swiglu(x2, lp["ws_gate"], lp["ws_up"], lp["ws_down"])
+        interpret=getattr(attn, "interpret", False), act=cfg.moe_act)
+    shared = None
+    if "ws_gate" in lp:
+        with jax.named_scope("moe_shared_expert"):
+            shared = swiglu(x2, lp["ws_gate"], lp["ws_up"], lp["ws_down"])
     decode = jnp.int32(s == 1)
-    stats = jnp.concatenate([
+    stats = [
         jnp.stack([jnp.int32(n_tokens), jnp.sum(groups.counts), computed,
                    jnp.max(groups.counts),
                    decode * jnp.sum(groups.counts > 0), decode]),
-        groups.counts]).astype(jnp.int32)
-    out = (routed + shared.astype(jnp.float32)).astype(h.dtype)
-    return out.reshape(b, s, d), stats
+        groups.counts]
+    if cfg.moe_row_stats:
+        stats.append((groups.n_tiles * groups.tm).reshape(1))
+    stats = jnp.concatenate(stats).astype(jnp.int32)
+    if shared is not None:
+        routed = routed + shared.astype(jnp.float32)
+    return routed.astype(h.dtype).reshape(b, s, d), stats
 
 
 def _block(cfg: ModelConfig, layer_idx, lp: dict, x: jax.Array,

@@ -49,7 +49,7 @@ from tpu_inference.models.common import (
     rms_norm,
     swiglu,
 )
-from tpu_inference.models.deepseek_v3 import moe_ffn, n_moe_stats
+from tpu_inference.models.deepseek_v3 import moe_ffn, n_moe_stats, route
 from tpu_inference.models.quant import qdot
 
 EXPERT_STACKS = ("we_gate", "we_up", "we_down")
@@ -89,25 +89,30 @@ def param_shapes(cfg: ModelConfig) -> dict:
     }
 
 
-def param_count(cfg: ModelConfig, active: bool = False) -> int:
-    """Parameters, counted off the leaf shapes. With ``active``, those a
-    token position multiplies through: a routed expert counts as the
-    share of it one token uses (k of all the layer's experts are chosen,
-    so k / n_experts of each HELD one on average)."""
+def param_count(cfg: ModelConfig, active: bool = False,
+                shapes=None) -> int:
+    """Parameters, counted off the leaf shapes (``shapes``: another
+    family's tree). With ``active``, those a token position multiplies
+    through: a routed expert counts as the share of it one token uses
+    (k of all the layer's experts are chosen, so k / n_experts of each
+    HELD one on average)."""
     share = cfg.n_experts_per_tok / cfg.n_experts if active else 1.0
     leaves = jax.tree_util.tree_flatten_with_path(
-        param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))[0]
+        param_shapes(cfg) if shapes is None else shapes,
+        is_leaf=lambda x: isinstance(x, tuple))[0]
     return int(sum(math.prod(shape)
                    * (share if path[-1].key in EXPERT_STACKS else 1.0)
                    for path, shape in leaves))
 
 
-def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
+def init_params(cfg: ModelConfig, key: jax.Array, shapes=None) -> dict:
     """Random init (normal, 0.02 std; norm scales 1; the selection bias
-    float32, 0.01 std), one jitted draw a leaf."""
+    float32, 0.01 std), one jitted draw a leaf. ``shapes``: another
+    family's tree of leaf shapes (models/smallthinker.py)."""
     cfg.validate()
     leaves, treedef = jax.tree_util.tree_flatten_with_path(
-        param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))
+        param_shapes(cfg) if shapes is None else shapes,
+        is_leaf=lambda x: isinstance(x, tuple))
 
     @partial(jax.jit, static_argnames=("shape", "dtype", "std"))
     def draw(k, shape, dtype, std):
@@ -136,7 +141,10 @@ def attention(cfg: ModelConfig, kind: str, slot, ap: dict, h: jax.Array,
         q = qdot(h, ap["wq"]).astype(h.dtype).reshape(b, s, nh, hd)
         k = qdot(h, ap["wk"]).astype(h.dtype).reshape(b, s, hkv, hd)
         v = qdot(h, ap["wv"]).astype(h.dtype).reshape(b, s, hkv, hd)
-        if kind == "window":
+        if kind in cfg.nope_kinds:
+            def rope(x):        # no position signal on this kind
+                return x
+        elif kind == "window":
             rope = partial(apply_rope, positions=positions,
                            theta=cfg.window_rope_theta)
         else:
@@ -197,6 +205,16 @@ def forward_hidden(params: dict, cfg: ModelConfig, tokens: jax.Array,
         x, kv = carry
         ap = jax.tree.map(lambda a: a[slot], params["attn_" + kind])
         h = rms_norm(x, ap["attn_norm"], cfg.norm_eps)
+        routing = None
+        if routed and cfg.router_input == "attn_norm":
+            # The architecture routes from the layer's normed INPUT: the
+            # experts a token will use are known before its attention
+            # runs.
+            with jax.named_scope("moe_early_router"):
+                routing = route(
+                    cfg, {k: moe[k][l - nd]
+                          for k in ("w_router", "router_bias") if k in moe},
+                    h.reshape(-1, h.shape[-1]))
         a, kv = attention(cfg, kind, slot, ap, h, positions, kv, attn)
         x = x + a
         if not routed:
@@ -206,7 +224,11 @@ def forward_hidden(params: dict, cfg: ModelConfig, tokens: jax.Array,
                     kv), None
         fp = jax.tree.map(lambda a: a[l - nd], moe)
         h = rms_norm(x, fp["ffn_norm"], cfg.norm_eps)
-        y, stats = moe_ffn(cfg, fp, experts, l - nd, h, attn)
+        if routing is None:
+            y, stats = moe_ffn(cfg, fp, experts, l - nd, h, attn)
+        else:
+            y, stats = moe_ffn(cfg, fp, experts, l - nd, h, attn,
+                               routing=routing)
         return (x + y, kv), stats
 
     def run(state, seg, turn=0):

@@ -99,6 +99,7 @@ STORED_TRANSPOSED = {
     "laguna": frozenset({"wq", "wk", "wv"}),
     "deepseek_v3": frozenset({"wq_b", "wkv_b"}),
     "sambay": frozenset({"w_q"}),
+    "smallthinker": frozenset({"wq", "wk", "wv"}),
 }
 
 

@@ -2672,6 +2672,25 @@ class EngineTelemetry:
             r.gauge("tpu_inf_kv_window_pages_peak",
                     "Most pages of the window kind's pool in use at once "
                     "since boot", fn=lambda: wall.peak_in_use)
+            # What admission holds back for the bound sequences, taken
+            # or not yet (engine.admission_fits): a pool sized on live
+            # tokens is full when its BOOKED pages reach its total, well
+            # before its pages in use do.
+            booked = ("Pages of the {} kind's pool that admission held "
+                      "back for the bound sequences' whole lives at its "
+                      "last pass")
+            peak = ("Most pages of the {} kind's pool held back at once "
+                    "since boot (seen at admission passes)")
+            r.gauge("tpu_inf_kv_full_pages_booked", booked.format("full"),
+                    fn=lambda: engine.pages_booked_seen[0])
+            r.gauge("tpu_inf_kv_window_pages_booked",
+                    booked.format("window"),
+                    fn=lambda: engine.pages_booked_seen[1])
+            r.gauge("tpu_inf_kv_full_pages_booked_peak", peak.format("full"),
+                    fn=lambda: engine.pages_booked_peak[0])
+            r.gauge("tpu_inf_kv_window_pages_booked_peak",
+                    peak.format("window"),
+                    fn=lambda: engine.pages_booked_peak[1])
             r.counter("tpu_inf_kv_window_pages_released_total",
                       "Window-kind pages released behind the window "
                       "while their sequence ran (prefill chunks and "
@@ -2767,10 +2786,11 @@ class EngineTelemetry:
         them in ``engine.aux_stats`` (engine._fold_aux_stats)."""
         if not self.enabled:
             return
-        from tpu_inference.models.deepseek_v3 import MOE_STATS
+        from tpu_inference.models.deepseek_v3 import MOE_STATS, ROW_STATS
 
         r, st = self.registry, engine.aux_stats
         at = {name: i for i, name in enumerate(MOE_STATS)}
+        n_held = engine.model_cfg.n_local_experts
         r.counter("tpu_inf_moe_tokens_total",
                   "Token positions routed, summed over expert layers",
                   fn=lambda: int(st[at["tokens"]]))
@@ -2796,11 +2816,22 @@ class EngineTelemetry:
                   "Decode steps x expert layers behind "
                   "tpu_inf_moe_distinct_experts_total",
                   fn=lambda: int(st[at["decode_layers"]]))
-        for e in range(len(st) - len(MOE_STATS)):
+        for e in range(n_held):
             r.counter("tpu_inf_moe_expert_pairs_total",
                       "Routed pairs per held expert",
                       fn=lambda e=e: int(st[len(MOE_STATS) + e]),
                       expert=str(e))
+        if engine.model_cfg.moe_row_stats:
+            at_rows = len(MOE_STATS) + n_held + ROW_STATS.index("tile_rows")
+            r.counter("tpu_inf_moe_tile_rows_total",
+                      "Rows the grouped expert kernels ran, whole tiles "
+                      "(each held expert's pairs padded up to a tile): "
+                      "less the computed pairs it is what the tile size "
+                      "costs", fn=lambda: int(st[at_rows]))
+            r.counter("tpu_inf_moe_computed_pairs_total",
+                      "Local pairs the grouped expert rounds computed: "
+                      "the real rows among tpu_inf_moe_tile_rows_total",
+                      fn=lambda: int(st[at["computed_pairs"]]))
 
     def bind_state(self, engine) -> None:
         """Read-through metrics of a model with state-space layers: the
