@@ -19,6 +19,9 @@ PR 25) — and a graph that has one counts as refused.
                                              # graph (16 of them, ~25 s each)
     python benchmarks/aot_rehearsal.py --tp 4 --quant none     # bf16, 2x2 mesh
     python benchmarks/aot_rehearsal.py --graphs prefill:4x512 decode:16
+    python benchmarks/aot_rehearsal.py --model kimi-k2-ep32 --quant none \
+        --max-pages-per-seq 672 --hash-only     # lowers, compiles nothing:
+                                                # did a step program change?
     python benchmarks/aot_rehearsal.py --model smallthinker-21b-pp4 \
         --quant none --max-pages-per-seq 512 --target-ctx 1024 \
         --batch-cap 64       # a pool a kind, the window kind's sized on
@@ -134,6 +137,32 @@ def param_copies(hlo: str, params, device_laid: bool = False) -> list:
     return out
 
 
+def program_hash(lowered) -> str:
+    """sha256 (16 hex digits) of ``Lowered.as_text()`` with each Mosaic
+    kernel's body (serialized MLIR that carries the source lines and the
+    checkout's path of every op) replaced by the hash of its text without
+    locations: what the program IS, wherever the checkout lies. Two
+    commits whose step programs hash the same hand the chip's compiler
+    the same program. tests/test_tpu_compile.py uses it too."""
+    import base64
+    import hashlib
+    import re
+
+    from jax.extend.mlir import ir
+
+    def digest(text):
+        return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+    def kernel(m):
+        mod = ir.Module.parse(base64.b64decode(m.group(1)))
+        return digest(mod.operation.get_asm(enable_debug_info=False))
+
+    with ir.Context() as ctx:
+        ctx.allow_unregistered_dialects = True
+        return digest(re.sub(r'\\22body\\22: \\22([^\\]*)\\22', kernel,
+                             lowered.as_text()))
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--model", default="mistral-7b")
@@ -157,6 +186,10 @@ def main() -> int:
                          "decode1:<B> hybrid:<bucket>x<B> swap")
     ap.add_argument("--dump-hlo", default="",
                     help="directory to write each compiled graph's HLO to")
+    ap.add_argument("--hash-only", action="store_true",
+                    help="lower each graph and print program_hash() of it, "
+                         "compiling nothing (seconds a graph): to compare "
+                         "two commits' step programs")
     args = ap.parse_args()
 
     import jax
@@ -304,6 +337,11 @@ def main() -> int:
     failed = 0
     for graph in graphs:
         t0 = time.time()
+        if args.hash_only:
+            print(json.dumps({"graph": graph,
+                              "program_hash": program_hash(lower(graph))}),
+                  flush=True)
+            continue
         try:
             compiled = lower(graph).compile()
         except Exception as e:  # noqa: BLE001 — report and go on to the next

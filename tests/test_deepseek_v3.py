@@ -8,7 +8,10 @@ which a rank holds 8, rope / nope split, YaRN on), seeded random weights:
     form, with a cached prefix, at layer > 0 of the stacked pool;
 (c) the shares add up: the routed parts of all expert-parallel ranks plus
     the shared expert once equal the uncut reference's layer output;
-(d) no pair is dropped under a routing skewed onto one expert;
+(d) no pair is dropped under a routing skewed onto one expert; where ONE
+    round of the layout holds every pair (half or more of the experts
+    held) the rows come back by a gather and a sum over k, equal to the
+    scatter-add under the loop, and the lowered program says which ran;
 (e) the presets' latent / expert / YaRN fields equal the keys of the
     configuration files;
 (f) what the family does not support is refused at construction.
@@ -342,6 +345,166 @@ def test_no_pair_is_dropped_under_skew(pallas):
             hh = jax.nn.silu(x[i] @ wg[1, e]) * (x[i] @ wu[1, e])
             want[i] += gates[i, j] * np.asarray(hh @ wd[1, e])
     np.testing.assert_allclose(y, want, atol=2e-4)
+
+
+def _one_routing(t, k, held, n_experts, d=32, f=16, seed=0, idle=True):
+    """Random activations, stacked expert weights (layer 1 is used),
+    top-k of ``n_experts`` of which the first ``held`` are here, every
+    third row idle."""
+    ks = jax.random.split(jax.random.PRNGKey(seed + t), 6)
+    x = jax.random.normal(ks[0], (t, d), jnp.float32)
+    wg, wu = (0.3 * jax.random.normal(kk, (2, held, d, f), jnp.float32)
+              for kk in ks[1:3])
+    wd = 0.3 * jax.random.normal(ks[3], (2, held, f, d), jnp.float32)
+    top = jnp.argsort(-jax.random.normal(ks[4], (t, n_experts)),
+                      axis=1)[:, :k]
+    gates = jax.nn.softmax(jax.random.normal(ks[5], (t, k)), axis=-1)
+    valid = (jnp.arange(t) % 3 != 2)[:, None] | (not idle)
+    top_local = jnp.where(valid & (top < held), top, held).astype(jnp.int32)
+    return x, (wg, wu, wd), top_local, gates, valid
+
+
+def _plain_loop(x, weights, top_local, gates, act):
+    """sum over held experts of (the token's gate on it) * E_e(x), in
+    float64: what the grouped layer computes, with no layout at all."""
+    wg, wu, wd = (np.asarray(w[1], np.float64) for w in weights)
+    xs, want = np.asarray(x, np.float64), 0.0
+    for e in range(wg.shape[0]):
+        ge = np.where(np.asarray(top_local) == e, np.asarray(gates),
+                      0.0).sum(1)
+        hid = xs @ wg[e]
+        hid = (np.maximum(hid, 0) if act == "relu"
+               else hid / (1 + np.exp(-hid))) * (xs @ wu[e])
+        want = want + ge[:, None] * (hid @ wd[e])
+    return want
+
+
+def by_scatter(groups):
+    """The same layout, combined as a layout of several rounds is: the
+    loop (one trip) and the scatter-add."""
+    return groups._replace(pair_row=None, pair_gate=None)
+
+
+@pytest.mark.parametrize("t", [3, 64, 1024])
+@pytest.mark.parametrize("pallas", [False, True], ids=["dense", "pallas"])
+@pytest.mark.parametrize("act", ["relu", "silu"])
+def test_the_gather_combine_is_the_scatter_combine(act, pallas, t):
+    """All 64 experts held, top-6 (SmallThinker's stage): one round holds
+    every pair at a decode step and at a chunk, and summing each token's
+    six gathered rows equals scatter-adding the rows to their tokens."""
+    e, k = 64, 6
+    x, w, top_local, gates, valid = _one_routing(t, k, e, e)
+    assert moe_experts.combines_by_gather(t, k, e, t * k)
+    groups = moe_experts.group_pairs(top_local, gates, e, t * k)
+    assert groups.row_token.shape[0] == groups.round_rows     # one round
+    kw = dict(pallas=pallas, interpret=True, act=act)
+    y, done = moe_experts.grouped_experts(x, groups, *w, 1, **kw)
+    y2, done2 = moe_experts.grouped_experts(x, by_scatter(groups), *w, 1,
+                                            **kw)
+    assert y.dtype == y2.dtype == jnp.float32
+    assert int(done) == int(done2) == int(valid.sum()) * k
+    scale = float(np.std(np.asarray(y2)))
+    assert np.abs(np.asarray(y) - np.asarray(y2)).max() < 1e-5 * scale
+    want = _plain_loop(x, w, top_local, gates, act)
+    assert np.abs(np.asarray(y) - want).max() < 1e-3 * np.std(want)
+    # A row that holds no token has no row in the layout and adds 0.0.
+    assert not np.asarray(y)[~np.asarray(valid)[:, 0]].any()
+
+
+@pytest.mark.parametrize("preset,b,s", [
+    ("tiny-smallthinker", 3, 1), ("tiny-smallthinker", 1, 64),
+    ("tiny-kimi", 3, 1), ("tiny-kimi", 1, 64)])
+def test_a_row_without_a_token_adds_nothing_to_the_gather(preset, b, s):
+    """An idle decode lane (b x 1) and a padded bucket's tail (1 x s)
+    through ``moe_ffn`` where the combine gathers: the routed part of such
+    a row is exactly 0 and it counts in no statistic (tiny-kimi holds 8
+    of 16: half of the other rows' pairs have no row here either)."""
+    mcfg = PRESETS[preset]()
+    assert dsv3.combines_by_gather(mcfg, b * s)
+    held, d, f = mcfg.n_local_experts, mcfg.d_model, mcfg.moe_d_ff
+    ks = jax.random.split(jax.random.PRNGKey(b * s), 5)
+    lp = {"w_router": jax.random.normal(ks[0], (d, mcfg.n_experts))}
+    experts = tuple(0.3 * jax.random.normal(kk, shape) for kk, shape in zip(
+        ks[1:4], [(1, held, d, f), (1, held, d, f), (1, held, f, d)]))
+    h = jax.random.normal(ks[4], (b, s, d))
+    valid = (jnp.arange(b * s) % 3 != 1).reshape(b, s)
+
+    def attn(*a):
+        raise AssertionError("the expert layer calls no attention")
+
+    every, _ = dsv3.moe_ffn(mcfg, lp, experts, 0, h, attn)
+    attn.valid = valid
+    out, stats = dsv3.moe_ffn(mcfg, lp, experts, 0, h, attn)
+    assert not np.asarray(out)[~np.asarray(valid)].any()
+    assert np.asarray(every)[~np.asarray(valid)].any()
+    np.testing.assert_allclose(out[valid], every[valid], atol=1e-5)
+    at = dict(zip(dsv3.MOE_STATS, range(len(dsv3.MOE_STATS))))
+    n = int(valid.sum())
+    assert int(stats[at["tokens"]]) == n
+    assert int(stats[at["computed_pairs"]]) == int(
+        stats[at["local_pairs"]]) <= n * mcfg.n_experts_per_tok
+    top, _ = dsv3.route(mcfg, lp, h.reshape(b * s, d))
+    here = (np.asarray(top) < held) & np.asarray(valid).reshape(-1, 1)
+    assert int(stats[at["local_pairs"]]) == here.sum()
+
+
+@pytest.mark.parametrize("pallas", [False, True], ids=["dense", "pallas"])
+@pytest.mark.parametrize("t", [64, 1024])
+def test_every_pair_on_one_expert_is_still_one_round(pallas, t):
+    """The skew a served random init shows (8x max over mean), taken to
+    its end: all T x 6 pairs on expert 5 of 64. The one round is sized
+    for the worst case, so it holds them, and the gather is exact."""
+    e, k = 64, 6
+    x, w, _, gates, _ = _one_routing(t, k, e, e, idle=False)
+    top_local = jnp.full((t, k), 5, jnp.int32)
+    groups = moe_experts.group_pairs(top_local, gates, e, t * k)
+    assert groups.pair_row is not None
+    assert int(groups.counts[5]) == t * k == int(groups.counts.sum())
+    assert int(groups.n_tiles) * groups.tm <= groups.round_rows
+    y, done = moe_experts.grouped_experts(x, groups, *w, 1, pallas=pallas,
+                                          interpret=True, act="relu")
+    assert int(done) == t * k
+    want = _plain_loop(x, w, top_local, gates, "relu")
+    assert np.abs(np.asarray(y) - want).max() < 1e-3 * np.std(want)
+
+
+def _float_scatters(text):
+    """Result types of the float scatters in a lowered program (the
+    layout's own scatter writes int32 row indices and stays)."""
+    import re
+    return [r for r in re.findall(
+        r'"stablehlo\.scatter".*?\) -> tensor<([^>]*)>', text, re.S)
+        if not r.endswith("i32")]
+
+
+# (held, of, top-k, tokens): tiny-laguna's expert layer at its 32-token
+# bucket, one made for the test; tiny-kimi's (half held, the boundary)
+# and tiny-smallthinker's at a bucket and at a decode rung.
+@pytest.mark.parametrize("held,n_experts,k,t,gathers", [
+    (4, 16, 3, 32, False), (3, 16, 4, 64, False),
+    (8, 16, 4, 32, True), (8, 16, 4, 2, True),
+    (8, 8, 3, 32, True), (8, 8, 3, 2, True)])
+def test_the_lowered_expert_layer_says_which_combine_runs(
+        held, n_experts, k, t, gathers):
+    """Under half of the experts held: several rounds, the predicate is
+    false, the program holds the ``while`` and the float scatter-add.
+    Half or more: one round, no ``while``, no float scatter, and the
+    output is the plain loop over the held experts."""
+    expected = t * k * held / n_experts
+    assert moe_experts.combines_by_gather(t, k, held, expected) == gathers
+    x, w, top_local, gates, _ = _one_routing(t, k, held, n_experts)
+
+    def layer(x, top_local, gates):
+        groups = moe_experts.group_pairs(top_local, gates, held, expected)
+        return moe_experts.grouped_experts(x, groups, *w, 1, pallas=False)
+
+    text = jax.jit(layer).lower(x, top_local, gates).as_text()
+    assert ("stablehlo.while" in text) == (not gathers)
+    assert bool(_float_scatters(text)) == (not gathers)
+    y, done = layer(x, top_local, gates)
+    assert int(done) == int((np.asarray(top_local) < held).sum())
+    want = _plain_loop(x, w, top_local, gates, "silu")
+    assert np.abs(np.asarray(y) - want).max() < 1e-3 * np.std(want)
 
 
 def test_pairs_of_absent_experts_are_left_out():

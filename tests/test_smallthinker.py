@@ -419,7 +419,14 @@ def test_a_full_window_pool_is_a_wait_at_admission():
 # sha256 of ``Lowered.as_text()`` of three step programs of each accepted
 # preset's tiny twin on the CPU (``lowered_hashes`` below), as the PARENT
 # commit (14d131c, PR 42) lowers them. A PR that means to change a step
-# program replaces the lines it changes and says so.
+# program replaces the lines it changes and says so. (PR 44: where one
+# round of the expert layer's layout holds every pair the rows come back
+# by a gather, kernels/moe_experts.py: tiny-kimi holds 8 of 16 experts,
+# all three programs; tiny-laguna holds 4 of 16 and its 32-token bucket
+# keeps the loop, but its 2-lane decode steps lay out 6 pairs, under one
+# tile, and are one round too. The published-width programs of both
+# presets keep the loop: ``test_which_served_programs_combine_by_gather``,
+# tests/test_tpu_compile.py.)
 PARENT_HASHES = {
     "tiny-mistral": {"prefill": "538493b173e6e567",
                      "decode_k": "df416240d56df7bf",
@@ -427,15 +434,15 @@ PARENT_HASHES = {
     "tiny-qwen2": {"prefill": "b92f31e2c994b1e2",
                    "decode_k": "fbb9479f751c24da",
                    "decode_1": "ff869e77b5cef3b6"},
-    "tiny-kimi": {"prefill": "473cfab079ce2609",
-                  "decode_k": "520c2fb468478b46",
-                  "decode_1": "89c8aa34e7819e6e"},
+    "tiny-kimi": {"prefill": "c01b6677958768ab",
+                  "decode_k": "a5c9f080d47e8a54",
+                  "decode_1": "cea87255482a1752"},
     "tiny-ouro": {"prefill": "a5efedbb3455cbcf",
                   "decode_k": "01c69f2e5d1ac34b",
                   "decode_1": "bed61f0ce62afae7"},
     "tiny-laguna": {"prefill": "c195c8983a0d5914",
-                    "decode_k": "92bfea531d3c7c9d",
-                    "decode_1": "24602c286d4a4cbe"},
+                    "decode_k": "87051d309842c151",
+                    "decode_1": "8ddb155b723260dc"},
     "tiny-sambay": {"prefill": "944404ea719e1fe4",
                     "decode_k": "945a438870f3d480",
                     "decode_1": "b1951983aaba7d9e"},
@@ -573,6 +580,30 @@ def test_the_counters_and_gauges_this_family_fills():
     assert m["tpu_inf_kv_window_pages_booked_peak"] == \
         mid["tpu_inf_kv_window_pages_booked"] == 8
     assert float(m["tpu_inf_weight_stacks_transposed"]) == 6
+    # Every expert held: every step program warm-up builds sums its
+    # expert layers' rows by gather (nothing warmed yet: 0).
+    assert m["tpu_inf_moe_gather_combine_programs"] == 0
+    eng.warmup()
+    assert scrape()["tpu_inf_moe_gather_combine_programs"] == \
+        eng.warmup_graphs > 0
+
+
+# Batch, ladder rungs and prefill buckets 'auto' gives each expert cell
+# (benchmarks/aot_rehearsal.py's flags, SKILL.md) and how many prompts a
+# prefill program batches.
+@pytest.mark.parametrize("model,rungs,gathers", [
+    ("kimi-k2-ep32", (8, 16, 32), False),
+    ("laguna-s-ep8", (8, 16, 32), False),
+    ("smallthinker-21b-pp4", (8, 16, 32, 64), True)])
+def test_which_served_programs_combine_by_gather(model, rungs, gathers):
+    """At published widths: a chip that holds 12 of 384 or 32 of 256
+    experts lays out several rounds at every rung and bucket it serves
+    (the loop and the scatter-add stay, gauge 0), one that holds all 64
+    lays out one (every program gathers)."""
+    mcfg = PRESETS[model]()
+    rows = set(rungs) | {p * b for p in (1, 2, 4)
+                         for b in (64, 128, 256, 512, 1024)}
+    assert {dsv3.combines_by_gather(mcfg, r) for r in rows} == {gathers}
 
 
 def test_validate_and_what_is_refused():

@@ -315,6 +315,95 @@ def test_grouped_experts_compile_for_v5e_without_a_weight_copy(
     assert made == []
 
 
+def _expert_layers(chip, preset, tokens):
+    """The expert layers of a preset at its published widths as a step
+    program runs them (``deepseek_v3.moe_ffn`` under a scan over the
+    expert layers, the stacked weights out of the scan's xs, rows that
+    may hold no token): a decode step's rows where ``tokens`` is a
+    ladder rung, else one prompt's chunk. -> (function, argument shapes
+    on the described chip)."""
+    from tpu_inference.config import PRESETS
+    from tpu_inference.models import deepseek_v3 as dsv3
+
+    mcfg = PRESETS[preset]()
+    d, f, held = mcfg.d_model, mcfg.moe_d_ff, mcfg.n_local_experts
+    le = mcfg.n_layers - mcfg.first_k_dense
+    b, sq = (tokens, 1) if tokens <= 64 else (1, tokens)
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def step(h, valid, w_router, wg, wu, wd):
+        def attn(*a):
+            raise AssertionError("the expert layer calls no attention")
+        attn.pallas, attn.valid = True, valid
+
+        def body(h, scanned):
+            layer, router = scanned
+            y, stats = dsv3.moe_ffn(mcfg, {"w_router": router},
+                                    (wg, wu, wd), layer, h, attn)
+            return h + y, stats
+
+        return jax.lax.scan(body, h, (jnp.arange(le), w_router))
+
+    return step, (s((b, sq, d)), s((b, sq), jnp.bool_),
+                  s((le, d, mcfg.n_experts)), s((le, held, d, f)),
+                  s((le, held, d, f)), s((le, held, f, d)))
+
+
+# ``aot_rehearsal.program_hash`` of ``_expert_layers`` for the v5e, as
+# the PARENT commit (5d59d4d, PR 43) lowers them: the two expert cells
+# whose layouts are several rounds (12 of 384 and 32 of 256 experts held)
+# keep the loop and the scatter-add, at a 1024-token chunk and at their
+# widest decode rung. A PR that means to change them replaces the lines.
+PARENT_EXPERT_LAYERS = {
+    ("kimi-k2-ep32", 1024): "c55990047f54407d",
+    ("kimi-k2-ep32", 32): "2cd9fde333375077",
+    ("laguna-s-ep8", 1024): "8839eb2b6a92f0a9",
+    ("laguna-s-ep8", 32): "f64883ec8f9e9709",
+}
+
+
+@pytest.mark.parametrize("preset,tokens", list(PARENT_EXPERT_LAYERS))
+def test_expert_layers_of_several_rounds_lower_to_the_parents(
+        chip, preset, tokens):
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir,
+                                    "benchmarks"))
+    from aot_rehearsal import program_hash
+
+    step, shapes = _expert_layers(chip, preset, tokens)
+    lowered = jax.jit(step).lower(*shapes)
+    text = lowered.as_text()
+    assert "stablehlo.while" in text and "tpu_custom_call" in text
+    assert program_hash(lowered) == PARENT_EXPERT_LAYERS[preset, tokens]
+
+
+@pytest.mark.parametrize("tokens", [64, 512, 1024])
+def test_all_held_expert_layers_compile_for_v5e_with_no_scatter_add(
+        chip, tokens):
+    """SmallThinker's stage (64 of 64 experts held, top-6, D 2560): one
+    round holds every pair, so the compiled program sums each token's six
+    gathered rows: no scatter into a float32 ``[.., 2560]`` operand, no
+    float32 product over the ``cap`` padded rows (14,336 at a 1024-token
+    chunk, 11,264 at 512, 1,408 at 64 lanes), and the only loop is the
+    scan over the 12 layers."""
+    import re
+
+    step, shapes = _expert_layers(chip, "smallthinker-21b-pp4", tokens)
+    hlo = jax.jit(step).lower(*shapes).compile().as_text()
+    assert hlo.count("tpu_custom_call") >= 2
+    cap = {64: 1408, 512: 11264, 1024: 14336}[tokens]
+    made = re.findall(
+        r"^\s*(?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\(", hlo,
+        re.M)
+    assert [m for m in made if m[2] == "scatter" and m[0] == "f32"] == []
+    assert [m for m in made if m[0] == "f32" and m[1] == f"{cap},2560"
+            and m[2] not in ("custom-call", "get-tuple-element",
+                             "bitcast", "parameter")] == []
+    assert len(re.findall(r"^\s*(?:ROOT )?%[\w.\-]+ = .*? while\(", hlo,
+                          re.M)) == 1
+
+
 @pytest.mark.parametrize("lanes,rows", [(1, 1024), (4, 1024), (4, 64)])
 def test_selective_scan_compiles_at_the_published_width(chip, lanes, rows):
     """The state-space layers' prefill kernel at Phi-4-mini-flash's scan

@@ -1043,6 +1043,9 @@ class InferenceEngine:
         # warmup() and how many graphs it ran.
         self.warmup_s = 0.0
         self.warmup_graphs = 0
+        # ... and how many of them sum their expert layers' rows by a
+        # gather (a family with grouped experts: kernels/moe_experts.py).
+        self.gather_combine_programs = 0
         # Step programs take their small operands as ONE packed int32
         # array a dispatch (engine/staging.py), put where the program
         # expects it: replicated over the mesh, or the default device.
@@ -1544,13 +1547,20 @@ class InferenceEngine:
 
         timeline: List[dict] = []
         mon = telemetry.compile_monitor()
+        by_gather = family_fn(self.model_cfg, "combines_by_gather")
+        self.gather_combine_programs = 0
 
-        def run(label, jitted, *args):
+        def run(label, jitted, *args, rows=()):
             """One warm-up dispatch = one compiled graph (every call
             below has a shape no earlier call had). Each is timed and
-            named in the boot timeline, with where XLA got it from."""
+            named in the boot timeline, with where XLA got it from.
+            ``rows``: the token rows of each forward pass of this model
+            the program holds (what sizes an expert layer's layout)."""
             nonlocal graphs
             graphs += 1
+            if by_gather is not None and rows:
+                self.gather_combine_programs += all(
+                    by_gather(self.model_cfg, r) for r in rows)
             before = mon.snapshot() if mon is not None else None
             t = time.perf_counter()
             out = jitted(*args)
@@ -1591,12 +1601,13 @@ class InferenceEngine:
                 shape = f"{p}x{bucket}"
                 self.kv, _, _ = run(
                     f"prefill {shape}", self._prefill_jit, self.params,
-                    self.kv, self._base_key, operand(layout, p))
+                    self.kv, self._base_key, operand(layout, p),
+                    rows=(p * bucket,))
                 if self.sp > 1 and bucket % self.sp == 0:
                     self.kv, _, _ = run(
                         f"prefill_sp {shape}", self._prefill_sp_jit,
                         self.params, self.kv, self._base_key,
-                        operand(layout, p))
+                        operand(layout, p), rows=(p * bucket,))
                 if self.spec_draft:
                     self.draft_kv = run(
                         f"draft_prefill {shape}",
@@ -1606,7 +1617,7 @@ class InferenceEngine:
                         jnp.zeros((p,), jnp.int32),
                         jnp.zeros((p, self.bt_width), jnp.int32))
 
-        def warm_carry(label, jitted, b, *operands):
+        def warm_carry(label, jitted, b, *operands, rows):
             """One decode-side graph at rung ``b``. Deeper than 1 the
             program takes a carry: warmed on the null one, then run
             once more on its own outputs, which is what serving hands
@@ -1614,9 +1625,9 @@ class InferenceEngine:
             carry = self._null_carry.get(b)
             if carry is None:
                 return run(label, jitted, self.params, self.kv,
-                           self._base_key, *operands)
+                           self._base_key, *operands, rows=rows)
             out = run(label, jitted, self.params, self.kv, self._base_key,
-                      *operands, carry)
+                      *operands, carry, rows=rows)
             return jitted(self.params, out[0], self._base_key, *operands,
                           out[-2:])
 
@@ -1641,7 +1652,7 @@ class InferenceEngine:
             for b in self.ladder:
                 self.kv = warm_carry(
                     f"decode b={b}", self._decode_multi_jit, b,
-                    operand(self._decode_layout, b))[0]
+                    operand(self._decode_layout, b), rows=(b,))[0]
                 if self._decode_one_jit is not self._decode_multi_jit:
                     # The 1-step graph is a second full decode compile,
                     # but decode_step()/decode_steps(max_steps=1) route
@@ -1651,7 +1662,7 @@ class InferenceEngine:
                     self.kv, _, _, _ = run(
                         f"decode b={b}", self._decode_one_jit, self.params,
                         self.kv, self._base_key,
-                        operand(self._decode_layout, b))
+                        operand(self._decode_layout, b), rows=(b,))
         if self.spec_ngram:
             # The verify-only graph compiles at EVERY ladder rung x
             # EVERY active verify width (the full γ+1 round AND the
@@ -1697,7 +1708,8 @@ class InferenceEngine:
                     self.kv = warm_carry(
                         f"hybrid 1x{bucket} b={b}", self._hybrid_jit, b,
                         operand(self._prefill_layout(bucket), 1),
-                        operand(self._decode_layout, b))[0]
+                        operand(self._decode_layout, b),
+                        rows=(bucket, b))[0]
         return self._warmup_done(t0, graphs, timeline)
 
     def _warmup_done(self, t0: float, graphs: int,

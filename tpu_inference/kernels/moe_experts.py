@@ -10,11 +10,23 @@ the routing:
    whole tile of ``tm`` rows, so a tile belongs to ONE expert. Only index
    vectors are sized for the worst case (every pair local).
 2. ``grouped_experts`` walks that layout in rounds of a fixed number of
-   tiles under a ``lax.while_loop`` whose trip count is the routing's:
-   one round covers twice the expected load, so it is almost always the
-   only one, and a routing skewed onto one expert just takes more rounds.
-   A round gathers its rows' activations, runs the two kernels below and
-   adds the gated results back to their tokens.
+   tiles: one round covers twice the expected load, so it is almost
+   always the only one that has rows. A round gathers its rows'
+   activations and runs the two kernels below. How the gated results
+   come back to their tokens is decided by the layout's shape alone
+   (``combines_by_gather``, Python ints at trace time):
+   - where ONE round is the worst case too (twice the expected local
+     pairs cover every pair, i.e. half or more of the routed experts are
+     held: SmallThinker's stage holds all 64), the round runs once with
+     no loop, and each token GATHERS its k rows (``PairGroups.pair_row``)
+     and sums them with its gates in float32: ``T x k <= cap`` rows are
+     read, the padding is never touched and no index collides;
+   - everywhere else (Kimi 12 of 384, Laguna 32 of 256) a
+     ``lax.while_loop`` whose trip count is the routing's runs the rounds
+     (a routing skewed onto one expert just takes more of them) and each
+     SCATTER-ADDS its gated rows to their tokens: a round lays out a
+     fraction of ``T x k`` rows there, so the scatter walks fewer rows
+     than a gather would read.
 3. The kernels (``moe_grouped_experts_gate_up``, ``moe_grouped_experts_
    down``) take the STACKED expert weights ``[Le, E, K, N]`` with the
    layer and each tile's expert scalar-prefetched: the weight block's DMA
@@ -240,6 +252,9 @@ class PairGroups(NamedTuple):
     counts: jax.Array         # [E_held] pairs per held expert
     tm: int
     round_rows: int
+    # Where one round holds every pair (``combines_by_gather``), else None:
+    pair_row: Optional[jax.Array] = None    # [T, k] row of each pair; cap: none
+    pair_gate: Optional[jax.Array] = None   # [T, k] float32 gate; 0 where none
 
 
 def tile_rows(n_tokens: int, expected_pairs_per_expert: float) -> int:
@@ -249,17 +264,33 @@ def tile_rows(n_tokens: int, expected_pairs_per_expert: float) -> int:
     return int(min(128, 1 << (int(want) - 1).bit_length()))
 
 
-def group_pairs(top_local: jax.Array, gates: jax.Array, n_held: int,
-                expected_pairs: float) -> PairGroups:
-    """top_local [T, k]: each pair's held-expert index, or ``n_held`` for
-    an expert that is not here; gates [T, k] float32."""
-    t, k = top_local.shape
+def round_layout(t: int, k: int, n_held: int, expected_pairs: float):
+    """(rows of a tile, tiles of a round, rounds laid out) for T tokens
+    of k pairs each, from Python ints alone."""
     tm = tile_rows(t, expected_pairs / n_held)
     # One round: every held expert's partial tile + twice the expected
     # rows; never more than the worst case needs.
     worst_tiles = n_held + (t * k) // tm
     round_tiles = min(worst_tiles, n_held + -(-int(2 * expected_pairs) // tm))
-    rounds = -(-worst_tiles // round_tiles)
+    return tm, round_tiles, -(-worst_tiles // round_tiles)
+
+
+def combines_by_gather(t: int, k: int, n_held: int,
+                       expected_pairs: float) -> bool:
+    """Whether ``grouped_experts`` sums each token's k rows by a gather
+    (one round is the whole layout: twice the expected local pairs cover
+    the worst case) or scatter-adds round by round under a loop. What
+    ``group_pairs`` decides ``PairGroups.pair_row`` by, and what the
+    engine counts its warmed step programs by."""
+    return round_layout(t, k, n_held, expected_pairs)[2] == 1
+
+
+def group_pairs(top_local: jax.Array, gates: jax.Array, n_held: int,
+                expected_pairs: float) -> PairGroups:
+    """top_local [T, k]: each pair's held-expert index, or ``n_held`` for
+    an expert that is not here; gates [T, k] float32."""
+    t, k = top_local.shape
+    tm, round_tiles, rounds = round_layout(t, k, n_held, expected_pairs)
     cap = rounds * round_tiles * tm
 
     expert = top_local.reshape(-1)                             # [P]
@@ -282,21 +313,53 @@ def group_pairs(top_local: jax.Array, gates: jax.Array, n_held: int,
     tile_expert = jnp.sum(jnp.arange(cap // tm)[:, None]
                           >= tile_end[None, :], axis=1).astype(jnp.int32)
     tile_expert = jnp.minimum(tile_expert, n_held - 1)
-    return PairGroups(row_token, row_gate, tile_expert,
-                      tile_end[-1].astype(jnp.int32), counts, tm,
-                      round_tiles * tm)
+    groups = PairGroups(row_token, row_gate, tile_expert,
+                        tile_end[-1].astype(jnp.int32), counts, tm,
+                        round_tiles * tm)
+    if rounds == 1:
+        groups = groups._replace(
+            pair_row=dest.reshape(t, k),
+            pair_gate=jnp.where(local, gates.reshape(-1), 0.0).reshape(t, k))
+    return groups
 
 
 def grouped_experts(x: jax.Array, groups: PairGroups, w_gate, w_up, w_down,
                     layer, *, pallas: bool, interpret: bool = False,
                     act: str = "silu"):
     """x [T, D] -> (sum over local pairs of gate * E_e(x) [T, D] float32,
-    pairs computed). Runs ceil(tiles in use / tiles a round) rounds.
-    ``act`` is the gate's activation (ACTS), static."""
+    pairs computed). Where the layout is one round (``groups.pair_row``)
+    it runs once and every token gathers its k rows; else
+    ceil(tiles in use / tiles a round) rounds run under a loop, each
+    scatter-adding its rows. ``act`` is the gate's activation (ACTS),
+    static."""
     t, d = x.shape
     tm, rr = groups.tm, groups.round_rows
     rt = rr // tm
     x_pad = jnp.concatenate([x, jnp.zeros((1, d), x.dtype)], axis=0)
+
+    def run(tok, te, n_act):
+        """One round: its rows' activations through their tiles' experts
+        -> [rows, D] float32, ungated; rows of tiles >= n_act are zero."""
+        xr = x_pad[tok]
+        with jax.named_scope("moe_grouped_experts"):
+            if pallas:
+                h = moe_grouped_experts_gate_up(xr, w_gate, w_up, layer, te,
+                                                n_act, tm=tm, act=act,
+                                                interpret=interpret)
+                return moe_grouped_experts_down(h, w_down, layer, te, n_act,
+                                                tm=tm, interpret=interpret)
+            return grouped_ffn_xla(xr, w_gate, w_up, w_down, layer, te,
+                                   n_act, tm=tm, act=act)
+
+    if groups.pair_row is not None:
+        tok = groups.row_token
+        yr = run(tok, groups.tile_expert, groups.n_tiles)
+        # A pair with no row (not local, no token) reads any row at gate
+        # 0. The k rows of a token are k-major, [k, T, D]: whole tiles of
+        # (tokens, D), where [T, k, D] would pad k up to a tile's 8 rows.
+        rows = jnp.take(yr, groups.pair_row.T, axis=0, mode="clip")
+        y = jnp.sum(rows * groups.pair_gate.T[:, :, None], axis=0)
+        return y, jnp.sum(tok < t).astype(jnp.int32)
     n_rounds = -(-groups.n_tiles // rt)
 
     def body(carry):
@@ -305,17 +368,7 @@ def grouped_experts(x: jax.Array, groups: PairGroups, w_gate, w_up, w_down,
         gate = jax.lax.dynamic_slice(groups.row_gate, (r * rr,), (rr,))
         te = jax.lax.dynamic_slice(groups.tile_expert, (r * rt,), (rt,))
         n_act = jnp.clip(groups.n_tiles - r * rt, 0, rt)
-        xr = x_pad[tok]
-        with jax.named_scope("moe_grouped_experts"):
-            if pallas:
-                h = moe_grouped_experts_gate_up(xr, w_gate, w_up, layer, te,
-                                                n_act, tm=tm, act=act,
-                                                interpret=interpret)
-                yr = moe_grouped_experts_down(h, w_down, layer, te, n_act,
-                                              tm=tm, interpret=interpret)
-            else:
-                yr = grouped_ffn_xla(xr, w_gate, w_up, w_down, layer, te,
-                                     n_act, tm=tm, act=act)
+        yr = run(tok, te, n_act)
         y = y.at[tok].add(yr * gate[:, None], mode="drop")
         return r + 1, y, done + jnp.sum(tok < t).astype(jnp.int32)
 

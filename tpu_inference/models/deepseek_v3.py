@@ -216,6 +216,21 @@ def route(cfg: ModelConfig, lp: dict, x2: jax.Array):
     return top_idx, gates * cfg.routed_scaling_factor
 
 
+def expected_local_pairs(cfg: ModelConfig, rows: int) -> float:
+    """Pairs of ``rows`` tokens that land on this chip's experts if the
+    router spreads them evenly: what sizes the expert layer's layout."""
+    return rows * cfg.n_experts_per_tok * cfg.n_local_experts / cfg.n_experts
+
+
+def combines_by_gather(cfg: ModelConfig, rows: int) -> bool:
+    """Whether the expert layer of a step program that runs ``rows``
+    token rows sums its rows by gather (kernels/moe_experts.py, point 2):
+    the predicate ``moe_ffn``'s layout is built by, from the same ints."""
+    return moe_experts.combines_by_gather(
+        rows, cfg.n_experts_per_tok, cfg.n_local_experts,
+        expected_local_pairs(cfg, rows))
+
+
 def moe_ffn(cfg: ModelConfig, lp: dict, experts: tuple, moe_layer,
             h: jax.Array, attn: AttentionFn, routing=None):
     """h [B, S, D] -> (shared expert + this chip's routed part, stats).
@@ -238,8 +253,8 @@ def moe_ffn(cfg: ModelConfig, lp: dict, experts: tuple, moe_layer,
         if valid is not None:
             held &= valid.reshape(b * s, 1)
         top_local = jnp.where(held, top_idx - first, n_held)
-        expected = (b * s * cfg.n_experts_per_tok * n_held / cfg.n_experts)
-        groups = moe_experts.group_pairs(top_local, gates, n_held, expected)
+        groups = moe_experts.group_pairs(top_local, gates, n_held,
+                                         expected_local_pairs(cfg, b * s))
     routed, computed = moe_experts.grouped_experts(
         x2, groups, *experts, moe_layer,
         pallas=getattr(attn, "pallas", False),

@@ -49,11 +49,15 @@ from tpu_inference.models.common import (
     rms_norm,
     swiglu,
 )
-from tpu_inference.models.deepseek_v3 import moe_ffn, n_moe_stats, route
+from tpu_inference.models.deepseek_v3 import (combines_by_gather, moe_ffn,
+                                              n_moe_stats, route)
 from tpu_inference.models.quant import qdot
 
 EXPERT_STACKS = ("we_gate", "we_up", "we_down")
 
+# What the shared layers ask a family module for (models/registry.py
+# family_fn): the counter vector's length, and which warmed step programs
+# sum their expert layers' rows by gather (``combines_by_gather``, imported).
 n_aux_stats = n_moe_stats
 
 

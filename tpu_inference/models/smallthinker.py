@@ -44,6 +44,7 @@ unembed = laguna.unembed
 make_dense_attn = laguna.make_dense_attn
 forward_hidden = laguna.forward_hidden
 n_aux_stats = n_moe_stats
+combines_by_gather = laguna.combines_by_gather
 
 
 def param_shapes(cfg: ModelConfig) -> dict:

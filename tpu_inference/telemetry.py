@@ -2816,6 +2816,12 @@ class EngineTelemetry:
                   "Decode steps x expert layers behind "
                   "tpu_inf_moe_distinct_experts_total",
                   fn=lambda: int(st[at["decode_layers"]]))
+        r.gauge("tpu_inf_moe_gather_combine_programs",
+                "Warmed step programs whose expert layers sum each "
+                "token's k rows by a gather (one round holds every pair: "
+                "kernels/moe_experts.py combines_by_gather) and not by a "
+                "scatter-add under a loop; set once, by warm-up",
+                fn=lambda: engine.gather_combine_programs)
         for e in range(n_held):
             r.counter("tpu_inf_moe_expert_pairs_total",
                       "Routed pairs per held expert",
