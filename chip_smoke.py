@@ -5,6 +5,8 @@ Run from the repo root on a machine with a TPU:
 
     python chip_smoke.py            # one chip (what the driver runs)
     python chip_smoke.py --chips 4  # the cross-chip paths only (4-chip host)
+    python chip_smoke.py --combine-costs   # a microbenchmark, no phase:
+                                    # kernels/moe_experts.py's constant
 
 It drives the main path once through the entry points a user calls — the
 server CLI over HTTP — serving Mistral-7B at its published widths (random
@@ -1032,9 +1034,50 @@ def _latent_kernel_errors(cfg: dict, *, heads: int = 64, rank: int = 512,
     return {k: round(v, 5) for k, v in out.items()}
 
 
+def _bf16_normal(key, shape):
+    """Random bf16 weights, std 0.02, made on the device."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.jit(lambda k: (0.02 * jax.random.normal(
+        k, shape, jnp.float32)).astype(jnp.bfloat16))(key)
+
+
+def _expert_layer_attn(valid=None, interpret: bool = False):
+    """What ``moe_ffn`` reads off its attention argument and nothing
+    else: the Pallas backend, and the rows that hold a token."""
+    def attn(*a):
+        raise AssertionError("the expert layer calls no attention")
+    attn.pallas, attn.interpret, attn.valid = True, interpret, valid
+    return attn
+
+
+def _spilled_routing(mcfg, n: int, live: int, tm: int, key):
+    """(top_idx [n, k], gates [n, k] float32) that put every pair of the
+    ``live`` first tokens on held experts and need more tiles than a
+    round holds: k - 1 pairs of every token go, in turn, to a few
+    ``heavy`` experts that each fill over one tile of ``tm`` rows, the
+    last one, in turn, to the other held experts, whose partial tiles
+    lie behind the heavy ones: in a later round than the token's other
+    rows."""
+    import jax
+    import numpy as np
+
+    k, held = mcfg.n_experts_per_tok, mcfg.n_local_experts
+    heavy = min(held - 1, live * (k - 1) // (tm + 1))
+    check(heavy >= k - 1, f"{live} tokens x {k} cannot spill {held} experts")
+    i, j = np.arange(n)[:, None], np.arange(k - 1)[None, :]
+    top = np.concatenate([(i * (k - 1) + j) % heavy,
+                          heavy + i % (held - heavy)], axis=1)
+    gates = jax.random.uniform(key, (n, k), minval=0.5, maxval=1.5)
+    gates = gates / gates.sum(1, keepdims=True) * mcfg.routed_scaling_factor
+    return (top + mcfg.ep_rank * held).astype(np.int32), gates
+
+
 def _routed_expert_errors(cfg: dict, *, preset: str = "kimi-k2-ep32",
                           tokens=(32, 1024), idle: int = 5,
-                          interpret: bool = False) -> dict:
+                          interpret: bool = False,
+                          spill: bool = False) -> dict:
     """The expert layer as the engine runs it (``deepseek_v3.moe_ffn``:
     the router over all experts, the held pairs grouped into one-expert
     tiles, ``moe_grouped_experts_gate_up`` / ``_down`` addressing (layer,
@@ -1048,7 +1091,11 @@ def _routed_expert_errors(cfg: dict, *, preset: str = "kimi-k2-ep32",
     counts), to be far over the tolerance. A preset that holds EVERY
     expert of a layer with no shared one beside them
     (``smallthinker-21b-pp4``: 64 held, top-6, relu) runs the same
-    check: 6 rows an expert at 64 tokens, 96 at 1024."""
+    check: 6 rows an expert at 64 tokens, 96 at 1024. ``spill``: the
+    routing is not the router's but ``_spilled_routing``'s, handed to
+    both sides: the layout's loop runs a second round, a token's rows
+    lie in two rounds, and each round's combine gathers (a decode rung
+    of Kimi's or Laguna's: ``spilled_<n>``)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -1062,10 +1109,7 @@ def _routed_expert_errors(cfg: dict, *, preset: str = "kimi-k2-ep32",
     fs, layer = f * mcfg.n_shared_experts, 1
     key = jax.random.split(jax.random.PRNGKey(cfg["seed"]), 10)
 
-    def mat(k, shape):
-        return jax.jit(lambda k: (0.02 * jax.random.normal(
-            k, shape, jnp.float32)).astype(jnp.bfloat16))(k)
-
+    mat = _bf16_normal
     experts = (mat(key[0], (2, held, d, f)), mat(key[1], (2, held, d, f)),
                mat(key[2], (2, held, f, d)))
     lp = {"w_router": mat(key[3], (d, mcfg.n_experts))}
@@ -1080,24 +1124,21 @@ def _routed_expert_errors(cfg: dict, *, preset: str = "kimi-k2-ep32",
     def expert(x, wg, wu, wd):
         return (act(x @ wg) * (x @ wu)) @ wd
 
-    def attn_of(valid):
-        def attn(*a):
-            raise AssertionError("the expert layer calls no attention")
-        attn.pallas, attn.interpret, attn.valid = True, interpret, valid
-        return attn
+    @jax.jit
+    def _program(lp, h, valid, experts, moe_layer, routing):
+        return dsv3.moe_ffn(mcfg, lp, experts, moe_layer, h,
+                            _expert_layer_attn(valid, interpret),
+                            routing=routing)
+
+    def program(h, valid, experts, moe_layer, routing=None):
+        return _program(lp, h, valid, experts, moe_layer, routing)
 
     @jax.jit
-    def _program(lp, h, valid, experts, moe_layer):
-        return dsv3.moe_ffn(mcfg, lp, experts, moe_layer, h, attn_of(valid))
-
-    def program(h, valid, experts, moe_layer):
-        return _program(lp, h, valid, experts, moe_layer)
-
-    @jax.jit
-    def _plain(lp, experts, h, valid):
+    def _plain(lp, experts, h, valid, routing):
         with jax.default_matmul_precision("highest"):
             x = h[:, 0].astype(jnp.float32)
-            top, gates = dsv3.route(mcfg, lp, h[:, 0])
+            top, gates = (dsv3.route(mcfg, lp, h[:, 0]) if routing is None
+                          else routing)
             first = mcfg.ep_rank * held
             y = (dsv3.swiglu(x, *(lp[k].astype(jnp.float32) for k in
                                   ("ws_gate", "ws_up", "ws_down")))
@@ -1109,8 +1150,25 @@ def _routed_expert_errors(cfg: dict, *, preset: str = "kimi-k2-ep32",
                     x, *(w[layer, e].astype(jnp.float32) for w in experts))
             return y
 
-    def plain(h, valid):
-        return _plain(lp, experts, h, valid)
+    def plain(h, valid, routing=None):
+        return _plain(lp, experts, h, valid, routing)
+
+    def spilled(n, valid):
+        """The routing that spills, and that it does: more tiles in use
+        than a round's, every round gathering, a token in two rounds."""
+        k = mcfg.n_experts_per_tok
+        expected = dsv3.expected_local_pairs(mcfg, n)
+        tm = moe_experts.round_layout(n, k, held, expected)[0]
+        top, gates = _spilled_routing(mcfg, n, n - idle, tm, key[9])
+        groups = moe_experts.group_pairs(
+            jnp.where(valid, top - mcfg.ep_rank * held, held), gates, held,
+            expected)
+        check(groups.pair_row is not None, f"{n} rows do not gather")
+        in_round = np.asarray(groups.pair_row)[:n - idle] // groups.round_rows
+        check(int(groups.n_tiles) * tm > groups.round_rows
+              and (in_round.min(1) < in_round.max(1)).any(),
+              f"the routing of {n} rows does not spill: {in_round.max()}")
+        return jnp.asarray(top), gates
 
     def err(got, want, valid):
         got = np.asarray(got, np.float32)[np.asarray(valid)[:, 0]]
@@ -1126,21 +1184,23 @@ def _routed_expert_errors(cfg: dict, *, preset: str = "kimi-k2-ep32",
         h = jax.random.normal(jax.random.fold_in(key[8], n), (n, 1, d),
                               jnp.bfloat16)
         valid = (jnp.arange(n) < n - idle)[:, None]
-        want = plain(h, valid)
-        got, stats = program(h, valid, experts, layer)
+        routing = spilled(n, valid) if spill else None
+        want = plain(h, valid, routing)
+        got, stats = program(h, valid, experts, layer, routing)
         st = dict(zip(dsv3.MOE_STATS, np.asarray(stats)))
         check(st["tokens"] == n - idle
               and st["local_pairs"] == st["computed_pairs"],
               f"routing counts of {n} rows: {st}")
         pairs += int(st["local_pairs"])
-        out[f"routed_{n}"] = err(got[:, 0], want, valid)
+        out[f"{'spilled' if spill else 'routed'}_{n}"] = err(
+            got[:, 0], want, valid)
         rolled = tuple(jnp.roll(w, 1, axis=1) for w in experts)
         planted["zero"].append(err(
-            plain(h, valid & False), want, valid))
+            plain(h, valid & False, routing), want, valid))
         planted["next_expert"].append(err(
-            program(h, valid, rolled, layer)[0][:, 0], want, valid))
+            program(h, valid, rolled, layer, routing)[0][:, 0], want, valid))
         planted["layer_0"].append(err(
-            program(h, valid, experts, 0)[0][:, 0], want, valid))
+            program(h, valid, experts, 0, routing)[0][:, 0], want, valid))
     check(pairs > 0, "no pair was routed to a held expert")
     out.update({f"planted_{k}": min(v) for k, v in planted.items()})
     return {k: round(v, 5) for k, v in out.items()}
@@ -1148,12 +1208,134 @@ def _routed_expert_errors(cfg: dict, *, preset: str = "kimi-k2-ep32",
 
 def check_routed_experts(errs: dict, tol: float) -> None:
     for name, e in errs.items():
-        if name.startswith("routed_"):
+        if name.startswith(("routed_", "spilled_")):
             check(e <= tol, f"routed-expert layer vs the plain float32 "
                             f"loop: {errs}")
         else:
             check(e > 10 * tol, f"a planted fault in the routed-expert "
                                 f"path reads like a sound layer: {errs}")
+
+
+def _combine_costs(cfg: dict, *, shapes=(
+        ("laguna-s-ep8", 32), ("laguna-s-ep8", 1024), ("laguna-s-ep8", 4096),
+        ("kimi-k2-ep32", 32), ("kimi-k2-ep32", 1024)),
+        reps: int = 100, interpret: bool = False) -> dict:
+    """What ``moe_experts.SCATTERED_ROW_COST`` is set from: a round's two
+    combines timed on this device at a preset's widths and a step
+    program's rows (a decode rung, a chunk, four chunks), the routing
+    uniform over all experts. Per shape, in microseconds:
+    ``scatter_us`` / ``gather_us``, one round's combine ALONE
+    (``y.at[tok].add(yr * gate)`` over ``round_rows`` rows against
+    ``gathered_rows`` over ``pairs`` = T x k; indices and gates made to
+    depend on the carried ``y``, so no part is hoisted out of the timed
+    loop), ``row_cost`` = (scatter_us / round_rows) / (gather_us /
+    pairs), and ``layer_scatter_us`` / ``layer_gather_us``, the whole
+    layer (``moe_ffn`` without its shared expert: router, layout,
+    kernels, combine) in a scan fed its own output, as a step program
+    runs it, each form forced on the same layout. A time is the
+    difference of a call of 2 x ``reps`` trips and one of ``reps``, the
+    best of five, so what a call costs besides its trips is not in it.
+    The forms are forced by setting the module's constant for the trace
+    (nothing else can: no flag decides the path)."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_inference.config import PRESETS
+    from tpu_inference.kernels import moe_experts
+    from tpu_inference.models import deepseek_v3 as dsv3
+
+    key = jax.random.PRNGKey(cfg["seed"])
+
+    def forced(gather: bool, fn):
+        """``fn`` traced with every layout made to gather, or none."""
+        def call(*args):
+            kept = moe_experts.SCATTERED_ROW_COST
+            moe_experts.SCATTERED_ROW_COST = float("inf") if gather else 0.0
+            try:
+                return fn(*args)
+            finally:
+                moe_experts.SCATTERED_ROW_COST = kept
+        return call
+
+    def per_trip_us(step, carry, *args):
+        """``step(carry, *args) -> carry`` in a loop of n trips."""
+        run = jax.jit(lambda n, c, *a: jax.lax.fori_loop(
+            0, n, lambda _, c: step(c, *a), c))
+        best = {}
+        for n in (reps, 2 * reps):
+            jax.block_until_ready(run(n, carry, *args))
+            took = []
+            for _ in range(5):
+                t0 = time.perf_counter()
+                jax.block_until_ready(run(n, carry, *args))
+                took.append(time.perf_counter() - t0)
+            best[n] = min(took)
+        return (best[2 * reps] - best[reps]) / reps * 1e6
+
+    out = {}
+    for preset, t in shapes:
+        mcfg = PRESETS[preset]()
+        k, held, d, f = (mcfg.n_experts_per_tok, mcfg.n_local_experts,
+                         mcfg.d_model, mcfg.moe_d_ff)
+        expected = dsv3.expected_local_pairs(mcfg, t)
+        ks = jax.random.split(jax.random.fold_in(key, t), 8)
+        top = jax.random.randint(ks[0], (t, k), 0, mcfg.n_experts)
+        top_local = jnp.where(top < held, top, held).astype(jnp.int32)
+        gates = jax.random.uniform(ks[1], (t, k), minval=0.5, maxval=1.5)
+        groups = forced(True, moe_experts.group_pairs)(
+            top_local, gates, held, expected)
+        rr = groups.round_rows
+        yr = jax.random.normal(ks[2], (rr, d), jnp.float32)
+        y0 = jnp.zeros((t, d), jnp.float32)
+
+        def bump(y):        # 0, but only the device knows
+            return (y[0, 0] > 3e38).astype(jnp.int32)
+
+        def scatter(y, yr, tok, gate):
+            b = bump(y)
+            return y.at[tok + b].add(yr * (gate + b)[:, None], mode="drop")
+
+        def gather(y, yr, pair_row, pair_gate):
+            b = bump(y)
+            return y + moe_experts.gathered_rows(yr, groups._replace(
+                pair_row=pair_row + b, pair_gate=pair_gate + b), 0)
+
+        res = {"rows_round": rr, "pairs": t * k,
+               "rounds": groups.row_token.shape[0] // rr,
+               "scatter_us": per_trip_us(scatter, y0, yr,
+                                         groups.row_token[:rr],
+                                         groups.row_gate[:rr]),
+               "gather_us": per_trip_us(gather, y0, yr, groups.pair_row,
+                                        groups.pair_gate)}
+        res["row_cost"] = (res["scatter_us"] / rr) / (res["gather_us"]
+                                                     / (t * k))
+
+        mat = _bf16_normal
+        experts = (mat(ks[3], (2, held, d, f)), mat(ks[4], (2, held, d, f)),
+                   mat(ks[5], (2, held, f, d)))
+        lp = {"w_router": mat(ks[6], (d, mcfg.n_experts))}
+        h0 = jax.random.normal(ks[7], (t, 1, d), jnp.bfloat16)
+        attn = _expert_layer_attn(interpret=interpret)
+
+        def layer(h, lp, experts):
+            y, _ = dsv3.moe_ffn(mcfg, lp, experts, 1, h, attn)
+            # Kept at the input's scale, and the features moved on by
+            # one: a token routed to no held expert would get y = 0 and
+            # route there for ever, and trip by trip the layer would
+            # have less to do (my first chip run read 15 us a layer).
+            out = (h + y) * jax.lax.rsqrt(
+                jnp.mean(jnp.square((h + y).astype(jnp.float32)))
+            ).astype(h.dtype)
+            return jnp.roll(out, 1, axis=2)
+
+        for form in ("scatter", "gather"):
+            res[f"layer_{form}_us"] = per_trip_us(
+                forced(form == "gather", layer), h0, lp, experts)
+        out[f"{preset}_{t}"] = {k_: round(v, 3) if isinstance(v, float)
+                                else v for k_, v in res.items()}
+    return out
 
 
 def _seeded_prompts(cfg: dict, vocab: int) -> list:
@@ -1204,6 +1386,15 @@ def child_parity(cfg: dict) -> dict:
             cfg, preset="smallthinker-21b-pp4", tokens=(64, 1024))
         check_routed_experts(res["all_held_expert_err"],
                              cfg["routed_expert_tol"])
+        # The widest decode rung of the two chips that hold few experts,
+        # the routing piled so that a second round runs and a token's
+        # rows lie in two: each round's combine gathers (PR 45).
+        res["spilled_expert_err"] = {
+            preset: _routed_expert_errors(cfg, preset=preset, tokens=(32,),
+                                          spill=True)
+            for preset in ("kimi-k2-ep32", "laguna-s-ep8")}
+        for errs in res["spilled_expert_err"].values():
+            check_routed_experts(errs, cfg["routed_expert_tol"])
         res["nope_window_kernel_err"] = _mixed_kernel_errors(
             cfg, kv_heads=4, kinds=((28, 4096), (28, 0)), lanes=4,
             ctx=5000, rows=1024)
@@ -1288,7 +1479,12 @@ def child_tp4_parity(cfg: dict) -> dict:
             "device": device}
 
 
-CHILDREN = {"parity": child_parity, "tp4_parity": child_tp4_parity}
+def child_combine_costs(cfg: dict) -> dict:
+    return {"device": _child_setup(cfg), "combine_costs": _combine_costs(cfg)}
+
+
+CHILDREN = {"parity": child_parity, "tp4_parity": child_tp4_parity,
+            "combine_costs": child_combine_costs}
 
 
 def main() -> int:
@@ -1300,8 +1496,16 @@ def main() -> int:
                     help="4 = the cross-chip phases only (tp4, dp4)")
     ap.add_argument("--seed", type=int, default=0,
                     help="seed of the random weights and prompts")
+    ap.add_argument("--combine-costs", action="store_true",
+                    help="only time the grouped expert layer's two "
+                         "combines at Laguna's and Kimi's shapes (what "
+                         "moe_experts.SCATTERED_ROW_COST is set from)")
     args = ap.parse_args()
     t0 = time.monotonic()
+    if args.combine_costs:
+        emit(run_child(dict(SETTINGS, seed=args.seed), "combine_costs",
+                       timeout=1800.0))
+        return 0
     try:
         phases = run(args.chips, args.seed, SETTINGS)
         device = result_device(phases)

@@ -12,6 +12,8 @@ test spawns (fleet workers, the CLI under test) lands on the CPU too.
 import os
 import sys
 
+import pytest
+
 os.environ.setdefault("JAX_DEFAULT_MATMUL_PRECISION", "highest")
 
 import jax  # noqa: E402
@@ -29,6 +31,23 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 import _xla_cache  # noqa: E402
 
 _xla_cache.enable(jax)
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _drop_compiled_programs():
+    """A process keeps every program it has compiled (the jit caches),
+    each a few mappings of executable memory. A worker that has run a
+    thousand-odd tests of a dozen files reaches the kernel's limit of
+    mappings a process (``vm.max_map_count``, 65530), and XLA:CPU's next
+    compile kills it: "LLVM ERROR: Unable to allocate section memory!",
+    or a segmentation fault, in whatever test comes next (PR 45: three
+    whole runs of three each lost a worker, and the dead worker's tests
+    replayed in one process die at the same test at the parent commit
+    too). No test file runs another's programs again: drop them when a
+    file is done; the persistent cache above makes the few that recur
+    cheap."""
+    yield
+    jax.clear_caches()
 
 
 def randomize_qkv_biases(params, seed: int = 7, scale: float = 0.1) -> None:

@@ -261,3 +261,45 @@ def test_routed_expert_check_in_interpret_mode():
                          "planted_next_expert", "planted_layer_0"}
     chip_smoke.check_routed_experts(errs,
                                     chip_smoke.SETTINGS["routed_expert_tol"])
+
+
+def test_spilled_routing_check_in_interpret_mode():
+    """The smoke's check of a decode rung whose routing spills (run on
+    the chip at Kimi-K2's and Laguna's widths, 32 lanes) at tiny-laguna's
+    32-token bucket through the interpreter (4 of 16 held, top-3: two
+    rounds of 7 tiles): the check itself refuses a routing that fills
+    one round or keeps every token's rows in one; the layer, gathering
+    in each round, is within the tolerance; the planted faults are not."""
+    errs = chip_smoke._routed_expert_errors(
+        TINY, preset="tiny-laguna", tokens=(32,), idle=3, interpret=True,
+        spill=True)
+    assert set(errs) == {"spilled_32", "planted_zero",
+                         "planted_next_expert", "planted_layer_0"}
+    chip_smoke.check_routed_experts(errs,
+                                    chip_smoke.SETTINGS["routed_expert_tol"])
+    # A bucket too small to spill its experts' tiles is refused.
+    with pytest.raises(chip_smoke.SmokeFailure, match="cannot spill"):
+        chip_smoke._routed_expert_errors(
+            TINY, preset="tiny-laguna", tokens=(8,), idle=3, interpret=True,
+            spill=True)
+
+
+def test_combine_costs_in_interpret_mode():
+    """The microbenchmark ``moe_experts.SCATTERED_ROW_COST`` is set from
+    (run on the chip at Laguna's and Kimi's widths) at a tiny preset
+    through the interpreter: both combines and both forms of the layer
+    run on one layout, the constant is what it was afterwards, and the
+    reading has the keys the constant's comment quotes. (The times are
+    the CPU's: no device number.)"""
+    from tpu_inference.kernels import moe_experts
+
+    kept = moe_experts.SCATTERED_ROW_COST
+    out = chip_smoke._combine_costs(TINY, shapes=(("tiny-laguna", 32),),
+                                    reps=2, interpret=True)
+    assert moe_experts.SCATTERED_ROW_COST == kept
+    assert set(out) == {"tiny-laguna_32"}
+    got = out["tiny-laguna_32"]
+    assert (got["rows_round"], got["pairs"], got["rounds"]) == (112, 96, 2)
+    assert set(got) == {"rows_round", "pairs", "rounds", "scatter_us",
+                        "gather_us", "row_cost", "layer_scatter_us",
+                        "layer_gather_us"}

@@ -380,29 +380,61 @@ def _plain_loop(x, weights, top_local, gates, act):
 
 
 def by_scatter(groups):
-    """The same layout, combined as a layout of several rounds is: the
-    loop (one trip) and the scatter-add."""
+    """The same layout, combined as a layout that does not gather is: the
+    loop (one trip where one round is laid out) and the scatter-add."""
     return groups._replace(pair_row=None, pair_gate=None)
 
 
-@pytest.mark.parametrize("t", [3, 64, 1024])
+def _piled(t, k, held):
+    """Every pair held, all on expert 0 (a token's pairs need not differ
+    for the layout) but the last ``held - 1`` pairs, which go to an
+    expert each: the most tiles T x k pairs can take."""
+    top = np.zeros(t * k, np.int32)
+    top[1 - held:] = np.arange(1, held)
+    return jnp.asarray(top.reshape(t, k))
+
+
+# (held, of, top-k, tokens, routing, rounds laid out, rounds that run):
+# SmallThinker's stage at a decode step and at a chunk (one round); the
+# decode rungs of Laguna's chip (32 of 256, top-10: two rounds of 37
+# tiles) and of Kimi's (12 of 384, top-8: two or three rounds of 13), a
+# spread routing, which fills one round, and a piled one, which runs
+# every round laid out.
+@pytest.mark.parametrize("held,n_experts,k,t,routing,rounds,ran", [
+    (64, 64, 6, 3, "spread", 1, 1), (64, 64, 6, 64, "spread", 1, 1),
+    (64, 64, 6, 1024, "spread", 1, 1),
+    (32, 256, 10, 32, "spread", 2, 1), (32, 256, 10, 32, "piled", 2, 2),
+    (32, 256, 10, 8, "piled", 2, 2),
+    (12, 384, 8, 32, "spread", 3, 1), (12, 384, 8, 32, "piled", 3, 3),
+    (12, 384, 8, 16, "piled", 2, 2)])
 @pytest.mark.parametrize("pallas", [False, True], ids=["dense", "pallas"])
 @pytest.mark.parametrize("act", ["relu", "silu"])
-def test_the_gather_combine_is_the_scatter_combine(act, pallas, t):
-    """All 64 experts held, top-6 (SmallThinker's stage): one round holds
-    every pair at a decode step and at a chunk, and summing each token's
-    six gathered rows equals scatter-adding the rows to their tokens."""
-    e, k = 64, 6
-    x, w, top_local, gates, valid = _one_routing(t, k, e, e)
-    assert moe_experts.combines_by_gather(t, k, e, t * k)
-    groups = moe_experts.group_pairs(top_local, gates, e, t * k)
-    assert groups.row_token.shape[0] == groups.round_rows     # one round
+def test_the_gather_combine_is_the_scatter_combine(
+        act, pallas, held, n_experts, k, t, routing, rounds, ran):
+    """Summing each token's gathered rows, round by round where several
+    rounds are laid out, equals scatter-adding each round's rows to their
+    tokens: the same pairs computed, a token whose pairs lie in two
+    rounds gets each once."""
+    x, w, top_local, gates, valid = _one_routing(
+        t, k, held, n_experts, idle=routing == "spread")
+    if routing == "piled":
+        top_local = _piled(t, k, held)
+    expected = t * k * held / n_experts
+    assert moe_experts.combines_by_gather(t, k, held, expected)
+    groups = moe_experts.group_pairs(top_local, gates, held, expected)
+    rr = groups.round_rows
+    assert groups.row_token.shape[0] == rounds * rr
+    assert -(-int(groups.n_tiles) * groups.tm // rr) == ran
+    in_round = np.asarray(groups.pair_row) // rr
+    two = [i for i, r in enumerate(in_round)
+           if len(set(r[np.asarray(top_local[i]) < held])) > 1]
+    assert bool(two) == (ran > 1), "a token's pairs lie in two rounds"
     kw = dict(pallas=pallas, interpret=True, act=act)
     y, done = moe_experts.grouped_experts(x, groups, *w, 1, **kw)
     y2, done2 = moe_experts.grouped_experts(x, by_scatter(groups), *w, 1,
                                             **kw)
     assert y.dtype == y2.dtype == jnp.float32
-    assert int(done) == int(done2) == int(valid.sum()) * k
+    assert int(done) == int(done2) == int((top_local < held).sum())
     scale = float(np.std(np.asarray(y2)))
     assert np.abs(np.asarray(y) - np.asarray(y2)).max() < 1e-5 * scale
     want = _plain_loop(x, w, top_local, gates, act)
@@ -413,12 +445,13 @@ def test_the_gather_combine_is_the_scatter_combine(act, pallas, t):
 
 @pytest.mark.parametrize("preset,b,s", [
     ("tiny-smallthinker", 3, 1), ("tiny-smallthinker", 1, 64),
-    ("tiny-kimi", 3, 1), ("tiny-kimi", 1, 64)])
+    ("tiny-kimi", 3, 1), ("tiny-kimi", 1, 64), ("tiny-laguna", 1, 32)])
 def test_a_row_without_a_token_adds_nothing_to_the_gather(preset, b, s):
     """An idle decode lane (b x 1) and a padded bucket's tail (1 x s)
     through ``moe_ffn`` where the combine gathers: the routed part of such
     a row is exactly 0 and it counts in no statistic (tiny-kimi holds 8
-    of 16: half of the other rows' pairs have no row here either)."""
+    of 16: half of the other rows' pairs have no row here either;
+    tiny-laguna 4 of 16: its bucket gathers inside the rounds' loop)."""
     mcfg = PRESETS[preset]()
     assert dsv3.combines_by_gather(mcfg, b * s)
     held, d, f = mcfg.n_local_experts, mcfg.d_model, mcfg.moe_d_ff
@@ -477,19 +510,23 @@ def _float_scatters(text):
         if not r.endswith("i32")]
 
 
-# (held, of, top-k, tokens): tiny-laguna's expert layer at its 32-token
-# bucket, one made for the test; tiny-kimi's (half held, the boundary)
-# and tiny-smallthinker's at a bucket and at a decode rung.
-@pytest.mark.parametrize("held,n_experts,k,t,gathers", [
-    (4, 16, 3, 32, False), (3, 16, 4, 64, False),
-    (8, 16, 4, 32, True), (8, 16, 4, 2, True),
-    (8, 8, 3, 32, True), (8, 8, 3, 2, True)])
+# (held, of, top-k, tokens): two made for the test, a sixteenth or less
+# held at a bucket; tiny-laguna's expert layer at its 32-token bucket and
+# one more of several rounds; tiny-kimi's (half held, the boundary) and
+# tiny-smallthinker's at a bucket and at a decode rung.
+@pytest.mark.parametrize("held,n_experts,k,t,loops,gathers", [
+    (2, 64, 4, 256, True, False), (1, 16, 4, 64, True, False),
+    (4, 16, 3, 32, True, True), (3, 16, 4, 64, True, True),
+    (8, 16, 4, 32, False, True), (8, 16, 4, 2, False, True),
+    (8, 8, 3, 32, False, True), (8, 8, 3, 2, False, True)])
 def test_the_lowered_expert_layer_says_which_combine_runs(
-        held, n_experts, k, t, gathers):
-    """Under half of the experts held: several rounds, the predicate is
-    false, the program holds the ``while`` and the float scatter-add.
-    Half or more: one round, no ``while``, no float scatter, and the
-    output is the plain loop over the held experts."""
+        held, n_experts, k, t, loops, gathers):
+    """A round far smaller than ``T x k`` (a few experts held, a bucket
+    of rows): the predicate is false, the program holds the ``while`` and
+    the float scatter-add. Several rounds of which one holds about ``T x
+    k`` rows or more: the ``while`` stays and NO float scatter is in it.
+    Half or more held: one round, no ``while``, no float scatter. The
+    output is the plain loop over the held experts in each."""
     expected = t * k * held / n_experts
     assert moe_experts.combines_by_gather(t, k, held, expected) == gathers
     x, w, top_local, gates, _ = _one_routing(t, k, held, n_experts)
@@ -499,7 +536,7 @@ def test_the_lowered_expert_layer_says_which_combine_runs(
         return moe_experts.grouped_experts(x, groups, *w, 1, pallas=False)
 
     text = jax.jit(layer).lower(x, top_local, gates).as_text()
-    assert ("stablehlo.while" in text) == (not gathers)
+    assert ("stablehlo.while" in text) == loops
     assert bool(_float_scatters(text)) == (not gathers)
     y, done = layer(x, top_local, gates)
     assert int(done) == int((np.asarray(top_local) < held).sum())

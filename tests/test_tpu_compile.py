@@ -351,16 +351,21 @@ def _expert_layers(chip, preset, tokens):
                   s((le, held, d, f)), s((le, held, f, d)))
 
 
-# ``aot_rehearsal.program_hash`` of ``_expert_layers`` for the v5e, as
-# the PARENT commit (5d59d4d, PR 43) lowers them: the two expert cells
-# whose layouts are several rounds (12 of 384 and 32 of 256 experts held)
-# keep the loop and the scatter-add, at a 1024-token chunk and at their
-# widest decode rung. A PR that means to change them replaces the lines.
+# ``aot_rehearsal.program_hash`` of ``_expert_layers`` for the v5e: the
+# two expert cells whose layouts are several rounds (12 of 384 and 32 of
+# 256 experts held) keep the rounds' loop in every program. At a
+# 1024-token chunk a round lays out 896 / 4608 rows for 8192 / 10,240
+# pairs and scatter-adds them, as the PARENT commit (ddb76b1, PR 44)
+# lowers it: those two lines are the parent's. At their widest decode
+# rung (3 rounds of 208 rows for 256 pairs, 2 of 592 for 320) each round
+# gathers since PR 45 (``moe_experts.combines_by_gather``): those two
+# lines are this commit's. A PR that means to change them replaces the
+# lines.
 PARENT_EXPERT_LAYERS = {
     ("kimi-k2-ep32", 1024): "c55990047f54407d",
-    ("kimi-k2-ep32", 32): "2cd9fde333375077",
+    ("kimi-k2-ep32", 32): "f9b5e4d1ac13b7f9",
     ("laguna-s-ep8", 1024): "8839eb2b6a92f0a9",
-    ("laguna-s-ep8", 32): "f64883ec8f9e9709",
+    ("laguna-s-ep8", 32): "16383cb137a3d4c3",
 }
 
 
@@ -378,6 +383,41 @@ def test_expert_layers_of_several_rounds_lower_to_the_parents(
     assert program_hash(lowered) == PARENT_EXPERT_LAYERS[preset, tokens]
 
 
+def _made(hlo):
+    """(dtype, dims, op) of every instruction of a compiled program."""
+    import re
+    return re.findall(
+        r"^\s*(?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\(", hlo,
+        re.M)
+
+
+def _whiles(hlo):
+    import re
+    return len(re.findall(r"^\s*(?:ROOT )?%[\w.\-]+ = .*? while\(", hlo,
+                          re.M))
+
+
+@pytest.mark.parametrize("preset,d", [("laguna-s-ep8", 3072),
+                                      ("kimi-k2-ep32", 7168)])
+def test_a_decode_rung_of_several_rounds_compiles_with_no_scatter_add(
+        chip, preset, d):
+    """Laguna's and Kimi's widest decode rung (``[32, 3072]`` in 2 rounds
+    of 592 rows, ``[32, 7168]`` in 3 of 208): the compiled program keeps
+    the rounds' loop inside the scan over the layers and holds no float32
+    scatter, into ``[32, D]`` or anywhere, and no float32 product over a
+    round's padded rows."""
+    step, shapes = _expert_layers(chip, preset, 32)
+    hlo = jax.jit(step).lower(*shapes).compile().as_text()
+    assert hlo.count("tpu_custom_call") >= 2
+    made = _made(hlo)
+    assert [m for m in made if m[2] == "scatter" and m[0] == "f32"] == []
+    rr = {3072: 592, 7168: 208}[d]
+    assert [m for m in made if m[0] == "f32" and m[1] == f"{rr},{d}"
+            and m[2] not in ("custom-call", "get-tuple-element", "bitcast",
+                             "parameter")] == []
+    assert _whiles(hlo) == 2
+
+
 @pytest.mark.parametrize("tokens", [64, 512, 1024])
 def test_all_held_expert_layers_compile_for_v5e_with_no_scatter_add(
         chip, tokens):
@@ -387,21 +427,16 @@ def test_all_held_expert_layers_compile_for_v5e_with_no_scatter_add(
     float32 product over the ``cap`` padded rows (14,336 at a 1024-token
     chunk, 11,264 at 512, 1,408 at 64 lanes), and the only loop is the
     scan over the 12 layers."""
-    import re
-
     step, shapes = _expert_layers(chip, "smallthinker-21b-pp4", tokens)
     hlo = jax.jit(step).lower(*shapes).compile().as_text()
     assert hlo.count("tpu_custom_call") >= 2
     cap = {64: 1408, 512: 11264, 1024: 14336}[tokens]
-    made = re.findall(
-        r"^\s*(?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\(", hlo,
-        re.M)
+    made = _made(hlo)
     assert [m for m in made if m[2] == "scatter" and m[0] == "f32"] == []
     assert [m for m in made if m[0] == "f32" and m[1] == f"{cap},2560"
             and m[2] not in ("custom-call", "get-tuple-element",
                              "bitcast", "parameter")] == []
-    assert len(re.findall(r"^\s*(?:ROOT )?%[\w.\-]+ = .*? while\(", hlo,
-                          re.M)) == 1
+    assert _whiles(hlo) == 1
 
 
 @pytest.mark.parametrize("lanes,rows", [(1, 1024), (4, 1024), (4, 64)])

@@ -11,22 +11,22 @@ the routing:
    vectors are sized for the worst case (every pair local).
 2. ``grouped_experts`` walks that layout in rounds of a fixed number of
    tiles: one round covers twice the expected load, so it is almost
-   always the only one that has rows. A round gathers its rows'
-   activations and runs the two kernels below. How the gated results
+   always the only one that has rows; where several are laid out a
+   ``lax.while_loop`` whose trip count is the routing's runs them (a
+   routing skewed onto one expert just takes more). A round gathers its
+   rows' activations and runs the two kernels below. How its gated rows
    come back to their tokens is decided by the layout's shape alone
    (``combines_by_gather``, Python ints at trace time):
-   - where ONE round is the worst case too (twice the expected local
-     pairs cover every pair, i.e. half or more of the routed experts are
-     held: SmallThinker's stage holds all 64), the round runs once with
-     no loop, and each token GATHERS its k rows (``PairGroups.pair_row``)
-     and sums them with its gates in float32: ``T x k <= cap`` rows are
-     read, the padding is never touched and no index collides;
-   - everywhere else (Kimi 12 of 384, Laguna 32 of 256) a
-     ``lax.while_loop`` whose trip count is the routing's runs the rounds
-     (a routing skewed onto one expert just takes more of them) and each
-     SCATTER-ADDS its gated rows to their tokens: a round lays out a
-     fraction of ``T x k`` rows there, so the scatter walks fewer rows
-     than a gather would read.
+   - GATHER where ``T x k <= SCATTERED_ROW_COST x`` a round's rows: each
+     token reads its k rows (``PairGroups.pair_row``) FROM THAT ROUND's
+     result, a pair whose row lies in another round (or nowhere) at gate
+     0, and sums them in float32 (``gathered_rows``): the padding is
+     never touched, no index collides. One round always qualifies (``T x
+     k <= cap``; SmallThinker's stage holds all 64: no loop), and so do
+     the decode rungs of a chip that holds few (Kimi 12 of 384: 3 rounds
+     of 208 rows for 256 pairs; Laguna 32 of 256: 2 of 592 for 320);
+   - SCATTER-ADD of the round's gated rows everywhere else (their prefill
+     programs: 896 rows a round for 8192 pairs, 4608 for 10,240).
 3. The kernels (``moe_grouped_experts_gate_up``, ``moe_grouped_experts_
    down``) take the STACKED expert weights ``[Le, E, K, N]`` with the
    layer and each tile's expert scalar-prefetched: the weight block's DMA
@@ -252,7 +252,7 @@ class PairGroups(NamedTuple):
     counts: jax.Array         # [E_held] pairs per held expert
     tm: int
     round_rows: int
-    # Where one round holds every pair (``combines_by_gather``), else None:
+    # Where the rows come back by gather (``combines_by_gather``), else None:
     pair_row: Optional[jax.Array] = None    # [T, k] row of each pair; cap: none
     pair_gate: Optional[jax.Array] = None   # [T, k] float32 gate; 0 where none
 
@@ -275,14 +275,35 @@ def round_layout(t: int, k: int, n_held: int, expected_pairs: float):
     return tm, round_tiles, -(-worst_tiles // round_tiles)
 
 
+# What adding one row of a round by scatter costs on the v5e, in rows
+# read by gather. ``chip_smoke._combine_costs`` (my chip run, PR 45: one
+# round's combine alone, us, scatter over the round's rows / gather over
+# T x k pairs, three readings within 5%), Laguna's widths (D 3072, k 10):
+# rung 8 78 / 12 (544 rows / 80 pairs: 0.98), rung 32 80 / 21 (592 /
+# 320: 2.05-2.10), 128 rows 85 / 43 (832 / 1280: 3.04), a 1024-token
+# chunk 594 / 864 (4608 / 10,240: 1.53-1.56; the gather LOSES, and by
+# 0.63 ms a layer inside the layer), 4 chunks 2219 / 3374 (1.88); Kimi's
+# (D 7168, k 8): rung 32 43 / 22 (208 / 256: 2.3-2.4), 64 rows 88 / 40
+# (5.0-5.2), a chunk 890 / 761 (896 / 8192: 10.7). A scattered row is
+# 0.10-0.16 us at D 3072 and 0.2-1.0 at 7168, a gathered one 0.03-0.15:
+# no one ratio holds everywhere. 2.0 is the rungs' reading and lies
+# under 2.22, Laguna's ``T x k`` over a round's rows from 256 rows on,
+# where the scatter wins; it leaves Kimi's prefill programs (2.3-9.1),
+# where the gather would win by 15% of a 0.9 ms op, on the scatter.
+SCATTERED_ROW_COST = 2.0
+
+
 def combines_by_gather(t: int, k: int, n_held: int,
                        expected_pairs: float) -> bool:
-    """Whether ``grouped_experts`` sums each token's k rows by a gather
-    (one round is the whole layout: twice the expected local pairs cover
-    the worst case) or scatter-adds round by round under a loop. What
-    ``group_pairs`` decides ``PairGroups.pair_row`` by, and what the
-    engine counts its warmed step programs by."""
-    return round_layout(t, k, n_held, expected_pairs)[2] == 1
+    """Whether a round of ``grouped_experts`` sums each token's k rows by
+    a gather (``T x k`` rows read) or scatter-adds the round's rows to
+    their tokens (``round_rows`` walked, each at ``SCATTERED_ROW_COST``
+    gathered rows), from Python ints alone. One round always gathers
+    (``T x k <= cap``). What ``group_pairs`` decides
+    ``PairGroups.pair_row`` by, and what the engine counts its warmed
+    step programs by."""
+    tm, round_tiles, _ = round_layout(t, k, n_held, expected_pairs)
+    return t * k <= SCATTERED_ROW_COST * round_tiles * tm
 
 
 def group_pairs(top_local: jax.Array, gates: jax.Array, n_held: int,
@@ -316,22 +337,42 @@ def group_pairs(top_local: jax.Array, gates: jax.Array, n_held: int,
     groups = PairGroups(row_token, row_gate, tile_expert,
                         tile_end[-1].astype(jnp.int32), counts, tm,
                         round_tiles * tm)
-    if rounds == 1:
+    if combines_by_gather(t, k, n_held, expected_pairs):
         groups = groups._replace(
             pair_row=dest.reshape(t, k),
             pair_gate=jnp.where(local, gates.reshape(-1), 0.0).reshape(t, k))
     return groups
 
 
+def gathered_rows(yr: jax.Array, groups: PairGroups, r=None) -> jax.Array:
+    """What round ``r``'s rows ``yr`` [round_rows, D] add to the tokens,
+    [T, D] float32: each token's k rows of that round, gated, summed over
+    k (``r`` None: the layout's only round, every pair's row is its
+    own)."""
+    # A pair with no row (not local, no token) reads any row at gate 0.
+    # The k rows of a token are k-major, [k, T, D]: whole tiles of
+    # (tokens, D), where [T, k, D] would pad k up to a tile's 8 rows.
+    # (Index, rows, gate, in that order: a one-round program is then op
+    # for op what it was, and its compiled form is found in the cache.)
+    at = groups.pair_row.T
+    if r is not None:
+        at = at - r * groups.round_rows
+    rows = jnp.take(yr, at, axis=0, mode="clip")
+    gate = groups.pair_gate.T
+    if r is not None:       # a pair whose row lies in another round: 0
+        gate = jnp.where((at >= 0) & (at < groups.round_rows), gate, 0.0)
+    return jnp.sum(rows * gate[:, :, None], axis=0)
+
+
 def grouped_experts(x: jax.Array, groups: PairGroups, w_gate, w_up, w_down,
                     layer, *, pallas: bool, interpret: bool = False,
                     act: str = "silu"):
     """x [T, D] -> (sum over local pairs of gate * E_e(x) [T, D] float32,
-    pairs computed). Where the layout is one round (``groups.pair_row``)
-    it runs once and every token gathers its k rows; else
-    ceil(tiles in use / tiles a round) rounds run under a loop, each
-    scatter-adding its rows. ``act`` is the gate's activation (ACTS),
-    static."""
+    pairs computed). One round runs once; where several are laid out,
+    ceil(tiles in use / tiles a round) of them run under a loop. A
+    round's rows come back to their tokens by gather
+    (``groups.pair_row``) or by scatter-add (module docstring, 2).
+    ``act`` is the gate's activation (ACTS), static."""
     t, d = x.shape
     tm, rr = groups.tm, groups.round_rows
     rt = rr // tm
@@ -351,25 +392,25 @@ def grouped_experts(x: jax.Array, groups: PairGroups, w_gate, w_up, w_down,
             return grouped_ffn_xla(xr, w_gate, w_up, w_down, layer, te,
                                    n_act, tm=tm, act=act)
 
-    if groups.pair_row is not None:
+    gathers = groups.pair_row is not None
+    if gathers and groups.row_token.shape[0] == rr:
         tok = groups.row_token
         yr = run(tok, groups.tile_expert, groups.n_tiles)
-        # A pair with no row (not local, no token) reads any row at gate
-        # 0. The k rows of a token are k-major, [k, T, D]: whole tiles of
-        # (tokens, D), where [T, k, D] would pad k up to a tile's 8 rows.
-        rows = jnp.take(yr, groups.pair_row.T, axis=0, mode="clip")
-        y = jnp.sum(rows * groups.pair_gate.T[:, :, None], axis=0)
-        return y, jnp.sum(tok < t).astype(jnp.int32)
+        return gathered_rows(yr, groups), jnp.sum(tok < t).astype(jnp.int32)
     n_rounds = -(-groups.n_tiles // rt)
 
     def body(carry):
         r, y, done = carry
         tok = jax.lax.dynamic_slice(groups.row_token, (r * rr,), (rr,))
-        gate = jax.lax.dynamic_slice(groups.row_gate, (r * rr,), (rr,))
+        gate = (None if gathers else
+                jax.lax.dynamic_slice(groups.row_gate, (r * rr,), (rr,)))
         te = jax.lax.dynamic_slice(groups.tile_expert, (r * rt,), (rt,))
         n_act = jnp.clip(groups.n_tiles - r * rt, 0, rt)
         yr = run(tok, te, n_act)
-        y = y.at[tok].add(yr * gate[:, None], mode="drop")
+        if gathers:
+            y = y + gathered_rows(yr, groups, r)
+        else:
+            y = y.at[tok].add(yr * gate[:, None], mode="drop")
         return r + 1, y, done + jnp.sum(tok < t).astype(jnp.int32)
 
     _, y, done = jax.lax.while_loop(

@@ -224,7 +224,9 @@ def expected_local_pairs(cfg: ModelConfig, rows: int) -> float:
 
 def combines_by_gather(cfg: ModelConfig, rows: int) -> bool:
     """Whether the expert layer of a step program that runs ``rows``
-    token rows sums its rows by gather (kernels/moe_experts.py, point 2):
+    token rows sums each round's rows by gather (kernels/moe_experts.py,
+    point 2: ``rows x k`` is no more than ``SCATTERED_ROW_COST`` x a
+    round's rows; the rounds' loop stays wherever several are laid out):
     the predicate ``moe_ffn``'s layout is built by, from the same ints."""
     return moe_experts.combines_by_gather(
         rows, cfg.n_experts_per_tok, cfg.n_local_experts,

@@ -2818,9 +2818,10 @@ class EngineTelemetry:
                   fn=lambda: int(st[at["decode_layers"]]))
         r.gauge("tpu_inf_moe_gather_combine_programs",
                 "Warmed step programs whose expert layers sum each "
-                "token's k rows by a gather (one round holds every pair: "
+                "token's k rows by a gather from each round's result (T x "
+                "k is no more than SCATTERED_ROW_COST x a round's rows: "
                 "kernels/moe_experts.py combines_by_gather) and not by a "
-                "scatter-add under a loop; set once, by warm-up",
+                "scatter-add of the round's rows; set once, by warm-up",
                 fn=lambda: engine.gather_combine_programs)
         for e in range(n_held):
             r.counter("tpu_inf_moe_expert_pairs_total",
