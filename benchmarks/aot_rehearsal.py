@@ -218,8 +218,7 @@ def main() -> int:
         mcfg, EngineConfig(quant=args.quant, attn_backend="pallas",
                            max_pages_per_seq=mp),
         dict(max_batch_size="auto", num_pages="auto", decode_ladder="auto",
-             target_ctx=args.target_ctx, batch_cap=args.batch_cap,
-             speculative=False),
+             target_ctx=args.target_ctx, batch_cap=args.batch_cap),
         tp=args.tp, hbm_bytes=args.hbm_bytes)
 
     # The stand-in: any small engine on the Pallas backend (its

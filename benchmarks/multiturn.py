@@ -380,9 +380,6 @@ def main() -> dict:
     p.add_argument("--tokenizer", default="byte")
     p.add_argument("--checkpoint", default=None)
     p.add_argument("--tp", type=int, default=1)
-    p.add_argument("--draft-model", default=None)
-    p.add_argument("--draft-checkpoint", default=None)
-    p.add_argument("--num-speculative-tokens", type=int, default=0)
     p.add_argument("--conversations", type=int, default=6)
     p.add_argument("--turns", type=int, default=5)
     p.add_argument("--max-tokens", type=int, default=48,

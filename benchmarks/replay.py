@@ -180,8 +180,7 @@ def start_server(args) -> tuple:
     srv = build_server(
         model=args.model, tokenizer=args.tokenizer, tp=args.tp,
         sp=args.sp, sp_attn=args.sp_attn, dp=getattr(args, "dp", 1),
-        draft_model=args.draft_model, checkpoint=args.checkpoint,
-        draft_checkpoint=args.draft_checkpoint,
+        checkpoint=args.checkpoint,
         warmup=not args.no_warmup,
         max_batch_size=args.max_batch_size, num_pages=args.num_pages,
         decode_ladder=tuple(getattr(args, "decode_ladder_rungs", ()) or ()),
@@ -274,13 +273,10 @@ def start_server(args) -> tuple:
                                      "interactive"),
             "class_queue_depth":
                 getattr(args, "class_queue_depth", 0)},
-        spec_mode=("ngram" if getattr(args, "spec_mode", None) == "ngram"
-                   else "draft"),
         ngram_window=getattr(args, "ngram_window", 3),
         num_speculative_tokens=(
             args.num_speculative_tokens
-            if (args.draft_model
-                or getattr(args, "spec_mode", None) == "ngram") else 0),
+            if getattr(args, "spec_mode", None) == "ngram" else 0),
         # Smoke lane: small prefill buckets so the CPU tier-1 run
         # compiles in seconds, not minutes (a lane can pin its own —
         # compare-pd needs 256-token chunks so an in-engine prefill
@@ -346,8 +342,6 @@ def main() -> dict:
     p.add_argument("--host-cache-pages", type=int, default=0,
                    help="host-RAM KV tier capacity in pages (0 = off; "
                         "README 'Tiered KV cache')")
-    p.add_argument("--draft-model", default=None)
-    p.add_argument("--draft-checkpoint", default=None)
     p.add_argument("--num-speculative-tokens", type=int, default=4)
     p.add_argument("--spec-mode", default=None, choices=("ngram",),
                    help="'ngram' = draft-free self-drafting speculation "

@@ -25,8 +25,8 @@ select_platform("cpu", 8)
 # error on standard-normal f32 inputs), which swamps parity tolerances.
 jax.config.update("jax_default_matmul_precision", "highest")
 
-# Persistent XLA compilation cache: a warm test_speculative.py run drops
-# 41s -> 11s (rationale + knobs in tests/_xla_cache.py).
+# Persistent XLA compilation cache: a warm run of an engine test file
+# drops 41s -> 11s (rationale + knobs in tests/_xla_cache.py).
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 import _xla_cache  # noqa: E402
 

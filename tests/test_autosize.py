@@ -107,20 +107,31 @@ def test_swa_model_batches_by_window_not_context():
     assert swa_sz.target_ctx <= mistral.sliding_window + 32
 
 
-def test_swa_clamp_off_under_speculative_decoding():
-    """Spec decode disables behind-window eviction (the window-less
-    draft reads full context), so the SWA batch clamp must not apply."""
-    import dataclasses
+def test_swa_clamp_is_the_same_with_and_without_speculation():
+    """Speculation keeps behind-window eviction on, so the sizer knows
+    nothing of it: ``auto_size`` takes no such argument, and a server
+    that speculates is given the batch and the pool of one that does
+    not."""
+    import inspect
 
-    from tpu_inference.config import PRESETS
+    from tpu_inference.config import PRESETS, EngineConfig
 
+    assert "speculative" not in inspect.signature(
+        autosize.auto_size).parameters
     mistral = PRESETS["mistral-7b"]()
-    full = dataclasses.replace(mistral, sliding_window=0)
-    kw = dict(hbm_bytes=16e9, quant="int8", kv_quant="int8",
-              max_pages_per_seq=1024, batch_cap=256)
-    spec_sz = autosize.auto_size(mistral, speculative=True, **kw)
-    full_sz = autosize.auto_size(full, **kw)
-    assert spec_sz.max_batch_size == full_sz.max_batch_size
+    req = dict(max_batch_size="auto", num_pages="auto", decode_ladder="off",
+               target_ctx=0, batch_cap=256)
+    sized = [autosize.resolve_sizing(
+        mistral, EngineConfig(quant="int8", kv_quant="int8",
+                              max_pages_per_seq=1024,
+                              num_speculative_tokens=gamma),
+        dict(req), hbm_bytes=16e9) for gamma in (0, 3)]
+    assert (sized[0].max_batch_size, sized[0].num_pages) == (
+        sized[1].max_batch_size, sized[1].num_pages)
+    clamped = autosize.auto_size(mistral, hbm_bytes=16e9, quant="int8",
+                                 kv_quant="int8", max_pages_per_seq=1024,
+                                 batch_cap=256)
+    assert sized[1].max_batch_size == clamped.max_batch_size
 
 
 def test_decode_ladder_rungs_shapes():
@@ -202,7 +213,7 @@ def test_resolve_sizing_auto_and_explicit():
 
     args = types.SimpleNamespace(max_batch_size="auto", num_pages="auto",
                                  decode_ladder="auto", target_ctx=0,
-                                 batch_cap=32, draft_model=None)
+                                 batch_cap=32)
     req = autosize.sizing_request(args)
     ecfg = autosize.resolve_sizing(
         PRESETS["mistral-7b"](), EngineConfig(quant="int8"), req,

@@ -142,7 +142,7 @@ srv = build_server(model="tiny-llama", warmup=False, dp=1, platform="cpu",
                    server_overrides=dict(fleet="subprocess"),
                    sizing=dict(max_batch_size=2, num_pages=32,
                                decode_ladder="off", target_ctx=0,
-                               batch_cap=32, speculative=False),
+                               batch_cap=32),
                    page_size=8, max_pages_per_seq=4, prefill_buckets=(16,))
 srv.group.start()
 done, toks = threading.Event(), []

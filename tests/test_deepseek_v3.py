@@ -625,7 +625,7 @@ def test_yarn_frequencies_and_scale():
 # ------------------------------------------------------------------ (f)
 @pytest.mark.parametrize("kw,needle", [
     (dict(kv_quant="int8"), "kv_quant"),
-    (dict(num_speculative_tokens=3, spec_mode="ngram"), "speculative"),
+    (dict(num_speculative_tokens=3), "speculative"),
     (dict(host_cache_pages=8), "host KV tier"),
     (dict(quant="int4"), "int4"),
     (dict(role="prefill"), "role"),

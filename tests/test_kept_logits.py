@@ -88,7 +88,7 @@ def test_off_by_default_and_no_output_of_the_programs():
 
 
 @pytest.mark.parametrize("over,said", [
-    (dict(spec_mode="ngram", num_speculative_tokens=2),
+    (dict(num_speculative_tokens=2),
      "keep_logits does not support: speculative decoding"),
     (dict(role="decode"), "a handed-off sequence carries no kept rows"),
 ])

@@ -114,16 +114,19 @@ def test_mixtral_kv_int8():
     assert len(out[0]) == 8
 
 
-@pytest.mark.slow   # spec x kv-int8 combination; each covered separately
-def test_spec_decode_with_kv_int8():
+def test_verify_round_over_kv_int8():
+    """The verify round writes and reads the int8 pool like the decode
+    programs do: greedy tokens equal the plain int8 engine's."""
     cfg = tiny_llama()
-    draft = dataclasses.replace(cfg, n_layers=1, name="draft")
-    ecfg = EngineConfig(**BASE, kv_quant="int8", num_speculative_tokens=2,
-                        enable_prefix_cache=False)
-    eng = InferenceEngine(cfg, ecfg, seed=0, draft_cfg=draft)
-    assert eng.draft_kv.quantized
-    out = eng.generate([PROMPTS[0]], max_new_tokens=6)
-    assert len(out[0]) == 6
+    echo = [[3, 4, 5, 6] * 5]
+    want = InferenceEngine(cfg, EngineConfig(**BASE, kv_quant="int8"),
+                           seed=0).generate(echo, max_new_tokens=16)
+    eng = InferenceEngine(
+        cfg, EngineConfig(**BASE, kv_quant="int8", num_speculative_tokens=3),
+        seed=0)
+    assert eng.kv.quantized
+    assert eng.generate(echo, max_new_tokens=16) == want
+    assert eng.spec_rounds_total > 0
 
 
 @pytest.mark.slow   # int8 x kv-int8 x pallas combination sweep

@@ -400,14 +400,3 @@ def test_metrics_expose_rung_occupancy_mfu(model_setup, monkeypatch, chip):
     assert "tpu_inf_decode_ladder_top 16" in text
 
 
-def test_spec_decode_collapses_ladder(model_setup):
-    """Speculative decoding forces a single rung (the spec round has
-    one fused graph); the engine must say so rather than mis-dispatch."""
-    import dataclasses
-
-    model_cfg, params = model_setup
-    draft = dataclasses.replace(model_cfg, n_layers=1, name="draft")
-    ecfg = _ecfg(max_batch_size=4, decode_ladder=(2, 4),
-                 num_speculative_tokens=2, enable_prefix_cache=False)
-    eng = InferenceEngine(model_cfg, ecfg, params=params, draft_cfg=draft)
-    assert eng.ladder == (4,)

@@ -452,7 +452,7 @@ def test_cost_model_and_gauges_by_kind():
 
 # ------------------------------------------------------------------ (d)
 REQ = dict(max_batch_size="auto", num_pages="auto", decode_ladder="off",
-           target_ctx=0, batch_cap=32, speculative=False)
+           target_ctx=0, batch_cap=32)
 
 
 def test_auto_sizing_splits_the_budget_by_kind():
@@ -571,7 +571,7 @@ def test_validate_checks_values():
 @pytest.mark.parametrize("kw,what", [
     (dict(kv_quant="int8"), "kv_quant"),
     (dict(host_cache_pages=8), "host KV tier"),
-    (dict(num_speculative_tokens=2, spec_mode="ngram"), "speculative"),
+    (dict(num_speculative_tokens=2), "speculative"),
     (dict(role="prefill"), "role"),
     (dict(quant="int4"), "int4"),
 ])

@@ -1,5 +1,5 @@
-"""Draft-free n-gram speculation (README "Speculative decoding",
-spec_mode="ngram").
+"""N-gram speculation (README "Speculative decoding"): the one kind of
+speculation, on when ``num_speculative_tokens > 0``.
 
 The load-bearing claims: greedy output is byte-identical to plain decode
 (speculation is a scheduling decision, never a behavior change) through
@@ -7,7 +7,7 @@ the engine AND through the scheduler at every ladder rung, with
 dispatch-ahead staging, with the repetition penalty applied, and across
 preemption/recompute-resume; the adaptive-γ throttle converges to γ=0 on
 adversarial (echo-free) streams so spec can never lose; the host KV tier
-and the decode ladder stay ACTIVE under ngram mode (unlike draft mode);
+and the decode ladder stay ACTIVE under speculation;
 warmup covers (every rung) x (every verify width) so no XLA compile ever
 lands mid-serving; and the pool-leak invariant holds across spec rounds.
 """
@@ -44,7 +44,7 @@ def _ecfg(**kw):
 
 
 def _ngram_kw(gamma=4, **kw):
-    return dict(spec_mode="ngram", num_speculative_tokens=gamma, **kw)
+    return dict(num_speculative_tokens=gamma, **kw)
 
 
 def _submit_and_wait(sched, seqs, timeout=180.0, start=False):
@@ -99,9 +99,9 @@ def test_greedy_byte_identity_engine(model_setup):
 
 
 def test_ngram_keeps_ladder_and_host_tier(model_setup):
-    """Unlike draft-model spec, ngram mode keeps the decode ladder (no
-    single-rung collapse) and the host KV tier (no draft pool to
-    desync) — the gates PRs 6-7 built stay active."""
+    """Speculation keeps the decode ladder (no single-rung collapse)
+    and the host KV tier (no second pool to desync) — the gates PRs 6-7
+    built stay active."""
     model_cfg, params = model_setup
     eng = InferenceEngine(
         model_cfg, _ecfg(max_batch_size=16, decode_ladder=(4, 8, 16),
@@ -109,7 +109,7 @@ def test_ngram_keeps_ladder_and_host_tier(model_setup):
         params=params)
     assert eng.ladder == (4, 8, 16)
     assert eng.host_pool is not None
-    assert eng.spec_ngram and not eng.spec_draft
+    assert eng.spec_enabled
     # Verify graph widths: the full γ+1 round plus the narrow probe.
     assert eng._spec_widths == [2, 5]
 
@@ -171,8 +171,7 @@ def test_repeat_penalty_composes(model_setup, monkeypatch):
     """The repetition penalty applies inside the verify round (each
     position penalized against the window rolled with its accepted
     prefix), so penalized greedy ngram output == penalized plain output
-    — the PR drops the server's 'ignored under spec' warning for this
-    mode. Draft mode still zeroes the penalty.
+    and the server has no 'ignored under spec' warning to give.
 
     Two passes: the REAL proposer (the penalty suppresses the tiny
     model's cycles, so proposals mostly reject — the rejection/
@@ -441,38 +440,25 @@ def test_warmup_covers_rungs_and_widths_no_midserve_compile(model_setup):
 def test_spec_config_validation():
     from tpu_inference.config import validate_spec_config
 
-    validate_spec_config("ngram", 4, 3, has_draft_model=False)
-    validate_spec_config("draft", 4, 3, has_draft_model=True)
-    with pytest.raises(ValueError, match="draft-model"):
-        validate_spec_config("ngram", 4, 3, has_draft_model=True)
+    validate_spec_config(4, 3)
     with pytest.raises(ValueError, match="num-speculative-tokens"):
-        validate_spec_config("ngram", 0, 3, has_draft_model=False)
+        validate_spec_config(0, 3)
     with pytest.raises(ValueError, match="num-speculative-tokens"):
-        validate_spec_config("ngram", 17, 3, has_draft_model=False)
+        validate_spec_config(17, 3)
     with pytest.raises(ValueError, match="ngram-window"):
-        validate_spec_config("ngram", 4, 0, has_draft_model=False)
+        validate_spec_config(4, 0)
     with pytest.raises(ValueError, match="ngram-window"):
-        validate_spec_config("ngram", 4, 9, has_draft_model=False)
-    with pytest.raises(ValueError, match="spec-mode"):
-        validate_spec_config("banana", 4, 3, has_draft_model=False)
+        validate_spec_config(4, 9)
 
 
 def test_engine_rejects_bad_spec_config(model_setup):
     model_cfg, params = model_setup
-    with pytest.raises(ValueError, match="spec_mode"):
-        InferenceEngine(model_cfg, _ecfg(spec_mode="banana"),
-                        params=params)
     with pytest.raises(ValueError, match="num-speculative-tokens"):
-        InferenceEngine(model_cfg,
-                        _ecfg(spec_mode="ngram",
-                              num_speculative_tokens=0),
+        InferenceEngine(model_cfg, _ecfg(**_ngram_kw(gamma=17)),
                         params=params)
-    # ngram + a draft model is a contradiction, not a silent pick.
-    import dataclasses
-    draft = dataclasses.replace(model_cfg, n_layers=1, name="draft")
-    with pytest.raises(ValueError, match="draft-model"):
-        InferenceEngine(model_cfg, _ecfg(**_ngram_kw()), params=params,
-                        draft_cfg=draft)
+    with pytest.raises(ValueError, match="ngram-window"):
+        InferenceEngine(model_cfg, _ecfg(**_ngram_kw(ngram_window=9)),
+                        params=params)
 
 
 def test_spec_stats_snapshot(model_setup):
@@ -500,3 +486,175 @@ def test_spec_stats_snapshot(model_setup):
                  "tpu_inf_spec_fallback_rounds_total",
                  "tpu_inf_spec_throttles_total"):
         assert f"\n{name}" in text or text.startswith(name), name
+
+
+# ------------------------------------------- what the draft tests held
+
+def _serve(eng, seqs):
+    """Prefill ``seqs``, run rounds until none is active, release."""
+    for s in seqs:
+        eng.prefill(s)
+    while eng.active_sequences():
+        eng.decode_steps()
+    for s in seqs:
+        eng.release(s)
+
+
+ECHO = [3, 4, 5, 6] * 4         # proposals from the first round on
+
+
+@pytest.fixture(scope="module")
+def plain_engine(model_setup):
+    model_cfg, params = model_setup
+    return InferenceEngine(model_cfg, _ecfg(), params=params)
+
+
+@pytest.fixture(scope="module")
+def spec_engine(model_setup):
+    """Shared γ=3 engine (counters are cumulative across tests: assert
+    deltas or > 0, never totals)."""
+    model_cfg, params = model_setup
+    return InferenceEngine(model_cfg, _ecfg(num_speculative_tokens=3),
+                           params=params)
+
+
+def test_num_speculative_tokens_alone_turns_the_verify_round_on(
+        plain_engine, spec_engine):
+    assert spec_engine.spec_enabled and not plain_engine.spec_enabled
+    assert spec_engine._spec_widths == [2, 4]
+    rounds0 = spec_engine.spec_rounds_total
+    want = plain_engine.generate([ECHO], max_new_tokens=24)
+    assert spec_engine.generate([ECHO], max_new_tokens=24) == want
+    assert spec_engine.spec_rounds_total > rounds0
+    assert plain_engine.spec_rounds_total == 0
+
+
+@pytest.mark.parametrize("stop", ["eos", "budget"])
+def test_stops_at_the_same_token_as_plain_decode(plain_engine, spec_engine,
+                                                 monkeypatch, stop):
+    """EOS inside an accepted run, and a ``max_new_tokens`` budget that
+    ends mid-round, cut the stream where plain decode cuts it: what the
+    device emitted past the stop is dropped. An oracle proposer (the
+    plain continuation itself) makes every round accept its whole
+    proposal, so where the rounds end is known."""
+    ref = plain_engine.generate([ECHO], max_new_tokens=24)[0]
+
+    def oracle(hist, gamma, max_n, min_n=1):
+        done = len(hist) - len(ECHO)
+        return np.asarray(ref[done:done + gamma], np.int32)
+
+    monkeypatch.setattr(engine_mod, "ngram_propose", oracle)
+    probe = Sequence(request_id=0, prompt_tokens=list(ECHO),
+                     max_new_tokens=24)
+    spec_engine.prefill(probe)
+    ends = []                       # tokens generated after each round
+    while spec_engine.active_sequences():
+        spec_engine.decode_steps()
+        ends.append(len(probe.generated))
+    spec_engine.release(probe)
+    assert probe.generated == ref and len(ends) < 12   # runs accepted
+    # Positions with more of their round behind them.
+    inside = [i for i in range(1, 23) if i + 1 not in ends]
+    if stop == "eos":
+        # ... whose token occurs there FIRST (a tiny random model
+        # repeats; an earlier occurrence would stop the stream earlier).
+        k = max(i for i in inside if ref[i] not in ref[:i])
+        kw, budget, why = dict(eos_token_id=ref[k]), 24, "stop"
+    else:
+        k = inside[len(inside) // 2]
+        kw, budget, why = dict(), k + 1, "length"
+    s = Sequence(request_id=1, prompt_tokens=list(ECHO),
+                 max_new_tokens=budget, **kw)
+    _serve(spec_engine, [s])
+    assert s.generated == ref[:k + 1] and s.finish_reason == why
+    assert_pool_clean(spec_engine)
+
+
+def test_sampled_run_finishes(spec_engine):
+    """Temperature > 0 through the verify round: the right count, valid
+    ids, and the accounting stays ordered."""
+    out = spec_engine.generate([ECHO], max_new_tokens=20,
+                               temperature=0.8)[0]
+    assert len(out) == 20 and all(0 <= t < VOCAB for t in out)
+    assert spec_engine.spec_drafted >= spec_engine.spec_accepted >= 0
+    assert spec_engine.spec_drafted > 0
+
+
+def test_a_sequence_joins_a_speculating_batch(plain_engine, spec_engine):
+    """A sequence prefilled while another is mid-generation perturbs
+    neither stream."""
+    rng = np.random.default_rng(2)
+    p1 = ECHO + rng.integers(0, VOCAB, size=3).tolist()
+    p2 = rng.integers(0, VOCAB, size=17).tolist()
+    w1 = plain_engine.generate([p1], max_new_tokens=20)[0]
+    w2 = plain_engine.generate([p2], max_new_tokens=12)[0]
+    s1 = Sequence(request_id=3, prompt_tokens=p1, max_new_tokens=20)
+    s2 = Sequence(request_id=4, prompt_tokens=p2, max_new_tokens=12)
+    rounds0 = spec_engine.spec_rounds_total
+    spec_engine.prefill(s1)
+    spec_engine.decode_steps()
+    spec_engine.prefill(s2)         # joins while s1 is mid-generation
+    while spec_engine.active_sequences():
+        spec_engine.decode_steps()
+    for s in (s1, s2):
+        spec_engine.release(s)
+    assert (s1.generated, s2.generated) == (w1, w2)
+    assert spec_engine.spec_rounds_total > rounds0
+    assert_pool_clean(spec_engine)
+
+
+def test_prefix_cache_hit_then_speculation(model_setup, plain_engine):
+    """A request that hits the prefix cache and then speculates emits
+    the cold run's tokens, which are plain decode's."""
+    model_cfg, params = model_setup
+    eng = InferenceEngine(
+        model_cfg, _ecfg(num_speculative_tokens=3, enable_prefix_cache=True),
+        params=params)
+    assert eng.prefix_cache is not None
+    prompt = [ECHO + list(range(40, 57))]
+    want = plain_engine.generate(prompt, max_new_tokens=16)
+    cold = eng.generate(prompt, max_new_tokens=16)
+    hits0, rounds0 = eng.prefix_cache.hits_hbm.value, eng.spec_rounds_total
+    warm = eng.generate(prompt, max_new_tokens=16)
+    assert eng.prefix_cache.hits_hbm.value > hits0
+    assert eng.spec_rounds_total > rounds0
+    assert cold == warm == want
+
+
+def test_the_fork_is_gone_from_the_signatures():
+    """One kind of speculation: no field selects a proposal source, and
+    no constructor, builder or sizer takes a second model."""
+    import dataclasses
+    import inspect
+
+    from tpu_inference.config import validate_spec_config
+    from tpu_inference.engine import autosize, speculative
+    from tpu_inference.server import http
+
+    assert "spec_mode" not in {
+        f.name for f in dataclasses.fields(cfgs.EngineConfig)}
+    for fn in (InferenceEngine.__init__, http.build_server,
+               http.build_engine_group, autosize.auto_size,
+               validate_spec_config):
+        names = set(inspect.signature(fn).parameters)
+        assert not any("draft" in n or n in ("spec_mode", "speculative")
+                       for n in names), (fn.__qualname__, sorted(names))
+    with pytest.raises(TypeError):
+        InferenceEngine(cfgs.tiny_llama(vocab_size=VOCAB), _ecfg(),
+                        draft_cfg=cfgs.tiny_llama(vocab_size=VOCAB))
+    assert not hasattr(speculative, "spec_round")
+
+
+@pytest.mark.parametrize("argv,said", [
+    (["--draft-model", "tiny-llama"], "unrecognized arguments"),
+    (["--spec-mode", "draft"], "invalid choice: 'draft'"),
+])
+def test_cli_refuses_the_draft_flags_as_unknown(monkeypatch, capsys, argv,
+                                                said):
+    from tpu_inference.server import __main__ as cli
+
+    monkeypatch.setattr("sys.argv", ["tpu_inference.server"] + argv)
+    with pytest.raises(SystemExit) as e:
+        cli.main()
+    assert e.value.code == 2
+    assert said in capsys.readouterr().err

@@ -267,12 +267,10 @@ def _tp2_mesh():
 
 @pytest.mark.parametrize("model_kw,engine_kw,ctor_kw,needle", [
     ({}, dict(kv_quant="int8"), {}, "kv_quant"),
-    ({}, dict(num_speculative_tokens=3, spec_mode="ngram"), {},
-     "speculative"),
+    ({}, dict(num_speculative_tokens=3), {}, "speculative"),
     ({}, dict(host_cache_pages=8), {}, "host KV tier"),
     ({}, dict(role="prefill"), {}, "role"),
     (dict(early_exit_threshold=0.5), {}, {}, "early_exit_threshold"),
-    ({}, {}, dict(draft_cfg=PRESETS["tiny-llama"]), "speculative"),
     ({}, {}, dict(mesh=_tp2_mesh), "tp / sp"),
 ])
 def test_unsupported_is_refused_at_construction(model_kw, engine_kw, ctor_kw,

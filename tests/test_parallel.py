@@ -346,16 +346,17 @@ def _dp_tp_mesh():
             == _generate(cfg, ecfg))
 
 
-def _draft_speculation_under_tp():
-    """Draft-model speculation under TP: the draft's weights and its KV
-    pool shard like the target's; greedy tokens equal plain decode."""
-    import dataclasses
-    cfg = tp_llama_cfg()
-    draft = dataclasses.replace(cfg, n_layers=1, name="tp-draft")
-    spec = EngineConfig(**_MESH_ECFG, num_speculative_tokens=2)
-    assert (_generate(cfg, spec, build_mesh(ParallelConfig(tp=4)),
-                      draft_cfg=draft)
-            == _generate(cfg, EngineConfig(**_MESH_ECFG)))
+def _ngram_speculation_under_tp():
+    """N-gram speculation under TP: the verify round reads and writes
+    the tp-sharded pool; greedy tokens equal unsharded plain decode."""
+    cfg, echo = tp_llama_cfg(), [[5, 6, 7, 8] * 4]
+    spec = InferenceEngine(
+        cfg, EngineConfig(**_MESH_ECFG, num_speculative_tokens=2), seed=0,
+        mesh=build_mesh(ParallelConfig(tp=2), devices=jax.devices()[:2]))
+    plain = InferenceEngine(cfg, EngineConfig(**_MESH_ECFG), seed=0)
+    assert (spec.generate(echo, max_new_tokens=16)
+            == plain.generate(echo, max_new_tokens=16))
+    assert spec.spec_rounds_total > 0
 
 
 def _scheduler_over_tp_engine():
@@ -390,7 +391,7 @@ def _scheduler_over_tp_engine():
 
 
 @pytest.mark.parametrize("case", [_gemma_under_tp, _dp_tp_mesh,
-                                  _draft_speculation_under_tp,
+                                  _ngram_speculation_under_tp,
                                   _scheduler_over_tp_engine],
                          ids=lambda f: f.__name__.strip("_"))
 def test_sharded_serving_path_matches_unsharded(case):

@@ -280,7 +280,7 @@ REFUSED = {
     "kv_quant": (dict(kv_quant="int8"), "kv_quant='int8'"),
     "host tier": (dict(host_cache_pages=8), "the host KV tier"),
     "int4": (dict(quant="int4"), "quant='int4' (no test holds int4"),
-    "ngram": (dict(spec_mode="ngram", num_speculative_tokens=2),
+    "ngram": (dict(num_speculative_tokens=2),
               "a rejected draft would already have advanced"),
     "role": (dict(role="prefill"), "role='prefill' (P/D handoff"),
     "hybrid": (dict(hybrid_prefill=True), "hybrid_prefill (a prefill chunk"),
@@ -318,8 +318,7 @@ def test_refused_draft_mesh_and_export(weights, capsys):
     with pytest.raises(ValueError, match="speculative decoding"):
         InferenceEngine(CFG, EngineConfig(**dict(
             ENGINE, num_speculative_tokens=2, keep_logits=False)),
-            params=weights[1],
-            draft_cfg=PRESETS["tiny-llama"]())
+            params=weights[1])
     from jax.sharding import Mesh
     mesh = Mesh(np.asarray(jax.devices()[:2]).reshape(1, 2, 1),
                 ("dp", "tp", "sp"))

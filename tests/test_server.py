@@ -701,35 +701,45 @@ def test_boot_rejects_tokenizer_model_vocab_mismatch():
         InferenceServer(cfg)
 
 
-def test_spec_decode_repeat_penalty_warning():
-    """With a draft model configured, a request asking for repeat_penalty
-    gets a warning that the penalty is ignored (rejection sampling needs
-    the unmodified target distribution) — never a silent divergence."""
-    import dataclasses
+def test_repeat_penalty_under_speculation_is_applied_without_a_warning(
+        monkeypatch):
+    """A request asking for repeat_penalty on a speculating server gets
+    the penalised stream a plain server gives (the verify round applies
+    the penalty) and no warning. The proposer always proposes, so verify
+    rounds run whatever the tiny model says."""
+    import numpy as np
 
+    from tpu_inference.engine import engine as engine_mod
     from tpu_inference.engine.engine import InferenceEngine
     from tpu_inference.models import build_model
 
-    target = tiny_llama(vocab_size=512)
-    # Derive the draft from the target (same idiom as test_kv_quant) so
-    # the configs can't drift apart.
-    draft = dataclasses.replace(target, name="draft", n_layers=1)
-    params, _ = build_model(target, seed=0)
-    dparams, _ = build_model(draft, seed=9)
-    ecfg = EngineConfig(page_size=8, num_pages=64, max_pages_per_seq=8,
-                        max_batch_size=2, prefill_buckets=(16, 32),
-                        num_speculative_tokens=2)
-    eng = InferenceEngine(target, ecfg, params=params,
-                          draft_cfg=draft, draft_params=dparams)
-    srv = InferenceServer(FrameworkConfig(
-        model=target, engine=ecfg, server=ServerConfig(tokenizer="byte")),
-        engine=eng)
+    model = tiny_llama(vocab_size=512)
+    params, _ = build_model(model, seed=0)
+    monkeypatch.setattr(
+        engine_mod, "ngram_propose",
+        lambda hist, gamma, max_n, min_n=1: np.asarray(hist[-gamma:],
+                                                       np.int32))
 
-    async def go(client):
-        rec = await (await client.post("/api/generate", json={
-            "prompt": "hi", "stream": False, "max_tokens": 4,
-            "temperature": 0.0, "options": {"repeat_penalty": 1.2}})).json()
-        assert rec["done"]
-        assert any("speculative" in w for w in rec["warnings"])
+    def ask(gamma, penalty):
+        ecfg = EngineConfig(page_size=8, num_pages=64, max_pages_per_seq=8,
+                            max_batch_size=2, prefill_buckets=(16, 32),
+                            num_speculative_tokens=gamma)
+        eng = InferenceEngine(model, ecfg, params=params)
+        srv = InferenceServer(FrameworkConfig(
+            model=model, engine=ecfg,
+            server=ServerConfig(tokenizer="byte")), engine=eng)
 
-    _run(srv, go)
+        async def go(client):
+            return await (await client.post("/api/generate", json={
+                "prompt": "abcabcabcabc", "stream": False, "max_tokens": 24,
+                "temperature": 0.0,
+                "options": {"repeat_penalty": penalty}})).json()
+
+        return _run(srv, go), eng
+
+    spec, eng = ask(3, 1.3)
+    plain, _ = ask(0, 1.3)
+    unpenalised, _ = ask(0, 1.0)
+    assert spec["done"] and "warnings" not in spec
+    assert spec["response"] == plain["response"] != unpenalised["response"]
+    assert eng.spec_rounds_total > 0

@@ -411,26 +411,35 @@ def test_mistral_preset_registered():
     assert sz.max_batch_size >= 8
 
 
-def test_spec_decode_serves_swa_target(swa8, swa8_dense_engine):
-    """Speculative decoding with a window-less draft over an SWA target:
-    emitted tokens must equal the plain SWA engine's (the verify pass
-    windows the target's logits; rejection sampling is exact)."""
-    import dataclasses
+def test_speculation_serves_swa_target_with_eviction_on(
+        swa8, swa8_dense_engine, monkeypatch):
+    """Speculation over an SWA target keeps behind-window eviction ON
+    (the verify queries sit at positions >= ctx, so no windowed reader
+    reaches a released page): pages go back to the pool mid-flight and
+    the tokens equal the plain SWA engine's. The proposer is handed the
+    plain continuation, so whole proposals are accepted and the context
+    advances several positions a round."""
+    from tpu_inference.engine import engine as engine_mod
 
     cfg, params, _ = swa8
     rng = np.random.default_rng(7)
     prompts = [rng.integers(0, 256, size=n).tolist() for n in (6, 18)]
-    want = swa8_dense_engine.generate(prompts, max_new_tokens=12)
+    want = swa8_dense_engine.generate(prompts, max_new_tokens=40)
 
-    draft_cfg = dataclasses.replace(cfg, name="draft", n_layers=1,
-                                    sliding_window=0)
-    draft_params, _ = build_model(draft_cfg, seed=9)
+    def oracle(hist, gamma, max_n, min_n=1):
+        for p, w in zip(prompts, want):
+            if list(hist[:len(p)]) == p:
+                done = len(hist) - len(p)
+                return np.asarray(w[done:done + gamma], np.int32)
+
+    monkeypatch.setattr(engine_mod, "ngram_propose", oracle)
     spec = InferenceEngine(
         cfg, cfgs.EngineConfig(**SWA_KW, num_speculative_tokens=3),
-        params=params, draft_cfg=draft_cfg, draft_params=draft_params)
-    assert not spec.swa_evict        # window-less draft reads full ctx
-    got = spec.generate(prompts, max_new_tokens=12)
-    assert got == want
+        params=params)
+    assert spec.swa_evict
+    assert spec.generate(prompts, max_new_tokens=40) == want
+    assert spec.spec_accepted > 40
+    assert spec.window_pages_released > 0
 
 
 def test_swa_admission_reserves_window_not_generation():

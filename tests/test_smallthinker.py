@@ -337,7 +337,7 @@ def test_grouped_kernels_with_relu_at_64_held_experts(pallas, rows):
 
 # ------------------------------------------------------------------ (d)
 REQ = dict(max_batch_size="auto", num_pages="auto", decode_ladder="auto",
-           target_ctx=0, batch_cap=32, speculative=False)
+           target_ctx=0, batch_cap=32)
 
 
 def test_the_window_pool_is_sized_on_live_tokens_under_the_window():
@@ -632,7 +632,7 @@ def test_validate_and_what_is_refused():
                      (dict(host_cache_pages=8), "host KV tier"),
                      (dict(quant="int4"), "quant='int4'"),
                      (dict(role="decode"), "role="),
-                     (dict(spec_mode="ngram", num_speculative_tokens=2),
+                     (dict(num_speculative_tokens=2),
                       "speculative")):
         with pytest.raises(ValueError, match=said):
             engine(m, **dict(kw, keep_logits=False))

@@ -479,7 +479,7 @@ def test_step_programs_have_stable_names():
         tiny_llama(512), EngineConfig(**ENGINE_KW, decode_steps_per_call=8,
                                       hybrid_prefill=True), seed=0)
     spec = InferenceEngine(
-        tiny_llama(512), EngineConfig(**ENGINE_KW, spec_mode="ngram",
+        tiny_llama(512), EngineConfig(**ENGINE_KW,
                                       num_speculative_tokens=3), seed=0)
     names = {
         "prefill": plain._prefill_jit.__name__,

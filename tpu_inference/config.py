@@ -898,30 +898,25 @@ class EngineConfig:
     top_k: int = 0                    # 0 => disabled
     top_p: float = 1.0
     max_new_tokens: int = 1024
-    # Speculative decoding (0 = off). γ = drafted tokens per round; each
-    # verified round emits 1..γ+1 tokens from ONE target forward.
+    # Speculative decoding (0 = off; README "Speculative decoding"). γ =
+    # proposed tokens per round; each verified round emits 1..γ+1 tokens
+    # from ONE target forward. Proposals are n-gram self-drafts (prompt
+    # lookup, Saxena 2023): the host matches the sequence's last tokens
+    # against its own prompt+generated history and proposes the
+    # continuation of the most recent match as one-hot drafts; the
+    # verify-only round keeps exact greedy argmax-match acceptance and
+    # distribution-exact sampled acceptance. No second model, no second
+    # KV pool, no extra HBM — so the decode ladder, host KV tier, SWA
+    # eviction and the repetition penalty all stay active.
     num_speculative_tokens: int = 0
-    # Proposal source (README "Speculative decoding"):
-    # - "draft": a separate draft model scans γ steps then the target
-    #   verifies (needs a trained draft + its own KV pool; the classic
-    #   Leviathan et al. 2023 arrangement).
-    # - "ngram": draft-free self-drafting (prompt lookup, Saxena 2023) —
-    #   the host matches the sequence's last tokens against its own
-    #   prompt+generated history and proposes the continuation of the
-    #   most recent match as one-hot drafts; the verify-only round keeps
-    #   exact greedy argmax-match acceptance and distribution-exact
-    #   sampled acceptance. No draft model, no draft KV, no extra HBM —
-    #   so the decode ladder, host KV tier, SWA eviction and the
-    #   repetition penalty all stay active (unlike "draft" mode).
-    spec_mode: str = "draft"
-    # ngram mode: longest suffix n-gram matched against the history
+    # Longest suffix n-gram matched against the history
     # (matching tries window..1 and takes the most recent match).
     ngram_window: int = 3
-    # ngram mode: per-sequence acceptance-rate EWMA update weight (a
+    # Per-sequence acceptance-rate EWMA update weight (a
     # fresh echo-free stream throttles after ~2 rejected rounds; an
     # echoic one un-throttles after ~1-2 accepted probe rounds).
     spec_ewma_alpha: float = 0.4
-    # ngram mode: a sequence whose acceptance EWMA falls below this is
+    # A sequence whose acceptance EWMA falls below this is
     # throttled to γ=0 (no proposals; rounds where NO slot proposes run
     # the plain fused-K decode graph instead) so speculation can never
     # lose on echo-free streams. At the defaults a fresh stream
@@ -929,7 +924,7 @@ class EngineConfig:
     # established echoic stream (EWMA near 1) tolerates transient
     # misses; un-throttling needs one clean probe. 0 disables.
     spec_throttle_below: float = 0.35
-    # ngram mode: a throttled sequence re-probes (one narrow γ=1 verify
+    # A throttled sequence re-probes (one narrow γ=1 verify
     # round) after this many rounds, so a stream that turns echoic
     # mid-generation can re-earn its γ. Consecutive failed probes back
     # off (doubling, capped at 8x) and the engine aligns every
@@ -1065,31 +1060,21 @@ class EngineConfig:
         return self.prefill_buckets[-1]
 
 
-def validate_spec_config(spec_mode: str, num_speculative_tokens: int,
-                         ngram_window: int,
-                         has_draft_model: bool) -> None:
-    """Speculative-decoding knob validation shared by the engine and the
-    CLIs (server + replay), so a bad combination fails as a usage error
-    before any weights load.
+def validate_spec_config(num_speculative_tokens: int,
+                         ngram_window: int) -> None:
+    """Bounds on the knobs of speculative decoding when it is on, shared
+    by the engine and the CLIs (server + replay), so a bad value fails
+    as a usage error before any weights load.
 
     Raises ValueError; messages mention the flag spelling so argparse
     surfaces actionable errors."""
-    if spec_mode not in ("draft", "ngram"):
-        raise ValueError(f"--spec-mode {spec_mode!r}: one of "
-                         "('draft', 'ngram')")
-    if spec_mode == "ngram" and has_draft_model:
+    if not (1 <= num_speculative_tokens <= 16):
         raise ValueError(
-            "--spec-mode ngram does not take --draft-model: n-gram "
-            "self-drafting proposes from the sequence's own history "
-            "(drop the draft model, or use --spec-mode draft)")
-    if num_speculative_tokens > 0 or spec_mode == "ngram":
-        if not (1 <= num_speculative_tokens <= 16):
-            raise ValueError(
-                f"--num-speculative-tokens {num_speculative_tokens}: "
-                "must be in [1, 16] when speculative decoding is on "
-                "(γ drafts verify in one γ+1-position forward; huge γ "
-                "only compiles wider graphs to reject more)")
-    if spec_mode == "ngram" and not (1 <= ngram_window <= 8):
+            f"--num-speculative-tokens {num_speculative_tokens}: "
+            "must be in [1, 16] when speculative decoding is on "
+            "(γ drafts verify in one γ+1-position forward; huge γ "
+            "only compiles wider graphs to reject more)")
+    if not (1 <= ngram_window <= 8):
         raise ValueError(
             f"--ngram-window {ngram_window}: must be in [1, 8] "
             "(longest suffix n-gram matched against the history)")

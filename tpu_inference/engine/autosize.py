@@ -202,7 +202,6 @@ def auto_size(model_cfg, *, hbm_bytes: float, quant: str = "none",
               target_ctx: Optional[int] = None, batch_cap: int = 32,
               reserve_frac: float = 0.15,
               activation_headroom: int = 512 << 20,
-              speculative: bool = False,
               window_span: int = 0, written_ahead: int = 0) -> AutoSizing:
     """Size ``max_batch_size`` and ``num_pages`` for the chip.
 
@@ -260,10 +259,7 @@ def auto_size(model_cfg, *, hbm_bytes: float, quant: str = "none",
                                               // 2)
     ctx = max(1, min(ctx, page_size * max_pages_per_seq))
     win = getattr(model_cfg, "sliding_window", 0)
-    if win and not speculative:
-        # (Only when eviction will actually run: spec decode disables it
-        # — a window-less draft reads the full context, so each running
-        # sequence keeps O(context) pages; see engine.swa_evict.)
+    if win:
         # Behind-window eviction (engine._evict_behind_window) caps a
         # running SWA sequence's live KV at ~window tokens — batch
         # sizes against that, not the full context. (The prefill peak
@@ -568,8 +564,7 @@ def sizing_request(args) -> dict:
            "num_pages": args.num_pages,
            "decode_ladder": getattr(args, "decode_ladder", "off"),
            "target_ctx": getattr(args, "target_ctx", 0),
-           "batch_cap": getattr(args, "batch_cap", 32),
-           "speculative": bool(getattr(args, "draft_model", None))}
+           "batch_cap": getattr(args, "batch_cap", 32)}
     if "auto" not in (req["max_batch_size"], req["num_pages"]):
         parse_decode_ladder(req["decode_ladder"], req["max_batch_size"])
     return req
@@ -591,7 +586,7 @@ def resolve_sizing(model_cfg, engine_cfg, req: Optional[dict], *,
             page_size=engine_cfg.page_size,
             max_pages_per_seq=engine_cfg.max_pages_per_seq,
             target_ctx=req["target_ctx"] or None,
-            batch_cap=req["batch_cap"], speculative=req["speculative"],
+            batch_cap=req["batch_cap"],
             window_span=window_span_pages(model_cfg, engine_cfg)
             if model_cfg.layer_types else 0,
             written_ahead=written_ahead_tokens(engine_cfg))

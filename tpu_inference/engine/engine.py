@@ -33,7 +33,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from tpu_inference import telemetry
-from tpu_inference.config import EngineConfig, ModelConfig
+from tpu_inference.config import (EngineConfig, ModelConfig,
+                                  validate_spec_config)
 from tpu_inference.engine import kv_cache as kvc
 from tpu_inference.engine import staging
 from tpu_inference.engine.kv_cache import KVPages, PageAllocator
@@ -483,14 +484,13 @@ def model_is(model_cfg: ModelConfig) -> Optional[str]:
 
 
 def _refuse_unsupported(model_cfg: ModelConfig, engine_cfg: EngineConfig,
-                        mesh, draft_cfg) -> None:
+                        mesh) -> None:
     """What a latent pool (the latent-attention / routed-expert family),
     a looped stack (``loop_steps`` > 1), a stack of mixed kinds
     (``layer_types``) or one with state-space layers does not run yet,
     said at construction and not at the first request."""
     what = []
-    spec = (engine_cfg.num_speculative_tokens > 0 or draft_cfg is not None
-            or engine_cfg.spec_mode != "draft")
+    spec = engine_cfg.num_speculative_tokens > 0
     if engine_cfg.keep_logits:
         # Whatever the model is: the programs that file rows are the
         # prefill and the fused-K decode.
@@ -517,8 +517,8 @@ def _refuse_unsupported(model_cfg: ModelConfig, engine_cfg: EngineConfig,
     if engine_cfg.kv_quant != "none":
         what.append(f"kv_quant={engine_cfg.kv_quant!r} ({why['kv_quant']})")
     if spec:
-        what.append("speculative decoding (draft or ngram"
-                    + (f": {why['spec']})" if why["spec"] else ")"))
+        what.append("speculative decoding"
+                    + (f" ({why['spec']})" if why["spec"] else ""))
     if engine_cfg.host_cache_pages:
         what.append(f"the host KV tier (host_cache_pages > 0: {why['host']})")
     if why["quant"] and engine_cfg.quant == "int4":
@@ -645,7 +645,7 @@ class Sequence:
     # counter; 0 = none yet): splits queue wait into waiting for the
     # running dispatch to come back and waiting for capacity.
     admit_seen_time: float = 0.0
-    # Adaptive-γ state for draft-free n-gram speculation (README
+    # Adaptive-γ state for n-gram speculation (README
     # "Speculative decoding"): current per-sequence γ (-1 = engine
     # default, 0 = throttled), EWMA acceptance rate, and the countdown
     # until a throttled sequence re-probes. Survives preemption /
@@ -677,7 +677,7 @@ class Sequence:
     # tracker skips its TTFT (the client's first token streamed from
     # the prefill worker, not here).
     adopted: bool = False
-    # Per-request speculative-round exposure (ngram/draft modes):
+    # Per-request speculative-round exposure:
     # rounds this sequence proposed in and positions accepted —
     # surfaced as attrs on the request's decode span so a trace shows
     # where speculation paid off without a span per round.
@@ -701,14 +701,12 @@ class InferenceEngine:
                  attn_backend: Optional[str] = None,
                  shard_fn: Optional[Callable[[dict], dict]] = None,
                  mesh: Optional[Any] = None,
-                 draft_cfg: Optional[ModelConfig] = None,
-                 draft_params: Optional[dict] = None,
                  pallas_interpret: bool = False):
         model_cfg.validate()
         t_boot = time.perf_counter()
         self.model_cfg = model_cfg
         self.engine_cfg = engine_cfg
-        _refuse_unsupported(model_cfg, engine_cfg, mesh, draft_cfg)
+        _refuse_unsupported(model_cfg, engine_cfg, mesh)
         self.mod = get_model_fns(model_cfg)
         # Resolve the attention backend: constructor arg wins, then
         # EngineConfig; "auto" = the Pallas paged kernels on a TPU, the
@@ -737,8 +735,6 @@ class InferenceEngine:
         if mesh is not None:
             from tpu_inference.parallel import shardings as _shd
             _shd.validate_tp(model_cfg, mesh.shape.get("tp", 1))
-            if draft_cfg is not None:
-                _shd.validate_tp(draft_cfg, mesh.shape.get("tp", 1))
         def maybe_quantize(p):
             # Weight-only int8: halves the per-step HBM weight read that
             # bounds decode throughput (BASELINE.md roofline). Runs on
@@ -957,38 +953,17 @@ class InferenceEngine:
         self._pressure_target: Optional[int] = None
         if engine_cfg.chaos_page_pressure > 0:
             self.set_page_pressure(engine_cfg.chaos_page_pressure)
-        # Speculative decoding modes (README "Speculative decoding"):
-        # "draft" = a separate draft model proposes (needs its own KV
-        # pool, so several compositions below are gated off); "ngram" =
-        # draft-free host-side self-drafting (prompt lookup) — no draft
-        # pool, no extra HBM, so the ladder, host tier, SWA eviction and
-        # the repetition penalty all stay active.
-        if engine_cfg.spec_mode not in ("draft", "ngram"):
-            raise ValueError(f"unknown spec_mode {engine_cfg.spec_mode!r}; "
-                             "one of ('draft', 'ngram')")
-        if engine_cfg.spec_mode == "ngram":
-            from tpu_inference.config import validate_spec_config
-            validate_spec_config("ngram", engine_cfg.num_speculative_tokens,
-                                 engine_cfg.ngram_window,
-                                 draft_cfg is not None)
-        spec_draft = (engine_cfg.spec_mode == "draft"
-                      and draft_cfg is not None
-                      and engine_cfg.num_speculative_tokens > 0)
-        spec_ngram = engine_cfg.spec_mode == "ngram"
-        spec_on = spec_draft or spec_ngram
-        self.spec_draft = spec_draft
-        self.spec_ngram = spec_ngram
-        self.spec_mode = "ngram" if spec_ngram else "draft"
+        # Speculative decoding (README "Speculative decoding") is n-gram
+        # verification, on when num_speculative_tokens > 0: the host
+        # proposes from each sequence's own history (prompt lookup) and
+        # one verify-only program accepts. No second model and no second
+        # pool, so the ladder, the host tier, SWA eviction and the
+        # repetition penalty all stay active.
+        self.spec_enabled = engine_cfg.num_speculative_tokens > 0
+        if self.spec_enabled:
+            validate_spec_config(engine_cfg.num_speculative_tokens,
+                                 engine_cfg.ngram_window)
         self.prefix_cache = None
-        # Prefix caching composes with speculative decoding because the
-        # draft pool is a strict positional twin of the target pool: both
-        # write the SAME input-token stream at the same block-table slots
-        # (prompt chunks via _draft_prefill_fn; decode rounds via
-        # spec_round, whose draft scan and target verify consume
-        # identical [last, d_0..d_{gamma-1}] inputs), and cache hits are
-        # full pages below ctx_len, where every row in BOTH pools is
-        # settled. Reusing a cached page therefore reuses a valid draft
-        # twin for free.
         # The window only binds when the serving context can exceed it
         # (ADVICE r4): with max_context <= window no query ever looks
         # back past the window, eviction would never free a page, and
@@ -1007,22 +982,12 @@ class InferenceEngine:
             # with holes would hand garbage KV to a shorter follow-up
             # request whose own window lands inside the evicted region.
             from tpu_inference.engine.prefix_cache import PrefixCache
-            if engine_cfg.host_cache_pages > 0 and not spec_draft:
+            if engine_cfg.host_cache_pages > 0:
                 # Host-RAM second tier: evicted pages demote instead of
-                # being dropped (README "Tiered KV cache"). Off under
-                # DRAFT-model speculative decoding: only the TARGET pool
-                # offloads, and a restored page with a stale draft twin
-                # would silently tank acceptance — the draft pool's
-                # positional twin invariant (below) only holds for pages
-                # both models wrote in lockstep. Draft-free ngram spec
-                # has no draft pool, so the tier stays live.
+                # being dropped (README "Tiered KV cache").
                 self.host_pool = kvc.HostPagePool(
                     engine_cfg.host_cache_pages)
                 self.telemetry.bind_host_pool(self.host_pool)
-            elif engine_cfg.host_cache_pages > 0:
-                print(f"[engine] {model_cfg.name}: host KV tier disabled "
-                      "— speculative decoding's draft pool has no host "
-                      "twin to restore")
             self.prefix_cache = PrefixCache(self.allocator,
                                             engine_cfg.page_size,
                                             host_pool=self.host_pool,
@@ -1069,16 +1034,6 @@ class InferenceEngine:
         from tpu_inference.engine.autosize import validate_ladder
         ladder = validate_ladder(engine_cfg.ladder_rungs,
                                  engine_cfg.max_batch_size)
-        if spec_draft and len(ladder) > 1:
-            # The draft-model spec round compiles one fused draft+verify
-            # graph at the full batch; rung-switching it would multiply
-            # compiles for a path the roadmap still calls a slowdown.
-            # Single rung. (ngram spec keeps the full ladder: its
-            # verify-only graph compiles per rung in warmup, like the
-            # plain decode graphs.)
-            print(f"[engine] {model_cfg.name}: draft-model speculative "
-                  "decoding — decode ladder collapsed to the top rung")
-            ladder = (engine_cfg.max_batch_size,)
         self.ladder = ladder
         self.decode_rung = ladder[0]      # rung of the latest dispatch
         self.rung_peak = ladder[0]        # highest rung reached
@@ -1180,37 +1135,24 @@ class InferenceEngine:
                             sp_mode=engine_cfg.sp_attn)),
                 donate_argnums=(1,))
 
-        # Speculative decoding (BASELINE.json config 4): a draft model with
-        # its own KV pool but the SAME page geometry + block tables, so one
-        # host-side ctx/page state serves both models.
-        self.spec_enabled = spec_on
+        # Speculation's accounting: positions proposed and accepted,
+        # verify rounds dispatched, rounds that degraded to the plain
+        # fused-K graph (no slot proposed), and per-sequence γ=0 throttle
+        # events (the adaptive-γ "spec never loses" lever).
         self.spec_drafted = 0
         self.spec_accepted = 0
-        # ngram-mode round accounting: verify rounds dispatched, rounds
-        # that degraded to the plain fused-K graph (no slot proposed),
-        # and per-sequence γ=0 throttle events (the adaptive-γ "spec
-        # never loses" lever).
         self.spec_rounds_total = 0
         self.spec_fallback_rounds = 0
         self.spec_throttles_total = 0
-        if spec_on:
-            self.telemetry.bind_spec(self)
         # Behind-window page eviction (SWA): a running sequence holds
-        # O(window) KV pages instead of O(context). Off under DRAFT-model
-        # spec decode — a window-less DRAFT model still attends to the
-        # full context, so the target's behind-window pages stay live
-        # (ngram spec has no draft; its verify queries sit at positions
-        # >= ctx, whose windows start at or after plain decode's, so
-        # eviction composes). Off when the window can't bind (swa_binds
-        # above): there would never be a behind-window page to free.
-        self.swa_evict = (swa_binds and self.prefix_cache is None
-                          and not spec_draft)
-        if swa_binds and spec_draft:
-            print(f"[engine] {model_cfg.name}: SWA + speculative decoding"
-                  " — behind-window eviction OFF (the window-less draft"
-                  " attends full context), so sequences hold O(context)"
-                  " KV pages, not O(window)")
-        if self.spec_ngram:
+        # O(window) KV pages instead of O(context). It composes with
+        # speculation: the verify queries sit at positions >= ctx, whose
+        # windows start at or after plain decode's. Off when the window
+        # can't bind (swa_binds above): there would never be a
+        # behind-window page to free.
+        self.swa_evict = swa_binds and self.prefix_cache is None
+        if self.spec_enabled:
+            self.telemetry.bind_spec(self)
             from tpu_inference.engine.speculative import verify_round
             self._verify_jit = jax.jit(
                 telemetry.named_program("tpu_inf_spec_verify",
@@ -1223,35 +1165,6 @@ class InferenceEngine:
             # is its own executable — all warmed in warmup().
             gamma = engine_cfg.num_speculative_tokens
             self._spec_widths = sorted({2, gamma + 1})
-        if self.spec_draft:
-            assert draft_cfg.vocab_size == model_cfg.vocab_size, \
-                "draft and target must share a tokenizer/vocab"
-            self.draft_cfg = draft_cfg
-            self.draft_mod = get_model_fns(draft_cfg)
-            if draft_params is None:
-                draft_params, _ = build_model(draft_cfg, seed=seed + 1)
-            draft_params = maybe_quantize(draft_params)
-            if mesh is not None:
-                # Draft weights get the same mesh treatment as the target
-                # (divisibility was fail-fast-checked above); the draft
-                # pool reuses the tp-sharded kv layout.
-                from tpu_inference.parallel import shardings as _shd
-                draft_params = _shd.shard_params(draft_params, draft_cfg,
-                                                 mesh)
-            self.draft_params, _ = store_transposed(draft_params,
-                                                    draft_cfg.family)
-            self.draft_kv = kvc.alloc_kv_pages(draft_cfg, engine_cfg,
-                                               sharding=kv_sh,
-                                               scale_sharding=kv_scale_sh)
-            from tpu_inference.engine.speculative import spec_round
-            self._spec_jit = jax.jit(
-                telemetry.named_program("tpu_inf_spec_round",
-                                        partial(spec_round, self)),
-                donate_argnums=(2, 3))
-            self._draft_prefill_jit = jax.jit(
-                telemetry.named_program("tpu_inf_draft_prefill",
-                                        self._draft_prefill_fn),
-                donate_argnums=(1,))
 
     # ------------------------------------------------------------------
     # Device graphs (pure functions of arrays; jitted once per bucket/batch)
@@ -1319,23 +1232,6 @@ class InferenceEngine:
         tok = sample(logits, key, sp, ctx=total_len, penalty_window=window,
                      repeat_penalty=rpen, repeat_last_n=rlast)
         return kv, tok, logits
-
-    def _draft_prefill_fn(self, draft_params, draft_kv: KVPages, tokens,
-                          prompt_len, prefix_len, block_table):
-        """Populate the draft model's KV for the prompt (no sampling).
-        Shapes mirror _prefill_fn; runs once per prefill chunk."""
-        cfg = self.draft_cfg
-        s = tokens.shape[1]
-        ar = jnp.arange(s)[None, :]
-        positions = prefix_len[:, None] + ar
-        valid = ar < prompt_len[:, None]
-        positions = jnp.minimum(positions, self.engine_cfg.max_context - 1)
-        attn = self._paged_attn(cfg, block_table, positions, valid,
-                                q_offset=prefix_len,
-                                kv_len=prefix_len + prompt_len)
-        _, draft_kv = self.draft_mod.forward_hidden(
-            draft_params, cfg, tokens, positions, draft_kv, attn)
-        return draft_kv
 
     def _decode_multi_fn(self, params, kv: KVPages, tokens, ctx_lens,
                          block_tables, allowed, eos_ids, key, temperature,
@@ -1608,14 +1504,6 @@ class InferenceEngine:
                         f"prefill_sp {shape}", self._prefill_sp_jit,
                         self.params, self.kv, self._base_key,
                         operand(layout, p), rows=(p * bucket,))
-                if self.spec_draft:
-                    self.draft_kv = run(
-                        f"draft_prefill {shape}",
-                        self._draft_prefill_jit, self.draft_params,
-                        self.draft_kv, jnp.zeros((p, bucket), jnp.int32),
-                        jnp.ones((p,), jnp.int32),
-                        jnp.zeros((p,), jnp.int32),
-                        jnp.zeros((p, self.bt_width), jnp.int32))
 
         def warm_carry(label, jitted, b, *operands, rows):
             """One decode-side graph at rung ``b``. Deeper than 1 the
@@ -1633,43 +1521,31 @@ class InferenceEngine:
 
         if not warm_decode:
             return self._warmup_done(t0, graphs, timeline)
-        if self.spec_draft:
-            b = ecfg.max_batch_size
-            out = run(
-                f"spec_round b={b}", self._spec_jit, self.params,
-                self.draft_params, self.kv, self.draft_kv,
-                jnp.zeros((b,), jnp.int32), jnp.zeros((b,), jnp.int32),
-                jnp.zeros((b, self.bt_width), jnp.int32),
-                jnp.zeros((b,), jnp.int32), jnp.zeros((b,), bool),
-                self._next_key(), jnp.zeros((b,), jnp.float32),
-                jnp.ones((b,), jnp.float32), jnp.zeros((b,), jnp.int32))
-            self.kv, self.draft_kv = out.kv, out.draft_kv
-        else:
-            # EVERY ladder rung compiles here: continuous batching moves
-            # between rung graphs as occupancy changes, and a rung first
-            # reached mid-serving must find its executable warm (the
-            # mid-serving-compile failure mode ADVICE r3 flagged).
-            for b in self.ladder:
-                self.kv = warm_carry(
-                    f"decode b={b}", self._decode_multi_jit, b,
-                    operand(self._decode_layout, b), rows=(b,))[0]
-                if self._decode_one_jit is not self._decode_multi_jit:
-                    # The 1-step graph is a second full decode compile,
-                    # but decode_step()/decode_steps(max_steps=1) route
-                    # to it regardless of latency mode — warm it whenever
-                    # it's a distinct graph or a first single-step call
-                    # pays a full XLA compile mid-serving (ADVICE r3).
-                    self.kv, _, _, _ = run(
-                        f"decode b={b}", self._decode_one_jit, self.params,
-                        self.kv, self._base_key,
-                        operand(self._decode_layout, b), rows=(b,))
-        if self.spec_ngram:
+        # EVERY ladder rung compiles here: continuous batching moves
+        # between rung graphs as occupancy changes, and a rung first
+        # reached mid-serving must find its executable warm (the
+        # mid-serving-compile failure mode ADVICE r3 flagged).
+        for b in self.ladder:
+            self.kv = warm_carry(
+                f"decode b={b}", self._decode_multi_jit, b,
+                operand(self._decode_layout, b), rows=(b,))[0]
+            if self._decode_one_jit is not self._decode_multi_jit:
+                # The 1-step graph is a second full decode compile,
+                # but decode_step()/decode_steps(max_steps=1) route
+                # to it regardless of latency mode — warm it whenever
+                # it's a distinct graph or a first single-step call
+                # pays a full XLA compile mid-serving (ADVICE r3).
+                self.kv, _, _, _ = run(
+                    f"decode b={b}", self._decode_one_jit, self.params,
+                    self.kv, self._base_key,
+                    operand(self._decode_layout, b), rows=(b,))
+        if self.spec_enabled:
             # The verify-only graph compiles at EVERY ladder rung x
             # EVERY active verify width (the full γ+1 round AND the
             # narrow probe round; per-sequence adaptive γ below the
             # width lives in n_prop masking, never a new shape). The
-            # γ=0 fallback rounds run the plain decode graphs warmed in
-            # the else-branch above — between the three, no ngram-spec
+            # γ=0 fallback rounds run the plain decode graphs warmed
+            # above — between the three, no speculating
             # dispatch can meet a cold executable mid-serving (the
             # test_ladder.py zero-compile pin, extended).
             for b in self.ladder:
@@ -2743,8 +2619,8 @@ class InferenceEngine:
         packed = layout.blank(1)
         st = layout.views(packed)
         self._fill_prefill_lane(st, 0, seq, chunk, offset)
-        # The fields stay readable by name (the draft's prefill, the
-        # ledger); the program gets ``packed``, which they are views of.
+        # The fields stay readable by name (the ledger); the program
+        # gets ``packed``, which they are views of.
         st.update(seq=seq, prompt=prompt, chunk_tokens=len(chunk),
                   bucket=bucket, packed=packed)
         return st
@@ -2783,13 +2659,6 @@ class InferenceEngine:
             "prefill_chunk", prefill, args, slots=1, chunk_tokens=c)
         if self.keep_logits:
             tok = (tok, lg)      # read together at the final chunk
-        if self.spec_draft:
-            # Mirror the chunk into the draft model's KV (same pages).
-            self.draft_kv = self._draft_prefill_jit(
-                self.draft_params, self.draft_kv,
-                jnp.asarray(st["tokens"]), jnp.asarray(st["prompt_len"]),
-                jnp.asarray(st["prefix_len"]),
-                jnp.asarray(st["block_table"]))
         if self.telemetry.enabled:
             dt = t1 - t0
             self.telemetry.prefill_dispatch_s.observe(dt)
@@ -2919,11 +2788,6 @@ class InferenceEngine:
         (self.kv, tok, lg), dseq, t0, _ = self._run(
             "prefill_chunk", prefill, args, slots=n, tokens=n,
             chunk_tokens=chunk_tokens)
-        if self.spec_draft:
-            self.draft_kv = self._draft_prefill_jit(
-                self.draft_params, self.draft_kv, jnp.asarray(f["tokens"]),
-                jnp.asarray(plen), jnp.asarray(pref),
-                jnp.asarray(f["block_table"]))
         toks_out, _, t_done = self._wait(dseq, lambda: np.asarray(tok))
         if self.telemetry.enabled:
             dt = t_done - t0                 # includes the token readback
@@ -3216,16 +3080,10 @@ class InferenceEngine:
     def _penalty_arrays(self, seq: Sequence):
         """(repeat_penalty, repeat_last_n) with Ollama conventions:
         last_n < 0 means 'whole context' (clamped to the static window),
-        0 disables. Under DRAFT-model speculative decoding the penalty is
-        ignored ENTIRELY (prefill included) — the q/p acceptance ratio
-        needs the draft and target distributions unmodified, and a
-        first-token-only penalty would be a silent half-application.
-        ngram spec composes: proposals are one-hot (no p to corrupt), and
+        0 disables. Speculation composes: proposals are one-hot, and
         verify_round penalizes each position's target distribution
         against the window rolled with its accepted prefix — exactly the
         sequential plain-decode behavior."""
-        if self.spec_draft:
-            return 1.0, 0
         rlast = int(seq.repeat_last_n)
         if rlast < 0:
             rlast = PENALTY_WINDOW
@@ -3450,8 +3308,6 @@ class InferenceEngine:
         """
         self._chaos_step_gate()
         result = self.drain_pipeline()
-        if self.spec_draft:
-            return _extend(result, self._spec_decode_steps(max_steps))
         return _extend(result, self._round(1, max_steps=max_steps))
 
     def decode_steps_pipelined(self, prefill_seq: Optional[Sequence] = None
@@ -3473,8 +3329,6 @@ class InferenceEngine:
         """
         assert prefill_seq is None or not self.spec_enabled, \
             "hybrid steps don't compose with speculative decoding"
-        if self.spec_draft:
-            return self.decode_steps()         # gate runs inside
         if (self.admission == "optimistic" and self.under_pressure
                 and (prefill_seq is None or self.active_sequences())):
             # Watermark pressure settles first: in-flight calls hold
@@ -3501,13 +3355,13 @@ class InferenceEngine:
         flight once there are ``depth`` of them, or when nothing could
         be staged."""
         result: Dict[int, List[int]] = {}
-        if self.spec_ngram or self._pipeline_rung_blocked():
+        if self.spec_enabled or self._pipeline_rung_blocked():
             # Proposals need the previous round's accepted tokens (spec
             # rounds cannot chain blind like plain decode carries), and
             # a batch that outgrew the in-flight rung settles, then
             # grows.
             result = self.drain_pipeline()
-        if self.spec_ngram:
+        if self.spec_enabled:
             call = self._stage_ngram_call(max_steps)
         else:
             call = self._stage_decode_call(prefill_seq, max_steps)
@@ -3932,8 +3786,8 @@ class InferenceEngine:
     def _spec_grant(self, active_seqs: List[Sequence], s_len: int,
                     max_steps: Optional[int]) -> Tuple[List[Sequence],
                                                        Dict[int, int]]:
-        """Per-slot emission caps + page grants for one spec round
-        (draft or ngram): the device writes KV for up to ``s_len``
+        """Per-slot emission caps + page grants for one spec round:
+        the device writes KV for up to ``s_len``
         positions, so provision pages for what fits and clamp emissions
         to written capacity. Prefix-cache-held pages are reclaimable
         capacity here just as in _grant_decode_steps — counting only the
@@ -3973,113 +3827,15 @@ class InferenceEngine:
         return ([s for s in active_seqs if not s.done and s.slot >= 0],
                 emit_by_slot)
 
-    def _spec_decode_steps(self, max_steps: Optional[int] = None
-                           ) -> Dict[int, List[int]]:
-        """One speculative round: draft proposes gamma tokens, target
-        verifies them in a single forward, rejection sampling keeps an
-        exact-distribution prefix. Emits 1..gamma+1 tokens per sequence.
-
-        No KV rollback on rejection: host ctx_len only advances over kept
-        tokens and attention masks the cache by kv_len, so rejected
-        positions are dead rows that later writes overwrite."""
-        ecfg = self.engine_cfg
-        gamma = ecfg.num_speculative_tokens
-        s_len = gamma + 1
-        active_seqs = self.active_sequences()
-        if not active_seqs:
-            return {}
-        active_seqs = self._preempt_for_pressure(active_seqs, s_len)
-        active_seqs, emit_by_slot = self._spec_grant(active_seqs, s_len,
-                                                     max_steps)
-        if not active_seqs:
-            return {}
-
-        b = ecfg.max_batch_size       # draft spec runs single-rung (top)
-        # Seeds and repetition penalties are not plumbed into spec rounds
-        # (rejection sampling needs the unmodified target distribution).
-        clock = self.telemetry.clock
-        clock.enter("stage")
-        _, f = self._stage_batch(active_seqs, b)
-        cap = np.zeros((b,), np.int32)
-        active = np.zeros((b,), bool)
-        for seq in active_seqs:
-            cap[seq.slot] = len(seq.pages) * ecfg.page_size
-            active[seq.slot] = True
-
-        # Per-request seeds are not plumbed into spec rounds (the rejection
-        # sampler consumes randomness at a data-dependent rate, so a
-        # position-keyed stream would not reproduce anyway); spec uses the
-        # engine-global key.
-        clock.part("put")
-        out, dseq, t0, _ = self._run_decode(
-            "spec_verify", self._spec_jit,
-            (self.params, self.draft_params, self.kv, self.draft_kv,
-             jnp.asarray(f["tokens"]), jnp.asarray(f["ctx"]),
-             jnp.asarray(f["bts"]), jnp.asarray(cap), jnp.asarray(active),
-             self._next_key(), jnp.asarray(f["temps"]),
-             jnp.asarray(f["top_ps"]), jnp.asarray(f["top_ks"])),
-            rung=b, slots=len(active_seqs),
-            tokens=s_len * len(active_seqs))
-        self.kv, self.draft_kv = out.kv, out.draft_kv
-        (emitted, n_acc), t_wait, t_done = self._wait(dseq, lambda: (
-            np.asarray(out.emitted),                        # [B, gamma+1]
-            np.asarray(out.n_accepted)))
-        self.telemetry.decode_sync_s.observe(t_done - t_wait)
-        self._decode_streak(t_done)
-        # Pre-fold context: the verify forward read the cache at the ctx
-        # the lanes ENTERED the round with.
-        kv_read = sum(s.ctx_len for s in active_seqs) * s_len
-        acc0 = self.spec_accepted
-
-        result: Dict[int, List[int]] = {}
-        for seq in active_seqs:
-            got: List[int] = []
-            for j in range(s_len):
-                if seq.done or len(got) >= emit_by_slot[seq.slot]:
-                    break
-                tok = int(emitted[seq.slot, j])
-                if tok < 0:
-                    break
-                seq.ctx_len += 1
-                seq.generated.append(tok)
-                if seq.first_token_time == 0.0:
-                    seq.first_token_time = time.perf_counter()
-                self._maybe_finish(seq, tok)
-                got.append(tok)
-            # Acceptance-rate accounting: count only draft positions the
-            # host could actually emit (emit_cap can truncate a round when
-            # budget/context run out), and clamp accepted to that window —
-            # otherwise capped rounds overcount and the rate drifts.
-            drafted = min(gamma, emit_by_slot[seq.slot])
-            accepted = min(int(n_acc[seq.slot]), drafted)
-            self.spec_drafted += drafted
-            self.spec_accepted += accepted
-            if drafted > 0:
-                self.telemetry.spec_accept_rate.observe(accepted / drafted)
-                # Per-request spec exposure for the decode trace span.
-                seq.spec_rounds += 1
-                seq.spec_accepted_toks += accepted
-            if got:
-                result[seq.request_id] = got
-        if self.telemetry.enabled:
-            n_toks = sum(len(t) for t in result.values())
-            self.telemetry.tokens_per_dispatch.observe(n_toks)
-            self._ledger_push(
-                "spec_verify", rung=b, slots=len(active_seqs),
-                tokens=n_toks, device_s=t_done - t0, kv_read=kv_read,
-                spec_accepted=self.spec_accepted - acc0,
-                seq=dseq, t_enqueue=t0, t_done=t_done)
-        return result
-
     # ------------------------------------------------------------------
-    # Draft-free n-gram speculation (spec_mode="ngram"; README
-    # "Speculative decoding"). The host proposes continuations by suffix-
-    # matching each sequence's own prompt+generated history (cheap numpy
-    # in the host bubble), and a verify-only round scores γ+1 positions
-    # in ONE target forward — every accepted token is a decode step the
-    # chip never ran sequentially. Per-sequence EWMA acceptance throttles
-    # cold streams to γ=0; rounds where nothing proposes run the plain
-    # fused-K graph, so speculation can never lose.
+    # N-gram speculation (README "Speculative decoding"). The host
+    # proposes continuations by suffix-matching each sequence's own
+    # prompt+generated history (cheap numpy in the host bubble), and a
+    # verify-only round scores γ+1 positions in ONE target forward — every
+    # accepted token is a decode step the chip never ran sequentially.
+    # Per-sequence EWMA acceptance throttles cold streams to γ=0; rounds
+    # where nothing proposes run the plain fused-K graph, so speculation
+    # can never lose.
     # ------------------------------------------------------------------
 
     def _seq_spec_gamma(self, seq: Sequence) -> int:
@@ -4289,9 +4045,10 @@ class InferenceEngine:
                     seq.first_token_time = time.perf_counter()
                 self._maybe_finish(seq, tok)
                 got.append(tok)
-            # Same clamped accounting as the draft path: only positions
-            # the host could emit count as drafted, and accepted clamps
-            # to that window, so capped rounds can't drift the rate.
+            # Only positions the host could emit count as drafted (the
+            # emit cap can cut a round short when the budget or the
+            # context runs out), and accepted clamps to that window, so
+            # capped rounds can't drift the rate.
             drafted = min(prop_by_slot.get(slot, 0),
                           emit_by_slot.get(slot, 0))
             accepted = min(int(n_acc[slot]), drafted)
@@ -4304,14 +4061,14 @@ class InferenceEngine:
 
     def _stage_ngram_call(self, max_steps: Optional[int] = None
                           ) -> Optional[dict]:
-        """Stage one draft-free spec round (non-blocking): propose (host
+        """Stage one spec round (non-blocking): propose (host
         numpy), then enqueue the verify-accept forward at the current
         ladder rung and width. The call enters ``_inflight`` like a
         plain decode call, so at depth > 1 the host overlaps its device
         time with scheduler work and the NEXT round's n-gram matching.
         Rounds where NO slot proposes — cold streams, throttled streams,
         no history echo — stage the plain fused-K round instead, so
-        ngram spec is never slower than plain decode. The pipeline is
+        speculation is never slower than plain decode. The pipeline is
         empty here (_round drains first)."""
         ecfg = self.engine_cfg
         s_len = ecfg.num_speculative_tokens + 1

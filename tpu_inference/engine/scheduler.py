@@ -166,14 +166,13 @@ class SchedulerStats:
         if engine.spec_enabled:
             d, a = engine.spec_drafted, engine.spec_accepted
             out["speculative"] = {
-                # Proposal source + configured γ (README "Speculative
-                # decoding"): "ngram" = draft-free self-drafting with
-                # adaptive per-sequence γ; "draft" = draft-model rounds.
-                "mode": engine.spec_mode,
+                # The one proposal source (tests and the replay harness
+                # read the key) + configured γ.
+                "mode": "ngram",
                 "gamma": engine.engine_cfg.num_speculative_tokens,
                 "drafted": d, "accepted": a,
                 "acceptance_rate": (a / d) if d else 0.0,
-                # ngram-mode round mix: verify rounds vs plain-decode
+                # Round mix: verify rounds vs plain-decode
                 # fallbacks (no lane proposed), and γ=0 throttle events.
                 "rounds": engine.spec_rounds_total,
                 "fallback_rounds": engine.spec_fallback_rounds,

@@ -1,48 +1,37 @@
 """Speculative decoding: propose, target-model verify, exact acceptance.
 
-Two proposal sources share the verify/accept machinery:
+**N-gram self-drafting** (``ngram_propose`` + ``verify_round``):
+prompt-lookup decoding (Saxena 2023) — the host matches the sequence's
+last N tokens against its own prompt+generated history and proposes the
+continuation of the most recent match. No second model, no second KV
+pool, no extra HBM; proposals are one-hot distributions, so greedy
+acceptance degenerates to exact argmax match and sampled acceptance
+stays distribution-exact (with p one-hot at d_i: accept iff
+u < q_i(d_i); the rejection residual norm(max(q-p,0)) is q with d_i
+zeroed, renormalized).
 
-- **Draft model** (``spec_mode="draft"``, ``spec_round``): a small model
-  scans γ sequential steps, then the target verifies all γ+1 positions
-  in one forward — classic Leviathan et al. 2023.
-- **N-gram self-drafting** (``spec_mode="ngram"``, ``ngram_propose`` +
-  ``verify_round``): prompt-lookup decoding (Saxena 2023) — the host
-  matches the sequence's last N tokens against its own prompt+generated
-  history and proposes the continuation of the most recent match. No
-  draft model, no draft KV pool, no extra HBM; proposals are one-hot
-  distributions, so greedy acceptance degenerates to exact argmax match
-  and sampled acceptance stays distribution-exact (with p one-hot at
-  d_i: accept iff u < q_i(d_i); the rejection residual norm(max(q-p,0))
-  is q with d_i zeroed, renormalized).
+One spec round per device dispatch, all static shapes (SURVEY.md §7
+hard part 6 — "variable acceptance lengths vs static shapes"):
 
-One spec round per device dispatch (BASELINE.json config 4), all static
-shapes (SURVEY.md §7 hard part 6 — "variable acceptance lengths vs
-static shapes"):
-
-1. **Draft phase** — the small draft model runs ``gamma`` sequential
-   decode steps under ``lax.scan``, proposing d_1..d_gamma per slot and
-   recording its full probability rows (needed for exact rejection
-   sampling).
-2. **Verify phase** — the target model scores all gamma+1 positions in
-   ONE forward: inputs [last, d_1..d_gamma] at positions ctx..ctx+gamma.
+1. **Propose** — on the host, between dispatches: up to gamma tokens
+   d_1..d_gamma per slot.
+2. **Verify** — the target model scores all gamma+1 positions in ONE
+   forward: inputs [last, d_1..d_gamma] at positions ctx..ctx+gamma.
    This turns gamma sequential target steps into one MXU-friendly
    batched-matmul pass — the entire speedup.
-3. **Accept phase** — standard rejection sampling (greedy degenerates to
-   exact argmax match): accept d_i with prob min(1, q_i(d_i)/p_i(d_i));
-   on first rejection emit a correction drawn from norm(max(q_i - p_i,
-   0)); if all accepted, emit a bonus token from q_{gamma+1}.
+3. **Accept** — standard rejection sampling against one-hot proposals:
+   accept d_i with prob q_i(d_i); on first rejection emit a correction
+   drawn from q_i with d_i zeroed; if all accepted, emit a bonus token
+   from q_{gamma+1}.
 
 Variable acceptance needs NO KV rollback in this engine: attention masks
 the cache by per-sequence ``kv_len`` (= host ctx_len), so KV rows written
-for rejected drafts are simply never attended to and get overwritten when
-real tokens reach those positions. Draft and target share block tables
-(the draft pool has identical page geometry), so the host tracks one
-ctx per sequence for both models.
+for rejected proposals are simply never attended to and get overwritten
+when real tokens reach those positions.
 
-Sampling filters (temperature, top-k, top-p) are applied to BOTH the
-draft and target distributions before the q/p acceptance ratio, so spec
-mode samples from exactly the same filtered distribution as the plain
-decode path.
+Sampling filters (temperature, top-k, top-p) are applied to the target
+distribution before the acceptance test, so speculation samples from
+exactly the same filtered distribution as the plain decode path.
 """
 
 from __future__ import annotations
@@ -52,13 +41,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-
-class SpecRoundOut(NamedTuple):
-    kv: object               # target KVPages
-    draft_kv: object         # draft KVPages
-    emitted: jax.Array       # [B, gamma+1] int32, -1 padded
-    n_accepted: jax.Array    # [B] int32 (drafts accepted, excl. bonus)
 
 
 class VerifyRoundOut(NamedTuple):
@@ -134,104 +116,11 @@ def _sample_from(probs: jax.Array, key: jax.Array) -> jax.Array:
                                   ).astype(jnp.int32)
 
 
-def spec_round(engine, params, draft_params, kv, draft_kv, tokens, ctx_lens,
-               block_tables, cap, active, key, temperature, top_p, top_k):
-    """One propose/verify/accept round. Pure function of arrays; jitted by
-    the engine with both KV pools donated.
-
-    tokens [B] last sampled (unwritten) token; ctx_lens [B]; cap [B] =
-    provisioned token capacity per slot (writes at positions >= cap go to
-    the trash page); active [B] bool. Returns SpecRoundOut.
-    """
-    from tpu_inference.engine.engine import make_paged_attn
-
-    ecfg = engine.engine_cfg
-    gamma = ecfg.num_speculative_tokens
-    b = tokens.shape[0]
-    vocab = engine.model_cfg.vocab_size
-
-    # ---------------------------------------------------------- draft
-    def draft_step(carry, s):
-        dkv, tok, ctx = carry
-        positions = jnp.minimum(ctx, ecfg.max_context - 1)[:, None]
-        valid = active[:, None] & (positions < cap[:, None])
-        attn = make_paged_attn(engine.draft_cfg, ecfg.page_size,
-                               block_tables, positions, valid,
-                               q_offset=ctx, kv_len=ctx + 1)
-        hidden, dkv = engine.draft_mod.forward_hidden(
-            draft_params, engine.draft_cfg, tok[:, None], positions, dkv,
-            attn)
-        logits = engine.draft_mod.unembed(draft_params, engine.draft_cfg,
-                                          hidden[:, 0])
-        p_row = _probs(logits, temperature, top_p, top_k)       # [B, V]
-        d = _sample_from(p_row, jax.random.fold_in(key, s))
-        return (dkv, d, ctx + 1), (d, p_row)
-
-    # gamma+1 steps: the extra step's *write* (input d_gamma at position
-    # ctx+gamma) is what matters — on a full accept that row becomes part
-    # of the permanent context and no later step revisits it; skipping it
-    # would leave a stale draft-KV row degrading acceptance forever after.
-    # Its sampled token/probs are discarded.
-    (draft_kv, _, _), (drafts, p_rows) = jax.lax.scan(
-        draft_step, (draft_kv, tokens, ctx_lens),
-        jnp.arange(gamma + 1, dtype=jnp.int32))
-    drafts = drafts.T[:, :gamma]                              # [B, gamma]
-    p_rows = p_rows.transpose(1, 0, 2)[:, :gamma]             # [B, gamma, V]
-
-    # ---------------------------------------------------------- verify
-    s_len = gamma + 1
-    tokens_in = jnp.concatenate([tokens[:, None], drafts], axis=1)
-    ar = jnp.arange(s_len, dtype=jnp.int32)[None, :]
-    positions = jnp.minimum(ctx_lens[:, None] + ar, ecfg.max_context - 1)
-    valid = active[:, None] & (positions < cap[:, None])
-    attn = make_paged_attn(engine.model_cfg, ecfg.page_size, block_tables,
-                           positions, valid, q_offset=ctx_lens,
-                           kv_len=ctx_lens + s_len)
-    hidden, kv = engine.mod.forward_hidden(params, engine.model_cfg,
-                                           tokens_in, positions, kv, attn)
-    logits_all = engine.mod.unembed(params, engine.model_cfg, hidden)
-    q_rows = jax.vmap(_probs, in_axes=(1, None, None, None), out_axes=1)(
-        logits_all, temperature, top_p, top_k)                # [B, g+1, V]
-
-    # ---------------------------------------------------------- accept
-    d_idx = drafts[..., None]                                 # [B, g, 1]
-    q_d = jnp.take_along_axis(q_rows[:, :gamma], d_idx, -1)[..., 0]
-    p_d = jnp.take_along_axis(p_rows, d_idx, -1)[..., 0]      # [B, g]
-    u = jax.random.uniform(jax.random.fold_in(key, 7919), (b, gamma))
-    accept = u < q_d / jnp.maximum(p_d, 1e-30)                # [B, g]
-    acc_prefix = jnp.cumprod(accept.astype(jnp.int32), axis=1)
-    n_acc = jnp.sum(acc_prefix, axis=1)                       # [B] 0..g
-
-    # Correction dist at the first rejected row; bonus row when n_acc==g.
-    row = jax.vmap(lambda q, i: q[i])(q_rows, n_acc)          # [B, V]
-    p_row_at = jax.vmap(lambda p, i: p[jnp.minimum(i, gamma - 1)])(
-        p_rows, n_acc)
-    resid = jnp.maximum(row - p_row_at, 0.0)
-    resid_sum = jnp.sum(resid, axis=-1, keepdims=True)
-    # Degenerate residual (q==p, e.g. both greedy-one-hot on the same
-    # token can't be rejected, but guard anyway) falls back to q.
-    corr_dist = jnp.where(resid_sum > 1e-12, resid / (resid_sum + 1e-30),
-                          row)
-    final_dist = jnp.where((n_acc == gamma)[:, None], row, corr_dist)
-    final_tok = _sample_from(final_dist, jax.random.fold_in(key, 104729))
-
-    # emitted[b] = accepted drafts ++ [final_tok] ++ -1 padding.
-    slot_idx = jnp.arange(s_len, dtype=jnp.int32)[None, :]    # [1, g+1]
-    drafts_pad = jnp.concatenate(
-        [drafts, jnp.zeros((b, 1), jnp.int32)], axis=1)
-    emitted = jnp.where(slot_idx < n_acc[:, None], drafts_pad, -1)
-    emitted = jnp.where(slot_idx == n_acc[:, None], final_tok[:, None],
-                        emitted)
-    emitted = jnp.where(active[:, None], emitted, -1)
-    return SpecRoundOut(kv=kv, draft_kv=draft_kv, emitted=emitted,
-                        n_accepted=jnp.where(active, n_acc, 0))
-
-
 def verify_round(engine, params, kv, tokens, ctx_lens, block_tables, cap,
                  active, drafts, n_prop, key, temperature, top_p, top_k,
                  rpen, rlast, window):
     """Verify-only spec round for host-proposed (one-hot) drafts — the
-    ``spec_mode="ngram"`` device graph. Pure function of arrays; jitted
+    device graph of speculation. Pure function of arrays; jitted
     by the engine with the KV pool donated, compiled once per ladder
     rung (the batch dim B is the rung; γ+1 is static).
 
@@ -244,14 +133,14 @@ def verify_round(engine, params, kv, tokens, ctx_lens, block_tables, cap,
     (accept with prob q_i(d_i); the correction draws from
     norm(max(q_i - onehot(d_i), 0)) = q_i with d_i zeroed).
 
-    Unlike the draft-model round, the repetition penalty COMPOSES here:
-    position i's target distribution is penalized against the window
-    rolled forward with d_1..d_i — exactly the window the sequential
-    plain-decode path would hold if those drafts were its samples, and
-    position i's row is only ever consumed when they were all accepted.
+    The repetition penalty COMPOSES here: position i's target
+    distribution is penalized against the window rolled forward with
+    d_1..d_i — exactly the window the sequential plain-decode path would
+    hold if those drafts were its samples, and position i's row is only
+    ever consumed when they were all accepted.
 
-    Same no-rollback contract as ``spec_round``: rejected/padded rows
-    are dead KV (kv_len masking) and get overwritten by real tokens.
+    No rollback: rejected/padded rows are dead KV (kv_len masking) and
+    get overwritten by real tokens.
     Returns VerifyRoundOut; with n_prop==0 a round degenerates to one
     plain decode step (one forward, one emitted token).
     """

@@ -193,22 +193,13 @@ def main() -> None:
                         "the context a same-sized pool holds; int4 "
                         "nibble-packs (quarter traffic, lossier — int8 "
                         "is the accuracy-safe tier)")
-    p.add_argument("--spec-mode", default="auto",
-                   choices=("auto", "off", "draft", "ngram"),
-                   help="speculative decoding proposal source: 'ngram' "
-                        "= draft-free self-drafting (prompt lookup "
-                        "against each sequence's own history; no draft "
-                        "model, no extra HBM; composes with the decode "
-                        "ladder, host KV tier and repeat_penalty); "
-                        "'draft' = a separate draft model "
-                        "(--draft-model); 'auto' = draft when "
-                        "--draft-model is given, else off")
-    p.add_argument("--draft-model", default=None,
-                   help="enable draft-model speculative decoding with "
-                        "this draft preset or HF checkpoint dir")
-    p.add_argument("--draft-checkpoint", default=None,
-                   help="HF safetensors dir for the draft model (required "
-                        "when --checkpoint is set)")
+    p.add_argument("--spec-mode", default="off",
+                   choices=("off", "ngram"),
+                   help="speculative decoding: 'ngram' = self-drafting "
+                        "(prompt lookup against each sequence's own "
+                        "history; no second model, no extra HBM; "
+                        "composes with the decode ladder, host KV tier "
+                        "and repeat_penalty)")
     p.add_argument("--num-speculative-tokens", type=int, default=4,
                    help="speculation depth γ: proposed tokens verified "
                         "per round (each round emits 1..γ+1 tokens from "
@@ -486,25 +477,12 @@ def main() -> None:
 
     from tpu_inference.config import validate_spec_config
 
-    spec_mode = args.spec_mode
-    if spec_mode == "auto":
-        spec_mode = "draft" if args.draft_model else "off"
-    if spec_mode == "draft" and not args.draft_model:
-        p.error("--spec-mode draft requires --draft-model")
-    if spec_mode == "off" and args.draft_model:
-        p.error("--spec-mode off conflicts with --draft-model "
-                "(drop one)")
-    if spec_mode != "off":
+    if args.spec_mode != "off":
         try:
-            validate_spec_config(spec_mode, args.num_speculative_tokens,
-                                 args.ngram_window,
-                                 has_draft_model=bool(args.draft_model))
+            validate_spec_config(args.num_speculative_tokens,
+                                 args.ngram_window)
         except ValueError as e:
             p.error(str(e))
-    if args.fleet == "subprocess" and args.draft_model:
-        p.error("--fleet subprocess does not support --draft-model "
-                "(workers boot their own params; use --spec-mode ngram "
-                "or the in-process fleet)")
     if args.autoscale and args.fleet != "subprocess":
         p.error("--autoscale needs --fleet subprocess (scaling spawns "
                 "and drains worker processes)")
@@ -582,8 +560,6 @@ def main() -> None:
                           checkpoint=args.checkpoint,
                           warmup=not args.no_warmup, tp=args.tp, sp=args.sp,
                           dp=args.dp,
-                          draft_model=args.draft_model,
-                          draft_checkpoint=args.draft_checkpoint,
                           enable_debug=args.debug,
                           server_overrides=dict(
                               routing=args.routing,
@@ -672,12 +648,10 @@ def main() -> None:
                           chunked_prefill_size=args.chunked_prefill_size,
                           hybrid_prefill=args.hybrid_prefill,
                           step_token_budget=args.step_token_budget,
-                          spec_mode=("ngram" if spec_mode == "ngram"
-                                     else "draft"),
                           ngram_window=args.ngram_window,
                           num_speculative_tokens=(
                               args.num_speculative_tokens
-                              if spec_mode != "off" else 0))
+                              if args.spec_mode != "off" else 0))
     if args.check_numerics:
         if args.fleet == "subprocess":
             p.error("--check-numerics needs the in-process fleet "

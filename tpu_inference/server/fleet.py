@@ -371,7 +371,6 @@ class _EngineInfo:
         self.ladder = tuple(hello.get("ladder") or (1,))
         self.swa_evict = hello.get("swa_evict", False)
         self.prefix_cache = True if hello.get("prefix_cache") else None
-        self.spec_draft = hello.get("spec_draft", False)
         self.host_pool = None
         self._device = hello.get("device") or {}
 
@@ -912,7 +911,7 @@ class ProcessEngineGroup:
             telemetry.emit_build_info(
                 self._fleet_registry, device=self.engine.device_info(),
                 fleet="subprocess", kv_quant=self.engine_cfg.kv_quant,
-                spec_mode=(self.engine_cfg.spec_mode
+                spec_mode=("ngram"
                            if self.engine_cfg.num_speculative_tokens > 0
                            else "off"),
                 routing=self.server_cfg.routing)

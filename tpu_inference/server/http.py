@@ -105,7 +105,6 @@ class DeliveryOutbox:
 
 
 def build_engine_group(cfg: FrameworkConfig, load_params=None,
-                       draft_cfg=None, load_draft=None,
                        platform: Optional[str] = None,
                        sizing: Optional[dict] = None) -> "EngineGroup":
     """Construct the dp replica fleet for a FrameworkConfig.
@@ -115,10 +114,10 @@ def build_engine_group(cfg: FrameworkConfig, load_params=None,
     (dp=1: one engine over the whole (tp, sp) mesh; dp>1: each replica
     its own tp*sp-device submesh, KV pool and scheduler thread);
     "subprocess" returns a ProcessEngineGroup router that spawns one
-    engine-worker OS process per replica at start(). ``load_params``/
-    ``load_draft`` are callables (mesh | None) -> params so checkpoints
-    stream into each replica's own device layout (in-process only —
-    workers load their own checkpoints from cfg.checkpoint_path).
+    engine-worker OS process per replica at start(). ``load_params`` is
+    a callable (mesh | None) -> params so a checkpoint streams into
+    each replica's own device layout (in-process only — workers load
+    their own checkpoints from cfg.checkpoint_path).
 
     ``platform`` (the CLI's --platform) and ``sizing``
     (autosize.sizing_request) are settled by whichever process owns the
@@ -129,11 +128,6 @@ def build_engine_group(cfg: FrameworkConfig, load_params=None,
     from tpu_inference.server.replicas import EngineGroup
 
     if cfg.server.fleet == "subprocess":
-        if draft_cfg is not None:
-            raise ValueError(
-                "--fleet subprocess does not support draft-model "
-                "speculative decoding yet (the worker boots its own "
-                "params; use spec_mode='ngram' or the in-process fleet)")
         from tpu_inference.server.fleet import ProcessEngineGroup
         return ProcessEngineGroup(cfg, platform=platform, sizing=sizing)
     if cfg.server.fleet != "in-process":
@@ -172,12 +166,9 @@ def build_engine_group(cfg: FrameworkConfig, load_params=None,
     for mesh in meshes:
         t_load = time.perf_counter()
         params = load_params(mesh) if load_params else None
-        draft_params = (load_draft(mesh)
-                        if (load_draft and draft_cfg is not None) else None)
         load_s = time.perf_counter() - t_load
         engines.append(InferenceEngine(
-            cfg.model, cfg.engine, params=params, seed=cfg.seed, mesh=mesh,
-            draft_cfg=draft_cfg, draft_params=draft_params))
+            cfg.model, cfg.engine, params=params, seed=cfg.seed, mesh=mesh))
         if params is not None:
             engines[-1].note_checkpoint_load(load_s)
     return EngineGroup(engines, cfg.server)
@@ -807,16 +798,6 @@ class InferenceServer:
                     warnings.append(
                         f"repeat_last_n={repeat_last_n} clamped to the "
                         f"static penalty window {PENALTY_WINDOW}")
-                if getattr(self.engine, "spec_draft", False):
-                    # Draft-model spec only: the q/p acceptance ratio
-                    # needs both distributions unmodified. Draft-free
-                    # ngram spec applies the penalty inside the verify
-                    # round (one-hot proposals have no p to corrupt),
-                    # so it composes with no warning.
-                    warnings.append(
-                        "repeat_penalty ignored: draft-model speculative "
-                        "decoding samples from the unmodified target "
-                        "distribution")
             stop = opts.get("stop", body.get("stop"))
             if stop is None:
                 stop = []
@@ -1161,8 +1142,6 @@ class InferenceServer:
 def build_server(model: str = "tiny-llama", tokenizer: str = "byte",
                  checkpoint: Optional[str] = None, warmup: bool = True,
                  tp: int = 1, sp: int = 1, dp: int = 1,
-                 draft_model: Optional[str] = None,
-                 draft_checkpoint: Optional[str] = None,
                  enable_debug: bool = False,
                  server_overrides: Optional[dict] = None,
                  platform: Optional[str] = None,
@@ -1170,7 +1149,7 @@ def build_server(model: str = "tiny-llama", tokenizer: str = "byte",
                  **engine_overrides) -> InferenceServer:
     """Convenience constructor used by CLI, tests, and benchmarks.
 
-    ``model``/``draft_model`` accept a preset name, a path to a HF
+    ``model`` accepts a preset name, a path to a HF
     checkpoint directory (architecture read from its config.json), or
     "auto" with ``checkpoint`` set. ``tokenizer="auto"`` uses the
     checkpoint directory's tokenizer files when present, else bytes.
@@ -1185,9 +1164,8 @@ def build_server(model: str = "tiny-llama", tokenizer: str = "byte",
     # Single model-resolution rule, shared with the pre-boot auto-sizing
     # path so the model that gets sized is the model that boots.
     from tpu_inference.engine.autosize import resolve_model_and_checkpoint
-    resolve = resolve_model_and_checkpoint
 
-    model_cfg, checkpoint = resolve(model, checkpoint)
+    model_cfg, checkpoint = resolve_model_and_checkpoint(model, checkpoint)
     if tokenizer == "auto":
         has_tok = checkpoint and any(
             os.path.exists(os.path.join(checkpoint, f))
@@ -1202,43 +1180,28 @@ def build_server(model: str = "tiny-llama", tokenizer: str = "byte",
                                               enable_debug=enable_debug,
                                               **(server_overrides or {})),
                           checkpoint_path=checkpoint)
-    draft_cfg = None
-    if draft_model:
-        draft_cfg, draft_checkpoint = resolve(draft_model, draft_checkpoint)
-    if draft_cfg is not None and checkpoint and not draft_checkpoint:
-        # Trained target + random draft = ~zero acceptance: every
-        # round pays draft+verify to emit one token. Refuse loudly.
-        raise ValueError(
-            "--draft-model with --checkpoint requires "
-            "--draft-checkpoint: a random-weight draft makes "
-            "speculative decoding a pure slowdown")
 
-    def _loader(mcfg, path):
-        """(mesh | None) -> params: checkpoints stream per-replica so each
-        replica's leaves land directly in ITS device layout — never an
+    def load(mesh):
+        """(mesh | None) -> params: the checkpoint streams per-replica so
+        each replica's leaves land directly in ITS device layout — never an
         unsharded copy on host or device 0 (host-OOM at 70B scale). With
         quant on, each matmul weight quantizes as it lands, so peak device
         memory stays ~int8-model-sized (never full bf16 + int8)."""
-        def load(mesh):
-            from tpu_inference.models import weights
+        from tpu_inference.models import weights
 
-            shardings = None
-            if mesh is not None:
-                from tpu_inference.parallel import shardings as shd
+        shardings = None
+        if mesh is not None:
+            from tpu_inference.parallel import shardings as shd
 
-                shardings = shd.param_shardings(mcfg, mesh)
-            return weights.load_checkpoint(mcfg, path, shardings=shardings,
-                                           quant=cfg.engine.quant)
-
-        return load
+            shardings = shd.param_shardings(model_cfg, mesh)
+        return weights.load_checkpoint(model_cfg, checkpoint,
+                                       shardings=shardings,
+                                       quant=cfg.engine.quant)
 
     t0 = time.perf_counter()
     group = build_engine_group(
         cfg,
-        load_params=_loader(model_cfg, checkpoint) if checkpoint else None,
-        draft_cfg=draft_cfg,
-        load_draft=(_loader(draft_cfg, draft_checkpoint)
-                    if draft_checkpoint else None),
+        load_params=load if checkpoint else None,
         platform=platform, sizing=sizing)
     load_ns = int((time.perf_counter() - t0) * 1e9)
     return InferenceServer(cfg, group=group, load_duration_ns=load_ns)

@@ -341,8 +341,8 @@ class EngineGroup:
         kw = dict(device=self.engines[0].device_info(),
                   fleet=self.server_cfg.fleet,
                   kv_quant=ecfg.kv_quant,
-                  spec_mode=(self.engines[0].spec_mode
-                             if self.engines[0].spec_enabled else "off"),
+                  spec_mode=("ngram" if self.engines[0].spec_enabled
+                             else "off"),
                   routing=self.server_cfg.routing)
         telemetry.emit_build_info(r, **kw)
         for e in self.engines:

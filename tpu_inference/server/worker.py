@@ -302,8 +302,7 @@ class EngineWorker:
             device=self.engine.device_info(),
             fleet=cfg.server.fleet,
             kv_quant=cfg.engine.kv_quant,
-            spec_mode=(self.engine.spec_mode if self.engine.spec_enabled
-                       else "off"),
+            spec_mode="ngram" if self.engine.spec_enabled else "off",
             routing=cfg.server.routing)
         # Zero-copy KV plane counters (README "KV data plane"): arena
         # traffic this worker moved without a socket copy, plus the
@@ -616,8 +615,6 @@ class EngineWorker:
             "prefix_cache": e.prefix_cache is not None,
             "host_cache_pages": (e.host_pool.capacity
                                  if e.host_pool is not None else 0),
-            "spec_draft": bool(getattr(e, "spec_draft", False)),
-            "spec_mode": e.spec_mode if e.spec_enabled else None,
             "device": self._device_facts(),
         }
 

@@ -2762,16 +2762,16 @@ class EngineTelemetry:
         r = self.registry
         r.counter("tpu_inf_spec_drafted_total",
                   "Speculative positions proposed for verification "
-                  "(draft-model or n-gram proposals)",
+                  "(n-gram proposals the host could emit)",
                   fn=lambda: engine.spec_drafted)
         r.counter("tpu_inf_spec_accepted_total",
                   "Speculative positions accepted by the target model",
                   fn=lambda: engine.spec_accepted)
         r.counter("tpu_inf_spec_rounds_total",
-                  "Verify rounds dispatched (ngram mode)",
+                  "Verify rounds dispatched",
                   fn=lambda: engine.spec_rounds_total)
         r.counter("tpu_inf_spec_fallback_rounds_total",
-                  "Spec-mode rounds that ran the plain fused-K decode "
+                  "Speculating rounds that ran the plain fused-K decode "
                   "graph because no lane proposed (cold/throttled "
                   "streams — the 'spec never loses' path)",
                   fn=lambda: engine.spec_fallback_rounds)
