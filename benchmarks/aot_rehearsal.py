@@ -26,6 +26,12 @@ PR 25) — and a graph that has one counts as refused.
         --quant none --max-pages-per-seq 512 --target-ctx 1024 \
         --batch-cap 64       # a pool a kind, the window kind's sized on
                              # live tokens: 18 graphs, 64 lanes
+    python benchmarks/aot_rehearsal.py --model xing4-29b-pp6 --quant none \
+        --max-pages-per-seq 704 --target-ctx 2048 --batch-cap 64 \
+        --graphs decode:64 prefill:1x1024 --dump-hlo /tmp/hlo
+                             # four residual streams a token: the ops under
+                             # the mhc_* scopes are in the dumped HLO's
+                             # metadata (~23 fusions a hyper-connection)
 
 Compile EVERY graph the warm-up will run: the v5e compiler refused
 exactly one (prefill 4x512: the kernel's VMEM plus an operand XLA

@@ -124,6 +124,28 @@ SETTINGS = {
     # mis-addresses an expert is off by the routed part's whole spread
     # (planted there: 1.6 / 2.2 / 2.2; each must read over 10x this).
     "routed_expert_tol": 0.05,
+    # One hyper-connection alone (models/hyper_connections.py: the
+    # coefficient head, the Sinkhorn projection, both mixes) at Xing4.0's
+    # widths (4 streams of 3584) on random bfloat16 streams, against the
+    # same equations in float32. Two tolerances, because the cell's
+    # ``correct`` cannot see the precision of the coefficients or of the
+    # mixes at three layers (PERF.md section 2) and this can:
+    # ``_f32_tol`` for what the program states as float32, the LARGEST
+    # error of a coefficient (H_pre, H_post, H_res: numbers of 0 to 2)
+    # and of a pre-mix value as a share of the pre-mix's rms; the v5e
+    # reads the coefficients at 5.4e-7 / 1.07e-6 and the pre-mix at
+    # 8.4e-7 / 1.5e-6 (64 / 1024 rows; my chip runs, PR 47, calls A and
+    # E: its compiler hands the float32 sum to the consumer and drops the
+    # bfloat16 store in between), and coefficients rounded to bfloat16
+    # (2^-9 of a number up to 2), planted, read 0.0039 in both and must
+    # read over 10x this;
+    # ``_tol`` for the post-mix, which is STORED bfloat16: 2^-9 of the
+    # largest of 0.9 M (64 rows) or 14.7 M (1024 rows) values, which lies
+    # 5-6 rms out: 0.0239 / 0.0249 on the v5e (the same run). 2x above
+    # that; a projection stopped after one iteration read 1.20 there and
+    # must read over 10x this.
+    "hyper_connection_f32_tol": 1e-4,
+    "hyper_connection_tol": 0.05,
     # tp4_parity: bf16 at a depth one chip also holds (the tp=1 side;
     # the same 4 layers the tolerance above was measured at), prompts
     # inside one prefill bucket (the one-chip parity does the chunking).
@@ -1206,6 +1228,97 @@ def _routed_expert_errors(cfg: dict, *, preset: str = "kimi-k2-ep32",
     return {k: round(v, 5) for k, v in out.items()}
 
 
+def _hyper_connection_errors(cfg: dict, *, preset: str = "xing4-29b-pp6",
+                             rows=(64, 1024)) -> dict:
+    """The hyper-connection alone at ``preset``'s widths, ``rows`` token
+    rows a call (a decode rung, a prefill chunk), the program's own
+    functions under jit against the equations in plain float32 at the
+    highest matmul precision on the same values: the coefficients
+    themselves, the pre-mix and the post-mix; and, planted, the
+    coefficients rounded to bfloat16 before the mixes use them and the
+    projection stopped after ONE iteration."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_inference.config import PRESETS
+    from tpu_inference.models import hyper_connections as mhc
+
+    mcfg = PRESETS[preset]()
+    n, d, c = mcfg.hc_mult, mcfg.d_model, mhc.n_coeff(mcfg)
+    key = jax.random.fold_in(jax.random.PRNGKey(cfg["seed"] & 0x7FFFFFFF), 47)
+    k_phi, k_b, k_x, k_y = jax.random.split(key, 4)
+    lp = {"hc_attn_phi": (jax.random.normal(k_phi, (n * d, c), jnp.float32)
+                          / (n * d) ** 0.5).astype(mcfg.dtype),
+          "hc_attn_b": 0.5 * jax.random.normal(k_b, (c,), jnp.float32),
+          "hc_attn_alpha": jnp.ones((3,), jnp.float32)}
+
+    def program(x, y, rounded=False):
+        coef, err = mhc.coefficients(mcfg, lp, "attn", x)
+        if rounded:
+            # (bfloat16's 8 exponent and 7 mantissa bits, by the one op
+            # no compiler folds away)
+            coef = jax.lax.reduce_precision(coef, 8, 7)
+        return (coef, mhc.pre_mix(mcfg, coef, x).astype(jnp.float32),
+                mhc.post_mix(mcfg, coef, x, y).astype(jnp.float32), err)
+
+    def plain(x, y, iters):
+        with jax.default_matmul_precision("highest"):
+            xf = x.astype(jnp.float32).reshape(-1, n, d)
+            v = xf.reshape(-1, n * d)
+            z = (v * jax.lax.rsqrt(jnp.mean(v * v, -1, keepdims=True)
+                                   + mcfg.hc_eps)
+                 ) @ lp["hc_attn_phi"].astype(jnp.float32) + lp["hc_attn_b"]
+            pre = jax.nn.sigmoid(z[:, :n])
+            post = 2.0 * jax.nn.sigmoid(z[:, n:2 * n])
+            res = jnp.exp(jnp.clip(z[:, 2 * n:], -mcfg.hc_res_clamp,
+                                   mcfg.hc_res_clamp)).reshape(-1, n, n)
+            for _ in range(iters):
+                res = res / (res.sum(1, keepdims=True) + mcfg.hc_eps)
+                res = res / (res.sum(2, keepdims=True) + mcfg.hc_eps)
+            h = jnp.einsum("tn,tnd->td", pre, xf)
+            out = (jnp.einsum("tij,tjd->tid", res, xf)
+                   + post[:, :, None] * y.reshape(-1, 1, d))
+            coef = jnp.concatenate([pre, post, res.reshape(-1, n * n)], -1)
+            return coef, h, out.reshape(-1, n * d)
+
+    def err(got, want, scale=None):
+        got = got.reshape(want.shape)
+        return float(jnp.max(jnp.abs(got - want))
+                     / (scale or jnp.sqrt(jnp.mean(want * want))))
+
+    out = {}
+    for t in rows:
+        b, s = (t, 1) if t <= 64 else (1, t)
+        x = jax.random.normal(jax.random.fold_in(k_x, t), (b, s, n * d),
+                              jnp.float32).astype(mcfg.dtype)
+        y = jax.random.normal(jax.random.fold_in(k_y, t), (b, s, d),
+                              jnp.float32)
+        coef, h, mixed, off = jax.jit(program)(x, y)
+        want_coef, want_h, want = plain(x, y, mcfg.hc_sinkhorn_iters)
+        out[f"coef_{t}"] = err(coef, want_coef, scale=1.0)
+        out[f"pre_mix_{t}"] = err(h, want_h)
+        out[f"post_mix_{t}"] = err(mixed, want)
+        out[f"sum_err_ppm_{t}"] = round(1e6 * float(off))
+    coef, h, _, _ = jax.jit(lambda x, y: program(x, y, True))(x, y)
+    out["planted_coef_bf16"] = min(err(coef, want_coef, scale=1.0),
+                                   err(h, want_h))
+    out["planted_one_iteration"] = err(mixed, plain(x, y, 1)[2])
+    return out
+
+
+def check_hyper_connection(errs: dict, tol: float, f32_tol: float) -> None:
+    def largest(*prefixes):
+        return max(v for k, v in errs.items() if k.startswith(prefixes))
+
+    check(largest("coef_", "pre_mix_") <= f32_tol
+          and largest("post_mix_") <= tol,
+          f"hyper-connection vs the equations in float32: {errs}")
+    check(errs["planted_coef_bf16"] > 10 * f32_tol,
+          f"coefficients rounded to bfloat16 read as sound: {errs}")
+    check(errs["planted_one_iteration"] > 10 * tol,
+          f"a projection stopped after one iteration reads as sound: {errs}")
+
+
 def check_routed_experts(errs: dict, tol: float) -> None:
     for name, e in errs.items():
         if name.startswith(("routed_", "spilled_")):
@@ -1395,6 +1508,12 @@ def child_parity(cfg: dict) -> dict:
             for preset in ("kimi-k2-ep32", "laguna-s-ep8")}
         for errs in res["spilled_expert_err"].values():
             check_routed_experts(errs, cfg["routed_expert_tol"])
+        # Four residual streams of 3584 mixed by one hyper-connection:
+        # the coefficients and the pre-mix held to float32.
+        res["hyper_connection_err"] = _hyper_connection_errors(cfg)
+        check_hyper_connection(res["hyper_connection_err"],
+                               cfg["hyper_connection_tol"],
+                               cfg["hyper_connection_f32_tol"])
         res["nope_window_kernel_err"] = _mixed_kernel_errors(
             cfg, kv_heads=4, kinds=((28, 4096), (28, 0)), lanes=4,
             ctx=5000, rows=1024)

@@ -263,6 +263,22 @@ def test_routed_expert_check_in_interpret_mode():
                                     chip_smoke.SETTINGS["routed_expert_tol"])
 
 
+def test_hyper_connection_check_on_the_cpu():
+    """The smoke's check of one hyper-connection (run on the chip at
+    Xing4.0's widths, 64 and 1024 rows) at the tiny preset: the program's
+    functions are the equations in float32; coefficients rounded to
+    bfloat16 and a projection stopped after one iteration are not."""
+    errs = chip_smoke._hyper_connection_errors(TINY, preset="tiny-xing",
+                                               rows=(96,))
+    assert set(errs) == {"coef_96", "pre_mix_96", "post_mix_96",
+                         "sum_err_ppm_96", "planted_coef_bf16",
+                         "planted_one_iteration"}
+    chip_smoke.check_hyper_connection(
+        errs, chip_smoke.SETTINGS["hyper_connection_tol"],
+        chip_smoke.SETTINGS["hyper_connection_f32_tol"])
+    assert max(errs["pre_mix_96"], errs["post_mix_96"]) < 1e-4   # float32
+
+
 def test_spilled_routing_check_in_interpret_mode():
     """The smoke's check of a decode rung whose routing spills (run on
     the chip at Kimi-K2's and Laguna's widths, 32 lanes) at tiny-laguna's

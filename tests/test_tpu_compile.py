@@ -477,7 +477,7 @@ def _forward_hlo(chip, name, quant_mode, rows, stored=True):
     call, each fed the last one's tokens, as the fused-K scan runs them
     (the whole-stack copy is hoisted out of THAT loop)."""
     from tpu_inference.config import PRESETS
-    from tpu_inference.models import laguna, quant
+    from tpu_inference.models import deepseek_v3, laguna, quant
     from tpu_inference.models.common import make_dense_attn
     from tpu_inference.models.registry import get_model_fns
 
@@ -492,7 +492,8 @@ def _forward_hlo(chip, name, quant_mode, rows, stored=True):
             lambda p: quant.store_transposed(p, cfg.family)[0], shapes)
     params = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
         x.shape, x.dtype, sharding=chip), shapes)
-    attn = (laguna.make_dense_attn(cfg) if cfg.family == "laguna"
+    own = {"laguna": laguna, "deepseek_v3": deepseek_v3}.get(cfg.family)
+    attn = (own.make_dense_attn(cfg) if own
             else make_dense_attn(cfg.sliding_window))
 
     def forward(params, tokens, positions):
@@ -542,3 +543,116 @@ def test_a_stack_left_as_published_is_copied_and_seen(chip):
     assert len(found) == 3, found
     for leaf in ("wq", "wk", "wv"):
         assert any(f"['{leaf}']" in c for c in found), (leaf, found)
+
+
+@pytest.mark.parametrize("rows", [(64, 1), (1, 1024)])
+def test_a_hyper_connection_compiles_to_a_handful_of_fusions(chip, rows):
+    """One hyper-connection at Xing4.0's widths (4 streams of 3584: the
+    coefficient head, twenty Sinkhorn iterations, both mixes): no loop
+    reaches the chip, and the compiler makes about a hundred fusions of
+    it alone, two an iteration of the projection (a sum, and the division
+    by it), a fraction of a microsecond each at a decode rung's 64 rows,
+    fourteen hyper-connections a decode step."""
+    import re
+
+    from tpu_inference.config import PRESETS
+    from tpu_inference.models import hyper_connections as mhc
+
+    cfg = PRESETS["xing4-29b-pp6"]()
+    wide, c = cfg.hc_mult * cfg.d_model, mhc.n_coeff(cfg)
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    lp = {"hc_attn_phi": s((wide, c), jnp.bfloat16),
+          "hc_attn_b": s((c,), jnp.float32),
+          "hc_attn_alpha": s((3,), jnp.float32)}
+
+    def f(lp, x, y):
+        coef, err = mhc.coefficients(cfg, lp, "attn", x)
+        return (mhc.pre_mix(cfg, coef, x), mhc.post_mix(cfg, coef, x, y),
+                err)
+
+    hlo = jax.jit(f).lower(lp, s((*rows, wide), jnp.bfloat16),
+                           s((*rows, cfg.d_model), jnp.float32)
+                           ).compile().as_text()
+    assert _whiles(hlo) == 0
+    fused = len(re.findall(r" fusion\(", hlo[hlo.index("ENTRY"):]))
+    assert fused <= 110, fused
+
+
+def _timed_ops(hlo):
+    """(scope or None, op_name metadata, the name bench/trace_reduce.py
+    gives the op in a trace summary) of every instruction the device
+    runs as an op of its own and a trace summary gives a shape: those
+    outside the fused computations, but for the ones that move no data
+    and the ones with a tuple result (which the summary names without a
+    shape)."""
+    import re
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir,
+                                    "bench"))
+    from trace_reduce import short_name
+
+    timed = None
+    for line in hlo.splitlines():
+        if line[:1] not in ("", " ", "}"):
+            head = line.split()[1 if line.startswith("ENTRY") else 0]
+            timed = (line.startswith("ENTRY")
+                     or head.lstrip("%").startswith(("wide.", "region_"))
+                     and "reduce_sub" not in head) and line.endswith("{")
+            continue
+        line = line.strip().removeprefix("ROOT ")
+        kind = re.match(r"%\S+ = (?:\([^)]*\)|\S+) ([\w\-]+)\(", line)
+        if not timed or kind is None or kind.group(1) in (
+                "parameter", "get-tuple-element", "bitcast", "constant",
+                "tuple", "copy-start", "copy-done", "while"):
+            continue
+        meta = re.search(r'op_name="([^"]*)"', line)
+        meta = meta.group(1) if meta else ""
+        scope = re.search(r"mhc_[a-z_]+", meta)
+        yield (scope.group(0) if scope else None, meta,
+               short_name(line.split(", metadata=")[0]))
+
+
+@pytest.mark.parametrize("rows", [(64, 1), (1, 1024)])
+def test_the_trace_readers_shape_table_follows_the_mhc_scopes(chip, rows):
+    """``bench/readers/xing_mhc.py`` tells a hyper-connection's ops in a
+    trace summary by their result's shape (the summary carries no scope).
+    Held here to the scopes the program names, in the chip compiler's HLO
+    of the preset's forward at published widths, a decode rung and a
+    prefill chunk: every op it accepts is under an ``mhc_`` scope (or is
+    the fan-out, or an op the compiler made, which carries no scope at
+    all); every scoped op it refuses has a result no shape can tell from
+    another layer's (a vector a token, or one stream wide); and it
+    accepts ops of the head, the projection and the post-mix. A change
+    of layout that silences the cell's two ``xing_mhc_*`` readings fails
+    here."""
+    import json
+    bench = os.path.join(os.path.dirname(__file__), os.pardir, "bench")
+    sys.path.insert(0, bench)
+    from manifest import load_module
+    reader = load_module(os.path.join(bench, "readers", "xing_mhc.py"))
+    with open(os.path.join(bench, "configs",
+                           "xing4-29b-pp6-bf16.json")) as f:
+        cfg = json.load(f)
+
+    # (the tests' compile cache keeps tracebacks out of an op's location,
+    # runtime.enable_compile_cache, and the scope goes with them)
+    was = jax.config.jax_include_full_tracebacks_in_locations
+    jax.config.update("jax_include_full_tracebacks_in_locations", True)
+    try:
+        hlo, _ = _forward_hlo(chip, "xing4-29b-pp6", "none", rows)
+    finally:
+        jax.config.update("jax_include_full_tracebacks_in_locations", was)
+    told = {}
+    for scope, meta, name in _timed_ops(hlo):
+        dims = reader._dims(name)[1]
+        if reader.is_mhc(name, cfg):
+            assert (scope or "/" not in meta
+                    or meta.endswith("/tile")), (name, meta)
+            told[scope] = told.get(scope, 0) + 1
+        elif scope and dims:
+            assert (len(dims) == 1 or dims[-1] == cfg["hidden_size"]), (
+                scope, name)
+    assert {"mhc_coeff", "mhc_sinkhorn", "mhc_post_mix"} <= set(told), told
+

@@ -244,6 +244,19 @@ class ModelConfig:
     # the page dim, and copies the whole pool in front of every kernel
     # call. Only a stack of mixed kinds reads it (engine.make_kind_attn).
     pool_rows_merged: bool = False
+    # --- hyper-connections (models/hyper_connections.py) ---
+    # Residual STREAMS a token carries through the stack: 1 = the plain
+    # residual ``x + f(norm(x))`` of every other preset. With n > 1 each
+    # sublayer reads a per-token mix of the n streams and writes back
+    # through a doubly stochastic n x n matrix (``hc_sinkhorn_iters``
+    # column-then-row normalisations of exp(clip(., +-hc_res_clamp)),
+    # ``hc_eps`` in every divisor and in the stream norm) plus a
+    # per-stream share of its output. The streams live inside one
+    # forward call: no cache entry knows of them.
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: float = 30.0
     dtype: jnp.dtype = jnp.bfloat16
 
     def __post_init__(self):
@@ -361,6 +374,10 @@ class ModelConfig:
         # Only the looped family's forward runs passes / output norms.
         assert self.family == "ouro" or (self.loop_steps == 1
                                          and not self.sandwich_norm)
+        assert self.hc_mult >= 1 and self.hc_sinkhorn_iters >= 1
+        assert self.hc_eps > 0 and self.hc_res_clamp > 0
+        # Only models/deepseek_v3.py's block carries several streams.
+        assert self.hc_mult == 1 or self.family == "deepseek_v3"
         if self.family == "deepseek_v3":
             assert self.kv_lora_rank and self.qk_rope_head_dim % 2 == 0
             # The one routing a preset has: models/deepseek_v3.py route()
@@ -479,6 +496,34 @@ def kimi_k2_ep32() -> ModelConfig:
         n_experts=384, n_experts_per_tok=8, moe_scoring="sigmoid",
         norm_topk_prob=True, routed_scaling_factor=2.827,
         ep_size=32, ep_rank=0,
+    )
+
+
+def xing4_29b_pp6() -> ModelConfig:
+    """Xing4.0-29B-A4B (XingChen-AGI) as ONE stage of a six-stage
+    pipeline, at every published width: 1 dense + 6 expert layers of the
+    40, FOUR residual streams a token mixed by manifold-constrained
+    hyper-connections twice a layer (arXiv:2512.24880; 20 Sinkhorn
+    iterations), latent attention at 32 heads, a router over 64 experts
+    of width 1024 ALL held here (top-4, sigmoid scores, a selection
+    bias, scaling 2) beside one shared expert, YaRN 64 x 4096, and the
+    whole untied vocabulary of 131,072 so that the stage serves alone.
+    bench/configs/xing4-29b-pp6-bf16.json states the cut and what is
+    assumed."""
+    return ModelConfig(
+        name="xing4-29b-pp6", family="deepseek_v3", vocab_size=131072,
+        d_model=3584, n_layers=7, n_heads=32, n_kv_heads=32, d_ff=9216,
+        max_seq_len=262144, rope_theta=10000.0,
+        rope_scaling=YarnScaling(factor=64.0, original_max_len=4096,
+                                 beta_fast=32.0, beta_slow=1.0,
+                                 mscale=1.0, mscale_all_dim=1.0),
+        norm_eps=1e-6, q_lora_rank=768, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        first_k_dense=1, moe_d_ff=1024, n_shared_experts=1,
+        n_experts=64, n_experts_per_tok=4, moe_scoring="sigmoid",
+        norm_topk_prob=True, routed_scaling_factor=2.0,
+        ep_size=1, ep_rank=0, moe_row_stats=True, hc_mult=4,
+        hc_sinkhorn_iters=20, hc_eps=1e-6, hc_res_clamp=30.0,
     )
 
 
@@ -665,6 +710,15 @@ def tiny_kimi(vocab_size: int = 512) -> ModelConfig:
     )
 
 
+def tiny_xing(vocab_size: int = 512) -> ModelConfig:
+    """The Xing4.0 structure at test widths: four residual streams mixed
+    by hyper-connections around 1 dense + 3 expert layers of latent
+    attention, 16 routed experts top-4 ALL held, one shared."""
+    return dataclasses.replace(
+        tiny_kimi(vocab_size), name="tiny-xing", n_layers=4, ep_size=1,
+        routed_scaling_factor=2.0, moe_row_stats=True, hc_mult=4)
+
+
 def tiny_gpt2(vocab_size: int = 512) -> ModelConfig:
     return ModelConfig(
         name="tiny-gpt2", family="gpt2", vocab_size=vocab_size, d_model=128,
@@ -758,6 +812,7 @@ PRESETS = {
     "laguna-s-ep8": laguna_s_ep8,
     "phi4-mini-flash": phi4_mini_flash,
     "smallthinker-21b-pp4": smallthinker_21b_pp4,
+    "xing4-29b-pp6": xing4_29b_pp6,
     "tiny-llama": tiny_llama,
     "tiny-llama-fatkv": tiny_llama_fatkv,
     "tiny-qwen2": tiny_qwen2,
@@ -771,6 +826,7 @@ PRESETS = {
     "tiny-laguna": tiny_laguna,
     "tiny-sambay": tiny_sambay,
     "tiny-smallthinker": tiny_smallthinker,
+    "tiny-xing": tiny_xing,
 }
 
 

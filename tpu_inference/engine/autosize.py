@@ -196,13 +196,24 @@ class AutoSizing:
     num_window_pages: int = 0
 
 
+def stream_activation_bytes(model_cfg, chunk_tokens: int) -> int:
+    """What a prefill chunk's resident activations take BEYOND the one
+    residual the fixed headroom was set for, where a token carries
+    ``hc_mult`` streams (models/hyper_connections.py): each further
+    stream's carry, the mixed copy being written and the float32 sum it
+    is rounded from, 2 + 2 + 4 bytes a value. A decode step's rows are
+    not worth counting."""
+    return (model_cfg.hc_mult - 1) * chunk_tokens * model_cfg.d_model * 8
+
+
 def auto_size(model_cfg, *, hbm_bytes: float, quant: str = "none",
               kv_quant: str = "none", tp: int = 1,
               page_size: int = 16, max_pages_per_seq: int = 64,
               target_ctx: Optional[int] = None, batch_cap: int = 32,
               reserve_frac: float = 0.15,
               activation_headroom: int = 512 << 20,
-              window_span: int = 0, written_ahead: int = 0) -> AutoSizing:
+              window_span: int = 0, written_ahead: int = 0,
+              chunk_tokens: int = 1024) -> AutoSizing:
     """Size ``max_batch_size`` and ``num_pages`` for the chip.
 
     Raises ValueError when the weights alone exceed the per-chip budget
@@ -222,6 +233,7 @@ def auto_size(model_cfg, *, hbm_bytes: float, quant: str = "none",
     hbm = float(hbm_bytes)
     wb = weight_bytes(model_cfg, quant)
     per_chip_w = wb // tp
+    activation_headroom += stream_activation_bytes(model_cfg, chunk_tokens)
     usable = (1.0 - reserve_frac) * hbm
     budget = usable - per_chip_w - activation_headroom
     if budget <= 0:
@@ -589,7 +601,8 @@ def resolve_sizing(model_cfg, engine_cfg, req: Optional[dict], *,
             batch_cap=req["batch_cap"],
             window_span=window_span_pages(model_cfg, engine_cfg)
             if model_cfg.layer_types else 0,
-            written_ahead=written_ahead_tokens(engine_cfg))
+            written_ahead=written_ahead_tokens(engine_cfg),
+            chunk_tokens=engine_cfg.chunk_tokens_cap)
         mbs = sz.max_batch_size if mbs == "auto" else mbs
         pages = sz.num_pages if pages == "auto" else pages
         import sys

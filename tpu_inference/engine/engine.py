@@ -807,9 +807,13 @@ class InferenceEngine:
         # ``n_aux_stats``), summed off the decode readbacks: expert
         # routing (models/deepseek_v3.py MOE_STATS + one slot per held
         # expert), the positions a prefill ran (models/sambay.py
-        # AUX_STATS). None for a family that counts nothing.
+        # AUX_STATS). None for a family that counts nothing. The slots
+        # a family names under ``aux_max_slots`` hold the largest value
+        # seen, the others sums.
         self.aux_stats = (np.zeros(self.kv.aux.shape, np.int64)
                           if self.kv.aux is not None else None)
+        max_slots = family_fn(model_cfg, "aux_max_slots")
+        self._aux_max_slots = list(max_slots(model_cfg)) if max_slots else []
         t_pool = time.perf_counter()
         self.allocator = PageAllocator(engine_cfg.num_pages)
         # A model whose layers differ in kind has a second pool, for its
@@ -1806,8 +1810,10 @@ class InferenceEngine:
         """The model's counts that rode a decode readback ``outs``
         [K, rung + n] behind the lanes' tokens (_decode_multi_fn)."""
         if self.aux_stats is not None:
-            self.aux_stats += outs[:, -len(self.aux_stats):].sum(
-                axis=0, dtype=np.int64)
+            rows, at = outs[:, -len(self.aux_stats):], self._aux_max_slots
+            seen = np.maximum(self.aux_stats[at], rows[:, at].max(axis=0))
+            self.aux_stats += rows.sum(axis=0, dtype=np.int64)
+            self.aux_stats[at] = seen
 
     def _next_step(self) -> int:
         """The next dispatch's step number: its sampling key is

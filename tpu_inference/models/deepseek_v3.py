@@ -27,6 +27,12 @@ What differs from ``models/llama.py``:
   address (layer, expert) in the stacked array themselves.
 - Expert-routing counts ride ``kv.aux`` (an int32 vector in the KV
   state) out of the graph: ``MOE_STATS`` names its slots.
+- **Several residual streams** where ``cfg.hc_mult`` > 1
+  (models/hyper_connections.py): the scans carry ``[B, S, n D]``, every
+  sublayer reads a per-token mix of the n streams and writes back through
+  a doubly stochastic matrix. The streams live inside this forward call:
+  a token's latent entry is made from ``RMSNorm(H_pre X)`` as it is from
+  ``RMSNorm(x)`` with one stream, and no cache entry knows of them.
 
 Rope is the half-split pairing of ``apply_rope`` on the rope dims only,
 YaRN frequencies; the softmax scale carries YaRN's ``mscale_all_dim``
@@ -44,6 +50,7 @@ import jax.numpy as jnp
 
 from tpu_inference.config import ModelConfig, YarnScaling
 from tpu_inference.kernels import moe_experts
+from tpu_inference.models import hyper_connections as mhc
 from tpu_inference.models.common import (
     AttentionFn,
     apply_rope,
@@ -70,9 +77,16 @@ def n_moe_stats(cfg: ModelConfig) -> int:
 
 # What the shared layers ask a family module for, where it differs from
 # the dense default (models/registry.py family_fn): the length of the
-# int32 counter vector beside the KV pool, the parameter count, and the
+# int32 counter vector beside the KV pool (the routing counts, then
+# ``mhc.MHC_STATS`` where the model carries several streams), the slots
+# of it that fold by max and not by sum, the parameter count, and the
 # width an attention pair costs 4 x n_heads x of in FLOPs.
-n_aux_stats = n_moe_stats
+def n_aux_stats(cfg: ModelConfig) -> int:
+    return n_moe_stats(cfg) + (len(mhc.MHC_STATS) if cfg.hc_mult > 1 else 0)
+
+
+def aux_max_slots(cfg: ModelConfig) -> tuple:
+    return (n_aux_stats(cfg) - 1,) if cfg.hc_mult > 1 else ()
 
 
 def param_count(cfg: ModelConfig, active: bool = False) -> int:
@@ -126,9 +140,11 @@ def param_shapes(cfg: ModelConfig) -> dict:
     d, nd = cfg.d_model, cfg.first_k_dense
     ne, e, f = cfg.n_layers - nd, cfg.n_local_experts, cfg.moe_d_ff
     fs = f * cfg.n_shared_experts
-    dense = dict(_attn_shapes(cfg, nd), w_gate=(nd, d, cfg.d_ff),
+    dense = dict(_attn_shapes(cfg, nd), **mhc.shapes(cfg, nd),
+                 w_gate=(nd, d, cfg.d_ff),
                  w_up=(nd, d, cfg.d_ff), w_down=(nd, cfg.d_ff, d))
-    moe = dict(_attn_shapes(cfg, ne), w_router=(ne, d, cfg.n_experts),
+    moe = dict(_attn_shapes(cfg, ne), **mhc.shapes(cfg, ne),
+               w_router=(ne, d, cfg.n_experts),
                router_bias=(ne, cfg.n_experts),
                ws_gate=(ne, d, fs), ws_up=(ne, d, fs), ws_down=(ne, fs, d),
                we_gate=(ne, e, d, f), we_up=(ne, e, d, f),
@@ -140,7 +156,8 @@ def param_shapes(cfg: ModelConfig) -> dict:
 def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
     """Random init (normal, 0.02 std; norm scales 1; the selection bias
     float32, 0.01 std: the top scores of a few hundred sigmoids lie
-    ~0.003 apart, so a larger bias would pick the experts by itself). One
+    ~0.003 apart, so a larger bias would pick the experts by itself; a
+    hyper-connection's float32 leaves as ``mhc.init_float32`` says). One
     jitted draw a leaf: the float32 normals of a 2 GB expert stack never
     exist beside it."""
     cfg.validate()
@@ -156,6 +173,9 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
         name = path[-1].key
         if "norm" in name:
             out.append(jnp.ones(shape, cfg.dtype))
+        elif name in mhc.FLOAT32_LEAVES:
+            out.append(mhc.init_float32(name, jax.random.fold_in(key, i),
+                                        shape))
         else:
             bias = name == "router_bias"
             out.append(draw(jax.random.fold_in(key, i), shape,
@@ -281,12 +301,33 @@ def moe_ffn(cfg: ModelConfig, lp: dict, experts: tuple, moe_layer,
 
 def _block(cfg: ModelConfig, layer_idx, lp: dict, x: jax.Array,
            positions: jax.Array, kv: Any, attn: AttentionFn, ffn):
+    """One layer on the residual x -> (x, kv, the FFN's stats, the
+    hyper-connections' largest row / column sum error or None)."""
+    if cfg.hc_mult > 1:
+        return _block_streams(cfg, layer_idx, lp, x, positions, kv, attn,
+                              ffn)
     h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
     a, kv = latent_attention(cfg, layer_idx, lp, h, positions, kv, attn)
     x = x + a.astype(x.dtype)
     h = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
     y, stats = ffn(lp, h)
-    return x + y, kv, stats
+    return x + y, kv, stats, None
+
+
+def _block_streams(cfg: ModelConfig, layer_idx, lp: dict, x: jax.Array,
+                   positions: jax.Array, kv: Any, attn: AttentionFn, ffn):
+    """``_block`` on n residual streams x [B, S, n D]: each sublayer
+    reads ``H_pre X`` and writes ``H_res X + H_post^T y``."""
+    valid = getattr(attn, "valid", None)
+    coef, err_a = mhc.coefficients(cfg, lp, "attn", x, valid=valid)
+    h = rms_norm(mhc.pre_mix(cfg, coef, x), lp["attn_norm"], cfg.norm_eps)
+    a, kv = latent_attention(cfg, layer_idx, lp, h, positions, kv, attn)
+    x = mhc.post_mix(cfg, coef, x, a)
+    coef, err_f = mhc.coefficients(cfg, lp, "ffn", x, valid=valid)
+    h = rms_norm(mhc.pre_mix(cfg, coef, x), lp["ffn_norm"], cfg.norm_eps)
+    y, stats = ffn(lp, h)
+    return (mhc.post_mix(cfg, coef, x, y), kv, stats,
+            jnp.maximum(err_a, err_f))
 
 
 def forward_hidden(params: dict, cfg: ModelConfig, tokens: jax.Array,
@@ -295,18 +336,21 @@ def forward_hidden(params: dict, cfg: ModelConfig, tokens: jax.Array,
     """Token ids -> final hidden states. tokens, positions: [B, S]."""
     x = params["embed"][tokens].astype(cfg.dtype)
     nd = cfg.first_k_dense
+    streams = cfg.hc_mult > 1
+    if streams:
+        x = mhc.fan_out(cfg, x)
 
     def dense_body(carry, scanned):
         x, kv = carry
         layer_idx, lp = scanned
-        x, kv, _ = _block(
+        x, kv, _, err = _block(
             cfg, layer_idx, lp, x, positions, kv, attn,
             lambda lp, h: (swiglu(h, lp["w_gate"], lp["w_up"],
                                   lp["w_down"]), None))
-        return (x, kv), None
+        return (x, kv), err
 
-    (x, kv), _ = jax.lax.scan(dense_body, (x, kv),
-                              (jnp.arange(nd), params["dense"]))
+    (x, kv), err_d = jax.lax.scan(dense_body, (x, kv),
+                                  (jnp.arange(nd), params["dense"]))
 
     moe = dict(params["moe"])
     experts = tuple(moe.pop(k) for k in ("we_gate", "we_up", "we_down"))
@@ -314,16 +358,28 @@ def forward_hidden(params: dict, cfg: ModelConfig, tokens: jax.Array,
     def moe_body(carry, scanned):
         x, kv = carry
         i, lp = scanned
-        x, kv, stats = _block(
+        x, kv, stats, err = _block(
             cfg, nd + i, lp, x, positions, kv, attn,
             lambda lp, h: moe_ffn(cfg, lp, experts, i, h, attn))
-        return (x, kv), stats
+        return (x, kv), (stats, err)
 
-    (x, kv), stats = jax.lax.scan(moe_body, (x, kv),
-                                  (jnp.arange(cfg.n_layers - nd), moe))
+    (x, kv), (stats, err_m) = jax.lax.scan(
+        moe_body, (x, kv), (jnp.arange(cfg.n_layers - nd), moe))
     aux = getattr(kv, "aux", None)
-    if aux is not None:
+    if aux is not None and not streams:
         kv = kv._replace(aux=aux + stats.sum(0))
+    elif aux is not None:
+        # mhc.MHC_STATS behind the routing counts: the mixes add up, the
+        # sum error is the largest seen since the counts last left.
+        valid = getattr(attn, "valid", None)
+        mixes = len(mhc.SUBLAYERS) * cfg.n_layers * (
+            tokens.size if valid is None else jnp.sum(valid))
+        ppm = jnp.round(1e6 * jnp.max(jnp.concatenate([err_d, err_m])))
+        kv = kv._replace(aux=jnp.concatenate([
+            aux[:-1] + jnp.append(stats.sum(0), mixes).astype(jnp.int32),
+            jnp.maximum(aux[-1:], ppm.astype(jnp.int32)[None])]))
+    if streams:
+        x = mhc.read_out(cfg, x)
     return rms_norm(x, params["final_norm"], cfg.norm_eps), kv
 
 

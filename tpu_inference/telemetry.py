@@ -2839,6 +2839,20 @@ class EngineTelemetry:
                       "Local pairs the grouped expert rounds computed: "
                       "the real rows among tpu_inf_moe_tile_rows_total",
                       fn=lambda: int(st[at["computed_pairs"]]))
+        if engine.model_cfg.hc_mult > 1:
+            from tpu_inference.models.hyper_connections import MHC_STATS
+
+            first = len(st) - len(MHC_STATS)
+            r.counter("tpu_inf_mhc_mixes_total",
+                      "Hyper-connections applied: sublayers (two a layer) "
+                      "x token positions they mixed the residual streams "
+                      "of", fn=lambda: int(st[first]))
+            r.gauge("tpu_inf_mhc_row_sum_err_ppm_max",
+                    "Largest |sum - 1| over the rows and columns of any "
+                    "residual mixing matrix since boot, in parts per "
+                    "million (rows are normalised last: the columns' is "
+                    "what fewer Sinkhorn iterations move)",
+                    fn=lambda: int(st[first + 1]))
 
     def bind_state(self, engine) -> None:
         """Read-through metrics of a model with state-space layers: the
