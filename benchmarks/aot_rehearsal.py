@@ -175,7 +175,14 @@ def main() -> int:
     ap.add_argument("--quant", default="int8",
                     choices=("none", "int8", "int4"))
     ap.add_argument("--tp", type=int, default=1, choices=(1, 2, 4))
-    ap.add_argument("--max-pages-per-seq", type=int, default=320)
+    ap.add_argument("--max-pages-per-seq", type=int, default=320,
+                    help="as the server's flag: 16-token pages under "
+                         "--page-size auto")
+    ap.add_argument("--page-size", default="auto",
+                    type=lambda v: v if v == "auto" else int(v),
+                    help="as the server's flag: 'auto' (64 tokens where a "
+                         "16-token page is under 32 KB, else 16) or an "
+                         "integer")
     ap.add_argument("--target-ctx", type=int, default=0,
                     help="as the server's flag: what 'auto' sizes the "
                          "batch against (0: half the context cap)")
@@ -219,13 +226,15 @@ def main() -> int:
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name=args.topology)
     mcfg = PRESETS[args.model]()
-    mp = args.max_pages_per_seq
     ecfg = autosize.resolve_sizing(
         mcfg, EngineConfig(quant=args.quant, attn_backend="pallas",
-                           max_pages_per_seq=mp),
+                           page_size=(None if args.page_size == "auto"
+                                      else args.page_size),
+                           max_pages_per_seq=args.max_pages_per_seq),
         dict(max_batch_size="auto", num_pages="auto", decode_ladder="auto",
              target_ctx=args.target_ctx, batch_cap=args.batch_cap),
         tp=args.tp, hbm_bytes=args.hbm_bytes)
+    mp = ecfg.max_pages_per_seq       # in pages of the size in effect
 
     # The stand-in: any small engine on the Pallas backend (its
     # constructor asks jax which backend this is — answer for the chip).
@@ -310,7 +319,8 @@ def main() -> int:
     print(json.dumps({
         "model": mcfg.name, "layers": mcfg.n_layers, "quant": args.quant,
         "tp": args.tp, "max_batch_size": ecfg.max_batch_size,
-        "num_pages": ecfg.num_pages, "ladder": list(ecfg.ladder_rungs),
+        "num_pages": ecfg.num_pages, "page_size": ecfg.page_size,
+        "ladder": list(ecfg.ladder_rungs),
         "weights_GB": round(weights / 1e9, 3),
         "pool_GB": round(pool / 1e9, 3), "graphs": graphs}), flush=True)
 

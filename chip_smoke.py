@@ -868,7 +868,7 @@ def _mha_kernel_errors(cfg: dict, *, heads: int = 16, d: int = 128,
 def _mixed_kernel_errors(cfg: dict, *, d: int = 128, kv_heads: int = 8,
                          kinds=((72, 512), (48, 0)), slots: int = 9,
                          lanes: int = 6, ctx: int = 1400, rows: int = 512,
-                         interpret: bool = False) -> dict:
+                         page: int = 16, interpret: bool = False) -> dict:
     """The two GQA kernels at the shapes of a stack of mixed kinds
     (Laguna-S-2.1's: 72 query heads over a window of 512 and 48 over the
     whole context, on 8 KV heads x 128: ``n_rep`` 9 and 6, a window of
@@ -877,7 +877,8 @@ def _mixed_kernel_errors(cfg: dict, *, d: int = 128, kv_heads: int = 8,
     a prefill chunk of ``rows`` rows at offset 0 and behind a prefix
     longer than the window that ends inside a page block. The pages
     behind a windowed lane's window are the trash page, as the engine
-    leaves them (released while the sequence runs)."""
+    leaves them (released while the sequence runs). ``page``: the tokens
+    of a page, 16, or the 64 that 'auto' gives a pool of 4 KV heads."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -888,7 +889,6 @@ def _mixed_kernel_errors(cfg: dict, *, d: int = 128, kv_heads: int = 8,
         paged_prefill_attention)
     from tpu_inference.models.common import dense_causal_attention
 
-    page = 16
     q_off = np.array([0, ctx - 7], np.int32)
     mp = -(-(int(q_off[1]) + rows) // page)
     slot = slots // 2 + 1
@@ -993,7 +993,8 @@ def _sambay_kernel_errors(cfg: dict, *, d_inner: int = 5120, n_state: int = 16,
 def _latent_kernel_errors(cfg: dict, *, heads: int = 64, rank: int = 512,
                           rope: int = 64,
                           ctx=(100, 0, 5000, 8200, 0, 0, 10000, 3333),
-                          chunk: int = 512, interpret: bool = False) -> dict:
+                          chunk: int = 512, page: int = 16,
+                          interpret: bool = False) -> dict:
     """mla_decode_attention / mla_prefill_attention against the dense
     float32 form (mla_attention_dense) on the same random bf16 latent
     pool, at layer 1 of a 3-layer stacked pool: Kimi-K2's 64 heads over
@@ -1001,14 +1002,15 @@ def _latent_kernel_errors(cfg: dict, *, heads: int = 64, rank: int = 512,
     uneven contexts short to 10k with idle lanes (ctx 0: they must come
     back 0) among them, and a batch of chunks: one at offset 0, one row
     without a sequence, one behind a cached prefix of the second live
-    lane's tokens."""
+    lane's tokens. ``page``: the tokens of a page, 16, or the 64 that
+    'auto' gives a latent pool."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from tpu_inference.kernels import mla_attention as mla
 
-    page, width = 16, -(-(rank + rope) // 128) * 128
+    width = -(-(rank + rope) // 128) * 128
     b = len(ctx)
     mp = -(-(max(ctx) + chunk) // page)
     key = jax.random.split(jax.random.PRNGKey(cfg["seed"]), 3)
@@ -1521,6 +1523,22 @@ def child_parity(cfg: dict) -> dict:
               <= cfg["kernel_tol"],
               f"Pallas kernels at 28 heads on 4 KV heads, window 4096 and "
               f"none, vs dense float32: {res}")
+        # The 64-token page that 'auto' gives the pools under 32 KB a
+        # 16-token page (PR 48): [P, 64, 4, 128] and [P, 64, 640], at
+        # the tolerances of the 16-token cases.
+        res["wide_page_kernel_err"] = _mixed_kernel_errors(
+            cfg, kv_heads=4, kinds=((28, 4096), (28, 0)), lanes=4,
+            ctx=5000, rows=1024, page=64)
+        check(max(res["wide_page_kernel_err"].values())
+              <= cfg["kernel_tol"],
+              f"Pallas kernels on 64-token pages of 4 KV heads vs dense "
+              f"float32: {res}")
+        res["wide_page_latent_kernel_err"] = _latent_kernel_errors(
+            cfg, page=64)
+        check(max(res["wide_page_latent_kernel_err"].values())
+              <= cfg["latent_kernel_tol"],
+              f"latent-attention kernel on 64-token pages vs its dense "
+              f"float32 form: {res}")
     return {"ok": True, "layers": cfg["parity_layers"],
             "depth_cut": f"{cfg['parity_layers']} of the model's layers: "
                          "what a float32 reference fits beside on one chip",

@@ -188,6 +188,29 @@ def test_latent_kernel_check_in_interpret_mode():
     assert max(errs.values()) <= chip_smoke.SETTINGS["latent_kernel_tol"]
 
 
+@pytest.mark.parametrize("check", ["gqa", "latent"])
+def test_wide_page_kernel_checks_in_interpret_mode(check):
+    """The smoke's two checks at the 64-token page that 'auto' gives a
+    pool under 32 KB a 16-token page (run on the chip at [P, 64, 4, 128],
+    a window of 4096 and none, and at [P, 64, 640]) at small shapes
+    through the interpreter: the same code, the 16-token cases'
+    tolerances."""
+    if check == "gqa":
+        errs = chip_smoke._mixed_kernel_errors(
+            TINY, d=16, kv_heads=2, kinds=((8, 96), (8, 0)), slots=4,
+            lanes=4, ctx=300, rows=64, page=64, interpret=True)
+        assert set(errs) == {"h8w96_decode", "h8w96_prefill", "h8w0_decode",
+                             "h8w0_prefill"}
+        tol = chip_smoke.SETTINGS["kernel_tol"]
+    else:
+        errs = chip_smoke._latent_kernel_errors(
+            TINY, heads=4, rank=128, rope=16, ctx=(20, 0, 300, 0, 700, 41),
+            chunk=64, page=64, interpret=True)
+        assert set(errs) == {"latent_decode", "latent_prefill"}
+        tol = chip_smoke.SETTINGS["latent_kernel_tol"]
+    assert max(errs.values()) <= tol
+
+
 def test_mha_kernel_check_in_interpret_mode():
     """The smoke's check of the two GQA kernels at an MHA shape (run on
     the chip at Ouro-2.6B's: 16 heads x 128, a 16-slot pool, 12 lanes of

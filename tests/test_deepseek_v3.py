@@ -67,8 +67,8 @@ def tiny():
 
 
 def engine(mcfg, weights, **kw):
-    ecfg = EngineConfig(**{**dict(num_pages=128, max_pages_per_seq=24,
-                                  max_batch_size=4,
+    ecfg = EngineConfig(**{**dict(page_size=16, num_pages=128,
+                                  max_pages_per_seq=24, max_batch_size=4,
                                   prefill_buckets=(32, 64)), **kw})
     return InferenceEngine(mcfg, ecfg, params=weights,
                            pallas_interpret=kw.get("attn_backend")

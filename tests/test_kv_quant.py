@@ -27,7 +27,8 @@ from tpu_inference.config import (
 from tpu_inference.engine import kv_cache as kvc
 from tpu_inference.engine.engine import InferenceEngine
 
-BASE = dict(num_pages=64, max_batch_size=2, prefill_buckets=(64,),
+BASE = dict(page_size=16, num_pages=64, max_batch_size=2,
+            prefill_buckets=(64,),
             max_new_tokens=16)
 PROMPTS = [list(range(1, 20)), list(range(5, 40))]
 

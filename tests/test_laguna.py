@@ -457,7 +457,7 @@ REQ = dict(max_batch_size="auto", num_pages="auto", decode_ladder="off",
 
 def test_auto_sizing_splits_the_budget_by_kind():
     mcfg = PRESETS["laguna-s-ep8"]()
-    base = EngineConfig(max_pages_per_seq=832)
+    base = EngineConfig(page_size=16, max_pages_per_seq=832)
     e = autosize.resolve_sizing(mcfg, base, dict(REQ, target_ctx=4608),
                                 hbm_bytes=16e9)
     span = kvc.window_span_pages(mcfg, base)

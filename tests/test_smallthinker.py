@@ -342,7 +342,7 @@ REQ = dict(max_batch_size="auto", num_pages="auto", decode_ladder="auto",
 
 def test_the_window_pool_is_sized_on_live_tokens_under_the_window():
     mcfg = PRESETS["smallthinker-21b-pp4"]()
-    base = EngineConfig(max_pages_per_seq=512)
+    base = EngineConfig(page_size=16, max_pages_per_seq=512)
     span = kvc.window_span_pages(mcfg, base)
     assert span == (4096 + 1024) // 16 + 2 == 322
     e = autosize.resolve_sizing(

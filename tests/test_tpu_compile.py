@@ -93,33 +93,53 @@ def _pool(chip, hkv, d, kv_quant):
     return code, s((LAYERS, NUM_PAGES, PAGE, hkv), jnp.float32)
 
 
+def _compile_kernel(chip, pool, scale, hq, window, mp, b, seq=0):
+    """The decode kernel at ``b`` lanes (``seq`` 0) or the prefill kernel
+    at ``b`` rows of ``seq`` tokens over ``pool`` (K = V), compiled for
+    the chip: raises what its compiler would."""
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    d = pool.shape[-1] * (2 if pool.dtype == jnp.uint8 else 1)
+    if scale is not None:
+        scale = s(scale.shape[1:], scale.dtype)      # one layer's
+    if not seq:
+        lowered = paged_attention.lower(
+            s((b, hq, d), jnp.bfloat16), pool, pool, s((), jnp.int32),
+            s((b, mp), jnp.int32), s((b,), jnp.int32), scale, scale,
+            interpret=False, sliding_window=window)
+    else:
+        lowered = paged_prefill_attention.lower(
+            s((b, seq, hq, d), jnp.bfloat16), pool, pool, s((), jnp.int32),
+            s((b, mp), jnp.int32), s((b,), jnp.int32), s((b,), jnp.int32),
+            scale, scale, interpret=False, sliding_window=window)
+    assert "tpu_custom_call" in lowered.compile().as_text()
+
+
 @pytest.mark.parametrize("kv_quant", ["none", "int8", "int4"])
 @pytest.mark.parametrize("model", sorted(HEADS))
 @pytest.mark.parametrize("kernel", ["decode", "decode-widest", "prefill",
                                     "prefill-widest"])
 def test_kernel_compiles_for_v5e(chip, kernel, model, kv_quant):
     hq, hkv, d, window, mp, widest, widest_prefill = HEADS[model]
-
-    def s(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
-
     pool, scale = _pool(chip, hkv, d, kv_quant)
-    if scale is not None:
-        scale = s(scale.shape[1:], scale.dtype)      # one layer's
-    if not kernel.startswith("prefill"):
-        b = 8 if kernel == "decode" else widest
-        lowered = paged_attention.lower(
-            s((b, hq, d), jnp.bfloat16), pool, pool, s((), jnp.int32),
-            s((b, mp), jnp.int32), s((b,), jnp.int32), scale, scale,
-            interpret=False, sliding_window=window)
-    else:
-        b, seq = (1, 512) if kernel == "prefill" else widest_prefill
-        lowered = paged_prefill_attention.lower(
-            s((b, seq, hq, d), jnp.bfloat16), pool, pool, s((), jnp.int32),
-            s((b, mp), jnp.int32), s((b,), jnp.int32), s((b,), jnp.int32),
-            scale, scale, interpret=False, sliding_window=window)
-    compiled = lowered.compile()    # raises what the chip's compiler would
-    assert "tpu_custom_call" in compiled.as_text()
+    b, seq = {"decode": (8, 0), "decode-widest": (widest, 0),
+              "prefill": (1, 512), "prefill-widest": widest_prefill}[kernel]
+    _compile_kernel(chip, pool, scale, hq, window, mp, b, seq)
+
+
+# The 64-token page 'auto' gives a pool whose 16-token page is under
+# 32 KB (autosize.resolve_page_size): the GQA kernels at 4 KV heads under
+# SmallThinker's window of 4096 and Qwen2's none, the widest rung and the
+# largest and smallest prefill graphs of their cells, with the caps those
+# cells' flags come to (8192 and 3072 tokens).
+@pytest.mark.parametrize("b,seq", [(64, 0), (8, 0), (1, 1024), (4, 512),
+                                   (4, 64)])
+@pytest.mark.parametrize("window,mp", [(4096, 128), (0, 48)])
+def test_kernel_compiles_for_v5e_at_the_wide_page(chip, b, seq, window, mp):
+    pool = jax.ShapeDtypeStruct((12, NUM_PAGES, 64, 4, 128), jnp.bfloat16,
+                                sharding=chip)
+    _compile_kernel(chip, pool, None, 28, window, mp, b, seq)
 
 
 @pytest.mark.parametrize("kernel,kv_quant,layer,model", [
@@ -211,17 +231,20 @@ KIMI = dict(heads=64, rank=512, rope=64, width=640, layers=7, mp=672,
             d=7168, f=2048, held=12, expert_layers=6)
 
 
+@pytest.mark.parametrize("page", [16, 64])
 @pytest.mark.parametrize("kernel,b,seq", [("decode", 32, 1),
                                           ("decode", 8, 1),
                                           ("prefill", 1, 1024),
                                           ("prefill", 4, 64)])
 def test_latent_kernel_compiles_for_v5e_and_reads_the_pool_in_place(
-        chip, kernel, b, seq):
+        chip, kernel, b, seq, page):
     """Inside the model's pattern: the donated stacked latent pool carried
     through a scan over layers, scattered by ``write_latent`` right before
     the kernel reads it at the scan's index. No instruction may produce
     one layer's pool or copy the stacked one (a 576-wide pool WOULD be
-    copied whole: kernels/mla_attention.py)."""
+    copied whole: kernels/mla_attention.py). At the 16-token page and
+    at the 64-token one that 'auto' gives a latent pool (20 KB a
+    16-token page), the cap the same 10752 tokens."""
     from tpu_inference.engine import kv_cache as kvc
     from tpu_inference.kernels import mla_attention as mla
 
@@ -234,7 +257,8 @@ def test_latent_kernel_compiles_for_v5e_and_reads_the_pool_in_place(
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
-    pool = s((k["layers"], NUM_PAGES * 8, PAGE, k["width"]), jnp.bfloat16)
+    pool = s((k["layers"], NUM_PAGES * 8 * PAGE // page, page, k["width"]),
+             jnp.bfloat16)
     kv = kvc.KVPages(k=pool, v=None)
 
     def step(kv, q, entry, bt, kv_len, slots):
@@ -258,7 +282,7 @@ def test_latent_kernel_compiles_for_v5e_and_reads_the_pool_in_place(
     compiled = jax.jit(step, donate_argnums=(0,)).lower(
         kv, s((b, seq, k["heads"], k["rank"] + k["rope"]), jnp.bfloat16),
         s((b, seq, k["rank"] + k["rope"]), jnp.bfloat16),
-        s((b, k["mp"]), jnp.int32), s((b,), jnp.int32),
+        s((b, k["mp"] * PAGE // page), jnp.int32), s((b,), jnp.int32),
         s((b, seq), jnp.int32)).compile()
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo

@@ -855,19 +855,31 @@ class ParallelConfig:
         return self.dp * self.tp * self.sp
 
 
+# What a page count means while ``EngineConfig.page_size`` is None: the
+# 16-token page every configuration file and flag was written in.
+KV_PAGE_UNIT = 16
+
+
 @dataclasses.dataclass(frozen=True)
 class EngineConfig:
     """Serving-engine knobs: paging, batching, bucketing."""
 
-    # Paged KV cache.
-    page_size: int = 16               # tokens per KV page
+    # Paged KV cache. Tokens per KV page: an integer is taken as given;
+    # None ("--page-size auto") is chosen ONCE from the bytes of a page
+    # and the backend that reads the pool (engine/autosize.py
+    # resolve_page_size), before anything else reads this config. While
+    # it is None the three page counts below are in KV_PAGE_UNIT-token
+    # units, and the resolver restates them in pages of the chosen size.
+    page_size: Optional[int] = None
     num_pages: int = 512              # pool size (per chip, per model)
     # A model whose layers differ in kind (ModelConfig.layer_types) has a
     # second pool for its window layers; ``num_pages`` is then the full
     # kind's. 0 = every lane's window span (engine.window_span_pages),
     # which is also what 'auto' sizing gives it.
     num_window_pages: int = 0
-    max_pages_per_seq: int = 64       # => max context = page_size * this
+    # => max context = page_size * this (16 tokens * this while
+    # page_size is None, rounded up to a whole page of the chosen size)
+    max_pages_per_seq: int = 64
     # Continuous batching.
     max_batch_size: int = 8           # decode slots in the batched graph
     # Compiled decode-graph ladder (README "Batch ladder"): batch sizes
@@ -1091,7 +1103,7 @@ class EngineConfig:
 
     @property
     def max_context(self) -> int:
-        return self.page_size * self.max_pages_per_seq
+        return (self.page_size or KV_PAGE_UNIT) * self.max_pages_per_seq
 
     @property
     def ladder_rungs(self) -> tuple:

@@ -35,6 +35,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional
 
+from tpu_inference.config import KV_PAGE_UNIT
 from tpu_inference.engine.kv_cache import (window_span_pages,
                                            written_ahead_tokens)
 
@@ -177,6 +178,87 @@ def kv_bytes_per_token(model_cfg, kv_quant: str = "none",
     if kv_quant == "int4":
         return 2 * L * hkv * (d // 2 + 4)
     return 2 * L * hkv * d * 2
+
+
+# The tokens of a KV page, where nobody gave a number. The Pallas decode
+# kernels bring the pool in one DMA descriptor a page a pool, issued and
+# waited for by the scalar core in the fold's own instruction stream:
+# ~45 ns a descriptor whatever it carries, where a 16 KB page needs 20 ns
+# of HBM. On the v5e one kernel (kernels/paged_attention.py) read 43% of
+# its roofline at 4 KV heads (16 KB a 16-token page: SmallThinker) and
+# 77-81% at 8 and 16 heads (32 / 64 KB: Mistral doc, Ouro); the latent
+# kernel 55-59% at 20 KB (Kimi-K2, Xing) (ledger, PR 47). So a page under
+# SMALL_PAGE_BYTES at 16 tokens is made WIDE_PAGE_TOKENS long, which puts
+# it at 64-80 KB: where the large pages already are (by hand at 32 / 64 /
+# 128 tokens in cell 8: PERF.md section 6, PR 48).
+SMALL_PAGE_BYTES = 32 << 10
+WIDE_PAGE_TOKENS = 64
+
+
+def page_bytes(model_cfg, tokens: int, kv_quant: str = "none",
+               tp: int = 1) -> int:
+    """Bytes ONE pool's page of ``tokens`` tokens holds on one chip, in
+    one layer: K's (V's are the same), or the latent entries'. What one
+    DMA descriptor of a decode kernel carries."""
+    if model_cfg.latent_dim:
+        from tpu_inference.engine.kv_cache import latent_width
+        return tokens * latent_width(model_cfg) * 2
+    heads = max(1, model_cfg.pool_kv_heads // max(1, tp))
+    d = model_cfg.pool_head_dim
+    row = {"int8": d, "int4": d // 2}.get(kv_quant, 2 * d)
+    return tokens * heads * row
+
+
+def pallas_reads_pool(attn_backend: str,
+                      platform: Optional[str] = None) -> bool:
+    """Whether ``attn_backend`` comes to the Pallas kernels. 'auto' is
+    Pallas on a TPU: by ``platform`` where the caller has the CLI's
+    --platform (anything but 'cpu' is a TPU or no server at all,
+    runtime.require_backend) and may not touch JAX, else by jax's
+    default backend."""
+    if attn_backend != "auto":
+        return attn_backend == "pallas"
+    if platform is not None:
+        return platform != "cpu"
+    import jax
+
+    return jax.default_backend() == "tpu"
+
+
+def auto_page_tokens(model_cfg, *, pallas: bool, kv_quant: str = "none",
+                     tp: int = 1) -> int:
+    """THE rule, from two things the program can observe:
+    WIDE_PAGE_TOKENS where the Pallas kernels read the pool (only there
+    is a page a DMA descriptor) and a KV_PAGE_UNIT-token page is under
+    SMALL_PAGE_BYTES, else KV_PAGE_UNIT."""
+    small = page_bytes(model_cfg, KV_PAGE_UNIT, kv_quant,
+                       tp) < SMALL_PAGE_BYTES
+    return WIDE_PAGE_TOKENS if pallas and small else KV_PAGE_UNIT
+
+
+def resolve_page_size(model_cfg, engine_cfg, *, tp: int = 1,
+                      pallas: Optional[bool] = None):
+    """EngineConfig with ``page_size`` an integer. One that is given
+    stays, and nothing else changes. None becomes ``auto_page_tokens``,
+    and the page counts, written in KV_PAGE_UNIT-token units until now,
+    become pages of the chosen size: the context cap rounded UP to a
+    whole page, the pools DOWN.
+
+    ``pallas``: whether the Pallas backend reads the pool, for a caller
+    that knows (the engine's constructor; a router that must stay off
+    JAX, from its --platform); None asks ``pallas_reads_pool``."""
+    if engine_cfg.page_size is not None:
+        return engine_cfg
+    if pallas is None:
+        pallas = pallas_reads_pool(engine_cfg.attn_backend)
+    page = auto_page_tokens(model_cfg, pallas=pallas,
+                            kv_quant=engine_cfg.kv_quant, tp=tp)
+    return dataclasses.replace(
+        engine_cfg, page_size=page,
+        max_pages_per_seq=-(-engine_cfg.max_pages_per_seq * KV_PAGE_UNIT
+                            // page),
+        num_pages=engine_cfg.num_pages * KV_PAGE_UNIT // page,
+        num_window_pages=engine_cfg.num_window_pages * KV_PAGE_UNIT // page)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -572,8 +654,11 @@ def sizing_request(args) -> dict:
     'auto' in either size that waits for ``resolve_sizing`` in the
     process that owns the device. JSON-able: the subprocess fleet ships
     it to its workers."""
+    page = getattr(args, "page_size", "auto")
     req = {"max_batch_size": args.max_batch_size,
            "num_pages": args.num_pages,
+           # the tokens a page of a numeric --num-pages holds
+           "pages_of": KV_PAGE_UNIT if page == "auto" else page,
            "decode_ladder": getattr(args, "decode_ladder", "off"),
            "target_ctx": getattr(args, "target_ctx", 0),
            "batch_cap": getattr(args, "batch_cap", 32)}
@@ -587,9 +672,17 @@ def resolve_sizing(model_cfg, engine_cfg, req: Optional[dict], *,
     """EngineConfig with ``req``'s batch, pool and ladder filled in.
     'auto' sizes come from ``hbm_bytes`` (default: the visible device's
     own figure — so this runs in the process that owns the chip)."""
+    unit = engine_cfg.page_size or KV_PAGE_UNIT
+    engine_cfg = resolve_page_size(model_cfg, engine_cfg, tp=tp)
     if req is None:
         return engine_cfg
     mbs, pages = req["max_batch_size"], req["num_pages"]
+    if pages != "auto":
+        # (A number counts pages of ``pages_of`` tokens: 16 where the
+        # page size was left to the program, which may have chosen
+        # another; a router that settled the page for its workers says
+        # so in the request.)
+        pages = pages * req.get("pages_of", unit) // engine_cfg.page_size
     if "auto" in (mbs, pages):
         sz = auto_size(
             model_cfg,
@@ -615,6 +708,7 @@ def resolve_sizing(model_cfg, engine_cfg, req: Optional[dict], *,
             engine_cfg = dataclasses.replace(
                 engine_cfg, num_window_pages=sz.num_window_pages)
         print(f"[autosize] {model_cfg.name}: batch={mbs} num_pages={pages} "
+              f"page_tokens={engine_cfg.page_size} "
               + (f"num_window_pages={sz.num_window_pages} "
                  if sz.num_window_pages else "") +
               f"(hbm {sz.hbm_bytes / 1e9:.2f} GB, weights/chip "

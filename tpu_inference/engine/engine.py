@@ -37,6 +37,7 @@ from tpu_inference.config import (EngineConfig, ModelConfig,
                                   validate_spec_config)
 from tpu_inference.engine import kv_cache as kvc
 from tpu_inference.engine import staging
+from tpu_inference.engine.autosize import resolve_page_size
 from tpu_inference.engine.kv_cache import KVPages, PageAllocator
 from tpu_inference.engine.sampling import (
     PENALTY_WINDOW,
@@ -730,6 +731,12 @@ class InferenceEngine:
                 "InferenceEngine(..., pallas_interpret=True) to run the "
                 "kernels in interpret mode for a test")
         self._pallas_interpret = pallas_interpret
+        # An engine built directly (no resolve_sizing before it) settles
+        # the tokens of a page here, before anything reads them: a no-op
+        # on a config that names them.
+        engine_cfg = self.engine_cfg = resolve_page_size(
+            model_cfg, engine_cfg, pallas=backend == "pallas",
+            tp=mesh.shape.get("tp", 1) if mesh is not None else 1)
         # Validate mesh compatibility BEFORE materializing params —
         # at 70B scale a post-init failure wastes minutes (or OOMs).
         if mesh is not None:
@@ -1425,6 +1432,7 @@ class InferenceEngine:
             "attn_backend": self.attn_backend,
             "max_batch_size": self.engine_cfg.max_batch_size,
             "num_pages": self.engine_cfg.num_pages,
+            "page_size": self.engine_cfg.page_size,
             "ladder": list(self.ladder),
             "warmup_s": round(self.warmup_s, 3),
             "warmup_graphs": self.warmup_graphs,

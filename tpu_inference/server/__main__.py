@@ -59,9 +59,18 @@ def main() -> None:
                         "max); batch = KV tokens / this, capped")
     p.add_argument("--batch-cap", type=int, default=32,
                    help="upper bound for --max-batch-size auto")
-    p.add_argument("--page-size", type=int, default=16)
+    p.add_argument("--page-size", type=int_or_auto, default="auto",
+                   help="tokens per KV page, or 'auto': 64 where the "
+                        "Pallas kernels read the pool and a 16-token "
+                        "page holds under 32 KB (a page is one DMA "
+                        "descriptor there), else 16. Under 'auto' "
+                        "--max-pages-per-seq and a numeric --num-pages "
+                        "count 16-token pages and are restated in pages "
+                        "of the size chosen")
     p.add_argument("--max-pages-per-seq", type=int, default=64,
-                   help="max context = page-size * this")
+                   help="max context = page-size * this (16 tokens * "
+                        "this under --page-size auto, rounded up to a "
+                        "whole page)")
     p.add_argument("--tp", type=int, default=1,
                    help="tensor-parallel degree (devices in the mesh)")
     p.add_argument("--sp", type=int, default=1,
@@ -536,18 +545,25 @@ def main() -> None:
     except ValueError as e:
         p.error(str(e))
 
+    page_size = None if args.page_size == "auto" else args.page_size
     host_cache_pages = args.host_cache_pages
     if host_cache_pages == "auto":
         from tpu_inference.engine.autosize import (
-            auto_host_cache_pages, resolve_model_config)
+            auto_host_cache_pages, auto_page_tokens, pallas_reads_pool,
+            resolve_model_config)
 
+        model_cfg = resolve_model_config(args.model, args.checkpoint)
+        # (The page this server will come to, without touching JAX: the
+        # rule resolve_sizing applies where the device is.)
+        host_page = page_size or auto_page_tokens(
+            model_cfg, kv_quant=args.kv_quant, tp=args.tp,
+            pallas=pallas_reads_pool(args.attn_backend, args.platform))
         # Every dp replica builds its OWN host pool from this one
         # EngineConfig — divide the machine budget so the fleet's tiers
         # together stay inside available RAM.
         host_cache_pages = auto_host_cache_pages(
-            resolve_model_config(args.model, args.checkpoint),
-            kv_quant=args.kv_quant,
-            page_size=args.page_size) // max(1, args.dp)
+            model_cfg, kv_quant=args.kv_quant,
+            page_size=host_page) // max(1, args.dp)
         import sys
 
         print(f"[autosize] host KV tier: {host_cache_pages} pages/replica "
@@ -642,7 +658,7 @@ def main() -> None:
                           host_cache_pages=host_cache_pages,
                           slo_ttft_ms=args.slo_ttft_ms,
                           slo_tpot_ms=args.slo_tpot_ms,
-                          page_size=args.page_size,
+                          page_size=page_size,
                           max_pages_per_seq=args.max_pages_per_seq,
                           decode_pipeline_depth=args.decode_pipeline_depth,
                           chunked_prefill_size=args.chunked_prefill_size,

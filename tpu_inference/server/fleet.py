@@ -54,6 +54,7 @@ from tpu_inference.config import (FrameworkConfig, class_rank,
                                   framework_config_to_dict,
                                   resolve_worker_roles)
 from tpu_inference.engine import kv_cache as kvc
+from tpu_inference.engine.autosize import pallas_reads_pool, resolve_page_size
 from tpu_inference.engine.engine import Sequence
 from tpu_inference.engine.prefix_cache import _chain_hashes
 from tpu_inference.runtime import chip_env
@@ -392,6 +393,15 @@ class ProcessEngineGroup:
         router — never initialises a JAX backend, so the chips stay
         free for the workers."""
         pcfg = cfg.parallel
+        # The tokens of a page are settled HERE, from what the router
+        # can see without a backend (the attention backend asked for and
+        # --platform; with neither named, 'auto' counts as no TPU), and
+        # ship to the workers as a number inside the config: the
+        # router's prefix digests and the workers' pools cannot drift.
+        cfg.engine = resolve_page_size(
+            cfg.model, cfg.engine, tp=pcfg.tp,
+            pallas=pallas_reads_pool(cfg.engine.attn_backend,
+                                     platform or "cpu"))
         self.cfg = cfg
         self._worker_platform = platform
         self._worker_sizing = sizing

@@ -2596,6 +2596,11 @@ class EngineTelemetry:
                   fn=lambda: alloc.pages_freed_total)
         r.gauge("tpu_inf_kv_pages_total", "Allocatable KV pool pages",
                 fn=lambda: total)
+        page_tokens = engine.engine_cfg.page_size
+        r.gauge("tpu_inf_kv_page_tokens",
+                "Tokens a KV page holds (given, or chosen from the "
+                "page's bytes: autosize.resolve_page_size)",
+                fn=lambda: page_tokens)
         r.gauge("tpu_inf_kv_pages_in_use", "KV pool pages in use",
                 fn=lambda: total - alloc.num_free)
         r.gauge("tpu_inf_kv_page_util",
