@@ -990,6 +990,93 @@ def _sambay_kernel_errors(cfg: dict, *, d_inner: int = 5120, n_state: int = 16,
     return {k: round(v, 6) for k, v in out.items()}
 
 
+def _kv_rows_write_check(cfg: dict, *, pools=(("window", 8), ("full", 1)),
+                         pages: int = 1024, page: int = 16, heads: int = 10,
+                         d: int = 128, lanes: int = 64, reps: int = 200,
+                         interpret: bool = False) -> dict:
+    """A decode step's K / V write into merged-row pools
+    (kernels/kv_rows_write.py) against the scatter it replaces
+    (``kv_cache.write_kv_rows``) on the same random bf16 pools at cell
+    7's shapes: Phi-4-mini-flash's 10 pair heads x 128 in 16-token pages
+    of 160 rows, 64 lanes, the window kind's 8 slots and the full kind's
+    one (``pages`` a pool: neither path's cost knows the pool's extent;
+    the cell's are 6273 and 22104). A token a lane on a page of its own,
+    one at its page's last position (the span clamped to the page's
+    end), one at its first, three lanes without a token (the trash page,
+    which is left out of the comparison: which of them lands last is
+    nobody's business). ``<kind>_err``: elements that differ, at either
+    end of the slots; ``<kind>_kernel_us`` / ``<kind>_scatter_us``: a
+    call (K and V) inside a loop that carries the donated pools, the
+    slot moving with the trip, as the difference of 2 x ``reps`` trips
+    and ``reps``, the best of five."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tpu_inference.engine import kv_cache as kvc
+    from tpu_inference.kernels.kv_rows_write import kv_rows_write
+
+    rng = np.random.default_rng(cfg["seed"])
+    rows = page * heads
+    at = rng.permutation(np.arange(1, pages))[:lanes]
+    tok = rng.integers(0, page, lanes)
+    tok[:2] = page - 1, 0
+    starts = (at * page + tok) * heads
+    starts[[3, 5, 6][:max(0, lanes - 3)]] = 0
+    starts = jnp.asarray(starts, jnp.int32)
+    keys = jax.random.split(jax.random.PRNGKey(cfg["seed"] & 0x7FFFFFFF), 4)
+    new = [jax.random.normal(k, (lanes, heads, d), jnp.bfloat16)
+           for k in keys[:2]]
+
+    def kernel(slot, k, v):
+        return kv_rows_write(k, v, slot, *new, starts, interpret=interpret)
+
+    def scatter(slot, k, v):
+        return tuple(kvc.write_kv_rows(pool, slot, x, starts)
+                     for pool, x in zip((k, v), new))
+
+    def per_trip_us(write, slots, fresh):
+        run = jax.jit(lambda n, k, v: jax.lax.fori_loop(
+            0, n, lambda i, c: write(jax.lax.rem(i, slots), *c), (k, v)),
+            donate_argnums=(1, 2))
+        best = {}
+        for n in (reps, 2 * reps):
+            took = []
+            for _ in range(6):                  # the first one compiles
+                pools = jax.block_until_ready(fresh())
+                t0 = time.perf_counter()
+                jax.block_until_ready(run(n, *pools))
+                took.append(time.perf_counter() - t0)
+            best[n] = min(took[1:])
+        return round((best[2 * reps] - best[reps]) / reps * 1e6, 2)
+
+    out = {}
+    for kind, slots in pools:
+        shape = (slots, pages, rows, d)
+        fresh = jax.jit(lambda shape=shape: tuple(
+            jax.random.normal(k, shape, jnp.bfloat16) for k in keys[2:]))
+        differ = jax.jit(lambda a, b: sum(
+            jnp.sum(x[:, 1:].view(jnp.uint16) != y[:, 1:].view(jnp.uint16))
+            for x, y in zip(a, b)))
+        out[f"{kind}_err"] = sum(
+            int(differ(kernel(jnp.int32(slot), *fresh()),
+                       scatter(jnp.int32(slot), *fresh())))
+            for slot in {0, slots - 1})
+        # (A write that did nothing must not read 0 against a scatter
+        # that did nothing: the reference moves a token's rows a live
+        # lane, less the few bf16 values that were there by chance.)
+        moved = int(differ(fresh(), scatter(jnp.int32(0), *fresh())))
+        wrote = 2 * int(jnp.sum(starts > 0)) * heads * d
+        check(0.9 * wrote < moved <= wrote,
+              f"the scatter moved {moved} elements of the {wrote} it wrote")
+        if reps:
+            out[f"{kind}_kernel_us"] = per_trip_us(kernel, slots, fresh)
+            out[f"{kind}_scatter_us"] = per_trip_us(scatter, slots, fresh)
+    return out
+
+
 def _latent_kernel_errors(cfg: dict, *, heads: int = 64, rank: int = 512,
                           rope: int = 64,
                           ctx=(100, 0, 5000, 8200, 0, 0, 10000, 3333),
@@ -1487,6 +1574,12 @@ def child_parity(cfg: dict) -> dict:
         check(max(res["sambay_kernel_err"].values()) <= cfg["kernel_tol"],
               f"selective scan vs lax.scan / GQA kernels at the pair-head "
               f"shapes vs dense float32: {res}")
+        # A decode step's K / V write into merged-row pools: the pool
+        # the scatter gives, bit for bit, and what a call of each costs.
+        res["kv_rows_write_err"] = _kv_rows_write_check(cfg)
+        check(all(v == 0 for k, v in res["kv_rows_write_err"].items()
+                  if k.endswith("_err")),
+              f"kv_rows_write vs the scatter it replaces: {res}")
         res["latent_kernel_err"] = _latent_kernel_errors(cfg)
         check(max(res["latent_kernel_err"].values())
               <= cfg["latent_kernel_tol"],

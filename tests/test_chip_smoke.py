@@ -273,6 +273,16 @@ def test_sambay_kernel_check_in_interpret_mode():
     assert max(errs.values()) <= chip_smoke.SETTINGS["kernel_tol"]
 
 
+def test_kv_rows_write_check_in_interpret_mode():
+    """The smoke's check of a decode step's K / V write into merged-row
+    pools (run on the chip at cell 7's shapes, 64 lanes, timed) at a few
+    lanes and pages through the interpreter, untimed: the kernel's pools
+    are the scatter's, bit for bit."""
+    errs = chip_smoke._kv_rows_write_check(TINY, pages=12, lanes=8, reps=0,
+                                           interpret=True)
+    assert errs == {"window_err": 0, "full_err": 0}
+
+
 def test_routed_expert_check_in_interpret_mode():
     """The smoke's routed-expert check (run on the chip at Kimi-K2's
     widths, layer 1 of a 2-layer stack) at the tiny preset through the

@@ -252,20 +252,28 @@ def test_the_references_bias_decides_the_held_experts():
 
 
 # ------------------------------------------------------------------ (c)
-def test_merged_row_pools_decode_what_five_dim_pools_decode():
+@pytest.mark.parametrize("backend", ["dense", "pallas"])
+def test_merged_row_pools_decode_what_five_dim_pools_decode(backend):
     """``ModelConfig.pool_rows_merged`` is a layout of a stack of kinds'
     pools, not one family's: this stack on pools ``[slots, P, page *
     heads, width]`` (written a page at a time in a prefill, a token at a
-    time in a decode step) samples the tokens it samples on five-dim
-    ones, over chunked prompts that release window pages."""
+    time in a decode step: by the scatter under the dense backend, by
+    kernels/kv_rows_write.py under the Pallas one, here in interpret
+    mode) samples the tokens it samples on five-dim ones, over chunked
+    prompts that release window pages."""
     mcfg, _, weights = tiny()
     rng = np.random.default_rng(3)
     prompts = [[int(t) for t in rng.integers(0, 512, n)] for n in (11, 70)]
     got = {}
     for merged in (False, True):
+        # (The kernel copies whole 8-row float32 tiles: 8-token pages of
+        # this stack's 3 KV heads are three of them, 4-token pages 1.5.)
         eng = engine(dataclasses.replace(mcfg, pool_rows_merged=merged),
-                     weights)
+                     weights, attn_backend=backend,
+                     page_size=8 if backend == "pallas" else 4)
         assert eng.kv.wk.ndim == (4 if merged else 5)
+        assert eng.device_info()["kv_decode_write"] == (
+            "kernel" if merged and backend == "pallas" else "scatter")
         seqs = [Sequence(request_id=i, prompt_tokens=p, max_new_tokens=10)
                 for i, p in enumerate(prompts)]
         for s in seqs:

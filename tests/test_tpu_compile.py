@@ -680,3 +680,87 @@ def test_the_trace_readers_shape_table_follows_the_mhc_scopes(chip, rows):
                 scope, name)
     assert {"mhc_coeff", "mhc_sinkhorn", "mhc_post_mix"} <= set(told), told
 
+
+
+# ---------------------------------------------------------------------------
+# Phi-4-mini-flash: a decode step's K / V write into pools allocated with
+# merged rows (kernels/kv_rows_write.py) at cell 7's shapes: 10 pair heads
+# of 128, 16-token pages of 160 rows, the window kind's 8 slots and the
+# full kind's one, at the top rung and the base one.
+# ---------------------------------------------------------------------------
+
+PHI4 = dict(pair_q=20, pair_kv=10, d=128, rows=PAGE * 10, window=512,
+            pools={"window": (8, 6273, 98), "full": (1, 22104, 640)})
+
+
+def _phi4_pool(chip, kind):
+    slots, pages, _ = PHI4["pools"][kind]
+    return jax.ShapeDtypeStruct((slots, pages, PHI4["rows"], PHI4["d"]),
+                                jnp.bfloat16, sharding=chip)
+
+
+@pytest.mark.parametrize("lanes", [64, 8])
+@pytest.mark.parametrize("kind", sorted(PHI4["pools"]))
+def test_the_decode_write_kernel_compiles_for_v5e(chip, kind, lanes):
+    from tpu_inference.kernels.kv_rows_write import kv_rows_write
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    pool = _phi4_pool(chip, kind)
+    new = s((lanes, PHI4["pair_kv"], PHI4["d"]), jnp.bfloat16)
+    hlo = kv_rows_write.lower(pool, pool, s((), jnp.int32), new, new,
+                              s((lanes,), jnp.int32)).compile().as_text()
+    assert "tpu_custom_call" in hlo and "kv_rows_write" in hlo
+
+
+@pytest.mark.parametrize("kind", sorted(PHI4["pools"]))
+def test_a_layer_loop_writes_merged_row_pools_in_place(chip, kind):
+    """``engine.make_kind_attn``'s ``merged_attn`` in a decode step,
+    compiled for the v5e: the donated merged-row pools are written by the
+    kernel and then read by the decode kernel through their five-dim
+    view, under a ``lax.scan`` over the kind's slots that carries them
+    (the full kind's one slot: a loop of one trip). The chip compiler's
+    HLO may hold no one-layer slice of a pool and no copy of one, in any
+    of the three views the two kernels take (as allocated, pages laid end
+    to end, five-dim)."""
+    from tpu_inference.kernels.kv_rows_write import kv_rows_write
+
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir,
+                                    "benchmarks"))
+    from aot_rehearsal import pool_copies
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    slots, pages, mp = PHI4["pools"][kind]
+    hq, hkv, d, b = PHI4["pair_q"], PHI4["pair_kv"], PHI4["d"], 64
+    window = PHI4["window"] if kind == "window" else 0
+    pool = _phi4_pool(chip, kind)
+    view = (slots, pages, PAGE, hkv, d)
+
+    def step(k_pool, v_pool, q, k_new, v_new, bt, kv_len, starts):
+        def body(carry, slot):
+            k_pool, v_pool, q = carry
+            k_pool, v_pool = kv_rows_write(k_pool, v_pool, slot, k_new,
+                                           v_new, starts)
+            out = paged_attention(q, k_pool.reshape(view),
+                                  v_pool.reshape(view), slot, bt, kv_len,
+                                  sliding_window=window)
+            return (k_pool, v_pool, out), None
+
+        return jax.lax.scan(body, (k_pool, v_pool, q), jnp.arange(slots))[0]
+
+    hlo = jax.jit(step, donate_argnums=(0, 1)).lower(
+        pool, pool, s((b, hq, d), jnp.bfloat16),
+        s((b, hkv, d), jnp.bfloat16), s((b, hkv, d), jnp.bfloat16),
+        s((b, mp), jnp.int32), s((b,), jnp.int32),
+        s((b,), jnp.int32)).compile().as_text()
+    assert "kv_rows_write" in hlo and "paged_attention" in hlo
+    for shape in (pool.shape, view, (slots, pages * PHI4["rows"], d)):
+        found = pool_copies(hlo, shape)
+        if slots == 1:
+            # One slot IS the pool: the kernels' own results have "one
+            # layer's" shape, so only a copy says anything.
+            found = [f for f in found if f.startswith("copy ")]
+        assert found == [], (shape, found)

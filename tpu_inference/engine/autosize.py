@@ -36,7 +36,8 @@ import dataclasses
 from typing import Optional
 
 from tpu_inference.config import KV_PAGE_UNIT
-from tpu_inference.engine.kv_cache import (window_span_pages,
+from tpu_inference.engine.kv_cache import (decode_write_path,
+                                           window_span_pages,
                                            written_ahead_tokens)
 
 
@@ -707,8 +708,11 @@ def resolve_sizing(model_cfg, engine_cfg, req: Optional[dict], *,
                 mbs * window_span_pages(model_cfg, engine_cfg) + 1):
             engine_cfg = dataclasses.replace(
                 engine_cfg, num_window_pages=sz.num_window_pages)
+        write = decode_write_path(
+            model_cfg, pallas_reads_pool(engine_cfg.attn_backend))
         print(f"[autosize] {model_cfg.name}: batch={mbs} num_pages={pages} "
               f"page_tokens={engine_cfg.page_size} "
+              f"kv_decode_write={write} "
               + (f"num_window_pages={sz.num_window_pages} "
                  if sz.num_window_pages else "") +
               f"(hbm {sz.hbm_bytes / 1e9:.2f} GB, weights/chip "

@@ -373,8 +373,18 @@ def make_kind_attn(cfg: ModelConfig, page_size: int, block_tables: jax.Array,
                         table, off, jnp.sum(ok, axis=1), s // page_size,
                         page_size, page_size * h)
                     shape = (b * s // page_size, page_size * h, d)
-                pk = kvc.write_kv_rows(pk, slot, k.reshape(shape), starts)
-                pv = kvc.write_kv_rows(pv, slot, v.reshape(shape), starts)
+                if s == 1 and kvc.decode_write_path(cfg, pallas) == "kernel":
+                    # One token a lane, so no two windows share a page
+                    # (n-gram verification's several tokens a lane would).
+                    from tpu_inference.kernels.kv_rows_write import (
+                        kv_rows_write)
+
+                    pk, pv = kv_rows_write(
+                        pk, pv, slot, k.reshape(shape), v.reshape(shape),
+                        starts.reshape(-1), interpret=interpret)
+                else:
+                    pk = kvc.write_kv_rows(pk, slot, k.reshape(shape), starts)
+                    pv = kvc.write_kv_rows(pv, slot, v.reshape(shape), starts)
                 kv = kv._replace(**{pool_k: pk, pool_v: pv})
             view = pk.shape[:2] + (page_size, cfg.pool_kv_heads,
                                    cfg.pool_head_dim)
@@ -1433,6 +1443,8 @@ class InferenceEngine:
             "max_batch_size": self.engine_cfg.max_batch_size,
             "num_pages": self.engine_cfg.num_pages,
             "page_size": self.engine_cfg.page_size,
+            "kv_decode_write": kvc.decode_write_path(
+                self.model_cfg, self.attn_backend == "pallas"),
             "ladder": list(self.ladder),
             "warmup_s": round(self.warmup_s, 3),
             "warmup_graphs": self.warmup_graphs,

@@ -411,6 +411,16 @@ def write_kv_rows(pool: jax.Array, layer_idx: jax.Array, new: jax.Array,
     return flat.reshape(L, P, R, D)
 
 
+def decode_write_path(model_cfg: ModelConfig, pallas: bool) -> str:
+    """How a decode step's K / V reach the pool, fixed when its program is
+    built: "kernel" (kernels/kv_rows_write.py: one call a layer, every
+    lane's copy in flight together) where the pool has merged rows and
+    the Pallas backend reads it, else "scatter" (``write_kv_rows`` /
+    ``write_kv``: the kernel's reference, and every other write's path).
+    /healthz ``device.kv_decode_write`` and the [autosize] line say it."""
+    return "kernel" if model_cfg.pool_rows_merged and pallas else "scatter"
+
+
 def page_starts(block_tables: jax.Array, first_pos: jax.Array,
                 n_valid: jax.Array, n_pages: int, page_size: int,
                 rows: int) -> jax.Array:
