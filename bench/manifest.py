@@ -5,15 +5,21 @@ by name. A later PR adds one by adding files and one manifest entry:
 
     configuration   the ``file`` its ``configs`` entry names
     traffic mix     <root>/traffic/<traffic>.json
-    per-layer       <root>/layer_metrics/<reading>.json, which names a
-    metric          reader, <root>/readers/<reader>.py, and its arguments
+    per-layer       the cell's configuration's ``readings[<reading>]``, else
+    metric          <root>/layer_metrics/<reading>.json: either names a
+                    reader, <root>/readers/<reader>.py, and its arguments
     probe           <root>/probes/<probe>.py, named by the configuration's
                     ``parity.probe`` (none named: ``refeed``)
 
-A per-layer metric ``<reading>.<suffix>`` (one quantity split by the
-end-to-end metric it moves in different cells, or a ``.watch`` candidate)
-reads ``<reading>.json``: one file a reading. Unit, layer, ``moves`` and
-cells of each name are the manifest entry's, where the contract puts them.
+One ``per_layer`` entry a reading, whatever the architecture: a
+configuration that computes the reading another way (a latent pool, a pool
+a kind, a looped stack) names its reader under ``readings`` in its own
+file, which the PR that adds the configuration brings; the file under
+``layer_metrics`` is the reading's default. A per-layer metric
+``<reading>.<suffix>`` (one quantity split by the end-to-end metric it
+moves in different cells, or a ``.watch`` candidate) reads ``<reading>``:
+one spec a reading and configuration. Unit, layer, ``moves`` and cells of
+each name are the manifest entry's, where the contract puts them.
 
 ``<root>`` is the manifest's first ``paths`` entry. A test manifest may
 carry ``_root`` to keep tiny configurations and mixes of its own; what it
@@ -92,11 +98,20 @@ class Manifest:
         return [m for m in self.data[section]
                 if "workloads" not in m or cell_name in m["workloads"]]
 
-    def layer_metric(self, name: str) -> dict:
+    def layer_metric(self, name: str, cfg: Optional[dict] = None) -> dict:
+        """``{"reader": ..., "args": {...}}`` of one per-layer metric: what
+        the configuration ``cfg`` (as ``config`` returns it) says under
+        ``readings``, else the reading's file; each is asked for the whole
+        name first, then for its stem before the first ``.``."""
+        stem = name.split(".")[0]
+        readings = (cfg or {}).get("readings", {})
+        for n in (name, stem):
+            if n in readings:
+                return readings[n]
         try:
             path = self._find("layer_metrics", name + ".json")
         except ManifestError:
-            path = self._find("layer_metrics", name.split(".")[0] + ".json")
+            path = self._find("layer_metrics", stem + ".json")
         with open(path) as f:
             return json.load(f)
 

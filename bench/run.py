@@ -329,7 +329,7 @@ def main() -> int:
                else None}
         specs = []
         for m in man.metrics_of("per_layer", cell["name"]):
-            spec = man.layer_metric(m["name"])
+            spec = man.layer_metric(m["name"], cfg)
             specs.append(spec)
             value = man.reader(spec["reader"])(ctx, **spec.get("args", {}))
             if value is not None:

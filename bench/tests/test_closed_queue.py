@@ -276,7 +276,7 @@ def test_client_stat_reads_them_and_the_manifest_names_the_files():
     man = Manifest(os.path.join(REPO, "BENCHMARK.json"))
     closed = {w["name"] for w in man.data["workloads"]
               if load(w["traffic"])["loop"] == "closed"}
-    assert len(closed) == 4
+    assert len(closed) >= 4          # cells 3-9; a later cell joins
     records = [rec(0.0, [1.0, 3.0], done=3.1)]
     ctx = {"records": records, "ok": records, "failed": [], "traffic": {},
            "seconds": 4.0}

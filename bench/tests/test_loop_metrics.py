@@ -124,8 +124,9 @@ def test_cpu_rehearsal_prints_the_new_metrics(tmp_path):
     shares = [got[n]["value"] for n in NEW if n.endswith("_share")]
     assert all(0.0 <= v <= 100.0 for v in shares)
     assert got["loop_device_wait_share"]["value"] > 0
+    # The program's counter and the log lines ``correct`` counts agree.
     assert got["xla_compiles_in_window"]["value"] \
-        == got["compiles_in_window"]["value"] == 0
+        == last["compared"]["compiles_in_window"][0] == 0
     assert got["boot_ready_s"]["value"] >= got["boot_warmup_s"]["value"] > 0
     assert got["queue_boundary_wait_ms"]["value"] >= 0
     # With the rehearsal manifest's own five shares (PR 35) the copy reads

@@ -46,8 +46,8 @@ def test_rehearsal_result_line(cell, trace, names):
         assert dev["busy_s"] > 0 and dev["window_s"] >= dev["busy_s"]
         assert set(last["breakdown"]) == {"device_ops", "idle_gaps"}
         assert 0 < len(last["breakdown"]["device_ops"]) <= 10
-        names = {"decode_batch_mean", "decode_step_ms", "compiles_in_window",
-                 "loop_enqueue_share", "loop_reap_share", "loop_other_share",
+        names = {"decode_batch_mean", "decode_step_ms",
+                 "xla_compiles_in_window", "loop_enqueue_share", "loop_reap_share", "loop_other_share",
                  "loop_idle_share", "loop_swap_share"}
         closed_only = {"streams_decoding_mean", "clients_waiting_mean",
                        "ttft_mean_s.batch", "ttft_p90_s.batch",
@@ -66,7 +66,7 @@ def test_rehearsal_result_line(cell, trace, names):
         else:
             names |= {"queue_wait_mean_ms", "ttft_p90_s"}
             assert not closed_only & set(last["metrics"])
-        assert last["metrics"]["compiles_in_window"]["value"] == 0
+        assert last["metrics"]["xla_compiles_in_window"]["value"] == 0
         # No chip, no peaks: roofline shares are absent, not made up.
         assert not any(k.endswith("_roofline") for k in last["metrics"])
         assert names <= set(last["metrics"])

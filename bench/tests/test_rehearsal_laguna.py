@@ -36,12 +36,12 @@ def test_traced_run_reports_the_pools_and_the_routing():
     assert 0.0 <= m["kv_window_pool_live_share"] <= 100.0
     assert 0.0 <= m["kv_full_pool_live_share"] <= 100.0
     assert m["kv_window_pages_released_per_s"] > 0.0
-    assert m["moe_dropped_pairs.mixed"] == 0.0
+    assert m["moe_dropped_pairs"] == 0.0
     # 4 held experts of 16, top-3, near-uniform random routing.
-    assert 0.3 < m["moe_local_pairs_per_token.mixed"] < 1.3
-    assert 0.0 < m["moe_decode_distinct_experts.mixed"] <= 4.0
-    assert m["preemptions_in_window.mixed"] == 0.0
-    assert m["compiles_in_window"] == 0 and m["decode_batch_mean"] > 1.0
+    assert 0.3 < m["moe_local_pairs_per_token"] < 1.3
+    assert 0.0 < m["moe_decode_distinct_experts"] <= 4.0
+    assert m["preemptions_in_window"] == 0.0
+    assert m["xla_compiles_in_window"] == 0 and m["decode_batch_mean"] > 1.0
     assert not any(k.endswith("_roofline") or k.endswith("_per_ktok")
                    for k in m), "no chip, no peaks: no share, no device time"
 

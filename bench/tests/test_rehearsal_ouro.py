@@ -27,11 +27,12 @@ def test_traced_run_reports_the_pool_and_the_queue():
     assert last["correct"] is True and last["failed"] == 0
     assert last["attempted"] > 0 and last["device"]["platform"] == "cpu"
     m = {k: v["value"] for k, v in last["metrics"].items()}
-    assert m["kv_pool_pages"] == 23.0          # --num-pages 24, less page 0
+    assert m["kv_page_tokens"] == 16.0         # a CPU server's page
     assert m["preemptions_in_window"] == 0.0
     assert m["queue_capacity_wait_ms.batch"] >= 0.0
-    assert m["compiles_in_window"] == 0 and m["decode_batch_mean"] > 1.0
-    assert not any(k.endswith("_roofline") or k.startswith("looped_")
+    assert m["xla_compiles_in_window"] == 0 and m["decode_batch_mean"] > 1.0
+    assert not any(k.endswith("_roofline")
+                   or k.startswith(("looped_", "prefill_ms_"))
                    for k in m), "no chip, no peaks: no share, no device time"
 
 

@@ -40,11 +40,11 @@ def test_traced_run_reports_the_state_slots_and_the_skip():
     assert 0.0 < m["prefill_cross_positions_share"] < 15.0
     # The pools a kind: prompts many windows long release pages behind
     # the window, and nothing is preempted at this size.
-    assert 0.0 < m["sambay_full_pool_live_share"] <= 100.0
-    assert 0.0 < m["sambay_window_pool_live_share"] <= 100.0
-    assert m["sambay_window_pages_released_per_s"] > 0.0
+    assert 0.0 < m["kv_full_pool_live_share"] <= 100.0
+    assert 0.0 < m["kv_window_pool_live_share"] <= 100.0
+    assert m["kv_window_pages_released_per_s"] > 0.0
     assert m["preemptions_in_window"] == 0
-    assert m["compiles_in_window"] == 0 and m["decode_batch_mean"] > 1.0
+    assert m["xla_compiles_in_window"] == 0 and m["decode_batch_mean"] > 1.0
     assert not any(k.endswith("_roofline") or k.endswith("_per_ktok")
                    for k in m), "no chip, no peaks: no share, no device time"
 

@@ -46,11 +46,11 @@ def test_traced_run_reports_the_experts_rows_and_both_pools():
     assert 1.0 <= m["moe_decode_rows_per_expert"] < 4.0
     # Tiles of 16 rows for a handful of pairs an expert.
     assert 50.0 < m["moe_padded_row_share"] < 100.0
-    assert 0.0 < m["thinker_full_pool_live_share"] <= 100.0
+    assert 0.0 < m["kv_full_pool_live_share"] <= 100.0
     assert 0.0 < m["thinker_window_pool_live_share"] <= 100.0
     assert 0.0 <= m["thinker_window_pool_booked_share"] <= 100.0
     assert m["preemptions_in_window"] == 0
-    assert m["compiles_in_window"] == 0 and m["decode_batch_mean"] > 1.0
+    assert m["xla_compiles_in_window"] == 0 and m["decode_batch_mean"] > 1.0
     assert not any(k.endswith("_roofline") or k.endswith("_per_ktok")
                    for k in m), "no chip, no peaks: no share, no device time"
 

@@ -46,7 +46,7 @@ def test_traced_run_reports_the_counters_this_cell_lists():
     assert m["prefix_hit_share.batch"] > 30.0
     assert m["moe_gather_combine_programs"] > 0
     assert m["preemptions_in_window"] == 0
-    assert m["compiles_in_window"] == 0 and m["decode_batch_mean"] > 1.0
+    assert m["xla_compiles_in_window"] == 0 and m["decode_batch_mean"] > 1.0
     assert not any(k.endswith("_roofline") or k.endswith("_per_ktok")
                    or k.endswith("_busy_share") for k in m), \
         "no chip, no peaks: no share, no device time"

@@ -206,19 +206,33 @@ def test_nothing_to_read_is_none():
     assert READER.read(other, "pool_live_share", kind="full") is None
 
 
-def test_every_new_metric_file_names_the_reader():
-    with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as f:
-        man = json.load(f)
-    cell = "phi4-mini-flash-bf16_reason-long-batch"
-    own = [m for m in man["per_layer"] if m.get("workloads") == [cell]]
-    assert len(own) == 13
-    for m in own:
-        if m["name"] == "preemptions_in_window.sambay":
-            # Found under the name before the dot: the counter's delta.
-            assert m["moves"] == "out_tok_s"
-            continue
-        with open(os.path.join(BENCH, "layer_metrics",
-                               m["name"] + ".json")) as f:
-            assert json.load(f)["reader"] == "sambay"
+def test_every_reading_of_the_cell_names_the_reader():
+    """What only this cell has is an entry of its own, whose file names
+    the reader; what every architecture has is the shared entry, and the
+    configuration's ``readings`` names the reader (PR 50)."""
+    from manifest import Manifest
+
+    man = Manifest(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json"))
+    cell = man.cell("phi4-mini-flash-bf16_reason-long-batch")
+    cfg = man.config(cell)
+    listed = {m["name"]: m for m in man.metrics_of("per_layer", cell["name"])}
+    own = [m for m in listed.values() if m.get("workloads") == [cell["name"]]]
+    assert sorted(m["name"] for m in own) == [
+        "prefill_cross_positions_share", "ssm_decode_state_roofline",
+        "ssm_scan_prefill_roofline", "state_slots_live_share"]
+    assert sorted(cfg["readings"]) == [
+        "decode_attn_roofline", "decode_hbm_roofline",
+        "kv_full_pool_live_share", "kv_window_pages_released_per_s",
+        "kv_window_pool_live_share", "prefill_attn_roofline",
+        "prefill_ms_per_ktok", "window_decode_attn_roofline"]
+    shared = [m for name, m in listed.items()
+              if name.split(".")[0] in cfg["readings"]]
+    assert len(shared) == 8
+    for m in own + shared:
+        assert man.layer_metric(m["name"], cfg)["reader"] == "sambay"
         if m["name"].endswith("_roofline"):
             assert m["unit"] == "%" and m["better"] == "higher"
+    # The counter's delta is everybody's reader, under everybody's name.
+    assert listed["preemptions_in_window"]["moves"] == "out_tok_s"
+    assert man.layer_metric("preemptions_in_window", cfg)["reader"] \
+        == "metrics_delta"

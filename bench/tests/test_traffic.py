@@ -166,4 +166,4 @@ def test_every_closed_cell_runs_its_answers_to_their_length():
             closed += 1
             assert "--ignore-eos" in run.server_flags(man.config(cell), mix), \
                 cell["name"]
-    assert closed == 4
+    assert closed >= 4               # cells 3-9; a later cell joins
