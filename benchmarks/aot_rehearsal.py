@@ -244,7 +244,8 @@ def main() -> int:
     stateful = bool(kvc.num_state_slots(mcfg, ecfg))
     tiny = dataclasses.replace(mcfg, n_layers=8 if stateful else 1,
                                d_model=256, n_heads=2,
-                               n_kv_heads=2, d_ff=256, vocab_size=512)
+                               n_kv_heads=2, d_ff=256, vocab_size=512,
+                               kda_n_heads=min(mcfg.kda_n_heads, 2))
     real_backend = jax.default_backend
     jax.default_backend = lambda: "tpu"
     try:
@@ -377,6 +378,10 @@ def main() -> int:
             wshape = tuple(kv.wk.shape)
             copies += (pool_copies(text, wshape) + pool_copies(
                 text, wshape[:2] + (wshape[2] * wshape[3],) + wshape[4:]))
+        if kv.ssm_h is not None and mcfg.state_kind == "kda":
+            # The matrix states: the delta-rule kernels advance them
+            # where they lie (kernels/delta_rule.py).
+            copies += pool_copies(text, tuple(kv.ssm_h.shape))
         pcopies, laid = ((param_copies(text, params),
                           param_copies(text, params, device_laid=True))
                          if graph != "swap" else ([], []))

@@ -114,6 +114,14 @@ SETTINGS = {
     # (prefill) on the v5e (PR 26). 2.5x above that; a wrong layer, page
     # or mask is off by the output's whole spread (~1).
     "latent_kernel_tol": 0.02,
+    # The delta-rule kernels (kernels/delta_rule.py) at Ling-3.0-flash's
+    # head sizes against the recurrence a token at a time, as shares of
+    # the reference's spread. Everything is float32 with matrix products
+    # at the highest precision: ~1e-5 in interpret mode on the CPU over a
+    # 1024-token chunk with half the channels at the gate's bound. A
+    # chunked form that loses a block, a sub-block's decay or the state
+    # at a boundary is off by the output's whole spread.
+    "delta_rule_tol": 0.001,
     # The routed-expert layer (models/deepseek_v3.py moe_ffn: router,
     # grouping, kernels/moe_experts.py) at Kimi-K2's widths against a
     # plain float32 loop over the held experts on the SAME routing: the
@@ -990,6 +998,131 @@ def _sambay_kernel_errors(cfg: dict, *, d_inner: int = 5120, n_state: int = 16,
     return {k: round(v, 6) for k, v in out.items()}
 
 
+def _delta_rule_errors(cfg: dict, *, heads: int = 32, d: int = 128,
+                       lanes: int = 3, rows: int = 1024, slots: int = 9,
+                       step_lanes: int = 96, time_it: bool = True,
+                       interpret: bool = False) -> dict:
+    """What delta-rule layers (Ling-3.0-flash) add to the kernels, at the
+    published head sizes (32 heads of a 128 x 128 float32 state): the
+    chunk kernel over a whole engine chunk against the recurrence a token
+    at a time (a whole row, one that ends inside a block, an idle one
+    whose state must leave as it entered; half the channels' decay AT the
+    gate's bound, e^-5 a token), then a second chunk from the states the
+    first left (the state crosses the chunk boundary through its slot),
+    and the one-token update against one step of the recurrence, each as
+    a share of the reference's spread. With ``time_it``, microseconds a
+    call of the one-token update at ``step_lanes`` lanes: the kernel (each
+    state read once and written once, in place) beside the same update in
+    plain XLA on gathered states, scattered back (what the engine runs off
+    the kernel's backend), and of the chunk kernel over one row."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tpu_inference.kernels import delta_rule as dr
+
+    ks = jax.random.split(jax.random.PRNGKey(cfg["seed"] + 9), 8)
+
+    def draw(n, s):
+        unit = lambda x: x / jnp.linalg.norm(x, axis=-1,       # noqa: E731
+                                             keepdims=True)
+        shape = (n, s, heads, d)
+        g = -5.0 * jax.nn.sigmoid(3.0 * jax.random.normal(ks[3], shape))
+        g = jnp.where(jnp.arange(d) % 2 == 0, -5.0, g)     # at the bound
+        # Keys and queries share a direction (k_i . k_j ~ 0.5), as a
+        # deep layer's do: the block's triangular system is then far
+        # from the identity.
+        return (unit(jax.random.normal(ks[0], shape) + 1.0) * d ** -0.5,
+                unit(jax.random.normal(ks[1], shape) + 1.0),
+                jax.random.normal(ks[2], shape), g,
+                jax.nn.sigmoid(jax.random.normal(ks[4], (n, s, heads))))
+
+    q, k, v, g, beta = draw(lanes, rows)
+    pool = jax.random.normal(ks[5], (2, slots, heads, d, d))
+    at = jnp.arange(1, lanes + 1)
+    lens = jnp.asarray(([rows, rows // 2 + 3] + [0] * lanes)[:lanes],
+                       jnp.int32)
+    flat = lambda a: a.reshape(a.shape[0], a.shape[1], -1)     # noqa: E731
+    out = {}
+    state = pool[1, at]
+    for name in ("chunk", "next_chunk"):
+        o, pool2 = dr.kda_chunk_prefill(
+            pool, 1, at, jnp.where(lens > 0, at, 0),
+            jnp.zeros((lanes,), bool), flat(q), flat(k), flat(v), flat(g),
+            beta, lens, n_heads=heads, interpret=interpret)
+        o_ref, s_ref = dr.kda_recurrence(q, k, v, g, beta, state, lens)
+        live = (np.arange(rows)[None] < np.asarray(lens)[:, None])[
+            ..., None, None]
+        check(bool(jnp.isfinite(o).all()), "the chunk kernel overflowed")
+        check(bool((pool2[0] == pool[0]).all()), "another layer's states "
+              "changed")
+        check(lanes < 3 or bool((pool2[1, at[-1]] == pool[1, at[-1]]).all()),
+              "a lane with no valid position changed its state")
+        out[name + "_o"] = float(
+            np.abs(np.where(live, o.reshape(o_ref.shape) - o_ref, 0)).max()
+            / np.std(np.asarray(o_ref)[:1]))
+        out[name + "_s"] = float(jnp.abs(pool2[1, at] - s_ref).max()
+                                 / jnp.std(s_ref))
+        pool, state = pool2, s_ref
+    one = lambda a: a[:, 0].reshape(lanes, -1)                 # noqa: E731
+    o1, pool3 = dr.kda_step(pool, 1, at, at, one(q), one(k), one(v), one(g),
+                            beta[:, 0], n_heads=heads, interpret=interpret)
+    o_ref, s_ref = dr.kda_recurrence(q[:, :1], k[:, :1], v[:, :1], g[:, :1],
+                                     beta[:, :1], state,
+                                     jnp.ones((lanes,), jnp.int32))
+    out["step_o"] = float(jnp.abs(o1.reshape(lanes, heads, d)
+                                  - o_ref[:, 0]).max() / jnp.std(o_ref))
+    out["step_s"] = float(jnp.abs(pool3[1, at] - s_ref).max()
+                          / jnp.std(s_ref))
+    out = {k: round(v, 7) for k, v in out.items()}
+    if not time_it:
+        return out
+
+    def per_call_us(fn, *args, calls=20):
+        res = fn(*args)
+        jax.block_until_ready(res)
+        t0 = time.perf_counter()
+        for _ in range(calls):
+            res = fn(*args)
+        jax.block_until_ready(res)
+        return round(1e6 * (time.perf_counter() - t0) / calls, 1)
+
+    n = step_lanes
+    big = jnp.zeros((1, n + 1, heads, d, d), jnp.float32)
+    sq, sk, sv, sg, sb = (a[:, 0] for a in draw(n, 1))
+    where = jnp.arange(1, n + 1)
+    f2 = lambda a: a.reshape(n, -1)                            # noqa: E731
+
+    @jax.jit
+    def kernel(pool):
+        return dr.kda_step(pool, 0, where, where, f2(sq), f2(sk), f2(sv),
+                           f2(sg), sb, n_heads=heads, interpret=interpret)[1]
+
+    @jax.jit
+    def plain(pool):
+        _, st = dr.kda_recurrence(sq[:, None], sk[:, None], sv[:, None],
+                                  sg[:, None], sb[:, None], pool[0, where],
+                                  jnp.ones((n,), jnp.int32))
+        return pool.at[0, where].set(st)
+
+    cq, ck, cv, cg, cb = draw(1, rows)
+
+    @jax.jit
+    def chunk(pool):
+        return dr.kda_chunk_prefill(
+            pool, 0, where[:1], where[:1], jnp.zeros((1,), bool), flat(cq),
+            flat(ck), flat(cv), flat(cg), cb, jnp.full((1,), rows),
+            n_heads=heads, interpret=interpret)[1]
+
+    out["step_us_kernel"] = per_call_us(kernel, big)
+    out["step_us_xla"] = per_call_us(plain, big)
+    out["chunk_us"] = per_call_us(chunk, big)
+    out["step_state_bytes"] = int(2 * n * heads * d * d * 4)
+    return out
+
+
 def _kv_rows_write_check(cfg: dict, *, pools=(("window", 8), ("full", 1)),
                          pages: int = 1024, page: int = 16, heads: int = 10,
                          d: int = 128, lanes: int = 64, reps: int = 200,
@@ -1574,6 +1707,13 @@ def child_parity(cfg: dict) -> dict:
         check(max(res["sambay_kernel_err"].values()) <= cfg["kernel_tol"],
               f"selective scan vs lax.scan / GQA kernels at the pair-head "
               f"shapes vs dense float32: {res}")
+        # Delta-rule layers: the chunk kernel and the one-token update
+        # at 32 heads of a 128 x 128 float32 state against the recurrence
+        # a token at a time, and what a call of each costs.
+        res["delta_rule_err"] = _delta_rule_errors(cfg)
+        check(max(v for k, v in res["delta_rule_err"].items()
+                  if k.endswith(("_o", "_s"))) <= cfg["delta_rule_tol"],
+              f"delta-rule kernels vs the recurrence: {res}")
         # A decode step's K / V write into merged-row pools: the pool
         # the scatter gives, bit for bit, and what a call of each costs.
         res["kv_rows_write_err"] = _kv_rows_write_check(cfg)

@@ -248,3 +248,38 @@ def test_resolve_sizing_args_noop_on_ints():
 
     args = types.SimpleNamespace(max_batch_size=8, num_pages=512)
     assert autosize.resolve_sizing_args(args) == (8, 512)
+
+
+@pytest.mark.parametrize("target_ctx,batch_cap,lanes", [
+    (2560, 96, 96), (2560, 128, 98), (16384, 96, 45)])
+def test_a_state_a_sequence_beside_one_latent_pool(target_ctx, batch_cap,
+                                                   lanes, capsys):
+    """Delta-rule layers beside a latent pool (ling3-flash-ep8): a lane
+    costs its 23.9 MB state slot and ``target_ctx`` tokens of latent
+    pages, the trash slot one state more; the pool gets what the slots
+    leave. At the cell's flags the STATE sets the batch: 96 lanes hold
+    2.3 GB of states beside 0.7 GB of pages."""
+    from tpu_inference.config import PRESETS, EngineConfig
+
+    mcfg = PRESETS["ling3-flash-ep8"]()
+    state = mcfg.state_bytes_per_seq()
+    assert state == 23_879_680
+    ecfg = autosize.resolve_sizing(
+        mcfg, EngineConfig(quant="none", attn_backend="pallas",
+                           max_pages_per_seq=1168),
+        dict(max_batch_size="auto", num_pages="auto", decode_ladder="auto",
+             target_ctx=target_ctx, batch_cap=batch_cap), hbm_bytes=16.91e9)
+    said = capsys.readouterr().err
+    assert ecfg.max_batch_size == lanes and ecfg.page_size == 64
+    assert f"state_slots={lanes} state_bytes_per_slot={state}" in said
+    kv_tok = autosize.kv_bytes_per_token(mcfg)
+    assert kv_tok == 2 * 640 * 2           # two latent layers, 640 wide
+    held = (ecfg.num_pages * 64 * kv_tok + (lanes + 1) * state
+            + autosize.weight_bytes(mcfg))
+    assert held < 0.85 * 16.91e9 and (ecfg.num_pages - 1) * 64 \
+        >= lanes * target_ctx
+    # A model with no state a sequence is sized as it was.
+    sz = autosize.auto_size(PRESETS["kimi-k2-ep32"](), hbm_bytes=16.91e9,
+                            quant="none", page_size=64,
+                            max_pages_per_seq=168, target_ctx=2048)
+    assert sz.kv_pool_bytes_per_chip == sz.num_pages * 64 * sz.kv_bytes_per_token

@@ -273,6 +273,21 @@ def test_sambay_kernel_check_in_interpret_mode():
     assert max(errs.values()) <= chip_smoke.SETTINGS["kernel_tol"]
 
 
+def test_delta_rule_check_in_interpret_mode():
+    """The smoke's delta-rule check (run on the chip at Ling-3.0-flash's:
+    32 heads of a 128 x 128 state, a 1024-token chunk) at a small shape
+    through the interpreter: the chunk kernel with a whole row, one that
+    ends inside a block and an idle one, half the channels at the gate's
+    bound, a second chunk from the states the first left, and the
+    one-token update."""
+    errs = chip_smoke._delta_rule_errors(TINY, heads=2, d=64, rows=128,
+                                         slots=5, time_it=False,
+                                         interpret=True)
+    assert set(errs) == {"chunk_o", "chunk_s", "next_chunk_o",
+                         "next_chunk_s", "step_o", "step_s"}
+    assert max(errs.values()) <= chip_smoke.SETTINGS["delta_rule_tol"]
+
+
 def test_kv_rows_write_check_in_interpret_mode():
     """The smoke's check of a decode step's K / V write into merged-row
     pools (run on the chip at cell 7's shapes, 64 lanes, timed) at a few

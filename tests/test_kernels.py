@@ -554,3 +554,152 @@ def test_ulysses_attention_bf16():
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
                                rtol=5e-2, atol=5e-2)
+
+
+# ---------------------------------------------------------------------------
+# The delta rule (kernels/delta_rule.py): the chunk kernel and the
+# one-token update, in interpret mode, against the recurrence a token at
+# a time. Float32 at the highest precision on both sides.
+# ---------------------------------------------------------------------------
+
+def _delta_inputs(lanes, rows, heads, d, seed, bound=False, aligned=0.0):
+    """``aligned``: a common direction added to every key and query
+    before they are normalised, a slow decay and a beta near 1 (what a
+    deep layer of a randomly initialised stack gives: k_i . k_j -> 1)."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa
+    shape = (lanes, rows, heads, d)
+    g = -5.0 * jax.nn.sigmoid(2.0 * jax.random.normal(ks[3], shape))
+    if bound:
+        g = jnp.full(shape, -5.0)
+    if aligned:
+        g = g * 0.002
+    return (unit(jax.random.normal(ks[0], shape) + aligned) * d ** -0.5,
+            unit(jax.random.normal(ks[1], shape) + aligned),
+            jax.random.normal(ks[2], shape), g,
+            jax.nn.sigmoid(jax.random.normal(ks[4], (lanes, rows, heads))
+                           + (3.0 if aligned else 0.0)),
+            jax.random.normal(ks[5], (lanes, heads, d, d)))
+
+
+def _delta_pool(s0, layers=2, layer=1):
+    """A state pool of ``layers`` layers whose layer ``layer`` holds s0
+    in slots 1.. (slot 0 the trash slot) and sevens elsewhere."""
+    lanes = s0.shape[0]
+    pool = jnp.full((layers, lanes + 1) + s0.shape[1:], 7.0)
+    return pool.at[layer, 1:].set(s0)
+
+
+@pytest.mark.parametrize("lanes,rows,heads,d,lens,bound", [
+    (2, 128, 2, 64, (128, 91), False),      # a row that ends inside a block
+    (1, 64, 1, 128, (64,), True),           # every channel AT the bound
+    (3, 256, 2, 32, (256, 70, 0), False),   # two time blocks, an idle row
+    (2, 16, 1, 64, (16, 5), False),         # a bucket shorter than a block
+])
+def test_kda_chunk_kernel_equals_the_recurrence(lanes, rows, heads, d, lens,
+                                                bound):
+    from tpu_inference.kernels import delta_rule as dr
+
+    q, k, v, g, beta, s0 = _delta_inputs(lanes, rows, heads, d, rows, bound)
+    lens = jnp.asarray(lens, jnp.int32)
+    o_ref, s_ref = dr.kda_recurrence(q, k, v, g, beta, s0, lens)
+    pool = _delta_pool(s0)
+    at = jnp.arange(1, lanes + 1)
+    flat = lambda a: a.reshape(lanes, rows, -1)                # noqa: E731
+    o, out = dr.kda_chunk_prefill(
+        pool, 1, at, jnp.where(lens > 0, at, 0), jnp.zeros((lanes,), bool),
+        flat(q), flat(k), flat(v), flat(g), beta, lens, n_heads=heads,
+        interpret=True)
+    live = (jnp.arange(rows)[None] < lens[:, None])[..., None, None]
+    assert bool(jnp.isfinite(o).all()) and bool(jnp.isfinite(out).all())
+    err = jnp.where(live, o.reshape(o_ref.shape) - o_ref, 0.0)
+    assert float(jnp.abs(err).max()) < 2e-5 * float(jnp.std(o_ref) + 1)
+    assert float(jnp.abs(out[1, 1:] - s_ref).max()) < 2e-5
+    # A lane with no valid position leaves its state as it was (it wrote
+    # the trash slot); the other layer's states are untouched.
+    idle = np.asarray(lens) == 0
+    assert bool((out[1, 1:][idle] == s0[idle]).all())
+    assert bool((out[0] == pool[0]).all())
+
+
+def test_kda_chunk_kernel_carries_the_state_across_chunks():
+    """Three engine chunks of one stream, the second from the slot the
+    first wrote and the last ragged, equal ONE recurrence over the whole;
+    a fresh lane reads zeros whatever its slot holds."""
+    from tpu_inference.kernels import delta_rule as dr
+
+    heads, d, chunk = 2, 64, 64
+    q, k, v, g, beta, s0 = _delta_inputs(1, 3 * chunk, heads, d, 7)
+    total = 2 * chunk + 23
+    o_ref, s_ref = dr.kda_recurrence(q, k, v, g, beta, jnp.zeros_like(s0),
+                                     jnp.asarray([total]))
+    pool = _delta_pool(s0)                   # the slot holds somebody's
+    outs = []
+    for c in range(3):
+        part = lambda a: a[:, c * chunk:(c + 1) * chunk].reshape(  # noqa
+            1, chunk, -1)
+        o, pool = dr.kda_chunk_prefill(
+            pool, 1, jnp.asarray([1]), jnp.asarray([1]),
+            jnp.asarray([c == 0]), part(q), part(k), part(v), part(g),
+            beta[:, c * chunk:(c + 1) * chunk],
+            jnp.asarray([min(chunk, total - c * chunk)]), n_heads=heads,
+            interpret=True)
+        outs.append(o.reshape(1, chunk, heads, d))
+    o = jnp.concatenate(outs, axis=1)[:, :total]
+    assert float(jnp.abs(o - o_ref[:, :total]).max()) < 2e-5
+    assert float(jnp.abs(pool[1, 1] - s_ref[0]).max()) < 2e-5
+
+
+@pytest.mark.parametrize("aligned,tol", [(2.0, 5e-4), (10.0, 5e-3)])
+def test_kda_chunk_kernel_with_keys_that_point_one_way(aligned, tol):
+    """Keys of a block nearly parallel (k_i . k_j 0.8 / 0.99), a decay
+    near 1 and a beta near 1: ``A`` is then close to a full lower
+    triangle of ones, whose powers reach C(63, k) and cancel in float32
+    (inverting the whole block by squarings read 1e13 times the output's
+    spread here; the deep layers of a randomly initialised stack are
+    like this, and the first chip run of the cell read not correct on one
+    seed in four for it). The blocks of 16 with forward substitution
+    below them stay at rounding."""
+    from tpu_inference.kernels import delta_rule as dr
+
+    q, k, v, g, beta, s0 = _delta_inputs(1, 128, 1, 128, 3, aligned=aligned)
+    assert float(jnp.mean(jnp.einsum("bshd,bthd->bst", k, k))) > 0.75
+    lens = jnp.asarray([128], jnp.int32)
+    o_ref, s_ref = dr.kda_recurrence(q, k, v, g, beta, s0, lens)
+    flat = lambda a: a.reshape(1, 128, -1)                     # noqa: E731
+    o, out = dr.kda_chunk_prefill(
+        _delta_pool(s0), 1, jnp.asarray([1]), jnp.asarray([1]),
+        jnp.zeros((1,), bool), flat(q), flat(k), flat(v), flat(g), beta,
+        lens, n_heads=1, interpret=True)
+    assert float(jnp.abs(o.reshape(o_ref.shape) - o_ref).max()) \
+        < tol * float(jnp.std(o_ref))
+    assert float(jnp.abs(out[1, 1] - s_ref[0]).max()) \
+        < tol * float(jnp.std(s_ref))
+
+
+@pytest.mark.parametrize("lanes,heads,d", [(3, 2, 64), (2, 16, 128)])
+def test_kda_step_kernel_equals_one_step(lanes, heads, d):
+    from tpu_inference.kernels import delta_rule as dr
+
+    q, k, v, g, beta, s0 = _delta_inputs(lanes, 1, heads, d, 11)
+    o_ref, s_ref = dr.kda_recurrence(q, k, v, g, beta, s0,
+                                     jnp.ones((lanes,), jnp.int32))
+    pool = _delta_pool(s0)
+    at = jnp.arange(1, lanes + 1)
+    write = at.at[-1].set(0)                 # the last lane is masked
+    one = lambda a: a[:, 0].reshape(lanes, -1)                 # noqa: E731
+    o, out = dr.kda_step(pool, 1, at, write, one(q), one(k), one(v), one(g),
+                         beta[:, 0], n_heads=heads, interpret=True)
+    assert float(jnp.abs(o.reshape(lanes, heads, d) - o_ref[:, 0]).max()) \
+        < 1e-5
+    assert float(jnp.abs(out[1, 1:-1] - s_ref[:-1]).max()) < 1e-5
+    assert bool((out[1, -1] == s0[-1]).all())   # ... and kept its state
+    assert bool((out[0] == pool[0]).all())
+
+
+def test_kda_gate_bound_that_would_overflow_is_refused():
+    from tpu_inference.kernels import delta_rule as dr
+
+    dr.check_bound(-5.0)
+    with pytest.raises(ValueError, match="overflows"):
+        dr.check_bound(-6.0)

@@ -485,6 +485,38 @@ def test_selective_scan_compiles_at_the_published_width(chip, lanes, rows):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("kernel,lanes,rows", [
+    ("chunk", 1, 1024), ("chunk", 4, 1024), ("chunk", 4, 64),
+    ("step", 96, 0), ("step", 8, 0)])
+def test_delta_rule_kernels_compile_at_the_published_head_sizes(
+        chip, kernel, lanes, rows):
+    """The delta-rule layers' kernels at Ling-3.0-flash's sizes (32 heads
+    of a 128 x 128 float32 state, 11 layers and 96 lanes of slots): the
+    chunk kernel over the largest and smallest prefill graphs (blocks of
+    64 tokens, transposed-operand products on the MXU) and the one-token
+    update at the widest and the base rung (16 heads a grid step)."""
+    from tpu_inference.kernels import delta_rule as dr
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    h, d = 32, 128
+    pool = sds((11, 97, h, d, d), jnp.float32)
+    i32 = lambda *shape: sds(shape, jnp.int32)                 # noqa: E731
+    if kernel == "chunk":
+        x = sds((lanes, rows, h * d), jnp.float32)
+        lowered = dr.kda_chunk_prefill.lower(
+            pool, i32(), i32(lanes), i32(lanes), sds((lanes,), jnp.bool_),
+            x, x, x, x, sds((lanes, rows, h), jnp.float32), i32(lanes),
+            n_heads=h)
+    else:
+        x = sds((lanes, h * d), jnp.float32)
+        lowered = dr.kda_step.lower(
+            pool, i32(), i32(lanes), i32(lanes), x, x, x, x,
+            sds((lanes, h), jnp.float32), n_heads=h)
+    assert "tpu_custom_call" in lowered.compile().as_text()
+
+
 # ---------------------------------------------------------------------------
 # The weight stacks in a step program: stored [.., N, K] (models/quant.py
 # STORED_TRANSPOSED), the chip compiler reads one layer's matrix in place;

@@ -11,6 +11,7 @@ dict keys (`url`, `model`, `temperature`, `max_tokens`, `trace_path`,
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Optional
 
 import jax.numpy as jnp
@@ -55,15 +56,19 @@ class YarnScaling:
     attention_factor: float = 0.0
 
 
-# Kinds of a stack whose layers differ (ModelConfig.layer_types). Three
+# Kinds of a stack whose layers differ (ModelConfig.layer_types). Four
 # hold sequence state (engine/kv_cache.py): "full" attends causally over
-# the whole context and "window" over the last ``sliding_window`` keys,
-# each with a KV pool of its own; "ssm" (a selective scan, models/sambay.py)
-# holds a fixed-size state a SEQUENCE, which a token advances. Two hold
-# none: "gmu" gates the last ssm layer's scan output of the same token,
-# "cross" attends with a query of its own over the "full" layer's keys
-# and values.
-LAYER_KINDS = ("full", "window", "ssm", "gmu", "cross")
+# the whole context (over K / V pages, or over latent entries where the
+# model has ``kv_lora_rank``: models/bailing_hybrid.py) and "window" over
+# the last ``sliding_window`` keys, each with a page pool of its own;
+# "ssm" (a selective scan, models/sambay.py) and "kda" (a gated delta
+# rule with a decay a channel, models/bailing_hybrid.py) hold a
+# fixed-size state a SEQUENCE, which a token advances: STATE_KINDS. A
+# model has at most one of those two. Two kinds hold nothing: "gmu" gates
+# the last ssm layer's scan output of the same token, "cross" attends
+# with a query of its own over the "full" layer's keys and values.
+LAYER_KINDS = ("full", "window", "ssm", "gmu", "cross", "kda")
+STATE_KINDS = ("ssm", "kda")
 
 
 def sambay_layer_kinds(n_layers: int) -> tuple:
@@ -97,7 +102,7 @@ class ModelConfig:
 
     name: str = "llama"
     # "llama" | "mixtral" | "gpt2" | "deepseek_v3" | "ouro" | "laguna" |
-    # "sambay" | "smallthinker"
+    # "sambay" | "smallthinker" | "bailing_hybrid"
     family: str = "llama"
     vocab_size: int = 32000
     d_model: int = 4096
@@ -145,6 +150,8 @@ class ModelConfig:
     # Latent attention: the cache holds ONE (kv_lora_rank +
     # qk_rope_head_dim)-wide entry per token per layer, shared by all
     # query heads. kv_lora_rank == 0 means K and V per kv head.
+    # q_lora_rank == 0 beside a kv_lora_rank: ONE query projection
+    # ``wq`` and no query compression (Ling-3.0: ``q_lora_rank`` null).
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -165,6 +172,13 @@ class ModelConfig:
     moe_scoring: str = "softmax"
     norm_topk_prob: bool = True
     routed_scaling_factor: float = 1.0
+    # Group-limited routing (DeepSeek-V3 ``n_group`` / ``topk_group``):
+    # the router's experts lie in n_group groups of consecutive experts;
+    # a group's score is the sum of its two largest biased scores, the
+    # best topk_group groups stay and the top-k is taken within them.
+    # n_group == 1: every expert is ranked at once.
+    n_group: int = 1
+    topk_group: int = 1
     # This chip's share of an expert-parallel deployment: ep_size chips
     # share each layer's routed experts; rank ep_rank holds experts
     # [ep_rank * n_experts / ep_size, ...). What the absent experts
@@ -253,6 +267,18 @@ class ModelConfig:
     # ``hc_eps`` in every divisor and in the stream norm) plus a
     # per-stream share of its output. The streams live inside one
     # forward call: no cache entry knows of them.
+    # --- family "bailing_hybrid" (models/bailing_hybrid.py): "kda"
+    # layers (Kimi delta attention, arXiv:2510.26692) beside latent
+    # attention ("full" layers over the latent pool). A kda layer has
+    # kda_n_heads heads of kda_head_dim for q, k and v alike, a causal
+    # depthwise convolution of kda_d_conv taps in front of each, a decay
+    # a CHANNEL ``g = kda_gate_lower_bound * sigmoid(.)`` (so exp(g) is
+    # never under exp(lower bound) a token) and a float32 state
+    # ``[kda_head_dim, kda_head_dim]`` a head a sequence.
+    kda_n_heads: int = 0
+    kda_head_dim: int = 128
+    kda_d_conv: int = 4
+    kda_gate_lower_bound: float = -5.0
     hc_mult: int = 1
     hc_sinkhorn_iters: int = 20
     hc_eps: float = 1e-6
@@ -287,14 +313,43 @@ class ModelConfig:
         """Width of a pool entry's head as stored."""
         return self.head_dim * 2 if self.diff_attn else self.head_dim
 
+    @property
+    def kda_width(self) -> int:
+        """Columns of a kda layer's q (and of its k, and of its v)."""
+        return self.kda_n_heads * self.kda_head_dim
+
+    @property
+    def state_kind(self) -> str:
+        """The kind of this model's layers that hold a state a sequence
+        ("" = none): one of STATE_KINDS."""
+        kinds = self.layer_types[:self.n_layers]
+        return next((k for k in STATE_KINDS if k in kinds), "")
+
+    def state_shapes(self) -> tuple:
+        """What ONE state slot of ONE layer holds: (the convolution's
+        tail, the recurrent state), each as a shape behind the pool's
+        ``[layers, slots]``. "ssm": the last d_conv - 1 inputs
+        [d_conv - 1, d_inner] and h [d_state, d_inner]; "kda": the last
+        kda_d_conv - 1 inputs of q, k and v side by side and a matrix
+        [d_k, d_v] a head. The tail is in the model dtype, the state
+        float32."""
+        if self.state_kind == "kda":
+            return ((self.kda_d_conv - 1, 3 * self.kda_width),
+                    (self.kda_n_heads, self.kda_head_dim, self.kda_head_dim))
+        return ((self.ssm_d_conv - 1, self.d_inner),
+                (self.ssm_d_state, self.d_inner))
+
     def state_bytes_per_seq(self) -> int:
-        """Bytes of per-sequence state the "ssm" layers hold (0: none):
-        float32 h [d_state, d_inner] and the conv tail [d_conv - 1,
-        d_inner] in the model dtype, a layer."""
-        n = len(self.kind_layers("ssm"))
-        return n * self.d_inner * (
-            self.ssm_d_state * 4
-            + (self.ssm_d_conv - 1) * jnp.dtype(self.dtype).itemsize)
+        """Bytes of per-sequence state the layers of ``state_kind`` hold
+        (0: none): ``state_shapes`` a layer, the state float32 and the
+        convolution's tail in the model dtype."""
+        kind = self.state_kind
+        if not kind:
+            return 0
+        tail, state = self.state_shapes()
+        return len(self.kind_layers(kind)) * (
+            math.prod(state) * 4
+            + math.prod(tail) * jnp.dtype(self.dtype).itemsize)
 
     @property
     def latent_dim(self) -> int:
@@ -305,7 +360,11 @@ class ModelConfig:
     @property
     def n_kv_slots(self) -> int:
         """Leading dim of the KV pool: one slot per (pass, layer), pass
-        major (slot = pass * n_layers + layer); n_layers unlooped."""
+        major (slot = pass * n_layers + layer); n_layers unlooped. A
+        latent pool beside layers of another kind has a slot a "full"
+        layer."""
+        if self.layer_types and self.kv_lora_rank:
+            return len(self.kind_layers("full"))
         return self.n_layers * self.loop_steps
 
     def kind_layers(self, kind: str) -> tuple:
@@ -340,6 +399,12 @@ class ModelConfig:
             assert 0 <= self.ep_rank < self.ep_size
             assert 0 <= self.first_k_dense <= self.n_layers
             assert self.moe_scoring in ("softmax", "sigmoid")
+            # Whole groups of experts; a chip's share is whole groups or
+            # a whole part of one.
+            assert self.n_group >= 1 and self.n_experts % self.n_group == 0
+            assert 1 <= self.topk_group <= self.n_group
+            assert (self.n_experts_per_tok
+                    <= self.topk_group * (self.n_experts // self.n_group))
         rot = self.head_dim * self.partial_rotary_factor
         assert 0 < rot <= self.head_dim and rot == int(rot) and rot % 2 == 0
         assert self.attn_gate in ("none", "per_head")
@@ -365,8 +430,15 @@ class ModelConfig:
                 assert self.sliding_window > 0 and self.window_rope_theta > 0
                 assert self.window_n_heads > 0
                 assert self.window_n_heads % self.n_kv_heads == 0
-            # One KV slot a layer, one latent-free pool a kind.
-            assert self.loop_steps == 1 and not self.kv_lora_rank
+            elif self.family == "bailing_hybrid":
+                # Delta-rule layers beside latent attention: the full
+                # kind's pool is the latent pool.
+                assert set(kinds) <= {"full", "kda"} and self.kv_lora_rank
+                assert self.kda_n_heads > 0 and self.kda_d_conv >= 2
+                assert self.kda_gate_lower_bound < 0
+            # One KV slot a layer; a latent pool only beside kda layers.
+            assert self.loop_steps == 1
+            assert self.family == "bailing_hybrid" or not self.kv_lora_rank
         # Pair heads and merged rows are a stack of mixed kinds' pools'.
         assert self.layer_types or not (self.diff_attn
                                         or self.pool_rows_merged)
@@ -378,7 +450,7 @@ class ModelConfig:
         assert self.hc_eps > 0 and self.hc_res_clamp > 0
         # Only models/deepseek_v3.py's block carries several streams.
         assert self.hc_mult == 1 or self.family == "deepseek_v3"
-        if self.family == "deepseek_v3":
+        if self.family in ("deepseek_v3", "bailing_hybrid"):
             assert self.kv_lora_rank and self.qk_rope_head_dim % 2 == 0
             # The one routing a preset has: models/deepseek_v3.py route()
             # implements no other until a configuration needs it.
@@ -524,6 +596,37 @@ def xing4_29b_pp6() -> ModelConfig:
         norm_topk_prob=True, routed_scaling_factor=2.0,
         ep_size=1, ep_rank=0, moe_row_stats=True, hc_mult=4,
         hc_sinkhorn_iters=20, hc_eps=1e-6, hc_res_clamp=30.0,
+    )
+
+
+def ling3_flash_ep8() -> ModelConfig:
+    """Ling-3.0-flash (inclusionAI; ``bailing_hybrid``) as ONE chip of an
+    EP8 x PP3 deployment, at every published width: rank 0 of stage 0
+    holds published layers 0 and 2..13 (the two leading dense layers
+    count once): 1 dense + 12 expert layers, of which 11 are KDA layers
+    (32 heads of a 128 x 128 float32 state, a decay a channel behind a
+    4-tap convolution) and 2 latent-attention layers (published layers 5
+    and 11: ``(l + 1) % 6 == 0``; 32 heads, ONE query projection, a
+    sigmoid gate a head). The router scores all 512 experts in 8 groups
+    of 64, keeps the best 4 groups and takes the top 8 within them; this
+    chip holds group 0 and 1/8 of the vocabulary (rows 0..19647), with
+    the embedding and the head slice so that it serves alone.
+    bench/configs/ling3-flash-ep8-bf16.json states the cut and what is
+    assumed."""
+    return ModelConfig(
+        name="ling3-flash-ep8", family="bailing_hybrid", vocab_size=19648,
+        d_model=2560, n_layers=13, n_heads=32, n_kv_heads=32, d_ff=6144,
+        max_seq_len=262144, rope_theta=6000000.0, norm_eps=1e-6,
+        q_lora_rank=0, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, attn_gate="per_head",
+        first_k_dense=1, moe_d_ff=768, n_shared_experts=1, n_experts=512,
+        n_experts_per_tok=8, moe_scoring="sigmoid", norm_topk_prob=True,
+        routed_scaling_factor=2.5, n_group=8, topk_group=4,
+        ep_size=8, ep_rank=0,
+        layer_types=(("kda",) * 4 + ("full",) + ("kda",) * 5 + ("full",)
+                     + ("kda",) * 2),
+        kda_n_heads=32, kda_head_dim=128, kda_d_conv=4,
+        kda_gate_lower_bound=-5.0,
     )
 
 
@@ -719,6 +822,26 @@ def tiny_xing(vocab_size: int = 512) -> ModelConfig:
         routed_scaling_factor=2.0, moe_row_stats=True, hc_mult=4)
 
 
+def tiny_ling(vocab_size: int = 512) -> ModelConfig:
+    """The Ling-3.0-flash structure at test widths: 1 dense + 3 expert
+    layers, (kda, kda, full, kda): delta-rule layers of 2 heads with a
+    64 x 64 state beside one latent-attention layer with one query
+    projection and a gate a head; 16 routed experts in 4 groups of which
+    the best 2 stay, top-4, this chip (rank 0 of 4) holding group 0."""
+    return ModelConfig(
+        name="tiny-ling", family="bailing_hybrid", vocab_size=vocab_size,
+        d_model=128, n_layers=4, n_heads=4, n_kv_heads=4, d_ff=256,
+        max_seq_len=4096, rope_theta=10000.0, norm_eps=1e-6,
+        q_lora_rank=0, kv_lora_rank=128, qk_nope_head_dim=32,
+        qk_rope_head_dim=16, v_head_dim=32, attn_gate="per_head",
+        first_k_dense=1, moe_d_ff=128, n_shared_experts=1, n_experts=16,
+        n_experts_per_tok=4, moe_scoring="sigmoid", norm_topk_prob=True,
+        routed_scaling_factor=2.5, n_group=4, topk_group=2,
+        ep_size=4, ep_rank=0, layer_types=("kda", "kda", "full", "kda"),
+        kda_n_heads=2, kda_head_dim=64, dtype=jnp.float32,
+    )
+
+
 def tiny_gpt2(vocab_size: int = 512) -> ModelConfig:
     return ModelConfig(
         name="tiny-gpt2", family="gpt2", vocab_size=vocab_size, d_model=128,
@@ -813,6 +936,7 @@ PRESETS = {
     "phi4-mini-flash": phi4_mini_flash,
     "smallthinker-21b-pp4": smallthinker_21b_pp4,
     "xing4-29b-pp6": xing4_29b_pp6,
+    "ling3-flash-ep8": ling3_flash_ep8,
     "tiny-llama": tiny_llama,
     "tiny-llama-fatkv": tiny_llama_fatkv,
     "tiny-qwen2": tiny_qwen2,
@@ -827,6 +951,7 @@ PRESETS = {
     "tiny-sambay": tiny_sambay,
     "tiny-smallthinker": tiny_smallthinker,
     "tiny-xing": tiny_xing,
+    "tiny-ling": tiny_ling,
 }
 
 

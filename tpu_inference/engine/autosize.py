@@ -334,6 +334,18 @@ def auto_size(model_cfg, *, hbm_bytes: float, quant: str = "none",
             batch_cap=batch_cap, window_span=window_span,
             written_ahead=written_ahead)
     kv_tok = kv_bytes_per_token(model_cfg, kv_quant)
+    ctx = int(target_ctx) if target_ctx else (page_size * max_pages_per_seq
+                                              // 2)
+    ctx = max(1, min(ctx, page_size * max_pages_per_seq))
+    # A state a sequence beside ONE page pool (delta-rule layers beside
+    # a latent pool): a lane costs its state slot and ``ctx`` tokens of
+    # pages, the trash slot one state more; the pool gets what the
+    # slots leave.
+    state = model_cfg.state_bytes_per_seq()
+    if state:
+        batch_cap = max(1, min(batch_cap, int(
+            (budget - state) // (state + ctx * kv_tok / tp))))
+        budget -= (batch_cap + 1) * state
     tokens = int(budget // (kv_tok / tp))
     num_pages = tokens // page_size
     # Don't hoard HBM a small model can never address: cap the pool at
@@ -350,9 +362,6 @@ def auto_size(model_cfg, *, hbm_bytes: float, quant: str = "none",
             f"holds only {num_pages} pages < one full sequence "
             f"({max_pages_per_seq}); lower --max-pages-per-seq or "
             "shrink the pool bytes with --kv-quant int8 (or int4)")
-    ctx = int(target_ctx) if target_ctx else (page_size * max_pages_per_seq
-                                              // 2)
-    ctx = max(1, min(ctx, page_size * max_pages_per_seq))
     win = getattr(model_cfg, "sliding_window", 0)
     if win:
         # Behind-window eviction (engine._evict_behind_window) caps a
@@ -365,7 +374,8 @@ def auto_size(model_cfg, *, hbm_bytes: float, quant: str = "none",
     return AutoSizing(
         max_batch_size=batch, num_pages=num_pages, hbm_bytes=int(hbm),
         weight_bytes_per_chip=int(per_chip_w),
-        kv_pool_bytes_per_chip=int(num_pages * page_size * kv_tok // tp),
+        kv_pool_bytes_per_chip=int(num_pages * page_size * kv_tok // tp
+                                   + (batch + 1) * state),
         kv_bytes_per_token=kv_tok, target_ctx=ctx)
 
 
@@ -694,7 +704,7 @@ def resolve_sizing(model_cfg, engine_cfg, req: Optional[dict], *,
             target_ctx=req["target_ctx"] or None,
             batch_cap=req["batch_cap"],
             window_span=window_span_pages(model_cfg, engine_cfg)
-            if model_cfg.layer_types else 0,
+            if "window" in model_cfg.layer_types[:model_cfg.n_layers] else 0,
             written_ahead=written_ahead_tokens(engine_cfg),
             chunk_tokens=engine_cfg.chunk_tokens_cap)
         mbs = sz.max_batch_size if mbs == "auto" else mbs
@@ -714,7 +724,10 @@ def resolve_sizing(model_cfg, engine_cfg, req: Optional[dict], *,
               f"page_tokens={engine_cfg.page_size} "
               f"kv_decode_write={write} "
               + (f"num_window_pages={sz.num_window_pages} "
-                 if sz.num_window_pages else "") +
+                 if sz.num_window_pages else "")
+              + (f"state_slots={mbs} state_bytes_per_slot="
+                 f"{model_cfg.state_bytes_per_seq()} "
+                 if model_cfg.state_kind else "") +
               f"(hbm {sz.hbm_bytes / 1e9:.2f} GB, weights/chip "
               f"{sz.weight_bytes_per_chip / 1e9:.2f} GB, kv pool/chip "
               f"{sz.kv_pool_bytes_per_chip / 1e9:.2f} GB, target ctx "

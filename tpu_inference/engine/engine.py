@@ -69,6 +69,13 @@ def make_latent_attn(cfg: ModelConfig, page_size: int,
 
     rank, scale = cfg.kv_lora_rank, family_fn(cfg, "softmax_scale")(cfg)
     pallas = attn_backend == "pallas"
+    state = None
+    if cfg.state_kind:
+        # Layers of a state kind beside the latent ones: the lane's
+        # STATE SLOT rides behind its block table (make_kind_attn).
+        state = PagedState(block_tables[:, -1], valid, q_offset, pallas,
+                           interpret)
+        block_tables = block_tables[:, :-1]
 
     def attn(layer_idx, q, entry, v, kv: KVPages):
         del v
@@ -92,6 +99,8 @@ def make_latent_attn(cfg: ModelConfig, page_size: int,
         return out, kv
 
     attn.pallas, attn.interpret, attn.valid = pallas, interpret, valid
+    if state is not None:
+        attn.state = state
     return attn
 
 
@@ -261,16 +270,22 @@ def make_paged_attn(cfg: ModelConfig, page_size: int, block_tables: jax.Array,
 
 class PagedState:
     """``attn.state`` over the state slots (kv_cache.KVPages.conv /
-    .ssm_h): what a state-space layer reads a lane's state through and
-    writes it back through. ONE rule for everything that must not
-    advance a state, said here: a position that is not ``valid`` (a
-    padded position of a prefill, a masked step of a fused decode call,
-    an idle lane) advances nothing — the model's scan reads ``dt = 0``
-    there and keeps the conv tail (``lens`` counts a lane's valid
+    .ssm_h): what a layer of a state kind (config.STATE_KINDS) reads a
+    lane's state through and writes it back through. ONE rule for
+    everything that must not advance a state, said here: a position that
+    is not ``valid`` (a padded position of a prefill, a masked step of a
+    fused decode call, an idle lane) advances nothing — the model's scan
+    reads ``dt = 0`` there, the delta rule a decay of 1 and a beta of 0,
+    and both keep the conv tail (``lens`` counts a lane's valid
     positions) — and a lane with NO valid position writes to slot 0, the
     trash slot, because the row it was staged with may be stale and its
     slot somebody else's by now. A lane whose call starts at position 0
-    (a prompt's first chunk, a recompute-resume's) reads zeros."""
+    (a prompt's first chunk, a recompute-resume's) reads zeros.
+
+    A state-space layer (models/sambay.py) calls ``read`` / ``scan`` /
+    ``write``; a delta-rule layer (models/bailing_hybrid.py) ``tail`` /
+    ``put_tail`` for the convolution's and ``delta`` for the matrix
+    state, which the kernels advance where it lies in the pool."""
 
     def __init__(self, slots, valid, q_offset, pallas: bool,
                  interpret: bool):
@@ -279,6 +294,14 @@ class PagedState:
         self.slots_w = jnp.where(self.lens > 0, slots, 0)
         self.fresh = q_offset == 0
         self.pallas, self.interpret = pallas, interpret
+
+    def tail(self, layer, kv: KVPages):
+        return jnp.where(~self.fresh[:, None, None],
+                         kv.conv[layer, self.slots], 0)
+
+    def put_tail(self, layer, tail, kv: KVPages) -> KVPages:
+        return kv._replace(conv=kv.conv.at[layer, self.slots_w].set(
+            tail.astype(kv.conv.dtype)))
 
     def read(self, layer, kv: KVPages):
         keep = ~self.fresh[:, None, None]
@@ -301,6 +324,35 @@ class PagedState:
                                      self.lens, interpret=self.interpret)
         return ss.selective_scan_reference(x, dt, b, c, a_t, d_skip, h0,
                                            self.lens)
+
+    def delta(self, layer, q, k, v, g, beta, kv: KVPages):
+        """The delta rule over q, k, g [B, S, H, d], v [B, S, H, d], beta
+        [B, S, H] -> (o [B, S, H, d] float32, kv): the kernels advance
+        each lane's matrix state in place in ``kv.ssm_h``
+        (kernels/delta_rule.py: a chunk in blocks, or one token); off
+        their backend the recurrence runs on gathered states."""
+        from tpu_inference.kernels import delta_rule as dr
+
+        b, s, h, d = q.shape
+        if not self.pallas:
+            s0 = jnp.where(self.fresh[:, None, None, None], 0.0,
+                           kv.ssm_h[layer, self.slots])
+            o, st = dr.kda_recurrence(q, k, v, g, beta, s0, self.lens)
+            return o, kv._replace(
+                ssm_h=kv.ssm_h.at[layer, self.slots_w].set(st))
+        if s == 1:
+            flat = lambda a: a.reshape(b, h * d)               # noqa: E731
+            o, pool = dr.kda_step(
+                kv.ssm_h, layer, self.slots, self.slots_w, flat(q), flat(k),
+                flat(v), flat(g), beta[:, 0], n_heads=h,
+                interpret=self.interpret)
+        else:
+            flat = lambda a: a.reshape(b, s, h * d)            # noqa: E731
+            o, pool = dr.kda_chunk_prefill(
+                kv.ssm_h, layer, self.slots, self.slots_w, self.fresh,
+                flat(q), flat(k), flat(v), flat(g), beta, self.lens,
+                n_heads=h, interpret=self.interpret)
+        return o.reshape(b, s, h, d), kv._replace(ssm_h=pool)
 
 
 def make_kind_attn(cfg: ModelConfig, page_size: int, block_tables: jax.Array,
@@ -415,7 +467,11 @@ def make_kind_attn(cfg: ModelConfig, page_size: int, block_tables: jax.Array,
 # pool), "looped" (a looped stack), "kinds" (a stack of mixed kinds with a
 # pool a kind), "state" (state-space layers: a state a sequence, which a
 # token advances; such a model has kinds too, and this column speaks for
-# it).
+# them), "delta" (delta-rule layers: a matrix state a head a sequence; a
+# state kind too, config.STATE_KINDS, with reasons of its own). A model
+# can be several at once (delta-rule layers beside a latent pool:
+# "latent" and "delta"): every column that speaks for it gives its
+# reason (``why_not``).
 _WHY_NOT = {
     "tp": {
         "latent": "no param shardings, no sharded latent pool, no expert "
@@ -428,6 +484,10 @@ _WHY_NOT = {
         "state": "no param shardings for parameters stacked per kind, no "
                  "sharded per-kind pools or state slots: "
                  "parallel/shardings.py (pipeline stages: "
+                 "parallel/pipeline.pp_forward runs the llama family)",
+        "delta": "no param shardings for parameters stacked per kind, no "
+                 "sharded state slots (a head's matrix state would shard "
+                 "with its head): parallel/shardings.py (pipeline stages: "
                  "parallel/pipeline.pp_forward runs the llama family)"},
     "kv_quant": {
         "latent": "the latent pool is stored in the model dtype",
@@ -436,7 +496,10 @@ _WHY_NOT = {
         "kinds": "per-kind pools are stored in the model dtype: "
                  "kv_cache.alloc_kind_pages",
         "state": "per-kind pools are stored in the model dtype and the "
-                 "scan's state in float32: kv_cache.alloc_kind_pages"},
+                 "scan's state in float32: kv_cache.alloc_kind_pages",
+        "delta": "the delta rule's matrix state is float32 and its "
+                 "convolution tail the model dtype: "
+                 "kv_cache.alloc_state_slots"},
     "host": {
         "latent": "offload / restore / serialize assume K and V pools",
         "looped": "a page of pass x layer slots is tens of MiB to copy "
@@ -445,7 +508,9 @@ _WHY_NOT = {
                  "kind has no window-kind twin to restore",
         "state": "offload / restore copy one pool's pages; a restored "
                  "prefix would need the scan's state at its end, which "
-                 "no page holds"},
+                 "no page holds",
+        "delta": "a restored prefix would need the delta rule's state at "
+                 "its end, which no page holds"},
     "role": {
         "latent": "P/D handoff serializes K and V pages",
         "looped": "P/D handoff of pages of pass x layer slots is untested",
@@ -453,6 +518,10 @@ _WHY_NOT = {
                  "kind",
         "state": "P/D handoff serializes one pool's pages: neither a "
                  "table a kind nor the sequence's state slot "
+                 "(export_sequence_kv* / adopt_sequence refuse too)",
+        "delta": "a handed-off sequence would need its state slot (a "
+                 "matrix a head a delta-rule layer) beside its latent "
+                 "pages: nothing exports or adopts it "
                  "(export_sequence_kv* / adopt_sequence refuse too)"},
     "quant": {
         "latent": "the grouped expert kernels take bf16 or int8 weights",
@@ -460,14 +529,23 @@ _WHY_NOT = {
         "state": "no test holds int4 projections around a recurrence to "
                  "the reference (int8 quantizes the projections and "
                  "leaves the scan's own parameters, A_log, D, the dt "
-                 "and conv weights, as they are)"},
+                 "and conv weights, as they are)",
+        "delta": "no test holds int4 projections around a recurrence to "
+                 "the reference (int8 quantizes the projections and "
+                 "leaves the delta rule's own parameters, A_log, dt_bias, "
+                 "the conv, beta and gate weights, as they are)"},
     "spec": {
         "state": "a rejected draft would already have advanced the "
                  "sequence's state, and no snapshot is kept to go back "
-                 "to"},
+                 "to",
+        "delta": "a rejected draft would already have advanced the "
+                 "sequence's matrix states, and no snapshot is kept to "
+                 "go back to"},
     "hybrid": {
         "state": "a prefill chunk and the decode lanes in one program "
-                 "both scatter into the state slots: untested"},
+                 "both scatter into the state slots: untested",
+        "delta": "a prefill chunk and the decode lanes in one program "
+                 "both advance the state slots in place: untested"},
     # Not an error: the cache is left off with this line (a one-kind
     # window model does the same, __init__ below).
     "prefix": {
@@ -477,21 +555,39 @@ _WHY_NOT = {
         "state": "a hit would need a snapshot of every state-space "
                  "layer's state at the prefix's end (and the window "
                  "kind's last sliding_window tokens before it): none is "
-                 "kept"},
+                 "kept",
+        "delta": "a hit would need a snapshot of every delta-rule "
+                 "layer's matrix state and convolution tail at the "
+                 "prefix's end: none is kept"},
 }
 
 _WHAT_IT_IS = {"latent": "latent attention", "looped": "a looped stack",
                "kinds": "layers of mixed kinds",
-               "state": "state-space layers"}
+               "state": "state-space layers", "delta": "delta-rule layers"}
+
+
+def model_columns(model_cfg: ModelConfig) -> tuple:
+    """The columns of ``_WHY_NOT`` that speak for this model (none: a
+    plain stack, nothing is refused for what it is)."""
+    kinds = model_cfg.layer_types[:model_cfg.n_layers]
+    return tuple(c for c, on in (
+        ("latent", model_cfg.latent_dim), ("looped", model_cfg.loop_steps > 1),
+        ("state", model_cfg.state_kind == "ssm"),
+        ("delta", model_cfg.state_kind == "kda"),
+        ("kinds", kinds and not model_cfg.state_kind)) if on)
 
 
 def model_is(model_cfg: ModelConfig) -> Optional[str]:
-    """The column of ``_WHY_NOT`` that speaks for this model (None: a
-    plain stack, nothing is refused for what it is)."""
-    kinds = model_cfg.layer_types[:model_cfg.n_layers]
-    return ("latent" if model_cfg.latent_dim else
-            "looped" if model_cfg.loop_steps > 1 else
-            "state" if "ssm" in kinds else "kinds" if kinds else None)
+    """``model_columns`` in words: "state", "latent + delta", ... (None:
+    a plain stack)."""
+    return " + ".join(model_columns(model_cfg)) or None
+
+
+def why_not(what: str, model_cfg: ModelConfig) -> Optional[str]:
+    """Row ``what`` of ``_WHY_NOT`` for this model: the reason of every
+    column that speaks for it and has one (None: no column does)."""
+    return "; ".join(_WHY_NOT[what][c] for c in model_columns(model_cfg)
+                     if c in _WHY_NOT[what]) or None
 
 
 def _refuse_unsupported(model_cfg: ModelConfig, engine_cfg: EngineConfig,
@@ -514,17 +610,17 @@ def _refuse_unsupported(model_cfg: ModelConfig, engine_cfg: EngineConfig,
         if what:
             raise ValueError("keep_logits does not support: "
                              + "; ".join(what))
-    is_ = model_is(model_cfg)
-    if is_ is None:
+    is_ = model_columns(model_cfg)
+    if not is_:
         return
-    why = {k: v.get(is_) for k, v in _WHY_NOT.items()}
+    why = {k: why_not(k, model_cfg) for k in _WHY_NOT}
     if model_cfg.early_exit_threshold < 1.0:
         what.append(f"early_exit_threshold={model_cfg.early_exit_threshold}"
                     " < 1 (per-token depth: every token runs every pass)")
     if mesh is not None and any(int(mesh.shape.get(ax, 1)) > 1
                                 for ax in ("tp", "sp", "pp")):
-        what.append(f"tp / sp / pp > 1 ({why['tp']})" if is_ == "state"
-                    else f"tp / sp > 1 ({why['tp']})")
+        what.append(f"tp / sp / pp > 1 ({why['tp']})"
+                    if model_cfg.state_kind else f"tp / sp > 1 ({why['tp']})")
     if engine_cfg.kv_quant != "none":
         what.append(f"kv_quant={engine_cfg.kv_quant!r} ({why['kv_quant']})")
     if spec:
@@ -539,8 +635,9 @@ def _refuse_unsupported(model_cfg: ModelConfig, engine_cfg: EngineConfig,
     if engine_cfg.role != "mixed":
         what.append(f"role={engine_cfg.role!r} ({why['role']})")
     if what:
-        raise ValueError(f"{model_cfg.name} ({_WHAT_IT_IS[is_]}) does not "
-                         "support: " + "; ".join(what))
+        raise ValueError(
+            f"{model_cfg.name} ({' and '.join(_WHAT_IT_IS[c] for c in is_)}) "
+            "does not support: " + "; ".join(what))
 
 
 def _extend(result: Dict[int, List[int]], more: Dict[int, List[int]]
@@ -869,7 +966,7 @@ class InferenceEngine:
         self.telemetry = telemetry.EngineTelemetry(self)
         if self.state_slots is not None:
             self.telemetry.bind_state(self)
-        elif self.aux_stats is not None:
+        if self.aux_stats is not None and model_cfg.n_experts:
             self.telemetry.bind_moe(self)
         # Boot phases (gauges set once; a caller that loaded a
         # checkpoint itself adds its load time to the first).
@@ -993,9 +1090,11 @@ class InferenceEngine:
         swa_binds = bool(model_cfg.sliding_window) and (
             engine_cfg.max_context > model_cfg.sliding_window)
         self.host_pool = None
-        if engine_cfg.enable_prefix_cache and self.win_allocator is not None:
+        if engine_cfg.enable_prefix_cache and (
+                self.win_allocator is not None
+                or self.state_slots is not None):
             print(f"[engine] {model_cfg.name}: prefix cache disabled — "
-                  + _WHY_NOT["prefix"][model_is(model_cfg)])
+                  + why_not("prefix", model_cfg))
         elif engine_cfg.enable_prefix_cache and not swa_binds:
             # SWA models run WITHOUT the prefix cache (vLLM makes the
             # same exclusion): behind-window pages are evicted while a
@@ -1435,7 +1534,11 @@ class InferenceEngine:
         devs = self._devices
         peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
                  for d in devs]
+        state = ({} if self.state_slots is None else {
+            "state_slots": self.state_slots.num_slots - 1,
+            "state_bytes_per_slot": self.model_cfg.state_bytes_per_seq()})
         return {
+            **state,
             "platform": devs[0].platform,
             "kind": devs[0].device_kind,
             "ids": [d.id for d in devs],
@@ -1952,11 +2055,11 @@ class InferenceEngine:
         model with state-space layers also needs a free state slot a
         request."""
         room = self._free_plus_evictable() - headroom
-        if self.win_allocator is None:
-            return room >= want[0]
         if self.state_slots is not None and \
                 self.state_slots.num_free < want[2]:
             return False        # a state slot a sequence
+        if self.win_allocator is None:
+            return room >= want[0]
         usable = np.asarray([self.engine_cfg.num_pages - 1,
                              self.win_allocator.num_pages - 1])
         booked = self.pages_booked_seen = self.pages_booked()
@@ -2256,8 +2359,10 @@ class InferenceEngine:
         state: nothing exports or adopts the state slot."""
         if self.state_slots is not None:
             raise ValueError(
-                f"{self.model_cfg.name} (state-space layers) does not "
-                f"support KV export / adoption: {_WHY_NOT['role']['state']}")
+                f"{self.model_cfg.name} "
+                f"({_WHAT_IT_IS[model_columns(self.model_cfg)[-1]]}) does "
+                "not support KV export / adoption: "
+                + _WHY_NOT["role"][model_columns(self.model_cfg)[-1]])
 
     def export_sequence_kv(self, seq: Sequence
                            ) -> Tuple[List[bytes], List["kvc.HostKVPage"]]:
@@ -2559,7 +2664,8 @@ class InferenceEngine:
         n_new = kvc.pages_needed(len(prompt), ecfg.page_size) - len(shared)
         try:
             seq.pages = shared + self._allocate_reclaiming(n_new)
-            if self.win_allocator is not None:
+            if (self.win_allocator is not None
+                    or self.state_slots is not None):
                 seq.pages = kvc.KindPages(seq.pages)
         except MemoryError:
             self.allocator.free(shared)

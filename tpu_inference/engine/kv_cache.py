@@ -68,16 +68,31 @@ class KVPages(NamedTuple):
     P_window, ...]``, each with its own allocator and its own block
     table a sequence (``KindPages``). None for a model of one kind.
 
-    A model with state-space layers (kind "ssm") also holds a state a
-    SEQUENCE, which a token advances and no page table addresses:
-    ``conv`` ``[n_ssm, S + 1, d_conv - 1, d_inner]`` (the conv's last
-    inputs, model dtype) and ``ssm_h`` ``[n_ssm, S + 1, d_state,
-    d_inner]`` (float32, state-major so that d_inner lies on the lanes),
-    S state slots handed out by ``StateSlots``; slot 0 is the TRASH slot,
-    as page 0 is the trash page: a lane that advances nothing in a call
-    writes there. (Fields beside the pools, not a mapping by kind: the
-    kinds' states differ in shape, indexing and allocator, so a mapping
-    would move each special case to its reader and remove none.)
+    A model with layers of a state kind (``config.STATE_KINDS``: "ssm",
+    a selective scan, or "kda", a delta rule) also holds a state a
+    SEQUENCE, which a token advances and no page table addresses, in
+    ``conv`` and ``ssm_h``, each ``[n_layers_of_the_kind, S + 1, ...]``
+    with the rest as ``ModelConfig.state_shapes`` says: the
+    convolution's last inputs in the model dtype (ssm ``[d_conv - 1,
+    d_inner]``; kda ``[d_conv - 1, 3 x heads x d]``, q, k and v side by
+    side) and the recurrent state in float32 (ssm ``[d_state, d_inner]``,
+    state-major so that d_inner lies on the lanes; kda ``[heads, d_k,
+    d_v]``, a matrix a head). S state slots are handed out by
+    ``StateSlots``; slot 0 is the TRASH slot, as page 0 is the trash
+    page: a lane that advances nothing in a call writes there. Such a
+    model's page pools are a pool a kind (``k`` / ``v`` / ``wk`` / ``wv``,
+    Phi-4) or ONE latent pool of its "full" layers (``k`` alone,
+    Ling-3.0).
+
+    (Fields beside the pools, not a mapping by kind. PR 40 kept them on
+    the ground that the kinds' states differ in shape, indexing and
+    allocator; PR 51 brought a second state shape and decided again:
+    the two shapes differ ONLY in what lies behind ``[layers, slots]``.
+    Both are indexed ``[layer's place among its kind, slot]``, handed out
+    by the one ``StateSlots``, read and written by the one
+    ``engine.PagedState``, and a model has one state kind, so the two
+    fields hold either and ``state_shapes`` is the one place that knows
+    which. A mapping by kind would have one entry a model.)
     """
 
     k: jax.Array
@@ -122,8 +137,10 @@ def latent_width(model_cfg: ModelConfig) -> int:
 
 def alloc_latent_pages(model_cfg: ModelConfig, engine_cfg: EngineConfig,
                        dtype=None) -> KVPages:
-    """A latent pool, with the model's counter vector beside it where
-    its family module asks for one (``n_aux_stats``)."""
+    """A latent pool (of the "full" layers alone where the model has
+    other kinds), with the model's counter vector beside it where its
+    family module asks for one (``n_aux_stats``) and the state slots
+    where it has layers of a state kind."""
     from tpu_inference.models.registry import family_fn
 
     n_aux = family_fn(model_cfg, "n_aux_stats")
@@ -135,7 +152,8 @@ def alloc_latent_pages(model_cfg: ModelConfig, engine_cfg: EngineConfig,
              engine_cfg.page_size, latent_width(model_cfg))
     pool = jax.jit(lambda: jnp.zeros(shape, dtype or model_cfg.dtype))()
     return KVPages(k=pool, v=None, aux=jnp.zeros(
-        (n_aux(model_cfg),), jnp.int32) if n_aux else None)
+        (n_aux(model_cfg),), jnp.int32) if n_aux else None,
+        **alloc_state_slots(model_cfg, engine_cfg))
 
 
 def write_latent(kv: KVPages, layer_idx: jax.Array, entry: jax.Array,
@@ -224,11 +242,29 @@ class StateSlots:
 
 
 def num_state_slots(model_cfg: ModelConfig, engine_cfg: EngineConfig) -> int:
-    """State slots of a model with state-space layers, trash slot
+    """State slots of a model with layers of a state kind, trash slot
     included (0: the model has none): one a lane."""
-    if "ssm" not in model_cfg.layer_types[:model_cfg.n_layers]:
+    if not model_cfg.state_kind:
         return 0
     return engine_cfg.max_batch_size + 1
+
+
+def alloc_state_slots(model_cfg: ModelConfig,
+                      engine_cfg: EngineConfig) -> dict:
+    """The ``conv`` / ``ssm_h`` fields of a model with layers of a state
+    kind ({}: it has none): zeros, ``ModelConfig.state_shapes`` behind
+    ``[layers of the kind, slots]``."""
+    n_slots = num_state_slots(model_cfg, engine_cfg)
+    if not n_slots:
+        return {}
+    lead = (len(model_cfg.kind_layers(model_cfg.state_kind)), n_slots)
+    tail, state = model_cfg.state_shapes()
+
+    def zeros(shape, dt):
+        return jax.jit(lambda: jnp.zeros(lead + shape, dt))()
+
+    return dict(conv=zeros(tail, model_cfg.dtype),
+                ssm_h=zeros(state, jnp.float32))
 
 
 def alloc_kind_pages(model_cfg: ModelConfig, engine_cfg: EngineConfig,
@@ -255,14 +291,7 @@ def alloc_kind_pages(model_cfg: ModelConfig, engine_cfg: EngineConfig,
 
     n_aux = family_fn(model_cfg, "n_aux_stats")
     n_win = num_window_pages(model_cfg, engine_cfg)
-    state = {}
-    n_slots = num_state_slots(model_cfg, engine_cfg)
-    if n_slots:
-        lead = (len(model_cfg.kind_layers("ssm")), n_slots)
-        state = dict(
-            conv=zeros(lead + (model_cfg.ssm_d_conv - 1, model_cfg.d_inner)),
-            ssm_h=zeros(lead + (model_cfg.ssm_d_state, model_cfg.d_inner),
-                        jnp.float32))
+    state = alloc_state_slots(model_cfg, engine_cfg)
     return KVPages(
         k=pool("full", engine_cfg.num_pages),
         v=pool("full", engine_cfg.num_pages),

@@ -19,8 +19,10 @@ What differs from ``models/llama.py``:
   with a SwiGLU of d_ff) and ``params["moe"]`` (router, shared expert,
   held routed experts) are different pytrees.
 - **This chip's share of the experts.** The router scores all
-  ``n_experts``; the top-k is taken over all of them and the gates are
-  normalised over the k chosen; only pairs whose expert is held here
+  ``n_experts``; the top-k is taken over all of them (over the experts
+  of the ``topk_group`` best of ``n_group`` groups where the router is
+  limited to groups: a chip of such a deployment holds whole groups)
+  and the gates are normalised over the k chosen; only pairs whose expert is held here
   (``ep_rank``'s ``n_experts / ep_size``) are computed, none of them
   dropped (kernels/moe_experts.py); the shared expert runs in full. The
   routed stacks ``we_*`` stay OUT of the scan's xs: the grouped kernels
@@ -68,11 +70,17 @@ MOE_STATS = ("tokens", "local_pairs", "computed_pairs", "busiest_pairs",
 # tiles, beside the real pairs among them (``computed_pairs``): what
 # kernels/moe_experts.tile_rows costs.
 ROW_STATS = ("tile_rows",)
+# Behind those, where the router is limited to groups (``cfg.n_group`` >
+# 1): the routed token positions one of whose chosen groups has experts
+# held here. Over ``tokens`` it is the share of tokens the expert
+# exchange would send this chip at all.
+GROUP_STATS = ("group_reach_tokens",)
 
 
 def n_moe_stats(cfg: ModelConfig) -> int:
     return (len(MOE_STATS) + cfg.n_local_experts
-            + (len(ROW_STATS) if cfg.moe_row_stats else 0))
+            + (len(ROW_STATS) if cfg.moe_row_stats else 0)
+            + (len(GROUP_STATS) if cfg.n_group > 1 else 0))
 
 
 # What the shared layers ask a family module for, where it differs from
@@ -121,11 +129,15 @@ def softmax_scale(cfg: ModelConfig) -> float:
 
 def _attn_shapes(cfg: ModelConfig, n: int) -> dict:
     d, h = cfg.d_model, cfg.n_heads
+    hq = h * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+    # No query compression (``q_lora_rank`` 0): one projection ``wq``.
+    query = ({"wq_a": (n, d, cfg.q_lora_rank), "q_norm": (n, cfg.q_lora_rank),
+              "wq_b": (n, cfg.q_lora_rank, hq)} if cfg.q_lora_rank
+             else {"wq": (n, d, hq)})
+    if cfg.attn_gate == "per_head":
+        query["w_head_gate"] = (n, d, h)
     return {
-        "attn_norm": (n, d), "wq_a": (n, d, cfg.q_lora_rank),
-        "q_norm": (n, cfg.q_lora_rank),
-        "wq_b": (n, cfg.q_lora_rank,
-                 h * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)),
+        "attn_norm": (n, d), **query,
         "wkv_a": (n, d, cfg.kv_lora_rank + cfg.qk_rope_head_dim),
         "kv_norm": (n, cfg.kv_lora_rank),
         "wkv_b": (n, cfg.kv_lora_rank,
@@ -191,9 +203,13 @@ def latent_attention(cfg: ModelConfig, layer_idx, lp: dict, h: jax.Array,
     nh, r = cfg.n_heads, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     with jax.named_scope("mla_q_proj"):
-        cq = rms_norm(qdot(h, lp["wq_a"]).astype(h.dtype), lp["q_norm"],
-                      cfg.norm_eps)
-        q = qdot(cq, lp["wq_b"]).astype(h.dtype).reshape(b, s, nh, dn + dr)
+        if cfg.q_lora_rank:
+            cq = rms_norm(qdot(h, lp["wq_a"]).astype(h.dtype), lp["q_norm"],
+                          cfg.norm_eps)
+            q = qdot(cq, lp["wq_b"])
+        else:
+            q = qdot(h, lp["wq"])
+        q = q.astype(h.dtype).reshape(b, s, nh, dn + dr)
         q_rope = apply_rope(q[..., dn:], positions, cfg.rope_theta,
                             cfg.rope_scaling)
     with jax.named_scope("mla_kv_proj"):
@@ -212,13 +228,37 @@ def latent_attention(cfg: ModelConfig, layer_idx, lp: dict, h: jax.Array,
     with jax.named_scope("mla_out_proj"):
         o = jnp.einsum("bshr,rhv->bshv", o_lat, w_kvb[..., dn:],
                        preferred_element_type=jnp.float32).astype(h.dtype)
+        if cfg.attn_gate == "per_head":
+            # Head h's output times sigmoid(x_normed . w_h) (gated
+            # attention, head-wise: models/laguna.py has the same gate).
+            gate = jax.nn.sigmoid(jnp.einsum(
+                "bsd,dh->bsh", h, lp["w_head_gate"],
+                preferred_element_type=jnp.float32))
+            o = (o.astype(jnp.float32) * gate[..., None]).astype(h.dtype)
         return qdot(o.reshape(b, s, nh * dv), lp["wo"]), kv
 
 
-def route(cfg: ModelConfig, lp: dict, x2: jax.Array):
-    """x2 [T, D] -> (top_idx [T, k] over all n_experts, gates [T, k] f32).
-    The router runs in float32 at the highest matmul precision: a pair
-    flips expert on a last-bit difference, and 2.75M weights are cheap."""
+def group_limit(cfg: ModelConfig, ranked: jax.Array):
+    """ranked [T, n_experts] (the scores the top-k ranks by, selection
+    bias in) -> (ranked with the experts of every group that does not
+    stay at -inf, kept [T, n_group] bool). The experts lie in
+    ``cfg.n_group`` groups of consecutive experts; a group's score is the
+    sum of its two largest ranked scores and the best ``cfg.topk_group``
+    groups stay."""
+    t = ranked.shape[0]
+    grouped = ranked.reshape(t, cfg.n_group, -1)
+    best2, _ = jax.lax.top_k(grouped, min(2, grouped.shape[-1]))
+    _, stay = jax.lax.top_k(best2.sum(-1), cfg.topk_group)      # [T, tg]
+    kept = jnp.any(stay[:, :, None] == jnp.arange(cfg.n_group)[None, None],
+                   axis=1)                                       # [T, G]
+    return jnp.where(kept[:, :, None], grouped, -jnp.inf).reshape(t, -1), kept
+
+
+def router_scores(cfg: ModelConfig, lp: dict, x2: jax.Array):
+    """x2 [T, D] -> (scores [T, n_experts] f32, the same with the
+    selection bias in: what the top-k ranks by). The router runs in
+    float32 at the highest matmul precision: a pair flips expert on a
+    last-bit difference, and 2.75M weights are cheap."""
     logits = jnp.dot(x2.astype(jnp.float32),
                      lp["w_router"].astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
@@ -227,9 +267,21 @@ def route(cfg: ModelConfig, lp: dict, x2: jax.Array):
     # (A router with no selection bias has no such leaf: the top-k is
     # taken by the scores themselves.)
     bias = lp.get("router_bias")
-    _, top_idx = jax.lax.top_k(
-        scores if bias is None else scores + bias[None, :],
-        cfg.n_experts_per_tok)
+    return scores, scores if bias is None else scores + bias[None, :]
+
+
+def route(cfg: ModelConfig, lp: dict, x2: jax.Array):
+    """x2 [T, D] -> (top_idx [T, k] over all n_experts, gates [T, k] f32).
+    Where the router is limited to groups (``cfg.n_group`` > 1:
+    ``group_limit``) the top-k is taken among the experts of the groups
+    that stay; with one group every expert is ranked at once. The gates
+    are the chosen experts' scores WITHOUT the selection bias either
+    way, normalised over the chosen."""
+    scores, ranked = router_scores(cfg, lp, x2)
+    if cfg.n_group > 1:
+        with jax.named_scope("moe_group_select"):
+            ranked, _ = group_limit(cfg, ranked)
+    _, top_idx = jax.lax.top_k(ranked, cfg.n_experts_per_tok)
     gates = jnp.take_along_axis(scores, top_idx, axis=1)
     if cfg.norm_topk_prob:
         gates = gates / (jnp.sum(gates, axis=1, keepdims=True) + 1e-20)
@@ -293,6 +345,18 @@ def moe_ffn(cfg: ModelConfig, lp: dict, experts: tuple, moe_layer,
         groups.counts]
     if cfg.moe_row_stats:
         stats.append((groups.n_tiles * groups.tm).reshape(1))
+    if cfg.n_group > 1:
+        # Tokens one of whose chosen groups has experts held here (the
+        # router's scores a second time in the trace, once in the
+        # program: the compiler merges the two).
+        size = cfg.n_experts // cfg.n_group
+        lo, hi = first // size, (first + n_held - 1) // size + 1
+        with jax.named_scope("moe_group_select"):
+            _, kept = group_limit(cfg, router_scores(cfg, lp, x2)[1])
+        reach = jnp.any(kept[:, lo:hi], axis=1)
+        if valid is not None:
+            reach &= valid.reshape(b * s)
+        stats.append(jnp.sum(reach).reshape(1))
     stats = jnp.concatenate(stats).astype(jnp.int32)
     if shared is not None:
         routed = routed + shared.astype(jnp.float32)

@@ -85,6 +85,12 @@ QUANT_KEYS = frozenset({
     # out, the attention's query and output). The scan's own parameters
     # (conv taps, W_x, W_dt and its bias, A_log, D) stay in their dtype.
     "w1", "w2", "w_in", "w_q", "w_o",
+    # models/bailing_hybrid.py: the delta-rule layers' projections (the
+    # fused q / k / v, the decay's, the output's; ``wq`` / ``wkv_a`` /
+    # ``wo`` of its latent layers are above). The gate's own parameters
+    # (A_log, dt_bias), the convolution's taps and the head-wide beta and
+    # gate projections stay in their dtype.
+    "w_f",
 })
 
 # The stacks the engine stores transposed, ``[.., N, K]`` (see the module
@@ -100,6 +106,7 @@ STORED_TRANSPOSED = {
     "deepseek_v3": frozenset({"wq_b", "wkv_b"}),
     "sambay": frozenset({"w_q"}),
     "smallthinker": frozenset({"wq", "wk", "wv"}),
+    "bailing_hybrid": frozenset({"wq", "wkv_b"}),
 }
 
 

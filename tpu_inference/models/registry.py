@@ -11,13 +11,15 @@ from tpu_inference.config import ModelConfig
 
 
 def get_model_fns(cfg: ModelConfig) -> types.ModuleType:
-    from tpu_inference.models import (deepseek_v3, gpt2, laguna, llama,
-                                      mixtral, ouro, sambay, smallthinker)
+    from tpu_inference.models import (bailing_hybrid, deepseek_v3, gpt2,
+                                      laguna, llama, mixtral, ouro, sambay,
+                                      smallthinker)
 
     return {"llama": llama, "mixtral": mixtral, "gpt2": gpt2,
             "deepseek_v3": deepseek_v3, "ouro": ouro,
             "laguna": laguna, "sambay": sambay,
-            "smallthinker": smallthinker}[cfg.family]
+            "smallthinker": smallthinker,
+            "bailing_hybrid": bailing_hybrid}[cfg.family]
 
 
 def family_fn(cfg: ModelConfig, name: str):

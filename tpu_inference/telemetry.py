@@ -2791,7 +2791,8 @@ class EngineTelemetry:
         them in ``engine.aux_stats`` (engine._fold_aux_stats)."""
         if not self.enabled:
             return
-        from tpu_inference.models.deepseek_v3 import MOE_STATS, ROW_STATS
+        from tpu_inference.models.deepseek_v3 import (GROUP_STATS, MOE_STATS,
+                                                      ROW_STATS)
 
         r, st = self.registry, engine.aux_stats
         at = {name: i for i, name in enumerate(MOE_STATS)}
@@ -2844,6 +2845,18 @@ class EngineTelemetry:
                       "Local pairs the grouped expert rounds computed: "
                       "the real rows among tpu_inf_moe_tile_rows_total",
                       fn=lambda: int(st[at["computed_pairs"]]))
+        if engine.model_cfg.n_group > 1:
+            at_reach = (len(MOE_STATS) + n_held
+                        + len(ROW_STATS) * engine.model_cfg.moe_row_stats
+                        + GROUP_STATS.index("group_reach_tokens"))
+            r.counter("tpu_inf_moe_group_reach_tokens_total",
+                      "Routed token positions (summed over expert layers, "
+                      "as tpu_inf_moe_tokens_total) one of whose chosen "
+                      "expert GROUPS has experts this chip holds: over "
+                      "the tokens it is the share the expert exchange "
+                      "would send here at all (topk_group / n_group "
+                      "under uniform routing)",
+                      fn=lambda: int(st[at_reach]))
         if engine.model_cfg.hc_mult > 1:
             from tpu_inference.models.hyper_connections import MHC_STATS
 
@@ -2860,20 +2873,18 @@ class EngineTelemetry:
                     fn=lambda: int(st[first + 1]))
 
     def bind_state(self, engine) -> None:
-        """Read-through metrics of a model with state-space layers: the
-        state slots (engine/kv_cache.py StateSlots) and what its prefill
-        programs ran for, counted on the device (models/sambay.py
-        AUX_STATS; the counts ride the decode token readback out as the
-        routing counts do)."""
+        """Read-through metrics of a model with layers of a state kind
+        (config.STATE_KINDS): the state slots (engine/kv_cache.py
+        StateSlots) and, for the sambay family, what its prefill programs
+        ran for, counted on the device (models/sambay.py AUX_STATS; the
+        counts ride the decode token readback out as the routing counts
+        do)."""
         if not self.enabled:
             return
-        from tpu_inference.models.sambay import AUX_STATS
-
         r, slots, st = self.registry, engine.state_slots, engine.aux_stats
-        at = {name: i for i, name in enumerate(AUX_STATS)}
         r.gauge("tpu_inf_state_slots_total",
-                "Allocatable per-sequence state slots (state-space "
-                "layers)", fn=lambda: slots.num_slots - 1)
+                "Allocatable per-sequence state slots (layers with a "
+                "state a sequence)", fn=lambda: slots.num_slots - 1)
         r.gauge("tpu_inf_state_slots_in_use",
                 "State slots held by a sequence", fn=lambda: slots.in_use)
         r.gauge("tpu_inf_state_slots_peak",
@@ -2881,12 +2892,17 @@ class EngineTelemetry:
                 fn=lambda: slots.peak_in_use)
         r.gauge("tpu_inf_state_bytes_per_seq",
                 "Bytes of state one sequence's slot holds over all "
-                "state-space layers",
+                "layers of the state kind",
                 fn=lambda: engine.model_cfg.state_bytes_per_seq())
         r.counter("tpu_inf_state_resets_total",
                   "Prefill chunks at position 0 (a prompt's first, a "
                   "recompute-resume's): states started from zeros",
                   fn=lambda: slots.resets_total)
+        if engine.model_cfg.family != "sambay":
+            return
+        from tpu_inference.models.sambay import AUX_STATS
+
+        at = {name: i for i, name in enumerate(AUX_STATS)}
         r.counter("tpu_inf_prefill_positions_total",
                   "Prompt positions the prefill programs ran the layers "
                   "up to the full-attention one for (counted in the "
