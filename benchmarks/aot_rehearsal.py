@@ -382,6 +382,9 @@ def main() -> int:
             # The matrix states: the delta-rule kernels advance them
             # where they lie (kernels/delta_rule.py).
             copies += pool_copies(text, tuple(kv.ssm_h.shape))
+            # ... and the convolutions' tails (kda_tail_step a decode
+            # step; a prefill chunk gathers lanes and scatters them).
+            copies += pool_copies(text, tuple(kv.conv.shape))
         pcopies, laid = ((param_copies(text, params),
                           param_copies(text, params, device_laid=True))
                          if graph != "swap" else ([], []))

@@ -122,6 +122,14 @@ SETTINGS = {
     # chunked form that loses a block, a sub-block's decay or the state
     # at a boundary is off by the output's whole spread.
     "delta_rule_tol": 0.001,
+    # The one-token convolution (kernels/delta_rule.kda_tail_step)
+    # against the XLA form on the same bf16 tails and taps: four
+    # products summed in float32 in one order on both sides and a SiLU;
+    # x as a share of its spread. 0 in interpret mode on the CPU; the
+    # chip's two compilers may round exp and the division differently,
+    # a few ulps of float32 (1e-7 a value). A tap lost or taken from the
+    # wrong row is off by the spread itself.
+    "kda_tail_tol": 1e-5,
     # The routed-expert layer (models/deepseek_v3.py moe_ffn: router,
     # grouping, kernels/moe_experts.py) at Kimi-K2's widths against a
     # plain float32 loop over the held experts on the SAME routing: the
@@ -1210,6 +1218,99 @@ def _kv_rows_write_check(cfg: dict, *, pools=(("window", 8), ("full", 1)),
     return out
 
 
+def _kda_tail_step_check(cfg: dict, *, lanes: int = 96, layers: int = 11,
+                         heads: int = 32, d: int = 128, taps: int = 4,
+                         reps: int = 200, interpret: bool = False) -> dict:
+    """A delta-rule layer's one-token convolution
+    (kernels/delta_rule.kda_tail_step) against the XLA form it replaces
+    (a gather of the tails, the taps, ``take_along_axis``, a scatter) on
+    the same random bf16 pool at cell 10's shapes: 96 lanes on 97 slots,
+    11 layers, 3 x 32 heads x 128 = 12,288 channels, 4 taps. Every sixth
+    lane has no valid token (its tail must stay, its write goes to the
+    trash slot), every tenth is fresh (reads zeros), the slots are
+    shuffled. ``tail_err``: elements of the pool that differ, the trash
+    slot left out (which idle lane lands there last is nobody's
+    business), at the first and the last layer; ``x_err``: the largest
+    distance of x as a share of the reference's spread; ``kernel_us`` /
+    ``xla_us``: a call inside a loop that carries the donated pool, the
+    layer moving with the trip, as the difference of 2 x ``reps`` trips
+    and ``reps``, the best of five."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tpu_inference.engine.engine import PagedState
+    from tpu_inference.engine.kv_cache import KVPages
+    from tpu_inference.kernels import delta_rule as dr
+
+    rng = np.random.default_rng(cfg["seed"])
+    bf, c = jnp.bfloat16, 3 * heads * d
+    keys = jax.random.split(jax.random.PRNGKey(cfg["seed"] & 0x7FFFFFFF), 3)
+    slots = jnp.asarray(rng.permutation(np.arange(1, lanes + 1)), jnp.int32)
+    lens = jnp.asarray(np.arange(lanes) % 6 != 3, jnp.int32)
+    fresh = jnp.asarray(np.arange(lanes) % 10 == 4)
+    slots_w = jnp.where(lens > 0, slots, 0)       # as PagedState's
+    qkv = jax.random.normal(keys[0], (lanes, c), bf)
+    conv_w = (taps ** -0.5 * jax.random.normal(keys[1], (taps, c))).astype(bf)
+    shape = (layers, lanes + 1, taps - 1, 3 * heads, d)
+    fresh_pool = jax.jit(lambda: jax.random.normal(keys[2], shape, bf))
+
+    def kernel(layer, pool):
+        return dr.kda_tail_step(pool, layer, slots, slots_w, lens, fresh,
+                                qkv, conv_w, interpret=interpret)
+
+    def xla(layer, pool):
+        """What the engine runs off the kernel's backend."""
+        state = PagedState(slots, lens[:, None] > 0,
+                           jnp.where(fresh, 0, 1), False, False)
+        x, kv = state.conv_step(layer, qkv[:, None], conv_w,
+                                KVPages(k=None, v=None, conv=pool))
+        return x[:, 0], kv.conv
+
+    def per_trip_us(step):
+        def trip(i, carry):
+            x, pool = step(jax.lax.rem(i, layers), carry[1])
+            return carry[0] + x[0, 0], pool
+
+        run = jax.jit(lambda n, pool: jax.lax.fori_loop(
+            0, n, trip, (jnp.float32(0), pool)), donate_argnums=(1,))
+        best = {}
+        for n in (reps, 2 * reps):
+            took = []
+            for _ in range(6):                  # the first one compiles
+                pool = jax.block_until_ready(fresh_pool())
+                t0 = time.perf_counter()
+                jax.block_until_ready(run(n, pool))
+                took.append(time.perf_counter() - t0)
+            best[n] = min(took[1:])
+        return round((best[2 * reps] - best[reps]) / reps * 1e6, 2)
+
+    differ = jax.jit(lambda a, b: jnp.sum(
+        a[:, 1:].view(jnp.uint16) != b[:, 1:].view(jnp.uint16)))
+    out = {"tail_err": 0, "x_err": 0.0}
+    for layer in sorted({0, layers - 1}):
+        x, pool = jax.jit(kernel)(jnp.int32(layer), fresh_pool())
+        x_ref, want = jax.jit(xla)(jnp.int32(layer), fresh_pool())
+        out["tail_err"] += int(differ(pool, want))
+        live = np.asarray(lens, bool)[:, None]
+        out["x_err"] = max(out["x_err"], float(
+            np.abs(np.where(live, x - x_ref, 0)).max() / np.std(x_ref)))
+        # (A kernel that moved nothing must not read 0 against a form
+        # that moved nothing: the reference changes a live lane's tail.)
+        moved = int(differ(fresh_pool(), want))
+        wrote = int(jnp.sum(lens)) * (taps - 1) * c
+        check(0.9 * wrote < moved <= wrote,
+              f"the XLA form moved {moved} elements of the {wrote} it wrote")
+    out["x_err"] = round(out["x_err"], 9)
+    if reps:
+        out["kernel_us"] = per_trip_us(kernel)
+        out["xla_us"] = per_trip_us(xla)
+        out["bytes"] = int(lanes * c * (2 * (taps - 1) * 2 + 2 + 4))
+    return out
+
+
 def _latent_kernel_errors(cfg: dict, *, heads: int = 64, rank: int = 512,
                           rope: int = 64,
                           ctx=(100, 0, 5000, 8200, 0, 0, 10000, 3333),
@@ -1714,6 +1815,13 @@ def child_parity(cfg: dict) -> dict:
         check(max(v for k, v in res["delta_rule_err"].items()
                   if k.endswith(("_o", "_s"))) <= cfg["delta_rule_tol"],
               f"delta-rule kernels vs the recurrence: {res}")
+        # A delta-rule layer's one-token convolution: the tails the
+        # gather, taps and scatter give, bit for bit, x to float32's
+        # rounding, and what a call of each costs.
+        res["kda_tail_step_err"] = _kda_tail_step_check(cfg)
+        check(res["kda_tail_step_err"]["tail_err"] == 0
+              and res["kda_tail_step_err"]["x_err"] <= cfg["kda_tail_tol"],
+              f"kda_tail_step vs the XLA form it replaces: {res}")
         # A decode step's K / V write into merged-row pools: the pool
         # the scatter gives, bit for bit, and what a call of each costs.
         res["kv_rows_write_err"] = _kv_rows_write_check(cfg)

@@ -118,7 +118,7 @@ def test_the_preset_is_the_cut_the_configuration_file_states():
     # 2 MiB of matrix state + a 72 KB convolution tail a layer.
     assert full.state_bytes_per_seq() == 11 * (32 * 128 * 128 * 4
                                                + 3 * 12288 * 2)
-    assert full.state_shapes() == ((3, 12288), (32, 128, 128))
+    assert full.state_shapes() == ((3, 96, 128), (32, 128, 128))
     assert full.n_local_experts == 64 == full.n_experts // full.n_group
     assert REF.layer_kinds(MODEL, 4) == CFG.layer_types
     assert model_columns(full) == ("latent", "delta")
@@ -136,14 +136,19 @@ def test_forward_matches_the_plain_reference(weights):
     assert float(np.std(ref)) > 0.05
 
 
-@pytest.mark.parametrize("backend", ["dense", "pallas"])
+# The engine's two backends: under "pallas" (interpret mode here) a
+# prefill chunk runs the chunk kernel and a decode step the one-token
+# convolution and the one-token update (kernels/delta_rule.py), which
+# advance the tails and the states where they lie in the pools.
+BACKENDS = ["dense", "pallas"]
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
 def test_engine_matches_the_reference_at_every_kept_position(weights,
                                                               backend):
     """Prefill in chunks (37 tokens: 16 + 16 + 5, the state crosses two
     chunk boundaries), a batched prefill with padded rows, then fused-K
-    paged decode of all three: every kept row is the reference's. On
-    the Pallas backend the chunk kernel and the one-token update run
-    (interpret mode) and advance the states in place in the pool."""
+    paged decode of all three: every kept row is the reference's."""
     eng = _engine(weights, attn_backend=backend)
     long = _seq(0, _prompts(37, seed=2)[0])
     eng.prefill(long)
@@ -158,16 +163,17 @@ def test_engine_matches_the_reference_at_every_kept_position(weights,
     assert 0 < eng.aux_stats[-1] <= stats["tokens"]
 
 
-def test_alone_and_batched_agree(weights):
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_alone_and_batched_agree(weights, backend):
     """Each prompt of a batched prefill reads what it reads alone: a
     padded position advances no state."""
     prompts = _prompts(9, 16, 11, seed=3)
-    eng = _engine(weights)
+    eng = _engine(weights, attn_backend=backend)
     batched = [_seq(i, p, 5) for i, p in enumerate(prompts)]
     eng.prefill_many(batched)
     _run(eng, batched)
     alone = _seq(10, prompts[0], 5)
-    one = _engine(weights)
+    one = _engine(weights, attn_backend=backend)
     one.prefill(alone)
     _run(one, [alone])
     assert alone.generated == batched[0].generated
@@ -175,10 +181,11 @@ def test_alone_and_batched_agree(weights):
         assert float(np.abs(row - batched[0].kept_logits[pos]).max()) < TOL
 
 
-def test_a_lane_allowed_fewer_steps_advances_no_further(weights):
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_a_lane_allowed_fewer_steps_advances_no_further(weights, backend):
     """Inside one fused call of K = 4 a lane with 2 tokens left runs 2
     steps; the steps it is masked for write the trash slot."""
-    eng = _engine(weights)
+    eng = _engine(weights, attn_backend=backend)
     a, b = _seq(0, _prompts(12)[0], 3), _seq(1, _prompts(9, seed=4)[0], 11)
     eng.prefill_many([a, b])
     _run(eng, [a, b])
@@ -205,8 +212,9 @@ def test_preempt_and_recompute_resume(weights):
     assert _worst(weights, [seq, other]) < TOL
 
 
-def test_a_released_slot_leaks_nothing_into_its_next_owner(weights):
-    eng = _engine(weights, max_batch_size=2)
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_a_released_slot_leaks_nothing_into_its_next_owner(weights, backend):
+    eng = _engine(weights, max_batch_size=2, attn_backend=backend)
     first = [_seq(i, p, 3) for i, p in enumerate(_prompts(9, 12, seed=8))]
     eng.prefill_many(first)
     held = {s.pages.state for s in first}

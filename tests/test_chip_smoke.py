@@ -288,6 +288,17 @@ def test_delta_rule_check_in_interpret_mode():
     assert max(errs.values()) <= chip_smoke.SETTINGS["delta_rule_tol"]
 
 
+def test_kda_tail_step_check_in_interpret_mode():
+    """The smoke's check of a delta-rule layer's one-token convolution
+    (run on the chip at cell 10's shapes: 96 lanes, 97 slots, 11 layers,
+    12,288 channels, timed) at a few lanes and heads through the
+    interpreter, untimed: idle and fresh lanes among the valid ones, the
+    tails the XLA form's bit for bit, x to float32's rounding."""
+    errs = chip_smoke._kda_tail_step_check(TINY, lanes=16, layers=2,
+                                           heads=2, reps=0, interpret=True)
+    assert errs == {"tail_err": 0, "x_err": 0.0}
+
+
 def test_kv_rows_write_check_in_interpret_mode():
     """The smoke's check of a decode step's K / V write into merged-row
     pools (run on the chip at cell 7's shapes, 64 lanes, timed) at a few

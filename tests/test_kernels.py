@@ -697,6 +697,99 @@ def test_kda_step_kernel_equals_one_step(lanes, heads, d):
     assert bool((out[0] == pool[0]).all())
 
 
+def _tail_reference(pool, layer, slots, slots_w, lens, fresh, qkv, conv_w):
+    """The XLA form ``kda_tail_step`` replaces, as the engine ran it
+    until PR 52: ``PagedState.tail`` (a gather, zeros for a fresh lane),
+    the taps summed in float32 from the oldest on, ``take_along_axis`` at
+    ``lens`` for the tail a lane leaves, ``PagedState.put_tail`` (a
+    scatter to ``slots_w``)."""
+    b, taps, f32 = qkv.shape[0], conv_w.shape[0], jnp.float32
+    tail = jnp.where(~fresh[:, None, None],
+                     pool[layer, slots].reshape(b, taps - 1, -1), 0)
+    seq = jnp.concatenate([tail, qkv[:, None]], axis=1)
+    conv = jnp.zeros((b, 1, qkv.shape[1]), f32)
+    for j in range(taps):
+        conv = conv + conv_w[j].astype(f32) * seq[:, j:j + 1].astype(f32)
+    at = lens[:, None] + jnp.arange(taps - 1)[None, :]
+    new = jnp.take_along_axis(seq, at[..., None], axis=1)
+    return jax.nn.silu(conv)[:, 0], pool.at[layer, slots_w].set(
+        new.reshape((b,) + pool.shape[2:])), tail
+
+
+# name: (lanes, taps, lens of each lane (cycled), fresh lanes, a lane
+# staged with lane 0's slot (stale: its lens is 0), the layer traced)
+TAIL_CASES = {
+    "every_lane_valid": (3, 4, (1,), (), None, False),
+    "idle_lanes_among_them": (16, 4, (1, 0, 1, 1, 0), (), None, False),
+    "a_fresh_lane": (4, 4, (1, 1, 0, 1), (1, 2), None, False),
+    "two_lanes_staged_with_one_slot": (4, 4, (1, 1, 1, 0), (), 3, False),
+    "two_taps": (8, 2, (1, 0, 1), (0,), None, False),
+    "two_grid_steps_of_four_taps": (16, 4, (1,), (5,), None, False),
+    "a_traced_layer_in_a_scan": (3, 4, (1, 0, 1), (2,), None, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TAIL_CASES))
+def test_kda_tail_step_equals_the_gather_taps_and_scatter(case):
+    """The one-token convolution in interpret mode against the XLA form:
+    the tails bit-equal in every slot a valid lane owns, untouched in the
+    slot of a lane with no valid token (which writes the tail it read to
+    slot 0) and in the other layer; x within 1e-6 of its spread."""
+    from tpu_inference.kernels import delta_rule as dr
+
+    lanes, taps, lens, fresh_at, stale, traced = TAIL_CASES[case]
+    rows, d, layers = 6, 128, 2
+    ks = jax.random.split(jax.random.PRNGKey(len(case)), 3)
+    bf = jnp.bfloat16
+    pool = jax.random.normal(
+        ks[0], (layers, lanes + 1, taps - 1, rows, d)).astype(bf)
+    qkv = jax.random.normal(ks[1], (lanes, rows * d)).astype(bf)
+    conv_w = (taps ** -0.5 * jax.random.normal(
+        ks[2], (layers, taps, rows * d))).astype(bf)
+    lens = jnp.asarray([lens[i % len(lens)] for i in range(lanes)],
+                       jnp.int32)
+    fresh = jnp.zeros((lanes,), bool).at[jnp.asarray(fresh_at, int)].set(True)
+    slots = jnp.arange(1, lanes + 1)
+    if stale is not None:
+        slots = slots.at[stale].set(slots[0])
+    slots_w = jnp.where(lens > 0, slots, 0)
+    args = (slots, slots_w, lens, fresh, qkv)
+
+    if traced:
+        def body(p, layer):
+            x, p = dr.kda_tail_step(p, layer, *args, conv_w[layer],
+                                    interpret=True)
+            return p, x
+
+        out, xs = jax.jit(lambda p: jax.lax.scan(
+            body, p, jnp.arange(layers)))(pool)
+        want = pool
+        for layer in range(layers):
+            x_ref, want, _ = _tail_reference(want, layer, *args,
+                                             conv_w[layer])
+            assert float(jnp.abs(xs[layer] - x_ref).max()) \
+                <= 1e-6 * float(jnp.std(x_ref))
+        assert bool((out[:, 1:] == want[:, 1:]).all())
+        return
+    x, out = dr.kda_tail_step(pool, 1, *args, conv_w[1], interpret=True)
+    x_ref, want, read = _tail_reference(pool, 1, *args, conv_w[1])
+    assert float(jnp.abs(x - x_ref).max()) <= 1e-6 * float(jnp.std(x_ref))
+    assert bool((out[1, 1:] == want[1, 1:]).all())
+    assert bool((out[0] == pool[0]).all())
+    idle = [i for i in range(lanes) if int(lens[i]) == 0]
+    for i in idle:
+        if stale is None:
+            assert bool((out[1, slots[i]] == pool[1, slots[i]]).all())
+    if idle:                       # slot 0 holds what one of them read
+        assert any(bool((out[1, 0].reshape(taps - 1, -1) == read[i]).all())
+                   for i in idle)
+    moved = [i for i in range(lanes) if int(lens[i]) == 1]
+    for i in moved:                # [tail[1:], qkv]
+        mine = out[1, slots[i]].reshape(taps - 1, -1)
+        assert bool((mine[-1] == qkv[i]).all())
+        assert bool((mine[:-1] == read[i][1:]).all())
+
+
 def test_kda_gate_bound_that_would_overflow_is_refused():
     from tpu_inference.kernels import delta_rule as dr
 

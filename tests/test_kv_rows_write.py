@@ -151,3 +151,44 @@ def test_the_autosize_line_says_it_too(capsys, backend, path):
          "decode_ladder": "off", "target_ctx": 2176, "batch_cap": 64},
         hbm_bytes=16.91e9)
     assert f"kv_decode_write={path} " in capsys.readouterr().err
+
+
+@pytest.mark.parametrize("preset,backend,path", [
+    ("tiny-ling", "pallas", "kernel"), ("tiny-ling", "dense", "xla"),
+    ("tiny-sambay", "pallas", None)])
+def test_healthz_says_how_a_decode_step_passes_the_convolution(
+        preset, backend, path):
+    """``device.kda_tail_step``, beside ``kv_decode_write``: a delta-rule
+    layer's one-token convolution is the kernel under the Pallas backend
+    (kernels/delta_rule.kda_tail_step) and the XLA form off it; a model
+    with no such layer has no such field."""
+    from tpu_inference.config import PRESETS, EngineConfig
+    from tpu_inference.engine.engine import InferenceEngine
+
+    eng = InferenceEngine(
+        PRESETS[preset](),
+        EngineConfig(page_size=16, num_pages=16, max_pages_per_seq=4,
+                     max_batch_size=2, prefill_buckets=(16,),
+                     enable_prefix_cache=False),
+        attn_backend=backend, pallas_interpret=backend == "pallas")
+    assert eng.device_info().get("kda_tail_step") == path
+
+
+@pytest.mark.parametrize("preset,backend,said", [
+    ("ling3-flash-ep8", "pallas", "kda_tail_step=kernel "),
+    ("ling3-flash-ep8", "dense", "kda_tail_step=xla "),
+    ("phi4-mini-flash", "pallas", "")])
+def test_the_autosize_line_says_the_convolutions_path_too(capsys, preset,
+                                                          backend, said):
+    from tpu_inference.config import PRESETS, EngineConfig
+    from tpu_inference.engine import autosize
+
+    autosize.resolve_sizing(
+        PRESETS[preset](),
+        EngineConfig(attn_backend=backend, page_size=16,
+                     max_pages_per_seq=640),
+        {"max_batch_size": "auto", "num_pages": "auto",
+         "decode_ladder": "off", "target_ctx": 2176, "batch_cap": 64},
+        hbm_bytes=16.91e9)
+    line = capsys.readouterr().err
+    assert said in line and ("kda_tail_step" in line) == bool(said)

@@ -796,3 +796,109 @@ def test_a_layer_loop_writes_merged_row_pools_in_place(chip, kind):
             # layer's" shape, so only a copy says anything.
             found = [f for f in found if f.startswith("copy ")]
         assert found == [], (shape, found)
+
+
+# ---------------------------------------------------------------------------
+# Ling-3.0-flash: a delta-rule layer's one-token convolution
+# (kernels/delta_rule.kda_tail_step) at cell 10's shapes: 96 lanes on 97
+# slots, 11 layers, 3 x 32 heads of 128 = 12,288 channels, 4 taps.
+# ---------------------------------------------------------------------------
+
+LING = dict(lanes=96, slots=97, layers=11, heads=32, d=128, taps=4)
+
+
+def _ling_tails(chip):
+    return jax.ShapeDtypeStruct(
+        (LING["layers"], LING["slots"], LING["taps"] - 1, 3 * LING["heads"],
+         LING["d"]), jnp.bfloat16, sharding=chip)
+
+
+@pytest.mark.parametrize("lanes", [96, 8])
+def test_the_tail_step_kernel_compiles_for_v5e(chip, lanes):
+    """Every lane's tail held in VMEM at once (7.1 MB at 96 lanes), eight
+    lanes a grid step, at the top rung and the base one."""
+    from tpu_inference.kernels import delta_rule as dr
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    c = 3 * LING["heads"] * LING["d"]
+    i32 = lambda *shape: s(shape, jnp.int32)                   # noqa: E731
+    hlo = dr.kda_tail_step.lower(
+        _ling_tails(chip), i32(), i32(lanes), i32(lanes), i32(lanes),
+        s((lanes,), jnp.bool_), s((lanes, c), jnp.bfloat16),
+        s((LING["taps"], c), jnp.bfloat16)).compile().as_text()
+    assert "tpu_custom_call" in hlo and "kda_tail_step" in hlo
+
+
+def test_a_decode_layer_loop_advances_the_tails_in_place(chip):
+    """``bailing_hybrid.kda_mix`` over one token a lane through
+    ``engine.PagedState`` on the Pallas backend at the published sizes,
+    under a ``lax.scan`` over the 11 delta-rule layers that carries the
+    donated pools, compiled for the v5e: the only instructions whose
+    result has the tail pool's shape are the kernel's (and the loop's
+    plumbing), none produces one layer's tails or every lane's, and
+    nothing gathers 288 rows of 12,288 (the XLA form's seven ops a
+    layer: PERF.md section 6, PR 52); the weight stack in front of the
+    kernel is read where it lies."""
+    import re
+
+    from tpu_inference.config import PRESETS
+    from tpu_inference.engine.engine import PagedState
+    from tpu_inference.engine.kv_cache import KVPages
+    from tpu_inference.models import bailing_hybrid as bh
+
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir,
+                                    "benchmarks"))
+    from aot_rehearsal import param_copies, pool_copies
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    cfg = PRESETS["ling3-flash-ep8"]()
+    b, n = LING["lanes"], LING["layers"]
+    assert cfg.state_shapes()[0] == _ling_tails(chip).shape[2:]
+    params = {k: s(shape, jnp.float32 if k in ("a_log", "dt_bias")
+                   else jnp.bfloat16)
+              for k, shape in bh.param_shapes(cfg)["kda"].items()}
+    tails = _ling_tails(chip)
+    states = s((n, LING["slots"]) + cfg.state_shapes()[1], jnp.float32)
+
+    def step(params, conv, ssm_h, x, slots, valid, q_offset):
+        attn = lambda *a: None                                 # noqa: E731
+        attn.state = PagedState(slots, valid, q_offset, True, False)
+        kv = KVPages(k=None, v=None, conv=conv, ssm_h=ssm_h)
+
+        def body(carry, i):
+            x, kv = carry
+            y, kv = bh.kda_mix(cfg, i, jax.tree.map(lambda a: a[i], params),
+                               x, kv, attn)
+            return (x + y, kv), None
+
+        (x, kv), _ = jax.lax.scan(body, (x, kv), jnp.arange(n))
+        return x, kv.conv, kv.ssm_h
+
+    hlo = jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, tails, states, s((b, 1, cfg.d_model), jnp.bfloat16),
+        s((b,), jnp.int32), s((b, 1), jnp.bool_),
+        s((b,), jnp.int32)).compile().as_text()
+    assert "kda_tail_step" in hlo and "kda_step" in hlo
+    assert pool_copies(hlo, tails.shape) == []
+    assert pool_copies(hlo, states.shape) == []
+    assert param_copies(hlo, params) == []
+    made = re.findall(r"^\s*(?:ROOT )?%[\w.\-]+ = \(?\w+\[([\d,]*)\]\S* "
+                      r"(?:[^=]*?\) )?([\w\-]+)\(", hlo, re.M)
+    whole = ",".join(map(str, tails.shape))
+    lanes_tails = ",".join(map(str, (b,) + tails.shape[2:]))
+    flat = 3 * LING["heads"] * LING["d"]
+    # (copy-start / copy-done: this program is small enough that the
+    # compiler moves the 79 MB pool into the chip's 128 MiB of VMEM for
+    # the kernel's call; beside 10.8 GB of weights it stays in HBM,
+    # benchmarks/aot_rehearsal.py's ``pool_copies`` on decode:96.)
+    assert {op for dims, op in made if dims == whole} <= {
+        "custom-call", "parameter", "get-tuple-element", "bitcast", "while",
+        "tuple", "copy-start", "copy-done"}
+    assert [op for dims, op in made
+            if dims in (lanes_tails, f"{b * (LING['taps'] - 1)},{flat}",
+                        f"{b},{LING['taps'] - 1},{flat}",
+                        f"{b},{LING['taps']},{flat}")] == []

@@ -330,11 +330,17 @@ class ModelConfig:
         tail, the recurrent state), each as a shape behind the pool's
         ``[layers, slots]``. "ssm": the last d_conv - 1 inputs
         [d_conv - 1, d_inner] and h [d_state, d_inner]; "kda": the last
-        kda_d_conv - 1 inputs of q, k and v side by side and a matrix
-        [d_k, d_v] a head. The tail is in the model dtype, the state
-        float32."""
+        kda_d_conv - 1 inputs of q, k and v side by side, each input's
+        3 x heads x d channels as [3 x heads, d] (a head a row: whole
+        tiles a lane, so the chip holds a lane's tail in one piece and
+        kernels/delta_rule.kda_tail_step advances it where it lies;
+        as [taps, channels] the chip lays the SLOTS along the tiles'
+        rows and a lane's tail is 288 half-rows of other lanes' tiles)
+        and a matrix [d_k, d_v] a head. The tail is in the model dtype,
+        the state float32."""
         if self.state_kind == "kda":
-            return ((self.kda_d_conv - 1, 3 * self.kda_width),
+            return ((self.kda_d_conv - 1, 3 * self.kda_n_heads,
+                     self.kda_head_dim),
                     (self.kda_n_heads, self.kda_head_dim, self.kda_head_dim))
         return ((self.ssm_d_conv - 1, self.d_inner),
                 (self.ssm_d_state, self.d_inner))

@@ -37,6 +37,7 @@ from typing import Optional
 
 from tpu_inference.config import KV_PAGE_UNIT
 from tpu_inference.engine.kv_cache import (decode_write_path,
+                                           kda_tail_step_path,
                                            window_span_pages,
                                            written_ahead_tokens)
 
@@ -718,8 +719,9 @@ def resolve_sizing(model_cfg, engine_cfg, req: Optional[dict], *,
                 mbs * window_span_pages(model_cfg, engine_cfg) + 1):
             engine_cfg = dataclasses.replace(
                 engine_cfg, num_window_pages=sz.num_window_pages)
-        write = decode_write_path(
-            model_cfg, pallas_reads_pool(engine_cfg.attn_backend))
+        pallas = pallas_reads_pool(engine_cfg.attn_backend)
+        write = decode_write_path(model_cfg, pallas)
+        tail_step = kda_tail_step_path(model_cfg, pallas)
         print(f"[autosize] {model_cfg.name}: batch={mbs} num_pages={pages} "
               f"page_tokens={engine_cfg.page_size} "
               f"kv_decode_write={write} "
@@ -727,7 +729,8 @@ def resolve_sizing(model_cfg, engine_cfg, req: Optional[dict], *,
                  if sz.num_window_pages else "")
               + (f"state_slots={mbs} state_bytes_per_slot="
                  f"{model_cfg.state_bytes_per_seq()} "
-                 if model_cfg.state_kind else "") +
+                 if model_cfg.state_kind else "")
+              + (f"kda_tail_step={tail_step} " if tail_step else "") +
               f"(hbm {sz.hbm_bytes / 1e9:.2f} GB, weights/chip "
               f"{sz.weight_bytes_per_chip / 1e9:.2f} GB, kv pool/chip "
               f"{sz.kv_pool_bytes_per_chip / 1e9:.2f} GB, target ctx "

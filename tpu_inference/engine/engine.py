@@ -284,8 +284,9 @@ class PagedState:
 
     A state-space layer (models/sambay.py) calls ``read`` / ``scan`` /
     ``write``; a delta-rule layer (models/bailing_hybrid.py) ``tail`` /
-    ``put_tail`` for the convolution's and ``delta`` for the matrix
-    state, which the kernels advance where it lies in the pool."""
+    ``put_tail`` for the convolution's (``conv_step`` where a call is
+    one token a lane) and ``delta`` for the matrix state; the kernels
+    advance both where they lie in the pools."""
 
     def __init__(self, slots, valid, q_offset, pallas: bool,
                  interpret: bool):
@@ -296,12 +297,33 @@ class PagedState:
         self.pallas, self.interpret = pallas, interpret
 
     def tail(self, layer, kv: KVPages):
+        """Each lane's tail [B, taps - 1, channels] (the pool holds a
+        delta-rule layer's channels a head a row)."""
+        tail = kv.conv[layer, self.slots]
         return jnp.where(~self.fresh[:, None, None],
-                         kv.conv[layer, self.slots], 0)
+                         tail.reshape(tail.shape[:2] + (-1,)), 0)
 
     def put_tail(self, layer, tail, kv: KVPages) -> KVPages:
         return kv._replace(conv=kv.conv.at[layer, self.slots_w].set(
-            tail.astype(kv.conv.dtype)))
+            tail.astype(kv.conv.dtype).reshape(
+                tail.shape[:1] + kv.conv.shape[2:])))
+
+    def conv_step(self, layer, qkv, conv_w, kv: KVPages):
+        """A delta-rule layer's convolution over ONE token a lane, qkv
+        [B, 1, C] -> (x [B, 1, C] float32, kv): the kernel reads, uses
+        and advances each lane's tail where it lies in ``kv.conv``
+        (kernels/delta_rule.kda_tail_step); off its backend, the
+        model's taps over ``tail`` / ``put_tail``."""
+        if not self.pallas:
+            from tpu_inference.models.bailing_hybrid import conv_taps
+
+            return conv_taps(self, layer, qkv, conv_w, kv)
+        from tpu_inference.kernels import delta_rule as dr
+
+        x, pool = dr.kda_tail_step(
+            kv.conv, layer, self.slots, self.slots_w, self.lens, self.fresh,
+            qkv[:, 0], conv_w, interpret=self.interpret)
+        return x[:, None], kv._replace(conv=pool)
 
     def read(self, layer, kv: KVPages):
         keep = ~self.fresh[:, None, None]
@@ -1537,6 +1559,10 @@ class InferenceEngine:
         state = ({} if self.state_slots is None else {
             "state_slots": self.state_slots.num_slots - 1,
             "state_bytes_per_slot": self.model_cfg.state_bytes_per_seq()})
+        tail_step = kvc.kda_tail_step_path(self.model_cfg,
+                                           self.attn_backend == "pallas")
+        if tail_step:
+            state["kda_tail_step"] = tail_step
         return {
             **state,
             "platform": devs[0].platform,

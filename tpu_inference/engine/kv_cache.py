@@ -74,11 +74,11 @@ class KVPages(NamedTuple):
     ``conv`` and ``ssm_h``, each ``[n_layers_of_the_kind, S + 1, ...]``
     with the rest as ``ModelConfig.state_shapes`` says: the
     convolution's last inputs in the model dtype (ssm ``[d_conv - 1,
-    d_inner]``; kda ``[d_conv - 1, 3 x heads x d]``, q, k and v side by
-    side) and the recurrent state in float32 (ssm ``[d_state, d_inner]``,
-    state-major so that d_inner lies on the lanes; kda ``[heads, d_k,
-    d_v]``, a matrix a head). S state slots are handed out by
-    ``StateSlots``; slot 0 is the TRASH slot, as page 0 is the trash
+    d_inner]``; kda ``[d_conv - 1, 3 x heads, d]``, q, k and v side by
+    side, a head a row) and the recurrent state in float32 (ssm
+    ``[d_state, d_inner]``, state-major so that d_inner lies on the
+    lanes; kda ``[heads, d_k, d_v]``, a matrix a head). S state slots are
+    handed out by ``StateSlots``; slot 0 is the TRASH slot, as page 0 is the trash
     page: a lane that advances nothing in a call writes there. Such a
     model's page pools are a pool a kind (``k`` / ``v`` / ``wk`` / ``wv``,
     Phi-4) or ONE latent pool of its "full" layers (``k`` alone,
@@ -448,6 +448,19 @@ def decode_write_path(model_cfg: ModelConfig, pallas: bool) -> str:
     ``write_kv``: the kernel's reference, and every other write's path).
     /healthz ``device.kv_decode_write`` and the [autosize] line say it."""
     return "kernel" if model_cfg.pool_rows_merged and pallas else "scatter"
+
+
+def kda_tail_step_path(model_cfg: ModelConfig, pallas: bool) -> str:
+    """How a decode step passes a delta-rule layer's convolution, fixed
+    when its program is built ("" for a model with no such layer):
+    "kernel" (kernels/delta_rule.kda_tail_step: each lane's tail read,
+    used and advanced where it lies) under the Pallas backend, else
+    "xla" (a gather of the tails, the taps, a scatter: the kernel's
+    reference, and a prefill chunk's path). /healthz
+    ``device.kda_tail_step`` and the [autosize] line say it."""
+    if model_cfg.state_kind != "kda":
+        return ""
+    return "kernel" if pallas else "xla"
 
 
 def page_starts(block_tables: jax.Array, first_pos: jax.Array,

@@ -52,6 +52,19 @@ each copy every lane's state once more):
   gathered states (tests, and the engine's path off the kernels).
 
 Float32 throughout, matrix products at the highest precision.
+
+In front of the delta rule a KDA layer passes ``[q | k | v]`` through a
+causal depthwise convolution whose last ``taps - 1`` inputs a sequence
+keeps in the TAIL POOL ``[layers, slots, taps - 1, 3 H, d]`` (model
+dtype; a head a row, so a lane's tail is whole tiles in one piece).
+``kda_tail_step`` is that convolution for ONE token a lane, one Pallas
+kernel named ``kda_tail_step``: every lane's tail is copied from its
+slot into VMEM (all copies started before the first is waited for),
+the taps are summed in float32, and the tail, moved on by the token, is
+copied back to the slot it is written to. As gathers and scatters the
+same job was seven XLA ops a layer that touched the tail row by row,
+192 us against 614 us for ``kda_step``'s fifty-seven times the bytes
+(PERF.md section 6, PR 52).
 """
 
 from __future__ import annotations
@@ -68,6 +81,7 @@ SUB = 16              # tokens a sub-block: SUB * |bound| has to stay < 88
 MAX_EXPONENT = 80.0   # what a masked row's exponent is clamped to
 TIME_BLOCK = 256      # tokens a grid step brings in
 STEP_HEADS = 16       # heads a grid step of the one-token update
+TAIL_LANES = 8        # lanes a grid step of the one-token convolution
 HIGHEST = jax.lax.Precision.HIGHEST
 
 
@@ -320,6 +334,110 @@ def kda_step(pool: jax.Array, layer: jax.Array, slots_r: jax.Array,
       cols(jnp.exp(g.astype(f32))), cols(k), cols(q), vb,
       jnp.broadcast_to(bt, (bsz, h, dv)), pool)
     return o.reshape(bsz, h * dv), pool
+
+
+def _tail_kernel(layer_ref, slots_r, slots_w, lens_ref, fresh_ref, qkv_ref,
+                 w_ref, pool_in, x_ref, pool, buf, sem_in, sem_out, *,
+                 group: int, taps: int):
+    del pool_in                           # aliased: ``pool`` is it
+    i = pl.program_id(0)
+    layer, lanes = layer_ref[0], buf.shape[0]
+
+    def fetch(b):
+        return pltpu.make_async_copy(pool.at[layer, slots_r[b]], buf.at[b],
+                                     sem_in.at[b // group])
+
+    def leave(b):
+        return pltpu.make_async_copy(buf.at[b], pool.at[layer, slots_w[b]],
+                                     sem_out.at[0])
+
+    def each(n, fn):
+        def body(b, carry):
+            fn(b)
+            return carry
+        jax.lax.fori_loop(0, n, body, 0)
+
+    @pl.when(i == 0)
+    def _():
+        each(lanes, lambda b: fetch(b).start())
+
+    each(group, lambda g: fetch(i * group + g).wait())
+    for g in range(group):
+        b = i * group + g
+        keep = fresh_ref[b] == 0
+        seq = [jnp.where(keep, buf[b, j], jnp.zeros_like(buf[b, j]))
+               for j in range(taps - 1)] + [qkv_ref[g]]
+        conv = jnp.zeros(seq[0].shape, jnp.float32)
+        for j in range(taps):
+            conv = conv + (w_ref[j].astype(jnp.float32)
+                           * seq[j].astype(jnp.float32))
+        x_ref[g] = jax.nn.silu(conv)
+        # The tail the lane leaves: [tail[1:], qkv] behind a valid token,
+        # the tail it read (zeros if fresh) behind none.
+        moved = lens_ref[b] > 0
+        for j in range(taps - 1):
+            buf[b, j] = jnp.where(moved, seq[j + 1], seq[j])
+        leave(b).start()
+
+    @pl.when(i == pl.num_programs(0) - 1)
+    def _():
+        each(lanes, lambda b: leave(b).wait())
+
+
+@partial(jax.jit, static_argnames=("interpret",))
+def kda_tail_step(pool: jax.Array, layer: jax.Array, slots_r: jax.Array,
+                  slots_w: jax.Array, lens: jax.Array, fresh: jax.Array,
+                  qkv: jax.Array, conv_w: jax.Array, *,
+                  interpret: bool = False):
+    """One token of B lanes through one layer's convolution, each lane's
+    tail read once and written once, in place.
+
+    pool:    [L, N, taps - 1, R, d] the tail slots, ``R d`` channels
+    layer:   int32 scalar, may be traced (the model's scan index)
+    slots_r: [B] the slot lane b's tail is read from; ``fresh`` [B]
+             bool: read zeros instead
+    slots_w: [B] the slot it is written to (0, the trash slot, for a
+             lane with ``lens`` 0; several lanes may write it at once)
+    lens:    [B] 1 where the lane's token is valid, 0 where the tail
+             stays as it was read
+    qkv:     [B, R * d] the token's input;  conv_w [taps, R * d]
+    -> (x [B, R * d] float32 = silu(sum_j conv_w[j] * [tail | qkv][j]),
+    taps summed in float32 from the oldest on, and the pool)."""
+    km1, rows, d = pool.shape[2:]
+    bsz, taps = qkv.shape[0], km1 + 1
+    group = TAIL_LANES if bsz % TAIL_LANES == 0 else bsz
+    lane = pl.BlockSpec((group, rows, d), lambda i, *_: (i, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    held = bsz * km1 * rows * d * pool.dtype.itemsize          # 7.1 MB at 96
+    i32 = lambda a: jnp.asarray(a, jnp.int32)                  # noqa: E731
+    # The token's row arrives a lane a row and is re-laid a head a row,
+    # one XLA copy of B rows. Behind a barrier: without it the chip's
+    # compiler moves the reshape into the projection in front and re-lays
+    # that layer stack's whole weight instead, once a dispatch (692 MB
+    # for Ling-3.0-flash's w_qkv: benchmarks/aot_rehearsal.py refuses it).
+    qkv = jax.lax.optimization_barrier(qkv.astype(pool.dtype))
+    x, pool = pl.pallas_call(
+        partial(_tail_kernel, group=group, taps=taps),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(bsz // group,),
+            in_specs=[lane, pl.BlockSpec((taps, rows, d),
+                                         lambda i, *_: (0, 0, 0)), hbm],
+            out_specs=[lane, hbm],
+            scratch_shapes=[pltpu.VMEM((bsz, km1, rows, d), pool.dtype),
+                            pltpu.SemaphoreType.DMA((bsz // group,)),
+                            pltpu.SemaphoreType.DMA((1,))]),
+        out_shape=[jax.ShapeDtypeStruct((bsz, rows, d), jnp.float32),
+                   jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
+        input_output_aliases={7: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=held + (8 << 20)),
+        interpret=interpret, name="kda_tail_step",
+    )(i32(layer).reshape(1), i32(slots_r), i32(slots_w), i32(lens),
+      i32(fresh), qkv.reshape(bsz, rows, d),
+      conv_w.reshape(taps, rows, d), pool)
+    return x.reshape(bsz, rows * d), pool
 
 
 def kda_recurrence(q, k, v, g, beta, s0, lens):

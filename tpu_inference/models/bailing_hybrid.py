@@ -19,9 +19,10 @@ The kind of ``Mix_l`` is ``cfg.layer_types[l]``:
   and no page: positions enter through the state alone. The state (the
   convolution's last ``kda_d_conv - 1`` inputs and ``S``) belongs to a
   SEQUENCE and a token ADVANCES it; the model reads and writes it
-  through ``attn.state`` (``tail`` / ``put_tail`` / ``delta`` / ``lens``:
-  Phi-4's contract, models/sambay.py) and never names a pool or a slot:
-  positions behind ``attn.state.lens`` advance nothing.
+  through ``attn.state`` (``tail`` / ``put_tail`` / ``conv_step`` /
+  ``delta`` / ``lens``: Phi-4's contract, models/sambay.py) and never
+  names a pool or a slot: positions behind ``attn.state.lens`` advance
+  nothing.
 - ``full``: latent attention, ``models/deepseek_v3.latent_attention``
   with ONE query projection (``q_lora_rank`` 0) and a sigmoid gate a
   head, through ``attn`` itself (the latent contract); the layer's slot
@@ -145,35 +146,48 @@ def _l2norm(x: jax.Array) -> jax.Array:
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + L2_EPS)
 
 
+def conv_taps(st, slot, qkv: jax.Array, conv_w: jax.Array, kv: Any):
+    """The causal depthwise convolution and its SiLU over qkv [B, S, C]
+    behind each lane's tail -> (x [B, S, C] float32, kv with the tails
+    moved on), through ``st.tail`` / ``st.put_tail``."""
+    b, s, c = qkv.shape
+    kc, f32 = conv_w.shape[0], jnp.float32
+    seq = jnp.concatenate([st.tail(slot, kv).astype(qkv.dtype), qkv], axis=1)
+    conv = jnp.zeros((b, s, c), f32)
+    for j in range(kc):
+        conv = conv + conv_w[j].astype(f32) * seq[:, j:j + s].astype(f32)
+    # The tail a lane leaves: the last kc - 1 inputs among its VALID
+    # ones (rows lens .. of [tail | qkv]); none valid: the one it had.
+    at = st.lens[:, None] + jnp.arange(kc - 1)[None, :]
+    return jax.nn.silu(conv), st.put_tail(
+        slot, jnp.take_along_axis(seq, at[..., None], axis=1), kv)
+
+
 def kda_mix(cfg: ModelConfig, slot, lp: dict, h: jax.Array, kv: Any,
             attn: AttentionFn):
     """A KDA mixer over h [B, S, D] (normed) -> (output [B, S, D], kv).
     Each lane's state comes from and goes back through ``attn.state``."""
     b, s, _ = h.shape
-    nh, hd, w, kc = (cfg.kda_n_heads, cfg.kda_head_dim, cfg.kda_width,
-                     cfg.kda_d_conv)
+    nh, hd, w = cfg.kda_n_heads, cfg.kda_head_dim, cfg.kda_width
     st = attn.state
     f32 = jnp.float32
     delta_rule.check_bound(cfg.kda_gate_lower_bound)
     with jax.named_scope("kda_conv"):
         qkv = qdot(h, lp["w_qkv"]).astype(h.dtype)             # [B, S, 3W]
-        seq = jnp.concatenate([st.tail(slot, kv).astype(h.dtype), qkv],
-                              axis=1)
-        conv = jnp.zeros((b, s, 3 * w), f32)
-        for j in range(kc):
-            conv = conv + (lp["conv_w"][j].astype(f32)
-                           * seq[:, j:j + s].astype(f32))
-        x = jax.nn.silu(conv)
-        # The tail a lane leaves: the last kc - 1 inputs among its VALID
-        # ones (rows lens .. of [tail | qkv]); none valid: the one it had.
-        at = st.lens[:, None] + jnp.arange(kc - 1)[None, :]
-        kv = st.put_tail(slot, jnp.take_along_axis(seq, at[..., None],
-                                                   axis=1), kv)
-        heads = lambda a: a.reshape(b, s, nh, hd)              # noqa: E731
-        q = _l2norm(heads(x[..., :w])) * hd ** -0.5
-        k = _l2norm(heads(x[..., w:2 * w]))
-        v = heads(x[..., 2 * w:])
+        # One token a lane: the state's own step (the engine's advances
+        # the tail where it lies); a chunk: the taps over the tails.
+        if s == 1:
+            x, kv = st.conv_step(slot, qkv, lp["conv_w"], kv)
+        else:
+            x, kv = conv_taps(st, slot, qkv, lp["conv_w"], kv)
+        # A head a row, the form the step leaves x in: q, k and v are
+        # then whole rows of it and nothing is laid out again.
+        x = x.reshape(b, s, 3 * nh, hd)
+        q = _l2norm(x[:, :, :nh]) * hd ** -0.5
+        k = _l2norm(x[:, :, nh:2 * nh])
+        v = x[:, :, 2 * nh:]
     with jax.named_scope("kda_gate"):
+        heads = lambda a: a.reshape(b, s, nh, hd)              # noqa: E731
         rate = jnp.exp(lp["a_log"].astype(f32))[:, None]       # [H, 1]
         g = cfg.kda_gate_lower_bound * jax.nn.sigmoid(
             rate * heads(qdot(h, lp["w_f"]) + lp["dt_bias"].astype(f32)))
@@ -271,6 +285,9 @@ class DenseState:
 
     def put_tail(self, slot, tail, kv):
         return kv
+
+    def conv_step(self, slot, qkv, conv_w, kv):
+        return conv_taps(self, slot, qkv, conv_w, kv)
 
     def delta(self, slot, q, k, v, g, beta, kv):
         c = self.cfg
